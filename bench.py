@@ -8,16 +8,14 @@ the reference repo publishes no numbers of its own — see BASELINE.md).
 MFU accounting per BASELINE.md: 6*N*T flops/token, reported both without
 ("mfu") and with ("mfu_incl_remat") the 2*N recompute-forward credit.
 
-The bench is un-killable by design (round-3 lesson: the TPU plugin's backend
-init raised/hung inside ``jax.devices()`` before any bench code ran, and the
-round lost its perf number):
-
-- The default invocation is a PARENT that never imports jax. It probes the
-  backend in a SUBPROCESS with a hard timeout, retries init with backoff
-  (alternating JAX_PLATFORMS pinning), runs the measured ladder in a child
-  with its own timeout, falls back to a CPU smoke run when the TPU cannot be
-  initialized, and on total failure still emits a diagnostic JSON line.
-- ``bench.py --probe`` / ``--child`` are the subprocess entry points.
+One process per chip: the default invocation is a PARENT that never
+imports jax.  It probes the backend in a SUBPROCESS with a hard timeout,
+runs the measured ladder in a child with its own timeout, then each extra
+in a child of its own, one at a time.  A backend that cannot be
+initialized, a ladder with no rung that ran, or a failed extra is a
+non-zero exit with a diagnostic JSON line — never a CPU number under a
+device metric's name.  ``bench.py --probe`` / ``--child`` / ``--extra`` are
+the subprocess entry points.
 
 The measured ladder itself is memory-aware: it walks configs (bf16 AdamW
 moments first, then smaller batch, then a smaller model) so an OOM degrades
@@ -46,7 +44,9 @@ def _peak_flops(device) -> float:
     for key, val in _PEAK_BF16.items():
         if key in kind:
             return val
-    return 197e12  # assume v5e-class if unknown
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {kind!r} (platform "
+        f"{device.platform}): a utilization needs a device in _PEAK_BF16")
 
 
 def _cpu_smoke_config():
@@ -585,16 +585,6 @@ def _run_large(on_tpu):
             out = {"large_error": f"{type(e).__name__}: {str(e)[:150]}"}
             traceback.print_exc(file=sys.stderr)
     return out
-
-
-def _force_cpu_if_asked():
-    """Env alone is not enough: a site plugin may import jax first and set
-    jax_platforms through the config system, so the env var is ignored.
-    Re-pin through the config API (same trick as tests/conftest.py)."""
-    if os.environ.get("BENCH_FORCE_CPU") == "1":
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
 
 
 def _run_flash_autotune(on_tpu):
@@ -2618,8 +2608,8 @@ _EXTRAS = (("large", _run_large), ("decode", _run_decode),
 def _force_host_devices(n=8):
     """Force an n-device host (CPU) platform before the backend
     initializes — the dp axis for the grad_comm A/B off-chip.  Affects
-    only the CPU platform, so it is harmless when the TPU plugin is
-    active.  Shared with benchmarks/run.py's grad_comm config."""
+    only the CPU platform, so it is harmless on a machine with a chip.
+    Shared with benchmarks/run.py's grad_comm config."""
     xf = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in xf:
         os.environ["XLA_FLAGS"] = (
@@ -2630,7 +2620,6 @@ def _extra_main(name):
     """--extra NAME entry point: one extra config, fresh process."""
     if name == "grad_comm":
         _force_host_devices()
-    _force_cpu_if_asked()
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -2638,7 +2627,10 @@ def _extra_main(name):
         out = dict(_EXTRAS)[name](on_tpu)
     except Exception as e:
         traceback.print_exc(file=sys.stderr)
-        out = {f"{name}_error": f"{type(e).__name__}: {str(e)[:150]}"}
+        print(json.dumps(
+            {f"{name}_error": f"{type(e).__name__}: {str(e)[:150]}"}),
+            flush=True)
+        return 1
     print(json.dumps(out), flush=True)
     return 0
 
@@ -2647,7 +2639,6 @@ def _child_main():
     """Measured flagship ladder ONLY — extras run as sibling subprocesses
     of the parent AFTER this process (and its PJRT client) is gone, so a
     TPU extra never races the child for the per-process libtpu lock."""
-    _force_cpu_if_asked()
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -2670,7 +2661,7 @@ def _child_main():
             result["complete"] = True
             print(json.dumps(result), flush=True)
             return 0
-        except Exception as e:  # OOM or anything else: degrade, never die
+        except Exception as e:  # OOM or anything else: try the next rung
             errors.append(f"rung {i}: {type(e).__name__}: {str(e)[:200]}")
             traceback.print_exc(file=sys.stderr)
 
@@ -2679,12 +2670,11 @@ def _child_main():
         "value": 0.0, "unit": "tokens/s", "vs_baseline": 0.0,
         "error": "; ".join(errors),
     }))
-    return 0
+    return 1    # no rung ran
 
 
 def _probe_main():
     """Print the backend platform; exits nonzero on init failure."""
-    _force_cpu_if_asked()
     import jax
 
     d = jax.devices()[0]
@@ -2740,28 +2730,31 @@ def _run_extras(result, env, platform):
     gets the per-process libtpu lock to itself, with its own timeout
     outside the child's budget.  Prints incrementally — the driver takes
     the LAST parseable line, so a kill mid-extras still lands everything
-    measured so far."""
+    measured so far.  Returns (result, number of extras that failed)."""
     print(json.dumps(result), flush=True)
     tmo = 900 if platform == "tpu" else 420
+    failed = 0
     for name, _fn in _EXTRAS:
         rc, out, err = _spawn(["--extra", name], env, tmo)
         extra = _extract_json(out, require_metric=False)
         if extra is None:
             extra = {f"{name}_error":
                      f"extra subprocess rc={rc}: {err[-200:]}"}
+        failed += f"{name}_error" in extra
         result.update(extra)
         print(json.dumps(result), flush=True)
-    return result
+    return result, failed
 
 
 def _parent_main():
-    """Supervise probe + measured child runs; ALWAYS emit one JSON line."""
+    """Supervise probe + measured child runs; ALWAYS emit one JSON line,
+    and exit non-zero unless the ladder and every extra ran."""
     diag = []
 
-    # 1) probe backend init in a throwaway subprocess (it can hang inside
-    #    PJRT client creation — round 3 lost its number exactly there)
+    # 1) probe backend init in a throwaway subprocess (the parent must stay
+    #    off jax: a chip belongs to one process at a time)
     platform = None
-    probe_plans = [300, 300, 360]  # three tries, ambient env (TPU plugin)
+    probe_plans = [300, 300, 360]  # three tries, ambient env
     for i, tmo in enumerate(probe_plans):
         env = dict(os.environ)
         rc, out, err = _spawn(["--probe"], env, tmo)
@@ -2786,11 +2779,11 @@ def _parent_main():
             # only (a complete child may be timeout-killed in teardown)
             if result is not None and (result.pop("complete", False)
                                        or rc == 0):
-                result = _run_extras(result, probe_env, platform)
+                result, failed = _run_extras(result, probe_env, platform)
                 if diag:
                     result["bench_diag"] = "; ".join(diag)[:1000]
                 print(json.dumps(result))
-                return 0
+                return 1 if failed else 0
             if result is not None:
                 # salvaged from a killed child — keep it, but let the
                 # remaining attempt try for a complete run first
@@ -2800,37 +2793,18 @@ def _parent_main():
             diag.append(f"child[{i}] rc={rc}: {err[-400:]}")
             time.sleep(15)
         if partial is not None:
-            partial = _run_extras(partial, probe_env, platform)
-            if diag:
-                partial["bench_diag"] = "; ".join(diag)[:1000]
+            partial, _ = _run_extras(partial, probe_env, platform)
+            partial["bench_diag"] = "; ".join(diag)[:1000]
             print(json.dumps(partial))
-            return 0
+            return 1    # the ladder child never ran to its end
 
-    # 3) TPU unusable: CPU smoke fallback so the round still has a number
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["BENCH_FORCE_CPU"] = "1"
-    for i in range(2):
-        rc, out, err = _spawn(["--child"], env, 900)
-        result = _extract_json(out)
-        if result is not None:
-            if not result.pop("complete", False) and rc != 0:
-                result["bench_partial"] = (   # salvaged from a killed child
-                    f"child rc={rc}; last complete measurement kept")
-            result = _run_extras(result, env, "cpu")
-            result["bench_diag"] = ("tpu-unavailable, cpu fallback; " +
-                                    "; ".join(diag))[:1000]
-            print(json.dumps(result))
-            return 0
-        diag.append(f"cpu-child[{i}] rc={rc}: {err[-400:]}")
-
-    # 4) total failure: still one parseable line
+    # 3) no backend, or no rung ran: one parseable line and a failure
     print(json.dumps({
         "metric": "llama_train_tokens_per_sec_per_chip",
         "value": 0.0, "unit": "tokens/s", "vs_baseline": 0.0,
         "error": "; ".join(diag)[:2000],
     }))
-    return 0
+    return 1
 
 
 def _gate_main():

@@ -8,41 +8,6 @@ docstring cites the reference component (file:line) it re-implements.
 import os as _os
 
 import jax as _jax
-import jax.export as _jax_export  # noqa: F401  (on the pinned jax the
-#   lazy `jax.export` attribute 404s until the submodule is imported once;
-#   jit.save/load and the Mosaic cross-lowering tests rely on it)
-
-# `jax.shard_map` graduated from jax.experimental after the pinned
-# version; the sharded kernels (pipeline_spmd, ring_attention, the
-# grouped MoE) all target the graduated spelling, so install it when
-# missing.  check_rep=False matches the graduated default closely enough
-# here: these callers all psum/ppermute explicitly and several wrap
-# custom_vjp functions the replication checker cannot see into.
-if not hasattr(_jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map_compat(f, mesh, in_specs, out_specs, **kw):
-        if "check_vma" in kw:   # the graduated rename of check_rep
-            kw.setdefault("check_rep", kw.pop("check_vma"))
-        kw.setdefault("check_rep", False)
-        names = kw.pop("axis_names", None)
-        if names is not None:   # graduated API: manual axes by name; the
-            #                     experimental one takes the AUTO complement
-            kw.setdefault("auto",
-                          frozenset(mesh.axis_names) - frozenset(names))
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    _jax.shard_map = _shard_map_compat
-
-# The varying-manual-axes cast ops (`jax.lax.pcast` / `jax.lax.pvary`)
-# belong to the newer replication checker; under this jax's shard_map
-# with check_rep=False they are semantically identity casts, so the
-# pipeline/ring kernels that annotate with them keep working.
-if not hasattr(_jax.lax, "pcast"):
-    _jax.lax.pcast = lambda x, axes=None, *, to=None: x
-if not hasattr(_jax.lax, "pvary"):
-    _jax.lax.pvary = lambda x, axes=None: x
 
 # Paddle's dtype surface includes real int64/float64 tensors
 # (phi DataType::INT64/FLOAT64); without x64 JAX silently narrows to 32-bit.
@@ -56,23 +21,18 @@ from . import dtypes, errors, flags
 
 # Persistent XLA compilation cache — the CompilationCache slot of the
 # reference's CINN stack (paddle/cinn/hlir/framework/pir/compilation_cache.h):
-# compiled executables are reused across processes, so a framework restart or
-# a bench subprocess pays ~0s instead of the 20-40s TPU compile.
-# FLAGS_jit_cache_dir="" disables (env-only: consumed once at import); an
-# explicit JAX_COMPILATION_CACHE_DIR wins, like JAX_ENABLE_X64 above.
-flags.define_flag(
-    "jit_cache_dir",
-    _os.path.join(_os.environ.get("XDG_CACHE_HOME")
-                  or _os.path.expanduser("~/.cache"),
-                  "paddle_tpu", "xla_cache"),
-    "persistent XLA compilation cache directory ('' disables; env-only)")
-if flags.flag("jit_cache_dir") and \
-        "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
-    try:
-        _jax.config.update("jax_compilation_cache_dir",
-                           flags.flag("jit_cache_dir"))
-    except Exception:  # older jaxlib without the knob: cache is best-effort
-        pass
+# compiled executables are reused across processes.  Placed from outside:
+# where JAX_COMPILATION_CACHE_DIR is set jax reads it itself and nothing is
+# set here; otherwise the cache goes to ONE fixed git-ignored directory
+# inside the checkout (the path is part of the cache key, so it must not
+# depend on home, a temp name, a pid or the time).  The autotune JSON
+# lives beside it (kernels/autotune.py).
+CACHE_DIR = _os.path.join(
+    _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+    ".paddle_tpu_cache")
+if "JAX_COMPILATION_CACHE_DIR" not in _os.environ:
+    _jax.config.update("jax_compilation_cache_dir",
+                       _os.path.join(CACHE_DIR, "xla"))
 
 from .dtypes import (  # noqa: F401
     bfloat16, bool_, complex64, complex128, dtype, float8_e4m3fn,

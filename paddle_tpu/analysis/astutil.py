@@ -80,7 +80,8 @@ def partial_aliases(tree: ast.AST) -> Dict[str, Set[str]]:
 def kernel_functions(tree: ast.AST) -> Set[ast.FunctionDef]:
     """Function defs that are Pallas kernel bodies: passed (directly, via
     a ``functools.partial`` alias, or as an inline partial) as the first
-    argument of a ``pallas_call``."""
+    argument of a ``pallas_call``, plus the module-level helpers those
+    bodies call."""
     defs = function_defs(tree)
     aliases = partial_aliases(tree)
     kernels: Set[ast.FunctionDef] = set()
@@ -103,6 +104,21 @@ def kernel_functions(tree: ast.AST) -> Set[ast.FunctionDef]:
                 dotted(arg.func) in PARTIAL_NAMES and arg.args and \
                 isinstance(arg.args[0], ast.Name):
             resolve(arg.args[0].id)
+
+    # module-level helpers a kernel body calls by bare name are kernel
+    # code too (grouped_matmul's _gather_rows carried python-int
+    # fori_loop bounds past JL001 that way)
+    top = {n.name: n for n in getattr(tree, "body", ())
+           if isinstance(n, ast.FunctionDef)}
+    work = list(kernels)
+    while work:
+        for node in ast.walk(work.pop()):
+            if isinstance(node, ast.Call) and \
+                    isinstance(node.func, ast.Name):
+                helper = top.get(node.func.id)
+                if helper is not None and helper not in kernels:
+                    kernels.add(helper)
+                    work.append(helper)
     return kernels
 
 

@@ -18,7 +18,8 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-__all__ = ["memory_analysis", "donation_audit", "live_arrays_report"]
+__all__ = ["memory_analysis", "compiled_memory_report", "donation_audit",
+           "live_arrays_report"]
 
 
 def _nbytes(x) -> int:
@@ -37,6 +38,12 @@ def memory_analysis(fn: Callable, *example_args,
     compiled = jax.jit(fn, donate_argnums=tuple(donate_argnums),
                        static_argnums=tuple(static_argnums)
                        ).lower(*args).compile()
+    return compiled_memory_report(compiled)
+
+
+def compiled_memory_report(compiled) -> Dict[str, Any]:
+    """XLA's memory accounting of an already-compiled executable (the
+    dict :func:`memory_analysis` returns)."""
     ms = compiled.memory_analysis()
     out = {"argument_bytes": getattr(ms, "argument_size_in_bytes", None),
            "output_bytes": getattr(ms, "output_size_in_bytes", None),

@@ -154,7 +154,7 @@ def tune_pretrain(model_config, n_devices: int, *, global_batch: int,
     import jax
     import numpy as np
 
-    from ...device.memory_debug import memory_analysis
+    from ...device.memory_debug import compiled_memory_report
     from ...models.pretrain import ParallelConfig, PretrainStep
 
     c = model_config
@@ -179,9 +179,12 @@ def tune_pretrain(model_config, n_devices: int, *, global_batch: int,
         return ps, state, ids, labels
 
     def memory_fn(cfg):
+        # the step's OWN compiled program: train_step reads its operands'
+        # shardings to pin the jit, which a tracer of an outer jit around
+        # it cannot answer
         ps, state, ids, labels = build(cfg)
-        rep = memory_analysis(
-            lambda s, i, l: ps.train_step(s, i, l), state, ids, labels)
+        rep = compiled_memory_report(
+            ps.lowered_step(state, ids, labels).compile())
         return rep["peak_estimate_bytes"] // max(n_devices, 1)
 
     def trial_fn(cfg):
@@ -257,7 +260,8 @@ class AutoTuner:
                 try:
                     rec.memory_bytes = int(memory_fn(rec.config))
                 except Exception as e:
-                    rec.pruned = f"memory probe failed: {type(e).__name__}"
+                    rec.pruned = (f"memory probe failed: "
+                                  f"{type(e).__name__}: {e}")
                     continue
                 if rec.memory_bytes > self.hbm_bytes:
                     rec.pruned = (
@@ -267,6 +271,6 @@ class AutoTuner:
             if trial_fn is not None:
                 try:
                     rec.measured = trial_fn(rec.config)
-                except Exception:
-                    rec.measured = float("inf")
+                except Exception as e:
+                    rec.pruned = f"trial failed: {type(e).__name__}: {e}"
         return self.recorder.best()

@@ -119,7 +119,7 @@ def _gpipe(mesh, axis, S, stage_fn, stage_params, microbatches, *consts):
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 P()) + tuple(P() for _ in consts)
     return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), axis_names={axis},
+                         out_specs=P(), axis_names={axis}, check_vma=True,
                          )(stage_params, microbatches, *consts)
 
 
@@ -195,7 +195,7 @@ def _circular(mesh, axis, S, v, stage_fn, stage_params, microbatches, *consts):
     in_specs = (jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 P()) + tuple(P() for _ in consts)
     return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=P(), axis_names={axis},
+                         out_specs=P(), axis_names={axis}, check_vma=True,
                          )(stage_params, microbatches, *consts)
 
 
@@ -369,7 +369,7 @@ def pipeline_1f1b_grads(mesh, axis: str, stage_fn: Callable,
     out_specs = (P(), jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                  jax.tree_util.tree_map(lambda _: P(), loss_params), P())
     return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names={axis},
+                         out_specs=out_specs, axis_names={axis}, check_vma=True,
                          )(stage_params, microbatches, labels, loss_params,
                            *consts)
 
@@ -581,7 +581,7 @@ def pipeline_zbh1_grads(mesh, axis: str, stage_fn: Callable,
     out_specs = (P(), jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                  jax.tree_util.tree_map(lambda _: P(), loss_params), P())
     return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names={axis},
+                         out_specs=out_specs, axis_names={axis}, check_vma=True,
                          )(stage_params, microbatches, labels, loss_params,
                            *consts)
 
@@ -806,7 +806,7 @@ def pipeline_zbvpp_grads(mesh, axis: str, stage_fn: Callable,
     out_specs = (P(), jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                  jax.tree_util.tree_map(lambda _: P(), loss_params), P())
     return jax.shard_map(per_stage, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names={axis},
+                         out_specs=out_specs, axis_names={axis}, check_vma=True,
                          )(stage_params, microbatches, labels, loss_params,
                            *consts)
 

@@ -74,7 +74,8 @@ define_flag("autotune_enable", True,
             "(kernels/autotune.py; the phi autotune cache analog).")
 define_flag("autotune_cache_path", "",
             "Override the on-disk autotune cache location "
-            "(default ~/.cache/paddle_tpu/autotune.json).")
+            "(default: autotune.json beside the compile cache, in "
+            "paddle_tpu.CACHE_DIR).")
 define_flag("to_static_cache_size", 64,
             "Max guard-cache entries per to_static function (LRU eviction;"
             " <=0 = unbounded). Reference: the SOT guard-tree cache cap.")

@@ -47,7 +47,8 @@ import numpy as np
 
 from .. import flags
 from .. import observability as _obs
-from ..kernels.paged_attention import (paged_attention,
+from ..kernels.paged_attention import (kernel_geometry_error,
+                                       paged_attention,
                                        ragged_paged_attention,
                                        write_kv_pages,
                                        write_kv_pages_all_layers,
@@ -312,12 +313,32 @@ class LlamaGenerator:
         self.pages_per_seq = -(-self.max_seq_len // page_size)
 
         self.params = self._extract(model)
+        if self.mesh is not None:
+            # the step's shard_map takes the weights replicated: place them
+            # on every device of the mesh ONCE, or each dispatch re-copies
+            # the first device's (uncommitted) arrays to the other shards
+            self.params = jax.device_put(
+                self.params, jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec()))
         # the KV pool: ``num_pages`` may be smaller than the dense
         # max_batch x pages_per_seq worst case — sequences share the pool
         # through the free-list allocator; admission blocks on pressure
         # and a sequence whose mid-decode growth finds the pool dry is
         # finalized early (engine._drain caps its output) — never a crash
         self.num_pages = num_pages or max_batch * self.pages_per_seq
+        if jax.default_backend() == "tpu":
+            # the step's attention is the Pallas kernel or nothing on a
+            # chip: refuse a geometry it does not cover now, not mid-trace
+            why = kernel_geometry_error(
+                page_size, c.head_dim,
+                quantized=str(cache_dtype or c.dtype) == "int8",
+                kv_heads=c.num_key_value_heads // tp,
+                num_pages=self.num_pages,
+                table_shape=(max_batch, self.pages_per_seq))
+            if why:
+                raise ValueError(
+                    f"engine geometry is not served by the paged-attention "
+                    f"kernel on TPU: {why}")
         self.cache = PagedKVCache(
             num_layers=c.num_hidden_layers,
             num_pages=self.num_pages,
@@ -360,7 +381,12 @@ class LlamaGenerator:
         every other operand — weights, tokens, masks, the PRNG key — is
         replicated.  Still ONE jitted program per bucket; pool donation
         passes through jit(shard_map) unchanged, so warm tp steps keep
-        the 0-compile / 0-sync contract."""
+        the 0-compile / 0-sync contract.
+
+        ``check_vma=False`` on purpose: the body holds ``pallas_call``s
+        (whose outputs carry no varying-axes type) and rebuilds every
+        replicated output itself with ``all_gather``, which the checker
+        cannot follow; tp=1 vs tp=N bit-match tests guard it instead."""
         if self.tp == 1:
             return jax.jit(fn, donate_argnums=(1,))
         from jax.sharding import PartitionSpec
@@ -371,7 +397,7 @@ class LlamaGenerator:
                           for i in range(n_out))
         return jax.jit(
             jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs),
+                          out_specs=out_specs, check_vma=False),
             donate_argnums=(1,))
 
     def pool_jit(self, fn, n_extra):
@@ -387,7 +413,7 @@ class LlamaGenerator:
         return jax.jit(
             jax.shard_map(fn, mesh=self.mesh,
                           in_specs=(cspec,) + (rep,) * n_extra,
-                          out_specs=cspec),
+                          out_specs=cspec, check_vma=False),
             donate_argnums=(0,))
 
     def _step_jit(self, gc: GenerationConfig, t: int, track_recent=False):
@@ -1156,6 +1182,31 @@ class ContinuousBatchingEngine:
     def has_work(self) -> bool:
         return bool(self.waiting) or any(r is not None for r in self.slot_req)
 
+    def step_operands(self, T: int) -> tuple:
+        """The fused step program's operands for q bucket ``T`` as
+        ``jax.ShapeDtypeStruct``s (shapes, dtypes and shardings of what
+        ``step()`` passes), so the program can be lowered or compiled
+        without running it."""
+        sds = jax.ShapeDtypeStruct
+        B = self.B
+
+        def like(a):
+            return sds(a.shape, a.dtype, sharding=a.sharding)
+
+        ivec, bvec = sds((B,), jnp.int32), sds((B,), jnp.bool_)
+        return (jax.tree_util.tree_map(like, self.g.params),
+                jax.tree_util.tree_map(like, tuple(self.g.cache.arrays)),
+                sds((B, T), jnp.int32), ivec, ivec, bvec, bvec, bvec,
+                ivec, ivec, sds(self._bt.shape, jnp.int32), like(self.key))
+
+    def lowered_step(self, T: int):
+        """``jax.stages.Lowered`` of the fused step program ``step()``
+        dispatches for q bucket ``T`` (``as_text()`` shows whether the
+        paged kernel is in it; ``compile().memory_analysis()`` what it
+        takes)."""
+        return self.g._step_jit(self.gen_cfg, T, False).lower(
+            *self.step_operands(T))
+
     def run(self) -> dict:
         """Drive to completion; returns {req_id: generated tokens} for every
         request completed so far (incl. during earlier manual step() calls)."""
@@ -1580,6 +1631,9 @@ class ContinuousBatchingEngine:
         window = [(kind, np.asarray(out), np.asarray(cm),
                    None if dl is None else np.asarray(dl), t)
                   for kind, out, cm, dl, t in self._pending]
+        # the moment this window's tokens became visible to the host —
+        # the only progress of the device the host can observe
+        t_ready = time.perf_counter()
         self._pending.clear()
         self._steps_since_drain = 0
         self._fold_spec_metrics(window)
@@ -1614,10 +1668,15 @@ class ContinuousBatchingEngine:
                         tok_ts.append(t)
             req.output.extend(new_tok)
             if obs is not None:
-                # TTFT/ITL from the committing steps' dispatch stamps;
-                # commits the trims below drop — past the budget, past
-                # cache capacity, or frozen repeats after a device-side
-                # EOS — are not real tokens and must not be timed
+                # TTFT/ITL are stamped HERE, when the tokens reach the
+                # host: the host runs sync_every dispatches ahead and
+                # then blocks, so a dispatch stamp is when a step was
+                # queued, not when its token existed.  A drain that
+                # hands a request n tokens observes n gaps of (span
+                # since its previous delivery) / n.  Commits the trims
+                # below drop — past the budget, past cache capacity, or
+                # frozen repeats after a device-side EOS — are not real
+                # tokens and must not be timed
                 room = max(0, req.max_new_tokens - prev_len)
                 cap_v = max(1, self.g.max_seq_len - len(req.prompt))
                 if self._gen_cap[b] is not None:
@@ -1625,15 +1684,23 @@ class ContinuousBatchingEngine:
                 room = min(room, max(0, cap_v - prev_len))
                 if eos is not None and eos in new_tok:
                     room = min(room, new_tok.index(eos) + 1)
-                for tj in tok_ts[:room]:
+                n_timed = len(tok_ts[:room])
+                if n_timed:
+                    # a request's first burst has no previous delivery:
+                    # its span opens where its first token's step was
+                    # dispatched
+                    since = req.t_last if req.t_last is not None \
+                        else tok_ts[0]
+                    gap_ms = (t_ready - since) / n_timed * 1e3
                     if req.t_first is None:
-                        req.t_first = tj
+                        req.t_first = t_ready
                         base = req.t_enqueue if req.t_enqueue is not None \
-                            else tj
-                        obs.ttft.observe((tj - base) * 1e3)
-                    else:
-                        obs.itl.observe((tj - req.t_last) * 1e3)
-                    req.t_last = tj
+                            else tok_ts[0]
+                        obs.ttft.observe((t_ready - base) * 1e3)
+                        n_timed -= 1
+                    for _ in range(n_timed):
+                        obs.itl.observe(gap_ms)
+                    req.t_last = t_ready
             # device freeze repeats the last token once finished — trim to
             # the true capacity/EOS/budget boundary host-side.  cap =
             # what physically fits in the cache (max_seq minus the

@@ -85,7 +85,8 @@ def make_upload_program(cache):
     cspec = cache.pspecs
     return jax.jit(
         jax.shard_map(_sharded, mesh=cache.mesh,
-                      in_specs=(cspec, rep, rep), out_specs=cspec),
+                      in_specs=(cspec, rep, rep), out_specs=cspec,
+                      check_vma=False),   # as the engine step: see _tp_jit
         donate_argnums=(0,))
 
 

@@ -36,11 +36,13 @@ _MEASURED = {}           # key -> {config_str: ms} measurement log (debug)
 
 
 def _cache_path() -> str:
+    """FLAGS_autotune_cache_path, else beside the compile cache: one fixed
+    git-ignored directory inside the checkout (``paddle_tpu.CACHE_DIR``)."""
     p = flags.flag("autotune_cache_path")
     if p:
         return os.path.expanduser(p)
-    base = os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache"))
-    return os.path.join(base, "paddle_tpu", "autotune.json")
+    from .. import CACHE_DIR
+    return os.path.join(CACHE_DIR, "autotune.json")
 
 
 def _load():
@@ -123,6 +125,24 @@ def lookup(key: str):
         return tuple(v) if isinstance(v, list) else v
 
 
+def entries(kernel: str) -> dict:
+    """Every cached ``key -> config`` of one kernel (what a run's tiles
+    were: chip_smoke.py prints it)."""
+    with _LOCK:
+        _load()
+        return {k: v for k, v in _MEM.items()
+                if k.split("|", 1)[0] == kernel}
+
+
+def measured(kernel: str) -> dict:
+    """``key -> {config: median ms}`` of the tuning passes THIS process
+    ran for one kernel (empty when every key was a cache hit): how far
+    apart the candidates were, i.e. whether the winner is noise."""
+    with _LOCK:
+        return {k: dict(v) for k, v in _MEASURED.items()
+                if k.split("|", 1)[0] == kernel}
+
+
 def record(key: str, config, measurements: Optional[dict] = None):
     """Explicitly store a measured winner (used by external sweeps, e.g.
     the bench's decode page-size search)."""
@@ -142,28 +162,37 @@ def lookup_or_tune(key: str, candidates: Sequence,
     """Cached config for ``key``, measuring candidates on a miss.
 
     ``bench(config)`` returns a nullary timed closure (must block until the
-    device finishes), or None if the config is infeasible; measurement
-    errors disqualify a candidate rather than failing the caller.  Returns
-    ``default`` untouched when tuning is disabled and the cache is cold.
+    device finishes), or None if the config is infeasible; a measurement
+    error disqualifies that candidate, and a pass in which EVERY measured
+    candidate failed raises with each one's error.  Returns ``default``
+    untouched when tuning is disabled and the cache is cold.
     """
     got = lookup(key)
     if got is not None:
         return got
     if not enabled() or not candidates:
         return default
-    best, best_ms, log = None, float("inf"), {}
+    best, best_ms, log, errors = None, float("inf"), {}, {}
     for cand in candidates:
         try:
             fn = bench(cand)
             if fn is None:
                 continue
             ms = measure(fn)
-        except Exception:
-            continue  # compile/runtime failure: disqualify
+        except Exception as e:   # compile/runtime failure: disqualify
+            errors[str(cand)] = f"{type(e).__name__}: {e}"
+            continue
         log[str(cand)] = round(ms, 4)
         if ms < best_ms:
             best, best_ms = cand, ms
     if best is None:
+        if errors:
+            # one bad tile is a disqualification; ALL of them failing is a
+            # broken kernel or device, and the default would fail the same
+            # way a moment later with less to go on
+            raise RuntimeError(
+                f"autotune {key}: every candidate failed to compile or "
+                f"run: {errors}")
         return default
     with _LOCK:
         _MEM[key] = list(best) if isinstance(best, (tuple, list)) else best

@@ -31,6 +31,7 @@ interpreter mode on CPU (used by tests to validate the exact kernel code).
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -623,17 +624,50 @@ _NO_MASK = None
 
 
 def _fa_supported(q, k, causal, mask, seg_q):
+    """(mode, blocks) of the Pallas path, or (None, None) for the XLA
+    reference — which on a TPU is logged and counted, never silent."""
     mode = _pallas_mode()
-    blocks = _blocks_for(q.shape[1], k.shape[1], q.shape[-1])
-    if q.dtype == jnp.float64 or mode is None or blocks is None:
+    if mode is None:
         return None, None
-    if mode == "tpu":
-        blocks = _tuned_blocks(q, k, causal, mask, seg_q, blocks)
-    if mask is not None:
-        bq, bkv = blocks
-        if mask.shape[-2] % bq or mask.shape[-1] % bkv:
-            return None, None
+    blocks = _blocks_for(q.shape[1], k.shape[1], q.shape[-1])
+    why = None
+    if q.dtype == jnp.float64:
+        why = "float64 operands"
+    elif blocks is None:
+        why = ("seq lengths must divide into FLAGS_flash_attention_block_q/"
+               "_kv blocks and head_dim be 64, 96 or a multiple of 128")
+    else:
+        if mode == "tpu":
+            blocks = _tuned_blocks(q, k, causal, mask, seg_q, blocks)
+        if mask is not None and (mask.shape[-2] % blocks[0]
+                                 or mask.shape[-1] % blocks[1]):
+            why = f"mask shape {mask.shape} does not tile blocks {blocks}"
+    if why is not None:
+        _note_reference_on_tpu(why, (q.shape, k.shape, str(q.dtype)))
+        return None, None
     return mode, blocks
+
+
+_LOG = logging.getLogger("paddle_tpu.kernels")
+_REFERENCE_SEEN: set = set()
+
+
+def _note_reference_on_tpu(why, shapes):
+    """Flash attention is reached from the general ``nn`` API with shapes
+    the kernels do not cover, so the XLA reference stays legal on a chip —
+    but never silent: every such trace bumps
+    ``kernels.reference_fallbacks{kernel=flash_attention}`` and the first
+    one per (rule, shapes) logs the rule that failed.  Off-TPU the
+    reference is the normal path (tests) and nothing is recorded."""
+    if jax.default_backend() != "tpu":
+        return
+    from .. import observability as _obs
+    _obs.counter("kernels.reference_fallbacks",
+                 kernel="flash_attention").inc()
+    if (why, shapes) not in _REFERENCE_SEEN:
+        _REFERENCE_SEEN.add((why, shapes))
+        _LOG.warning("flash_attention: XLA reference instead of the Pallas "
+                     "kernels on TPU for %s — %s", shapes, why)
 
 
 def _tuned_blocks(q, k, causal, mask, seg_q, default):
@@ -662,6 +696,8 @@ def _tuned_blocks(q, k, causal, mask, seg_q, default):
     def bench(blocks):
         import numpy as np_
 
+        if jax.default_backend() != "tpu":
+            return None   # cross-lowering on CPU: nothing to measure on
         rng = np_.random.default_rng(0)
         shape_q = (min(b, 1), sq, hq, d)
         qq = jnp.asarray(rng.standard_normal(shape_q), q.dtype)
@@ -728,9 +764,40 @@ def _flash_attention_arrays(q, k, v, causal, mask=None, seg_q=None,
                     seed if drop_p else jnp.zeros((1, 1), jnp.float32))
 
 
+def _split_over_mesh(mesh, causal, q_shape, kv_heads):
+    """The unmasked call as a prim split by hand over a multi-device mesh.
+
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    the call is split over what attention is independent in: batch over
+    'dp', heads over 'sep'/'mp' (kv groups stay whole per shard); any
+    other axis sees the call replicated."""
+    from jax.sharding import PartitionSpec
+
+    size = dict(zip(mesh.axis_names, mesh.devices.shape))
+    batch = "dp" if size.get("dp", 1) > 1 else None
+    heads = tuple(a for a in ("sep", "mp") if size.get(a, 1) > 1)
+    n = int(np.prod([size[a] for a in heads]))
+    if q_shape[0] % size.get("dp", 1) or kv_heads % n:
+        raise ValueError(
+            f"flash attention under mesh {size}: batch ({q_shape[0]}) must "
+            f"divide over dp and kv heads ({kv_heads}) over {heads}")
+    spec = PartitionSpec(batch, None, heads or None, None)
+    # check_vma=False: a pallas_call's outputs carry no varying-axes type
+    return jax.shard_map(
+        lambda q, k, v: _flash_attention_arrays(q, k, v, causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
+
+
 def flash_attention(query, key, value, causal=False, attn_mask=None,
-                    dropout=0.0, training=True, rng_name=None):
+                    dropout=0.0, training=True, rng_name=None, mesh=None):
     """Tensor-level flash attention, layout [b, s, h, d].
+
+    ``mesh``: the multi-device mesh the call is traced under, if any.
+    Where the Pallas kernels run they are then split over it by hand
+    (``_split_over_mesh``); the XLA reference needs nothing, GSPMD
+    partitions it.
 
     GQA-native: key/value may have fewer heads (a divisor of the query
     heads).  ``attn_mask``: additive fp32 mask [b, 1|h, sq, sk] (reference
@@ -745,7 +812,14 @@ def flash_attention(query, key, value, causal=False, attn_mask=None,
     args = tuple(a if isinstance(a, Tensor) else Tensor(a) for a in args)
     drop_p = float(dropout) if training else 0.0
 
-    if drop_p:
+    if mesh is not None and mesh.size > 1 and _pallas_mode() is not None:
+        if drop_p or attn_mask is not None:
+            raise NotImplementedError(
+                "flash_attention(mesh=...) splits only the unmasked, "
+                "dropout-free call over a mesh")
+        prim = _split_over_mesh(mesh, causal, args[0].shape,
+                                args[1].shape[2])
+    elif drop_p:
         from ..core.random import next_key
 
         # one seed per call from the paddle RNG stream (< 2^24: rides as

@@ -59,19 +59,29 @@ flags.define_flag("grouped_matmul_bn", 0,
 flags.define_flag("grouped_matmul_bk", 0,
                   "Default grouped-matmul contraction tile (0 = default); "
                   "explicit bk arguments always take precedence.")
-flags.define_flag("grouped_matmul_fused_gather", True,
+flags.define_flag("grouped_matmul_fused_gather", False,
                   "Fuse the MoE dispatch row-gather (and optional per-row "
                   "combine scale) into the grouped-matmul kernels via "
-                  "scalar-prefetched row indices + per-row DMA. Off: "
-                  "materialize the permuted operand and run the plain "
-                  "block kernels.")
+                  "scalar-prefetched row indices + per-row DMA. Off (the "
+                  "default): materialize the permuted operand and run the "
+                  "plain block kernels. On a TPU the fused arm does not "
+                  "compile today — Mosaic refuses the one-row slice of a "
+                  "tiled HBM operand (\"Slice shape along dimension 0 must "
+                  "be aligned to tiling (8), but is 1\") — so choosing it "
+                  "there fails when the step is built; interpret mode "
+                  "runs it.")
 
 
 def _mode(interpret=None):
+    """'tpu' (compiled), 'interpret' (CPU tests) or None (XLA reference,
+    off-TPU only: on a chip these kernels compile or raise)."""
+    if jax.default_backend() == "tpu":
+        if interpret:
+            raise ValueError("grouped matmul: interpret mode is for CPU "
+                             "tests; the backend is a TPU")
+        return "tpu"
     if interpret is not None:
         return "interpret" if interpret else "tpu"
-    if jax.default_backend() == "tpu":
-        return "tpu"
     if flags.flag("grouped_matmul_interpret"):
         return "interpret"
     return None
@@ -138,6 +148,8 @@ def _tune(kind, key, M, K, N, E, bm, dtype):
     the autotune module's re-entrant dispatch contract)."""
     from . import autotune
 
+    if jax.default_backend() != "tpu":
+        return None   # cross-lowering on CPU: nothing to measure on
     cands = autotune.grouped_matmul_candidates(
         M, K, N, itemsize=jnp.dtype(dtype).itemsize, bm=bm,
         kind="tgmm" if kind == "tgmm" else "gmm")
@@ -188,16 +200,18 @@ def _gather_rows(src_ref, rows_ref, base, col0, ncols, dst_ref, sem, bm):
             src_ref.at[rows_ref[base + r], pl.ds(col0, ncols)],
             dst_ref.at[r], sem)
 
-    def start(r, c):
-        copy(r).start()
-        return c
+    # a while_loop with an np.int32 carry, not fori_loop: with static
+    # bounds fori_loop becomes a scan whose counter is a python int — i64
+    # under x64 — and the i64 index reaching ``rows_ref[base + r]`` sends
+    # Mosaic's convert_element_type lowering into endless recursion
+    def each_row(fn):
+        def body(r):
+            fn(r)
+            return r + np.int32(1)
+        jax.lax.while_loop(lambda r: r < np.int32(bm), body, np.int32(0))
 
-    def wait(r, c):
-        copy(r).wait()
-        return c
-
-    jax.lax.fori_loop(0, bm, start, 0)
-    jax.lax.fori_loop(0, bm, wait, 0)
+    each_row(lambda r: copy(r).start())
+    each_row(lambda r: copy(r).wait())
 
 
 # ------------------------------------------------------------------ gmm ---
@@ -288,7 +302,7 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
     operands = []
     if fused:
         scalars.append(rows.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     else:
         in_specs.append(
             pl.BlockSpec((bm, bk), lambda i, j, k, g, *_: (i, k)))
@@ -323,7 +337,7 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, O), lhs.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
     )(*scalars, *operands)
@@ -441,11 +455,11 @@ def tgmm(lhs, rhs, tile_groups, num_groups, *, bm=512, bn=None, bk=None,
     if rfused:
         scalars.append(rhs_rows.astype(jnp.int32))
     in_specs.append(
-        pl.BlockSpec(memory_space=pltpu.ANY) if lfused else
+        pl.BlockSpec(memory_space=pl.ANY) if lfused else
         pl.BlockSpec((bm, bk), lambda k, j, i, g, *_: (i, k)))
     operands.append(lhs)
     in_specs.append(
-        pl.BlockSpec(memory_space=pltpu.ANY) if rfused else
+        pl.BlockSpec(memory_space=pl.ANY) if rfused else
         pl.BlockSpec((bm, bn), lambda k, j, i, g, *_: (i, j)))
     operands.append(rhs)
     if rscaled:
@@ -477,7 +491,7 @@ def tgmm(lhs, rhs, tile_groups, num_groups, *, bm=512, bn=None, bk=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_groups, K, N), lhs.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
     )(*scalars, *operands)

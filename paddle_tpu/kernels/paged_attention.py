@@ -17,7 +17,7 @@ TPU-first design (the "Ragged Paged Attention" shape of arxiv 2604.15464):
   flash-attention call or an analytic current-token merge — chunked
   prefill rides the decode schedule in one ``pallas_call``.
 - The KV cache is laid out **head-major**, ``[kv_heads, num_pages,
-  page_size, head_dim]``, and stays in **HBM** (``pltpu.ANY``): the kernel
+  page_size, head_dim]``, and stays in **HBM** (``pl.ANY``): the kernel
   itself DMAs exactly the pages a sequence owns into a two-slot VMEM ring,
   **double-buffered** — page ``p+1``'s copy is started while page ``p`` is
   being computed (the same overlap pattern as the grouped_matmul fused
@@ -38,9 +38,10 @@ TPU-first design (the "Ragged Paged Attention" shape of arxiv 2604.15464):
 - Online softmax (m, l, acc) carries across the page-chunk axis in VMEM
   scratch, which persists along the innermost grid dimension.
 
-Falls back to an XLA gather+masked-softmax reference off-TPU (tests use it
+Off-TPU an XLA gather+masked-softmax reference runs instead (tests use it
 as the numerics oracle; ``FLAGS_paged_attention_interpret=1`` runs the real
-kernel in interpreter mode).
+kernel in interpreter mode).  On a TPU the kernel always runs compiled, and
+a geometry it does not cover raises (``kernel_geometry_error``).
 """
 
 from __future__ import annotations
@@ -170,21 +171,21 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
     normalizes.
 
     ``quantized`` (int8 pool): the DMA moves the page's int8 bytes (4x
-    fewer than fp32) and the per-(kv-head, page) fp32 scale rides in as a
-    VMEM-resident row — dequant happens on the VMEM slot right after
-    ``wait()``, so the online-softmax math stays fp32 and nothing above
-    the kernel changes shape.
+    fewer than fp32) and the per-(kv-head, page) fp32 scales ride the
+    scalar-prefetch channel beside the block table — dequant happens on
+    the VMEM slot right after ``wait()``, so the online-softmax math
+    stays fp32 and nothing above the kernel changes shape.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     it = iter(refs)
     bt_ref, cl_ref, ql_ref = next(it), next(it), next(it)
+    ksc_ref = next(it) if quantized else None
+    vsc_ref = next(it) if quantized else None
     q_ref = next(it)
     knew_ref = next(it) if has_new else None
     vnew_ref = next(it) if has_new else None
-    ksc_ref = next(it) if quantized else None
-    vsc_ref = next(it) if quantized else None
     k_hbm, v_hbm = next(it), next(it)
     o_ref, lse_ref = next(it), next(it)
     kbuf, vbuf, sem = next(it), next(it), next(it)
@@ -342,24 +343,24 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         operands += [kn, vn]
         in_specs += [spec, spec]
     quantized = k_scale is not None
+    scalars = [bt, cl, ql]
     if quantized:
-        # one fp32 per (kv-head, page), SMEM-resident (kvh * n_pages * 4
-        # bytes): scalar loads at [head, page id] — the same dynamic-
-        # index shape as the scalar-prefetched block table
-        sspec = pl.BlockSpec(memory_space=pltpu.SMEM)
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-        in_specs += [sspec, sspec]
+        # one fp32 per (kv-head, page), scalar-prefetched (kvh * n_pages
+        # * 4 bytes of SMEM): scalar loads at [head, page id], the same
+        # dynamic-index shape as the block table beside it.  (As a plain
+        # SMEM *operand* of a scalar-prefetch grid Mosaic refuses it.)
+        scalars += [k_scale.astype(jnp.float32),
+                    v_scale.astype(jnp.float32)]
     operands += [k_cache, v_cache]
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),
-                 pl.BlockSpec(memory_space=pltpu.ANY)]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
 
     kernel = functools.partial(
         _ragged_paged_attn_kernel, page_size=page_size, ppc=ppc,
         scale=1.0 / math.sqrt(d), t=t, group=group, has_new=has_new,
         quantized=quantized)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(scalars),
         grid=(b, kvh, n_chunks),
         in_specs=in_specs,
         out_specs=[
@@ -382,10 +383,10 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, kvh, R, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kvh, R, 1), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, cl, ql, *operands)
+    )(*scalars, *operands)
     out = out[:, :, :rows].reshape(b, kvh, t, group, d)
     out = out.transpose(0, 2, 1, 3, 4).reshape(b, t, qh, d)
     lse = lse[:, :, :rows, 0].reshape(b, kvh, t, group)
@@ -394,6 +395,63 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
 
 
 # ----------------------------------------------------------- entry points ---
+
+# SMEM the compiler keeps for itself and the kernel's small scalar operands
+# beside the scale planes and the block table: between 4 and 8 KiB, found by
+# bisecting compiles for a described v5e (PR 21) with a one-tile table
+_SMEM_RESERVE_BYTES = 4 << 10
+
+
+def _smem_need_bytes(kv_heads, num_pages, table_entries_padded):
+    """SMEM of an int8 pool's scalar-prefetched operands: two fp32 scale
+    planes whose page axis is padded to the 128-lane tile, and the int32
+    block table."""
+    lanes = -(-num_pages // 128) * 128
+    return (2 * 4 * kv_heads * lanes + 4 * table_entries_padded
+            + _SMEM_RESERVE_BYTES)
+
+
+def kernel_geometry_error(page_size, head_dim, *, quantized=False,
+                          kv_heads=0, num_pages=0, table_shape=(0, 0),
+                          smem_bytes=None):
+    """The rule a paged-KV geometry fails for the Pallas kernel, as a
+    sentence, or None when the kernel covers it.  On a TPU a failing
+    geometry raises (here at trace time, and in the engine when it is
+    built); off-TPU the XLA reference takes it.
+
+    ``smem_bytes`` is the scalar memory of the core the kernel is built
+    for; None asks the attached TPU (``pltpu.get_tpu_info``) and, with no
+    TPU attached, leaves the SMEM rule to the compiler."""
+    # f32 sublane is 8; bf16 packs 16 — page_size must tile the sublane
+    # dim.  int8 packs 32 sublanes per tile, so a quantized pool needs
+    # page_size % 32 == 0 to keep each page a whole-tile DMA.
+    if page_size % 8:
+        return f"page_size ({page_size}) must be a multiple of 8"
+    if head_dim % 128 not in (0, 64):
+        return f"head_dim % 128 must be 0 or 64, got head_dim {head_dim}"
+    if quantized and page_size % 32:
+        return (f"an int8 pool needs page_size % 32 == 0 (int8 packs 32 "
+                f"sublanes per tile), got {page_size}")
+    if quantized and smem_bytes is None and jax.default_backend() == "tpu":
+        from jax.experimental.pallas import tpu as pltpu
+        smem_bytes = pltpu.get_tpu_info().smem_capacity_bytes
+    if quantized and smem_bytes is not None:
+        # both scale planes ride the scalar-prefetch channel, i.e. SMEM,
+        # which they share with the block table.  Bisected on a described
+        # v5e (1 MiB): 32 kv heads compile up to 3968 pages, 8 up to
+        # 16256, 4 up to 32512 — the next 128 pages are refused.
+        rows, width = table_shape
+        need = _smem_need_bytes(kv_heads, num_pages,
+                                rows * (-(-width // 128) * 128))
+        if need > smem_bytes:
+            return (f"an int8 pool's per-(kv-head, page) fp32 scales are "
+                    f"scalar-prefetched into SMEM with the block table: "
+                    f"kv_heads ({kv_heads}) x num_pages ({num_pages}) x 8 "
+                    f"bytes + a {rows}x{width} table need {need} of "
+                    f"{smem_bytes} bytes — use fewer, larger pages or "
+                    "shard kv heads (tensor_parallel)")
+    return None
+
 
 def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                            *, q_lens=None, k_new=None, v_new=None,
@@ -436,14 +494,13 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be given together")
     on_tpu = jax.default_backend() == "tpu"
-    interpret = flags.flag("paged_attention_interpret")
-    # f32 sublane is 8; bf16 packs 16 — page_size must tile the sublane
-    # dim.  int8 packs 32 sublanes per tile, so a quantized pool needs
-    # page_size % 32 == 0 to keep each page a whole-tile DMA.
-    ok = page_size % 8 == 0 and d % 128 in (0, 64)
-    if k_scale is not None:
-        ok = ok and page_size % 32 == 0
-    if (on_tpu or interpret) and ok:
+    why = kernel_geometry_error(
+        page_size, d, quantized=k_scale is not None, kv_heads=kvh,
+        num_pages=k_cache.shape[1], table_shape=block_tables.shape)
+    if on_tpu and why:
+        # the serving hot op has no business on the XLA reference on a chip
+        raise ValueError(f"ragged_paged_attention on TPU: {why}")
+    if (on_tpu or flags.flag("paged_attention_interpret")) and not why:
         out, lse = _pallas_ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_lens,
             k_new, v_new, interpret=not on_tpu, k_scale=k_scale,

@@ -100,7 +100,8 @@ def ring_attention_arrays(q, k, v, mesh, axis: str = "sep", causal: bool = True)
 
     spec = P(None, axis, None, None)
     return jax.shard_map(per_device, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, axis_names={axis})(q, k, v)
+                         out_specs=spec, axis_names={axis},
+                         check_vma=True)(q, k, v)
 
 
 def ring_flash_attention(query, key, value, mesh=None, axis: str = "sep",

@@ -270,7 +270,9 @@ class LlamaAttention(Layer):
             q, k, v = apply_op("sep_all2all_qkv", to_heads, (q, k, v))
         # GQA is native in the kernel: grouped K/V go in un-repeated, so
         # K/V residuals and backward bandwidth stay heads/kv_heads smaller
-        out = flash_attention(q, k, v, causal=True)
+        # (PretrainStep names its multi-device mesh on the template layer)
+        out = flash_attention(q, k, v, causal=True,
+                              mesh=getattr(self, "_attn_mesh", None))
         if _SEP_MESH is not None:
             out = apply_op(
                 "sep_all2all_out",
@@ -604,9 +606,9 @@ def moe_mlp_forward_grouped_sharded(x, gate_w, w_gate, w_up, w_down, *,
         xf = xb.reshape(n, h)
         # the router runs on the PRISTINE values (vma tracked by jax's own
         # primitives, so gw's dp-psum transpose is automatic); the custom-
-        # vjp FFN gets explicitly pvary'd operands instead — shard_map AD
-        # cannot see inside a custom vjp, and the pvary transpose is what
-        # emits the replicated axes' psums on dx / dw
+        # vjp FFN gets operands explicitly pcast to varying instead —
+        # shard_map AD cannot see inside a custom vjp, and the cast's
+        # transpose is what emits the replicated axes' psums on dx / dw
         topv, topi, aux_local, ce = _route_topk(xf, gw, k)
         aux = jax.lax.pmean(aux_local, dp_axis)
 
@@ -634,12 +636,12 @@ def moe_mlp_forward_grouped_sharded(x, gate_w, w_gate, w_up, w_down, *,
         # capacity overflow.)
         pos_t = jnp.where(keep.reshape(n * k), pos, M_loc)
         tg_t = jnp.minimum(tg[:M_loc // bm], E_loc - 1)
-        # (jax.lax.pvary is the package-init no-op shim on the pinned
-        # jax — shard_map there runs check_rep=False)
-        xf_v = jax.lax.pvary(xf, (ep_axis, mp_axis))  # x replicated there
-        wg_v, wu_v, wd_v = (jax.lax.pvary(t, (dp_axis,))
+        def vary(t, axes):
+            return jax.lax.pcast(t, axes, to="varying")
+        xf_v = vary(xf, (ep_axis, mp_axis))         # x replicated there
+        wg_v, wu_v, wd_v = (vary(t, (dp_axis,))
                             for t in (wg, wu, wd))    # weights: over dp
-        gates_v = jax.lax.pvary(gates, (mp_axis,))  # ep-varying already
+        gates_v = vary(gates, (mp_axis,))           # ep-varying already
         y = _grouped_ffn(xf_v, wg_v, wu_v, wd_v, gates_v, inv_t, pos_t,
                          tg_t, E_loc, k, bm)
         y = jax.lax.psum(y, (ep_axis, mp_axis))
@@ -657,6 +659,7 @@ def moe_mlp_forward_grouped_sharded(x, gate_w, w_gate, w_up, w_down, *,
                   P(ep_axis, None, mp_axis), P(ep_axis, None, mp_axis),
                   P(ep_axis, mp_axis, None)),
         out_specs=(P(dp_axis, None, None), P(), P()),
+        check_vma=True,   # the casts above feed the checker and AD
     )(x, gate_w, w_gate, w_up, w_down)
 
 

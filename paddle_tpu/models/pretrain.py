@@ -38,6 +38,7 @@ from ..distributed.pipeline_spmd import (interleave_chunk_order,
                                          pipeline_apply,
                                          pipeline_zbh1_grads,
                                          pipeline_zbvpp_grads)
+from ..kernels import flash_attention as _fa
 from ..utils import extract_params, functional_call, stack_params
 from .llama import LlamaConfig, LlamaDecoderLayer, _rope_cos_sin, _scaled_init
 
@@ -254,6 +255,20 @@ class PretrainStep:
             # multi-device grouped MoE runs the shard_map formulation
             # (replicated-router + ragged local GEMM + one psum)
             self._template.mlp._grouped_mesh = self.mesh
+        if self.mesh.size > 1 and self.pc.pp == 1:
+            # the flash kernel cannot be partitioned by GSPMD: its entry
+            # splits it over the mesh by hand (flash_attention(mesh=))
+            self._template.self_attn._attn_mesh = self.mesh
+        elif self.mesh.size > self.pc.pp and _fa._pallas_mode() == "tpu":
+            # inside the pipeline's partially-manual shard_map that split
+            # is not wired, and the compiler would refuse the kernel under
+            # the axes GSPMD still owns ("Mosaic kernels cannot be
+            # automatically partitioned")
+            raise NotImplementedError(
+                f"PretrainStep on a TPU with pp={self.pc.pp} and other "
+                f"mesh axes > 1 ({dict(self.mesh.shape)}): the flash "
+                "kernel is not split over the mesh inside pipeline "
+                "stages yet — use pp=1, or pp alone")
         self._jit_step = None
         self._zero1_warned: set = set()
         # per-step train telemetry (ISSUE 5): host-timestamp StepTimer —
@@ -784,47 +799,62 @@ class PretrainStep:
         return {"params": params, "m": m, "v": v, "step": step}
 
     # ---- the jitted step ----
+    def _jitted_step(self, state, ids, labels):
+        """The jitted step, built on first use for this state/batch layout
+        (arrays, or ``jax.ShapeDtypeStruct``s carrying shardings)."""
+        if self._jit_step is not None:
+            return self._jit_step
+        if self.pc.grad_comm != "auto":
+            def step(state, ids, labels):
+                loss, grads, new_ef = self._loss_and_grads_ring(
+                    state["params"], ids, labels, state["step"],
+                    state.get("ef", {}))
+                new_state = self._update(
+                    {k: v for k, v in state.items() if k != "ef"}, grads)
+                if "ef" in state:
+                    new_state["ef"] = new_ef
+                return new_state, loss
+        elif self.pc.schedule in ("1f1b", "zbh1", "zbvpp"):
+            def step(state, ids, labels):
+                loss, grads = self._loss_and_grads_1f1b(
+                    state["params"], ids, labels)
+                return self._update(state, grads), loss
+        else:
+            def step(state, ids, labels):
+                loss, grads = jax.value_and_grad(
+                    lambda p: self._forward_loss(p, ids, labels))(state["params"])
+                return self._update(state, grads), loss
+
+        # pin the state's shardings on BOTH sides of the program:
+        # without out_shardings XLA is free to hand the updated state
+        # back replicated/unspecified, and the next call — now seeing
+        # different input shardings — silently recompiles the whole
+        # step (one wasted multi-second compile per process, and the
+        # short-window bench reads it as throughput)
+        sh = jax.tree_util.tree_map(lambda a: a.sharding, state)
+        self._jit_step = jax.jit(
+            step, donate_argnums=(0,),
+            in_shardings=(sh, ids.sharding, labels.sharding),
+            out_shardings=(sh, None))
+        return self._jit_step
+
+    def lowered_step(self, state, ids, labels):
+        """``jax.stages.Lowered`` of the program ``train_step`` runs for
+        this state/batch layout, without running it: ``as_text()`` shows
+        which kernels are in it, ``compile().memory_analysis()`` what it
+        takes.  Takes device arrays or ``jax.ShapeDtypeStruct``s with
+        shardings (compile rehearsals for a described device)."""
+        return self._jitted_step(state, ids, labels).lower(
+            state, ids, labels)
+
     def train_step(self, state, ids, labels):
         if self._telemetry is not None:
             self._telemetry.begin_step()
-        if not (hasattr(ids, "sharding") and hasattr(labels, "sharding")):
+        if not (isinstance(ids, jax.Array) and isinstance(labels, jax.Array)):
             # raw host arrays (either of them): place both on the mesh
             ids, labels = self.shard_batch(np.asarray(ids),
                                            np.asarray(labels))
-        if self._jit_step is None:
-            if self.pc.grad_comm != "auto":
-                def step(state, ids, labels):
-                    loss, grads, new_ef = self._loss_and_grads_ring(
-                        state["params"], ids, labels, state["step"],
-                        state.get("ef", {}))
-                    new_state = self._update(
-                        {k: v for k, v in state.items() if k != "ef"}, grads)
-                    if "ef" in state:
-                        new_state["ef"] = new_ef
-                    return new_state, loss
-            elif self.pc.schedule in ("1f1b", "zbh1", "zbvpp"):
-                def step(state, ids, labels):
-                    loss, grads = self._loss_and_grads_1f1b(
-                        state["params"], ids, labels)
-                    return self._update(state, grads), loss
-            else:
-                def step(state, ids, labels):
-                    loss, grads = jax.value_and_grad(
-                        lambda p: self._forward_loss(p, ids, labels))(state["params"])
-                    return self._update(state, grads), loss
-
-            # pin the state's shardings on BOTH sides of the program:
-            # without out_shardings XLA is free to hand the updated state
-            # back replicated/unspecified, and the next call — now seeing
-            # different input shardings — silently recompiles the whole
-            # step (one wasted multi-second compile per process, and the
-            # short-window bench reads it as throughput)
-            sh = jax.tree_util.tree_map(lambda a: a.sharding, state)
-            self._jit_step = jax.jit(
-                step, donate_argnums=(0,),
-                in_shardings=(sh, ids.sharding, labels.sharding),
-                out_shardings=(sh, None))
-        out = self._jit_step(state, ids, labels)
+        out = self._jitted_step(state, ids, labels)(state, ids, labels)
         if self._telemetry is not None:
             if self._grad_sync_bytes is None:
                 try:    # analytic per-step dp gradient-sync traffic

@@ -38,11 +38,12 @@ CATALOG: Dict[str, tuple] = {
     "serving.queue_wait_ms": (
         "histogram", "", "enqueue -> admission wait per request"),
     "serving.ttft_ms": (
-        "histogram", "", "enqueue -> first token per request "
-        "(dispatch-stamped, drain-folded)"),
+        "histogram", "", "enqueue -> first token on the host, per "
+        "request (stamped when the drain that carries it returns)"),
     "serving.itl_ms": (
         "histogram", "", "inter-token latency per generated token after "
-        "the first"),
+        "the first: the span between the drains that delivered a "
+        "request's tokens, shared evenly by the tokens of one drain"),
     "serving.queue_depth": (
         "histogram", "", "waiting-queue depth observed at each step"),
     "serving.queue_depth_now": (
@@ -418,6 +419,12 @@ CATALOG: Dict[str, tuple] = {
         "counter", "", "XLA backend compiles attributed to train steps"),
     "train.grad_comm_bytes": (
         "counter", "", "analytic gradient-sync traffic"),
+    # ---- kernels (PR 21) ----
+    "kernels.reference_fallbacks": (
+        "counter", "kernel=flash_attention",
+        "traces on a TPU backend in which a Pallas kernel gave way to its "
+        "XLA reference (kernels/flash_attention.py logs the rule that "
+        "failed, once per shape); chip_smoke.py asserts zero on its paths"),
     # ---- compile telemetry (PR 2/5) ----
     "jit.backend_compiles": (
         "counter", "", "process-wide XLA backend compiles"),

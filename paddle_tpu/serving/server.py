@@ -410,6 +410,8 @@ class ServingServer:
         try:
             if self._warmup:
                 self._warm()
+                if self.slo is not None:
+                    self.slo.forget()     # warmup latency is compile time
             self._ready.set()
             while not self._stop.is_set():
                 while True:

@@ -24,7 +24,10 @@ of ``1 - FLAGS_serving_slo_quantile`` (e.g. 5% for a p95 SLO):
 Every decision increments ``serving.http.slo_decision{decision=...}``;
 sheds additionally bump the flat ``serving.http.shed`` counter the bench
 stamps into results.  Cold start (fewer than
-``FLAGS_serving_slo_min_samples`` fresh observations) always admits.
+``FLAGS_serving_slo_min_samples`` fresh observations) always admits;
+"fresh" starts when the controller is built and again when the server's
+warmup ends (``forget()``) — the histograms are process-wide and a
+warmup observation is a compile, not a latency.
 """
 
 from __future__ import annotations
@@ -99,13 +102,26 @@ class SLOController:
             "ttft": (_metrics.histogram("serving.ttft_ms"), self.ttft_ms),
             "itl": (_metrics.histogram("serving.itl_ms"), self.itl_ms),
         }
+        self._decisions = {
+            d: _metrics.counter("serving.http.slo_decision", decision=d)
+            for d in (ADMIT, QUEUE, SHED)}
+        self._shed = _metrics.counter("serving.http.shed")
+        self.last: Dict[str, dict] = {}
+        self.forget()
+
+    def forget(self) -> None:
+        """Start the burn evidence over from the histograms as they stand
+        now.  The histograms are process-wide: what they held before this
+        controller existed, and what the server's warmup request put in
+        them (one compile per observation), is not traffic to judge."""
         # per-term window base: (count, over-target count) at last rebase,
         # plus the completed previous window's (n, bad) — burn is computed
         # over previous + current so a rebase never zeroes the evidence
         # (without the carry, sustained overload would flap back to admit
         # for min_samples observations after every rebase)
         self._base: Dict[str, Tuple[int, int]] = {
-            k: (0, 0) for k in self._hists}
+            k: (h.count, _over_target(h, target))
+            for k, (h, target) in self._hists.items()}
         self._prev: Dict[str, Tuple[int, int]] = {
             k: (0, 0) for k in self._hists}
         # wall-clock window epochs + completed-window observation rates:
@@ -113,11 +129,6 @@ class SLOController:
         now = time.perf_counter()
         self._t0: Dict[str, float] = {k: now for k in self._hists}
         self._prev_rate: Dict[str, float] = {k: 0.0 for k in self._hists}
-        self._decisions = {
-            d: _metrics.counter("serving.http.slo_decision", decision=d)
-            for d in (ADMIT, QUEUE, SHED)}
-        self._shed = _metrics.counter("serving.http.shed")
-        self.last: Dict[str, dict] = {}
 
     # ------------------------------------------------------------ burn --
     def burn_rates(self) -> Dict[str, dict]:

@@ -93,11 +93,19 @@ def test_failing_candidates_are_disqualified():
     assert got == (3, 3)
 
 
-def test_all_candidates_fail_returns_default():
+def test_all_candidates_fail_is_reported():
+    """One failing tile is disqualified; a pass in which EVERY candidate
+    failed is a broken kernel or device and raises with each error."""
     def bench(cand):
         raise RuntimeError("nope")
 
-    assert autotune.lookup_or_tune("k3", [(1, 1)], bench, (5, 5)) == (5, 5)
+    with pytest.raises(RuntimeError, match=r"every candidate.*nope"):
+        autotune.lookup_or_tune("k3", [(1, 1)], bench, (5, 5))
+
+
+def test_all_candidates_infeasible_returns_default():
+    assert autotune.lookup_or_tune(
+        "k4", [(1, 1)], lambda cand: None, (5, 5)) == (5, 5)
 
 
 def test_key_includes_device_shape_dtype():

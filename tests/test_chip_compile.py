@@ -1,0 +1,190 @@
+"""Compile the main path's kernels for a DESCRIBED v5e at llama2_7b widths.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (on-chip-measurement guide, section 2, step 3):
+it refuses what interpret mode and ``jax.export`` (tests/
+test_mosaic_lowering.py) both let through — the fused-gather ``gmm`` arm
+below passes export and is refused here.  Nothing runs, so these say
+nothing about results or times; ``chip_smoke.py`` is the chip run.
+
+This is the only file that describes the chip.  The topology is described
+inside a module-scoped fixture — never at import — so every xdist worker
+collects the same tests and only the worker given this file loads the TPU
+library.  Keep all such tests in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu import flags
+from paddle_tpu.kernels import flash_attention as fa
+from paddle_tpu.kernels import grouped_matmul as gm
+from paddle_tpu.kernels import paged_attention as pa
+
+# LlamaConfig.llama2_7b() widths + the serving launcher's geometry
+QH = KVH = 32
+D = 128
+HIDDEN, INTER = 4096, 11008
+BATCH, PAGE, MAX_LEN = 8, 16, 1024
+W = MAX_LEN // PAGE
+N_PAGES = BATCH * W
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # such a compile is written to the persistent cache but cannot be read
+    # back without a chip: turn the cache off around this module
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(one_chip, fn, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _paged_shapes(T, cache_dtype=BF16, page=PAGE, n_pages=N_PAGES):
+    i32 = jnp.int32
+    cache = ((KVH, n_pages, page, D), cache_dtype)
+    return [((BATCH, T, QH, D), BF16), cache, cache,
+            ((BATCH, MAX_LEN // page), i32), ((BATCH,), i32),
+            ((BATCH,), i32), ((BATCH, T, KVH, D), BF16),
+            ((BATCH, T, KVH, D), BF16)]
+
+
+@pytest.mark.parametrize("T", [1, 64, 8],
+                         ids=["decode", "prefill_chunk", "spec_verify"])
+def test_paged_attention_compiles(one_chip, T):
+    _compile(one_chip,
+             lambda q, k, v, bt, cl, ql, kn, vn:
+             pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn, vn,
+                                               False),
+             *_paged_shapes(T))
+
+
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "prefill_chunk"])
+def test_paged_attention_int8_compiles(one_chip, T):
+    """PR 21 settled this by repair: the per-(kv-head, page) scales ride
+    the scalar-prefetch channel (as plain SMEM operands of a scalar-
+    prefetch grid Mosaic refused them: 'failed to legalize func.func')."""
+    page, n_pages = 32, BATCH * (MAX_LEN // 32)
+    scale = ((KVH, n_pages), jnp.float32)
+    _compile(one_chip,
+             lambda q, k, v, bt, cl, ql, kn, vn, ks, vs:
+             pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn, vn,
+                                               False, k_scale=ks, v_scale=vs),
+             *_paged_shapes(T, jnp.int8, page, n_pages), scale, scale)
+
+
+V5E_SMEM = 1 << 20
+
+
+@pytest.mark.parametrize("kvh,fits,refused", [(32, 3968, 4096),
+                                              (8, 16256, 16384)])
+def test_paged_int8_scale_planes_bounded_by_smem(one_chip, kvh, fits,
+                                                 refused):
+    """Scalar-prefetched scales live in SMEM (1 MiB on v5e).  The bound
+    was bisected with this compile (PR 21): the geometry rule and the
+    compiler agree on the last pool that fits and on the next 128-page
+    step.  So int8 KV is brought up for pools of kv_heads x num_pages up
+    to about 127 Ki (3968 pages at 32 heads) and no larger."""
+    def rule(n):
+        return pa.kernel_geometry_error(
+            32, D, quantized=True, kv_heads=kvh, num_pages=n,
+            table_shape=(BATCH, MAX_LEN // 32), smem_bytes=V5E_SMEM)
+
+    def compile_pool(n):
+        i32, cache = jnp.int32, ((kvh, n, 32, D), jnp.int8)
+        new, scale = ((BATCH, 1, kvh, D), BF16), ((kvh, n), jnp.float32)
+        _compile(one_chip,
+                 lambda q, k, v, bt, cl, ql, kn, vn, ks, vs:
+                 pa._pallas_ragged_paged_attention(
+                     q, k, v, bt, cl, ql, kn, vn, False, k_scale=ks,
+                     v_scale=vs),
+                 ((BATCH, 1, kvh, D), BF16), cache, cache,
+                 ((BATCH, MAX_LEN // 32), i32), ((BATCH,), i32),
+                 ((BATCH,), i32), new, new, scale, scale)
+
+    assert rule(fits) is None
+    compile_pool(fits)
+    why = rule(refused)
+    assert why and "SMEM" in why
+    with pytest.raises(Exception, match="smem"):
+        compile_pool(refused)
+    # with no chip attached and none described, the rule is the compiler's
+    assert pa.kernel_geometry_error(32, D, quantized=True, kv_heads=kvh,
+                                    num_pages=refused) is None
+
+
+def test_flash_fwd_bwd_compiles(one_chip):
+    blocks = (512, 512)
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = fa._fa_pallas_forward(q, k, v, True, None, None, None,
+                                         blocks, "tpu")
+        return fa._fa_pallas_backward(q, k, v, jnp.swapaxes(out, 1, 2), lse,
+                                      g, True, None, None, None, blocks,
+                                      "tpu")
+
+    qkv = ((1, 2048, QH, D), BF16)
+    compiled = _compile(one_chip, fwd_bwd, qkv, qkv, qkv, qkv)
+    assert compiled.as_text().count("tpu_custom_call") >= 3   # fwd, dq, dkv
+
+
+M, E, BM = 8192, 8, 512
+
+
+def test_gmm_compiles(one_chip):
+    _compile(one_chip,
+             lambda l, r, t: gm.gmm(l, r, t, bm=BM, interpret=False),
+             ((M, HIDDEN), BF16), ((E, HIDDEN, INTER), BF16),
+             ((M // BM,), jnp.int32))
+
+
+def test_tgmm_compiles(one_chip):
+    _compile(one_chip,
+             lambda l, r, t: gm.tgmm(l, r, t, E, bm=BM, interpret=False),
+             ((M, HIDDEN), BF16), ((M, INTER), BF16),
+             ((M // BM,), jnp.int32))
+
+
+def test_fused_gather_gmm_is_refused_with_the_compilers_reason(one_chip):
+    """PR 21 settled this by making the materialized operand the default:
+    the fused arm stays behind FLAGS_grouped_matmul_fused_gather, and
+    choosing it for a chip fails when the program is built, with Mosaic's
+    own reason (a one-row slice of a tiled HBM operand)."""
+    assert flags.flag("grouped_matmul_fused_gather") is False
+    flags.set_flags({"grouped_matmul_fused_gather": True})
+    try:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            _compile(one_chip,
+                     lambda l, r, t, rows: gm.gmm(l, r, t, bm=BM,
+                                                  interpret=False, rows=rows),
+                     ((4096, HIDDEN), BF16), ((E, HIDDEN, INTER), BF16),
+                     ((M // BM,), jnp.int32), ((M,), jnp.int32))
+    finally:
+        flags.set_flags({"grouped_matmul_fused_gather": False})

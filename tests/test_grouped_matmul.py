@@ -91,6 +91,13 @@ class TestFusedGather:
     M, K, N, E, bm, L = 32, 128, 256, 3, 8, 21
     tg = jnp.asarray([0, 0, 1, 2], jnp.int32)
 
+    @pytest.fixture(autouse=True)
+    def _fused_arm(self):
+        # the fused arm is opt-in (the materialized operand is the default)
+        flags.set_flags({"FLAGS_grouped_matmul_fused_gather": True})
+        yield
+        flags.set_flags({"FLAGS_grouped_matmul_fused_gather": False})
+
     def _rows(self):
         rng = np.random.default_rng(5)
         return jnp.asarray(rng.integers(0, self.L, self.M), jnp.int32)
@@ -132,10 +139,7 @@ class TestFusedGather:
         rows = self._rows()
         fused = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
         flags.set_flags({"FLAGS_grouped_matmul_fused_gather": False})
-        try:
-            unfused = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
-        finally:
-            flags.set_flags({"FLAGS_grouped_matmul_fused_gather": True})
+        unfused = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
         np.testing.assert_allclose(fused, unfused, rtol=1e-5, atol=1e-5)
 
 

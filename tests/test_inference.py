@@ -467,7 +467,6 @@ def test_paged_attention_kernel_under_shard_map(rng):
     """The ragged kernel inside shard_map on the 8-device CPU mesh: batch
     sharded over 'dp', KV pool replicated — per-shard results must match
     the unsharded reference to fp32 tolerance."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = jax.devices()
@@ -486,9 +485,9 @@ def test_paged_attention_kernel_under_shard_map(rng):
     def local(qb, kcb, vcb, btb, ctxb):
         return pa.paged_attention(qb, kcb, vcb, btb, ctxb)
 
-    f = shard_map(local, mesh=mesh,
-                  in_specs=(P("dp"), P(), P(), P("dp"), P("dp")),
-                  out_specs=P("dp"), check_rep=False)
+    f = jax.shard_map(local, mesh=mesh,
+                      in_specs=(P("dp"), P(), P(), P("dp"), P("dp")),
+                      out_specs=P("dp"), check_vma=False)
     old = flags.get_flags(["paged_attention_interpret"])
     flags.set_flags({"paged_attention_interpret": True})
     try:

@@ -548,3 +548,25 @@ def test_spill_telemetry_counters_and_stats():
     for key in ("kv_spill_capacity", "kv_spill_resident",
                 "kv_spilled_pages", "kv_swapins"):
         assert key in st
+
+
+def test_geometry_rule_asks_the_attached_tpu_for_its_smem(monkeypatch):
+    """With no ``smem_bytes`` given, an int8 geometry on a TPU backend is
+    held to the SMEM the attached device reports (``pltpu.get_tpu_info``);
+    off a TPU the rule is left to the compiler."""
+    import types
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.kernels import paged_attention as pa
+
+    kw = dict(quantized=True, kv_heads=32, num_pages=4096,
+              table_shape=(8, 32))
+    assert pa.kernel_geometry_error(32, 128, **kw) is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(
+        pltpu, "get_tpu_info",
+        lambda: types.SimpleNamespace(smem_capacity_bytes=1 << 20))
+    assert "SMEM" in pa.kernel_geometry_error(32, 128, **kw)
+    assert pa.kernel_geometry_error(32, 128, **{**kw, "num_pages": 3968}) \
+        is None

@@ -10,7 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import conftest
 import paddle_tpu as paddle
 import paddle_tpu.nn as nn
 
@@ -22,7 +21,6 @@ def seq_mesh():
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@conftest.xfail_pinned_partial_auto
 def test_ring_attention_parity(rng, seq_mesh, causal):
     from paddle_tpu.kernels.flash_attention import _reference_attention
     from paddle_tpu.kernels.ring_attention import ring_attention_arrays
@@ -36,7 +34,6 @@ def test_ring_attention_parity(rng, seq_mesh, causal):
                                atol=2e-5)
 
 
-@conftest.xfail_pinned_partial_auto
 def test_ring_attention_grad_and_jit(rng, seq_mesh):
     from paddle_tpu.kernels.flash_attention import _reference_attention
     from paddle_tpu.kernels.ring_attention import ring_attention_arrays

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-import conftest
 import paddle_tpu as paddle
 
 
@@ -66,7 +65,6 @@ def test_gpt_train_eager(rng):
     assert float(loss.item()) < first
 
 
-@conftest.xfail_pinned_partial_auto
 def test_pipeline_spmd_parity(rng):
     from paddle_tpu.distributed.pipeline_spmd import pipeline_apply
 
@@ -111,28 +109,24 @@ def test_pipeline_single_stage_scan(rng):
                                rtol=1e-5)
 
 
-_pp = conftest.xfail_pinned_partial_auto   # pipeline paths use partial-auto
 @pytest.mark.parametrize("pcfg_kw,name", [
-    pytest.param(dict(dp=2, pp=2, mp=2, micro_batches=4,
-                      sequence_parallel=True, remat=True),
-                 "dp2pp2mp2_sp_remat", marks=_pp),
+    (dict(dp=2, pp=2, mp=2, micro_batches=4, sequence_parallel=True,
+          remat=True), "dp2pp2mp2_sp_remat"),
     (dict(dp=8), "dp8"),
     (dict(mp=8, sequence_parallel=True), "mp8_sp"),
-    pytest.param(dict(pp=2, mp=2, micro_batches=4, schedule="interleave",
-                      virtual_pp=2), "pp2v2_interleave", marks=_pp),
-    pytest.param(dict(dp=2, pp=2, micro_batches=4, schedule="1f1b",
-                      remat=True), "pp2_1f1b", marks=_pp),
-    pytest.param(dict(pp=2, mp=2, micro_batches=4, schedule="zbh1"),
-                 "pp2_zbh1", marks=_pp),
+    (dict(pp=2, mp=2, micro_batches=4, schedule="interleave",
+          virtual_pp=2), "pp2v2_interleave"),
+    (dict(dp=2, pp=2, micro_batches=4, schedule="1f1b",
+          remat=True), "pp2_1f1b"),
+    (dict(pp=2, mp=2, micro_batches=4, schedule="zbh1"), "pp2_zbh1"),
     (dict(dp=2, sep=2, mp=2), "dp2_sep2_mp2_ulysses"),
     (dict(sep=2, mp=2, remat=True), "sep2_mp2_remat"),
-    pytest.param(dict(dp=2, pp=4, micro_batches=8, schedule="zbh1",
-                      remat=True), "pp4_zbh1_remat", marks=_pp),
-    pytest.param(dict(pp=2, mp=2, micro_batches=4, schedule="zbvpp",
-                      virtual_pp=2), "pp2v2_zbvpp", marks=_pp),
-    pytest.param(dict(dp=2, pp=2, micro_batches=4, schedule="zbvpp",
-                      virtual_pp=2, remat=True), "dp2pp2v2_zbvpp_remat",
-                 marks=_pp),
+    (dict(dp=2, pp=4, micro_batches=8, schedule="zbh1",
+          remat=True), "pp4_zbh1_remat"),
+    (dict(pp=2, mp=2, micro_batches=4, schedule="zbvpp",
+          virtual_pp=2), "pp2v2_zbvpp"),
+    (dict(dp=2, pp=2, micro_batches=4, schedule="zbvpp",
+          virtual_pp=2, remat=True), "dp2pp2v2_zbvpp_remat"),
 ])
 def test_pretrain_hybrid_parity(rng, pcfg_kw, name):
     from paddle_tpu.models.llama import LlamaConfig
@@ -178,7 +172,6 @@ def test_pretrain_state_sharded():
     assert state["m"]["embed"].dtype == jnp.float32
 
 
-@conftest.xfail_pinned_partial_auto
 def test_graft_entry():
     import sys
     sys.path.insert(0, "/root/repo")
@@ -238,7 +231,6 @@ def test_zbh1_schedule_structure():
             assert "W" in kinds, f"no W fill at stage {s} tick {t}"
 
 
-@conftest.xfail_pinned_partial_auto
 def test_zbh1_grads_match_1f1b(rng):
     """Same loss AND gradients from the split-backward schedule."""
     import jax
@@ -272,7 +264,6 @@ def test_zbh1_grads_match_1f1b(rng):
                                rtol=1e-4, atol=1e-5)
 
 
-@conftest.xfail_pinned_partial_auto
 def test_zbvpp_grads_match_direct(rng):
     """ZBVPP (zero-bubble x virtual pipeline, ref pipeline_zero_bubble.py:151)
     must reproduce the direct full-model loss AND gradients, chunk layout
@@ -323,7 +314,6 @@ def test_zbvpp_grads_match_direct(rng):
                                rtol=1e-4, atol=1e-5)
 
 
-@conftest.xfail_pinned_partial_auto
 def test_zbvpp_matches_zbh1_single_chunk(rng):
     """v=1 ZBVPP degenerates to the same math as ZBH1 (different tick
     layout, same gradients)."""
@@ -356,7 +346,6 @@ def test_zbvpp_matches_zbh1_single_chunk(rng):
                                rtol=1e-4, atol=1e-5)
 
 
-@conftest.xfail_pinned_scan_transpose
 def test_zero3_param_sharding_parity(rng):
     """stage-3: params laid over dp; loss matches the unsharded step and
     the placement actually shards over 'dp'."""
@@ -384,7 +373,6 @@ def test_zero3_param_sharding_parity(rng):
     assert "dp" in str(one.sharding.spec)
 
 
-@conftest.xfail_pinned_scan_transpose
 def test_zero3_composes_with_mp(rng):
     from paddle_tpu.models.llama import LlamaConfig
     from paddle_tpu.models.pretrain import ParallelConfig, PretrainStep
@@ -435,3 +423,78 @@ def test_remat_policy_validation():
         ParallelConfig(remat=True, remat_policy="nope")
     with pytest.raises(ValueError, match="remat=False"):
         ParallelConfig(remat_policy="dots")  # policy without remat=True
+
+
+@pytest.fixture(scope="module")
+def flash_one_device():
+    """Two interpreted-kernel steps on one device: what every mesh case
+    below must reproduce."""
+    return _flash_steps({})
+
+
+def _flash_steps(parallel, calls=None, monkeypatch=None):
+    from paddle_tpu import flags
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.models.pretrain import ParallelConfig, PretrainStep
+
+    if calls is not None:
+        real = fa._fa_pallas_forward
+        monkeypatch.setattr(
+            fa, "_fa_pallas_forward",
+            lambda q, *a, **kw: calls.append(q.shape) or real(q, *a, **kw))
+    cfg = LlamaConfig.tiny(hidden_size=256, max_position_embeddings=128)
+    ids = np.random.default_rng(0).integers(0, 250, (4, 128)).astype(np.int32)
+    flags.set_flags({"flash_attention_interpret": True})
+    try:
+        ps = PretrainStep(cfg, ParallelConfig(**parallel))
+        state = ps.init_state(seed=0)
+        state, l0 = ps.train_step(state, ids, ids)
+        state, l1 = ps.train_step(state, ids, ids)
+        return float(l0), float(l1)
+    finally:
+        flags.set_flags({"flash_attention_interpret": False})
+
+
+@pytest.mark.parametrize("parallel,shard", [
+    (dict(dp=2, mp=2), (2, 128, 2, 64)),       # batch 4/dp2, q heads 4/mp2
+    (dict(dp=2, sep=2), (2, 128, 2, 64)),      # heads over sep (Ulysses)
+], ids=["dp2_mp2", "dp2_sep2"])
+def test_flash_kernel_under_mesh_matches_single_device(
+        flash_one_device, monkeypatch, parallel, shard):
+    """PR 21: GSPMD cannot partition a Mosaic kernel, so on a multi-device
+    mesh the flash entry splits the Pallas call by hand (batch over dp,
+    heads over sep/mp) in a shard_map.  Interpret mode runs that same
+    wrapper on the virtual mesh: the kernel must actually be in the step
+    at the per-shard shape, and two steps' losses must match the
+    single-device run."""
+    calls = []
+    losses = _flash_steps(parallel, calls, monkeypatch)
+    assert shard in calls and (4, 128, 4, 64) not in calls
+    np.testing.assert_allclose(losses, flash_one_device, rtol=1e-4)
+    assert flash_one_device[1] < flash_one_device[0]
+
+
+def test_flash_on_a_chip_mesh_inside_pipeline_stages_is_refused_at_build(
+        monkeypatch):
+    """pp > 1 with other mesh axes > 1: the hand split is not wired inside
+    the pipeline's shard_map, and on a TPU the compiler would refuse the
+    kernel mid-compile — PretrainStep says so when it is built.  pp alone
+    and pp == 1 build."""
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.models.pretrain import ParallelConfig, PretrainStep
+
+    monkeypatch.setattr(fa, "_pallas_mode", lambda: "tpu")
+    cfg = LlamaConfig.tiny()
+    with pytest.raises(NotImplementedError, match="pipeline stages"):
+        PretrainStep(cfg, ParallelConfig(pp=2, mp=2))
+    PretrainStep(cfg, ParallelConfig(pp=2))
+    PretrainStep(cfg, ParallelConfig(dp=2, mp=2))
+
+
+def test_flash_under_mesh_names_the_heads_it_cannot_split():
+    """2 kv heads do not divide over sep2 x mp2: a sentence at trace time,
+    not a shape error from inside the shard_map."""
+    with pytest.raises(ValueError, match=r"kv heads \(2\)"):
+        _flash_steps(dict(sep=2, mp=2))

@@ -7,7 +7,6 @@ import jax
 import numpy as np
 import pytest
 
-import conftest
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
                                      LlamaMoEMLP, moe_mlp_forward)
@@ -82,10 +81,7 @@ def test_moe_eager_model_forward():
     assert np.isfinite(float(loss.numpy()))
 
 
-@pytest.mark.parametrize("zero1", [
-    False,
-    pytest.param(True, marks=conftest.xfail_pinned_scan_transpose),
-])
+@pytest.mark.parametrize("zero1", [False, True])
 def test_moe_pretrain_step_dp_ep_mp(rng, zero1):
     """One compiled step on the dp2 x ep2 x mp2 mesh: finite decreasing
     loss, expert banks actually sharded over 'ep'."""

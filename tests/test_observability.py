@@ -423,6 +423,42 @@ def test_engine_eos_does_not_inflate_itl():
         len(out[rid]) - 1
 
 
+def test_engine_latency_is_stamped_when_tokens_reach_the_host(monkeypatch):
+    """The host dispatches sync_every steps ahead and then blocks in the
+    drain.  A token exists for the client when that drain returns: TTFT
+    includes the block, and the tokens a drain delivers share its span
+    evenly — no observation is the whole catch-up (which is what sheds a
+    healthy replica through the ITL SLO) and none is the ~0 between two
+    dispatches."""
+    import time
+    block_s = 0.3
+    count_sync = obs.count_sync
+
+    def blocking_sync(n=1):          # called once per drain, ahead of the
+        time.sleep(block_s)          # window's device->host transfer
+        count_sync(n)
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny())
+    eng = ContinuousBatchingEngine(
+        model, max_batch=2, gen=GenerationConfig(max_new_tokens=20),
+        max_seq_len=64, page_size=8, prefill_bucket=8, metrics=True,
+        sync_every=8)
+    eng.add_request([1, 2, 3])
+    eng.run()                                # compiles: not timed below
+    obs.reset("serving.")
+    monkeypatch.setattr(obs, "count_sync", blocking_sync)
+    rid = eng.add_request([1, 2, 3])
+    out = eng.run()
+    ttft = obs.metrics.histogram("serving.ttft_ms")
+    itl = obs.metrics.histogram("serving.itl_ms")
+    assert ttft.count == 1 and itl.count == len(out[rid]) - 1 == 19
+    assert ttft.min >= block_s * 1e3         # visible only after the drain
+    # 8 tokens per drain share (block + 8 dispatches): ~40 ms each
+    assert itl.min >= block_s * 1e3 / 8 / 2
+    assert itl.max < block_s * 1e3
+
+
 def test_engine_metrics_off_records_nothing():
     obs.reset("serving.")
     eng = _tiny_engine(metrics=False)

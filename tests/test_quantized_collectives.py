@@ -10,7 +10,7 @@ import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-import paddle_tpu  # noqa: F401  (installs the jax.shard_map shim)
+import paddle_tpu  # noqa: F401
 from paddle_tpu.distributed import quantized_collectives as qc
 
 

@@ -254,7 +254,6 @@ def test_sync_batch_norm_matches_global_stats(rng):
     the full batch (the reference's NCCL-allreduce semantics)."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from paddle_tpu.core.tensor import Tensor
@@ -275,8 +274,9 @@ def test_sync_batch_norm_matches_global_stats(rng):
                                 Tensor(bs), training=True, group=G())
         return out._data
 
-    got = shard_map(local_fn, mesh=mesh,
-                    in_specs=(P("dp"), P(), P()), out_specs=P("dp"))(
+    got = jax.shard_map(local_fn, mesh=mesh,
+                        in_specs=(P("dp"), P(), P()), out_specs=P("dp"),
+                        check_vma=True)(
         jnp.asarray(x), jnp.asarray(w), jnp.asarray(b))
 
     mean = x.mean(axis=(0, 2, 3))

@@ -387,6 +387,42 @@ def test_slo_decisions_read_histograms_not_queue_length():
     assert slo2.decide(record=False) == "shed"
 
 
+def test_slo_judges_only_traffic_since_it_started(model):
+    """The histograms are process-wide.  What they held before the
+    controller was built is not its evidence, and neither is the warmup
+    request (one compile per observation): a replica that has just
+    compiled must admit its first requests."""
+    obs.reset("serving.")
+    itl = obs.metrics.histogram("serving.itl_ms")
+    for _ in range(64):
+        itl.observe(9999.0)                  # an earlier engine's stalls
+    slo = SLOController(ttft_ms=100.0, itl_ms=100.0, quantile=0.95,
+                        burn=2.0, min_samples=8, window=64)
+    assert slo.decide(record=False) == "admit"
+    assert slo.burn_rates()["itl"]["window_n"] == 0
+    for _ in range(16):
+        itl.observe(9999.0)
+    assert slo.decide(record=False) == "shed"
+    slo.forget()
+    assert slo.decide(record=False) == "admit"
+    # the server forgets what its own warmup observed
+    slo = SLOController(ttft_ms=1e-6, itl_ms=1e-6, quantile=0.95,
+                        burn=2.0, min_samples=1, window=64)
+    server = ServingServer(_engine(model, metrics=True), slo=slo,
+                           flight_recorder=False, warmup=True).start()
+    try:
+        deadline = time.time() + 120
+        while not server.ready() and time.time() < deadline:
+            time.sleep(0.02)
+        assert server.ready()
+        assert obs.metrics.histogram("serving.ttft_ms").count >= 1
+        terms = slo.burn_rates()
+        assert terms["ttft"]["window_n"] == terms["itl"]["window_n"] == 0
+        assert slo.decide(record=False) == "admit"
+    finally:
+        server.close()
+
+
 def test_slo_sustained_burn_survives_window_rebase():
     """A window rebase carries the completed window forward: sustained
     100%-violation traffic keeps shedding across every rebase boundary

@@ -152,6 +152,32 @@ def test_jl001_scale_indexing_bug_shape():
     assert lint(good, select={"JL001"}).findings == []
 
 
+def test_jl001_follows_helpers_called_from_kernel():
+    """PR 21: grouped_matmul's _gather_rows — a module-level helper the
+    kernels call — looped with python-int fori_loop bounds, and JL001
+    never looked inside it because only pallas_call's own argument was a
+    'kernel'.  Helpers reached from a kernel body are kernel code."""
+    src = """
+        import jax
+        from jax.experimental import pallas as pl
+
+        def _gather(rows_ref, bm):
+            jax.lax.fori_loop(0, bm, lambda r, c: c, 0)
+
+        def _unrelated(n):
+            return n // 2                     # never reached from a kernel
+
+        def _k(rows_ref, o_ref, *, bm):
+            _gather(rows_ref, bm)
+
+        def entry(x):
+            return pl.pallas_call(_k, out_shape=x)(x)
+    """
+    ctx = lint(src, select={"JL001"})
+    assert len(ctx.findings) == 2             # lower bound + init carry
+    assert all("fori_loop" in f.message for f in ctx.findings)
+
+
 def test_jl001_resolves_partial_alias():
     src = """
         import functools
