@@ -1,0 +1,11 @@
+"""1 - union of device-operation intervals over the traced window, mean over the chips."""
+from chipbench.harness import readers
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "serve_total_tok_s"
+SOURCE = "device_trace"
+
+
+def read(run):
+    return readers.device_idle_pct(run)
