@@ -148,7 +148,8 @@ def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
         # decode batches carry a handful of rows: shrink the row tile to
         # the 8-row sublane multiple that covers them (same math, less pad)
         bm = max(8, min(block_m, -(-N * top_k // 8) * 8))
-        topv, topi, _, _ = _llama._route_topk(xf, gw, top_k)
+        with jax.named_scope("router"):
+            topv, topi, _, _ = _llama._route_topk(xf, gw, top_k)
         if mp_shards and mp_shards > 1:
             E_loc = E // mp_shards
             my = jax.lax.axis_index(MP_AXIS)
@@ -173,10 +174,12 @@ def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
                 return jax.lax.dynamic_slice_in_dim(
                     w, my * E_loc, E_loc, axis=0)
 
-            part = _llama._grouped_ffn(
-                xf, _loc(lp["mlp.experts_gate"]),
-                _loc(lp["mlp.experts_up"]), _loc(lp["mlp.experts_down"]),
-                gates, inv, pos, tg, E_loc, top_k, bm)
+            with jax.named_scope("experts"):
+                part = _llama._grouped_ffn(
+                    xf, _loc(lp["mlp.experts_gate"]),
+                    _loc(lp["mlp.experts_up"]),
+                    _loc(lp["mlp.experts_down"]),
+                    gates, inv, pos, tg, E_loc, top_k, bm)
             parts = jax.lax.all_gather(part, MP_AXIS, axis=0)  # [mp, N, H]
             # explicit left-assoc shard-order sum — NEVER psum, whose
             # reduction order XLA leaves unspecified
@@ -184,28 +187,31 @@ def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
             for s in range(1, mp_shards):
                 out = out + parts[s]
             return out.reshape(shape)
-        inv, pos, tg = sorted_dispatch_plan(
-            topi.reshape(N * top_k), E, bm)
-        out = _llama._grouped_ffn(
-            xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
-            lp["mlp.experts_down"], topv, inv, pos, tg, E, top_k, bm)
+        with jax.named_scope("experts"):
+            inv, pos, tg = sorted_dispatch_plan(
+                topi.reshape(N * top_k), E, bm)
+            out = _llama._grouped_ffn(
+                xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
+                lp["mlp.experts_down"], topv, inv, pos, tg, E, top_k, bm)
         return out.reshape(shape)
-    probs = jax.nn.softmax(
-        xf.astype(jnp.float32) @ gw.astype(jnp.float32), axis=-1)
-    topv, topi = jax.lax.top_k(probs, top_k)
-    topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-    comb = jnp.zeros_like(probs).at[
-        jnp.arange(xf.shape[0])[:, None], topi].set(topv)
+    with jax.named_scope("router"):
+        probs = jax.nn.softmax(
+            xf.astype(jnp.float32) @ gw.astype(jnp.float32), axis=-1)
+        topv, topi = jax.lax.top_k(probs, top_k)
+        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+        comb = jnp.zeros_like(probs).at[
+            jnp.arange(xf.shape[0])[:, None], topi].set(topv)
 
     def step(acc, ex):
         h = jax.nn.silu(xf @ ex["wg"]) * (xf @ ex["wu"])
         return acc + ex["c"][:, None].astype(acc.dtype) * (h @ ex["wd"]), None
 
-    acc0 = jnp.zeros(xf.shape, xf.dtype)
-    out, _ = jax.lax.scan(step, acc0, {
-        "wg": lp["mlp.experts_gate"], "wu": lp["mlp.experts_up"],
-        "wd": lp["mlp.experts_down"],
-        "c": comb.T.astype(xf.dtype)})
+    with jax.named_scope("experts"):
+        acc0 = jnp.zeros(xf.shape, xf.dtype)
+        out, _ = jax.lax.scan(step, acc0, {
+            "wg": lp["mlp.experts_gate"], "wu": lp["mlp.experts_up"],
+            "wd": lp["mlp.experts_down"],
+            "c": comb.T.astype(xf.dtype)})
     return out.reshape(shape)
 
 
@@ -226,6 +232,7 @@ def _filter_logits(logits, gc: GenerationConfig):
     return logits
 
 
+@jax.named_scope("sampling")
 def _sample(logits, key, pos, gc: GenerationConfig):
     """logits: [N, V] fp32, pos: [N] int32 → [N] int32 (traced; gc
     fields are static).
@@ -374,8 +381,10 @@ class LlamaGenerator:
             "blocks": blocks,
         }
 
-    def _tp_jit(self, fn, n_in, n_out, out_cache_idx):
-        """jit one engine program, shard_map-wrapping it over the ``mp``
+    def _tp_jit(self, fn, name, n_in, n_out, out_cache_idx):
+        """jit one engine program under ``name`` (a profiler trace's ``XLA
+        Modules`` line then reads ``jit_<name>``, whatever ``fn`` is
+        wrapped in), shard_map-wrapping it over the ``mp``
         mesh when tensor-parallel: the cache tuple (arg 1 in, index
         ``out_cache_idx`` out) rides the pool's per-shard kv-head specs,
         every other operand — weights, tokens, masks, the PRNG key — is
@@ -387,34 +396,30 @@ class LlamaGenerator:
         (whose outputs carry no varying-axes type) and rebuilds every
         replicated output itself with ``all_gather``, which the checker
         cannot follow; tp=1 vs tp=N bit-match tests guard it instead."""
-        if self.tp == 1:
-            return jax.jit(fn, donate_argnums=(1,))
-        from jax.sharding import PartitionSpec
-        rep = PartitionSpec()
-        cspec = self.cache.pspecs
-        in_specs = tuple(cspec if i == 1 else rep for i in range(n_in))
-        out_specs = tuple(cspec if i == out_cache_idx else rep
-                          for i in range(n_out))
-        return jax.jit(
-            jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False),
-            donate_argnums=(1,))
+        if self.tp > 1:
+            from jax.sharding import PartitionSpec
+            rep = PartitionSpec()
+            cspec = self.cache.pspecs
+            in_specs = tuple(cspec if i == 1 else rep for i in range(n_in))
+            out_specs = tuple(cspec if i == out_cache_idx else rep
+                              for i in range(n_out))
+            fn = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
+        return jax.jit(_obs.tracing.named(fn, name), donate_argnums=(1,))
 
-    def pool_jit(self, fn, n_extra):
+    def pool_jit(self, fn, name, n_extra):
         """jit a pool-maintenance program ``fn(cache, *extras) -> cache``
-        (COW page copies, spill swap-ins) with the pool donated — shard_
-        map-wrapped like the step when tensor-parallel, extras
-        replicated."""
-        if self.tp == 1:
-            return jax.jit(fn, donate_argnums=(0,))
-        from jax.sharding import PartitionSpec
-        rep = PartitionSpec()
-        cspec = self.cache.pspecs
-        return jax.jit(
-            jax.shard_map(fn, mesh=self.mesh,
-                          in_specs=(cspec,) + (rep,) * n_extra,
-                          out_specs=cspec, check_vma=False),
-            donate_argnums=(0,))
+        (COW page copies, spill swap-ins) under ``name``, with the pool
+        donated — shard_map-wrapped like the step when tensor-parallel,
+        extras replicated."""
+        if self.tp > 1:
+            from jax.sharding import PartitionSpec
+            rep = PartitionSpec()
+            cspec = self.cache.pspecs
+            fn = jax.shard_map(fn, mesh=self.mesh,
+                               in_specs=(cspec,) + (rep,) * n_extra,
+                               out_specs=cspec, check_vma=False)
+        return jax.jit(_obs.tracing.named(fn, name), donate_argnums=(0,))
 
     def _step_jit(self, gc: GenerationConfig, t: int, track_recent=False):
         """The fused serving step, jitted for (sampling config, q bucket).
@@ -426,6 +431,7 @@ class LlamaGenerator:
             track = bool(track_recent)
             self._jit_cache[key] = self._tp_jit(
                 functools.partial(self._step_fn, gc, t, track),
+                f"serve_step_T{t}",
                 n_in=13 if track else 12, n_out=8 if track else 7,
                 out_cache_idx=5)
         return self._jit_cache[key]
@@ -439,6 +445,7 @@ class LlamaGenerator:
             import functools
             self._jit_cache[key] = self._tp_jit(
                 functools.partial(self._spec_verify_fn, gc, k, nmax),
+                f"serve_spec_verify_K{k}",
                 n_in=13, n_out=11, out_cache_idx=9)
         return self._jit_cache[key]
 
@@ -450,6 +457,7 @@ class LlamaGenerator:
             import functools
             self._jit_cache[key] = self._tp_jit(
                 functools.partial(self._fused_decode_fn, gc, k),
+                f"serve_fused_K{k}",
                 n_in=10, n_out=9, out_cache_idx=7)
         return self._jit_cache[key]
 
@@ -508,8 +516,11 @@ class LlamaGenerator:
         cos = jnp.take(self._cos, pos_c, axis=0)          # [B, T, d/2]
         sin = jnp.take(self._sin, pos_c, axis=0)
         ctx_prev = jnp.minimum(positions, self.max_seq_len).astype(jnp.int32)
-        toks = jnp.clip(tokens, 0, params["embed"].shape[0] - 1)
-        h = jnp.take(params["embed"], toks, axis=0)       # [B, T, H]
+        with jax.named_scope("embed"):
+            toks = jnp.clip(tokens, 0, params["embed"].shape[0] - 1)
+            h = jnp.take(params["embed"], toks, axis=0)   # [B, T, H]
+
+        moe = "mlp.experts_gate" in params["blocks"]     # MoE model serving
 
         def layer(carry, xs):
             x, = carry
@@ -518,51 +529,55 @@ class LlamaGenerator:
             else:
                 lp, kcl, vcl = xs
                 ksl = vsl = None
-            y = rms_norm_fp32(x, lp["input_layernorm.weight"], c.rms_norm_eps)
-            q = (y @ lp["self_attn.q_proj.weight"]).reshape(
-                B, T, c.num_attention_heads, c.head_dim)
-            k = (y @ lp["self_attn.k_proj.weight"]).reshape(
-                B, T, c.num_key_value_heads, c.head_dim)
-            v = (y @ lp["self_attn.v_proj.weight"]).reshape(
-                B, T, c.num_key_value_heads, c.head_dim)
-            q = _rope_bt(q, cos, sin)
-            k = _rope_bt(k, cos, sin)
-            # prior context from the paged cache + this step's own rows
-            # (causal), one mixed-mode kernel call; the fresh rows are
-            # committed to the cache only at the end of the step.  Under
-            # tp the cache slices kcl/vcl are already this shard's head
-            # planes (the scan carries per-shard storage), q/k/v slice to
-            # the matching head block, and each shard's kernel DMAs only
-            # its own heads' pages; the head-axis all_gather restores the
-            # full [B, T, qh, d] activation for the replicated o_proj
-            if tp > 1:
-                q_a = jax.lax.dynamic_slice_in_dim(
-                    q, shard * qh_l, qh_l, axis=2)
-                k_a = jax.lax.dynamic_slice_in_dim(
-                    k, shard * kvh_l, kvh_l, axis=2)
-                v_a = jax.lax.dynamic_slice_in_dim(
-                    v, shard * kvh_l, kvh_l, axis=2)
-            else:
-                q_a, k_a, v_a = q, k, v
-            attn = ragged_paged_attention(q_a, kcl, vcl, block_tables,
-                                          ctx_prev, q_lens=ql,
-                                          k_new=k_a, v_new=v_a,
-                                          k_scale=ksl, v_scale=vsl)
-            if tp > 1:
-                attn = jax.lax.all_gather(attn, MP_AXIS, axis=2,
-                                          tiled=True)
-            x = x + (attn.reshape(B, T, -1) @ lp["self_attn.o_proj.weight"])
-            y = rms_norm_fp32(x, lp["post_attention_layernorm.weight"],
-                              c.rms_norm_eps)
-            if "mlp.experts_gate" in lp:          # MoE model serving
-                x = x + _moe_ffn(y, lp, c.moe_top_k,
-                                 dispatch=c.moe_dispatch,
-                                 block_m=c.moe_block_m,
-                                 mp_shards=self._moe_shards)
-            else:
-                act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
-                    (y @ lp["mlp.up_proj.weight"])
-                x = x + act @ lp["mlp.down_proj.weight"]
+            with jax.named_scope("attention"):
+                y = rms_norm_fp32(x, lp["input_layernorm.weight"],
+                                  c.rms_norm_eps)
+                q = (y @ lp["self_attn.q_proj.weight"]).reshape(
+                    B, T, c.num_attention_heads, c.head_dim)
+                k = (y @ lp["self_attn.k_proj.weight"]).reshape(
+                    B, T, c.num_key_value_heads, c.head_dim)
+                v = (y @ lp["self_attn.v_proj.weight"]).reshape(
+                    B, T, c.num_key_value_heads, c.head_dim)
+                q = _rope_bt(q, cos, sin)
+                k = _rope_bt(k, cos, sin)
+                # prior context from the paged cache + this step's own rows
+                # (causal), one mixed-mode kernel call; the fresh rows are
+                # committed to the cache only at the end of the step.  Under
+                # tp the cache slices kcl/vcl are already this shard's head
+                # planes (the scan carries per-shard storage), q/k/v slice to
+                # the matching head block, and each shard's kernel DMAs only
+                # its own heads' pages; the head-axis all_gather restores the
+                # full [B, T, qh, d] activation for the replicated o_proj
+                if tp > 1:
+                    q_a = jax.lax.dynamic_slice_in_dim(
+                        q, shard * qh_l, qh_l, axis=2)
+                    k_a = jax.lax.dynamic_slice_in_dim(
+                        k, shard * kvh_l, kvh_l, axis=2)
+                    v_a = jax.lax.dynamic_slice_in_dim(
+                        v, shard * kvh_l, kvh_l, axis=2)
+                else:
+                    q_a, k_a, v_a = q, k, v
+                attn = ragged_paged_attention(q_a, kcl, vcl, block_tables,
+                                              ctx_prev, q_lens=ql,
+                                              k_new=k_a, v_new=v_a,
+                                              k_scale=ksl, v_scale=vsl)
+                if tp > 1:
+                    attn = jax.lax.all_gather(attn, MP_AXIS, axis=2,
+                                              tiled=True)
+                x = x + (attn.reshape(B, T, -1)
+                         @ lp["self_attn.o_proj.weight"])
+            with jax.named_scope("moe" if moe else "mlp"):
+                y = rms_norm_fp32(x, lp["post_attention_layernorm.weight"],
+                                  c.rms_norm_eps)
+                if moe:
+                    x = x + _moe_ffn(y, lp, c.moe_top_k,
+                                     dispatch=c.moe_dispatch,
+                                     block_m=c.moe_block_m,
+                                     mp_shards=self._moe_shards)
+                else:
+                    act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
+                        (y @ lp["mlp.up_proj.weight"])
+                    x = x + act @ lp["mlp.down_proj.weight"]
             return (x,), (k, v)
 
         xs = (params["blocks"], kc, vc, ks, vs) if quant else \
@@ -582,16 +597,18 @@ class LlamaGenerator:
                 k_all, shard * kvh_l, kvh_l, axis=2)
             v_all = jax.lax.dynamic_slice_in_dim(
                 v_all, shard * kvh_l, kvh_l, axis=2)
-        if quant:
-            # quantize fresh K/V per page on the way in (page-level RMW:
-            # the absmax scale covers every row of the page)
-            kc, vc, ks, vs = write_kv_pages_all_layers_quantized(
-                kc, vc, ks, vs, k_all, v_all, positions, ql,
-                block_tables, self.max_seq_len)
-            out_cache = (kc, vc, ks, vs)
-        else:
-            kc, vc = write_kv_pages_all_layers(kc, vc, k_all, v_all, slots)
-            out_cache = (kc, vc)
+        with jax.named_scope("attention"), jax.named_scope("kv_write"):
+            if quant:
+                # quantize fresh K/V per page on the way in (page-level
+                # RMW: the absmax scale covers every row of the page)
+                kc, vc, ks, vs = write_kv_pages_all_layers_quantized(
+                    kc, vc, ks, vs, k_all, v_all, positions, ql,
+                    block_tables, self.max_seq_len)
+                out_cache = (kc, vc, ks, vs)
+            else:
+                kc, vc = write_kv_pages_all_layers(kc, vc, k_all, v_all,
+                                                   slots)
+                out_cache = (kc, vc)
 
         h = rms_norm_fp32(h, params["norm"], c.rms_norm_eps)
         return h, out_cache
@@ -633,7 +650,8 @@ class LlamaGenerator:
                                         positions, block_tables)
         last_ix = jnp.maximum(ql - 1, 0)
         last = jnp.take_along_axis(h, last_ix[:, None, None], axis=1)[:, 0]
-        logits = (last @ params["head"]).astype(jnp.float32)
+        with jax.named_scope("head"):
+            logits = (last @ params["head"]).astype(jnp.float32)
         # positional sampling keys: the token being sampled lands at
         # sequence index positions + ql; the chained key never advances
         # (determinism across batch shapes and replicas — see _sample)
@@ -695,7 +713,8 @@ class LlamaGenerator:
         h, cache = self._forward_tokens(params, cache, tokens, ql,
                                         positions, block_tables)
         B = tokens.shape[0]
-        logits = (h @ params["head"]).astype(jnp.float32)      # [B, K, V]
+        with jax.named_scope("head"):
+            logits = (h @ params["head"]).astype(jnp.float32)  # [B, K, V]
         # one positional key per (row, slot): slot j samples the token
         # at sequence index positions + j + 1 — token-level sequential
         # sampling semantics (greedy ignores the keys entirely)
@@ -755,7 +774,8 @@ class LlamaGenerator:
                            0, 1).astype(jnp.int32)
             h, cache = self._forward_tokens(params, cache, tok[:, None],
                                             ql, positions, block_tables)
-            logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
+            with jax.named_scope("head"):
+                logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
             sampled = _sample(logits, key, positions + ql, gc)
             out = jnp.where(ql > 0, sampled, tok)
             positions = positions + ql
@@ -1046,6 +1066,7 @@ class ContinuousBatchingEngine:
         # speculative dispatches — drained together
         self._pending: List[tuple] = []
         self._steps_since_drain = 0
+        self._step_no = 0               # running number of step() calls
         # per-slot hard cap on VALID generated tokens, set when a sequence
         # freezes early (KV pool ran dry mid-decode): the device keeps
         # emitting frozen repeats until the next drain, which trims here
@@ -1119,7 +1140,8 @@ class ContinuousBatchingEngine:
             self.prefix_cache = PrefixCache(
                 self.g.cache.allocator, self.g.page_size,
                 min_pages=flags.flag("prefix_cache_min_pages"))
-            self._cow_jit = self.g.pool_jit(_cow_copy_pages, n_extra=2)
+            self._cow_jit = self.g.pool_jit(_cow_copy_pages, "pool_cow_copy",
+                                            n_extra=2)
             # warm the copy program with an all-no-op call so the first
             # cache hit (and every later one) stays zero-recompile
             none = jnp.full((B,), -1, jnp.int32)
@@ -1219,13 +1241,26 @@ class ContinuousBatchingEngine:
     def step(self) -> List[Request]:
         """Admit what fits, run ONE fused device step, drain every
         ``sync_every`` steps.  Returns requests retired by this call."""
-        t_host0 = time.perf_counter() if _obs.TRACER.enabled else None
-        self._admit()
+        self._step_no += 1
+        with _obs.TRACER.span("engine.step", step=self._step_no,
+                              slots=self.B) as span:
+            return self._step(span)
+
+    def _step(self, span) -> List[Request]:
+        """``step`` inside its ``engine.step`` span.  Each phase of the
+        host's work runs under a span of its own (``catalog.SPANS``), so
+        nothing between two device launches lies outside a named one."""
+        tracer = _obs.TRACER
+        with tracer.span("engine.admit") as sp:
+            admitted = self._admit()
+            sp.set_metadata(admitted=admitted, waiting=len(self.waiting))
         # requests retired by a mid-step emergency drain (pool pressure
         # under speculative overestimate) must still ride this call's
         # return — callers stream completions off it
         early_done: List[Request] = []
         if all(r is None for r in self.slot_req):
+            span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
+                              waiting=len(self.waiting))
             return self._drain() if self._pending else []
         g = self.g
         B = self.B
@@ -1254,12 +1289,152 @@ class ContinuousBatchingEngine:
                     self.host_lens[b] = min(
                         int(self.host_lens[b]) + self.spec.k, g.max_seq_len)
 
-        # grow pages BEFORE the step: every position this step writes must
-        # already be inside the allocated table (prompts are allocated in
-        # full at admission; decode rows may cross a page boundary here)
+        with tracer.span("engine.grow") as sp:
+            grown = self._grow_pages(early_done)
+            sp.set_metadata(pages=grown)
+
+        if spec_lane:
+            # ---- speculative lane: ngram verify / fused K-step ----
+            with tracer.span("engine.h2d") as sp:
+                sp.set_metadata(arrays=self._upload_tables(grown)
+                                + self._upload_caps())
+            rows = sum(r is not None for r in self.slot_req)
+            k = int(self.spec.k)
+            span.set_metadata(kind="spec", T=k, rows=rows, q_tokens=rows * k,
+                              waiting=len(self.waiting))
+            out_mat, ncommit, dlen = self._dispatch_spec()
+            t_step = time.perf_counter()
+            self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
+            if self.attribution is not None:
+                # committed-token counts are device-resident until the
+                # drain; credit_tokens() supplies them there
+                self.attribution.stamp(
+                    "spec_verify" if self.spec.mode == "ngram"
+                    else "fused_k", k, t_step)
+            if self._obs is not None:
+                o = self._obs
+                o.steps.inc()
+                o.occupancy.observe(rows / B)
+                o.queue_depth.observe(len(self.waiting))
+                o.queue_now.set(len(self.waiting))
+            self._steps_since_drain += 1
+            if self._steps_since_drain >= self.g.sync_every:
+                return early_done + self._drain()
+            return early_done
+
+        with tracer.span("engine.build"):
+            ql = np.zeros((B,), np.int32)
+            decode = np.zeros((B,), bool)
+            commit = np.zeros((B,), bool)
+            chunk = np.zeros((B, T), np.int32)
+            rows = 0
+            for b in range(B):
+                req = self.slot_req[b]
+                if req is None:
+                    continue
+                rows += 1
+                if self._gate[b]:
+                    # gated: this row's matched prefix pages are still
+                    # being written by their producer row — idle until
+                    # they're ready
+                    continue
+                rem = len(req.prompt) - int(self.prompt_pos[b])
+                if rem > 0:                      # prefill chunk
+                    n = min(rem, T)
+                    ql[b] = n
+                    chunk[b, :n] = np.asarray(
+                        req.prompt[self.prompt_pos[b]:self.prompt_pos[b] + n],
+                        np.int32)
+                    commit[b] = n == rem         # consumes the final token
+                    self.prompt_pos[b] += n
+                    self.host_lens[b] += n
+                else:                            # decode row
+                    ql[b] = 1
+                    decode[b] = True
+                    commit[b] = True
+                    self.host_lens[b] += 1
+            q_tokens = int(ql.sum())
+
+        with tracer.span("engine.h2d") as sp:
+            sp.set_metadata(arrays=4 + self._upload_tables(grown))
+            tokens_in = jnp.asarray(chunk)
+            dm = jnp.asarray(decode)
+            ql_dev = jnp.asarray(ql)
+            commit_dev = jnp.asarray(commit)
+            if T == 1:
+                tokens_in = jnp.where(dm[:, None], self.tokens[:, None],
+                                      tokens_in)
+            else:
+                tokens_in = tokens_in.at[:, 0].set(
+                    jnp.where(dm, self.tokens, tokens_in[:, 0]))
+
+        # ngram spec engines thread the drafter's recent-token ring
+        # through EVERY step (prefill commits update it too), so the
+        # verify step's context is exact when the row reaches decode
+        track = self.spec is not None and self.spec.mode == "ngram"
+        step = g._step_jit(self.gen_cfg, T, track)
+        span.set_metadata(kind="mixed" if T > 1 else "decode", T=int(T),
+                          rows=rows, q_tokens=q_tokens,
+                          waiting=len(self.waiting))
+        with tracer.span("engine.dispatch", program=step.__name__):
+            if track:
+                (self.tokens, self.positions, self.finished, _all_done,
+                 self.counts, cache, self.key, self._recent) = step(
+                    g.params, g.cache.arrays, tokens_in, ql_dev,
+                    self.positions, self.finished, dm, commit_dev,
+                    self.counts, self.budgets, self._bt_dev, self.key,
+                    self._recent)
+            else:
+                (self.tokens, self.positions, self.finished, _all_done,
+                 self.counts, cache, self.key) = step(
+                    g.params, g.cache.arrays, tokens_in, ql_dev,
+                    self.positions, self.finished, dm, commit_dev,
+                    self.counts, self.budgets, self._bt_dev, self.key)
+            g.cache.update(*cache)
+        # host dispatch timestamp rides the pending window: the drain
+        # stamps TTFT/ITL per committed token from it — dispatch-side
+        # wall clock, no device sync
+        t_step = time.perf_counter()
+        self._pending.append(("step", self.tokens, commit, None, t_step))
+        if self.attribution is not None:
+            # a mixed step (prefill chunks in flight) is the prefill-
+            # chunk program shape; T=1 is pure decode.  Tokens = query
+            # tokens this dispatch processed (prompt chunk + decode cols)
+            self.attribution.stamp("prefill" if T > 1 else "decode",
+                                   int(T), t_step, q_tokens)
+        if self._obs is not None:
+            o = self._obs
+            o.steps.inc()
+            o.occupancy.observe(rows / B)
+            o.queue_depth.observe(len(self.waiting))
+            o.queue_now.set(len(self.waiting))
+            n_prefill = q_tokens - int(decode.sum())
+            if n_prefill:
+                o.prefill_tokens.inc(n_prefill)
+        if self.prefix_cache is not None:
+            # this step's prefill writes are now dispatched: pages wholly
+            # below each row's prompt cursor are safe for later steps of
+            # other rows to read (device execution is dispatch-ordered)
+            for b in range(B):
+                req = self.slot_req[b]
+                if req is not None and ql[b] > 0 and not decode[b]:
+                    self.prefix_cache.note_progress(
+                        req.req_id, int(self.prompt_pos[b]))
+        self._steps_since_drain += 1
+        if self._steps_since_drain >= self.g.sync_every:
+            return early_done + self._drain()
+        return early_done
+
+    def _grow_pages(self, early_done: List[Request]) -> int:
+        """Grow pages BEFORE the step: every position this step writes
+        must already be inside the allocated table (prompts are allocated
+        in full at admission; decode rows may cross a page boundary
+        here).  Returns the pages allocated; requests an emergency drain
+        retired on the way are appended to ``early_done``."""
+        g = self.g
         alloc = g.cache.allocator
-        grew = False
-        for b in range(B):
+        grown = 0
+        for b in range(self.B):
             req = self.slot_req[b]
             if req is None or self.prompt_pos[b] < len(req.prompt):
                 continue
@@ -1298,131 +1473,17 @@ class ContinuousBatchingEngine:
                                  - alloc.context_len(req.req_id)))
                 self._bt[b] = alloc.block_table(
                     [req.req_id], max_pages=g.pages_per_seq)[0]
-                grew = True
-        if grew:
-            self._bt_dev = jnp.asarray(self._bt)
-            self._caps_dirty = True
+                grown += 1
+        return grown
 
-        if spec_lane:
-            # ---- speculative lane: ngram verify / fused K-step ----
-            out_mat, ncommit, dlen = self._dispatch_spec()
-            t_step = time.perf_counter()
-            self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
-            if self.attribution is not None:
-                # committed-token counts are device-resident until the
-                # drain; credit_tokens() supplies them there
-                self.attribution.stamp(
-                    "spec_verify" if self.spec.mode == "ngram"
-                    else "fused_k", int(self.spec.k), t_step)
-            if self._obs is not None:
-                o = self._obs
-                o.steps.inc()
-                o.occupancy.observe(
-                    sum(r is not None for r in self.slot_req) / B)
-                o.queue_depth.observe(len(self.waiting))
-                o.queue_now.set(len(self.waiting))
-            if t_host0 is not None:
-                _obs.TRACER.event("engine.step", t_host0, t_step - t_host0,
-                                  cat="serving", tid="engine",
-                                  args={"T": int(self.spec.k),
-                                        "spec": self.spec.mode})
-            self._steps_since_drain += 1
-            if self._steps_since_drain >= self.g.sync_every:
-                return early_done + self._drain()
-            return early_done
-
-        ql = np.zeros((B,), np.int32)
-        decode = np.zeros((B,), bool)
-        commit = np.zeros((B,), bool)
-        chunk = np.zeros((B, T), np.int32)
-        for b in range(B):
-            req = self.slot_req[b]
-            if req is None or self._gate[b]:
-                # gated: this row's matched prefix pages are still being
-                # written by their producer row — idle until they're ready
-                continue
-            rem = len(req.prompt) - int(self.prompt_pos[b])
-            if rem > 0:                      # prefill chunk
-                n = min(rem, T)
-                ql[b] = n
-                chunk[b, :n] = np.asarray(
-                    req.prompt[self.prompt_pos[b]:self.prompt_pos[b] + n],
-                    np.int32)
-                commit[b] = n == rem         # consumes the final token
-                self.prompt_pos[b] += n
-                self.host_lens[b] += n
-            else:                            # decode row
-                ql[b] = 1
-                decode[b] = True
-                commit[b] = True
-                self.host_lens[b] += 1
-
-        tokens_in = jnp.asarray(chunk)
-        dm = jnp.asarray(decode)
-        if T == 1:
-            tokens_in = jnp.where(dm[:, None], self.tokens[:, None],
-                                  tokens_in)
-        else:
-            tokens_in = tokens_in.at[:, 0].set(
-                jnp.where(dm, self.tokens, tokens_in[:, 0]))
-
-        # ngram spec engines thread the drafter's recent-token ring
-        # through EVERY step (prefill commits update it too), so the
-        # verify step's context is exact when the row reaches decode
-        track = self.spec is not None and self.spec.mode == "ngram"
-        step = g._step_jit(self.gen_cfg, T, track)
-        if track:
-            (self.tokens, self.positions, self.finished, _all_done,
-             self.counts, cache, self.key, self._recent) = step(
-                g.params, g.cache.arrays, tokens_in, jnp.asarray(ql),
-                self.positions, self.finished, dm, jnp.asarray(commit),
-                self.counts, self.budgets, self._bt_dev, self.key,
-                self._recent)
-        else:
-            (self.tokens, self.positions, self.finished, _all_done,
-             self.counts, cache, self.key) = step(
-                g.params, g.cache.arrays, tokens_in, jnp.asarray(ql),
-                self.positions, self.finished, dm, jnp.asarray(commit),
-                self.counts, self.budgets, self._bt_dev, self.key)
-        g.cache.update(*cache)
-        # host dispatch timestamp rides the pending window: the drain
-        # stamps TTFT/ITL per committed token from it — dispatch-side
-        # wall clock, no device sync
-        t_step = time.perf_counter()
-        self._pending.append(("step", self.tokens, commit, None, t_step))
-        if self.attribution is not None:
-            # a mixed step (prefill chunks in flight) is the prefill-
-            # chunk program shape; T=1 is pure decode.  Tokens = query
-            # tokens this dispatch processed (prompt chunk + decode cols)
-            self.attribution.stamp("prefill" if T > 1 else "decode",
-                                   int(T), t_step, int(ql.sum()))
-        if self._obs is not None:
-            o = self._obs
-            o.steps.inc()
-            o.occupancy.observe(
-                sum(r is not None for r in self.slot_req) / B)
-            o.queue_depth.observe(len(self.waiting))
-            o.queue_now.set(len(self.waiting))
-            n_prefill = int(ql.sum()) - int(decode.sum())
-            if n_prefill:
-                o.prefill_tokens.inc(n_prefill)
-        if t_host0 is not None:
-            _obs.TRACER.event("engine.step", t_host0, t_step - t_host0,
-                              cat="serving", tid="engine",
-                              args={"T": int(T)})
-        if self.prefix_cache is not None:
-            # this step's prefill writes are now dispatched: pages wholly
-            # below each row's prompt cursor are safe for later steps of
-            # other rows to read (device execution is dispatch-ordered)
-            for b in range(B):
-                req = self.slot_req[b]
-                if req is not None and ql[b] > 0 and not decode[b]:
-                    self.prefix_cache.note_progress(
-                        req.req_id, int(self.prompt_pos[b]))
-        self._steps_since_drain += 1
-        if self._steps_since_drain >= self.g.sync_every:
-            return early_done + self._drain()
-        return early_done
+    def _upload_tables(self, grown: int) -> int:
+        """Upload the block table when this step grew it, and mark the
+        speculative lane's write caps stale; the arrays uploaded."""
+        if not grown:
+            return 0
+        self._bt_dev = jnp.asarray(self._bt)
+        self._caps_dirty = True
+        return 1
 
     # ---- prefix-cache gates: rows waiting on producer prefill ----
     def _open_gates(self):
@@ -1442,8 +1503,10 @@ class ContinuousBatchingEngine:
             dst = np.full((self.B,), -1, np.int32)
             for i, (s, d) in enumerate(starting):
                 src[i], dst[i] = s, d
-            self.g.cache.update(*self._cow_jit(
-                self.g.cache.arrays, jnp.asarray(src), jnp.asarray(dst)))
+            with _obs.TRACER.span("engine.dispatch",
+                                  program=self._cow_jit.__name__):
+                self.g.cache.update(*self._cow_jit(
+                    self.g.cache.arrays, jnp.asarray(src), jnp.asarray(dst)))
             if self.attribution is not None:
                 self.attribution.stamp("cow_copy", 0)
 
@@ -1460,38 +1523,47 @@ class ContinuousBatchingEngine:
         """
         g = self.g
         spec = self.spec
-        # per-row write caps: tokens the block table actually covers —
-        # the device clamps ql against them, so a step can NEVER scatter
-        # into pages the row does not own (pad entries point at page 0).
-        # Cached: only an allocation/truncation/admission refreshes it
-        if self._caps_dirty:
-            alloc = g.cache.allocator
-            caps = np.zeros((self.B,), np.int32)
-            for b in range(self.B):
-                req = self.slot_req[b]
-                if req is not None:
-                    caps[b] = alloc.context_len(req.req_id)
-            self._caps_dev = jnp.asarray(caps)
-            self._caps_dirty = False
-        write_caps = self._caps_dev
         if spec.mode == "ngram":
             hist, hist_len = self._hist.device_arrays()
             step = g._spec_jit(self.gen_cfg, spec.k, spec.ngram_max)
-            (out, ncommit, dlen, self.tokens, self.positions, self.finished,
-             _all_done, self.counts, self._recent, cache, self.key) = step(
-                g.params, g.cache.arrays, self.tokens, self._recent, hist,
-                hist_len, self.positions, self.finished, self.counts,
-                self.budgets, write_caps, self._bt_dev, self.key)
+            with _obs.TRACER.span("engine.dispatch", program=step.__name__):
+                (out, ncommit, dlen, self.tokens, self.positions,
+                 self.finished, _all_done, self.counts, self._recent, cache,
+                 self.key) = step(
+                    g.params, g.cache.arrays, self.tokens, self._recent,
+                    hist, hist_len, self.positions, self.finished,
+                    self.counts, self.budgets, self._caps_dev,
+                    self._bt_dev, self.key)
+                g.cache.update(*cache)
         else:
             step = g._fused_jit(self.gen_cfg, spec.k)
-            (out, ncommit, self.tokens, self.positions, self.finished,
-             _all_done, self.counts, cache, self.key) = step(
-                g.params, g.cache.arrays, self.tokens, self.positions,
-                self.finished, self.counts, self.budgets, write_caps,
-                self._bt_dev, self.key)
+            with _obs.TRACER.span("engine.dispatch", program=step.__name__):
+                (out, ncommit, self.tokens, self.positions, self.finished,
+                 _all_done, self.counts, cache, self.key) = step(
+                    g.params, g.cache.arrays, self.tokens, self.positions,
+                    self.finished, self.counts, self.budgets,
+                    self._caps_dev, self._bt_dev, self.key)
+                g.cache.update(*cache)
             dlen = None
-        g.cache.update(*cache)
         return out, ncommit, dlen
+
+    def _upload_caps(self) -> int:
+        """Per-row write caps of the speculative lane: tokens the block
+        table actually covers — the device clamps ql against them, so a
+        step can NEVER scatter into pages the row does not own (pad
+        entries point at page 0).  Cached: only an allocation /
+        truncation / admission refreshes it; the arrays uploaded."""
+        if not self._caps_dirty:
+            return 0
+        alloc = self.g.cache.allocator
+        caps = np.zeros((self.B,), np.int32)
+        for b in range(self.B):
+            req = self.slot_req[b]
+            if req is not None:
+                caps[b] = alloc.context_len(req.req_id)
+        self._caps_dev = jnp.asarray(caps)
+        self._caps_dirty = False
+        return 1
 
     # ---- serving telemetry ----
     def stats(self) -> dict:
@@ -1614,10 +1686,18 @@ class ContinuousBatchingEngine:
 
     # ---- drain: the ONLY host<->device sync of the steady state ----
     def _drain(self) -> List[Request]:
-        done: List[Request] = []
         if not self._pending:
             self._steps_since_drain = 0
-            return done
+            return []
+        with _obs.TRACER.span("engine.drain",
+                              steps=len(self._pending)) as span:
+            done, n_tokens = self._drain_pending()
+            span.set_metadata(tokens=n_tokens)
+        return done
+
+    def _drain_pending(self) -> tuple:
+        """The pending window to the host and into its requests: (requests
+        retired, tokens delivered)."""
         # per-array host transfers, NOT a device-side stack: the pending
         # window length varies (partial windows at tail/run end) and a
         # jnp.stack would compile one executable per distinct length —
@@ -1628,9 +1708,11 @@ class ContinuousBatchingEngine:
         if obs is not None:
             obs.drains.inc()
             _obs.count_sync()        # the window's host<->device transfer
-        window = [(kind, np.asarray(out), np.asarray(cm),
-                   None if dl is None else np.asarray(dl), t)
-                  for kind, out, cm, dl, t in self._pending]
+        with _obs.TRACER.span("engine.drain.wait"):
+            window = [(kind, np.asarray(out), np.asarray(cm),
+                       None if dl is None else np.asarray(dl), t)
+                      for kind, out, cm, dl, t in self._pending]
+            fin = np.asarray(self.finished)
         # the moment this window's tokens became visible to the host —
         # the only progress of the device the host can observe
         t_ready = time.perf_counter()
@@ -1642,7 +1724,29 @@ class ContinuousBatchingEngine:
             # against the drain's entry time) AFTER the spec token
             # credits landed in _fold_spec_metrics
             attr.fold(t_drain0)
-        fin = np.asarray(self.finished)
+        with _obs.TRACER.span("engine.drain.retire") as span:
+            done, n_tokens, bt_dirty = self._retire_rows(window, fin,
+                                                         t_ready)
+            span.set_metadata(retired=len(done))
+        if bt_dirty:
+            self._bt_dev = jnp.asarray(self._bt)
+            self._caps_dirty = True
+        self.last_stats = self.stats()
+        if obs is not None:
+            obs.update_pool(self.last_stats)
+        if attr is not None:
+            # the drain IS a phase: the steady state's one blocking
+            # host<->device transfer plus retire bookkeeping
+            attr.observe_host("drain", time.perf_counter() - t_drain0)
+        return done, n_tokens
+
+    def _retire_rows(self, window, fin, t_ready: float) -> tuple:
+        """Per-row bookkeeping of a drained window: tokens into their
+        requests, latency stamps, trims, retirement.  Returns (requests
+        retired, tokens delivered, whether a block-table row changed)."""
+        obs = self._obs
+        done: List[Request] = []
+        n_tokens = 0
         alloc = self.g.cache.allocator
         eos = self.gen_cfg.eos_token_id
         bt_dirty = False
@@ -1667,6 +1771,7 @@ class ContinuousBatchingEngine:
                         new_tok.append(int(v))
                         tok_ts.append(t)
             req.output.extend(new_tok)
+            n_tokens += len(new_tok)
             if obs is not None:
                 # TTFT/ITL are stamped HERE, when the tokens reach the
                 # host: the host runs sync_every dispatches ahead and
@@ -1774,17 +1879,7 @@ class ContinuousBatchingEngine:
             self.finished = self.finished.at[b].set(True)
             self.completed[req.req_id] = req.output
             done.append(req)
-        if bt_dirty:
-            self._bt_dev = jnp.asarray(self._bt)
-            self._caps_dirty = True
-        self.last_stats = self.stats()
-        if obs is not None:
-            obs.update_pool(self.last_stats)
-        if attr is not None:
-            # the drain IS a phase: the steady state's one blocking
-            # host<->device transfer plus retire bookkeeping
-            attr.observe_host("drain", time.perf_counter() - t_drain0)
-        return done
+        return done, n_tokens, bt_dirty
 
     def _fold_spec_metrics(self, window) -> None:
         """Fold the window's speculative telemetry into the engine books
@@ -1855,10 +1950,11 @@ class ContinuousBatchingEngine:
         return False
 
     # ---- admission (host-known free slots only; frees appear at drains) ----
-    def _admit(self):
+    def _admit(self) -> int:
+        """Waiting requests into free slots; how many were admitted."""
         free = [b for b in range(self.B) if self.slot_req[b] is None]
         if not free or not self.waiting:
-            return
+            return 0
         g = self.g
         alloc = g.cache.allocator
         cache = self.prefix_cache
@@ -1928,7 +2024,7 @@ class ContinuousBatchingEngine:
                 self._cow_pairs[b] = []
             admitted.append((b, req))
         if not admitted:
-            return
+            return 0
         mask = np.zeros((self.B,), bool)
         budgets = self._budgets_np
         if self._obs is not None:
@@ -1968,3 +2064,4 @@ class ContinuousBatchingEngine:
                 rec_np[b] = _sp.recent_window(req.prompt, nmax)
             self._recent = jnp.where(m[:, None], jnp.asarray(rec_np),
                                      self._recent)
+        return len(admitted)

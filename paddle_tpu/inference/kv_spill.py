@@ -69,7 +69,8 @@ def make_upload_program(cache):
     migration import and warmup all share this one program, so swap-in
     bytes and compile counts are identical at any shard count."""
     if getattr(cache, "mesh", None) is None:
-        return jax.jit(_upload_page, donate_argnums=(0,))
+        return jax.jit(_obs.tracing.named(_upload_page, "pool_swap_in"),
+                       donate_argnums=(0,))
     axis = cache.axis
 
     def _sharded(pool, page, host):
@@ -83,11 +84,11 @@ def make_upload_program(cache):
     from jax.sharding import PartitionSpec
     rep = PartitionSpec()
     cspec = cache.pspecs
-    return jax.jit(
-        jax.shard_map(_sharded, mesh=cache.mesh,
-                      in_specs=(cspec, rep, rep), out_specs=cspec,
-                      check_vma=False),   # as the engine step: see _tp_jit
-        donate_argnums=(0,))
+    sharded = jax.shard_map(
+        _sharded, mesh=cache.mesh, in_specs=(cspec, rep, rep),
+        out_specs=cspec, check_vma=False)   # as the engine step: see _tp_jit
+    return jax.jit(_obs.tracing.named(sharded, "pool_swap_in"),
+                   donate_argnums=(0,))
 
 
 class HostSpillPool:

@@ -522,6 +522,7 @@ def _fwd_call(kernel, b, hq, sq, sk, d, blocks, in_specs, qmap, q, k, v,
     qf, kf, vf = _heads_first(q), _heads_first(k), _heads_first(v)
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(b, hq, sq // block_q, sk // block_kv),
         in_specs=in_specs,
         out_specs=[
@@ -576,6 +577,7 @@ def _fa_pallas_backward(q, k, v, out, lse, g, causal, mask, seg_q, seg_k,
     ] + in_specs[3:]
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, **common),
+        name="flash_dq",
         grid=(b, hq, sq // block_q, sk // block_kv),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((None, None, block_q, d), qmap),
@@ -596,6 +598,7 @@ def _fa_pallas_backward(q, k, v, out, lse, g, causal, mask, seg_q, seg_k,
     outmap = lambda bb, h, kv, jq: (bb, h, kv, _I0)
     dk_p, dv_p = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, **common),
+        name="flash_dkv",
         grid=(b, hq, sk // block_kv, sq // block_q),
         in_specs=dkv_specs,
         out_specs=[pl.BlockSpec((None, None, block_kv, d), outmap),

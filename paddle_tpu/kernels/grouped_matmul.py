@@ -335,6 +335,7 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
                                bm=bm, bk=bk, fused=fused, scaled=scaled)
     return pl.pallas_call(
         kernel,
+        name="gmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, O), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -489,6 +490,7 @@ def tgmm(lhs, rhs, tile_groups, num_groups, *, bm=512, bn=None, bk=None,
                                rscaled=rscaled)
     out = pl.pallas_call(
         kernel,
+        name="tgmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_groups, K, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(

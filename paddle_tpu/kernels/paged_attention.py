@@ -380,6 +380,7 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, kvh, R, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kvh, R, 1), jnp.float32)],

@@ -752,8 +752,12 @@ class LlamaDecoderLayer(Layer):
         self._config = config
 
     def forward(self, hidden, cos, sin):
-        h = hidden + self.self_attn(self.input_layernorm(hidden), cos, sin)
-        return h + self.mlp(self.post_attention_layernorm(h))
+        with jax.named_scope("attention"):
+            h = hidden + self.self_attn(self.input_layernorm(hidden),
+                                        cos, sin)
+        with jax.named_scope("moe" if self._config.moe_num_experts
+                             else "mlp"):
+            return h + self.mlp(self.post_attention_layernorm(h))
 
 
 class LlamaModel(Layer):

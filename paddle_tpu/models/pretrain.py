@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import observability as _obs
 from ..core.tensor import Tensor
 from ..distributed.pipeline_spmd import (interleave_chunk_order,
                                          pipeline_1f1b_grads,
@@ -276,7 +277,6 @@ class PretrainStep:
         # grad-comm bytes land in the observability registry (train.*)
         # with ZERO added device syncs (timing reads ride the caller's
         # existing host drain); FLAGS_metrics=0 disables entirely
-        from .. import observability as _obs
         self._telemetry = _obs.StepTimer("train") \
             if _obs.metrics_enabled() else None
         self._grad_sync_bytes: Optional[int] = None
@@ -404,19 +404,23 @@ class PretrainStep:
         return self._logits(params, ids)
 
     def _forward_loss(self, params, ids, labels):
+        h, aux = self._hidden(params, ids)
+        return self._head_loss(params, h, labels) + aux
+
+    @jax.named_scope("head_loss")
+    def _head_loss(self, params, h, labels):
+        """Mean cross-entropy of the head's logits over ``h``."""
         C = self.pc.loss_chunks
         if C <= 1:
-            h, aux = self._hidden(params, ids)
             logits = (h @ params["head"]).astype(jnp.float32)
             logits = jax.lax.with_sharding_constraint(
                 logits, NamedSharding(self.mesh, P("dp", None, "mp")))
             lse = jax.scipy.special.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(logits, labels[..., None],
                                        axis=-1)[..., 0]
-            return (lse - gold).mean() + aux
+            return (lse - gold).mean()
         # chunked CE: head matmul + logsumexp per token chunk under remat, so
         # peak memory holds one [N/C, V] fp32 block instead of [B, T, V]
-        h, aux = self._hidden(params, ids)
         H = h.shape[-1]
         hf = h.reshape(-1, H)
         lf = labels.reshape(-1)
@@ -436,7 +440,7 @@ class PretrainStep:
             return (lse - gold).sum()
 
         total = jax.lax.map(chunk_loss, (hc, lc)).sum()
-        return total / N + aux
+        return total / N
 
     def _logits(self, params, ids):
         h, _ = self._hidden(params, ids)
@@ -771,6 +775,7 @@ class PretrainStep:
         )(params, ids, labels, step, ef)
 
     # ---- adamw ----
+    @jax.named_scope("optimizer")
     def _update(self, state, grads):
         b1, b2, eps, lr, wd = self.b1, self.b2, self.eps, self.lr, self.wd
         step = state["step"] + 1
@@ -805,7 +810,7 @@ class PretrainStep:
         if self._jit_step is not None:
             return self._jit_step
         if self.pc.grad_comm != "auto":
-            def step(state, ids, labels):
+            def pretrain_step(state, ids, labels):
                 loss, grads, new_ef = self._loss_and_grads_ring(
                     state["params"], ids, labels, state["step"],
                     state.get("ef", {}))
@@ -815,12 +820,12 @@ class PretrainStep:
                     new_state["ef"] = new_ef
                 return new_state, loss
         elif self.pc.schedule in ("1f1b", "zbh1", "zbvpp"):
-            def step(state, ids, labels):
+            def pretrain_step(state, ids, labels):
                 loss, grads = self._loss_and_grads_1f1b(
                     state["params"], ids, labels)
                 return self._update(state, grads), loss
         else:
-            def step(state, ids, labels):
+            def pretrain_step(state, ids, labels):
                 loss, grads = jax.value_and_grad(
                     lambda p: self._forward_loss(p, ids, labels))(state["params"])
                 return self._update(state, grads), loss
@@ -833,7 +838,7 @@ class PretrainStep:
         # short-window bench reads it as throughput)
         sh = jax.tree_util.tree_map(lambda a: a.sharding, state)
         self._jit_step = jax.jit(
-            step, donate_argnums=(0,),
+            pretrain_step, donate_argnums=(0,),
             in_shardings=(sh, ids.sharding, labels.sharding),
             out_shardings=(sh, None))
         return self._jit_step
@@ -848,24 +853,31 @@ class PretrainStep:
             state, ids, labels)
 
     def train_step(self, state, ids, labels):
-        if self._telemetry is not None:
-            self._telemetry.begin_step()
-        if not (isinstance(ids, jax.Array) and isinstance(labels, jax.Array)):
-            # raw host arrays (either of them): place both on the mesh
-            ids, labels = self.shard_batch(np.asarray(ids),
-                                           np.asarray(labels))
-        out = self._jitted_step(state, ids, labels)(state, ids, labels)
-        if self._telemetry is not None:
-            if self._grad_sync_bytes is None:
-                try:    # analytic per-step dp gradient-sync traffic
-                    self._grad_sync_bytes = self.grad_sync_bytes() \
-                        if self.pc.dp > 1 else 0
-                except Exception:
-                    self._grad_sync_bytes = 0
-            self._telemetry.tick(
-                tokens=int(ids.shape[0]) * int(ids.shape[1]),
-                comm_bytes=self._grad_sync_bytes)
-        return out
+        tracer = _obs.TRACER
+        b, t = np.shape(ids)[:2]
+        tokens = int(b) * int(t)
+        with tracer.span("train.step", tokens=tokens):
+            if self._telemetry is not None:
+                self._telemetry.begin_step()
+            if not (isinstance(ids, jax.Array)
+                    and isinstance(labels, jax.Array)):
+                # raw host arrays (either of them): place both on the mesh
+                with tracer.span("train.shard_batch"):
+                    ids, labels = self.shard_batch(np.asarray(ids),
+                                                   np.asarray(labels))
+            step = self._jitted_step(state, ids, labels)
+            with tracer.span("train.dispatch"):
+                out = step(state, ids, labels)
+            if self._telemetry is not None:
+                if self._grad_sync_bytes is None:
+                    try:    # analytic per-step dp gradient-sync traffic
+                        self._grad_sync_bytes = self.grad_sync_bytes() \
+                            if self.pc.dp > 1 else 0
+                    except Exception:
+                        self._grad_sync_bytes = 0
+                self._telemetry.tick(tokens=tokens,
+                                     comm_bytes=self._grad_sync_bytes)
+            return out
 
     def eval_loss(self, state, ids, labels):
         return self._forward_loss(state["params"], ids, labels)

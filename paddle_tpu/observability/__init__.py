@@ -207,8 +207,4 @@ class StepTimer:
             self._step_ms.observe(dt * 1e3)
             if tokens and dt > 0:
                 self._tps.set(tokens / dt)
-            if TRACER.enabled:
-                TRACER.event(f"{self.name}.step", self._last, dt,
-                             cat="train", tid=self.name,
-                             args={"tokens": tokens})
         self._last = now

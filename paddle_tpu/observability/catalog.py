@@ -10,6 +10,10 @@ review nitpick (ISSUE 10 satellite).
 
 Keep entries in the family's home module order; the generator groups by
 dotted prefix.
+
+``SPANS`` is the same for the tracer's live spans: the closed vocabulary
+of ``Tracer.span`` names with the arguments each carries, generated into
+the same file and held by the same kind of drift test.
 """
 
 from __future__ import annotations
@@ -18,7 +22,8 @@ from typing import Dict, Optional
 
 from . import metrics as _metrics
 
-__all__ = ["CATALOG", "undocumented", "generate_markdown", "apply_help"]
+__all__ = ["CATALOG", "SPANS", "undocumented", "generate_markdown",
+           "apply_help"]
 
 # family -> (kind, labels, meaning)
 CATALOG: Dict[str, tuple] = {
@@ -463,6 +468,85 @@ CATALOG: Dict[str, tuple] = {
 }
 
 
+# live span -> (category, lane, sinks, arguments, where it is recorded).
+# Every span is in the profiler's trace while a session runs.  ``sinks``
+# says how far it goes in the tracer's own: "fleet" = ring, Chrome buffer
+# and the fleet exporter (the two whole-step spans only: what the
+# collector gets of the ``engine`` and ``train`` lanes must not grow with
+# the phases); "local" = ring and Chrome buffer; "profiler" = neither (the
+# serving loop's own spans recur at the poll rate while a server idles and
+# would flush the flight recorder's ring).  Arguments are values the site
+# already has at that boundary: a span costs no read of an array or of
+# the device.
+SPANS: Dict[str, tuple] = {
+    "engine.step": (
+        "serving", "engine", "fleet",
+        "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, slots, "
+        "waiting",
+        "one `ContinuousBatchingEngine.step` call, whole: `step` its "
+        "running number, `T` the program's query bucket (K in the "
+        "speculative lane, 0 when nothing was dispatched), `rows` the "
+        "slots with work, `q_tokens` the query tokens they hold (in the "
+        "speculative lane rows x K, what the dispatch may verify), "
+        "`slots` the batch B, `waiting` the queue behind it"),
+    "engine.admit": (
+        "serving", "engine", "local", "admitted, waiting",
+        "`_admit`: waiting requests into free slots, their pages and the "
+        "uploads of the new rows' state"),
+    "engine.grow": (
+        "serving", "engine", "local", "pages",
+        "the page-growth loop before a dispatch (`pages` allocated)"),
+    "engine.build": (
+        "serving", "engine", "local", "",
+        "the per-row loop that fills the step's `ql`, `decode`, `commit` "
+        "and `chunk`"),
+    "engine.h2d": (
+        "serving", "engine", "local", "arrays",
+        "host-to-device uploads of the step's inputs and the `tokens_in` "
+        "composition; the block table (and the speculative lane's write "
+        "caps) when they changed"),
+    "engine.dispatch": (
+        "serving", "engine", "local", "program",
+        "the call of a jitted program: `serve_step_T<bucket>`, "
+        "`serve_spec_verify_K<k>`, `serve_fused_K<k>`, `pool_cow_copy`"),
+    "engine.drain": (
+        "serving", "engine", "local", "steps, tokens",
+        "`_drain`: `steps` dispatches closed, `tokens` delivered to "
+        "their requests"),
+    "engine.drain.wait": (
+        "serving", "engine", "local", "",
+        "the `np.asarray` block of the drain: the only place the host "
+        "waits for the device"),
+    "engine.drain.retire": (
+        "serving", "engine", "local", "retired",
+        "the per-row bookkeeping after the wait (`retired` requests "
+        "finished)"),
+    "serve.intake": (
+        "serving", "engine", "profiler", "",
+        "`ServingServer._engine_loop`: the inbox sweep, control "
+        "operations and queue-expiry shedding"),
+    "serve.publish": (
+        "serving", "engine", "local", "tokens, streams",
+        "`_publish`: fresh tokens pushed to their streams"),
+    "serve.idle": (
+        "serving", "engine", "profiler", "",
+        "the engine thread waiting for work (`_wake.wait`)"),
+    "serve.housekeeping": (
+        "serving", "engine", "profiler", "",
+        "flight-recorder snapshot and sentinel check between steps"),
+    "train.step": (
+        "train", "train", "fleet", "tokens",
+        "one `PretrainStep.train_step` call, whole"),
+    "train.shard_batch": (
+        "train", "train", "local", "",
+        "placing a host batch on the mesh (only when the caller passed "
+        "host arrays)"),
+    "train.dispatch": (
+        "train", "train", "local", "",
+        "the call of the jitted `pretrain_step` program"),
+}
+
+
 def undocumented(families: Optional[Dict[str, str]] = None) -> list:
     """Families present in the registry but missing from the catalog.
     ``train.*``-shaped StepTimer families with custom names are the
@@ -507,6 +591,21 @@ def generate_markdown() -> str:
         for name, kind, labels, help_text in groups[prefix]:
             lbl = f"`{labels}`" if labels else "—"
             lines.append(f"| `{name}` | {kind} | {lbl} | {help_text} |")
+    lines += [
+        "", "## Live spans", "",
+        "The closed vocabulary of `Tracer.span` (`SPANS` in the same",
+        "file).  Each is a `jax.profiler.TraceAnnotation`, so a profiler",
+        "session finds it in the `.xplane.pb` beside the device",
+        "operations, with its arguments as the event's stats; with the",
+        "tracer's own sinks on it is also a Chrome event on its lane.",
+        "`sinks`: `fleet` = flight-recorder ring, Chrome buffer and the",
+        "fleet exporter; `local` = ring and buffer; `profiler` = the",
+        "profiler's trace only.",
+        "", "| span | lane | sinks | arguments | where |",
+        "|---|---|---|---|---|"]
+    for name, (_cat, lane, sinks, args, where) in SPANS.items():
+        lines.append(f"| `{name}` | {lane} | {sinks} "
+                     f"| {'`' + args + '`' if args else '—'} | {where} |")
     return "\n".join(lines) + "\n"
 
 
@@ -514,7 +613,7 @@ def main() -> int:
     import pathlib
     out = pathlib.Path(__file__).resolve().parents[2] / "docs/metrics.md"
     out.write_text(generate_markdown())
-    print(f"wrote {out} ({len(CATALOG)} families)")
+    print(f"wrote {out} ({len(CATALOG)} families, {len(SPANS)} spans)")
     return 0
 
 

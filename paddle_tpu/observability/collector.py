@@ -363,7 +363,7 @@ class SpanExporter:
                 continue                     # lane map ships separately
             lane = lanes.get(ev.get("tid"))
             if lane is None:
-                # unnamed lane (thread-ident / counter tracks): process-
+                # unnamed lane (a thread's own, by its ident): process-
                 # local unless the event itself is a keep marker (the
                 # sentinel's anomaly instants must reach the collector)
                 if not _keep_event(ev):

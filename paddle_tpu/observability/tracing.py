@@ -1,33 +1,45 @@
-"""Span-based host tracer with Chrome-trace / perfetto JSON export.
+"""Span tracer: one span API, two sinks.
 
-The timeline half of the observability runtime: host spans (engine steps,
-profiler RecordEvents, retroactive per-request serving lifecycles) land in
-one in-memory event buffer exported as the Chrome ``traceEvents`` JSON that
-chrome://tracing and https://ui.perfetto.dev load directly.  Device
-timelines stay jax.profiler's job (XPlane/perfetto); ``device_trace``
-wraps ``jax.profiler.start_trace``/``stop_trace`` so a harness can capture
-both views of the same run side by side.
+``Tracer.span(name, **args)`` is the one way a live span is recorded.  It
+always enters a ``jax.profiler.TraceAnnotation``, which records only while
+a profiler session runs: whoever runs the JAX profiler (a benchmark's
+traced run, an operator, ``profiler.Profiler``) finds the program's spans
+in the same ``.xplane.pb`` as the device operations, on one clock, nested
+by time on the thread that ran them.  When the tracer's own sinks are on
+(``start``, a flight-recorder ring, a fleet export sink) the span is also
+appended, as a Chrome "X" event, to the in-memory buffer exported as the
+``traceEvents`` JSON that chrome://tracing and https://ui.perfetto.dev
+load.  Retroactive events (per-request serving lifecycles, stamped at the
+drain from saved timestamps) reach the Chrome sinks only: the profiler's
+trace takes no event after the fact.
 
-Disabled (the default) the tracer is one attribute check per
-instrumentation site — nothing allocates.  Enabled, each span is one
-buffer append; the buffer is capped (``FLAGS_trace_max_events``) and the
-overflow count is reported in the exported file's metadata rather than
-silently dropped.
+Live span names are a closed vocabulary: ``catalog.SPANS`` (generated into
+``docs/metrics.md``) gives each its lane, its arguments, and whether the
+fleet exporter ships it.
+
+With no profiler session and the sinks off (the default) a span costs one
+``TraceAnnotation`` (under a microsecond) and nothing allocates in the
+tracer.  With the sinks on each span is one buffer append more; the buffer
+is capped (``FLAGS_trace_max_events``) and the overflow count is reported
+in the exported file's metadata rather than silently dropped.
 """
 
 from __future__ import annotations
 
-import contextlib
+import functools
 import json
 import os
 import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .. import flags
 from . import metrics as _metrics
+from .catalog import SPANS
 
-__all__ = ["Tracer", "TRACER", "device_tracing_available"]
+__all__ = ["Tracer", "TRACER", "device_tracing_available", "named"]
 
 # process-wide visibility for FLAGS_trace_max_events overflow (ISSUE 6
 # satellite): dropping a span is telemetry too — a flat buffer cap no
@@ -39,14 +51,56 @@ def device_tracing_available() -> bool:
     """True when a jax device trace may start: the backend is not CPU.
     The env probe short-circuits before any backend initialization, so
     the CPU tier-1 suite (JAX_PLATFORMS=cpu) never pays for — or
-    pollutes — a device-trace attempt.  The ONE guard shared by
-    ``Tracer.device_trace`` and ``profiler.Profiler``."""
+    pollutes — a device-trace attempt (``profiler.Profiler``'s guard)."""
     if os.environ.get("JAX_PLATFORMS", "").strip() == "cpu":
         return False
     try:
         import jax
         return jax.default_backend() != "cpu"
     except Exception:
+        return False
+
+
+# a name outside the vocabulary (tests, ad-hoc spans) rides the calling
+# thread's lane and goes as far as any event does
+_UNLISTED = ("host", None, "fleet")
+
+
+def named(fn, name: str):
+    """``fn`` under ``name`` for ``jax.jit``: a profiler trace's ``XLA
+    Modules`` line (and the compile log) then reads ``jit_<name>``.  A
+    ``functools.partial`` or a ``shard_map`` wrapper has no name of its
+    own and would read ``jit__unknown``."""
+    fn = functools.partial(fn)
+    fn.__name__ = name
+    return fn
+
+
+class _SinkSpan(TraceAnnotation):
+    """A live span while the tracer's own sinks are on: the profiler's
+    annotation, and on exit one Chrome "X" event.  ``set_metadata`` adds
+    the counts that are known only once the span is under way, to both."""
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
+        super().__init__(name, **args)
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        self._t0 = 0.0
+
+    def __enter__(self):
+        super().__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set_metadata(self, **args) -> None:
+        super().set_metadata(**args)
+        self._args.update(args)
+
+    def __exit__(self, exc_type, exc, tb):
+        dur = time.perf_counter() - self._t0
+        super().__exit__(exc_type, exc, tb)
+        self._tracer._live(self._name, self._t0, dur, self._args)
         return False
 
 
@@ -159,12 +213,12 @@ class Tracer:
                  "args": {"name": name}}
                 for name, n in sorted(self._tids.items(), key=lambda x: x[1])]
 
-    def _append(self, ev: dict) -> None:
+    def _append(self, ev: dict, export: bool = True) -> None:
         ring = self._ring
         if ring is not None:
             ring.append(ev)         # deque(maxlen): bounded, oldest out
         exp = self._export
-        if exp is not None:
+        if exp is not None and export:
             exp.offer(ev)           # bounded ring append, never blocks
         if not self._enabled:
             return
@@ -178,10 +232,12 @@ class Tracer:
         self._events.append(ev)
 
     def event(self, name: str, t0: float, dur: float, *, cat: str = "host",
-              tid=None, args: Optional[dict] = None) -> None:
+              tid=None, args: Optional[dict] = None,
+              export: bool = True) -> None:
         """Retroactive complete ("X") event: ``t0``/``dur`` in seconds on
         the perf_counter clock (the serving drain stamps request phases
-        from timestamps it recorded at dispatch time)."""
+        from timestamps it recorded at dispatch time).  Chrome sinks only;
+        ``export=False`` keeps it from the fleet export sink."""
         if not self._active:
             return
         ev = {"ph": "X", "name": name, "cat": cat, "pid": 0,
@@ -189,21 +245,24 @@ class Tracer:
               "dur": max(dur, 0.0) * 1e6}
         if args:
             ev["args"] = args
-        self._append(ev)
+        self._append(ev, export)
 
-    @contextlib.contextmanager
-    def span(self, name: str, *, cat: str = "host", tid=None,
-             args: Optional[dict] = None):
-        """Context-managed live span around host work."""
-        if not self._active:
-            yield self
-            return
-        t0 = time.perf_counter()
-        try:
-            yield self
-        finally:
-            self.event(name, t0, time.perf_counter() - t0, cat=cat,
-                       tid=tid, args=args)
+    def span(self, name: str, **args) -> TraceAnnotation:
+        """Context-managed live span around host work; ``args`` are the
+        counts measured at its boundary (``set_metadata(**more)`` on the
+        returned span adds those known only inside it).  Always in the
+        profiler's trace while a session runs; in the Chrome sinks when
+        they are on, as far as ``catalog.SPANS`` lets its name go."""
+        if not self._active or SPANS.get(name, _UNLISTED)[2] == "profiler":
+            return TraceAnnotation(name, **args)
+        return _SinkSpan(self, name, args)
+
+    def _live(self, name: str, t0: float, dur: float, args: dict) -> None:
+        """A closed live span into the Chrome sinks, on the lane and under
+        the category ``catalog.SPANS`` gives its name."""
+        cat, lane, sinks = SPANS.get(name, _UNLISTED)[:3]
+        self.event(name, t0, dur, cat=cat, tid=lane, args=args,
+                   export=sinks == "fleet")
 
     def instant(self, name: str, *, cat: str = "host", tid=None,
                 args: Optional[dict] = None) -> None:
@@ -214,13 +273,6 @@ class Tracer:
         if args:
             ev["args"] = args
         self._append(ev)
-
-    def counter(self, name: str, **values) -> None:
-        """Chrome counter ("C") track, e.g. queue depth over time."""
-        if not self._active:
-            return
-        self._append({"ph": "C", "name": name, "pid": 0,
-                      "ts": time.perf_counter() * 1e6, "args": dict(values)})
 
     # ------------------------------------------------------------ export --
     def export_chrome_trace(self, path: str) -> str:
@@ -235,26 +287,6 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return path
-
-    @contextlib.contextmanager
-    def device_trace(self, logdir: str):
-        """Wrap a jax.profiler device trace (XPlane/perfetto) around a
-        block, guarded off on the CPU backend — the host tracer keeps
-        working either way, so CPU tier-1 never spawns device tracing."""
-        started = False
-        if device_tracing_available():
-            try:
-                import jax
-                jax.profiler.start_trace(logdir)
-                started = True
-            except Exception:
-                started = False
-        try:
-            yield started
-        finally:
-            if started:
-                import jax
-                jax.profiler.stop_trace()
 
 
 # the process-wide tracer every subsystem emits into

@@ -413,34 +413,10 @@ class ServingServer:
                 if self.slo is not None:
                     self.slo.forget()     # warmup latency is compile time
             self._ready.set()
+            tracer = _obs.TRACER
             while not self._stop.is_set():
-                while True:
-                    try:
-                        h = self._inbox.get_nowait()
-                    except queue.Empty:
-                        break
-                    h.req = eng.submit(h.prompt, h.max_new_tokens,
-                                       trace_id=h.trace_id)
-                    self._live.append(h)
-                self._run_control(eng)
-                if self._queue_timeout_s > 0 and self._live:
-                    # queue-expiry shedding (ISSUE 15): a request that
-                    # admission hasn't picked up inside the bound is
-                    # retired 504 BEFORE its prefill is spent — the
-                    # client behind it gave up long ago; an admitted
-                    # request is past the point of free cancellation
-                    # and runs out (continuous batching has no cheap
-                    # mid-flight cancel)
-                    now = time.perf_counter()
-                    for h in list(self._live):
-                        if h.req is not None and not h.req.done and \
-                                now - h.t_accept > self._queue_timeout_s \
-                                and eng.cancel_waiting(h.req):
-                            self._m.queue_expired.inc()
-                            self._live.remove(h)
-                            h.post(("done",
-                                    {"finish_reason": "queue_expired",
-                                     "n": 0}))
+                with tracer.span("serve.intake"):
+                    self._intake(eng)
                 if eng.has_work():
                     if wd is not None:
                         tid = wd.begin("serving.engine_step")
@@ -460,14 +436,16 @@ class ServingServer:
                         eng.step()
                         self._publish()
                         flush = False
-                    self._wake.wait(self._poll_s)
-                    self._wake.clear()
-                if fr is not None:
-                    fr.maybe_snapshot()
-                if self.sentinel is not None:
-                    # host-side registry reads only (never a device
-                    # sync); time-gated by FLAGS_sentinel_interval_s
-                    self.sentinel.maybe_check()
+                    with tracer.span("serve.idle"):
+                        self._wake.wait(self._poll_s)
+                        self._wake.clear()
+                with tracer.span("serve.housekeeping"):
+                    if fr is not None:
+                        fr.maybe_snapshot()
+                    if self.sentinel is not None:
+                        # host-side registry reads only (never a device
+                        # sync); time-gated by FLAGS_sentinel_interval_s
+                        self.sentinel.maybe_check()
         except Exception as e:
             # the engine died mid-serve: THE flight-recorder moment.
             # Dump, then fall through to retire every waiter — clients
@@ -506,6 +484,36 @@ class ServingServer:
                                  "n": len(h.req.output) if h.req else 0}))
             self._live.clear()
 
+    def _intake(self, eng) -> None:
+        """Between steps, on the engine thread: hand the inbox to the
+        engine, run control operations, shed what waited too long."""
+        while True:
+            try:
+                h = self._inbox.get_nowait()
+            except queue.Empty:
+                break
+            h.req = eng.submit(h.prompt, h.max_new_tokens,
+                               trace_id=h.trace_id)
+            self._live.append(h)
+        self._run_control(eng)
+        if self._queue_timeout_s > 0 and self._live:
+            # queue-expiry shedding (ISSUE 15): a request that admission
+            # hasn't picked up inside the bound is retired 504 BEFORE its
+            # prefill is spent — the client behind it gave up long ago;
+            # an admitted request is past the point of free cancellation
+            # and runs out (continuous batching has no cheap mid-flight
+            # cancel)
+            now = time.perf_counter()
+            for h in list(self._live):
+                if h.req is not None and not h.req.done and \
+                        now - h.t_accept > self._queue_timeout_s \
+                        and eng.cancel_waiting(h.req):
+                    self._m.queue_expired.inc()
+                    self._live.remove(h)
+                    h.post(("done",
+                            {"finish_reason": "queue_expired",
+                             "n": 0}))
+
     def _warm(self) -> None:
         """Compile the engine's step-program pair (T=prefill_bucket mixed
         + T=1 decode) by driving one junk request to completion on the
@@ -541,17 +549,23 @@ class ServingServer:
     def _publish(self) -> None:
         """Diff every live request's drained output; push fresh tokens."""
         eos = self.engine.gen_cfg.eos_token_id
-        for h in list(self._live):
-            req = h.req
-            out = req.output
-            if len(out) > h.sent:
-                h.post(("tokens", list(out[h.sent:])))
-                h.sent = len(out)
-            if req.done:
-                reason = "stop" if (eos is not None and out
-                                    and out[-1] == eos) else "length"
-                h.post(("done", {"finish_reason": reason, "n": len(out)}))
-                self._live.remove(h)
+        with _obs.TRACER.span("serve.publish") as span:
+            tokens = streams = 0
+            for h in list(self._live):
+                req = h.req
+                out = req.output
+                if len(out) > h.sent:
+                    h.post(("tokens", list(out[h.sent:])))
+                    tokens += len(out) - h.sent
+                    streams += 1
+                    h.sent = len(out)
+                if req.done:
+                    reason = "stop" if (eos is not None and out
+                                        and out[-1] == eos) else "length"
+                    h.post(("done", {"finish_reason": reason,
+                                     "n": len(out)}))
+                    self._live.remove(h)
+            span.set_metadata(tokens=tokens, streams=streams)
 
     # ---------------------------------------------------------- handler --
     async def handle(self, reader, writer) -> None:

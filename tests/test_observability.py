@@ -84,9 +84,10 @@ def test_reset_zeroes_in_place_keeping_handles_live():
 def test_tracer_spans_and_chrome_export(tmp_path):
     tr = obs.Tracer()
     tr.start()
-    with tr.span("outer", cat="test"):
-        with tr.span("inner", cat="test"):
+    with tr.span("outer", rows=2) as outer:
+        with tr.span("inner"):
             time.sleep(0.002)
+        outer.set_metadata(tokens=7)      # a count known only inside it
     tr.event("retro", time.perf_counter() - 1.0, 0.5, tid="lane")
     tr.instant("marker")
     tr.stop()
@@ -96,6 +97,8 @@ def test_tracer_spans_and_chrome_export(tmp_path):
     assert "outer" in names and "inner" in names and "retro" in names
     xs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
     assert xs["outer"]["dur"] >= xs["inner"]["dur"] > 0
+    assert xs["outer"]["args"] == {"rows": 2, "tokens": 7}
+    assert "args" not in xs["inner"]
     assert xs["retro"]["dur"] == pytest.approx(0.5e6)
     # named lanes get a thread_name metadata event
     assert any(e["ph"] == "M" and e["args"]["name"] == "lane"
@@ -498,7 +501,14 @@ def test_engine_request_spans_in_trace(tmp_path):
     path = obs.export_chrome_trace(str(tmp_path / "serve.json"))
     doc = json.loads(open(path).read())
     names = [e["name"] for e in doc["traceEvents"]]
-    assert "engine.step" in names
+    # the live step span with the counts at its dispatch: five prompt
+    # tokens in one mixed step, then one decode token a step
+    steps = [e["args"] for e in doc["traceEvents"]
+             if e["name"] == "engine.step"]
+    assert [(a["kind"], a["T"], a["rows"], a["q_tokens"])
+            for a in steps[:2]] == [("mixed", 8, 1, 5), ("decode", 1, 1, 1)]
+    assert {"engine.admit", "engine.build", "engine.h2d", "engine.dispatch",
+            "engine.drain", "engine.drain.wait"} <= set(names)
     for phase in ("queued", "prefill", "decode"):
         assert f"req{rid}.{phase}" in names, names
     # the lifecycle phases tile the request's wall time in order
