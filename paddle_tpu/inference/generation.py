@@ -21,6 +21,12 @@ Structure — ONE jitted step function serves every serving phase:
   mixed step.  Both compile once; **warm steps never recompile** (asserted
   by ``paddle_tpu.jit.assert_no_recompiles`` in the serving tests) and all
   state arrays are fixed ``[max_batch]`` buckets.
+- The mixed step multiplies only the tokens it holds: everything that is
+  per token runs over a packed ``[rows, H]`` array of the step's live
+  tokens, ``rows`` the smaller of two row buckets (``row_buckets``) that
+  holds them; only the attention kernel keeps its ``[B, T]`` tile.
+  A bucket's programs are one family under one name, compiled together
+  when the engine first dispatches that T.
 - Prefill IS the step: prompts stream through T-sized chunks with
   per-sequence ``q_lens`` raggedness, so a prefill chunk and concurrent
   decode rows ride one ``pallas_call`` (the ragged-paged-attention shape).
@@ -29,8 +35,9 @@ Structure — ONE jitted step function serves every serving phase:
   dispatch per step — and drains results every ``sync_every`` steps.
   Essential when the device sits behind a high-latency link.
 
-Static shapes throughout: fixed [max_batch] rows, fixed chunk buckets and a
-fixed block-table width keep the compile count at two per sampling config.
+Static shapes throughout: fixed [max_batch] rows, fixed chunk buckets, a
+fixed block-table width and two row buckets keep the compile count at
+three programs per sampling config.
 """
 
 from __future__ import annotations
@@ -47,6 +54,7 @@ import numpy as np
 
 from .. import flags
 from .. import observability as _obs
+from ..kernels.grouped_matmul import take_sentinel_rows
 from ..kernels.paged_attention import (kernel_geometry_error,
                                        paged_attention,
                                        ragged_paged_attention,
@@ -64,6 +72,46 @@ from .kv_cache import PagedKVCache
 # from this constant (jaxlint JL008): a hard-coded "mp" that drifts from
 # the mesh construction is a silent wrong-axis collective.
 MP_AXIS = "mp"
+
+# The fewest rows a mixed step's per-token GEMMs are compiled for.  Not a
+# setting: under peak FLOP/s / HBM bandwidth rows (v5e: 197e12 / 819e9 =
+# 240) a bf16 GEMM is bound by reading its weights, which costs the same
+# at any row count, so a smaller bucket buys compile time and no speed.
+MIN_GEMM_ROWS = 256
+# The packed member of a step family holds a quarter of the dense grid.
+# One packed member, not a ladder of them: every member is lowered and
+# loaded before the first step runs, cache hit or not, and the Mixtral
+# step costs 1.1 s a member warm (v5e, PR 25: with four members `setup_s`
+# read 25.8 s for 20.1, with three 21.0 for 17.9).  A quarter: a mixed step
+# holds a few prefilling slots' chunks and one token of each other slot,
+# so steady traffic stays under it; a burst takes the dense member.
+PACKED_GRID_SHARE = 4
+
+
+def _pack_plan(ql, T, rows):
+    """The gather maps between a step's ``[B, T]`` query places and the
+    ``rows`` packed rows its per-token work runs over — derived on the
+    device from ``ql`` like the write slots are from the block table.
+
+    Slot b's tokens fill packed rows ``[starts[b], starts[b] + ql[b])``,
+    ``starts = cumsum(ql) - ql``; rows from ``sum(ql)`` on are padding.
+    Returns ``(live [rows], src [rows], dst [B, T])``: ``src[p]`` the flat
+    ``b * T + t`` place of packed row p, ``dst[b, t]`` the packed row of a
+    place, ``rows`` for a place past ``ql[b]`` (callers read zero there).
+    Both ways are pure gathers (the ``sorted_dispatch_plan`` idiom)."""
+    i32 = jnp.int32
+    ql = ql.astype(i32)
+    ends = jnp.cumsum(ql)
+    starts = ends - ql
+    p = jnp.arange(rows, dtype=i32)
+    live = p < ends[-1]
+    b = jnp.minimum((p[:, None] >= ends[None, :]).sum(axis=1),
+                    ql.shape[0] - 1).astype(i32)
+    t = jnp.clip(p - jnp.take(starts, b), 0, T - 1)
+    offs = jnp.arange(T, dtype=i32)
+    dst = jnp.where(offs[None, :] < ql[:, None],
+                    starts[:, None] + offs[None, :], rows)
+    return live, b * T + t, dst
 
 
 def _cow_copy_pages(cache, src, dst):
@@ -421,16 +469,36 @@ class LlamaGenerator:
                                out_specs=cspec, check_vma=False)
         return jax.jit(_obs.tracing.named(fn, name), donate_argnums=(0,))
 
-    def _step_jit(self, gc: GenerationConfig, t: int, track_recent=False):
-        """The fused serving step, jitted for (sampling config, q bucket).
-        ``track_recent`` (ngram spec engines) threads the drafter's
-        recent-token ring through the step as extra chained state."""
-        key = (gc._key(), t, bool(track_recent))
+    def row_buckets(self, t: int) -> List[int]:
+        """The row counts a q bucket's step program is compiled for: a
+        ``PACKED_GRID_SHARE``-th of the dense ``max_batch * t`` grid, not
+        under ``MIN_GEMM_ROWS``, and the grid itself (T=1 and small
+        engines: the grid alone)."""
+        full = self.max_batch * t
+        packed = max(MIN_GEMM_ROWS, full // PACKED_GRID_SHARE)
+        return [packed, full] if packed < full else [full]
+
+    def gemm_rows(self, t: int, q_tokens: int) -> int:
+        """Rows of the step's per-token GEMMs: the smallest of
+        ``row_buckets(t)`` that holds ``q_tokens``."""
+        return next(n for n in self.row_buckets(t) if n >= q_tokens)
+
+    def _step_jit(self, gc: GenerationConfig, t: int, track_recent=False,
+                  rows: Optional[int] = None):
+        """The fused serving step, jitted for (sampling config, q bucket,
+        GEMM rows).  ``rows`` is one of ``row_buckets(t)``; ``None`` or
+        ``max_batch * t`` is the dense program, with no pack or unpack
+        operation in it.  Every member of a bucket's family carries the
+        same name.  ``track_recent`` (ngram spec engines) threads the
+        drafter's recent-token ring through the step as extra chained
+        state."""
+        rows = min(rows or self.max_batch * t, self.max_batch * t)
+        key = (gc._key(), t, bool(track_recent), rows)
         if key not in self._jit_cache:
             import functools
             track = bool(track_recent)
             self._jit_cache[key] = self._tp_jit(
-                functools.partial(self._step_fn, gc, t, track),
+                functools.partial(self._step_fn, gc, t, track, rows),
                 f"serve_step_T{t}",
                 n_in=13 if track else 12, n_out=8 if track else 7,
                 out_cache_idx=5)
@@ -463,7 +531,7 @@ class LlamaGenerator:
 
     # ---- the shared transformer core of every serving step ----
     def _forward_tokens(self, params, cache, tokens, ql, positions,
-                        block_tables):
+                        block_tables, rows=None):
         """Run the whole model over this step's query tokens: derive write
         slots in-jit from the block table, stream every layer through the
         mixed-mode ``ragged_paged_attention`` kernel (the step's own K/V
@@ -483,6 +551,15 @@ class LlamaGenerator:
         the int8 plane (per-(layer, kv-head, page) fp32 scales): pages
         dequantize inside the kernel and the commit requantizes per
         page, so the two modes share this whole function.
+
+        ``rows`` < B * T (a static bucket that holds ``sum(ql)``; the
+        mixed step only) packs the step's live tokens: everything that is
+        per token — embedding, norms, projections, rope, the MLP or MoE
+        FFN — runs over ``[1, rows, H]`` instead of the ``[B, T, H]``
+        grid.  Only the kernel's operands are gathered into their
+        ``[B, T]`` places (rows past ``ql[b]`` are don't-care there
+        either way), so the kernel and the commit see today's shapes; the
+        hidden states come back in their places too, zero past ``ql[b]``.
         """
         c = self.config
         B, T = tokens.shape
@@ -513,12 +590,35 @@ class LlamaGenerator:
         slots = jnp.where(valid, page_ids * page + pos_c % page,
                           -1).reshape(B * T)
 
-        cos = jnp.take(self._cos, pos_c, axis=0)          # [B, T, d/2]
-        sin = jnp.take(self._sin, pos_c, axis=0)
         ctx_prev = jnp.minimum(positions, self.max_seq_len).astype(jnp.int32)
+        toks = jnp.clip(tokens, 0, params["embed"].shape[0] - 1)
+        packed = rows is not None and rows < B * T
+        if packed:
+            live, src, dst = _pack_plan(ql, T, rows)
+
+            def pack(a):       # [B, T, ...] -> [1, rows, ...], padding zero
+                # a select on the gathered rows, not a zero row appended
+                # to the grid: it fuses, the grid is never copied
+                with jax.named_scope("token_pack"):
+                    a = jnp.take(a.reshape((B * T,) + a.shape[2:]), src,
+                                 axis=0)
+                    keep = live.reshape((rows,) + (1,) * (a.ndim - 1))
+                    return jnp.where(keep, a, jnp.zeros((), a.dtype))[None]
+
+            def unpack(a):     # [1, rows, ...] -> [B, T, ...], sentinel zero
+                with jax.named_scope("token_unpack"):
+                    return take_sentinel_rows(a[0], dst)
+
+            # ids and positions are packed, not their lookups: the
+            # embedding and the rope tables are then read rows times
+            toks, pos_c = pack(toks), pack(pos_c)
+        cos = jnp.take(self._cos, pos_c, axis=0)          # [R0, R1, d/2]
+        sin = jnp.take(self._sin, pos_c, axis=0)
         with jax.named_scope("embed"):
-            toks = jnp.clip(tokens, 0, params["embed"].shape[0] - 1)
-            h = jnp.take(params["embed"], toks, axis=0)   # [B, T, H]
+            h = jnp.take(params["embed"], toks, axis=0)   # [R0, R1, H]
+        if packed:
+            h = jnp.where(live[None, :, None], h, jnp.zeros((), h.dtype))
+        R0, R1 = h.shape[:2]              # B, T, or 1, rows when packed
 
         moe = "mlp.experts_gate" in params["blocks"]     # MoE model serving
 
@@ -533,13 +633,15 @@ class LlamaGenerator:
                 y = rms_norm_fp32(x, lp["input_layernorm.weight"],
                                   c.rms_norm_eps)
                 q = (y @ lp["self_attn.q_proj.weight"]).reshape(
-                    B, T, c.num_attention_heads, c.head_dim)
+                    R0, R1, c.num_attention_heads, c.head_dim)
                 k = (y @ lp["self_attn.k_proj.weight"]).reshape(
-                    B, T, c.num_key_value_heads, c.head_dim)
+                    R0, R1, c.num_key_value_heads, c.head_dim)
                 v = (y @ lp["self_attn.v_proj.weight"]).reshape(
-                    B, T, c.num_key_value_heads, c.head_dim)
+                    R0, R1, c.num_key_value_heads, c.head_dim)
                 q = _rope_bt(q, cos, sin)
                 k = _rope_bt(k, cos, sin)
+                if packed:
+                    q, k, v = unpack(q), unpack(k), unpack(v)
                 # prior context from the paged cache + this step's own rows
                 # (causal), one mixed-mode kernel call; the fresh rows are
                 # committed to the cache only at the end of the step.  Under
@@ -564,8 +666,10 @@ class LlamaGenerator:
                 if tp > 1:
                     attn = jax.lax.all_gather(attn, MP_AXIS, axis=2,
                                               tiled=True)
-                x = x + (attn.reshape(B, T, -1)
-                         @ lp["self_attn.o_proj.weight"])
+                attn = attn.reshape(B, T, -1)
+                if packed:
+                    attn = pack(attn)
+                x = x + attn @ lp["self_attn.o_proj.weight"]
             with jax.named_scope("moe" if moe else "mlp"):
                 y = rms_norm_fp32(x, lp["post_attention_layernorm.weight"],
                                   c.rms_norm_eps)
@@ -611,12 +715,12 @@ class LlamaGenerator:
                 out_cache = (kc, vc)
 
         h = rms_norm_fp32(h, params["norm"], c.rms_norm_eps)
-        return h, out_cache
+        return (unpack(h) if packed else h), out_cache
 
     # ---- the ONE engine step ----
-    def _step_fn(self, gc, T, track_recent, params, cache, tokens, q_lens,
-                 positions, finished, decode_mask, commit_mask, counts,
-                 budgets, block_tables, key, recent=None):
+    def _step_fn(self, gc, T, track_recent, rows, params, cache, tokens,
+                 q_lens, positions, finished, decode_mask, commit_mask,
+                 counts, budgets, block_tables, key, recent=None):
         """One fused serving step: admit (slots derived in-jit) →
         ragged attention over every layer → ONE batched KV commit → sample.
 
@@ -647,7 +751,7 @@ class LlamaGenerator:
         ql = jnp.where(finished, 0, q_lens).astype(jnp.int32)
 
         h, cache = self._forward_tokens(params, cache, tokens, ql,
-                                        positions, block_tables)
+                                        positions, block_tables, rows)
         last_ix = jnp.maximum(ql - 1, 0)
         last = jnp.take_along_axis(h, last_ix[:, None, None], axis=1)[:, 0]
         with jax.named_scope("head"):
@@ -823,7 +927,6 @@ class LlamaGenerator:
         # chunked prefill: prompts stream through the step in fixed
         # T-sized chunks (one compile, any prompt length)
         T = self.prefill_bucket
-        step_p = self._step_jit(gen, T)
         n_chunks = max(1, -(-int(lens.max()) // T))
         for ci in range(n_chunks):
             s0 = ci * T
@@ -836,6 +939,11 @@ class LlamaGenerator:
                     chunk[i, :n] = np.asarray(p[s0:s0 + n], np.int32)
             commit = np.zeros((MB,), bool)
             commit[:B] = (lens > s0) & (lens <= s0 + T)   # prompt ends here
+            # the engine's rule: the smallest row bucket that holds the
+            # chunk's tokens (all rows prefill together here, so it is the
+            # dense grid or near it; jit caches each member once)
+            step_p = self._step_jit(gen, T,
+                                    rows=self.gemm_rows(T, int(ql.sum())))
             out, positions, finished, _ad, counts, cache, key = step_p(
                 self.params, self.cache.arrays, jnp.asarray(chunk),
                 jnp.asarray(ql), positions, finished, no_mask,
@@ -1010,9 +1118,10 @@ class ContinuousBatchingEngine:
     newly admitted prompts stream through the SAME jitted step as decode,
     in ``prefill_bucket``-sized chunks, while already-running rows keep
     decoding in the same call (their single token rides column 0 of the
-    chunk bucket).  Two compiles total per sampling config (T=1 decode-only
-    steps and T=bucket mixed steps); every warm step reuses them —
-    telemetry-asserted zero recompiles.
+    chunk bucket).  Two program families per sampling config (T=1
+    decode-only steps and T=bucket mixed steps, the latter a packed and a
+    dense member), each compiled whole at its first dispatch; every warm
+    step reuses them — telemetry-asserted zero recompiles.
 
     EOS / budget / capacity freezing happens on device; the host drains
     sampled tokens, retires finished requests (freeing their pages back to
@@ -1113,20 +1222,28 @@ class ContinuousBatchingEngine:
         else:
             self._hist = None
             self._recent = None
-        # tensor-parallel: the step programs return carried state
-        # mesh-replicated (out_specs P() over the serving mesh).  Seed
+        # ngram spec engines thread the drafter's recent-token ring
+        # through EVERY step (prefill commits update it too), so the
+        # verify step's context is exact when the row reaches decode
+        self._track_recent = self._recent is not None
+        self._families: dict = {}      # T -> {GEMM rows: compiled step}
+        # the compiled step programs return carried state committed to
+        # their device (mesh-replicated, out_specs P(), under tp).  Seed
         # the carried arrays with the SAME sharding, or the first drain
         # flips their layout and the second admission wave re-specializes
         # every eager op AND the step program (warm contract: 0 compiles)
         if self.g.tp > 1:
             rep = jax.sharding.NamedSharding(
                 self.g.mesh, jax.sharding.PartitionSpec())
-            self.tokens, self.positions, self.finished, self.counts, \
-                self.key = jax.device_put(
-                    (self.tokens, self.positions, self.finished,
-                     self.counts, self.key), rep)
-            if self._recent is not None:
-                self._recent = jax.device_put(self._recent, rep)
+        else:
+            rep = jax.sharding.SingleDeviceSharding(
+                next(iter(self.key.devices())))
+        self.tokens, self.positions, self.finished, self.counts, \
+            self.key = jax.device_put(
+                (self.tokens, self.positions, self.finished,
+                 self.counts, self.key), rep)
+        if self._recent is not None:
+            self._recent = jax.device_put(self._recent, rep)
         # per-row write caps for the spec programs (tokens the block
         # table covers): cached device array, refreshed only when an
         # allocation/truncation/admission changed it — the same
@@ -1208,7 +1325,7 @@ class ContinuousBatchingEngine:
         """The fused step program's operands for q bucket ``T`` as
         ``jax.ShapeDtypeStruct``s (shapes, dtypes and shardings of what
         ``step()`` passes), so the program can be lowered or compiled
-        without running it."""
+        without running it.  The same for every GEMM row count."""
         sds = jax.ShapeDtypeStruct
         B = self.B
 
@@ -1216,18 +1333,36 @@ class ContinuousBatchingEngine:
             return sds(a.shape, a.dtype, sharding=a.sharding)
 
         ivec, bvec = sds((B,), jnp.int32), sds((B,), jnp.bool_)
-        return (jax.tree_util.tree_map(like, self.g.params),
-                jax.tree_util.tree_map(like, tuple(self.g.cache.arrays)),
-                sds((B, T), jnp.int32), ivec, ivec, bvec, bvec, bvec,
-                ivec, ivec, sds(self._bt.shape, jnp.int32), like(self.key))
+        ops = (jax.tree_util.tree_map(like, self.g.params),
+               jax.tree_util.tree_map(like, tuple(self.g.cache.arrays)),
+               sds((B, T), jnp.int32), ivec, like(self.positions),
+               like(self.finished), bvec, bvec, like(self.counts), ivec,
+               sds(self._bt.shape, jnp.int32), like(self.key))
+        return ops + ((like(self._recent),) if self._track_recent else ())
 
-    def lowered_step(self, T: int):
+    def lowered_step(self, T: int, rows: Optional[int] = None):
         """``jax.stages.Lowered`` of the fused step program ``step()``
-        dispatches for q bucket ``T`` (``as_text()`` shows whether the
-        paged kernel is in it; ``compile().memory_analysis()`` what it
-        takes)."""
-        return self.g._step_jit(self.gen_cfg, T, False).lower(
-            *self.step_operands(T))
+        dispatches for q bucket ``T`` and ``rows`` GEMM rows (default: the
+        dense grid).  ``as_text()`` shows whether the paged kernel is in
+        it; ``compile().memory_analysis()`` what it takes."""
+        return self.g._step_jit(self.gen_cfg, T, self._track_recent,
+                                rows).lower(*self.step_operands(T))
+
+    def _step_family(self, T: int) -> dict:
+        """``{GEMM rows: compiled step program}`` of q bucket ``T``, the
+        whole family compiled when its first member is asked for: no later
+        step may compile (the warm contract), whichever bucket its
+        ``q_tokens`` fall in.  Compiled ahead of time from
+        ``step_operands``: nothing runs, no operand is held twice.  One
+        after the other: on threads four cold compiles took 12.3 s for
+        19.4, but four reads from the persistent cache 1.8 s for 1.0
+        (v5e, PR 25), and a server starts warm more often."""
+        fam = self._families.get(T)
+        if fam is None:
+            fam = self._families[T] = {
+                n: self.lowered_step(T, n).compile()
+                for n in self.g.row_buckets(T)}
+        return fam
 
     def run(self) -> dict:
         """Drive to completion; returns {req_id: generated tokens} for every
@@ -1260,7 +1395,7 @@ class ContinuousBatchingEngine:
         early_done: List[Request] = []
         if all(r is None for r in self.slot_req):
             span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
-                              waiting=len(self.waiting))
+                              gemm_rows=0, waiting=len(self.waiting))
             return self._drain() if self._pending else []
         g = self.g
         B = self.B
@@ -1301,7 +1436,7 @@ class ContinuousBatchingEngine:
             rows = sum(r is not None for r in self.slot_req)
             k = int(self.spec.k)
             span.set_metadata(kind="spec", T=k, rows=rows, q_tokens=rows * k,
-                              waiting=len(self.waiting))
+                              gemm_rows=B * k, waiting=len(self.waiting))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
             self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
@@ -1368,15 +1503,15 @@ class ContinuousBatchingEngine:
                 tokens_in = tokens_in.at[:, 0].set(
                     jnp.where(dm, self.tokens, tokens_in[:, 0]))
 
-        # ngram spec engines thread the drafter's recent-token ring
-        # through EVERY step (prefill commits update it too), so the
-        # verify step's context is exact when the row reaches decode
-        track = self.spec is not None and self.spec.mode == "ngram"
-        step = g._step_jit(self.gen_cfg, T, track)
+        # the host knows q_tokens exactly and the device-side freeze can
+        # only lower it: the smallest row bucket that holds it
+        gemm_rows = g.gemm_rows(T, q_tokens)
+        track = self._track_recent
+        step = self._step_family(T)[gemm_rows]
         span.set_metadata(kind="mixed" if T > 1 else "decode", T=int(T),
-                          rows=rows, q_tokens=q_tokens,
+                          rows=rows, q_tokens=q_tokens, gemm_rows=gemm_rows,
                           waiting=len(self.waiting))
-        with tracer.span("engine.dispatch", program=step.__name__):
+        with tracer.span("engine.dispatch", program=f"serve_step_T{T}"):
             if track:
                 (self.tokens, self.positions, self.finished, _all_done,
                  self.counts, cache, self.key, self._recent) = step(

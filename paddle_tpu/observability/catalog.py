@@ -481,14 +481,19 @@ CATALOG: Dict[str, tuple] = {
 SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
-        "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, slots, "
-        "waiting",
+        "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
+        "slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
         "slots with work, `q_tokens` the query tokens they hold (in the "
         "speculative lane rows x K, what the dispatch may verify), "
-        "`slots` the batch B, `waiting` the queue behind it"),
+        "`gemm_rows` the rows the dispatched program's per-token GEMMs "
+        "run over (the smallest row bucket that holds `q_tokens`; "
+        "slots x T for a dense dispatch, slots x K in the speculative "
+        "lane, 0 when idle), so `q_tokens / gemm_rows` is the occupancy "
+        "those GEMMs see, `slots` the batch B, `waiting` the queue "
+        "behind it"),
     "engine.admit": (
         "serving", "engine", "local", "admitted, waiting",
         "`_admit`: waiting requests into free slots, their pages and the "

@@ -112,6 +112,16 @@ def test_engine_steps_carry_the_counts_of_their_own_prompts(engine_trace):
     assert all(s[3]["slots"] == 2 and s[3]["waiting"] == 0 for s in steps)
 
 
+def test_a_steps_span_carries_the_rows_of_its_gemms(engine_trace):
+    """``gemm_rows`` holds ``q_tokens`` and never passes ``slots x T``; an
+    engine this small is under the floor, so every dispatch is dense."""
+    spans, _, _ = engine_trace
+    steps = [s[3] for s in spans if s[0] == "engine.step"]
+    for a in steps:
+        assert a["q_tokens"] <= a["gemm_rows"] <= a["slots"] * a["T"]
+    assert [a["gemm_rows"] for a in steps] == [2 * BUCKET] * 3 + [2] * 5
+
+
 def test_every_step_holds_its_phases_and_every_fourth_a_drain(engine_trace):
     spans, _, _ = engine_trace
     steps = [s for s in spans if s[0] == "engine.step"]
@@ -141,8 +151,9 @@ def test_step_programs_have_stable_names(engine_trace):
     spans, names, _ = engine_trace
     programs = [s[3]["program"] for s in spans if s[0] == "engine.dispatch"]
     assert programs == [f"serve_step_T{BUCKET}"] * 3 + ["serve_step_T1"] * 5
-    assert f"PjitFunction(serve_step_T{BUCKET})" in names
-    assert "PjitFunction(serve_step_T1)" in names
+    # the engine calls its step programs as compiled ahead of time
+    assert f"PjitFunction(jit(serve_step_T{BUCKET}))" in names
+    assert "PjitFunction(jit(serve_step_T1))" in names
     assert not any("_unknown" in n for n in names)
 
 
@@ -346,20 +357,54 @@ def test_a_span_costs_under_two_microseconds_when_nobody_listens():
     assert statistics.median(took) < 2000, statistics.median(took)
 
 
-@pytest.mark.parametrize("listening", [False, True])
-def test_warm_steps_compile_nothing_and_sync_nothing(listening):
-    eng = _tiny_engine(metrics=True, sync_every=64)
+# what is handed in before each step, by prompt length.  The second plan
+# (8 slots of 8 places, the floor of the row buckets lowered to 8: a packed
+# member of 16 rows and the dense 64) walks through both members of the T=8
+# family with no drain in between: 5 tokens -> 16 rows; 8 + 1 decoding ->
+# 16; three first chunks + 2 -> 64; three new prompts + three second chunks
+# + 2 -> 64; then decode-only steps (the T=1 program, 8 rows).
+PLANS = {
+    "two_short_prompts": (2, None, [[3, 2], [], [], [], [], []], None),
+    "both_row_buckets": (8, 8, [[5], [8], [16, 16, 16], [8, 8, 8], [], []],
+                         [16, 16, 64, 64, 8, 8]),
+}
+
+
+@pytest.mark.parametrize("listening,plan", [
+    (False, "two_short_prompts"), (True, "two_short_prompts"),
+    (False, "both_row_buckets")])
+def test_warm_steps_compile_nothing_and_sync_nothing(monkeypatch, listening,
+                                                     plan):
+    from paddle_tpu.inference import generation
+    max_batch, floor, arrivals, want_rows = PLANS[plan]
+    if floor:
+        monkeypatch.setattr(generation, "MIN_GEMM_ROWS", floor)
+    paddle.seed(0)
+    eng = ContinuousBatchingEngine(
+        LlamaForCausalLM(LlamaConfig.tiny()), max_batch=max_batch,
+        gen=GenerationConfig(max_new_tokens=6), max_seq_len=64, page_size=8,
+        prefill_bucket=BUCKET, metrics=True, sync_every=64)
+    # the first dispatch of a T compiles its whole family
     for p in ([1, 2, 3], [4, 5]):
         eng.add_request(p)
     eng.run()
-    for p in ([9, 8, 7], [2, 3]):
-        eng.add_request(p)
+    assert sorted(eng._step_family(BUCKET)) == eng.g.row_buckets(BUCKET)
+    seen = []
+    inner = eng.g.gemm_rows
+    monkeypatch.setattr(
+        eng.g, "gemm_rows",
+        lambda t, n: seen.append(inner(t, n)) or seen[-1])
     if listening:
         obs.TRACER.start()
     try:
         with obs.assert_overhead(max_compiles=0, max_syncs=0):
-            for _ in range(6):
+            for lens in arrivals:
+                for n in lens:
+                    eng.add_request(list(range(1, n + 1)))
                 eng.step()
     finally:
         obs.TRACER.stop()
+    if want_rows:
+        assert seen == want_rows
+        assert set(seen) >= set(eng.g.row_buckets(BUCKET))
     assert all(len(v) == 6 for v in eng.run().values())
