@@ -59,7 +59,8 @@ def run(r) -> None:
     eng.step()
     r.ready()
 
-    before = {s: registry.snap(s) for s in REGISTRY_SERIES}
+    series = registry.series_of(r.cell, REGISTRY_SERIES)
+    before = {s: registry.snap(s) for s in series}
     r.watch.start()
     live, finished, nxt, done_tokens = {}, [], 0, 0
     drain_count = registry.counter("serving.drains")
@@ -93,7 +94,7 @@ def run(r) -> None:
     tokens = _progress(eng, done_tokens)
     r.note_compiles(t0)
     r.results["registry"] = {s: registry.delta(before[s], registry.snap(s))
-                             for s in REGISTRY_SERIES}
+                             for s in series}
     r.tracer.finish()
     r.note_memory()
     r.attempted = len(finished) + len(live)

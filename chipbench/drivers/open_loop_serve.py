@@ -55,7 +55,8 @@ def run(r) -> None:
             if srv.slo is not None:
                 srv.slo.forget()       # as after the server's own warm-up
             r.ready()
-            before = {s: registry.snap(s) for s in REGISTRY_SERIES}
+            series = registry.series_of(r.cell, REGISTRY_SERIES)
+            before = {s: registry.snap(s) for s in series}
             r.watch.start()
             t0 = time.perf_counter()
             streams = await client.open_loop(host, port, items, prompts, t0,
@@ -63,7 +64,7 @@ def run(r) -> None:
             r.note_compiles(t0)
             r.results["registry"] = {
                 s: registry.delta(before[s], registry.snap(s))
-                for s in REGISTRY_SERIES}
+                for s in series}
             r.results["slo"] = None if srv.slo is None else srv.slo.state()
             return streams, t0
         finally:

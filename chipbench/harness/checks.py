@@ -16,7 +16,7 @@ class Checks:
     is true only if every one held and at least one was made."""
 
     def __init__(self):
-        self.made = []
+        self.made = {}               # name -> its number beside its limit
 
     def add(self, name: str, value, limit, rule: str = "<=") -> bool:
         value = float(value)
@@ -24,29 +24,44 @@ class Checks:
               "==": value == limit}[rule]
         if value != value:                       # NaN never passes
             ok = False
-        self.made.append((name, ok))
-        emit(phase="check", check=name, value=value, limit=limit, rule=rule,
-             ok=bool(ok))
+        self._note(name, value=value, limit=limit, rule=rule, ok=bool(ok))
         return ok
 
     def fail(self, name: str, why: str) -> None:
-        self.made.append((name, False))
-        emit(phase="check", check=name, ok=False, why=why)
+        self._note(name, ok=False, why=why)
+
+    def _note(self, name: str, **made) -> None:
+        if name in self.made:
+            raise ValueError(f"the check {name!r} was made twice")
+        self.made[name] = made
+        emit(phase="check", check=name, **made)
 
     @property
     def correct(self) -> bool:
-        return bool(self.made) and all(ok for _, ok in self.made)
+        return bool(self.made) and all(c["ok"] for c in self.made.values())
 
 
-def last_line(correct: bool, attempted: int, failed: int, metrics: dict,
+def _beside_its_limit(name: str, c: dict) -> str:
+    what = f"{c['value']!r} {c['rule']} {c['limit']!r}" if "value" in c \
+        else c["why"]
+    return f"check {name}: {what}" + ("" if c["ok"] else "  NOT OK")
+
+
+def last_line(checks: Checks, attempted: int, failed: int, metrics: dict,
               device: dict, breakdown: dict | None = None,
               rehearsal: bool = False) -> None:
-    """The result: the LAST line of stdout, with the contract's keys."""
-    out = {"correct": bool(correct), "attempted": int(attempted),
+    """The result: the LAST line of stdout, with the contract's keys and,
+    last in it, every number compared beside its limit (``checks``); the
+    same numbers are the last lines of stderr, one a line."""
+    out = {"correct": checks.correct, "attempted": int(attempted),
            "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         out["breakdown"] = breakdown
     if rehearsal:
         out["rehearsal"] = True
+    out["checks"] = checks.made
     sys.stdout.flush()
+    for name, c in checks.made.items():
+        print(_beside_its_limit(name, c), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(out), flush=True)

@@ -159,13 +159,24 @@ class Run:
 
     @property
     def model(self) -> dict:
-        """The configuration's sizes with this traffic kind's depth."""
-        m = dict(self.cell.config["model"])
-        depth = self.cell.config["depth"]
+        """The configuration's sizes with this traffic kind's depth.  For a
+        configuration cut to one chip's share (``spec.py``'s docstring) the
+        counts held here lie over the published ones, which stand beside
+        them under ``published`` (the router's width, the whole vocabulary)
+        with the place among the chips that share a layer under ``share``:
+        the program and the reference are handed the same share."""
+        config = self.cell.config
+        m = dict(config["model"])
         role = "train" if self.cell.kind == "train" else "serve"
-        m["num_hidden_layers"] = int(depth[role])
+        m["num_hidden_layers"] = int(config["depth"][role])
+        share = config.get("share")
+        if share is not None:
+            m.update(share.get(role, {}))
+            m["published"] = {k: config["model"][k]
+                              for k in config["reduced"]}
+            m["share"] = {"chips": share["chips"], "index": share["index"]}
         if self.rehearse:
-            m.update(self.cell.config.get("rehearsal_model", {}))
+            m.update(config.get("rehearsal_model", {}))
         return m
 
     @property

@@ -14,9 +14,17 @@ The host does not only wait for the chip in ``engine.drain.wait``: the
 runtime holds about four launches in flight, and the call of a further one
 (``engine.dispatch``) returns when the oldest has finished, a whole device
 step later.  So both count as time the chip holds the host, not the host
-the chip.  A span that is open when the profiler starts or stops is not in
-the trace, so what happens before the first recorded span and after the
-last can be charged to nothing.
+the chip.  The eager upload programs of ``engine.h2d`` are launches too and
+can meet the same wait (PR 25: with shorter steps the places fill sooner).
+The runtime's own events tell that wait from the upload's work: every
+launch is a ``CommonPjRtLoadedExecutable::ExecutePrepare`` event of the
+host plane (on a line of the runtime's, not the thread's), and what lies
+between its start and the start of the ``Acquire semaphore`` event inside
+it is the wait for a place (about a microsecond when one is free, a device
+step when none is).  ``launch waits`` below are those intervals.  A span
+that is open when the profiler starts or stops is not in the trace, so what
+happens before the first recorded span and after the last can be charged to
+nothing.
 
 A program from before the vocabulary has no such events: every function
 here then finds nothing, and every reader returns None.
@@ -40,6 +48,9 @@ HOST_PLANE = "/host:CPU"
 STEP = "engine.step"
 WAIT = "engine.drain.wait"
 DISPATCH = "engine.dispatch"
+UPLOAD = "engine.h2d"
+PREPARE = "CommonPjRtLoadedExecutable::ExecutePrepare"
+ACQUIRE = "Acquire semaphore"
 # where the chip holds the host: the drain's transfer, and a launch that
 # blocks until the runtime has a place in flight for it
 HELD_BY_DEVICE = (WAIT, DISPATCH)
@@ -73,15 +84,18 @@ class Span:
         return self.end - self.start
 
 
-def load(path: str, names=None) -> list:
-    """Every event of the host plane whose name is in ``names`` (default:
-    the program's vocabulary), on every line, nested by time within its
-    thread; sorted by start."""
+def read_host(path: str, names=None) -> tuple:
+    """(spans, launch waits) of the host plane.  Spans: every event whose
+    name is in ``names`` (default: the program's vocabulary), on every
+    line, nested by time within its thread, sorted by start.  Launch
+    waits: [(start, end)] in ns, sorted, one for each launch the runtime
+    prepared: from the start of its ``ExecutePrepare`` event to the start
+    of the first ``Acquire semaphore`` event inside it."""
     names = vocabulary() if names is None else frozenset(names)
     if not names:
-        return []
+        return [], []
     from jax.profiler import ProfileData
-    spans = []
+    spans, waits = [], []
     for plane in ProfileData.from_file(path).planes:
         if plane.name != HOST_PLANE:
             continue
@@ -89,12 +103,29 @@ def load(path: str, names=None) -> list:
             # a line's name is not its thread's alone (the engine's thread
             # and the main thread both read "python")
             thread = f"{line.name}#{i}"
-            mine = [Span(e.name, e.start_ns, e.start_ns + e.duration_ns,
-                         dict(e.stats), thread)
-                    for e in line.events if e.name in names]
+            mine, launches, acquired = [], [], []
+            for e in line.events:
+                if e.name in names:
+                    mine.append(Span(e.name, e.start_ns,
+                                     e.start_ns + e.duration_ns,
+                                     dict(e.stats), thread))
+                elif e.name == PREPARE:
+                    launches.append((e.start_ns, e.start_ns + e.duration_ns))
+                elif e.name == ACQUIRE:
+                    acquired.append(e.start_ns)
             nest(mine)
             spans += mine
-    return sorted(spans, key=lambda s: s.start)
+            acquired.sort()
+            for a, b in launches:
+                i = bisect.bisect_left(acquired, a)
+                if i < len(acquired) and acquired[i] < b:
+                    waits.append((a, acquired[i]))
+    return sorted(spans, key=lambda s: s.start), sorted(waits)
+
+
+def load(path: str, names=None) -> list:
+    """The spans of ``read_host`` alone."""
+    return read_host(path, names)[0]
 
 
 def nest(spans: list) -> None:
@@ -162,10 +193,21 @@ def idle_by_span(spans: list, busy: list, lo: float, hi: float) -> dict:
 # ---------------------------------------------------------------------------
 
 def of(run) -> list:
-    """The run's program spans, read once."""
+    """The run's program spans, read once (with its launch waits)."""
     if getattr(run, "program_spans", None) is None:
-        run.program_spans = load(tr.find_xplane(run.tracer.dir))
+        run.program_spans, run.launch_waits = read_host(
+            tr.find_xplane(run.tracer.dir))
     return run.program_spans
+
+
+def upload_blocked_ns(run, upload: Span, lo: float, hi: float) -> float:
+    """The part of [lo, hi] in which an ``engine.h2d`` span waits for a
+    place to launch one of its upload programs: held by the chip, not the
+    upload's own work."""
+    of(run)
+    a, b = _clip(upload, lo, hi)
+    return sum(min(y, b) - max(x, a) for x, y in run.launch_waits
+               if y > a and x < b)
 
 
 def steps(run) -> list:
@@ -186,19 +228,24 @@ def _by_kind(found: list) -> dict:
 
 def host_step_ms(run):
     """Mean over the window's steps of the step's duration minus the time
-    the chip held the host inside it (``HELD_BY_DEVICE``)."""
+    the chip held the host inside it: ``HELD_BY_DEVICE`` and the launch
+    waits of its uploads."""
     found = steps(run)
     if not found:
         return None
     held = {name: [sum(w.dur for w in within(s, name)) for s in found]
             for name in HELD_BY_DEVICE}
+    held[UPLOAD] = [sum(upload_blocked_ns(run, h, s.start, s.end)
+                        for h in within(s, UPLOAD)) for s in found]
     host = [s.dur - sum(held[name][i] for name in held)
             for i, s in enumerate(found)]
     emit(phase="metric_detail", name="host_step_ms", steps=len(found),
          steps_by_kind=_by_kind(found), min_ms=min(host) / 1e6,
          max_ms=max(host) / 1e6, wait_ms=sum(held[WAIT]) / 1e6,
          dispatch_ms=sum(held[DISPATCH]) / 1e6,
-         dispatch_min_ms=min(held[DISPATCH]) / 1e6)
+         dispatch_min_ms=min(held[DISPATCH]) / 1e6,
+         h2d_blocked_ms=sum(held[UPLOAD]) / 1e6,
+         h2d_blocked_max_ms=max(held[UPLOAD]) / 1e6)
     return sum(host) / len(host) / 1e6
 
 
@@ -215,24 +262,46 @@ def token_occupancy_pct(run):
     return 100.0 * real / room
 
 
+def gemm_occupancy_pct(run):
+    """Query tokens the window's steps held over the rows their step
+    programs multiplied (``gemm_rows``: the packed member's row bucket, or
+    the grid), where ``token_occupancy_pct`` reads the grid.  A program
+    from before the argument has nothing to read."""
+    found = [s for s in steps(run) if "gemm_rows" in s.stats]
+    real = sum(int(s.stats["q_tokens"]) for s in found)
+    rows = sum(int(s.stats["gemm_rows"]) for s in found)
+    if not rows:
+        return None
+    by_rows = {}
+    for s in found:
+        k = str(s.stats["gemm_rows"])
+        by_rows[k] = by_rows.get(k, 0) + 1
+    emit(phase="metric_detail", name="gemm_occupancy_pct", steps=len(found),
+         steps_by_gemm_rows=by_rows, q_tokens=real, gemm_rows=rows)
+    return 100.0 * real / rows
+
+
 def host_busy_pct(run):
     """Share of the window the engine's thread spends in program spans
-    other than waiting for work or held by the chip (self times)."""
+    other than waiting for work or held by the chip (self times, the
+    uploads' without their launch waits)."""
     lo, hi = run.trace_window
     spans = of(run)
     threads = {s.thread for s in spans if s.name == STEP}
     if not threads:
         return None
-    by_name = {}
+    by_name, blocked = {}, 0.0
     for s in spans:
         if s.thread in threads:
             by_name[s.name] = by_name.get(s.name, 0.0) + self_ns(s, lo, hi)
+            if s.name == UPLOAD:
+                blocked += upload_blocked_ns(run, s, lo, hi)
     busy = sum(v for k, v in by_name.items()
-               if k != "serve.idle" and k not in HELD_BY_DEVICE)
+               if k != "serve.idle" and k not in HELD_BY_DEVICE) - blocked
     emit(phase="metric_detail", name="host_busy_pct",
          self_ms_by_span={k: v / 1e6 for k, v in sorted(
              by_name.items(), key=lambda kv: -kv[1])},
-         threads=sorted(threads))
+         h2d_blocked_ms=blocked / 1e6, threads=sorted(threads))
     return 100.0 * busy / (hi - lo)
 
 

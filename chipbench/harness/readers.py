@@ -106,15 +106,34 @@ def kernel_roofline_pct(run, kernel: str, cost_of):
                               calls=len(calls), bound_by=bounds)
 
 
+def layer_windows(m: dict) -> list:
+    """[(window or None, share of the layers)]: where the model states
+    ``layer_types`` and a ``sliding_window``, its sliding-attention layers
+    see a window and the others everything, in their published ratio (a
+    call of the trace does not say which layer it is)."""
+    types, window = m.get("layer_types"), m.get("sliding_window")
+    if not types or not window:
+        return [(None, 1.0)]
+    sliding = sum(1 for t in types if t == "sliding_attention") / len(types)
+    return [(w, share) for w, share in ((int(window), sliding),
+                                        (None, 1.0 - sliding)) if share > 0]
+
+
 def paged_cost_of(run):
     """Per-call cost of the paged kernel by step program: the mean over the
     steps the host logged while the trace ran, since a call's work depends
-    on each slot's query and context lengths, which shapes do not give."""
+    on each slot's query and context lengths, which shapes do not give;
+    over the layer kinds too where some see a window (``layer_windows``)."""
     log = run.results.get("step_log") or []
     t0 = run.tracer.t_started
     inside = [s for s in log if t0 <= s["t"] <= t0 + run.tracer.seconds]
     m = run.model
     group = m["num_attention_heads"] // m["num_key_value_heads"]
+    heads = (m["num_attention_heads"], m["num_key_value_heads"],
+             m["head_dim"])
+    page = int(run.traffic["engine"]["page_size"])
+    kinds = [({} if w is None else {"window": w, "page_size": page}, share)
+             for w, share in layer_windows(m)]
     by_T = {}
     for s in inside:
         by_T.setdefault(s["T"], []).append(s["rows"])
@@ -124,10 +143,11 @@ def paged_cost_of(run):
         T = next((T for T in by_T if max(8, T * group) == rows_q), None)
         if T is None:
             return None
-        costs = [mod.cost(rows, m["num_attention_heads"],
-                          m["num_key_value_heads"], m["head_dim"])
-                 for rows in by_T[T]]
-        return (sum(c[0] for c in costs) / len(costs),
-                sum(c[1] for c in costs) / len(costs))
+        flops = nbytes = 0.0
+        for kw, share in kinds:
+            costs = [mod.cost(rows, *heads, **kw) for rows in by_T[T]]
+            flops += share * sum(c[0] for c in costs) / len(costs)
+            nbytes += share * sum(c[1] for c in costs) / len(costs)
+        return flops, nbytes
 
     return cost_of

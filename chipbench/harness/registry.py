@@ -15,6 +15,15 @@ def counter(name: str):
     return metrics.counter(name)
 
 
+def series_of(cell, always: tuple) -> tuple:
+    """The histograms a driver snapshots around its window: its own
+    (``always``) and those the cell's file names under
+    ``reports.registry_series`` (absent: none more), so that a reader of a
+    later configuration's series needs no edit to a driver."""
+    more = cell.extras.get("reports", {}).get("registry_series", ())
+    return tuple(dict.fromkeys(tuple(always) + tuple(more)))
+
+
 def snap(name: str) -> dict:
     h = histogram(name)
     return {"bounds": tuple(h.bounds), "buckets": list(h.bucket_counts),

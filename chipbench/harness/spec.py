@@ -2,6 +2,29 @@
 
 A later PR adds a configuration, a mix, a cell, a driver kind or a per-layer
 metric as new files plus entries; nothing here knows any of their names.
+
+A configuration's file (``configs/<name>.json``): ``model`` holds the
+source's own keys, verbatim (published counts and widths); ``depth`` the
+layers run for each role (``published``, ``serve``, ``train``); ``reduced``
+a sentence for every key that differs from the source, the same keys as the
+entry's ``reduced`` in ``BENCHMARK.json``; ``deployment`` what it stands for.
+
+A configuration cut to ONE CHIP'S SHARE of a stated deployment adds
+
+    "share": {"chips": <n that share each layer>, "index": <which, 0-based>,
+              "how": "<expert parallel / vocabulary parallel ..., in words>",
+              "serve": {<key of model>: <held here>, ...}, "train": {...}}
+    "layer_pattern": {"period": <layers in one period>,
+                      "leading_dense": <count>}        (absent: 1 and 0)
+
+with a role only where ``depth`` has it.  ``reduced`` is then exactly
+``num_hidden_layers`` (where depth is cut) plus the keys of ``share[role]``.
+``chips`` is the number that share a layer, not the cell's ``chips``.  What
+is held is one of ``chips`` equal parts (held x chips = published), never a
+width, never fewer than 8 experts, never under an eighth (``chips`` <= 8);
+the depth keeps the leading dense layers and whole periods, at least four
+layers.  ``Run.model`` (``core.py``) lays ``share[role]`` over the published
+sizes and puts the source's values beside them under ``published``.
 """
 
 from __future__ import annotations
@@ -18,6 +41,13 @@ ROOT = os.path.dirname(BENCH_DIR)
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# a key that may never be in ``reduced``: a width, not a count held here
+WIDTH_RE = re.compile(r"(_dim|_rank|_size|_per_tok|_width)$|window|top_?k"
+                      r"|expand")
+ROLES = ("serve", "train")
+SHARE_CHIPS = (2, 8)           # an eighth is the least a chip may hold
+EXPERTS_HELD_FLOOR = 8
+DEPTH_FLOOR = 4                # layers after the leading dense ones
 
 
 class SpecError(ValueError):
@@ -111,6 +141,83 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
         root=root)
 
 
+def is_width(key: str) -> bool:
+    return key != "vocab_size" and bool(WIDTH_RE.search(key))
+
+
+def _whole(x, least: int) -> bool:
+    return isinstance(x, int) and not isinstance(x, bool) and x >= least
+
+
+def validate_config(entry: dict, config: dict) -> list:
+    """What a configuration's file breaches, as sentences: ``reduced``
+    against its entry in ``BENCHMARK.json``, and a chip's share (the
+    module's docstring) against the floors that keep it the model."""
+    who = f"config {entry['name']}"
+    bad = []
+    reduced = set(config.get("reduced", {}))
+    if reduced != set(entry["reduced"]):
+        bad.append(f"{who}: reduced of the file {sorted(reduced)} differs "
+                   f"from BENCHMARK.json's {sorted(entry['reduced'])}")
+    bad += [f"{who}: reduced names a width {k}"
+            for k in sorted(reduced - set(entry["reduced"])) if is_width(k)]
+    if not str(config.get("deployment", "")).strip():
+        bad.append(f"{who}: the file has no deployment text")
+    share = config.get("share")
+    if share is None:
+        return bad
+    model, depth = config.get("model", {}), config.get("depth", {})
+    chips = share.get("chips")
+    if not _whole(chips, 1) or not SHARE_CHIPS[0] <= chips <= SHARE_CHIPS[1]:
+        bad.append(f"{who}: share.chips {chips!r} is not a whole number "
+                   f"from {SHARE_CHIPS[0]} to {SHARE_CHIPS[1]}")
+        return bad
+    if not _whole(share.get("index"), 0) or share["index"] >= chips:
+        bad.append(f"{who}: share.index {share.get('index')!r} is not one "
+                   f"of the {chips} chips")
+    if not str(share.get("how", "")).strip():
+        bad.append(f"{who}: share.how does not say how a layer is divided")
+    roles = [k for k in share if k not in ("chips", "index", "how")]
+    held_keys = set()
+    for role in roles:
+        if role not in ROLES or role not in depth:
+            bad.append(f"{who}: share.{role}, but depth has no {role!r}")
+            continue
+        for key, held in share[role].items():
+            held_keys.add(key)
+            if key not in model:
+                bad.append(f"{who}: share.{role}.{key} is no key of model")
+            elif not _whole(held, 1) or held * chips != model[key]:
+                bad.append(f"{who}: share.{role}.{key} {held!r} x {chips} "
+                           f"chips != the published {model[key]!r}")
+            elif "expert" in key and held < EXPERTS_HELD_FLOOR:
+                bad.append(f"{who}: share.{role}.{key} holds {held} "
+                           f"experts, under {EXPERTS_HELD_FLOOR}")
+    cut = any(depth.get(r) != depth.get("published")
+              for r in ROLES if r in depth)
+    want = held_keys | ({"num_hidden_layers"} if cut else set())
+    if reduced != want:
+        bad.append(f"{who}: reduced {sorted(reduced)} is not depth plus "
+                   f"the keys of share: {sorted(want)}")
+    pattern = config.get("layer_pattern", {})
+    period = pattern.get("period", 1)
+    dense = pattern.get("leading_dense", 0)
+    if not _whole(period, 1) or not _whole(dense, 0):
+        bad.append(f"{who}: layer_pattern {pattern!r}")
+        return bad
+    floor = dense + max(DEPTH_FLOOR, period)
+    for role in (r for r in ROLES if r in depth):
+        d = depth[role]
+        if not _whole(d, floor):
+            bad.append(f"{who}: depth.{role} {d!r} is under the floor "
+                       f"{floor} ({dense} leading dense + max({DEPTH_FLOOR}, "
+                       f"a period of {period}))")
+        elif (d - dense) % period:
+            bad.append(f"{who}: depth.{role} {d} is not {dense} leading "
+                       f"dense + whole periods of {period}")
+    return bad
+
+
 def validate(bench: dict, root: str = ROOT) -> list:
     """Every breach of the contract's rules on names, units, sources and
     files that can be seen without a run, as sentences."""
@@ -139,13 +246,15 @@ def validate(bench: dict, root: str = ROOT) -> list:
             bad.append(f"config {c['name']}: keys {sorted(c)}")
         if not any(c["file"].startswith(p + "/") for p in paths):
             bad.append(f"config {c['name']}: file outside paths")
-        if not os.path.exists(os.path.join(root, c["file"])):
-            bad.append(f"config {c['name']}: no file {c['file']}")
         for k in c["reduced"]:
             name_ok(f"config {c['name']} reduced", k)
-            if re.search(r"(_dim|_rank|hidden_size|intermediate_size|"
-                         r"head_dim|experts_per_tok)$", k):
+            if is_width(k):
                 bad.append(f"config {c['name']}: reduced names a width {k}")
+        try:
+            bad += validate_config(c, load_json(os.path.join(root,
+                                                             c["file"])))
+        except SpecError as e:
+            bad.append(f"config {c['name']}: {e}")
     cfg_names = {c["name"] for c in bench["configs"]}
     pairs = set()
     four = 0

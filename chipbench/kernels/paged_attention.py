@@ -28,19 +28,30 @@ def match(op):
 
 
 def cost(rows, q_heads: int, kv_heads: int, head_dim: int,
-         dtype_bytes: int = 2):
+         dtype_bytes: int = 2, window: int | None = None,
+         page_size: int = 1):
     """(flops, bytes) one layer's call needs for ``rows`` = [(q_len,
     context_len)], context counted BEFORE this step's tokens.  Each query
     token attends to the context plus the step's tokens up to itself; the
     kernel reads each attended K and V element once per KV head, reads Q
-    and writes O once."""
+    and writes O once.  With ``window`` (a sliding-attention layer) a query
+    token attends to at most ``window`` tokens ending at itself, and the K
+    and V read are the pages of ``page_size`` tokens that hold what the
+    row's first query token attends to and all that follows."""
     flops = nbytes = 0.0
     for q, ctx in rows:
         if q <= 0:
             continue
-        attended = q * ctx + q * (q + 1) / 2.0          # causal inside q
+        if window is None:
+            attended = q * ctx + q * (q + 1) / 2.0      # causal inside q
+            kv_tokens = ctx + q
+        else:
+            # the first k query tokens still see everything before them
+            k = min(q, max(0, window - ctx))
+            attended = k * ctx + k * (k + 1) / 2.0 + (q - k) * float(window)
+            first = max(0, ctx + 1 - window)            # of the first query
+            kv_tokens = ctx + q - first // page_size * page_size
         flops += 4.0 * q_heads * head_dim * attended    # QK^T and PV
-        kv_tokens = ctx + q
         nbytes += 2.0 * kv_heads * kv_tokens * head_dim * dtype_bytes \
             + 2.0 * q_heads * q * head_dim * dtype_bytes
     return flops, nbytes

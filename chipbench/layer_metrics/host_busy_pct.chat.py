@@ -1,4 +1,4 @@
-"""Share of the window the engine thread spends in program spans other than serve.idle and engine.drain.wait (self times)."""
+"""Share of the window the engine thread spends in program spans other than serve.idle, engine.drain.wait, engine.dispatch and the launch waits of engine.h2d (self times)."""
 from chipbench.harness import program_spans
 
 LAYER = "scheduler"

@@ -1,4 +1,4 @@
-"""Mean host time of an engine step in the traced window: the engine.step span minus the engine.drain.wait inside it."""
+"""Mean host time of an engine step in the traced window: the engine.step span minus what the chip holds of it (engine.drain.wait, engine.dispatch, the launch waits of engine.h2d)."""
 from chipbench.harness import program_spans
 
 LAYER = "scheduler"

@@ -3,7 +3,9 @@
 
 Earlier lines of stdout carry phases, counts and every number compared
 beside its limit; the LAST line is the result (``correct``, ``attempted``,
-``failed``, ``metrics``, ``device``, and ``breakdown`` with ``--trace 1``).
+``failed``, ``metrics``, ``device``, ``breakdown`` with ``--trace 1``, and
+last ``checks``: each number compared beside its limit, which are also the
+last lines of stderr).
 Without a TPU (or with fewer chips than the cell asks for) it exits with
 code 3 and prints no result.  ``--rehearse 1`` is the builder's CPU
 rehearsal at tiny sizes: it prints ``"rehearsal": true`` and no metric.
@@ -126,7 +128,7 @@ def main(argv=None) -> int:
             metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
     if run.rehearse:
         metrics = {}
-    last_line(run.checks.correct, run.attempted, run.failed, metrics, dev,
+    last_line(run.checks, run.attempted, run.failed, metrics, dev,
               breakdown, rehearsal=run.rehearse)
     return 0
 

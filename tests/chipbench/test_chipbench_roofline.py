@@ -68,6 +68,109 @@ def test_paged_attention_cost_by_hand():
     assert bound == "memory"
 
 
+def _cost_before_the_window(rows, q_heads, kv_heads, head_dim,
+                            dtype_bytes=2):
+    """``cost`` as it stood before it knew a window (PR 23), kept here so
+    that ``window=None`` is held to it to the last digit."""
+    flops = nbytes = 0.0
+    for q, ctx in rows:
+        if q <= 0:
+            continue
+        attended = q * ctx + q * (q + 1) / 2.0
+        flops += 4.0 * q_heads * head_dim * attended
+        kv_tokens = ctx + q
+        nbytes += 2.0 * kv_heads * kv_tokens * head_dim * dtype_bytes \
+            + 2.0 * q_heads * q * head_dim * dtype_bytes
+    return flops, nbytes
+
+
+ROWS = [[(1, 100)], [(64, 0)], [(64, 4031), (1, 4223), (0, 0), (37, 12)],
+        [(1, 7)] * 32, [(64, 64 * i) for i in range(40)],
+        [(q, 997 * q % 4000) for q in range(1, 65)]]
+
+
+@pytest.mark.parametrize("rows", ROWS, ids=range(len(ROWS)))
+def test_paged_attention_cost_without_a_window_is_as_before(rows):
+    k = spec.load_module(ROOT, "kernels", "paged_attention")
+    for heads in ((32, 8, 128), (128, 8, 128)):
+        assert k.cost(rows, *heads) == _cost_before_the_window(rows, *heads)
+        assert k.cost(rows, *heads, window=None, page_size=16) == \
+            _cost_before_the_window(rows, *heads)
+        # a window wider than every context changes nothing either
+        assert k.cost(rows, *heads, window=10 ** 6, page_size=1) == \
+            pytest.approx(_cost_before_the_window(rows, *heads), rel=1e-15)
+
+
+def test_paged_attention_cost_with_a_window_by_hand():
+    k = spec.load_module(ROOT, "kernels", "paged_attention")
+    hq, hkv, d = 32, 8, 128
+    # a decode row behind 100 tokens, window 16: it attends to the last 16
+    # (15 of the context and itself); they begin at token 85, in the page
+    # of 16 that begins at 80, so 21 tokens of K and of V are read
+    flops, nbytes = k.cost([(1, 100)], hq, hkv, d, window=16, page_size=16)
+    assert flops == 4 * hq * d * 16
+    assert nbytes == 2 * hkv * 21 * d * 2 + 2 * hq * 1 * d * 2
+    # by tokens, not pages: 16 are read
+    assert k.cost([(1, 100)], hq, hkv, d, window=16)[1] == \
+        2 * hkv * 16 * d * 2 + 2 * hq * 1 * d * 2
+    # a chunk of 8 behind 4 tokens, window 6: the query tokens see
+    # 5, 6, 6, 6, 6, 6, 6, 6 = 47; the first looks back to token 0
+    flops, nbytes = k.cost([(8, 4)], hq, hkv, d, window=6, page_size=4)
+    assert flops == 4 * hq * d * 47
+    assert nbytes == 2 * hkv * 12 * d * 2 + 2 * hq * 8 * d * 2
+    # the same chunk behind 10 tokens: every query token sees 6; the first
+    # looks back to token 5, whose page of 4 begins at 4: 18 - 4 = 14 read
+    flops, nbytes = k.cost([(8, 10)], hq, hkv, d, window=6, page_size=4)
+    assert flops == 4 * hq * d * 48
+    assert nbytes == 2 * hkv * 14 * d * 2 + 2 * hq * 8 * d * 2
+    # a window never adds work, and idle slots still cost nothing
+    rows = [(64, 4031), (1, 4223), (0, 0), (37, 12)]
+    whole = k.cost(rows, hq, hkv, d)
+    seen = k.cost(rows, hq, hkv, d, window=4096, page_size=16)
+    assert seen[0] < whole[0] and seen[1] < whole[1]
+    assert k.cost([(0, 50)], hq, hkv, d, window=8) == (0.0, 0.0)
+
+
+def test_a_call_is_priced_over_the_layer_kinds_in_their_published_ratio():
+    """Three sliding layers to one full one: a call of the trace does not
+    say which it is, so it costs the mean."""
+    from types import SimpleNamespace
+    from chipbench.harness import readers
+    k = spec.load_module(ROOT, "kernels", "paged_attention")
+    dense = {"num_attention_heads": 32, "num_key_value_heads": 8,
+             "head_dim": 128, "sliding_window": None}
+    mixed = dict(dense, sliding_window=64, layer_types=[
+        "sliding_attention"] * 3 + ["full_attention"])
+    assert readers.layer_windows(dense) == [(None, 1.0)]
+    assert readers.layer_windows(dict(dense, sliding_window=64)) == [
+        (None, 1.0)]                        # no layer_types: no window priced
+    assert readers.layer_windows(mixed) == [(64, 0.75), (None, 0.25)]
+    assert readers.layer_windows(dict(mixed, layer_types=[
+        "sliding_attention"] * 4)) == [(64, 1.0)]
+    steps = [[(1, 300), (1, 20)], [(1, 301), (1, 21)]]
+    shapes = {"q_rows": 8}                  # T = 1: max(8, 1 x group 4)
+
+    def price(m):
+        run = SimpleNamespace(
+            model=m, traffic={"engine": {"page_size": 16}},
+            tracer=SimpleNamespace(t_started=10.0, seconds=1.0),
+            results={"step_log": [
+                {"T": 1, "rows": rows, "t": 10.2 + i}   # the 2nd: outside
+                for i, rows in enumerate(steps)]})
+        return readers.paged_cost_of(run)(k, shapes)
+
+    whole = k.cost(steps[0], 32, 8, 128)
+    seen = k.cost(steps[0], 32, 8, 128, window=64, page_size=16)
+    assert price(dense) == whole            # to the last digit
+    assert price(mixed) == (0.75 * seen[0] + 0.25 * whole[0],
+                            0.75 * seen[1] + 0.25 * whole[1])
+    assert price(mixed)[1] < whole[1]
+    assert readers.paged_cost_of(SimpleNamespace(
+        model=dense, traffic={"engine": {"page_size": 16}},
+        tracer=SimpleNamespace(t_started=0.0, seconds=1.0),
+        results={}))(k, shapes) is None     # no step logged: nothing to price
+
+
 def test_grouped_matmul_cost_by_hand():
     k = spec.load_module(ROOT, "kernels", "grouped_matmul")
     shapes = {"rows": 4096, "k": 4096, "n": 14336, "experts": 8}

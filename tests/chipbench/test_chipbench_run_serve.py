@@ -33,13 +33,28 @@ def chat():
                     str(2**31 + 17), "--seconds", "5", "--trace", "0",
                     "--rehearse", "1", "--control", "1"])
     assert p.returncode == 0, p.stderr[-2000:]
-    return parsed(lines)
+    out = parsed(lines)
+    out[-1]["stderr_end"] = p.stderr.splitlines()[-len(out[-1]["checks"]):]
+    return out
 
 
 def test_last_line_has_the_contracts_keys(chat):
-    last = chat[-1]
+    last = dict(chat[-1])
+    stderr_end = last.pop("stderr_end")
     assert set(last) == {"correct", "attempted", "failed", "metrics",
-                         "device", "rehearsal"}
+                         "device", "rehearsal", "checks"}
+    # each number compared beside its limit: the line's last key, and the
+    # last lines of stderr
+    assert list(last)[-1] == "checks"
+    made = {c["check"]: c for c in chat if c.get("phase") == "check"}
+    assert set(last["checks"]) == set(made) and len(made) >= 6
+    for name, c in last["checks"].items():
+        assert (c["value"], c["limit"], c["ok"]) == (
+            made[name]["value"], made[name]["limit"], made[name]["ok"])
+    assert [ln.split(":")[0] for ln in stderr_end] == [
+        "check " + name for name in last["checks"]]
+    assert "served_logit_gap_max: " in stderr_end[4] and \
+        stderr_end[4].endswith("<= 0.11")
     assert last["correct"] is True and last["rehearsal"] is True
     assert last["failed"] == 0 and last["attempted"] > 5
     assert set(last["device"]) >= {"platform", "kind", "count",
@@ -84,6 +99,9 @@ def test_a_served_token_altered_where_it_is_produced_is_not_correct():
     bad = [c["check"] for c in out
            if c.get("phase") == "check" and not c["ok"]]
     assert "served_logit_gap_max" in bad
+    assert bad == [n for n, c in out[-1]["checks"].items() if not c["ok"]]
+    assert "served_logit_gap_max" in p.stderr.splitlines()[-4] and \
+        p.stderr.splitlines()[-4].endswith("NOT OK")
 
 
 def test_without_a_chip_it_fails_and_prints_no_result():
