@@ -11,11 +11,14 @@ Structure — ONE jitted step function serves every serving phase:
   from the block table, run every layer through the mixed-mode
   ``ragged_paged_attention`` kernel (the step's own K/V rows fold in with a
   causal mask — no separate prefill kernel, no analytic current-token
-  merge), commit all layers' fresh KV in ONE batched scatter at the end
-  (the cache stays strictly read-only until then, which is what lets XLA
-  alias the donated pool in place), then sample.  The layer loop is a
-  ``lax.scan`` over stacked per-layer weights and cache slices; each
-  layer's new K/V row is emitted as a scan output.
+  merge), commit all layers' fresh KV in ONE pass at the end (the cache
+  stays strictly read-only until then, which is what lets XLA alias the
+  donated pool in place), then sample.  The layer loop is a ``lax.scan``
+  over whole periods of the model's layer pattern (``models/
+  decoder_spec.py``; a stack of identical layers is the period of one),
+  over one stack of weights for each place in the period; the kernel
+  reads the pool by layer number, and each layer's new K/V row is
+  emitted as a scan output.
 - The step is compiled per (sampling config, T) where T is the query-token
   bucket: T=1 is pure decode, T=prefill_bucket is a chunked-prefill /
   mixed step.  Both compile once; **warm steps never recompile** (asserted
@@ -44,7 +47,7 @@ from __future__ import annotations
 
 import math
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass
 from typing import List, Optional, Sequence
 
@@ -61,9 +64,8 @@ from ..kernels.paged_attention import (kernel_geometry_error,
                                        write_kv_pages,
                                        write_kv_pages_all_layers,
                                        write_kv_pages_all_layers_quantized)
-from ..kernels.rms_norm import rms_norm_fp32
-from ..models.llama import LlamaConfig, LlamaForCausalLM, _rope_cos_sin
-from ..utils import extract_params, stack_params
+from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
+from ..models.llama import _rope_cos_sin
 from . import speculative as _sp
 from .kv_cache import PagedKVCache
 
@@ -160,19 +162,33 @@ def _rope_bt(x, cos, sin):
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
+def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
     """Routed SwiGLU expert mixture for the serving path (reference:
-    incubate fused_moe inference semantics).
+    incubate fused_moe inference semantics), one function for every
+    family: ``moe`` (``models.decoder_spec.MoeSpec``) states the router
+    (softmax or sigmoid scores in float32, top-k, renormalised or not),
+    the experts held here and the shared experts.  Returns ``(out, rows)``,
+    ``rows`` None or, where this chip holds a share of the experts,
+    int32 ``[entries that fell on held experts, rows laid out for them]``.
 
-    - grouped (``dispatch="grouped"``): the expert-sorted ragged-GEMM path
-      shared with training (``models.llama._grouped_ffn``) — each expert
-      runs over exactly its own rows, E/top_k-fold fewer FFN FLOPs than
-      the dense mixture.  Serves prefill chunks AND decode steps: the
+    - grouped (``moe.dispatch == "grouped"``): the expert-sorted ragged-GEMM
+      path shared with training (``models.llama._grouped_ffn``) — each
+      expert runs over exactly its own rows, E/top_k-fold fewer FFN FLOPs
+      than the dense mixture.  Serves prefill chunks AND decode steps: the
       row tile shrinks to fit the actual (token, choice) entry count so a
       decode batch doesn't pay a full ``block_m`` of padding per expert.
-    - dense (non-grouped configs): every expert runs under a lax.scan over
-      all rows, combined with top-k gate weights — exact routing, no
-      capacity, transients bounded to one expert.
+    - dense: every held expert runs under a lax.scan over all rows,
+      combined with top-k gate weights — exact routing, no capacity,
+      transients bounded to one expert.
+
+    A share of the experts (``moe.partial``): the router keeps its
+    published width; entries routed to experts this chip does not hold
+    are dropped BEFORE rows are laid out (they sort behind the held
+    experts' tiles, which alone are multiplied: ``gmm(live_tiles=)``) and
+    a gate keeps the value it has over all ``top_k`` chosen.  Dropless:
+    every entry of a held expert is computed, whatever their number.
+    ``live`` (bool, one a row of ``y``): rows that hold no token (the
+    padding of a step's row bucket) are routed nowhere and counted nowhere.
 
     ``mp_shards`` > 1 (tensor-parallel serving, inside a shard_map body):
     each shard runs the grouped path over its own E/mp expert bank —
@@ -184,20 +200,27 @@ def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
     exact +0.0, and IEEE addition of two values is order-insensitive
     bitwise for top_k <= 2 (the caller only enables sharding then).
     """
-    gw = lp["mlp.gate.weight"]              # [H, E]
+    from ..models import llama as _llama
+
+    gw = lp["mlp.gate.weight"]              # [H, router width]
     shape = y.shape
     xf = y.reshape(-1, shape[-1])
-    E = gw.shape[-1]
-    if dispatch == "grouped":
+    N = xf.shape[0]
+    top_k, E, held = moe.top_k, moe.num_experts, moe.held
+    rows = None
+    with jax.named_scope("router"):
+        topv, topi, _, _ = _llama._route_topk(xf, gw, top_k, moe.score)
+    if moe.partial:
+        local = topi - moe.offset
+        own = jnp.logical_and(local >= 0, local < held)       # [N, k]
+        if live is not None:
+            own = jnp.logical_and(own, live.reshape(N, 1))
+    if moe.dispatch == "grouped":
         from ..kernels.grouped_matmul import sorted_dispatch_plan
-        from ..models import llama as _llama
 
-        N = xf.shape[0]
         # decode batches carry a handful of rows: shrink the row tile to
         # the 8-row sublane multiple that covers them (same math, less pad)
-        bm = max(8, min(block_m, -(-N * top_k // 8) * 8))
-        with jax.named_scope("router"):
-            topv, topi, _, _ = _llama._route_topk(xf, gw, top_k)
+        bm = max(8, min(moe.block_m, -(-N * top_k // 8) * 8))
         if mp_shards and mp_shards > 1:
             E_loc = E // mp_shards
             my = jax.lax.axis_index(MP_AXIS)
@@ -234,33 +257,65 @@ def _moe_ffn(y, lp, top_k, dispatch="dense", block_m=128, mp_shards=None):
             out = parts[0]
             for s in range(1, mp_shards):
                 out = out + parts[s]
-            return out.reshape(shape)
+        elif moe.partial:
+            with jax.named_scope("experts"):
+                F = N * top_k
+                # entries of experts held elsewhere sort into a discard
+                # group behind the held experts' tiles
+                local_e = jnp.where(own, local, held).reshape(F)
+                inv, pos, tg = sorted_dispatch_plan(local_e, held + 1, bm)
+                M = inv.shape[0]
+                per = jnp.bincount(local_e, length=held + 1)[:held]
+                live_rows = (jnp.maximum(-(-per // bm), 1) * bm).sum() \
+                    .astype(jnp.int32)
+                inv = jnp.where(jnp.arange(M) < live_rows, inv, F)
+                own_flat = own.reshape(F)
+                pos = jnp.where(own_flat, pos, M)  # sentinel row reads zero
+                out, _ = _llama._grouped_ffn_fwd(
+                    xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
+                    lp["mlp.experts_down"], topv * own, inv, pos,
+                    jnp.minimum(tg, held - 1), held, top_k, bm,
+                    live_tiles=live_rows // bm)
+                rows = jnp.stack([own_flat.sum().astype(jnp.int32),
+                                  live_rows])
+        else:
+            with jax.named_scope("experts"):
+                inv, pos, tg = sorted_dispatch_plan(
+                    topi.reshape(N * top_k), E, bm)
+                out = _llama._grouped_ffn(
+                    xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
+                    lp["mlp.experts_down"], topv, inv, pos, tg, E, top_k,
+                    bm)
+    else:
+        with jax.named_scope("router"):
+            comb = jnp.zeros((N, E), jnp.float32).at[
+                jnp.arange(N)[:, None], topi].set(topv)
+            if moe.partial:
+                comb = comb[:, moe.offset:moe.offset + held]
+                rows = jnp.stack([own.sum().astype(jnp.int32),
+                                  jnp.int32(N * held)])
+
+        def step(acc, ex):
+            h = jax.nn.silu(xf @ ex["wg"]) * (xf @ ex["wu"])
+            return acc + ex["c"][:, None].astype(acc.dtype) \
+                * (h @ ex["wd"]), None
+
         with jax.named_scope("experts"):
-            inv, pos, tg = sorted_dispatch_plan(
-                topi.reshape(N * top_k), E, bm)
-            out = _llama._grouped_ffn(
-                xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
-                lp["mlp.experts_down"], topv, inv, pos, tg, E, top_k, bm)
-        return out.reshape(shape)
-    with jax.named_scope("router"):
-        probs = jax.nn.softmax(
-            xf.astype(jnp.float32) @ gw.astype(jnp.float32), axis=-1)
-        topv, topi = jax.lax.top_k(probs, top_k)
-        topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
-        comb = jnp.zeros_like(probs).at[
-            jnp.arange(xf.shape[0])[:, None], topi].set(topv)
-
-    def step(acc, ex):
-        h = jax.nn.silu(xf @ ex["wg"]) * (xf @ ex["wu"])
-        return acc + ex["c"][:, None].astype(acc.dtype) * (h @ ex["wd"]), None
-
-    with jax.named_scope("experts"):
-        acc0 = jnp.zeros(xf.shape, xf.dtype)
-        out, _ = jax.lax.scan(step, acc0, {
-            "wg": lp["mlp.experts_gate"], "wu": lp["mlp.experts_up"],
-            "wd": lp["mlp.experts_down"],
-            "c": comb.T.astype(xf.dtype)})
-    return out.reshape(shape)
+            acc0 = jnp.zeros(xf.shape, xf.dtype)
+            out, _ = jax.lax.scan(step, acc0, {
+                "wg": lp["mlp.experts_gate"], "wu": lp["mlp.experts_up"],
+                "wd": lp["mlp.experts_down"],
+                "c": comb.T.astype(xf.dtype)})
+    if moe.shared:
+        # the shared experts, side by side along the width ([H, S * I] and
+        # [S * I, H]): ordinary dense GEMMs over the same rows, whose
+        # down-projection adds the S outputs up
+        with jax.named_scope("shared_experts"):
+            act = jax.nn.silu(xf @ lp["mlp.shared_gate_proj.weight"]) * \
+                (xf @ lp["mlp.shared_up_proj.weight"])
+            sh = act @ lp["mlp.shared_down_proj.weight"]
+            out = out + sh * jnp.asarray(1.0 / moe.shared, sh.dtype)
+    return out.reshape(shape), rows
 
 
 def _filter_logits(logits, gc: GenerationConfig):
@@ -303,18 +358,22 @@ def _sample(logits, key, pos, gc: GenerationConfig):
 
 
 class LlamaGenerator:
-    """Batch text generation for ``LlamaForCausalLM`` with paged KV."""
+    """Batch text generation with paged KV for any decoder that states its
+    ``decoder_spec()`` and hands out its ``serving_params()``
+    (``models/decoder_spec.py``): what differs between the families (layer
+    pattern, norm, block form, rotary pairing, windows, head size, router,
+    shared and held experts, tied head) is data of the model here."""
 
-    def __init__(self, model: LlamaForCausalLM, *, max_batch: int = 8,
+    def __init__(self, model, *, max_batch: int = 8,
                  max_seq_len: Optional[int] = None, page_size=32,
                  cache_dtype: Optional[str] = None,
                  prefill_bucket: int = 64, sync_every: int = 8,
                  num_pages: Optional[int] = None,
                  tensor_parallel: Optional[int] = None):
-        c = model.config
-        self.config = c
+        self.config = model.config
+        c = self.spec = model.decoder_spec()
         self.max_batch = max_batch
-        self.max_seq_len = max_seq_len or c.max_position_embeddings
+        self.max_seq_len = max_seq_len or model.config.max_position_embeddings
         # tensor-parallel serving (FLAGS_serving_tensor_parallel): tp > 1
         # shards the whole fused step over the `mp` mesh axis — attention
         # by kv-head, grouped MoE by expert, everything else replicated —
@@ -327,11 +386,10 @@ class LlamaGenerator:
                 raise ValueError(
                     f"tensor_parallel={tp} needs {tp} devices, have "
                     f"{len(jax.devices())}")
-            if c.num_key_value_heads % tp or c.num_attention_heads % tp:
+            if c.num_kv_heads % tp or c.num_heads % tp:
                 raise ValueError(
                     f"tensor_parallel={tp} must divide num_kv_heads="
-                    f"{c.num_key_value_heads} and num_heads="
-                    f"{c.num_attention_heads}")
+                    f"{c.num_kv_heads} and num_heads={c.num_heads}")
             self.mesh = jax.sharding.Mesh(
                 np.asarray(jax.devices()[:tp]), (MP_AXIS,))
         else:
@@ -340,10 +398,18 @@ class LlamaGenerator:
         # grouped MoE shards by expert only where the discard-group
         # combine is provably bit-exact (top_k <= 2: at most two nonzero
         # terms per token, IEEE pairwise-commutative) and the bank
-        # divides; otherwise the mixture stays replicated under tp
+        # divides; otherwise the mixture stays replicated under tp (as it
+        # does where this chip holds a share of the experts already)
+        moe = c.moe
         self._moe_shards = tp if (
-            tp > 1 and c.moe_num_experts and c.moe_dispatch == "grouped"
-            and c.moe_top_k <= 2 and c.moe_num_experts % tp == 0) else None
+            tp > 1 and moe is not None and moe.dispatch == "grouped"
+            and moe.top_k <= 2 and not moe.partial
+            and moe.num_experts % tp == 0) else None
+        # a share of the experts: the step also returns how many entries
+        # fell on held experts and the rows laid out for them
+        self.counts_moe_rows = moe is not None and moe.partial
+        self._layers_by_window = tuple(Counter(c.windows).items())
+        dtype = str(model.config.dtype)
         if cache_dtype is None:
             # FLAGS_kv_cache_dtype: "auto" follows the model dtype;
             # "int8" turns on the quantized memory plane (ISSUE 13)
@@ -357,8 +423,8 @@ class LlamaGenerator:
             # back to 32 on a cold cache (phi autotune-cache idiom)
             from ..kernels import autotune
             page_size = autotune.lookup(autotune.make_key(
-                "paged_decode", heads=c.num_key_value_heads,
-                d=c.head_dim, dt=str(cache_dtype or c.dtype))) or 32
+                "paged_decode", heads=c.num_kv_heads,
+                d=c.head_dim, dt=str(cache_dtype or dtype))) or 32
             if isinstance(page_size, (tuple, list)):
                 page_size = page_size[0]
         page_size = int(page_size)
@@ -367,7 +433,13 @@ class LlamaGenerator:
         self.sync_every = sync_every
         self.pages_per_seq = -(-self.max_seq_len // page_size)
 
-        self.params = self._extract(model)
+        # the model's own layout for the engine's scan: a tuple with one
+        # dict of [periods, ...] stacks for each place in the layer pattern
+        self.params = model.serving_params()
+        if len(self.params["blocks"]) != len(c.pattern):
+            raise ValueError(
+                f"serving_params() has {len(self.params['blocks'])} block "
+                f"stacks, the layer pattern {len(c.pattern)} places")
         if self.mesh is not None:
             # the step's shard_map takes the weights replicated: place them
             # on every device of the mesh ONCE, or each dispatch re-copies
@@ -386,26 +458,28 @@ class LlamaGenerator:
             # chip: refuse a geometry it does not cover now, not mid-trace
             why = kernel_geometry_error(
                 page_size, c.head_dim,
-                quantized=str(cache_dtype or c.dtype) == "int8",
-                kv_heads=c.num_key_value_heads // tp,
+                quantized=str(cache_dtype or dtype) == "int8",
+                kv_heads=c.num_kv_heads // tp,
                 num_pages=self.num_pages,
                 table_shape=(max_batch, self.pages_per_seq))
             if why:
                 raise ValueError(
                     f"engine geometry is not served by the paged-attention "
                     f"kernel on TPU: {why}")
+        # a uniform pool: every layer keeps every page, whatever its window
+        # (pages behind a sliding layer's window are held and never read)
         self.cache = PagedKVCache(
-            num_layers=c.num_hidden_layers,
+            num_layers=c.num_layers,
             num_pages=self.num_pages,
-            page_size=page_size, num_kv_heads=c.num_key_value_heads,
-            head_dim=c.head_dim, dtype=cache_dtype or c.dtype,
+            page_size=page_size, num_kv_heads=c.num_kv_heads,
+            head_dim=c.head_dim, dtype=cache_dtype or dtype,
             mesh=self.mesh, axis=MP_AXIS)
         # host-global pool bytes (all shards) — advertised via stats() /
         # /statusz so the router's capacity-weighted placement can rank
         # heterogeneous replicas
         self.pool_bytes = self.num_pages * PagedKVCache.bytes_per_page(
-            c.num_hidden_layers, c.num_key_value_heads, page_size,
-            c.head_dim, cache_dtype or c.dtype)
+            c.num_layers, c.num_kv_heads, page_size,
+            c.head_dim, cache_dtype or dtype)
         if _obs.metrics_enabled():
             from ..observability import metrics as _metrics
             _metrics.gauge("serving.tp.degree").set(tp)
@@ -417,17 +491,28 @@ class LlamaGenerator:
         self._jit_cache = {}
         self._metrics_on = _obs.metrics_enabled()
 
-    # ---- params ----
-    def _extract(self, model: LlamaForCausalLM):
-        blocks = stack_params([extract_params(l) for l in model.llama.layers])
-        head = (model.lm_head.weight._data if model.lm_head is not None
-                else model.llama.embed_tokens.weight._data.T)
-        return {
-            "embed": model.llama.embed_tokens.weight._data,
-            "head": head,
-            "norm": model.llama.norm.weight._data,
-            "blocks": blocks,
-        }
+    def kv_read_tokens(self, rows) -> int:
+        """Key tokens the step's attention reads for ``rows`` = [(query
+        tokens, context before them)], summed over the layers with each
+        layer's window applied (a windowed layer reads from the first key
+        its earliest query token sees)."""
+        n = 0
+        for window, layers in self._layers_by_window:
+            for q, ctx in rows:
+                first = 0 if window is None else max(0, ctx + 1 - window)
+                n += layers * (ctx + q - first)
+        return n
+
+    def _head_logits(self, params, h):
+        """float32 logits of hidden states ``h [..., H]``: the head, or the
+        embedding itself where the model ties them (no transposed copy)."""
+        with jax.named_scope("head"):
+            if "head" in params:
+                logits = (h @ params["head"]).astype(jnp.float32)
+            else:
+                logits = jnp.einsum("...h,vh->...v", h,
+                                    params["embed"]).astype(jnp.float32)
+        return logits
 
     def _tp_jit(self, fn, name, n_in, n_out, out_cache_idx):
         """jit one engine program under ``name`` (a profiler trace's ``XLA
@@ -500,7 +585,8 @@ class LlamaGenerator:
             self._jit_cache[key] = self._tp_jit(
                 functools.partial(self._step_fn, gc, t, track, rows),
                 f"serve_step_T{t}",
-                n_in=13 if track else 12, n_out=8 if track else 7,
+                n_in=13 if track else 12,
+                n_out=(8 if track else 7) + int(self.counts_moe_rows),
                 out_cache_idx=5)
         return self._jit_cache[key]
 
@@ -535,9 +621,10 @@ class LlamaGenerator:
         """Run the whole model over this step's query tokens: derive write
         slots in-jit from the block table, stream every layer through the
         mixed-mode ``ragged_paged_attention`` kernel (the step's own K/V
-        rows fold in causally), commit all layers' fresh KV in ONE batched
-        scatter, and return the final-norm hidden states for ALL T
-        positions.  Callers own freeze semantics, sampling and
+        rows fold in causally), commit all layers' fresh KV in ONE pass,
+        and return the final-norm hidden states for ALL T
+        positions, the updated pool and (a share of the experts only,
+        else None) the step's MoE row counts.  Callers own freeze semantics, sampling and
         bookkeeping — this core is shared verbatim by the plain step, the
         T=K speculative verify step and the fused K-step decode loop, so
         a prefill chunk, a decode token and a draft verification are
@@ -561,7 +648,7 @@ class LlamaGenerator:
         either way), so the kernel and the commit see today's shapes; the
         hidden states come back in their places too, zero past ``ql[b]``.
         """
-        c = self.config
+        c = self.spec
         B, T = tokens.shape
         page = self.page_size
         quant = len(cache) == 4
@@ -569,6 +656,7 @@ class LlamaGenerator:
             kc, vc, ks, vs = cache
         else:
             kc, vc = cache
+            ks = vs = None
         tp = self.tp
         if tp > 1:
             # inside the shard_map body: this shard's contiguous head
@@ -577,8 +665,8 @@ class LlamaGenerator:
             # [i*qh_l, (i+1)*qh_l) that attend to them — the all_gather
             # on the head axis reassembles the oracle's layout bitwise
             shard = jax.lax.axis_index(MP_AXIS)
-            qh_l = c.num_attention_heads // tp
-            kvh_l = c.num_key_value_heads // tp
+            qh_l = c.num_heads // tp
+            kvh_l = c.num_kv_heads // tp
 
         # token positions & write slots, derived in-jit from the block table
         offs = jnp.arange(T, dtype=jnp.int32)
@@ -620,33 +708,31 @@ class LlamaGenerator:
             h = jnp.where(live[None, :, None], h, jnp.zeros((), h.dtype))
         R0, R1 = h.shape[:2]              # B, T, or 1, rows when packed
 
-        moe = "mlp.experts_gate" in params["blocks"]     # MoE model serving
+        moe = c.moe
+        norm_fn = rms_norm_fp32 if c.norm == "rms" else layer_norm_fp32
 
-        def layer(carry, xs):
-            x, = carry
-            if quant:
-                lp, kcl, vcl, ksl, vsl = xs   # cache slices: READ-ONLY
-            else:
-                lp, kcl, vcl = xs
-                ksl = vsl = None
+        def one_layer(x, lp, kind, layer, ksl, vsl):
+            """Decoder layer number ``layer``, of ``kind``, reading the pool
+            (READ-ONLY; the kernel takes the whole pool and the layer, no
+            layer is sliced out of it): (x, this step's k, v, MoE rows)."""
             with jax.named_scope("attention"):
-                y = rms_norm_fp32(x, lp["input_layernorm.weight"],
-                                  c.rms_norm_eps)
+                y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
                 q = (y @ lp["self_attn.q_proj.weight"]).reshape(
-                    R0, R1, c.num_attention_heads, c.head_dim)
+                    R0, R1, c.num_heads, c.head_dim)
                 k = (y @ lp["self_attn.k_proj.weight"]).reshape(
-                    R0, R1, c.num_key_value_heads, c.head_dim)
+                    R0, R1, c.num_kv_heads, c.head_dim)
                 v = (y @ lp["self_attn.v_proj.weight"]).reshape(
-                    R0, R1, c.num_key_value_heads, c.head_dim)
-                q = _rope_bt(q, cos, sin)
-                k = _rope_bt(k, cos, sin)
+                    R0, R1, c.num_kv_heads, c.head_dim)
+                if kind.rope:
+                    q = _rope_bt(q, cos, sin)
+                    k = _rope_bt(k, cos, sin)
                 if packed:
                     q, k, v = unpack(q), unpack(k), unpack(v)
                 # prior context from the paged cache + this step's own rows
                 # (causal), one mixed-mode kernel call; the fresh rows are
                 # committed to the cache only at the end of the step.  Under
-                # tp the cache slices kcl/vcl are already this shard's head
-                # planes (the scan carries per-shard storage), q/k/v slice to
+                # tp the pool kc/vc is already this shard's head planes
+                # (per-shard storage), q/k/v slice to
                 # the matching head block, and each shard's kernel DMAs only
                 # its own heads' pages; the head-axis all_gather restores the
                 # full [B, T, qh, d] activation for the replicated o_proj
@@ -659,36 +745,76 @@ class LlamaGenerator:
                         v, shard * kvh_l, kvh_l, axis=2)
                 else:
                     q_a, k_a, v_a = q, k, v
-                attn = ragged_paged_attention(q_a, kcl, vcl, block_tables,
+                attn = ragged_paged_attention(q_a, kc, vc, block_tables,
                                               ctx_prev, q_lens=ql,
                                               k_new=k_a, v_new=v_a,
-                                              k_scale=ksl, v_scale=vsl)
+                                              k_scale=ksl, v_scale=vsl,
+                                              window=kind.window,
+                                              layer=layer)
                 if tp > 1:
                     attn = jax.lax.all_gather(attn, MP_AXIS, axis=2,
                                               tiled=True)
                 attn = attn.reshape(B, T, -1)
                 if packed:
                     attn = pack(attn)
-                x = x + attn @ lp["self_attn.o_proj.weight"]
-            with jax.named_scope("moe" if moe else "mlp"):
-                y = rms_norm_fp32(x, lp["post_attention_layernorm.weight"],
-                                  c.rms_norm_eps)
-                if moe:
-                    x = x + _moe_ffn(y, lp, c.moe_top_k,
-                                     dispatch=c.moe_dispatch,
-                                     block_m=c.moe_block_m,
-                                     mp_shards=self._moe_shards)
+                a = attn @ lp["self_attn.o_proj.weight"]
+                if not c.parallel_block:
+                    x = x + a
+            with jax.named_scope("moe" if moe is not None else "mlp"):
+                if not c.parallel_block:     # else the FFN reads the same y
+                    y = norm_fn(x, lp["post_attention_layernorm.weight"],
+                                c.norm_eps)
+                n_rows = None
+                if moe is not None:
+                    f, n_rows = _moe_ffn(
+                        y, lp, moe, mp_shards=self._moe_shards,
+                        live=live if packed else valid)
                 else:
                     act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
                         (y @ lp["mlp.up_proj.weight"])
-                    x = x + act @ lp["mlp.down_proj.weight"]
-            return (x,), (k, v)
+                    f = act @ lp["mlp.down_proj.weight"]
+                x = x + a + f if c.parallel_block else x + f
+            return x, k, v, n_rows
 
-        xs = (params["blocks"], kc, vc, ks, vs) if quant else \
-            (params["blocks"], kc, vc)
-        (h,), (k_all, v_all) = jax.lax.scan(layer, (h,), xs)
-        L = k_all.shape[0]
-        kvh, dh = c.num_key_value_heads, c.head_dim
+        # the scan runs over whole periods of the layer pattern, a period's
+        # layers unrolled inside; what is scanned is one [periods, ...]
+        # stack a place (and the int8 scale planes, split [periods, places])
+        P = len(c.pattern)
+
+        def by_period(a):
+            return None if a is None else \
+                a.reshape((c.periods, P) + a.shape[1:])
+
+        def period(carry, xs):
+            x, = carry
+            r, blocks, ksp, vsp = xs
+            ks_new, vs_new, n_rows = [], [], None
+            for p, kind in enumerate(c.pattern):
+                x, k, v, n = one_layer(
+                    x, blocks[p], kind, r * P + p,
+                    None if ksp is None else ksp[p],
+                    None if vsp is None else vsp[p])
+                ks_new.append(k)
+                vs_new.append(v)
+                if n is not None:
+                    n_rows = n if n_rows is None else n_rows + n
+            return (x,), (jnp.stack(ks_new), jnp.stack(vs_new), n_rows)
+
+        xs = (jnp.arange(c.periods, dtype=jnp.int32), params["blocks"],
+              by_period(ks), by_period(vs))
+        if c.periods == 1:
+            # nothing to scan over: the one period runs in line on the
+            # stacks' only slice (a view; a scan's slices are copies)
+            (h,), ys = period((h,), jax.tree_util.tree_map(
+                lambda a: a[0], xs))
+            k_all, v_all, moe_rows = jax.tree_util.tree_map(
+                lambda a: a[None], ys)
+        else:
+            (h,), (k_all, v_all, moe_rows) = jax.lax.scan(period, (h,), xs)
+        if moe_rows is not None:
+            moe_rows = moe_rows.sum(axis=0)        # over the periods: [2]
+        L = c.num_layers
+        kvh, dh = c.num_kv_heads, c.head_dim
         k_all = k_all.reshape(L, B * T, kvh, dh)
         v_all = v_all.reshape(L, B * T, kvh, dh)
         if tp > 1:
@@ -714,8 +840,8 @@ class LlamaGenerator:
                                                    slots)
                 out_cache = (kc, vc)
 
-        h = rms_norm_fp32(h, params["norm"], c.rms_norm_eps)
-        return (unpack(h) if packed else h), out_cache
+        h = norm_fn(h, params["norm"], c.norm_eps)
+        return (unpack(h) if packed else h), out_cache, moe_rows
 
     # ---- the ONE engine step ----
     def _step_fn(self, gc, T, track_recent, rows, params, cache, tokens,
@@ -750,12 +876,11 @@ class LlamaGenerator:
         finished = jnp.logical_or(finished, positions >= self.max_seq_len)
         ql = jnp.where(finished, 0, q_lens).astype(jnp.int32)
 
-        h, cache = self._forward_tokens(params, cache, tokens, ql,
-                                        positions, block_tables, rows)
+        h, cache, moe_rows = self._forward_tokens(
+            params, cache, tokens, ql, positions, block_tables, rows)
         last_ix = jnp.maximum(ql - 1, 0)
         last = jnp.take_along_axis(h, last_ix[:, None, None], axis=1)[:, 0]
-        with jax.named_scope("head"):
-            logits = (last @ params["head"]).astype(jnp.float32)
+        logits = self._head_logits(params, last)
         # positional sampling keys: the token being sampled lands at
         # sequence index positions + ql; the chained key never advances
         # (determinism across batch shapes and replicas — see _sample)
@@ -773,8 +898,10 @@ class LlamaGenerator:
         if track_recent:
             recent = _sp.shift_append(recent, out_tokens[:, None],
                                       committed.astype(jnp.int32))
-            return out + (recent,)
-        return out
+            out = out + (recent,)
+        # last, where this chip holds a share of the experts: [entries on
+        # held experts, rows laid out], read at the drain that exists
+        return out + (moe_rows,) if self.counts_moe_rows else out
 
     # ---- ISSUE 9: the T=K speculative verify step (ngram mode) ----
     def _spec_verify_fn(self, gc, K, nmax, params, cache, last_tok, recent,
@@ -814,11 +941,10 @@ class LlamaGenerator:
         drafted = jnp.maximum(ql - 1, 0)          # drafts actually dispatched
         tokens = jnp.concatenate([last_tok[:, None], drafts], axis=1)
 
-        h, cache = self._forward_tokens(params, cache, tokens, ql,
-                                        positions, block_tables)
+        h, cache, _ = self._forward_tokens(params, cache, tokens, ql,
+                                           positions, block_tables)
         B = tokens.shape[0]
-        with jax.named_scope("head"):
-            logits = (h @ params["head"]).astype(jnp.float32)  # [B, K, V]
+        logits = self._head_logits(params, h)                  # [B, K, V]
         # one positional key per (row, slot): slot j samples the token
         # at sequence index positions + j + 1 — token-level sequential
         # sampling semantics (greedy ignores the keys entirely)
@@ -876,10 +1002,9 @@ class LlamaGenerator:
             ql = jnp.where(jnp.logical_or(finished,
                                           positions >= write_caps),
                            0, 1).astype(jnp.int32)
-            h, cache = self._forward_tokens(params, cache, tok[:, None],
-                                            ql, positions, block_tables)
-            with jax.named_scope("head"):
-                logits = (h[:, 0] @ params["head"]).astype(jnp.float32)
+            h, cache, _ = self._forward_tokens(params, cache, tok[:, None],
+                                               ql, positions, block_tables)
+            logits = self._head_logits(params, h[:, 0])
             sampled = _sample(logits, key, positions + ql, gc)
             out = jnp.where(ql > 0, sampled, tok)
             positions = positions + ql
@@ -947,7 +1072,7 @@ class LlamaGenerator:
             out, positions, finished, _ad, counts, cache, key = step_p(
                 self.params, self.cache.arrays, jnp.asarray(chunk),
                 jnp.asarray(ql), positions, finished, no_mask,
-                jnp.asarray(commit), counts, budgets, bt_dev, key)
+                jnp.asarray(commit), counts, budgets, bt_dev, key)[:7]
             self.cache.update(*cache)
             first = jnp.where(jnp.asarray(commit), out, first)
 
@@ -983,7 +1108,7 @@ class LlamaGenerator:
             tokens, positions, finished, all_done, counts, cache, key = \
                 step_d(self.params, self.cache.arrays, tokens[:, None],
                        ql1, positions, finished, all_mask, all_mask,
-                       counts, budgets, bt_dev, key)
+                       counts, budgets, bt_dev, key)[:7]
             self.cache.update(*cache)
             collected.append(tokens)
             host_lens = np.minimum(host_lens + 1, self.max_seq_len)
@@ -1013,7 +1138,7 @@ class LlamaGenerator:
         return out
 
 
-def generate(model: LlamaForCausalLM, prompts, gen: Optional[GenerationConfig] = None,
+def generate(model, prompts, gen: Optional[GenerationConfig] = None,
              **kw) -> List[List[int]]:
     """One-shot convenience: build a generator sized to the request."""
     gen = gen or GenerationConfig()
@@ -1065,7 +1190,8 @@ class _ServingMetrics:
                  "occupancy", "steps", "drains", "pages_in_use",
                  "peak_pages", "active_seqs", "cached_pages",
                  "evictable_pages", "spec_drafted", "spec_accepted",
-                 "spec_rejected", "accept_len", "digest_epoch")
+                 "spec_rejected", "accept_len", "digest_epoch",
+                 "moe_held_rows", "moe_rows_laid_out")
 
     def __init__(self):
         m = _obs.metrics
@@ -1078,6 +1204,15 @@ class _ServingMetrics:
         self.accept_len = m.histogram(
             "serving.spec.accept_len",
             bounds=[0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0])
+        # a share of the experts (held < the router's width): one
+        # observation a step, the sum over the layers, folded in at the
+        # drain — (token, choice) entries that fell on held experts and
+        # the rows the grouped GEMM laid out for them
+        rows_bounds = [float(2 ** i) for i in range(4, 21)]
+        self.moe_held_rows = m.histogram("serving.moe_held_rows",
+                                         bounds=rows_bounds)
+        self.moe_rows_laid_out = m.histogram("serving.moe_rows_laid_out",
+                                             bounds=rows_bounds)
         self.requests = m.counter("serving.requests_total")
         self.completed = m.counter("serving.requests_completed")
         self.tokens = m.counter("serving.tokens_generated")
@@ -1141,7 +1276,7 @@ class ContinuousBatchingEngine:
     cache on bit-match the cache-off oracle.
     """
 
-    def __init__(self, model: LlamaForCausalLM, *, max_batch: int = 8,
+    def __init__(self, model, *, max_batch: int = 8,
                  gen: Optional[GenerationConfig] = None,
                  prefix_cache: Optional[bool] = None,
                  metrics: Optional[bool] = None,
@@ -1174,6 +1309,9 @@ class ContinuousBatchingEngine:
         # n_commit_dev [B], drafted_dev [B] | None, t_disp) for
         # speculative dispatches — drained together
         self._pending: List[tuple] = []
+        # a share of the experts: each plain step's [entries on held
+        # experts, rows laid out], device values drained with the window
+        self._pending_moe_rows: List = []
         self._steps_since_drain = 0
         self._step_no = 0               # running number of step() calls
         # per-slot hard cap on VALID generated tokens, set when a sequence
@@ -1395,7 +1533,8 @@ class ContinuousBatchingEngine:
         early_done: List[Request] = []
         if all(r is None for r in self.slot_req):
             span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
-                              gemm_rows=0, waiting=len(self.waiting))
+                              gemm_rows=0, kv_read_tokens=0,
+                              waiting=len(self.waiting))
             return self._drain() if self._pending else []
         g = self.g
         B = self.B
@@ -1435,8 +1574,12 @@ class ContinuousBatchingEngine:
                                 + self._upload_caps())
             rows = sum(r is not None for r in self.slot_req)
             k = int(self.spec.k)
-            span.set_metadata(kind="spec", T=k, rows=rows, q_tokens=rows * k,
-                              gemm_rows=B * k, waiting=len(self.waiting))
+            span.set_metadata(
+                kind="spec", T=k, rows=rows, q_tokens=rows * k,
+                gemm_rows=B * k, waiting=len(self.waiting),
+                kv_read_tokens=g.kv_read_tokens(
+                    [(k, max(int(self.host_lens[b]) - k, 0))
+                     for b in range(B) if self.slot_req[b] is not None]))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
             self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
@@ -1463,6 +1606,7 @@ class ContinuousBatchingEngine:
             commit = np.zeros((B,), bool)
             chunk = np.zeros((B, T), np.int32)
             rows = 0
+            attends = []         # (query tokens, context before them) a row
             for b in range(B):
                 req = self.slot_req[b]
                 if req is None:
@@ -1474,6 +1618,7 @@ class ContinuousBatchingEngine:
                     # they're ready
                     continue
                 rem = len(req.prompt) - int(self.prompt_pos[b])
+                attends.append((min(max(rem, 1), T), int(self.host_lens[b])))
                 if rem > 0:                      # prefill chunk
                     n = min(rem, T)
                     ql[b] = n
@@ -1510,21 +1655,20 @@ class ContinuousBatchingEngine:
         step = self._step_family(T)[gemm_rows]
         span.set_metadata(kind="mixed" if T > 1 else "decode", T=int(T),
                           rows=rows, q_tokens=q_tokens, gemm_rows=gemm_rows,
+                          kv_read_tokens=g.kv_read_tokens(attends),
                           waiting=len(self.waiting))
         with tracer.span("engine.dispatch", program=f"serve_step_T{T}"):
+            out = step(g.params, g.cache.arrays, tokens_in, ql_dev,
+                       self.positions, self.finished, dm, commit_dev,
+                       self.counts, self.budgets, self._bt_dev, self.key,
+                       *((self._recent,) if track else ()))
+            (self.tokens, self.positions, self.finished, _all_done,
+             self.counts, cache, self.key) = out[:7]
             if track:
-                (self.tokens, self.positions, self.finished, _all_done,
-                 self.counts, cache, self.key, self._recent) = step(
-                    g.params, g.cache.arrays, tokens_in, ql_dev,
-                    self.positions, self.finished, dm, commit_dev,
-                    self.counts, self.budgets, self._bt_dev, self.key,
-                    self._recent)
-            else:
-                (self.tokens, self.positions, self.finished, _all_done,
-                 self.counts, cache, self.key) = step(
-                    g.params, g.cache.arrays, tokens_in, ql_dev,
-                    self.positions, self.finished, dm, commit_dev,
-                    self.counts, self.budgets, self._bt_dev, self.key)
+                self._recent = out[7]
+            if g.counts_moe_rows:
+                # device values until the drain that exists reads them
+                self._pending_moe_rows.append(out[-1])
             g.cache.update(*cache)
         # host dispatch timestamp rides the pending window: the drain
         # stamps TTFT/ITL per committed token from it — dispatch-side
@@ -1826,13 +1970,14 @@ class ContinuousBatchingEngine:
             return []
         with _obs.TRACER.span("engine.drain",
                               steps=len(self._pending)) as span:
-            done, n_tokens = self._drain_pending()
-            span.set_metadata(tokens=n_tokens)
+            done, n_tokens, held_rows = self._drain_pending()
+            span.set_metadata(tokens=n_tokens, held_rows=held_rows)
         return done
 
     def _drain_pending(self) -> tuple:
         """The pending window to the host and into its requests: (requests
-        retired, tokens delivered)."""
+        retired, tokens delivered, entries that fell on held experts: 0
+        unless this chip holds a share of them)."""
         # per-array host transfers, NOT a device-side stack: the pending
         # window length varies (partial windows at tail/run end) and a
         # jnp.stack would compile one executable per distinct length —
@@ -1848,6 +1993,14 @@ class ContinuousBatchingEngine:
                        None if dl is None else np.asarray(dl), t)
                       for kind, out, cm, dl, t in self._pending]
             fin = np.asarray(self.finished)
+            moe_rows = [np.asarray(r) for r in self._pending_moe_rows]
+        self._pending_moe_rows.clear()
+        held_rows = 0
+        for held, laid_out in moe_rows:
+            held_rows += int(held)
+            if obs is not None:
+                obs.moe_held_rows.observe(float(held))
+                obs.moe_rows_laid_out.observe(float(laid_out))
         # the moment this window's tokens became visible to the host —
         # the only progress of the device the host can observe
         t_ready = time.perf_counter()
@@ -1873,7 +2026,7 @@ class ContinuousBatchingEngine:
             # the drain IS a phase: the steady state's one blocking
             # host<->device transfer plus retire bookkeeping
             attr.observe_host("drain", time.perf_counter() - t_drain0)
-        return done, n_tokens
+        return done, n_tokens, held_rows
 
     def _retire_rows(self, window, fin, t_ready: float) -> tuple:
         """Per-row bookkeeping of a drained window: tokens into their

@@ -216,11 +216,12 @@ def _gather_rows(src_ref, rows_ref, base, col0, ncols, dst_ref, sem, bm):
 
 # ------------------------------------------------------------------ gmm ---
 
-def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled):
+def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled, ragged=False):
     from jax.experimental import pallas as pl
 
     it = iter(refs)
     group_ref = next(it)
+    live_ref = next(it) if ragged else None
     rows_ref = next(it) if fused else None
     lhs_ref = next(it)
     rhs_ref = next(it)
@@ -230,32 +231,43 @@ def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled):
     acc_ref = next(it)
     sem = next(it) if fused else None
     del group_ref
+    # read outside every pl.when: interpret mode has no rule for it inside
+    i, kk = pl.program_id(0), pl.program_id(2)
 
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def tile():
+        @pl.when(kk == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if fused:
-        _gather_rows(lhs_ref, rows_ref, pl.program_id(0) * bm,
-                     pl.program_id(2) * bk, bk, lx_ref, sem, bm)
-        lblk = lx_ref[...]
+        if fused:
+            _gather_rows(lhs_ref, rows_ref, i * bm, kk * bk, bk, lx_ref,
+                         sem, bm)
+            lblk = lx_ref[...]
+        else:
+            lblk = lhs_ref[...]
+        if scaled:
+            lblk = lblk * scale_ref[...]
+
+        dims = (((1,), (1,)), ((), ())) if trans_rhs \
+            else (((1,), (0,)), ((), ()))
+        acc_ref[...] += jax.lax.dot_general(
+            lblk, rhs_ref[...], dims,
+            preferred_element_type=jnp.float32)
+
+        @pl.when(kk == nk - 1)
+        def _flush():
+            out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+    if ragged:
+        # a row tile past the live ones holds no expert's rows: nothing is
+        # multiplied and (the index maps park on one block) nothing moves
+        pl.when(i < live_ref[0])(tile)
     else:
-        lblk = lhs_ref[...]
-    if scaled:
-        lblk = lblk * scale_ref[...]
-
-    dims = (((1,), (1,)), ((), ())) if trans_rhs else (((1,), (0,)), ((), ()))
-    acc_ref[...] += jax.lax.dot_general(
-        lblk, rhs_ref[...], dims,
-        preferred_element_type=jnp.float32)
-
-    @pl.when(pl.program_id(2) == nk - 1)
-    def _flush():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        tile()
 
 
 def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
-        interpret=None, rows=None, row_scale=None):
+        interpret=None, rows=None, row_scale=None, live_tiles=None):
     """Grouped matmul: ``out[m, :] = lhs[m, :] @ rhs[tile_groups[m//bm]]``.
 
     lhs: [M, C] with rows grouped by expert, group spans bm-aligned.
@@ -271,6 +283,14 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
     HBM).  row_scale: optional fp [M] per-row multiplier fused the same
     way (diag(s) @ lhs[rows] @ rhs — the combine-weight scaling of the
     MoE backward).  Returns [M, O] in lhs.dtype.
+
+    live_tiles: optional int32 scalar (a device value) — only the first
+    ``live_tiles`` row tiles hold rows some expert owns (a layer that
+    holds a share of the experts sorts the entries of the others last).
+    The tiles after them are skipped: their blocks are neither fetched
+    nor multiplied, and their rows of the result are left unwritten
+    (callers never read them: ``take_sentinel_rows`` on live positions).
+    At least one tile must be live.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -298,6 +318,26 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         lhs = lhs * row_scale[:, None].astype(lhs.dtype)
 
     scalars = [tile_groups.astype(jnp.int32)]
+    ragged = live_tiles is not None
+    if ragged:
+        scalars.append(jnp.asarray(live_tiles, jnp.int32).reshape(1))
+        nj = O // bn
+
+        def park(fn):
+            """``fn``'s block for a live tile; for every tile after them
+            the last live tile's last block, so that consecutive dead
+            steps name one block and the pipeline moves nothing."""
+            def index_map(i, j, k, g, live, *_):
+                # np.int32 constants: a bare python int is an i64 under
+                # x64 mode, and the convert breaks Mosaic lowering
+                dead = i >= live[0]
+                i_ = jnp.minimum(i, live[0] - np.int32(1))
+                return fn(i_, jnp.where(dead, np.int32(nj - 1), j),
+                          jnp.where(dead, np.int32(nk - 1), k), g)
+            return index_map
+    else:
+        def park(fn):
+            return lambda i, j, k, g, *_: fn(i, j, k, g)
     in_specs = []
     operands = []
     if fused:
@@ -305,16 +345,16 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     else:
         in_specs.append(
-            pl.BlockSpec((bm, bk), lambda i, j, k, g, *_: (i, k)))
+            pl.BlockSpec((bm, bk), park(lambda i, j, k, g: (i, k))))
     operands.append(lhs)
     in_specs.append(
-        pl.BlockSpec((None, bn, bk), lambda i, j, k, g, *_: (g[i], j, k))
+        pl.BlockSpec((None, bn, bk), park(lambda i, j, k, g: (g[i], j, k)))
         if trans_rhs else
-        pl.BlockSpec((None, bk, bn), lambda i, j, k, g, *_: (g[i], k, j)))
+        pl.BlockSpec((None, bk, bn), park(lambda i, j, k, g: (g[i], k, j))))
     operands.append(rhs)
     if scaled:
         in_specs.append(
-            pl.BlockSpec((bm, 1), lambda i, j, k, g, *_: (i, 0)))
+            pl.BlockSpec((bm, 1), park(lambda i, j, k, g: (i, 0))))
         operands.append(row_scale.reshape(M, 1).astype(lhs.dtype))
 
     scratch = []
@@ -328,18 +368,22 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         num_scalar_prefetch=len(scalars),
         grid=(M // bm, O // bn, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, g, *_: (i, j)),
+        out_specs=pl.BlockSpec((bm, bn), park(lambda i, j, k, g: (i, j))),
         scratch_shapes=scratch,
     )
     kernel = functools.partial(_gmm_kernel, nk=nk, trans_rhs=trans_rhs,
-                               bm=bm, bk=bk, fused=fused, scaled=scaled)
+                               bm=bm, bk=bk, fused=fused, scaled=scaled,
+                               ragged=ragged)
     return pl.pallas_call(
         kernel,
         name="gmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, O), lhs.dtype),
+        # the dead tiles of a ragged call all park on one result block,
+        # which only consecutive steps of one core may revisit
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary" if ragged else "parallel",
+                                 "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
     )(*scalars, *operands)
 

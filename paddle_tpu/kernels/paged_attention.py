@@ -103,7 +103,8 @@ def _reference_paged_attention(q, k_cache, v_cache, block_tables,
 
 def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
                                       context_lens, q_lens=None, k_new=None,
-                                      v_new=None, k_scale=None, v_scale=None):
+                                      v_new=None, k_scale=None, v_scale=None,
+                                      window=None):
     """XLA oracle for the mixed prefill+decode form.
 
     q: [B, T, qh, d]; k_new/v_new: [B, T, kvh, d] — the step's fresh rows,
@@ -112,6 +113,8 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     exactly like the kernel).  With ``k_scale``/``v_scale`` (int8 pool,
     one fp32 per (kv-head, page)) gathered pages are dequantized before
     the math — the same dequant the kernel does on its VMEM slot.
+    ``window``: query token j (position ``context_lens[b] + j``) sees only
+    keys at positions in ``(position - window, position]``.
     Returns (out [B, T, qh, d], lse [B, T, qh]).
     """
     b, t, qh, d = q.shape
@@ -136,7 +139,13 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     s = jnp.einsum("btkgd,kbsd->btkgs", qg, k.astype(jnp.float32)) * scale
     pos = jnp.arange(S)
     mask = pos[None, :] < context_lens[:, None]                    # [B, S]
-    s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
+    if window is None:
+        s = jnp.where(mask[:, None, None, None, :], s, NEG_INF)
+    else:
+        q_pos = context_lens[:, None].astype(jnp.int32) + jnp.arange(t)
+        mask = jnp.logical_and(                                 # [B, T, S]
+            mask[:, None, :], pos[None, None, :] > q_pos[:, :, None] - window)
+        s = jnp.where(mask[:, :, None, None, :], s, NEG_INF)
     parts_s, parts_v = [s], [v]
     if k_new is not None:
         kn = jnp.moveaxis(k_new, 2, 0).astype(jnp.float32)   # [kvh, B, T, d]
@@ -147,6 +156,9 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
               else jnp.full((b,), t)).astype(jnp.int32)
         causal = jq[None, :, None] >= jq[None, None, :]          # [1, T, T]
         valid = jnp.logical_and(causal, jq[None, None, :] < ql[:, None, None])
+        if window is not None:
+            valid = jnp.logical_and(
+                valid, jq[None, :, None] - jq[None, None, :] < window)
         s2 = jnp.where(valid[:, :, None, None, :], s2, NEG_INF)
         parts_s.append(s2)
         parts_v.append(vn)
@@ -162,7 +174,8 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
 # ---------------------------------------------------------------- kernel ---
 
 def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
-                              has_new, quantized=False):
+                              has_new, quantized=False, window=None,
+                              layered=False):
     """One (sequence, kv_head, page_chunk) program.
 
     Double-buffered page loop over this chunk's live pages (slot = absolute
@@ -175,12 +188,25 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
     scalar-prefetch channel beside the block table — dequant happens on
     the VMEM slot right after ``wait()``, so the online-softmax math
     stays fp32 and nothing above the kernel changes shape.
+
+    ``window`` (static; a sliding-attention layer): query token j, at
+    position ``ctx + j``, sees keys in ``(ctx + j - window, ctx + j]``.
+    The page walk starts at the page that holds the EARLIEST query's
+    first visible key (``first``): pages wholly behind it are never
+    fetched, and chunk ``c`` covers pages ``first + c * ppc`` on, so the
+    grid needs only the chunks a window can span.
+
+    ``layered`` (static): the caches are the WHOLE pool ``[layers,
+    kv_heads, num_pages, page_size, head_dim]`` in HBM and the layer to
+    read rides the scalar-prefetch channel, so the caller never slices a
+    layer out of the pool (a slice handed to a kernel is a copy).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     it = iter(refs)
     bt_ref, cl_ref, ql_ref = next(it), next(it), next(it)
+    ly_ref = next(it) if layered else None
     ksc_ref = next(it) if quantized else None
     vsc_ref = next(it) if quantized else None
     q_ref = next(it)
@@ -202,18 +228,26 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
     # recursion bug) — hence lax.div/lax.rem against np.int32 constants
     ps_c = np.int32(page_size)
     pages_total = jax.lax.div(ctx + ps_c - np.int32(1), ps_c)
-    start = c * np.int32(ppc)
+    if window is None:
+        first = _I0
+    else:
+        first = jax.lax.div(
+            jnp.maximum(ctx + np.int32(1 - window), _I0), ps_c)
+    start = first + c * np.int32(ppc)
     n_here = jnp.minimum(jnp.maximum(pages_total - start, _I0),
                          np.int32(ppc))
 
+    def page_of(hbm, p):
+        return hbm.at[ly_ref[0], h, bt_ref[b, p]] if layered \
+            else hbm.at[h, bt_ref[b, p]]
+
     def k_copy(p, slot):
         return pltpu.make_async_copy(
-            k_hbm.at[h, bt_ref[b, p]], kbuf.at[slot], sem.at[slot, _I0])
+            page_of(k_hbm, p), kbuf.at[slot], sem.at[slot, _I0])
 
     def v_copy(p, slot):
         return pltpu.make_async_copy(
-            v_hbm.at[h, bt_ref[b, p]], vbuf.at[slot],
-            sem.at[slot, np.int32(1)])
+            page_of(v_hbm, p), vbuf.at[slot], sem.at[slot, np.int32(1)])
 
     @pl.when(c == 0)
     def _init():
@@ -223,10 +257,11 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
 
     # chain warm-up: only the very first live page of a (seq, head) visit
     # has no chunk before it to have prefetched it
-    @pl.when(jnp.logical_and(c == 0, pages_total > 0))
+    @pl.when(jnp.logical_and(c == 0, pages_total > first))
     def _warmup():
-        k_copy(_I0, _I0).start()
-        v_copy(_I0, _I0).start()
+        slot0 = jax.lax.rem(first, np.int32(2))
+        k_copy(first, slot0).start()
+        v_copy(first, slot0).start()
 
     def _accumulate(s, v):
         """Online-softmax update of the (m, l, acc) scratch carry."""
@@ -269,7 +304,13 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
                                     preferred_element_type=jnp.float32)
             pos = p * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = jnp.where(pos < ctx, s, jnp.float32(NEG_INF))
+            seen = pos < ctx
+            if window is not None:    # static: query row r is token r // group
+                q_pos = ctx + jax.lax.div(
+                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 0),
+                    jnp.full(s.shape, group, jnp.int32))
+                seen = jnp.logical_and(seen, pos > q_pos - np.int32(window))
+            s = jnp.where(seen, s, jnp.float32(NEG_INF))
             _accumulate(s, v)
             return carry
 
@@ -290,6 +331,8 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
                 jnp.full(s.shape, group, jnp.int32))
             jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             valid = jnp.logical_and(jk <= jq, jk < ql_ref[b])
+            if window is not None:
+                valid = jnp.logical_and(valid, jq - jk < np.int32(window))
             s = jnp.where(valid, s, jnp.float32(NEG_INF))
             _accumulate(s, vn)
         l = jnp.maximum(l_ref[...], jnp.float32(1e-30))
@@ -299,12 +342,14 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
 
 def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
                                    context_lens, q_lens, k_new, v_new,
-                                   interpret, k_scale=None, v_scale=None):
+                                   interpret, k_scale=None, v_scale=None,
+                                   window=None, layer=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, qh, d = q.shape
-    kvh, n_pages, page_size, _ = k_cache.shape
+    layered = layer is not None
+    kvh, n_pages, page_size, _ = k_cache.shape[-4:]
     group = qh // kvh
     max_pages = block_tables.shape[1]
     rows = t * group
@@ -319,7 +364,10 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
 
     ppc = max(1, min(int(flags.flag("paged_attention_pages_per_chunk")),
                      max_pages))
-    n_chunks = -(-max_pages // ppc)
+    # a window's keys span at most ceil((window - 1) / page) + 1 pages
+    live_pages = max_pages if window is None else min(
+        max_pages, -(-(window - 1) // page_size) + 1)
+    n_chunks = -(-live_pages // ppc)
 
     # unused table entries must still be valid page ids for the DMA
     bt = jnp.clip(block_tables, 0, n_pages - 1).astype(jnp.int32)
@@ -344,6 +392,8 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         in_specs += [spec, spec]
     quantized = k_scale is not None
     scalars = [bt, cl, ql]
+    if layered:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
     if quantized:
         # one fp32 per (kv-head, page), scalar-prefetched (kvh * n_pages
         # * 4 bytes of SMEM): scalar loads at [head, page id], the same
@@ -358,7 +408,7 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     kernel = functools.partial(
         _ragged_paged_attn_kernel, page_size=page_size, ppc=ppc,
         scale=1.0 / math.sqrt(d), t=t, group=group, has_new=has_new,
-        quantized=quantized)
+        quantized=quantized, window=window, layered=layered)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, kvh, n_chunks),
@@ -380,7 +430,9 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     )
     out, lse = pl.pallas_call(
         kernel,
-        name="ragged_paged_attention",
+        # a trace tells the layer kinds apart by the window in the name
+        name="ragged_paged_attention" + (
+            "" if window is None else f"_w{window}"),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, kvh, R, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kvh, R, 1), jnp.float32)],
@@ -456,7 +508,8 @@ def kernel_geometry_error(page_size, head_dim, *, quantized=False,
 
 def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                            *, q_lens=None, k_new=None, v_new=None,
-                           k_scale=None, v_scale=None, with_lse=False):
+                           k_scale=None, v_scale=None, with_lse=False,
+                           window=None, layer=None):
     """Mixed-mode serving attention: prefill chunks and decode tokens in one
     call over a paged KV cache.
 
@@ -464,7 +517,8 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
       q:            [batch, T, num_q_heads, head_dim] — this step's query
                     tokens (T = 1 for pure decode, the chunk length for
                     chunked prefill; sequences ragged via ``q_lens``).
-      k_cache:      [num_kv_heads, num_pages, page_size, head_dim].
+      k_cache:      [num_kv_heads, num_pages, page_size, head_dim], or the
+                    whole pool [layers, num_kv_heads, ...] with ``layer``.
       v_cache:      same shape as k_cache.
       block_tables: [batch, max_pages_per_seq] int32 page ids (pad with 0).
       context_lens: [batch] int32 — tokens ALREADY in the cache (the prior
@@ -483,11 +537,27 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     changes shape.
       with_lse:     also return the per-query logsumexp [batch, T, q_heads]
                     (fp32) for online-softmax merging of extra keys.
+      window:       static int or None — sliding attention: the query
+                    token at position p sees keys in ``(p - window, p]``.
+                    Pages that end before the earliest query's window are
+                    skipped in the walk, not masked after the fetch, and
+                    the kernel is named ``ragged_paged_attention_w<window>``.
+      layer:        int32 scalar (may be traced) — with the whole pool as
+                    ``k_cache``/``v_cache``, the layer whose pages are
+                    read: the kernel indexes the pool in HBM, no layer is
+                    sliced out of it (``k_scale``/``v_scale`` stay one
+                    layer's [num_kv_heads, num_pages] planes).
 
     Returns [batch, T, num_q_heads, head_dim] (and lse when requested).
     """
     b, t, qh, d = q.shape
-    kvh, _, page_size, _ = k_cache.shape
+    if window is not None and (int(window) != window or window < 1):
+        raise ValueError(f"window must be a whole number >= 1, got {window!r}")
+    window = None if window is None else int(window)
+    if (k_cache.ndim == 5) != (layer is not None):
+        raise ValueError("a whole pool [layers, ...] is read at `layer`; "
+                         "one layer's cache takes none")
+    kvh, n_pool_pages, page_size, _ = k_cache.shape[-4:]
     if qh % kvh:
         raise ValueError(f"q heads ({qh}) must be a multiple of kv heads ({kvh})")
     if (k_new is None) != (v_new is None):
@@ -497,7 +567,7 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     on_tpu = jax.default_backend() == "tpu"
     why = kernel_geometry_error(
         page_size, d, quantized=k_scale is not None, kv_heads=kvh,
-        num_pages=k_cache.shape[1], table_shape=block_tables.shape)
+        num_pages=n_pool_pages, table_shape=block_tables.shape)
     if on_tpu and why:
         # the serving hot op has no business on the XLA reference on a chip
         raise ValueError(f"ragged_paged_attention on TPU: {why}")
@@ -505,11 +575,14 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
         out, lse = _pallas_ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_lens,
             k_new, v_new, interpret=not on_tpu, k_scale=k_scale,
-            v_scale=v_scale)
+            v_scale=v_scale, window=window, layer=layer)
     else:
+        if layer is not None:       # the oracle takes one layer's cache
+            k_cache, v_cache = (jax.lax.dynamic_index_in_dim(
+                c, layer, axis=0, keepdims=False) for c in (k_cache, v_cache))
         out, lse = _reference_ragged_paged_attention(
             q, k_cache, v_cache, block_tables, context_lens, q_lens,
-            k_new, v_new, k_scale=k_scale, v_scale=v_scale)
+            k_new, v_new, k_scale=k_scale, v_scale=v_scale, window=window)
     return (out, lse) if with_lse else out
 
 
@@ -566,24 +639,44 @@ def write_kv_pages(k_cache, v_cache, k_new, v_new, slot_mapping):
 
 
 def write_kv_pages_all_layers(k_cache, v_cache, k_all, v_all, slot_mapping):
-    """One scatter committing every layer's new KV rows.
+    """Commit every layer's new KV rows, token by token, in place.
 
     k_cache/v_cache: [layers, kv_heads, num_pages, page_size, head_dim];
     k_all/v_all: [layers, n_tokens, kv_heads, head_dim]; slot_mapping:
-    [n_tokens] (-1 = drop).  A single batched scatter (all layers share the
-    slot vector) keeps the decode step's cache strictly read-before-write:
-    attention reads the pre-step cache, the commit happens once at the end,
-    and XLA aliases the donated buffers in place.
+    [n_tokens] (-1 = drop).  All layers share the slot vector and the
+    commit happens once at the end of the step, so the cache stays strictly
+    read-before-write: attention reads the pre-step cache and XLA aliases
+    the donated buffers in place.
+
+    A loop of ``dynamic_update_slice`` over the step's VALID tokens (those
+    with a slot, taken first), not one scatter: for a scatter along the
+    token axis XLA's TPU layout assignment moves the whole pool into a
+    token-major layout and back (four copies of the pool a step: 3.25 GB of
+    transients beside a 3.25 GB pool, and a third of the dense chat step,
+    v5e, PR 27), where an update of one ``[layers, kv_heads, 1, head_dim]``
+    window leaves the pool where it lies.
     """
     L, kvh, n_pages, page_size, d = k_cache.shape
     flat_k = k_cache.reshape(L, kvh, n_pages * page_size, d)
     flat_v = v_cache.reshape(L, kvh, n_pages * page_size, d)
     slots = slot_mapping.astype(jnp.int32)
-    safe = jnp.where(slots >= 0, slots, n_pages * page_size)
     kn = jnp.swapaxes(k_all, 1, 2).astype(flat_k.dtype)   # [L, kvh, n, d]
     vn = jnp.swapaxes(v_all, 1, 2).astype(flat_v.dtype)
-    flat_k = flat_k.at[:, :, safe].set(kn, mode="drop")
-    flat_v = flat_v.at[:, :, safe].set(vn, mode="drop")
+    valid = slots >= 0
+    order = jnp.argsort(jnp.logical_not(valid), stable=True).astype(jnp.int32)
+
+    def commit(i, kv):
+        fk, fv = kv
+        src = order[i]
+        dst = slots[src]
+        fk = jax.lax.dynamic_update_slice_in_dim(
+            fk, jax.lax.dynamic_slice_in_dim(kn, src, 1, axis=2), dst, axis=2)
+        fv = jax.lax.dynamic_update_slice_in_dim(
+            fv, jax.lax.dynamic_slice_in_dim(vn, src, 1, axis=2), dst, axis=2)
+        return fk, fv
+
+    flat_k, flat_v = jax.lax.fori_loop(
+        jnp.int32(0), valid.sum().astype(jnp.int32), commit, (flat_k, flat_v))
     return (flat_k.reshape(k_cache.shape), flat_v.reshape(v_cache.shape))
 
 

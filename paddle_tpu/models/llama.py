@@ -420,13 +420,16 @@ def moe_mlp_forward_einsum(x, gate_w, w_gate, w_up, w_down, *, top_k,
     return y.reshape(B, S, H), aux, stats
 
 
-def _route_topk(xf, gate_w, k):
-    """Shared top-k router: returns (normalized gate weights [N, k],
-    expert ids [N, k], GShard aux loss, first-choice load ce [E])."""
+def _route_topk(xf, gate_w, k, score="softmax"):
+    """Shared top-k router: returns (gate weights [N, k], expert ids
+    [N, k], GShard aux loss, first-choice load ce [E]).  Scores are the
+    ``score`` function ("softmax" or "sigmoid") of the float32 logits; the
+    gates are the k largest, divided by their sum."""
     N = xf.shape[0]
     E = gate_w.shape[-1]
     logits = xf.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [N, E]
-    probs = jax.nn.softmax(logits, axis=-1)
+    probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+        else jax.nn.sigmoid(logits)
     topv, topi = jax.lax.top_k(probs, k)
     topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
     me = probs.mean(axis=0)
@@ -462,7 +465,11 @@ def _grouped_ffn(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
 
 
 def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
-                     tile_groups, E, k, bm):
+                     tile_groups, E, k, bm, live_tiles=None):
+    """The forward pass and its residuals.  ``live_tiles`` (serving a share
+    of the experts, forward only): the row tiles after the first
+    ``live_tiles`` hold no held expert's rows and are not multiplied
+    (``gmm``); their ``pos`` entries are the sentinel, so they read zero."""
     from ..kernels.grouped_matmul import (gmm, take_sentinel_rows,
                                           validate_tile_flags)
 
@@ -471,10 +478,11 @@ def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     validate_tile_flags(H, w_gate.shape[2])
     xz = jnp.concatenate([xf, jnp.zeros((1, H), xf.dtype)], axis=0)
     tok_of = jnp.where(inv_flat < N * k, inv_flat // k, N)
-    h_g = gmm(xz, w_gate, tile_groups, bm=bm, rows=tok_of)  # fused gather
-    h_u = gmm(xz, w_up, tile_groups, bm=bm, rows=tok_of)
+    lt = {} if live_tiles is None else {"live_tiles": live_tiles}
+    h_g = gmm(xz, w_gate, tile_groups, bm=bm, rows=tok_of, **lt)  # fused gather
+    h_u = gmm(xz, w_up, tile_groups, bm=bm, rows=tok_of, **lt)
     a = jax.nn.silu(h_g) * h_u
-    o = gmm(a, w_down, tile_groups, bm=bm)                # [M, H]
+    o = gmm(a, w_down, tile_groups, bm=bm, **lt)          # [M, H]
     # combine gather: sentinel pos >= M (dropped entries) reads zero
     o_pos = take_sentinel_rows(o, pos).reshape(N, k, H)
     y = (o_pos * gates[..., None].astype(o.dtype)).sum(axis=1)
@@ -856,6 +864,33 @@ class LlamaForCausalLM(Layer):
                 labels.reshape([-1]))
             return logits, loss
         return logits
+
+    # ---- what the serving engine asks of a model (decoder_spec.py) ----
+    def decoder_spec(self):
+        """The degenerate case of the engine's layer pattern: a period of
+        one full-attention rotary layer, RMS norm, sequential residuals."""
+        from .decoder_spec import DecoderSpec, LayerKind, MoeSpec
+        c = self.config
+        moe = MoeSpec(num_experts=c.moe_num_experts, top_k=c.moe_top_k,
+                      dispatch="grouped" if c.moe_dispatch == "grouped"
+                      else "dense", block_m=c.moe_block_m) \
+            if c.moe_num_experts else None
+        return DecoderSpec(
+            pattern=(LayerKind(),), periods=c.num_hidden_layers,
+            num_heads=c.num_attention_heads,
+            num_kv_heads=c.num_key_value_heads, head_dim=c.head_dim,
+            norm="rms", norm_eps=c.rms_norm_eps, rope_theta=c.rope_theta,
+            moe=moe)
+
+    def serving_params(self):
+        """The engine's parameter tree: the layers stacked ``[L, ...]`` (a
+        copy beside the per-layer parameters this model trains with)."""
+        from ..utils import extract_params, stack_params
+        blocks = stack_params([extract_params(l) for l in self.llama.layers])
+        head = (self.lm_head.weight._data if self.lm_head is not None
+                else self.llama.embed_tokens.weight._data.T)
+        return {"embed": self.llama.embed_tokens.weight._data, "head": head,
+                "norm": self.llama.norm.weight._data, "blocks": (blocks,)}
 
 
 # ---- sharding plan ----

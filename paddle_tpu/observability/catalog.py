@@ -55,6 +55,20 @@ CATALOG: Dict[str, tuple] = {
         "gauge", "", "live waiting-queue depth"),
     "serving.batch_occupancy": (
         "histogram", "", "busy slots / max_batch per step"),
+    # ---- serving: a chip that holds a share of the experts (PR 27) ----
+    "serving.moe_held_rows": (
+        "histogram", "",
+        "(token, choice) entries of one plain step that fell on experts "
+        "this chip holds, summed over the layers (a model whose "
+        "`MoeSpec.held` is under the router's width; counted on the "
+        "device, read at the drain that exists)"),
+    "serving.moe_rows_laid_out": (
+        "histogram", "",
+        "rows the grouped expert GEMM laid out for those entries in the "
+        "same step, summed over the layers: every held expert's entries "
+        "rounded up to whole row tiles, at least one tile each; "
+        "`moe_held_rows / moe_rows_laid_out` is the occupancy of the "
+        "tiles that are multiplied"),
     # ---- serving: per-phase step attribution (PR 10) ----
     "serving.step_ms": (
         "histogram", "phase=prefill|decode|spec_verify|fused_k|cow_copy"
@@ -482,7 +496,7 @@ SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
         "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
-        "slots, waiting",
+        "kv_read_tokens, slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
@@ -492,8 +506,10 @@ SPANS: Dict[str, tuple] = {
         "run over (the smallest row bucket that holds `q_tokens`; "
         "slots x T for a dense dispatch, slots x K in the speculative "
         "lane, 0 when idle), so `q_tokens / gemm_rows` is the occupancy "
-        "those GEMMs see, `slots` the batch B, `waiting` the queue "
-        "behind it"),
+        "those GEMMs see, `kv_read_tokens` the key tokens the step's "
+        "attention reads, summed over the layers with each layer's "
+        "window applied (the host knows every row's context and query "
+        "length), `slots` the batch B, `waiting` the queue behind it"),
     "engine.admit": (
         "serving", "engine", "local", "admitted, waiting",
         "`_admit`: waiting requests into free slots, their pages and the "
@@ -515,9 +531,11 @@ SPANS: Dict[str, tuple] = {
         "the call of a jitted program: `serve_step_T<bucket>`, "
         "`serve_spec_verify_K<k>`, `serve_fused_K<k>`, `pool_cow_copy`"),
     "engine.drain": (
-        "serving", "engine", "local", "steps, tokens",
+        "serving", "engine", "local", "steps, tokens, held_rows",
         "`_drain`: `steps` dispatches closed, `tokens` delivered to "
-        "their requests"),
+        "their requests, `held_rows` the (token, choice) entries of those "
+        "steps that fell on experts held here (0 unless the chip holds a "
+        "share of them)"),
     "engine.drain.wait": (
         "serving", "engine", "local", "",
         "the `np.asarray` block of the drain: the only place the host "

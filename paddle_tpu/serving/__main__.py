@@ -18,7 +18,17 @@ from typing import List, Optional
 
 from .. import flags
 
-_PRESETS = ("tiny", "llama2_7b", "llama2_13b", "mixtral_tiny")
+_LLAMA_PRESETS = ("tiny", "llama2_7b", "llama2_13b", "mixtral_tiny")
+# cohere2_moe (models/cohere2_moe.py): the test size, and Command A+ as one
+# chip of eight that share each layer holds it (16 of the 128 experts, an
+# eighth of the vocabulary, one period of four layers: 9.5 GB in bf16)
+_COHERE2_MOE_PRESETS = {
+    "cohere2_moe_tiny": lambda cfg: cfg.tiny(),
+    "command_a_plus_ep8": lambda cfg: cfg.command_a_plus(
+        num_hidden_layers=4, experts_held=16, expert_offset=0,
+        vocab_size=262144 // 8),
+}
+_PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -120,11 +130,16 @@ def build_engine(args):
     """Model + engine from parsed args (import-heavy, so deferred)."""
     import paddle_tpu as paddle
     from ..inference import ContinuousBatchingEngine
-    from ..models.llama import LlamaConfig, LlamaForCausalLM
 
     paddle.seed(args.seed)
-    cfg = getattr(LlamaConfig, args.preset)()
-    model = LlamaForCausalLM(cfg)
+    if args.preset in _COHERE2_MOE_PRESETS:
+        from ..models.cohere2_moe import (Cohere2MoeConfig,
+                                          CohereMoeForCausalLM)
+        model = CohereMoeForCausalLM(
+            _COHERE2_MOE_PRESETS[args.preset](Cohere2MoeConfig))
+    else:
+        from ..models.llama import LlamaConfig, LlamaForCausalLM
+        model = LlamaForCausalLM(getattr(LlamaConfig, args.preset)())
     if args.checkpoint:
         state = paddle.load(args.checkpoint)
         model.set_state_dict(state)

@@ -188,3 +188,41 @@ def test_fused_gather_gmm_is_refused_with_the_compilers_reason(one_chip):
                      ((M // BM,), jnp.int32), ((M,), jnp.int32))
     finally:
         flags.set_flags({"grouped_matmul_fused_gather": False})
+
+
+# ---- command-a-plus-05-2026 as one chip of eight holds it (PR 27) ----
+# 128 query heads over 8 KV heads x T = 64 is a 1,024-row query block; the
+# cell's engine: 16 slots, 12,416 positions in pages of 16, a pool of 12,416
+CA_QH, CA_KVH, CA_B, CA_LEN, CA_PAGES = 128, 8, 16, 12416, 12416
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["sliding", "full"])
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_paged_attention_command_a_plus_shapes_compile(one_chip, T, window):
+    """The windowed walk and the 1,024-row block fit the chip's VMEM; the
+    window is in the kernel's name so a trace tells the kinds apart."""
+    assert pa.kernel_geometry_error(PAGE, D, kv_heads=CA_KVH,
+                                    num_pages=CA_PAGES,
+                                    table_shape=(CA_B, CA_LEN // PAGE)) is None
+    i32 = jnp.int32
+    cache = ((CA_KVH, CA_PAGES, PAGE, D), BF16)
+    compiled = _compile(
+        one_chip,
+        lambda q, k, v, bt, cl, ql, kn, vn: pa._pallas_ragged_paged_attention(
+            q, k, v, bt, cl, ql, kn, vn, interpret=False, window=window),
+        ((CA_B, T, CA_QH, D), BF16), cache, cache,
+        ((CA_B, CA_LEN // PAGE), i32), ((CA_B,), i32), ((CA_B,), i32),
+        ((CA_B, T, CA_KVH, D), BF16), ((CA_B, T, CA_KVH, D), BF16))
+    assert ("ragged_paged_attention_w4096" in compiled.as_text()) \
+        == (window is not None)
+
+
+def test_gmm_with_live_tiles_compiles(one_chip):
+    """A share of the experts: 16 held experts of width 4,096, tiles of 128
+    rows, the tiles after the live ones parked on one block."""
+    m, e, bm = 4096 + 17 * 128, 16, 128
+    _compile(one_chip,
+             lambda l, r, t, live: gm.gmm(l, r, t, bm=bm, interpret=False,
+                                          live_tiles=live),
+             ((m, HIDDEN), BF16), ((e, HIDDEN, HIDDEN), BF16),
+             ((m // bm,), jnp.int32), ((), jnp.int32))

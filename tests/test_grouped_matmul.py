@@ -420,3 +420,30 @@ class TestMosaicLowering:
 
         jax.export.export(jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))),
                           platforms=["tpu"])(x, wg, wu, wd, gw)
+
+
+@pytest.mark.parametrize("trans_rhs", [False, True], ids=["plain", "trans"])
+@pytest.mark.parametrize("live", [1, 3, 6, 10])
+def test_gmm_live_tiles_multiplies_only_the_live_prefix(live, trans_rhs):
+    """``gmm(live_tiles=)`` (a chip that holds a share of the experts sorts
+    the other experts' entries last): the first ``live`` row tiles are the
+    plain product; the rest are skipped, whatever their number, and only
+    the live rows are ever read."""
+    rng = np.random.default_rng(0)
+    bm, K, N, E = 8, 128, 256, 4
+    M = 10 * bm
+    lhs = jnp.asarray(rng.normal(size=(M, K)).astype(np.float32))
+    shape = (E, N, K) if trans_rhs else (E, K, N)
+    rhs = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    tg = jnp.asarray([0, 0, 1, 2, 2, 3, 3, 3, 3, 3], jnp.int32)
+    want = np.asarray(G._gmm_reference(lhs, rhs, tg, bm=bm,
+                                       trans_rhs=trans_rhs))
+    got = np.asarray(jax.jit(lambda n: G.gmm(
+        lhs, rhs, tg, bm=bm, bn=128, bk=128, trans_rhs=trans_rhs,
+        interpret=True, live_tiles=n))(jnp.int32(live)))
+    np.testing.assert_allclose(got[:live * bm], want[:live * bm],
+                               rtol=1e-5, atol=1e-5)
+    if live == 10:          # all live: the call without the argument
+        plain = np.asarray(G.gmm(lhs, rhs, tg, bm=bm, bn=128, bk=128,
+                                 trans_rhs=trans_rhs, interpret=True))
+        assert np.array_equal(got, plain)

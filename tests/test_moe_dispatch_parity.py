@@ -103,13 +103,18 @@ class TestServingDispatch:
             "mlp.experts_down": _rand((self.E, self.I, self.H), 0.05, 4),
         }
 
+    def _moe(self, dispatch, block_m=128):
+        from paddle_tpu.models.decoder_spec import MoeSpec
+        return MoeSpec(num_experts=self.E, top_k=self.k, dispatch=dispatch,
+                       block_m=block_m)
+
     def test_prefill_grouped_matches_dense(self):
         from paddle_tpu.inference.generation import _moe_ffn
 
         lp = self._lp()
         y = _rand((2, 32, self.H), 0.5, 8)
-        grouped = _moe_ffn(y, lp, self.k, dispatch="grouped", block_m=8)
-        dense = _moe_ffn(y, lp, self.k)
+        grouped, _ = _moe_ffn(y, lp, self._moe("grouped", 8))
+        dense, _ = _moe_ffn(y, lp, self._moe("dense"))
         np.testing.assert_allclose(np.asarray(grouped), np.asarray(dense),
                                    rtol=2e-4, atol=2e-5)
 
@@ -118,8 +123,8 @@ class TestServingDispatch:
 
         lp = self._lp()
         y = _rand((2, self.H), 0.5, 8)     # 2 rows * k=2 < block_m=128
-        out = _moe_ffn(y, lp, self.k, dispatch="grouped", block_m=128)
-        dense = _moe_ffn(y, lp, self.k)
+        out, _ = _moe_ffn(y, lp, self._moe("grouped", 128))
+        dense, _ = _moe_ffn(y, lp, self._moe("dense"))
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    rtol=1e-6)
 

@@ -1,0 +1,87 @@
+"""The paged kernel's static ``window`` (sliding attention): the Pallas
+kernel in interpret mode and the XLA oracle against a dense masked softmax,
+for windows below, at and above the context, page-aligned and not."""
+
+import math
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu import flags
+from paddle_tpu.kernels.paged_attention import ragged_paged_attention
+
+PAGE, MAX_PAGES = 8, 12
+QH, KVH, D = 4, 2, 64
+# contexts: none, inside a page, one page, page-aligned, not, long
+CONTEXTS = [0, 3, 8, 16, 37, 60, 80]
+
+
+def _dense(q, k_full, v_full, ctx, ql, window):
+    """q [B, T, qh, d]; k/v_full [B, S, kvh, d] hold the context and then
+    the step's own rows: softmax over the keys in (p - window, p]."""
+    out = np.zeros(q.shape, np.float32)
+    group = q.shape[2] // k_full.shape[2]
+    for b in range(q.shape[0]):
+        for t in range(int(ql[b])):
+            p = int(ctx[b]) + t
+            lo = 0 if window is None else max(0, p - window + 1)
+            for h in range(q.shape[2]):
+                k = k_full[b, lo:p + 1, h // group]
+                v = v_full[b, lo:p + 1, h // group]
+                s = k @ q[b, t, h] / math.sqrt(q.shape[3])
+                w = np.exp(s - s.max())
+                out[b, t, h] = (w / w.sum()) @ v
+    return out
+
+
+@pytest.fixture
+def interpret(request):
+    flags.set_flags({"paged_attention_interpret": request.param})
+    yield request.param
+    flags.set_flags({"paged_attention_interpret": False})
+
+
+@pytest.mark.parametrize("interpret", [True, False], indirect=True,
+                         ids=["kernel_interpreted", "xla_oracle"])
+@pytest.mark.parametrize("T", [1, 8], ids=["decode", "chunk"])
+@pytest.mark.parametrize("window", [None, 1, 5, 8, 16, 21, 64, 200])
+def test_window_against_a_dense_masked_softmax(window, T, interpret):
+    rng = np.random.default_rng(0)
+    B = len(CONTEXTS)
+    n_pages = B * MAX_PAGES + 3
+    S = MAX_PAGES * PAGE
+    k_full = rng.normal(size=(B, S, KVH, D)).astype(np.float32)
+    v_full = rng.normal(size=(B, S, KVH, D)).astype(np.float32)
+    q = rng.normal(size=(B, T, QH, D)).astype(np.float32)
+    ql = np.asarray([T if b % 2 == 0 else max(1, T // 2) for b in range(B)],
+                    np.int32)
+    ctx = np.asarray(CONTEXTS, np.int32)
+    table = rng.permutation(n_pages)[:B * MAX_PAGES].reshape(
+        B, MAX_PAGES).astype(np.int32)          # pages scattered in the pool
+    kc = np.zeros((KVH, n_pages, PAGE, D), np.float32)
+    vc = np.zeros_like(kc)
+    for b in range(B):
+        for pos in range(int(ctx[b])):
+            kc[:, table[b, pos // PAGE], pos % PAGE] = k_full[b, pos]
+            vc[:, table[b, pos // PAGE], pos % PAGE] = v_full[b, pos]
+    k_new = np.stack([k_full[b, ctx[b]:ctx[b] + T] for b in range(B)])
+    v_new = np.stack([v_full[b, ctx[b]:ctx[b] + T] for b in range(B)])
+    got = np.asarray(ragged_paged_attention(
+        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(table),
+        jnp.asarray(ctx), q_lens=jnp.asarray(ql), k_new=jnp.asarray(k_new),
+        v_new=jnp.asarray(v_new), window=window))
+    want = _dense(q, k_full, v_full, ctx, ql, window)
+    for b in range(B):          # rows past q_lens[b] are don't-care
+        np.testing.assert_allclose(got[b, :ql[b]], want[b, :ql[b]],
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_a_window_is_a_whole_number_of_at_least_one():
+    z = jnp.zeros((1, 1, QH, D))
+    cache = jnp.zeros((KVH, 4, PAGE, D))
+    for bad in (0, -3, 2.5):
+        with pytest.raises(ValueError, match="window"):
+            ragged_paged_attention(z, cache, cache,
+                                   jnp.zeros((1, 2), jnp.int32),
+                                   jnp.zeros((1,), jnp.int32), window=bad)

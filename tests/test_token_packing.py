@@ -60,8 +60,8 @@ def primed(request):
     toks = jnp.asarray(rng.integers(1, vocab, (B, T)).astype(np.int32))
     ql = jnp.asarray(np.array([16, 5, 0, 0], np.int32))
     pos = jnp.zeros((B,), jnp.int32)
-    _, cache = g._forward_tokens(g.params, tuple(g.cache.arrays), toks, ql,
-                                 pos, bt)
+    _, cache, _ = g._forward_tokens(g.params, tuple(g.cache.arrays), toks,
+                                    ql, pos, bt)
     return g, bt, cache, rng
 
 
@@ -87,14 +87,15 @@ def test_packed_forward_equals_dense_at_every_bucket(primed, case):
     toks = jnp.asarray(rng.integers(1, g.config.vocab_size,
                                     (B, T)).astype(np.int32))
     ql, pos = jnp.asarray(ql_np), jnp.asarray(pos_np)
-    want_h, want_cache = g._forward_tokens(g.params, cache, toks, ql, pos, bt)
+    want_h, want_cache, _ = g._forward_tokens(g.params, cache, toks, ql, pos,
+                                              bt)
     live = np.arange(T)[None, :] < ql_np[:, None]
     tried = 0
     for rows in BUCKETS:
         if rows < ql_np.sum():
             continue
         tried += 1
-        got_h, got_cache = jax.jit(functools.partial(
+        got_h, got_cache, _ = jax.jit(functools.partial(
             g._forward_tokens, rows=rows))(g.params, cache, toks, ql, pos, bt)
         assert got_h.shape == want_h.shape
         np.testing.assert_allclose(np.asarray(got_h)[live],
