@@ -196,8 +196,7 @@ def phase_kernels(sz: Sizes, seed: int, on_tpu: bool) -> None:
          paged_shapes={"q": [B, "T", qh, d],
                        "cache": [kvh, n_pages, page, d], "block_table": [B, W]},
          paged=paged, paged_tol=PAGED_TOL, paged_seconds=round(t_paged, 2),
-         paged_tiles={"page_size": page, "pages_per_chunk": int(
-             flags.flag("paged_attention_pages_per_chunk"))},
+         paged_tiles={"page_size": page},
          flash_shapes={"q": [Bt, S, qh, d], "kv": [Bt, S, kvh, d],
                        "causal": True},
          flash=flash, flash_tol=FLASH_TOL,

@@ -58,7 +58,7 @@ import numpy as np
 from .. import flags
 from .. import observability as _obs
 from ..kernels.grouped_matmul import take_sentinel_rows
-from ..kernels.paged_attention import (kernel_geometry_error,
+from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        paged_attention,
                                        ragged_paged_attention,
                                        write_kv_pages,
@@ -502,6 +502,16 @@ class LlamaGenerator:
                 first = 0 if window is None else max(0, ctx + 1 - window)
                 n += layers * (ctx + q - first)
         return n
+
+    def attn_rows(self, t, rows) -> int:
+        """Query rows the paged kernel's row tiles cover for each KV head
+        in one layer's call of a step with bucket ``t`` over ``rows`` =
+        [(query tokens, context before them)]: the kernel's own tile
+        arithmetic, so ``q_tokens x group / attn_rows`` is the occupancy
+        its tiles see."""
+        c = self.spec
+        return attn_rows([q for q, _ in rows], t,
+                         c.num_heads // c.num_kv_heads)
 
     def _head_logits(self, params, h):
         """float32 logits of hidden states ``h [..., H]``: the head, or the
@@ -1533,7 +1543,7 @@ class ContinuousBatchingEngine:
         early_done: List[Request] = []
         if all(r is None for r in self.slot_req):
             span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
-                              gemm_rows=0, kv_read_tokens=0,
+                              gemm_rows=0, kv_read_tokens=0, attn_rows=0,
                               waiting=len(self.waiting))
             return self._drain() if self._pending else []
         g = self.g
@@ -1574,12 +1584,13 @@ class ContinuousBatchingEngine:
                                 + self._upload_caps())
             rows = sum(r is not None for r in self.slot_req)
             k = int(self.spec.k)
+            attends = [(k, max(int(self.host_lens[b]) - k, 0))
+                       for b in range(B) if self.slot_req[b] is not None]
             span.set_metadata(
                 kind="spec", T=k, rows=rows, q_tokens=rows * k,
                 gemm_rows=B * k, waiting=len(self.waiting),
-                kv_read_tokens=g.kv_read_tokens(
-                    [(k, max(int(self.host_lens[b]) - k, 0))
-                     for b in range(B) if self.slot_req[b] is not None]))
+                kv_read_tokens=g.kv_read_tokens(attends),
+                attn_rows=g.attn_rows(k, attends))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
             self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
@@ -1656,6 +1667,7 @@ class ContinuousBatchingEngine:
         span.set_metadata(kind="mixed" if T > 1 else "decode", T=int(T),
                           rows=rows, q_tokens=q_tokens, gemm_rows=gemm_rows,
                           kv_read_tokens=g.kv_read_tokens(attends),
+                          attn_rows=g.attn_rows(T, attends),
                           waiting=len(self.waiting))
         with tracer.span("engine.dispatch", program=f"serve_step_T{T}"):
             out = step(g.params, g.cache.arrays, tokens_in, ql_dev,

@@ -6,7 +6,7 @@ masked_multihead_attention): each sequence's query tokens attend its whole
 KV history, which lives in fixed-size *pages* scattered through a global
 cache and addressed by a per-sequence block table (vLLM-style paged KV).
 
-TPU-first design (the "Ragged Paged Attention" shape of arxiv 2604.15464):
+TPU-first design (the "Ragged Paged Attention" schedule of arxiv 2604.15464):
 
 - **One mixed-mode kernel** serves prefill chunks AND decode tokens: the
   query operand is ``[batch, T, q_heads, head_dim]`` where T is the step's
@@ -16,27 +16,41 @@ TPU-first design (the "Ragged Paged Attention" shape of arxiv 2604.15464):
   in-kernel with a causal mask, so a serving step never needs a separate
   flash-attention call or an analytic current-token merge — chunked
   prefill rides the decode schedule in one ``pallas_call``.
+- The grid is **(sequence, kv_head)**: one program walks a slot's whole
+  KV in a loop whose trip count comes from ``context_lens`` (and, with a
+  window, from the first page the earliest query sees), so no grid step
+  exists for pages a sequence does not have.  A slot with ``q_lens == 0``
+  does nothing: no DMA, no fold, no normalization (its rows are written
+  as zeros).
 - The KV cache is laid out **head-major**, ``[kv_heads, num_pages,
-  page_size, head_dim]``, and stays in **HBM** (``pl.ANY``): the kernel
-  itself DMAs exactly the pages a sequence owns into a two-slot VMEM ring,
-  **double-buffered** — page ``p+1``'s copy is started while page ``p`` is
-  being computed (the same overlap pattern as the grouped_matmul fused
-  gather).  The buffer slot is ``p % 2`` with p the *absolute* page index,
-  so the prefetch chain continues across page-chunk grid steps with no
-  warm-up bubble after the first page.
-- The grid is **(sequence, kv_head, page_chunk)** with per-sequence
-  ``context_lens`` raggedness: a chunk wholly beyond a sequence's context
-  issues NO DMA and no compute — HBM traffic and FLOPs are O(context),
-  never O(max_context).
+  page_size, head_dim]``, and stays in **HBM** (``pl.ANY``).  The walk
+  goes in **blocks of many pages** (``_BLOCK_KEYS`` keys in whole pages,
+  never more than the table's width): the kernel DMAs the pages of the
+  blocks a sequence's context reaches, one copy a page, into ONE
+  contiguous ``[keys, head_dim]`` VMEM tile per K and V, **double-buffered** at block granularity — block
+  ``j+1``'s copies are in flight while block ``j`` is computed.  A block
+  gets one ``QK^T``, one mask, one online-softmax update and one ``PV``,
+  so the score tile is lane-dense and the (m, l, acc) carry is touched
+  once per block, not once per page.
+- GQA is native: a program holds the ``group = q_heads // kv_heads``
+  query rows of all T tokens for one KV head, row ``r`` = token
+  ``r // group``, so K/V pages are fetched ONCE per group and a slot's
+  live rows are the prefix ``[0, q_len * group)``.  The program loops
+  over **row tiles** of that prefix only (``row_tile``): a decoding slot
+  inside a T = 64 step computes one tile, not ``64 * group`` rows.  The
+  tiles are the inner loop (the whole (m, l, acc) carry lives in VMEM
+  scratch), so the KV is read once whatever the number of tiles.
+- **Operands enter the MXU as stored**: ``q`` and ``k`` in the cache's
+  dtype (a bf16 x bf16 product is exact in the float32 accumulator; an
+  int8 page is exact there too and its per-page scale applies to the
+  score and probability columns), ``1/sqrt(d)`` on the float32 scores;
+  the probabilities stay float32 into ``PV``, and m, l, the accumulator
+  and the log-sum-exp are float32.
 - The block table, context lengths and query lengths ride in as
   **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``), so page
   ids resolve before the body runs — data-dependent addressing with zero
-  data-dependent control flow outside ``fori_loop`` trip counts.
-- GQA is native: each program holds the ``group = q_heads // kv_heads``
-  query rows of all T tokens for one KV head (``T * group`` MXU rows), so
-  K/V pages are fetched ONCE per group, not per query head.
-- Online softmax (m, l, acc) carries across the page-chunk axis in VMEM
-  scratch, which persists along the innermost grid dimension.
+  data-dependent control flow outside loop trip counts.  Block and tile
+  sizes follow from the static shapes; no flag sets them.
 
 Off-TPU an XLA gather+masked-softmax reference runs instead (tests use it
 as the numerics oracle; ``FLAGS_paged_attention_interpret=1`` runs the real
@@ -61,13 +75,17 @@ _I0 = np.int32(0)  # index-map literal: bare 0 would be int64 under x64 mode
 flags.define_flag("paged_attention_interpret", False,
                   "Run the Pallas paged-attention kernel in interpreter mode "
                   "on CPU (tests only; TPU always uses the compiled path).")
-flags.define_flag("paged_attention_pages_per_chunk", 8,
-                  "KV pages per page-chunk grid step of the ragged "
-                  "paged-attention kernel. Chunks wholly beyond a "
-                  "sequence's context are skipped (no DMA, no compute); "
-                  "within a chunk pages are double-buffered.")
 
-_SUBLANE = 8  # f32 sublane count — query-row tiles pad to a multiple
+_SUBLANE = 8      # f32 sublane count — a query-row block pads to a multiple
+# The two sizes of the schedule, settled on a v5e at the benchmark cells'
+# geometries (PERF.md section 6, PR 28): a row tile of at most 256 query
+# rows and a KV block of 1,024 keys.  Against 128 rows x 512 keys the
+# 1,024-row Command A+ calls are 27-28 % shorter (a K block is loaded into
+# the MXU once for twice the rows; the (m, l, acc) carry is updated half
+# as often) and the Mixtral cell's 7 %; the chat cell's, whose contexts
+# hardly fill one block, 7 % longer; 512 rows or 2,048 keys gain no more.
+_ROW_TILE = 256
+_BLOCK_KEYS = 1024
 
 
 # --------------------------------------------------------------- oracles ---
@@ -109,10 +127,13 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
 
     q: [B, T, qh, d]; k_new/v_new: [B, T, kvh, d] — the step's fresh rows,
     attended with an intra-step causal mask on top of the cached context.
-    Rows with token index >= q_lens[b] are don't-care (garbage-but-finite,
-    exactly like the kernel).  With ``k_scale``/``v_scale`` (int8 pool,
+    Rows with token index >= q_lens[b] are don't-care (finite, like the
+    kernel's, but not the same values: the kernel stops at whole row
+    tiles).  With ``k_scale``/``v_scale`` (int8 pool,
     one fp32 per (kv-head, page)) gathered pages are dequantized before
-    the math — the same dequant the kernel does on its VMEM slot.
+    the math (the kernel multiplies the int8 pages as stored and scales
+    the score and probability columns: the same numbers, summed in
+    another order).
     ``window``: query token j (position ``context_lens[b] + j``) sees only
     keys at positions in ``(position - window, position]``.
     Returns (out [B, T, qh, d], lse [B, T, qh]).
@@ -173,28 +194,68 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
 
 # ---------------------------------------------------------------- kernel ---
 
-def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
+def _padded_rows(t, group):
+    """Query rows of one (slot, KV head) block: ``t * group``, padded to
+    the sublane count."""
+    return -(-max(t * group, _SUBLANE) // _SUBLANE) * _SUBLANE
+
+
+def row_tile(t, group):
+    """Height of the kernel's query-row tiles for a step of ``t`` query
+    tokens over ``group`` query heads a KV head: the whole block up to
+    ``_ROW_TILE`` rows, else the largest multiple of 16 (bf16 packs 16
+    sublanes) up to it that divides the block."""
+    rows = _padded_rows(t, group)
+    if rows <= _ROW_TILE:
+        return rows
+    return next((tile for tile in range(_ROW_TILE, 0, -16)
+                 if rows % tile == 0), rows)
+
+
+def attn_rows(q_lens, t, group):
+    """Query rows one layer's call computes for each KV head: every slot
+    with work covers the prefix ``[0, q_len * group)`` of its block in
+    whole row tiles.  Host arithmetic (the engine's ``attn_rows``), so no
+    caller has to know the tile."""
+    tile = row_tile(t, group)
+    return sum(-(-int(q) * group // tile) * tile for q in q_lens if q > 0)
+
+
+def _pages_per_block(page_size, max_pages):
+    """Pages of one KV block: ``_BLOCK_KEYS`` keys, never more than the
+    block table is wide."""
+    return max(1, min(_BLOCK_KEYS // page_size, max_pages))
+
+
+def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
                               has_new, quantized=False, window=None,
                               layered=False):
-    """One (sequence, kv_head, page_chunk) program.
+    """One (slot, kv_head) program: the whole walk over the slot's KV.
 
-    Double-buffered page loop over this chunk's live pages (slot = absolute
-    page index % 2, so the prefetch chain crosses chunk boundaries); the
-    final chunk folds the step's fresh K/V rows with a causal mask and
-    normalizes.
+    The slot's live query rows are the prefix ``[0, q_len * group)`` of
+    its block (row ``r`` = token ``r // group``), covered by ``n_tiles``
+    row tiles of ``tile`` rows; a slot with ``q_len == 0`` covers none and
+    fetches nothing.  The KV is walked in blocks of ``ppb`` pages: a
+    block's pages are copied (one DMA a page) into ONE contiguous
+    ``[ppb * page_size, d]`` tile per K and V, block ``j + 1``'s copies in
+    flight while block ``j`` is computed (two buffers), and every row tile
+    takes one ``QK^T``, one mask, one online-softmax update and one ``PV``
+    per block.  ``q`` and ``k`` enter the MXU as stored (a bf16 x bf16
+    product is exact in the float32 accumulator; ``1/sqrt(d)`` is applied
+    to the float32 scores); the probabilities stay float32 into ``PV``.
+    After the walk each tile folds the step's own K/V rows with a causal mask and
+    normalizes.  Rows of the tiles past ``n_tiles`` are written as zeros.
 
-    ``quantized`` (int8 pool): the DMA moves the page's int8 bytes (4x
-    fewer than fp32) and the per-(kv-head, page) fp32 scales ride the
-    scalar-prefetch channel beside the block table — dequant happens on
-    the VMEM slot right after ``wait()``, so the online-softmax math
-    stays fp32 and nothing above the kernel changes shape.
+    ``quantized`` (int8 pool): the DMA moves the pages' int8 bytes and the
+    per-(kv-head, page) fp32 scales ride the scalar-prefetch channel
+    beside the block table.  An int8 value is exact in the MXU's operand
+    dtype, so the pages are multiplied as stored and the scales apply to
+    the block's score and probability COLUMNS, page by page.
 
     ``window`` (static; a sliding-attention layer): query token j, at
     position ``ctx + j``, sees keys in ``(ctx + j - window, ctx + j]``.
-    The page walk starts at the page that holds the EARLIEST query's
-    first visible key (``first``): pages wholly behind it are never
-    fetched, and chunk ``c`` covers pages ``first + c * ppc`` on, so the
-    grid needs only the chunks a window can span.
+    The walk starts at the page that holds the EARLIEST query's first
+    visible key (``first``): pages wholly behind it are never fetched.
 
     ``layered`` (static): the caches are the WHOLE pool ``[layers,
     kv_heads, num_pages, page_size, head_dim]`` in HBM and the layer to
@@ -219,127 +280,203 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppc, scale, t, group,
 
     b = pl.program_id(0)
     h = pl.program_id(1)
-    c = pl.program_id(2)
-    n_c = pl.num_programs(2)
     ctx = cl_ref[b]
+    ql = ql_ref[b]
+    rows, d = q_ref.shape
+    keys = ppb * page_size
     # all int scalars must stay strongly-typed int32: python-int divisors /
     # clip bounds embed i64 literals under x64 mode, and the i64->i32
     # convert_element_type they force breaks Mosaic lowering (the round-4
     # recursion bug) — hence lax.div/lax.rem against np.int32 constants
-    ps_c = np.int32(page_size)
-    pages_total = jax.lax.div(ctx + ps_c - np.int32(1), ps_c)
+    i32 = np.int32
+    ps_c, ppb_c, tile_c, one = i32(page_size), i32(ppb), i32(tile), i32(1)
+    max_tiles = i32(pl.cdiv(rows, tile))
+    last_entry = i32(bt_ref.shape[1] - 1)
+    n_tiles = jnp.minimum(
+        jax.lax.div(ql * i32(group) + tile_c - one, tile_c), max_tiles)
+    pages_total = jax.lax.div(ctx + ps_c - one, ps_c)
     if window is None:
         first = _I0
     else:
-        first = jax.lax.div(
-            jnp.maximum(ctx + np.int32(1 - window), _I0), ps_c)
-    start = first + c * np.int32(ppc)
-    n_here = jnp.minimum(jnp.maximum(pages_total - start, _I0),
-                         np.int32(ppc))
+        first = jax.lax.div(jnp.maximum(ctx + i32(1 - window), _I0), ps_c)
+    n_blocks = jax.lax.div(
+        jnp.maximum(pages_total - first, _I0) + ppb_c - one, ppb_c)
+    # q and k meet in q's dtype where the cache holds it or int8 (exact
+    # there), else (a float32 cache under bf16 queries) in float32
+    mxu = q_ref.dtype if kbuf.dtype in (q_ref.dtype, jnp.int8) \
+        else jnp.float32
 
-    def page_of(hbm, p):
-        return hbm.at[ly_ref[0], h, bt_ref[b, p]] if layered \
-            else hbm.at[h, bt_ref[b, p]]
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(i * tile_c, tile), tile)
 
-    def k_copy(p, slot):
-        return pltpu.make_async_copy(
-            page_of(k_hbm, p), kbuf.at[slot], sem.at[slot, _I0])
+    def page_rows(i):
+        """Rows of page ``i`` of a block in the K and V buffers."""
+        return pl.ds(pl.multiple_of(i * ps_c, page_size), page_size)
 
-    def v_copy(p, slot):
-        return pltpu.make_async_copy(
-            page_of(v_hbm, p), vbuf.at[slot], sem.at[slot, np.int32(1)])
+    def for_live_tiles(body):
+        """``body(i)`` for every live row tile (a block of one tile has
+        no loop: this runs where ``n_tiles > 0``)."""
+        if rows == tile:
+            body(_I0)
+            return
 
-    @pl.when(c == 0)
-    def _init():
-        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, m_ref.dtype)
-        l_ref[...] = jnp.zeros(l_ref.shape, l_ref.dtype)
-        acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+        def step(i, carry):
+            body(i)
+            return carry
 
-    # chain warm-up: only the very first live page of a (seq, head) visit
-    # has no chunk before it to have prefetched it
-    @pl.when(jnp.logical_and(c == 0, pages_total > first))
-    def _warmup():
-        slot0 = jax.lax.rem(first, np.int32(2))
-        k_copy(first, slot0).start()
-        v_copy(first, slot0).start()
+        jax.lax.fori_loop(_I0, n_tiles, step, _I0)
 
-    def _accumulate(s, v):
-        """Online-softmax update of the (m, l, acc) scratch carry."""
-        m_prev, l_prev = m_ref[...], l_ref[...]
+    def fetch(j, slot):
+        """Start the copies of block ``j`` into buffer ``slot``: one DMA a
+        page, K's on one semaphore and V's on another.  The whole block
+        is fetched whatever the context holds of it (an entry past the
+        context, or past the table, names a valid page whose keys are
+        masked), so one wait takes all copies.  A loop, not ``ppb``
+        copies written out: unrolled, the kernel takes ten times as long
+        to lower, which every run pays for every step program."""
+        p0 = first + j * ppb_c
+
+        def page(i):
+            pid = bt_ref[b, jnp.minimum(p0 + i, last_entry)]
+            for hbm, buf, col in ((k_hbm, kbuf, _I0), (v_hbm, vbuf, one)):
+                src = hbm.at[ly_ref[0], h, pid] if layered \
+                    else hbm.at[h, pid]
+                pltpu.make_async_copy(src, buf.at[slot, page_rows(i)],
+                                      sem.at[slot, col]).start()
+            return i + one
+
+        # static bounds: a while_loop keeps the counter int32 under x64
+        jax.lax.while_loop(lambda i: i < ppb_c, page, _I0)
+
+    def wait(slot):
+        """Wait for the ``ppb`` page copies into buffer ``slot``: a DMA
+        semaphore counts bytes, and this descriptor is a block's."""
+        for buf, col in ((kbuf, _I0), (vbuf, one)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[slot, col]).wait()
+
+    def accumulate(r, s, v, v_scale=None):
+        """Online-softmax update of row tile ``r`` of the (m, l, acc)
+        scratch with scores ``s`` over the keys whose values are ``v``."""
+        m_prev, l_prev = m_ref[r, :], l_ref[r, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+        m_ref[r, :] = m_new
+        l_ref[r, :] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        if v_scale is not None:
+            p = p * v_scale
+        # float32 p x v as stored: the compiler's mixed product (a split
+        # of p into three bf16 terms by hand measured 15-35 % slower)
+        acc_ref[r, :] = alpha * acc_ref[r, :] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(n_here > 0)
-    def _pages():
-        q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)  # [R, d]
+    def scores(r, k):
+        return jax.lax.dot_general(
+            q_ref[r, :].astype(mxu), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
 
-        def body(i, carry):
-            p = start + i
-            slot = jax.lax.rem(p, np.int32(2))
-            nxt = p + np.int32(1)
+    def tokens_of(i):
+        """[tile, 1]: the query token of each row of tile ``i``."""
+        r = i * tile_c + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        return jax.lax.div(r, jnp.full((tile, 1), group, jnp.int32))
 
-            # prefetch page p+1 (possibly the NEXT chunk's first page)
-            # while p's arrival is awaited and computed on
-            @pl.when(nxt < pages_total)
-            def _prefetch():
-                nslot = jax.lax.rem(nxt, np.int32(2))
-                k_copy(nxt, nslot).start()
-                v_copy(nxt, nslot).start()
+    def column_scales(sc_ref, p0):
+        """[1, keys]: the dequant scale of each key column of the block
+        that starts at page ``p0``."""
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        out = jnp.zeros((1, keys), jnp.float32)
+        for i in range(ppb):
+            pid = bt_ref[b, jnp.minimum(p0 + i32(i), last_entry)]
+            out = jnp.where(col >= i32(i * page_size), sc_ref[h, pid], out)
+        return out
 
-            k_copy(p, slot).wait()
-            v_copy(p, slot).wait()
-            k = kbuf[slot].astype(jnp.float32)                 # [page, d]
-            v = vbuf[slot].astype(jnp.float32)
-            if quantized:   # static: dequant on the VMEM slot post-wait
-                pid = bt_ref[b, p]
-                k = k * ksc_ref[h, pid]      # SMEM scalar load, dynamic id
-                v = v * vsc_ref[h, pid]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            pos = p * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
+    def block(j, carry):
+        slot = jax.lax.rem(j, i32(2))
+
+        @pl.when(j + one < n_blocks)
+        def _prefetch():
+            fetch(j + one, one - slot)
+
+        wait(slot)
+        p0 = first + j * ppb_c
+
+        # the last block's pages past the context are some other
+        # sequence's: their scores are masked, but p = 0 times a V that is
+        # not finite would still be NaN, so no value of theirs is kept
+        def clear(i, c):
+            vbuf[slot, page_rows(i), :] = jnp.zeros((page_size, d),
+                                                    vbuf.dtype)
+            return c
+
+        jax.lax.fori_loop(jnp.minimum(pages_total - p0, ppb_c), ppb_c,
+                          clear, _I0)
+
+        k = kbuf[slot].astype(mxu)                          # [keys, d]
+        v = vbuf[slot]
+        if quantized:        # exact, and the product bf16 pools take
+            v = v.astype(jnp.bfloat16)
+        k_scale = column_scales(ksc_ref, p0) if quantized else None
+        v_scale = column_scales(vsc_ref, p0) if quantized else None
+        base = p0 * ps_c
+
+        def row_tile_of_block(i):
+            r = rows_of(i)
+            s = scores(r, k)
+            if quantized:
+                s = s * k_scale
+            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             seen = pos < ctx
-            if window is not None:    # static: query row r is token r // group
-                q_pos = ctx + jax.lax.div(
-                    jax.lax.broadcasted_iota(jnp.int32, s.shape, 0),
-                    jnp.full(s.shape, group, jnp.int32))
-                seen = jnp.logical_and(seen, pos > q_pos - np.int32(window))
-            s = jnp.where(seen, s, jnp.float32(NEG_INF))
-            _accumulate(s, v)
-            return carry
-
-        # int32 literals: a bare python 0 is an i64 under x64 mode, and an
-        # i64->i32 convert inside the kernel breaks Mosaic lowering
-        jax.lax.fori_loop(_I0, n_here.astype(jnp.int32), body, _I0)
-
-    @pl.when(c == n_c - 1)
-    def _finalize():
-        if has_new:   # static: compiled in only for the mixed-mode form
-            q = q_ref[...].astype(jnp.float32) * jnp.float32(scale)
-            kn = knew_ref[...].astype(jnp.float32)             # [Tp, d]
-            vn = vnew_ref[...].astype(jnp.float32)
-            s = jax.lax.dot_general(q, kn, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            jq = jax.lax.div(
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 0),
-                jnp.full(s.shape, group, jnp.int32))
-            jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            valid = jnp.logical_and(jk <= jq, jk < ql_ref[b])
             if window is not None:
-                valid = jnp.logical_and(valid, jq - jk < np.int32(window))
+                seen = jnp.logical_and(
+                    seen, pos > ctx + tokens_of(i) - i32(window))
+            accumulate(r, jnp.where(seen, s, jnp.float32(NEG_INF)), v,
+                       v_scale)
+
+        for_live_tiles(row_tile_of_block)
+        return carry
+
+    def finish(i):
+        r = rows_of(i)
+        if has_new:   # static: compiled in only for the mixed-mode form
+            s = scores(r, knew_ref[...].astype(mxu))         # [tile, Tp]
+            jq = tokens_of(i)
+            jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = jnp.logical_and(jk <= jq, jk < ql)
+            if window is not None:
+                valid = jnp.logical_and(valid, jq - jk < i32(window))
             s = jnp.where(valid, s, jnp.float32(NEG_INF))
-            _accumulate(s, vn)
-        l = jnp.maximum(l_ref[...], jnp.float32(1e-30))
-        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[...] = m_ref[...] + jnp.log(l)
+            accumulate(r, s, vnew_ref[...])
+        l = jnp.maximum(l_ref[r, :], jnp.float32(1e-30))
+        o_ref[r, :] = (acc_ref[r, :] / l).astype(o_ref.dtype)
+        lse_ref[r, :] = m_ref[r, :] + jnp.log(l)
+
+    # rows past the live tiles (all of them where the slot holds nothing)
+    # are don't-care by contract: they read zeros, never what VMEM held
+    @pl.when(n_tiles < max_tiles)
+    def _blank():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+        lse_ref[...] = jnp.zeros(lse_ref.shape, jnp.float32)
+
+    @pl.when(n_tiles > _I0)
+    def _work():
+        @pl.when(n_blocks > _I0)
+        def _warmup():
+            fetch(_I0, _I0)
+
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        jax.lax.fori_loop(_I0, n_blocks, block, _I0)
+        for_live_tiles(finish)
 
 
+# jitted so that the kernel is traced once for all the call sites of one
+# signature (a step program calls it once a layer of its period, every
+# member of a step family again) and lowered once a program: lowering it
+# is paid at every set-up, cache hit or not (PERF.md section 6, PR 28)
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
                                    context_lens, q_lens, k_new, v_new,
                                    interpret, k_scale=None, v_scale=None,
@@ -351,23 +488,19 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     layered = layer is not None
     kvh, n_pages, page_size, _ = k_cache.shape[-4:]
     group = qh // kvh
-    max_pages = block_tables.shape[1]
     rows = t * group
-    R = -(-max(rows, _SUBLANE) // _SUBLANE) * _SUBLANE
+    R = _padded_rows(t, group)
 
     # [B, T, qh, d] -> [B, kvh, T*group, d]: row r = token*(group) + g, so
-    # one MXU tile holds every query row sharing this program's KV head
+    # one block holds every query row sharing this program's KV head and a
+    # slot's live rows are its prefix [0, q_len * group)
     qg = q.reshape(b, t, kvh, group, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, kvh, rows, d)
     if R != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rows), (0, 0)))
 
-    ppc = max(1, min(int(flags.flag("paged_attention_pages_per_chunk")),
-                     max_pages))
-    # a window's keys span at most ceil((window - 1) / page) + 1 pages
-    live_pages = max_pages if window is None else min(
-        max_pages, -(-(window - 1) // page_size) + 1)
-    n_chunks = -(-live_pages // ppc)
+    ppb = _pages_per_block(page_size, block_tables.shape[1])
+    keys = ppb * page_size
 
     # unused table entries must still be valid page ids for the DMA
     bt = jnp.clip(block_tables, 0, n_pages - 1).astype(jnp.int32)
@@ -375,10 +508,13 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     ql = (q_lens if q_lens is not None
           else jnp.full((b,), t)).astype(jnp.int32)
 
+    def block_of(block_rows, last):
+        return pl.BlockSpec((None, None, block_rows, last),
+                            lambda b_, h, *_: (b_, h, _I0, _I0))
+
     has_new = k_new is not None
     operands = [qg]
-    in_specs = [pl.BlockSpec((None, None, R, d),
-                             lambda b_, h, c, *_: (b_, h, _I0, _I0))]
+    in_specs = [block_of(R, d)]
     if has_new:
         Tp = -(-t // _SUBLANE) * _SUBLANE
         kn = k_new.transpose(0, 2, 1, 3)        # [B, kvh, T, d]
@@ -386,10 +522,8 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         if Tp != t:
             pad = ((0, 0), (0, 0), (0, Tp - t), (0, 0))
             kn, vn = jnp.pad(kn, pad), jnp.pad(vn, pad)
-        spec = pl.BlockSpec((None, None, Tp, d),
-                            lambda b_, h, c, *_: (b_, h, _I0, _I0))
         operands += [kn, vn]
-        in_specs += [spec, spec]
+        in_specs += [block_of(Tp, d), block_of(Tp, d)]
     quantized = k_scale is not None
     scalars = [bt, cl, ql]
     if layered:
@@ -406,22 +540,17 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
                  pl.BlockSpec(memory_space=pl.ANY)]
 
     kernel = functools.partial(
-        _ragged_paged_attn_kernel, page_size=page_size, ppc=ppc,
-        scale=1.0 / math.sqrt(d), t=t, group=group, has_new=has_new,
-        quantized=quantized, window=window, layered=layered)
+        _ragged_paged_attn_kernel, page_size=page_size, ppb=ppb,
+        tile=row_tile(t, group), scale=1.0 / math.sqrt(d), group=group,
+        has_new=has_new, quantized=quantized, window=window, layered=layered)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, kvh, n_chunks),
+        grid=(b, kvh),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((None, None, R, d),
-                         lambda b_, h, c, *_: (b_, h, _I0, _I0)),
-            pl.BlockSpec((None, None, R, 1),
-                         lambda b_, h, c, *_: (b_, h, _I0, _I0)),
-        ],
+        out_specs=[block_of(R, d), block_of(R, 1)],
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, d), k_cache.dtype),
-            pltpu.VMEM((2, page_size, d), v_cache.dtype),
+            pltpu.VMEM((2, keys, d), k_cache.dtype),
+            pltpu.VMEM((2, keys, d), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.VMEM((R, 1), jnp.float32),
             pltpu.VMEM((R, 1), jnp.float32),
@@ -437,7 +566,7 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         out_shape=[jax.ShapeDtypeStruct((b, kvh, R, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kvh, R, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*scalars, *operands)
     out = out[:, :, :rows].reshape(b, kvh, t, group, d)
@@ -525,16 +654,19 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     context; this step's own tokens are NOT included).
       q_lens:       [batch] int32 — valid query tokens per sequence
                     (None = all T).  Output rows past q_lens[b] are
-                    don't-care.
+                    don't-care: the kernel computes whole row tiles over
+                    the live rows and writes zeros past them, and a
+                    sequence with q_lens[b] == 0 is not computed at all
+                    (no DMA, no fold of k_new, no normalization).
       k_new/v_new:  [batch, T, num_kv_heads, head_dim] — the step's fresh
                     KV rows, folded in with a causal mask (token j attends
                     new tokens <= j).  They need not be written to the
                     cache before the call; commit them after the step.
       k_scale/v_scale: [num_kv_heads, num_pages] fp32 — per-(kv-head,
-                    page) dequant scales of an int8 cache pool.  Pages
-                    are dequantized inside the kernel (on the VMEM slot,
-                    right after the DMA wait) — nothing downstream
-                    changes shape.
+                    page) dequant scales of an int8 cache pool, applied
+                    inside the kernel (to a block's score and
+                    probability columns, page by page) — nothing
+                    downstream changes shape.
       with_lse:     also return the per-query logsumexp [batch, T, q_heads]
                     (fp32) for online-softmax merging of extra keys.
       window:       static int or None — sliding attention: the query

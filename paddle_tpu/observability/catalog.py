@@ -496,7 +496,7 @@ SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
         "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
-        "kv_read_tokens, slots, waiting",
+        "kv_read_tokens, attn_rows, slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
@@ -509,7 +509,12 @@ SPANS: Dict[str, tuple] = {
         "those GEMMs see, `kv_read_tokens` the key tokens the step's "
         "attention reads, summed over the layers with each layer's "
         "window applied (the host knows every row's context and query "
-        "length), `slots` the batch B, `waiting` the queue behind it"),
+        "length), `attn_rows` the query rows the paged kernel's row tiles "
+        "cover for each KV head in one layer's call (every slot with work "
+        "covers its `q_len x group` rows in whole tiles; "
+        "`kernels.paged_attention.attn_rows`), so `q_tokens x group / "
+        "attn_rows` is to attention what `q_tokens / gemm_rows` is to the "
+        "GEMMs, `slots` the batch B, `waiting` the queue behind it"),
     "engine.admit": (
         "serving", "engine", "local", "admitted, waiting",
         "`_admit`: waiting requests into free slots, their pages and the "

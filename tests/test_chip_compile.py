@@ -14,6 +14,7 @@ library.  Keep all such tests in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -196,25 +197,69 @@ def test_fused_gather_gmm_is_refused_with_the_compilers_reason(one_chip):
 CA_QH, CA_KVH, CA_B, CA_LEN, CA_PAGES = 128, 8, 16, 12416, 12416
 
 
-@pytest.mark.parametrize("window", [4096, None], ids=["sliding", "full"])
-@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
-def test_paged_attention_command_a_plus_shapes_compile(one_chip, T, window):
-    """The windowed walk and the 1,024-row block fit the chip's VMEM; the
-    window is in the kernel's name so a trace tells the kinds apart."""
-    assert pa.kernel_geometry_error(PAGE, D, kv_heads=CA_KVH,
-                                    num_pages=CA_PAGES,
-                                    table_shape=(CA_B, CA_LEN // PAGE)) is None
+_PAGED_CALL = re.compile(
+    r"%(ragged_paged_attention\w*)[.\d]* = \((\w+\[[\d,]+\])\{[^}]*\}, "
+    r"(\w+\[[\d,]+\])\{[^}]*\}\) custom-call\(.*"
+    r"operand_layout_constraints=\{(\w+\[[\d,]+\])")
+
+
+def _paged_call(compiled):
+    """What the benchmark recognises the kernel's call by (``chipbench/
+    kernels/paged_attention.py::match``): (name, output, log-sum-exp,
+    first operand) of the one custom call, as the compiled program's text
+    has them."""
+    calls = _PAGED_CALL.findall(compiled.as_text())
+    assert len(calls) == 1, calls
+    return calls[0]
+
+
+def _compile_engine_call(one_chip, slots, qh, kvh, T, table, n_pages, window):
+    """The kernel as an engine's step calls it: the step's own K/V rows,
+    one layer's bf16 cache, a table ``[slots, table]``."""
+    assert pa.kernel_geometry_error(PAGE, D, kv_heads=kvh,
+                                    num_pages=n_pages,
+                                    table_shape=(slots, table)) is None
     i32 = jnp.int32
-    cache = ((CA_KVH, CA_PAGES, PAGE, D), BF16)
-    compiled = _compile(
+    cache = ((kvh, n_pages, PAGE, D), BF16)
+    return _compile(
         one_chip,
         lambda q, k, v, bt, cl, ql, kn, vn: pa._pallas_ragged_paged_attention(
             q, k, v, bt, cl, ql, kn, vn, interpret=False, window=window),
-        ((CA_B, T, CA_QH, D), BF16), cache, cache,
-        ((CA_B, CA_LEN // PAGE), i32), ((CA_B,), i32), ((CA_B,), i32),
-        ((CA_B, T, CA_KVH, D), BF16), ((CA_B, T, CA_KVH, D), BF16))
-    assert ("ragged_paged_attention_w4096" in compiled.as_text()) \
-        == (window is not None)
+        ((slots, T, qh, D), BF16), cache, cache,
+        ((slots, table), i32), ((slots,), i32), ((slots,), i32),
+        ((slots, T, kvh, D), BF16), ((slots, T, kvh, D), BF16))
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["sliding", "full"])
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_paged_attention_command_a_plus_shapes_compile(one_chip, T, window):
+    """The windowed walk and the 1,024-row block (four row tiles over
+    KV blocks of 64 pages) fit the chip's VMEM, and the call is what the
+    benchmark matches on: the block table first, the pair (output,
+    float32 log-sum-exp with a last dimension of 1) with ``q_rows ==
+    max(8, T x group)``, the window in the name."""
+    compiled = _compile_engine_call(one_chip, CA_B, CA_QH, CA_KVH, T,
+                                    CA_LEN // PAGE, CA_PAGES, window)
+    rows = max(8, T * CA_QH // CA_KVH)
+    assert _paged_call(compiled) == (
+        "ragged_paged_attention" + ("_w4096" if window else ""),
+        f"bf16[16,8,{rows},128]", f"f32[16,8,{rows},1]", "s32[16,776]")
+    if T == 64:
+        assert rows == 1024 and pa.row_tile(T, CA_QH // CA_KVH) == 256
+
+
+@pytest.mark.parametrize("table", [160, 264], ids=["chat", "mixtral_batch"])
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_paged_attention_mistral_engine_shapes_compile(one_chip, T, table):
+    """The chat and Mixtral cells' engines: 32 slots, 32 query heads over
+    8 KV heads, tables of 160 (2,560 positions) and 264 pages (4,224), a
+    pool of 8,448 pages."""
+    compiled = _compile_engine_call(one_chip, 32, 32, 8, T, table, 8448,
+                                    None)
+    rows = max(8, T * 4)
+    assert _paged_call(compiled) == (
+        "ragged_paged_attention", f"bf16[32,8,{rows},128]",
+        f"f32[32,8,{rows},1]", f"s32[32,{table}]")
 
 
 def test_gmm_with_live_tiles_compiles(one_chip):

@@ -373,39 +373,161 @@ def test_continuous_batching_exact_page_multiple_prompts(rng):
 # ragged kernel edge cases (vs the reference oracles)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("qh,kvh,ctx,ppc", [
+@pytest.mark.parametrize("qh,kvh,ctx,max_pages", [
     # exact page multiples (ctx % page == 0), incl. a 1-page and a max-page
     # sequence in one ragged batch
     (4, 4, (8, 64, 16, 32), 8),
     # single-token contexts next to max-page ones
     (4, 2, (1, 64, 1, 40), 8),
-    # GQA ratio 4, ragged mix, multi-chunk grid (ppc=2 forces chunking)
-    (8, 2, (5, 64, 8, 17), 2),
-    # MQA-ish ratio 8, chunk size 1 (page-per-chunk degenerate grid)
-    (8, 1, (64, 1, 33, 24), 1),
+    # GQA ratio 4, ragged mix: a KV block of 128 pages (1,024 keys) under
+    # a table of 264, so the walk takes one, two and three blocks: contexts
+    # inside a block, at its edge, one page past it, at the table's end
+    (8, 2, (5, 1024, 1032, 2112), 264),
+    # MQA-ish ratio 8, the same with odd ends inside the second and third
+    # block and a single token
+    (8, 1, (2050, 1, 1025, 777), 264),
 ])
-def test_paged_attention_edge_cases_vs_oracle(rng, qh, kvh, ctx, ppc):
+def test_paged_attention_edge_cases_vs_oracle(rng, qh, kvh, ctx, max_pages):
     """Decode kernel vs the reference across the ragged edge shapes: page
-    boundaries, single tokens, 1-page/max-page mixes, GQA ratios != 1."""
+    and block boundaries, single tokens, 1-page/max-page mixes, GQA
+    ratios != 1."""
     d, page = 128, 8
     n_pages = 64
     B = len(ctx)
+    assert pa._pages_per_block(page, max_pages) == min(max_pages, 128)
     kc, vc = _mk_cache(rng, n_pages, page, kvh, d)
     q = jnp.asarray(rng.standard_normal((B, qh, d)), jnp.float32)
-    bt = jnp.asarray(rng.integers(0, n_pages, (B, 8)), jnp.int32)
+    bt = jnp.asarray(rng.integers(0, n_pages, (B, max_pages)), jnp.int32)
     cl = jnp.asarray(ctx, jnp.int32)
 
     expect = pa._reference_paged_attention(q, kc, vc, bt, cl)
-    old = flags.get_flags(["paged_attention_interpret",
-                           "paged_attention_pages_per_chunk"])
-    flags.set_flags({"paged_attention_interpret": True,
-                     "paged_attention_pages_per_chunk": ppc})
+    old = flags.get_flags(["paged_attention_interpret"])
+    flags.set_flags({"paged_attention_interpret": True})
     try:
         got = pa.paged_attention(q, kc, vc, bt, cl)
     finally:
         flags.set_flags(old)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_block_and_tile_sizes_follow_from_the_shapes():
+    """A KV block aims at 1,024 keys in whole pages and never passes the
+    table; a row tile is the whole block up to 256 rows, else a multiple
+    of 16 that divides it."""
+    assert [pa._pages_per_block(p, w) for p, w in
+            [(16, 160), (16, 776), (8, 264), (32, 34), (16, 10), (8, 8)]] \
+        == [64, 64, 128, 32, 10, 8]
+    assert [pa.row_tile(t, g) for t, g in
+            [(1, 1), (1, 4), (1, 16), (8, 4), (64, 1), (64, 4), (64, 16),
+             (64, 5), (24, 11)]] == [8, 8, 16, 32, 64, 256, 256, 160, 264]
+    # the rows a call covers: a slot's q_len x group rows in whole tiles
+    assert pa.attn_rows([64, 1, 0, 17], 64, 16) == 1024 + 256 + 512
+    assert pa.attn_rows([64, 1, 0, 33], 64, 4) == 3 * 256
+    assert pa.attn_rows([0, 0], 64, 4) == 0
+
+
+def _schedule_case(rng, cache, *, kvh, group, T, ctx, ql, window=None):
+    """One mixed-mode call on a long table (2,112 positions: a KV block is
+    1,024 keys) with a float32, bf16 or int8 pool; (kernel output and lse,
+    float32 oracle's) over the inputs as the pool holds them."""
+    d = 128
+    page = {"float32": 16, "bfloat16": 16, "int8": 32}[cache]
+    W, n_pages, B = 2112 // page, 96, len(ctx)
+    qdt = jnp.bfloat16 if cache == "bfloat16" else jnp.float32
+    qh = kvh * group
+    q = jnp.asarray(rng.standard_normal((B, T, qh, d)), qdt)
+    kn = jnp.asarray(rng.standard_normal((B, T, kvh, d)), qdt)
+    vn = jnp.asarray(rng.standard_normal((B, T, kvh, d)), qdt)
+    bt = jnp.asarray(rng.integers(0, n_pages, (B, W)), jnp.int32)
+    cl, qlens = jnp.asarray(ctx, jnp.int32), jnp.asarray(ql, jnp.int32)
+    ks = vs = None
+    if cache == "int8":
+        kc = jnp.asarray(rng.integers(-127, 128, (kvh, n_pages, page, d)),
+                         jnp.int8)
+        vc = jnp.asarray(rng.integers(-127, 128, (kvh, n_pages, page, d)),
+                         jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.005, 0.02, (kvh, n_pages)),
+                         jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.005, 0.02, (kvh, n_pages)),
+                         jnp.float32)
+    else:
+        kc, vc = _mk_cache(rng, n_pages, page, kvh, d, qdt)
+
+    def f32(a):
+        return a.astype(jnp.float32)
+
+    ref = pa._reference_ragged_paged_attention(
+        f32(q), kc if ks is not None else f32(kc),
+        vc if vs is not None else f32(vc), bt, cl, qlens, f32(kn), f32(vn),
+        k_scale=ks, v_scale=vs, window=window)
+    old = flags.get_flags(["paged_attention_interpret"])
+    flags.set_flags({"paged_attention_interpret": True})
+    try:
+        got = pa.ragged_paged_attention(
+            q, kc, vc, bt, cl, q_lens=qlens, k_new=kn, v_new=vn, k_scale=ks,
+            v_scale=vs, window=window, with_lse=True)
+    finally:
+        flags.set_flags(old)
+    return got, ref
+
+
+def _assert_live_rows_match(got, ref, ql, cache):
+    """Rows past q_lens[b] (all of an idle slot's) are don't-care.  bf16
+    operands multiply exactly, so only the output's own rounding shows."""
+    (out, lse), (ref_out, ref_lse) = got, ref
+    assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
+    tol = 1e-2 if cache == "bfloat16" else 5e-5
+    for b, n in enumerate(ql):
+        np.testing.assert_allclose(
+            np.asarray(out[b, :n].astype(jnp.float32)),
+            np.asarray(ref_out[b, :n]), rtol=tol, atol=tol)
+        np.testing.assert_allclose(np.asarray(lse[b, :n]),
+                                   np.asarray(ref_lse[b, :n]),
+                                   rtol=5e-5, atol=5e-5)
+
+
+@pytest.mark.parametrize("cache", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("T,ctx,ql", [
+    # decode beside a chunk: contexts inside the first block, at its edge
+    # (1,024), one page past it, at the second block's edge, the table's end
+    (8, (300, 1024, 1056, 2048, 2104), (1, 8, 1, 5, 8)),
+    # an idle slot (q_lens == 0) between live slots, contexts of none, two
+    # blocks and a bit, exactly two blocks
+    (8, (0, 2100, 1500, 2048), (8, 0, 3, 0)),
+], ids=["block_edges", "idle_between_live"])
+def test_block_walk_vs_oracle(rng, cache, T, ctx, ql):
+    """The KV walk in blocks of many pages: the last block's tail is
+    masked by position, a slot without work leaves its neighbours' rows
+    right, and the bf16 and int8 pools multiply as stored."""
+    got, ref = _schedule_case(rng, cache, kvh=2, group=4, T=T, ctx=ctx,
+                              ql=ql)
+    _assert_live_rows_match(got, ref, ql, cache)
+
+
+@pytest.mark.parametrize("cache", ["float32", "bfloat16"])
+@pytest.mark.parametrize("group,ql", [
+    # 64 rows: one tile, the whole block
+    (1, (1, 64, 33, 0)),
+    # 256 rows: one tile again, whatever the slot holds of it
+    (4, (1, 64, 33, 0)),
+    # 1,024 rows in tiles of 256: one tile, four, 17 x 16 = 272 rows (a
+    # second tile for sixteen of them), none
+    (16, (1, 64, 17, 0)),
+])
+def test_row_tiles_follow_q_lens_inside_a_step_of_64(rng, cache, group, ql):
+    """A decoding slot inside a T = 64 step computes one row tile, a
+    ``q_len x group`` that is no multiple of the tile one more than it
+    fills, and only the tiles past them are written as zeros."""
+    assert pa.row_tile(64, group) == min(64 * group, 256)
+    got, ref = _schedule_case(rng, cache, kvh=1, group=group, T=64,
+                              ctx=(1030, 17, 2000, 64), ql=ql)
+    _assert_live_rows_match(got, ref, ql, cache)
+    out = np.asarray(got[0].astype(jnp.float32))
+    tile_tokens = max(pa.row_tile(64, group) // group, 1)
+    for b, n in enumerate(ql):
+        covered = -(-n // tile_tokens) * tile_tokens
+        assert not out[b, covered:].any()
 
 
 def test_ragged_paged_attention_mixed_mode_parity(rng):

@@ -42,23 +42,21 @@ def interpret(request):
     flags.set_flags({"paged_attention_interpret": False})
 
 
-@pytest.mark.parametrize("interpret", [True, False], indirect=True,
-                         ids=["kernel_interpreted", "xla_oracle"])
-@pytest.mark.parametrize("T", [1, 8], ids=["decode", "chunk"])
-@pytest.mark.parametrize("window", [None, 1, 5, 8, 16, 21, 64, 200])
-def test_window_against_a_dense_masked_softmax(window, T, interpret):
+def _kernel_and_dense(window, T, contexts, max_pages):
+    """The entry point's output and the dense oracle's for slots at
+    ``contexts`` whose pages lie scattered in the pool."""
     rng = np.random.default_rng(0)
-    B = len(CONTEXTS)
-    n_pages = B * MAX_PAGES + 3
-    S = MAX_PAGES * PAGE
+    B = len(contexts)
+    n_pages = B * max_pages + 3
+    S = max_pages * PAGE
     k_full = rng.normal(size=(B, S, KVH, D)).astype(np.float32)
     v_full = rng.normal(size=(B, S, KVH, D)).astype(np.float32)
     q = rng.normal(size=(B, T, QH, D)).astype(np.float32)
     ql = np.asarray([T if b % 2 == 0 else max(1, T // 2) for b in range(B)],
                     np.int32)
-    ctx = np.asarray(CONTEXTS, np.int32)
-    table = rng.permutation(n_pages)[:B * MAX_PAGES].reshape(
-        B, MAX_PAGES).astype(np.int32)          # pages scattered in the pool
+    ctx = np.asarray(contexts, np.int32)
+    table = rng.permutation(n_pages)[:B * max_pages].reshape(
+        B, max_pages).astype(np.int32)          # pages scattered in the pool
     kc = np.zeros((KVH, n_pages, PAGE, D), np.float32)
     vc = np.zeros_like(kc)
     for b in range(B):
@@ -71,8 +69,33 @@ def test_window_against_a_dense_masked_softmax(window, T, interpret):
         jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(table),
         jnp.asarray(ctx), q_lens=jnp.asarray(ql), k_new=jnp.asarray(k_new),
         v_new=jnp.asarray(v_new), window=window))
-    want = _dense(q, k_full, v_full, ctx, ql, window)
-    for b in range(B):          # rows past q_lens[b] are don't-care
+    return got, _dense(q, k_full, v_full, ctx, ql, window), ql
+
+
+@pytest.mark.parametrize("interpret", [True, False], indirect=True,
+                         ids=["kernel_interpreted", "xla_oracle"])
+@pytest.mark.parametrize("T", [1, 8], ids=["decode", "chunk"])
+@pytest.mark.parametrize("window", [None, 1, 5, 8, 16, 21, 64, 200])
+def test_window_against_a_dense_masked_softmax(window, T, interpret):
+    got, want, ql = _kernel_and_dense(window, T, CONTEXTS, MAX_PAGES)
+    for b in range(len(CONTEXTS)):   # rows past q_lens[b] are don't-care
+        np.testing.assert_allclose(got[b, :ql[b]], want[b, :ql[b]],
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("interpret", [True], indirect=True,
+                         ids=["kernel_interpreted"])
+@pytest.mark.parametrize("window", [100, 1025, 1200])
+def test_a_windows_first_page_falls_inside_a_block(window, interpret):
+    """A table of 260 pages walks in blocks of 128 pages (1,024 keys).
+    The windowed walk starts at the page of the earliest query's first
+    visible key, so its blocks lie anywhere against the table's: the
+    window's head is masked inside the first block, a window of 1,200
+    needs a second block, and the unmasked path is taken only by blocks
+    that every live row sees whole."""
+    contexts = [1400, 2000, 64, 0, 2060, 1025]
+    got, want, ql = _kernel_and_dense(window, 8, contexts, 260)
+    for b in range(len(contexts)):
         np.testing.assert_allclose(got[b, :ql[b]], want[b, :ql[b]],
                                    rtol=1e-5, atol=1e-5)
 

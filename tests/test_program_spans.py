@@ -317,6 +317,38 @@ def test_phases_stay_in_the_ring_and_only_whole_steps_are_exported(sinks):
     assert {lanes[e["tid"]] for e in ring if e["name"] in SPANS} == {"engine"}
 
 
+def test_a_steps_span_carries_the_rows_of_its_attention_tiles(sinks,
+                                                            monkeypatch):
+    """``attn_rows`` against the kernel's own tile arithmetic.  With row
+    tiles of 8 (the tiny model's T x group = 16 rows would be one tile) a
+    slot's ``q_len x 2`` live rows round up to whole tiles: the 19-token
+    prompt goes in as 8 + 8 + 3 tokens beside the 2-token prompt's chunk
+    and then its decode token, and a decode step's block is 8 rows."""
+    from paddle_tpu.kernels import paged_attention as pa
+    monkeypatch.setattr(pa, "_ROW_TILE", 8)
+    assert pa.row_tile(BUCKET, 2) == 8 and pa.row_tile(1, 2) == 8
+    ring, _ = sinks
+    eng = _tiny_engine()
+    eng.add_request(list(range(1, 20)))
+    eng.add_request([4, 5])
+    eng.run()
+    steps = [e["args"] for e in ring if e["name"] == "engine.step"]
+    mixed = [a for a in steps if a["kind"] == "mixed"]
+    assert [(a["q_tokens"], a["attn_rows"]) for a in mixed] == [
+        (10, 16 + 8), (9, 16 + 8), (4, 8 + 8)]
+    for a in steps:
+        assert a["attn_rows"] % 8 == 0
+        assert a["q_tokens"] * 2 <= a["attn_rows"] <= a["slots"] * max(
+            a["T"] * 2, 8)
+    assert all(a["attn_rows"] == 8 * a["rows"] for a in steps
+               if a["kind"] == "decode")
+    # the same sums from the kernel's module, whatever the tile
+    monkeypatch.undo()
+    assert pa.attn_rows([64, 1, 0, 3], 64, 4) == 3 * 256
+    assert pa.attn_rows([64, 1, 17], 64, 16) == 1024 + 256 + 512
+    assert pa.attn_rows([1, 1, 0], 1, 4) == 16
+
+
 def test_steptimer_records_no_event_and_train_step_is_a_live_span(sinks):
     ring, _ = sinks
     t = obs.StepTimer("t24train")
