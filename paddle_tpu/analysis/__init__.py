@@ -64,7 +64,7 @@ def analyze_source(source: str, rel: str = "paddle_tpu/example.py",
 
 def package_report() -> dict:
     """Run the analyzer over the installed ``paddle_tpu`` package and
-    return the JSON-shaped summary (the benchmarks/run.py stamp)."""
+    return the JSON-shaped summary."""
     import json
     import os
 

@@ -161,9 +161,8 @@ define_flag("metrics", True,
             "hot paths (paddle_tpu/observability/): per-request TTFT/ITL "
             "histograms, StepTimer train telemetry, pool gauges.  The "
             "overhead contract (warm steps: zero recompiles, zero added "
-            "device syncs, <2% tok/s) is telemetry-asserted in tests and "
-            "A/B'd by `benchmarks/run.py serve`; 0 disables every hot-path "
-            "instrumentation site.")
+            "device syncs) is telemetry-asserted in tests; 0 disables every "
+            "hot-path instrumentation site.")
 define_flag("trace_max_events", 200000,
             "Cap on buffered Chrome-trace events in the observability "
             "tracer (observability/tracing.py); overflow is counted in the "

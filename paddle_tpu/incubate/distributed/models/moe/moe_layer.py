@@ -54,8 +54,7 @@ def _dispatch_indices(gate_val, gate_idx, num_experts, capacity):
     priority, same drops, but carried as int32 maps instead of [N, E, C]
     one-hots.  Delegates to the single-sourced
     ``kernels.grouped_matmul.capacity_dispatch_plan`` (the "gather"
-    dispatch idiom of models.llama — see the dispatch-mode matrix in
-    benchmarks/README.md); returns (inv, slot, gate_keep)."""
+    dispatch idiom of models.llama); returns (inv, slot, gate_keep)."""
     from .....kernels.grouped_matmul import capacity_dispatch_plan
 
     inv, slot, gate_keep, _ = capacity_dispatch_plan(

@@ -365,7 +365,7 @@ class LlamaGenerator:
     shared and held experts, tied head) is data of the model here."""
 
     def __init__(self, model, *, max_batch: int = 8,
-                 max_seq_len: Optional[int] = None, page_size=32,
+                 max_seq_len: Optional[int] = None, page_size: int = 32,
                  cache_dtype: Optional[str] = None,
                  prefill_bucket: int = 64, sync_every: int = 8,
                  num_pages: Optional[int] = None,
@@ -417,16 +417,6 @@ class LlamaGenerator:
             cache_dtype = None if fd == "auto" else fd
         cache_dtype = {"fp32": "float32", "bf16": "bfloat16"}.get(
             cache_dtype, cache_dtype)
-        if page_size in (None, "auto"):
-            # the page IS the decode kernel's KV tile: consult the measured
-            # autotune cache (populated by the bench's decode sweep), fall
-            # back to 32 on a cold cache (phi autotune-cache idiom)
-            from ..kernels import autotune
-            page_size = autotune.lookup(autotune.make_key(
-                "paged_decode", heads=c.num_kv_heads,
-                d=c.head_dim, dt=str(cache_dtype or dtype))) or 32
-            if isinstance(page_size, (tuple, list)):
-                page_size = page_size[0]
         page_size = int(page_size)
         self.page_size = page_size
         self.prefill_bucket = min(prefill_bucket, self.max_seq_len)

@@ -144,8 +144,8 @@ def measured(kernel: str) -> dict:
 
 
 def record(key: str, config, measurements: Optional[dict] = None):
-    """Explicitly store a measured winner (used by external sweeps, e.g.
-    the bench's decode page-size search)."""
+    """Explicitly store a winner chosen outside the tuner (the benchmark
+    pins flash tiles this way)."""
     with _LOCK:
         _load()
         _MEM[key] = list(config) if isinstance(config, (tuple, list)) \

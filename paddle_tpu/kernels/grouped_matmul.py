@@ -14,13 +14,6 @@ TPU-native design (megablocks-style, built for the MXU):
   prefetch channel (`pltpu.PrefetchScalarGridSpec`) so the index map can
   DMA the right expert's weight block — data-dependent weight selection
   with zero data-dependent control flow inside the kernel.
-- The dispatch permutation itself also rides the scalar-prefetch channel:
-  ``rows`` (gmm) / ``lhs_rows``/``rhs_rows`` (tgmm) carry the
-  padded-buffer-row -> token-row map and the kernel gathers operand rows
-  straight out of HBM with per-row async copies into a VMEM staging
-  block, so the ``[M, H]`` permuted operand copies of an unfused
-  dispatch never materialize (an optional per-row ``row_scale`` fuses
-  the combine-weight scaling of the MoE backward the same way).
 - ``gmm``: out[m] = lhs[m] @ rhs[group(m)] with an fp32 VMEM accumulator
   over k-steps.  ``tgmm`` (the weight-grad transpose) accumulates
   lhs^T @ rhs into out[group]: the m grid dim is innermost, so each
@@ -59,17 +52,6 @@ flags.define_flag("grouped_matmul_bn", 0,
 flags.define_flag("grouped_matmul_bk", 0,
                   "Default grouped-matmul contraction tile (0 = default); "
                   "explicit bk arguments always take precedence.")
-flags.define_flag("grouped_matmul_fused_gather", False,
-                  "Fuse the MoE dispatch row-gather (and optional per-row "
-                  "combine scale) into the grouped-matmul kernels via "
-                  "scalar-prefetched row indices + per-row DMA. Off (the "
-                  "default): materialize the permuted operand and run the "
-                  "plain block kernels. On a TPU the fused arm does not "
-                  "compile today — Mosaic refuses the one-row slice of a "
-                  "tiled HBM operand (\"Slice shape along dimension 0 must "
-                  "be aligned to tiling (8), but is 1\") — so choosing it "
-                  "there fails when the step is built; interpret mode "
-                  "runs it.")
 
 
 def _mode(interpret=None):
@@ -184,53 +166,15 @@ def _tune(kind, key, M, K, N, E, bm, dtype):
     return autotune.lookup_or_tune(key, cands, bench, None)
 
 
-# ----------------------------------------------------- fused row gather ---
-
-def _gather_rows(src_ref, rows_ref, base, col0, ncols, dst_ref, sem, bm):
-    """Gather ``bm`` arbitrary rows of ``src_ref`` (HBM) into the VMEM
-    staging block ``dst_ref``: start all per-row copies back-to-back so
-    they overlap, then drain the semaphore.  This is the in-kernel form
-    of the dispatch permutation — same HBM bytes as the block fetch of a
-    pre-permuted operand, without ever writing the permuted copy."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def copy(r):
-        return pltpu.make_async_copy(
-            src_ref.at[rows_ref[base + r], pl.ds(col0, ncols)],
-            dst_ref.at[r], sem)
-
-    # a while_loop with an np.int32 carry, not fori_loop: with static
-    # bounds fori_loop becomes a scan whose counter is a python int — i64
-    # under x64 — and the i64 index reaching ``rows_ref[base + r]`` sends
-    # Mosaic's convert_element_type lowering into endless recursion
-    def each_row(fn):
-        def body(r):
-            fn(r)
-            return r + np.int32(1)
-        jax.lax.while_loop(lambda r: r < np.int32(bm), body, np.int32(0))
-
-    each_row(lambda r: copy(r).start())
-    each_row(lambda r: copy(r).wait())
-
-
 # ------------------------------------------------------------------ gmm ---
 
-def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled, ragged=False):
+def _gmm_kernel(*refs, nk, trans_rhs, ragged):
     from jax.experimental import pallas as pl
 
-    it = iter(refs)
-    group_ref = next(it)
-    live_ref = next(it) if ragged else None
-    rows_ref = next(it) if fused else None
-    lhs_ref = next(it)
-    rhs_ref = next(it)
-    scale_ref = next(it) if scaled else None
-    out_ref = next(it)
-    lx_ref = next(it) if fused else None
-    acc_ref = next(it)
-    sem = next(it) if fused else None
-    del group_ref
+    # scalar prefetch first: tile_groups (the index maps' alone), then the
+    # live-tile count of a ragged call
+    live_ref = refs[1] if ragged else None
+    lhs_ref, rhs_ref, out_ref, acc_ref = refs[-4:]
     # read outside every pl.when: interpret mode has no rule for it inside
     i, kk = pl.program_id(0), pl.program_id(2)
 
@@ -239,20 +183,11 @@ def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled, ragged=False):
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        if fused:
-            _gather_rows(lhs_ref, rows_ref, i * bm, kk * bk, bk, lx_ref,
-                         sem, bm)
-            lblk = lx_ref[...]
-        else:
-            lblk = lhs_ref[...]
-        if scaled:
-            lblk = lblk * scale_ref[...]
-
+        lhs = lhs_ref[...]
         dims = (((1,), (1,)), ((), ())) if trans_rhs \
             else (((1,), (0,)), ((), ()))
         acc_ref[...] += jax.lax.dot_general(
-            lblk, rhs_ref[...], dims,
-            preferred_element_type=jnp.float32)
+            lhs, rhs_ref[...], dims, preferred_element_type=jnp.float32)
 
         @pl.when(kk == nk - 1)
         def _flush():
@@ -267,22 +202,14 @@ def _gmm_kernel(*refs, nk, trans_rhs, bm, bk, fused, scaled, ragged=False):
 
 
 def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
-        interpret=None, rows=None, row_scale=None, live_tiles=None):
+        interpret=None, live_tiles=None):
     """Grouped matmul: ``out[m, :] = lhs[m, :] @ rhs[tile_groups[m//bm]]``.
 
     lhs: [M, C] with rows grouped by expert, group spans bm-aligned.
     rhs: [E, C, O] ([E, O, C] when ``trans_rhs``).
     tile_groups: [M//bm] int32, nondecreasing, expert id per row-tile.
     bn/bk: explicit tiles win over the autotune cache and the sweep
-    flags (see ``_resolve_tiles``).
-
-    rows: optional int32 [M] fused dispatch gather — lhs is then the
-    UN-permuted token buffer [L, C] and the kernel computes
-    ``out[m] = lhs[rows[m]] @ rhs[group(m)]``, reading lhs rows straight
-    from HBM via scalar-prefetched indices (no [M, C] permuted copy in
-    HBM).  row_scale: optional fp [M] per-row multiplier fused the same
-    way (diag(s) @ lhs[rows] @ rhs — the combine-weight scaling of the
-    MoE backward).  Returns [M, O] in lhs.dtype.
+    flags (see ``_resolve_tiles``).  Returns [M, O] in lhs.dtype.
 
     live_tiles: optional int32 scalar (a device value) — only the first
     ``live_tiles`` row tiles hold rows some expert owns (a layer that
@@ -295,8 +222,7 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    M = rows.shape[0] if rows is not None else lhs.shape[0]
-    C = lhs.shape[1]
+    M, C = lhs.shape
     E = rhs.shape[0]
     O = rhs.shape[1] if trans_rhs else rhs.shape[2]
     if M % bm:
@@ -304,18 +230,10 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
     mode = _mode(interpret)
     if mode is None:
         return _gmm_reference(lhs, rhs, tile_groups, bm=bm,
-                              trans_rhs=trans_rhs, rows=rows,
-                              row_scale=row_scale)
+                              trans_rhs=trans_rhs)
     bn, bk = _resolve_tiles("gmm_t" if trans_rhs else "gmm", M, C, O, E,
                             bm, lhs.dtype, bn, bk, mode)
     nk = C // bk
-
-    fused = rows is not None and flags.flag("grouped_matmul_fused_gather")
-    if rows is not None and not fused:
-        lhs = jnp.take(lhs, rows, axis=0)
-    scaled = fused and row_scale is not None
-    if row_scale is not None and not scaled:   # scale without fused gather
-        lhs = lhs * row_scale[:, None].astype(lhs.dtype)
 
     scalars = [tile_groups.astype(jnp.int32)]
     ragged = live_tiles is not None
@@ -327,7 +245,7 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
             """``fn``'s block for a live tile; for every tile after them
             the last live tile's last block, so that consecutive dead
             steps name one block and the pipeline moves nothing."""
-            def index_map(i, j, k, g, live, *_):
+            def index_map(i, j, k, g, live):
                 # np.int32 constants: a bare python int is an i64 under
                 # x64 mode, and the convert breaks Mosaic lowering
                 dead = i >= live[0]
@@ -337,42 +255,22 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
             return index_map
     else:
         def park(fn):
-            return lambda i, j, k, g, *_: fn(i, j, k, g)
-    in_specs = []
-    operands = []
-    if fused:
-        scalars.append(rows.astype(jnp.int32))
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    else:
-        in_specs.append(
-            pl.BlockSpec((bm, bk), park(lambda i, j, k, g: (i, k))))
-    operands.append(lhs)
-    in_specs.append(
-        pl.BlockSpec((None, bn, bk), park(lambda i, j, k, g: (g[i], j, k)))
-        if trans_rhs else
-        pl.BlockSpec((None, bk, bn), park(lambda i, j, k, g: (g[i], k, j))))
-    operands.append(rhs)
-    if scaled:
-        in_specs.append(
-            pl.BlockSpec((bm, 1), park(lambda i, j, k, g: (i, 0))))
-        operands.append(row_scale.reshape(M, 1).astype(lhs.dtype))
-
-    scratch = []
-    if fused:
-        scratch.append(pltpu.VMEM((bm, bk), lhs.dtype))
-    scratch.append(pltpu.VMEM((bm, bn), jnp.float32))
-    if fused:
-        scratch.append(pltpu.SemaphoreType.DMA(()))
+            return fn
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(M // bm, O // bn, nk),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((bm, bk), park(lambda i, j, k, g: (i, k))),
+            pl.BlockSpec((None, bn, bk),
+                         park(lambda i, j, k, g: (g[i], j, k)))
+            if trans_rhs else
+            pl.BlockSpec((None, bk, bn),
+                         park(lambda i, j, k, g: (g[i], k, j)))],
         out_specs=pl.BlockSpec((bm, bn), park(lambda i, j, k, g: (i, j))),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     kernel = functools.partial(_gmm_kernel, nk=nk, trans_rhs=trans_rhs,
-                               bm=bm, bk=bk, fused=fused, scaled=scaled,
                                ragged=ragged)
     return pl.pallas_call(
         kernel,
@@ -385,26 +283,13 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
             dimension_semantics=("arbitrary" if ragged else "parallel",
                                  "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
-    )(*scalars, *operands)
+    )(*scalars, lhs, rhs)
 
 
 # ----------------------------------------------------------------- tgmm ---
 
-def _tgmm_kernel(*refs, nm, bm, bk, bn, lfused, rfused, rscaled):
+def _tgmm_kernel(group_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *, nm):
     from jax.experimental import pallas as pl
-
-    it = iter(refs)
-    group_ref = next(it)
-    lrows_ref = next(it) if lfused else None
-    rrows_ref = next(it) if rfused else None
-    lhs_ref = next(it)
-    rhs_ref = next(it)
-    scale_ref = next(it) if rscaled else None
-    out_ref = next(it)
-    lx_ref = next(it) if lfused else None
-    rx_ref = next(it) if rfused else None
-    acc_ref = next(it)
-    sem = next(it) if (lfused or rfused) else None
 
     m = pl.program_id(2)
     g_here = group_ref[m]
@@ -421,24 +306,9 @@ def _tgmm_kernel(*refs, nm, bm, bk, bn, lfused, rfused, rscaled):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    base = m * bm
-    if lfused:
-        _gather_rows(lhs_ref, lrows_ref, base, pl.program_id(0) * bk, bk,
-                     lx_ref, sem, bm)
-        lblk = lx_ref[...]
-    else:
-        lblk = lhs_ref[...]
-    if rfused:
-        _gather_rows(rhs_ref, rrows_ref, base, pl.program_id(1) * bn, bn,
-                     rx_ref, sem, bm)
-        rblk = rx_ref[...]
-    else:
-        rblk = rhs_ref[...]
-    if rscaled:
-        rblk = rblk * scale_ref[...]
-
+    lhs, rhs = lhs_ref[...], rhs_ref[...]
     acc_ref[...] += jax.lax.dot_general(
-        lblk, rblk, (((0,), (0,)), ((), ())),
+        lhs, rhs, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
     @pl.when(last)
@@ -447,15 +317,11 @@ def _tgmm_kernel(*refs, nm, bm, bk, bn, lfused, rfused, rscaled):
 
 
 def tgmm(lhs, rhs, tile_groups, num_groups, *, bm=512, bn=None, bk=None,
-         interpret=None, lhs_rows=None, rhs_rows=None, rhs_scale=None):
+         interpret=None):
     """Transposed grouped matmul (the weight gradient):
     ``out[e] = sum over e's rows of lhs[m, :]^T @ rhs[m, :]``.
 
     lhs: [M, K]; rhs: [M, N]; both row-grouped as in gmm.
-    ``lhs_rows`` / ``rhs_rows``: optional fused row gathers (as ``rows``
-    in :func:`gmm`) — the named operand is then an un-permuted [L, dim]
-    buffer indexed per padded row; ``rhs_scale`` fuses a per-row
-    multiplier onto the gathered rhs rows (lhs^T @ diag(s) @ rhs[rows]).
     A group owning zero tiles gets an explicitly zeroed output block (the
     kernel only writes blocks it visits; the mask below covers truncated
     dispatch plans where a tail expert's span was cut).  Returns
@@ -464,97 +330,45 @@ def tgmm(lhs, rhs, tile_groups, num_groups, *, bm=512, bn=None, bk=None,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    M = lhs_rows.shape[0] if lhs_rows is not None else lhs.shape[0]
-    K = lhs.shape[1]
+    M, K = lhs.shape
     N = rhs.shape[1]
     if M % bm:
         raise ValueError(f"M ({M}) must be a multiple of bm ({bm})")
     mode = _mode(interpret)
     if mode is None:
-        return _tgmm_reference(lhs, rhs, tile_groups, num_groups, bm=bm,
-                               lhs_rows=lhs_rows, rhs_rows=rhs_rows,
-                               rhs_scale=rhs_scale)
+        return _tgmm_reference(lhs, rhs, tile_groups, num_groups, bm=bm)
     bn, bk = _resolve_tiles("tgmm", M, K, N, num_groups, bm, lhs.dtype,
                             bn, bk, mode)
     nm = M // bm
 
-    fuse = flags.flag("grouped_matmul_fused_gather")
-    if lhs_rows is not None and not fuse:
-        lhs, lhs_rows = jnp.take(lhs, lhs_rows, axis=0), None
-    if rhs_rows is not None and not fuse:
-        rhs = jnp.take(rhs, rhs_rows, axis=0)
-        if rhs_scale is not None:
-            rhs = rhs * rhs_scale[:, None].astype(rhs.dtype)
-        rhs_rows, rhs_scale = None, None
-    lfused = lhs_rows is not None
-    rfused = rhs_rows is not None
-    rscaled = rfused and rhs_scale is not None
-    if rhs_scale is not None and not rfused:
-        rhs = rhs * rhs_scale[:, None].astype(rhs.dtype)
-
-    scalars = [tile_groups.astype(jnp.int32)]
-    in_specs = []
-    operands = []
-    if lfused:
-        scalars.append(lhs_rows.astype(jnp.int32))
-    if rfused:
-        scalars.append(rhs_rows.astype(jnp.int32))
-    in_specs.append(
-        pl.BlockSpec(memory_space=pl.ANY) if lfused else
-        pl.BlockSpec((bm, bk), lambda k, j, i, g, *_: (i, k)))
-    operands.append(lhs)
-    in_specs.append(
-        pl.BlockSpec(memory_space=pl.ANY) if rfused else
-        pl.BlockSpec((bm, bn), lambda k, j, i, g, *_: (i, j)))
-    operands.append(rhs)
-    if rscaled:
-        in_specs.append(
-            pl.BlockSpec((bm, 1), lambda k, j, i, g, *_: (i, 0)))
-        operands.append(rhs_scale.reshape(M, 1).astype(rhs.dtype))
-
-    scratch = []
-    if lfused:
-        scratch.append(pltpu.VMEM((bm, bk), lhs.dtype))
-    if rfused:
-        scratch.append(pltpu.VMEM((bm, bn), rhs.dtype))
-    scratch.append(pltpu.VMEM((bk, bn), jnp.float32))
-    if lfused or rfused:
-        scratch.append(pltpu.SemaphoreType.DMA(()))
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
+        num_scalar_prefetch=1,
         grid=(K // bk, N // bn, nm),          # m innermost: consecutive
-        in_specs=in_specs,                    # visits per expert block
+        in_specs=[                            # visits per expert block
+            pl.BlockSpec((bm, bk), lambda k, j, i, g: (i, k)),
+            pl.BlockSpec((bm, bn), lambda k, j, i, g: (i, j))],
         out_specs=pl.BlockSpec((None, bk, bn),
-                               lambda k, j, i, g, *_: (g[i], k, j)),
-        scratch_shapes=scratch,
+                               lambda k, j, i, g: (g[i], k, j)),
+        scratch_shapes=[pltpu.VMEM((bk, bn), jnp.float32)],
     )
-    kernel = functools.partial(_tgmm_kernel, nm=nm, bm=bm, bk=bk, bn=bn,
-                               lfused=lfused, rfused=rfused,
-                               rscaled=rscaled)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_tgmm_kernel, nm=nm),
         name="tgmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_groups, K, N), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
-    )(*scalars, *operands)
+    )(tile_groups.astype(jnp.int32), lhs, rhs)
     visited = jnp.zeros((num_groups,), bool).at[tile_groups].set(True)
     return jnp.where(visited[:, None, None], out, 0)
 
 
 # ------------------------------------------------- XLA reference (CPU) ---
 
-def _gmm_reference(lhs, rhs, tile_groups, *, bm, trans_rhs=False, rows=None,
-                   row_scale=None):
+def _gmm_reference(lhs, rhs, tile_groups, *, bm, trans_rhs=False):
     """Oracle/CPU fallback: gather each row-tile's expert weights and run
     one batched matmul — M*K*N flops (no E-fold masking), fp32 accum."""
-    if rows is not None:
-        lhs = jnp.take(lhs, rows, axis=0)
-    if row_scale is not None:
-        lhs = lhs * row_scale[:, None].astype(lhs.dtype)
     M, C = lhs.shape
     T = M // bm
     w = jnp.take(rhs, tile_groups.astype(jnp.int32), axis=0)
@@ -565,14 +379,7 @@ def _gmm_reference(lhs, rhs, tile_groups, *, bm, trans_rhs=False, rows=None,
     return out.reshape(M, -1).astype(lhs.dtype)
 
 
-def _tgmm_reference(lhs, rhs, tile_groups, num_groups, *, bm, lhs_rows=None,
-                    rhs_rows=None, rhs_scale=None):
-    if lhs_rows is not None:
-        lhs = jnp.take(lhs, lhs_rows, axis=0)
-    if rhs_rows is not None:
-        rhs = jnp.take(rhs, rhs_rows, axis=0)
-    if rhs_scale is not None:
-        rhs = rhs * rhs_scale[:, None].astype(rhs.dtype)
+def _tgmm_reference(lhs, rhs, tile_groups, num_groups, *, bm):
     M = lhs.shape[0]
     T = M // bm
     per_tile = jnp.einsum("tbk,tbn->tkn", lhs.reshape(T, bm, -1),

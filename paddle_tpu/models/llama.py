@@ -63,8 +63,7 @@ class LlamaConfig:
     # GEMM + one psum, capacity-bounded per shard).  "gather": int32
     # scatter + row gather (global capacity) and "einsum": GShard/t5x
     # one-hot matmul dispatch (per-group capacity) are kept as reference
-    # oracles for parity tests and A/B baselines.  The bench measures all
-    # three; see benchmarks/README.md for the dispatch-mode matrix.
+    # oracles for parity tests (tests/test_moe_dispatch_parity.py).
     moe_dispatch: str = "grouped"
     moe_groups: int = 0          # einsum only: token groups (0 -> batch dim)
     moe_block_m: int = 512       # grouped only: row-tile (group alignment)
@@ -448,10 +447,7 @@ def _grouped_ffn(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     ``sorted_dispatch_plan``.  Dispatch and combine are GATHERS and the
     hand-written VJP keeps them gathers in reverse (the AD transpose of a
     gather is a scatter-add, which TPU serializes row-by-row — the
-    whole point of carrying both maps is never to emit one).  The
-    dispatch gathers ride INSIDE the grouped-matmul kernels (scalar-
-    prefetched row indices, kernels/grouped_matmul.py) so no ``[M, H]``
-    permuted activation copy ever lands in HBM, forward or backward.
+    whole point of carrying both maps is never to emit one).
 
     ``pos`` entries >= M (the padded-buffer row count) are a DROPPED-
     entry sentinel: combine and the dx gather go through a zero-extended
@@ -462,6 +458,16 @@ def _grouped_ffn(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     y, _ = _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat,
                             pos, tile_groups, E, k, bm)
     return y
+
+
+def _padded_rows(buf, tok_of, scale=None):
+    """``buf``'s rows laid out as the dispatch plan's padded buffer
+    (``[M, ...]``: row ``p`` is ``buf[tok_of[p]]``), each times its
+    ``scale[p]`` where one is given — the operand ``gmm``/``tgmm`` take."""
+    out = jnp.take(buf, tok_of, axis=0)
+    if scale is not None:
+        out = out * scale[:, None].astype(out.dtype)
+    return out
 
 
 def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
@@ -479,8 +485,8 @@ def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     xz = jnp.concatenate([xf, jnp.zeros((1, H), xf.dtype)], axis=0)
     tok_of = jnp.where(inv_flat < N * k, inv_flat // k, N)
     lt = {} if live_tiles is None else {"live_tiles": live_tiles}
-    h_g = gmm(xz, w_gate, tile_groups, bm=bm, rows=tok_of, **lt)  # fused gather
-    h_u = gmm(xz, w_up, tile_groups, bm=bm, rows=tok_of, **lt)
+    h_g = gmm(_padded_rows(xz, tok_of), w_gate, tile_groups, bm=bm, **lt)
+    h_u = gmm(_padded_rows(xz, tok_of), w_up, tile_groups, bm=bm, **lt)
     a = jax.nn.silu(h_g) * h_u
     o = gmm(a, w_down, tile_groups, bm=bm, **lt)          # [M, H]
     # combine gather: sentinel pos >= M (dropped entries) reads zero
@@ -508,22 +514,21 @@ def _grouped_ffn_bwd(E, k, bm, res, dy):
     d_gates = (o_pos.astype(jnp.float32)
                * dy[:, None, :].astype(jnp.float32)).sum(-1)  # [N, k]
 
-    # d(combine): do[p] = gate(p) * dy[token(p)] — both gathers, fused
-    # into the kernels below as (rows, row_scale) so do never materializes
+    # d(combine): do[p] = gate(p) * dy[token(p)] — both gathers
     gate_pad = take_sentinel_rows(
         gates.reshape(N * k).astype(dy.dtype), inv_flat)        # [M]
     dy_z = jnp.concatenate([dy, jnp.zeros((1, H), dy.dtype)], axis=0)
 
-    da = gmm(dy_z, w_down, tile_groups, bm=bm, trans_rhs=True,
-             rows=tok_of, row_scale=gate_pad)                 # [M, I]
+    da = gmm(_padded_rows(dy_z, tok_of, gate_pad), w_down, tile_groups,
+             bm=bm, trans_rhs=True)                           # [M, I]
     sig = jax.nn.sigmoid(h_g.astype(jnp.float32)).astype(h_g.dtype)
     dsilu = sig + h_g * sig * (1 - sig)
     dh_g = da * h_u * dsilu
     dh_u = da * sg
-    dw_d = tgmm(a, dy_z, tile_groups, E, bm=bm, rhs_rows=tok_of,
-                rhs_scale=gate_pad)
-    dw_g = tgmm(xz, dh_g, tile_groups, E, bm=bm, lhs_rows=tok_of)
-    dw_u = tgmm(xz, dh_u, tile_groups, E, bm=bm, lhs_rows=tok_of)
+    dw_d = tgmm(a, _padded_rows(dy_z, tok_of, gate_pad), tile_groups, E,
+                bm=bm)
+    dw_g = tgmm(_padded_rows(xz, tok_of), dh_g, tile_groups, E, bm=bm)
+    dw_u = tgmm(_padded_rows(xz, tok_of), dh_u, tile_groups, E, bm=bm)
     dx_pad = gmm(dh_g, w_gate, tile_groups, bm=bm, trans_rhs=True) + \
         gmm(dh_u, w_up, tile_groups, bm=bm, trans_rhs=True)   # [M, H]
     # d(dispatch): token t accumulates its k buffer rows — a gather;

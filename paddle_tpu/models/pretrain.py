@@ -506,7 +506,7 @@ class PretrainStep:
             # the functional call — same trace, so it composes with scan.
             # stats (keep.mean/ce.max + a carried [2] vector) only when
             # asked: the hot training scan keeps the 2-tuple carry and no
-            # extra reductions inside the remat'd block (ADVICE r4)
+            # extra reductions inside the remat'd block
             def block_aux(lp, x):
                 y = block(lp, x)
                 aux = template.mlp._last_aux

@@ -323,7 +323,7 @@ def classify():
 
 # --- oracle resolution, shared with tests/test_schema_oracle.py ---------
 # The sweep imports these so the report's "oracle-verified" count and the
-# test's actual skip behavior can never drift apart (ADVICE r4: counting
+# test's actual skip behavior can never drift apart (counting
 # by name presence overstated verified coverage).
 
 # ops the sweep skips: numerics checked elsewhere / oracle semantics differ

@@ -128,25 +128,6 @@ def test_incubate_set_config_drives_flag(tmp_path):
     assert iat.get_config()["kernel"]["enable"] is True
 
 
-def test_record_and_generator_page_auto():
-    """Explicitly recorded sweep winners drive
-    LlamaGenerator(page_size='auto') (the bench's decode page sweep)."""
-    import paddle_tpu as P
-    from paddle_tpu.inference import LlamaGenerator
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-
-    P.seed(0)
-    cfg = LlamaConfig.tiny()
-    m = LlamaForCausalLM(cfg)
-    g = LlamaGenerator(m, max_batch=2, max_seq_len=64, page_size="auto")
-    assert g.page_size == 32    # cold cache: default
-    key = autotune.make_key("paged_decode", heads=cfg.num_key_value_heads,
-                            d=cfg.head_dim, dt=str(cfg.dtype))
-    autotune.record(key, [16], {"16": 1.0, "32": 2.0})
-    g2 = LlamaGenerator(m, max_batch=2, max_seq_len=64, page_size="auto")
-    assert g2.page_size == 16
-
-
 def test_flash_attention_consults_cache(monkeypatch):
     """A pre-seeded cache entry must drive the kernel's block choice on the
     TPU path (exercised via the interpret-mode kernel on CPU)."""

@@ -3,8 +3,8 @@
 The TPU's compiler is installed in the sandbox and compiles for a chip that
 is described, not attached (on-chip-measurement guide, section 2, step 3):
 it refuses what interpret mode and ``jax.export`` (tests/
-test_mosaic_lowering.py) both let through — the fused-gather ``gmm`` arm
-below passes export and is refused here.  Nothing runs, so these say
+test_mosaic_lowering.py) both let through (a one-row slice of a tiled HBM
+operand, an int8 scale plane larger than SMEM).  Nothing runs, so these say
 nothing about results or times; ``chip_smoke.py`` is the chip run.
 
 This is the only file that describes the chip.  The topology is described
@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from paddle_tpu import flags
 from paddle_tpu.kernels import flash_attention as fa
 from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import paged_attention as pa
@@ -171,24 +170,6 @@ def test_tgmm_compiles(one_chip):
              lambda l, r, t: gm.tgmm(l, r, t, E, bm=BM, interpret=False),
              ((M, HIDDEN), BF16), ((M, INTER), BF16),
              ((M // BM,), jnp.int32))
-
-
-def test_fused_gather_gmm_is_refused_with_the_compilers_reason(one_chip):
-    """PR 21 settled this by making the materialized operand the default:
-    the fused arm stays behind FLAGS_grouped_matmul_fused_gather, and
-    choosing it for a chip fails when the program is built, with Mosaic's
-    own reason (a one-row slice of a tiled HBM operand)."""
-    assert flags.flag("grouped_matmul_fused_gather") is False
-    flags.set_flags({"grouped_matmul_fused_gather": True})
-    try:
-        with pytest.raises(Exception, match="aligned to tiling"):
-            _compile(one_chip,
-                     lambda l, r, t, rows: gm.gmm(l, r, t, bm=BM,
-                                                  interpret=False, rows=rows),
-                     ((4096, HIDDEN), BF16), ((E, HIDDEN, INTER), BF16),
-                     ((M // BM,), jnp.int32), ((M,), jnp.int32))
-    finally:
-        flags.set_flags({"grouped_matmul_fused_gather": False})
 
 
 # ---- command-a-plus-05-2026 as one chip of eight holds it (PR 27) ----

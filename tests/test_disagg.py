@@ -309,6 +309,42 @@ def test_handoff_end_to_end_bit_identical_stream(model, oracle):
         fleet.close()
 
 
+def test_warm_handoffs_compile_nothing_and_serve_the_mixed_tokens(model):
+    """Once two streams have gone through prefill replica, KV handoff and
+    decode replica (the second still compiles the importer's
+    ``pool_swap_in`` once), a third with another prompt compiles nothing
+    on either side, re-prefills no full page and reads as the same prompt
+    served by one mixed engine."""
+    obs.reset("router.")
+    obs.reset("serving.kv.handoff")
+    second = [p + 20 for p in PROMPT]
+    eng = _engine(model, gen=GenerationConfig(max_new_tokens=24))
+    rid = eng.add_request(list(second))
+    mixed = eng.run()[rid]
+    fleet = RoleFleet(model, ["prefill", "decode"])
+    try:
+        async def main():
+            await fleet.router.poll_replicas()
+            for warm_prompt in (PROMPT, [p + 40 for p in PROMPT]):
+                warm = await do(fleet.router, "POST", "/v1/completions",
+                                completion_body(warm_prompt, 24, stream=True))
+            with obs.assert_overhead(record=True) as rec:
+                resp = await do(fleet.router, "POST", "/v1/completions",
+                                completion_body(second, 24, stream=True))
+            return warm, resp, rec.compiles
+
+        (wstatus, _, _), (status, _, body), compiles = asyncio.run(main())
+        assert (wstatus, status) == (200, 200)
+        assert compiles == 0
+        assert _stream_tokens(body)[0] == mixed
+        assert int(obs.metrics.counter("router.handoff",
+                                       outcome="ok").value) == 3
+        assert int(obs.metrics.counter(
+            "serving.kv.handoff_reprefill_tokens").value) == 0
+    finally:
+        fleet.close()
+
+
 def test_handoff_pins_session_to_decode_target(model, oracle):
     """After a handoff the session's KV lives on the decode replica:
     the pin moves there, and the NEXT turn of the same session bypasses

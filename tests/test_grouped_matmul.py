@@ -83,70 +83,10 @@ class TestKernels:
         np.testing.assert_array_equal(np.asarray(out[1]), 0.0)
 
 
-class TestFusedGather:
-    """The in-kernel dispatch permutation: gmm/tgmm with scalar-prefetched
-    row indices (+ optional per-row scale) must match materialize-then-
-    multiply, in interpret mode (same code path Mosaic compiles)."""
-
-    M, K, N, E, bm, L = 32, 128, 256, 3, 8, 21
-    tg = jnp.asarray([0, 0, 1, 2], jnp.int32)
-
-    @pytest.fixture(autouse=True)
-    def _fused_arm(self):
-        # the fused arm is opt-in (the materialized operand is the default)
-        flags.set_flags({"FLAGS_grouped_matmul_fused_gather": True})
-        yield
-        flags.set_flags({"FLAGS_grouped_matmul_fused_gather": False})
-
-    def _rows(self):
-        rng = np.random.default_rng(5)
-        return jnp.asarray(rng.integers(0, self.L, self.M), jnp.int32)
-
-    def test_gmm_rows_matches_materialized(self, interp):
-        lhs = _rand((self.L, self.K))
-        rhs = _rand((self.E, self.K, self.N), seed=1)
-        rows = self._rows()
-        out = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
-        ref = G.gmm(jnp.take(lhs, rows, axis=0), rhs, self.tg, bm=self.bm)
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-
-    def test_gmm_rows_scale_trans(self, interp):
-        lhs = _rand((self.L, self.N))          # trans: contract over N
-        rhs = _rand((self.E, self.K, self.N), seed=1)
-        rows = self._rows()
-        scale = _rand((self.M,), seed=6)
-        out = G.gmm(lhs, rhs, self.tg, bm=self.bm, trans_rhs=True,
-                    rows=rows, row_scale=scale)
-        ref = G.gmm(jnp.take(lhs, rows, axis=0) * scale[:, None], rhs,
-                    self.tg, bm=self.bm, trans_rhs=True)
-        np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-5)
-
-    def test_tgmm_fused_rows_and_scale(self, interp):
-        lhs = _rand((self.L, self.K))
-        rhs = _rand((self.L, self.N), seed=1)
-        lrows, rrows = self._rows(), self._rows()
-        scale = _rand((self.M,), seed=7)
-        out = G.tgmm(lhs, rhs, self.tg, self.E, bm=self.bm,
-                     lhs_rows=lrows, rhs_rows=rrows, rhs_scale=scale)
-        ref = G.tgmm(jnp.take(lhs, lrows, axis=0),
-                     jnp.take(rhs, rrows, axis=0) * scale[:, None],
-                     self.tg, self.E, bm=self.bm)
-        np.testing.assert_allclose(out, ref, rtol=1e-4, atol=1e-4)
-
-    def test_fused_gather_flag_off_parity(self, interp):
-        lhs = _rand((self.L, self.K))
-        rhs = _rand((self.E, self.K, self.N), seed=1)
-        rows = self._rows()
-        fused = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
-        flags.set_flags({"FLAGS_grouped_matmul_fused_gather": False})
-        unfused = G.gmm(lhs, rhs, self.tg, bm=self.bm, rows=rows)
-        np.testing.assert_allclose(fused, unfused, rtol=1e-5, atol=1e-5)
-
-
 class TestTileSelection:
     """Explicit bn/bk > autotune cache > sweep flags > 512 default; flag
     values that cannot tile the backward shapes fail fast at forward
-    time with the flag named (ADVICE r5 low)."""
+    time with the flag named."""
 
     @pytest.fixture(autouse=True)
     def _isolated_autotune(self, tmp_path):
@@ -331,6 +271,70 @@ class TestMoEGrouped:
             losses.append(float(loss))
         assert np.isfinite(losses).all()
         assert losses[-1] < losses[0]
+
+
+def _grouped_under_capacity(x, gw, wg, wu, wd, *, k, cf, bm):
+    """``_grouped_ffn`` over the entries a capacity keeps (the k-major
+    priority of the gather formulation).  Every token stands ``k`` times in
+    the padded layout; a dropped entry goes to a trailing discard group:
+    its padded row reads the zero row, its ``pos`` is the sentinel ``M``
+    and its gate is zero, as the sharded path lays them out."""
+    B, S, H = x.shape
+    N, E = B * S, gw.shape[-1]
+    xf = x.reshape(N, H)
+    topv, topi, _, _ = L._route_topk(xf, gw, k)
+    cap = max(1, int(N * k * cf / E))
+    keep = G.capacity_dispatch_plan(topi, topv, E, cap)[3].reshape(k, N).T
+    kept = keep.reshape(N * k)
+    inv, pos, tg = G.sorted_dispatch_plan(
+        jnp.where(keep, topi, E).reshape(N * k), E + 1, bm)
+    inv = jnp.where((inv < N * k)
+                    & jnp.take(kept, jnp.minimum(inv, N * k - 1)),
+                    inv, N * k)
+    pos = jnp.where(kept, pos, inv.shape[0])
+    y = L._grouped_ffn(xf, wg, wu, wd, topv * keep, inv, pos,
+                       jnp.minimum(tg, E - 1), E, k, bm)
+    return y.reshape(B, S, H), keep
+
+
+@pytest.mark.parametrize("what", ["value", "gradient"])
+@pytest.mark.parametrize("cf", [8.0, 0.5], ids=["dropless", "drops"])
+@pytest.mark.parametrize("kernel", ["xla", "interpret"])
+def test_grouped_ffn_takes_and_scales_its_rows_as_the_gather_reference(
+        request, kernel, cf, what):
+    """The dispatch's take (each token ``k`` times, dropped entries as zero
+    rows) and the backward's gate scaling live in ``_grouped_ffn_fwd`` /
+    ``_grouped_ffn_bwd``, around kernels that multiply what they are given:
+    value and every gradient agree with the capacity-gather formulation
+    under the same router, with top-2 gates that are not uniform."""
+    B, S, H, I, E, k, bm = 2, 8, 128, 256, 4, 2, 8
+    x = _rand((B, S, H))
+    weights = (_rand((H, E), 0.3, 1), _rand((E, H, I), 0.05, 2),
+               _rand((E, H, I), 0.05, 3), _rand((E, I, H), 0.05, 4))
+    dy = _rand((B, S, H), seed=5)
+
+    def grouped(x_, *ws):
+        return _grouped_under_capacity(x_, *ws, k=k, cf=cf, bm=bm)[0]
+
+    def gather(x_, *ws):
+        return L.moe_mlp_forward(x_, *ws, top_k=k, capacity_factor=cf)[0]
+
+    keep = np.asarray(_grouped_under_capacity(x, *weights, k=k, cf=cf,
+                                              bm=bm)[1])
+    assert keep.all() == (cf == 8.0)       # "drops" really drops entries
+    gates = np.asarray(L._route_topk(x.reshape(-1, H), weights[0], k)[0])
+    assert np.abs(gates[:, 0] - gates[:, 1]).min() > 1e-3
+    if kernel == "interpret":
+        request.getfixturevalue("interp")
+    if what == "value":
+        np.testing.assert_allclose(grouped(x, *weights), gather(x, *weights),
+                                   rtol=1e-4, atol=1e-5)
+        return
+    loss = lambda f: lambda *a: (f(*a) * dy).sum()
+    got = jax.grad(loss(grouped), tuple(range(5)))(x, *weights)
+    want = jax.grad(loss(gather), tuple(range(5)))(x, *weights)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-5)
 
 
 class TestMoEGroupedSharded:

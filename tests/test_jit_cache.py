@@ -101,7 +101,7 @@ class TestBucketing:
             np.testing.assert_allclose(ob.numpy(), 2.0)
 
     def test_bucket_sized_output_axis_not_truncated(self):
-        # ADVICE r5 medium: an output axis that LEGITIMATELY has the
+        # an output axis that LEGITIMATELY has the
         # bucket's size at a padded axis position (here: a fixed [128, 8]
         # projection output while the input's axis 0 pads 100 -> 128) must
         # not be cut down to the batch's true length

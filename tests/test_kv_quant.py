@@ -248,19 +248,42 @@ def _run_engine(model, prompts, *, cache_dtype=None, prefix_cache=False,
     return [out[r] for r in rids], eng
 
 
-def test_engine_int8_parity_and_bit_stability():
+# How far an int8-served token's logit may lie below the float model's best
+# (the model's own whole-sequence forward over prompt + served tokens, the
+# measure of chipbench's ``served_logit_gap_*``).  Readings, these prompts,
+# model seeds 0-7 (CPU, float32): largest gap 8.702e-05 (seed 0) and
+# 2.234e-04 (seed 7), where the float model's first and second choice lie
+# 1e-4 and 2e-4 apart and int8 takes the other; 0.0 on the six other seeds,
+# whose smallest first-to-second margin is 0.0049 (median 0.19-0.37).  The
+# limit is 9 x the largest reading and under every margin int8 left alone.
+INT8_LOGIT_GAP_LIMIT = 2e-3
+
+
+@pytest.mark.parametrize("seed", [0, 7])
+def test_engine_int8_parity_and_bit_stability(seed):
     """The tolerance contract (MIGRATION.md "KV dtype & spill tier"):
-    greedy int8 outputs are bit-stable run-to-run, and on this fixture —
-    whose argmax logit gaps exceed the int8 absmax quantization noise —
-    they equal the cache-fp32 arm exactly."""
-    model = _tiny_model()
+    greedy int8 outputs are bit-stable run-to-run, and every token they
+    hold is, by the float model's logits, its best choice or within
+    ``INT8_LOGIT_GAP_LIMIT`` of it.  Not token equality with the float
+    arm: on both seeds here int8 takes the second of two near-tied
+    choices and the texts part from there."""
+    paddle.seed(seed)
+    model = LlamaForCausalLM(LlamaConfig.tiny())
     prompts = [list(range(1, 20)), [5, 6, 7, 8, 9, 10, 11],
                [9, 9, 9, 1, 2]]
     fp, eng_fp = _run_engine(model, prompts, cache_dtype=None)
     q1, eng_q = _run_engine(model, prompts, cache_dtype="int8")
     q2, _ = _run_engine(model, prompts, cache_dtype="int8")
     assert q1 == q2                       # bit-stable run-to-run
-    assert q1 == fp                       # within tolerance (exact here)
+    # the float arm holds the measure to account: the engine's own tokens
+    # are the forward's best choices (0.0 on every seed read)
+    for served, limit in ((fp, 1e-5), (q1, INT8_LOGIT_GAP_LIMIT)):
+        for prompt, toks in zip(prompts, served):
+            ids = np.asarray([prompt + toks], np.int32)
+            logits = np.asarray(model(paddle.to_tensor(ids))._data)[0]
+            rows = logits[len(prompt) - 1:len(prompt) - 1 + len(toks)]
+            gap = rows.max(-1) - rows[np.arange(len(toks)), toks]
+            assert gap.max() <= limit, (gap, limit)
     assert eng_q.stats()["kv_cache_dtype"] == "int8"
     assert eng_fp.stats()["kv_cache_dtype"] != "int8"
 
@@ -330,6 +353,40 @@ def test_bytes_per_page_accounting():
     assert fp == 2 * 2 * 2 * 8 * 16 * 4
     assert q == 2 * 2 * 2 * (8 * 16 + 4)
     assert fp / q > 3.5                   # ~4x capacity at equal bytes
+
+
+def test_engine_int8_holds_more_sessions_in_the_same_pool_bytes():
+    """At equal pool bytes the int8 plane has >= 1.8 x the float pool's
+    pages, and the engine keeps that many more sessions resident at once
+    (a count of sequences the allocator holds, from the same traffic)."""
+    model = _tiny_model()
+    c = model.config
+    bpp = {d: PagedKVCache.bytes_per_page(
+        c.num_hidden_layers, c.num_key_value_heads, 8, c.head_dim, d)
+        for d in ("float32", "int8")}
+    pool_bytes = 6 * bpp["float32"]
+    pages = {d: pool_bytes // bpp[d] for d in bpp}
+    assert pages["int8"] >= 1.8 * pages["float32"]
+    prompts = [list(range(1 + i, 17 + i)) for i in range(8)]   # 2 pages + 1
+
+    def resident_high_water(dtype):
+        eng = ContinuousBatchingEngine(
+            model, max_batch=8, max_seq_len=64, page_size=8,
+            prefill_bucket=8, num_pages=int(pages[dtype]), cache_dtype=dtype,
+            gen=GenerationConfig(max_new_tokens=4, do_sample=False))
+        assert eng.g.pool_bytes <= pool_bytes
+        for p in prompts:
+            eng.add_request(p)
+        high = 0
+        while eng.has_work():
+            eng.step()
+            high = max(high, eng.g.cache.allocator.stats()["active_seqs"])
+        # every request is answered; one the pool cannot grow ends early
+        assert len(eng.run()) == len(prompts)
+        return high
+
+    fp, q = resident_high_water("float32"), resident_high_water("int8")
+    assert q >= 1.8 * fp, (fp, q)
 
 
 # ---------------------------------------------------------------------------

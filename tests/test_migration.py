@@ -632,11 +632,15 @@ def test_journal_bounds_cap_memory():
 # fleet: drain-triggered migration + chaos (layer 4)
 # ---------------------------------------------------------------------------
 
+STREAM_TOKENS = 112          # 5 prompt tokens + 112 of max_seq_len 128
+
+
 def _fleet(model, chaos=None, **sup_kw):
     from paddle_tpu.fleet import FleetSupervisor, InprocReplicaHandle
 
     def factory():
-        eng = _engine(model, gen=GenerationConfig(max_new_tokens=32))
+        eng = _engine(model,
+                      gen=GenerationConfig(max_new_tokens=STREAM_TOKENS))
         eng.add_request(list(range(1, 13)), max_new_tokens=4)
         eng.run()                          # warm both step programs
         return eng
@@ -670,10 +674,17 @@ async def _converge(sup, router, deadline_s=240.0):
 
 
 async def _stream_on_each(sup, router, chaos_clients=None):
-    """One in-flight stream per replica; returns the gathered tasks."""
+    """One in-flight stream per replica; returns the gathered tasks.
+
+    The streams run to ``STREAM_TOKENS``, most of what ``max_seq_len``
+    leaves: the caller drains a victim right after this returns, and
+    both streams must still be in flight then.  At 32 tokens that was two
+    drain periods of the engines' threads after the wait's condition;
+    beside five other test workers one replica's stream ended before the
+    other's had sent 12, and the wait ran out ("streams never started")."""
     tasks = [asyncio.ensure_future(_do(
         router, "POST", "/v1/completions",
-        completion_body([10 + i, 3, 5, 7, 11], 32, stream=True),
+        completion_body([10 + i, 3, 5, 7, 11], STREAM_TOKENS, stream=True),
         headers=(("X-Session-Id", f"sess{i}"),))) for i in range(2)]
     deadline = time.perf_counter() + 60
     while True:

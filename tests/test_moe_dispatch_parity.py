@@ -1,16 +1,16 @@
 """MoE dispatch-mode parity smoke + overflow-regime gradient regression.
 
-The dispatch-mode matrix (benchmarks/README.md): gather / einsum /
-grouped are three formulations of the same routed mixture.  This file is
-the tier-1 guard for that equivalence:
+gather / einsum / grouped (``LlamaConfig.moe_dispatch``) are three
+formulations of the same routed mixture.  This file is the tier-1 guard
+for that equivalence:
 
 - the fast smoke: all three modes, tiny E/H, forward AND backward
   allclose against the einsum oracle at no-drop capacity — catches any
   future dispatch regression without the slow mesh tests;
 - the overflow regime (kept_frac < 1): finite-difference gradient parity
   and EXACTLY-zero FFN gradient for dropped tokens, for gather, einsum,
-  grouped and grouped_sharded.  This is the regression test for the
-  ADVICE r5 high finding: the sharded grouped path used to clamp dropped
+  grouped and grouped_sharded.  This is the regression test for a
+  PR 1 finding: the sharded grouped path used to clamp dropped
   entries' buffer positions to a real row, silently accumulating a kept
   row's gradient into unrelated tokens under capacity overflow.
 """
@@ -254,7 +254,7 @@ class TestOverflowRegimeGradients:
         _fd_check(loss_x, x, jax.jit(jax.grad(loss_x))(x), [0, 5, 63, 200])
 
     def test_grouped_sharded_overflow(self):
-        """THE ADVICE r5 high regression: dp2 x ep2 x mp2 mesh, cf=0.25
+        """The overflow regression on a dp2 x ep2 x mp2 mesh, cf=0.25
         (kept_frac < 1) — dropped (token, choice) entries must route to
         the zero sentinel row, giving dropped tokens exactly-zero dx and
         finite-difference-correct gradients everywhere else."""

@@ -361,10 +361,10 @@ class TestWeightOnlyMosaic:
 
 
 class TestEndToEndMosaic:
-    """Cross-lower the bench ladder's compiled steps at flagship geometry
-    (2 layers — per-layer kernel shapes identical to bench.py's configs),
-    so a chip-only lowering failure can't silently kill the round's perf
-    number again."""
+    """Cross-lower the compiled train and decode steps at flagship
+    geometry (2 layers, per-layer kernel shapes of a 1-2B Llama), so a
+    lowering failure that only the TPU target shows is caught on the
+    CPU."""
 
     def _llama_step(self, **extra):
         from paddle_tpu.models.llama import LlamaConfig

@@ -462,6 +462,22 @@ def test_engine_latency_is_stamped_when_tokens_reach_the_host(monkeypatch):
     assert itl.max < block_s * 1e3
 
 
+@pytest.mark.parametrize("engine_kw", [{}, {"prefix_cache": True}],
+                         ids=["plain", "prefix_cache"])
+def test_engine_serves_the_same_tokens_with_metrics_on_and_off(engine_kw):
+    """Telemetry observes the engine and decides nothing: the same
+    requests give the same tokens with the registry on and off."""
+    prompts = ([1, 2, 3, 4, 5, 6, 7, 8, 9], [1, 2, 3, 4, 5, 6, 7, 8, 4],
+               [6, 7, 8, 9], [4, 5])
+    outs = []
+    for on in (False, True):
+        eng = _tiny_engine(metrics=on, **engine_kw)
+        rids = [eng.add_request(list(p)) for p in prompts]
+        done = eng.run()
+        outs.append([done[r] for r in rids])
+    assert outs[0] == outs[1] and all(len(o) == 6 for o in outs[0])
+
+
 def test_engine_metrics_off_records_nothing():
     obs.reset("serving.")
     eng = _tiny_engine(metrics=False)

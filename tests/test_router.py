@@ -205,11 +205,13 @@ def test_placement_round_robin_rotates():
 # end-to-end: bit identity through the router
 # ---------------------------------------------------------------------------
 
-def test_router_stream_bit_identical(model, oracle):
+@pytest.mark.parametrize("policy", ["scored", "round_robin"])
+def test_router_stream_bit_identical(model, oracle, policy):
     """Streamed and unary outputs through the router bit-match the
-    direct single-engine oracle; the response carries the router trace
-    id on every chunk AND which replica served it."""
-    fleet = Fleet(model, n=2)
+    direct single-engine oracle, whichever placement policy routed them;
+    the response carries the router trace id on every chunk AND which
+    replica served it."""
+    fleet = Fleet(model, n=2, policy=policy)
     try:
         async def main():
             outs = await asyncio.gather(

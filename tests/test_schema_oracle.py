@@ -36,7 +36,7 @@ _DOMAIN = {
 
 # skip set + oracle resolution live in ops.coverage so the
 # OPS_COVERAGE.md "oracle-verified" count is derived from the exact same
-# logic this sweep runs (ADVICE r4)
+# logic this sweep runs
 from paddle_tpu.ops.coverage import ORACLE_SKIP as _SKIP
 from paddle_tpu.ops.coverage import resolve_oracle as _oracle
 

@@ -75,6 +75,22 @@ def test_tp4_greedy_bit_matches_tp1(model4):
     assert got == base and eng.g.tp == 4
 
 
+@pytest.mark.parametrize("tp", [2, 4])
+def test_tp_pool_holds_the_bytes_of_tp1_a_share_on_each_shard(model4, tp):
+    """Page ids and block tables are host-global: at the same ``num_pages``
+    a tensor-parallel engine's pool is the single-device engine's bytes,
+    split by KV head, ``1/tp`` of them on each device of the mesh."""
+    one = _engine(model4, tp=1, num_pages=24)
+    eng = _engine(model4, tp=tp, num_pages=24)
+    assert eng.stats()["pool_bytes"] == one.stats()["pool_bytes"] \
+        == one.g.pool_bytes > 0
+    for whole, pool in zip(one.g.cache.arrays, eng.g.cache.arrays):
+        assert pool.shape == whole.shape and pool.dtype == whole.dtype
+        shards = pool.addressable_shards
+        assert len(shards) == tp
+        assert all(s.data.nbytes * tp == whole.nbytes for s in shards)
+
+
 def test_sampled_seed_determinism_parity_matrix(model4):
     """Same seed → byte-identical sampled streams at every tp degree;
     a different seed still diverges (sampling is real, not degenerate)."""

@@ -76,20 +76,27 @@ def test_wide_resnet(rng):
     _check(M.wide_resnet101_2(num_classes=10)(_img(rng, 32)))
 
 
-def test_vision_model_trains(rng):
-    """One SGD step on the smallest new family: loss finite, params move."""
-    m = M.mobilenet_v2(scale=0.25, num_classes=4)
+@pytest.mark.parametrize("family,kw", [("mobilenet_v2", {"scale": 0.25}),
+                                       ("resnet18", {})])
+def test_vision_model_trains(rng, family, kw):
+    """A few SGD steps on one batch: every loss finite, the last under the
+    first, parameters moved."""
+    paddle.seed(0)
+    m = getattr(M, family)(num_classes=4, **kw)
     opt = paddle.optimizer.SGD(learning_rate=0.05, parameters=m.parameters())
     x = _img(rng, 32, batch=2)
     y = T(np.asarray([0, 3], "int64"))
-    before = np.asarray(m.features[0][0].weight._data).copy()
-    loss = paddle.nn.CrossEntropyLoss()(m(x), y)
-    loss.backward()
-    opt.step()
-    opt.clear_grad()
-    assert np.isfinite(float(loss._data))
-    after = np.asarray(m.features[0][0].weight._data)
-    assert not np.allclose(before, after)
+    first = next(p for p in m.parameters() if p.trainable)
+    before = np.asarray(first._data).copy()
+    losses = []
+    for _ in range(4):
+        loss = paddle.nn.CrossEntropyLoss()(m(x), y)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        losses.append(float(loss._data))
+    assert np.isfinite(losses).all() and losses[-1] < losses[0], losses
+    assert not np.allclose(before, np.asarray(first._data))
 
 
 # ---------------- widened transforms ----------------
