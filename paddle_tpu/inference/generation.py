@@ -17,8 +17,10 @@ Structure — ONE jitted step function serves every serving phase:
   over whole periods of the model's layer pattern (``models/
   decoder_spec.py``; a stack of identical layers is the period of one),
   over one stack of weights for each place in the period; the kernel
-  reads the pool by layer number, and each layer's new K/V row is
-  emitted as a scan output.
+  reads the pool by layer number (and the grouped GEMMs each layer's own
+  expert banks, which a model hands out unstacked and the scan's body
+  picks by the period's number), and each layer's new K/V row is emitted
+  as a scan output.
 - The step is compiled per (sampling config, T) where T is the query-token
   bucket: T=1 is pure decode, T=prefill_bucket is a chunked-prefill /
   mixed step.  Both compile once; **warm steps never recompile** (asserted
@@ -45,6 +47,7 @@ three programs per sampling config.
 
 from __future__ import annotations
 
+import functools
 import math
 import time
 from collections import Counter, deque
@@ -65,6 +68,7 @@ from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        write_kv_pages_all_layers,
                                        write_kv_pages_all_layers_quantized)
 from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
+from ..models.decoder_spec import EXPERT_BANKS
 from ..models.llama import _rope_cos_sin
 from . import speculative as _sp
 from .kv_cache import PagedKVCache
@@ -162,7 +166,7 @@ def _rope_bt(x, cos, sin):
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
-def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
+def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
     """Routed SwiGLU expert mixture for the serving path (reference:
     incubate fused_moe inference semantics), one function for every
     family: ``moe`` (``models.decoder_spec.MoeSpec``) states the router
@@ -190,6 +194,13 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
     ``live`` (bool, one a row of ``y``): rows that hold no token (the
     padding of a step's row bucket) are routed nowhere and counted nowhere.
 
+    ``layer`` (an int32 device scalar; the engine's layer scan): ``lp``'s
+    three expert banks are then tuples, one ``[E, ...]`` bank for each
+    layer of the place, and the layer is number ``layer`` of them.  The
+    experts run under a ``lax.switch`` over the layers, each branch on its
+    own layer's arrays: a whole array is read where it lies, while a layer
+    indexed out of a stack would be written out first for the kernel.
+
     ``mp_shards`` > 1 (tensor-parallel serving, inside a shard_map body):
     each shard runs the grouped path over its own E/mp expert bank —
     non-owned (token, choice) entries route to a local discard group
@@ -208,6 +219,15 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
     N = xf.shape[0]
     top_k, E, held = moe.top_k, moe.num_experts, moe.held
     rows = None
+    banks = tuple(lp[name] for name in EXPERT_BANKS)
+
+    def on_banks(fn):
+        """``fn(w_gate, w_up, w_down)`` on this layer's banks."""
+        if layer is None:
+            return fn(*banks)
+        return jax.lax.switch(layer, [functools.partial(fn, *ws)
+                                      for ws in zip(*banks)])
+
     with jax.named_scope("router"):
         topv, topi, _, _ = _llama._route_topk(xf, gw, top_k, moe.score)
     if moe.partial:
@@ -246,11 +266,9 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
                     w, my * E_loc, E_loc, axis=0)
 
             with jax.named_scope("experts"):
-                part = _llama._grouped_ffn(
-                    xf, _loc(lp["mlp.experts_gate"]),
-                    _loc(lp["mlp.experts_up"]),
-                    _loc(lp["mlp.experts_down"]),
-                    gates, inv, pos, tg, E_loc, top_k, bm)
+                part = on_banks(lambda wg, wu, wd: _llama._grouped_ffn(
+                    xf, _loc(wg), _loc(wu), _loc(wd),
+                    gates, inv, pos, tg, E_loc, top_k, bm))
             parts = jax.lax.all_gather(part, MP_AXIS, axis=0)  # [mp, N, H]
             # explicit left-assoc shard-order sum — NEVER psum, whose
             # reduction order XLA leaves unspecified
@@ -271,21 +289,18 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
                 inv = jnp.where(jnp.arange(M) < live_rows, inv, F)
                 own_flat = own.reshape(F)
                 pos = jnp.where(own_flat, pos, M)  # sentinel row reads zero
-                out, _ = _llama._grouped_ffn_fwd(
-                    xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
-                    lp["mlp.experts_down"], topv * own, inv, pos,
+                out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn_fwd(
+                    xf, wg, wu, wd, topv * own, inv, pos,
                     jnp.minimum(tg, held - 1), held, top_k, bm,
-                    live_tiles=live_rows // bm)
+                    live_tiles=live_rows // bm)[0])
                 rows = jnp.stack([own_flat.sum().astype(jnp.int32),
                                   live_rows])
         else:
             with jax.named_scope("experts"):
                 inv, pos, tg = sorted_dispatch_plan(
                     topi.reshape(N * top_k), E, bm)
-                out = _llama._grouped_ffn(
-                    xf, lp["mlp.experts_gate"], lp["mlp.experts_up"],
-                    lp["mlp.experts_down"], topv, inv, pos, tg, E, top_k,
-                    bm)
+                out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn(
+                    xf, wg, wu, wd, topv, inv, pos, tg, E, top_k, bm))
     else:
         with jax.named_scope("router"):
             comb = jnp.zeros((N, E), jnp.float32).at[
@@ -302,10 +317,9 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None):
 
         with jax.named_scope("experts"):
             acc0 = jnp.zeros(xf.shape, xf.dtype)
-            out, _ = jax.lax.scan(step, acc0, {
-                "wg": lp["mlp.experts_gate"], "wu": lp["mlp.experts_up"],
-                "wd": lp["mlp.experts_down"],
-                "c": comb.T.astype(xf.dtype)})
+            out = on_banks(lambda wg, wu, wd: jax.lax.scan(step, acc0, {
+                "wg": wg, "wu": wu, "wd": wd,
+                "c": comb.T.astype(xf.dtype)})[0])
     if moe.shared:
         # the shared experts, side by side along the width ([H, S * I] and
         # [S * I, H]): ordinary dense GEMMs over the same rows, whose
@@ -711,10 +725,12 @@ class LlamaGenerator:
         moe = c.moe
         norm_fn = rms_norm_fp32 if c.norm == "rms" else layer_norm_fp32
 
-        def one_layer(x, lp, kind, layer, ksl, vsl):
+        def one_layer(x, lp, kind, layer, ksl, vsl, bank_layer):
             """Decoder layer number ``layer``, of ``kind``, reading the pool
             (READ-ONLY; the kernel takes the whole pool and the layer, no
-            layer is sliced out of it): (x, this step's k, v, MoE rows)."""
+            layer is sliced out of it): (x, this step's k, v, MoE rows).
+            ``bank_layer``: None, or ``lp``'s expert banks are its place's
+            unstacked layers and this layer is that one of them."""
             with jax.named_scope("attention"):
                 y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
                 q = (y @ lp["self_attn.q_proj.weight"]).reshape(
@@ -768,7 +784,7 @@ class LlamaGenerator:
                 if moe is not None:
                     f, n_rows = _moe_ffn(
                         y, lp, moe, mp_shards=self._moe_shards,
-                        live=live if packed else valid)
+                        live=live if packed else valid, layer=bank_layer)
                 else:
                     act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
                         (y @ lp["mlp.up_proj.weight"])
@@ -785,26 +801,38 @@ class LlamaGenerator:
             return None if a is None else \
                 a.reshape((c.periods, P) + a.shape[1:])
 
+        # a scanned stack is sliced, and a slice that feeds a custom call
+        # (``gmm``) is written out first: a copy of the layer's expert banks
+        # a layer a step.  So a model may hand out a place's banks unstacked
+        # (a tuple, one array a layer); those stay out of the scan, which
+        # closes over them and hands them down with the period's number
+        unstacked = [{n: a for n, a in lp.items() if isinstance(a, tuple)}
+                     for lp in params["blocks"]]
+        scanned = [{n: a for n, a in lp.items() if n not in own}
+                   for lp, own in zip(params["blocks"], unstacked)]
+
         def period(carry, xs):
             x, = carry
             r, blocks, ksp, vsp = xs
             ks_new, vs_new, n_rows = [], [], None
             for p, kind in enumerate(c.pattern):
                 x, k, v, n = one_layer(
-                    x, blocks[p], kind, r * P + p,
+                    x, {**blocks[p], **unstacked[p]}, kind, r * P + p,
                     None if ksp is None else ksp[p],
-                    None if vsp is None else vsp[p])
+                    None if vsp is None else vsp[p],
+                    r if unstacked[p] else None)
                 ks_new.append(k)
                 vs_new.append(v)
                 if n is not None:
                     n_rows = n if n_rows is None else n_rows + n
             return (x,), (jnp.stack(ks_new), jnp.stack(vs_new), n_rows)
 
-        xs = (jnp.arange(c.periods, dtype=jnp.int32), params["blocks"],
+        xs = (jnp.arange(c.periods, dtype=jnp.int32), scanned,
               by_period(ks), by_period(vs))
         if c.periods == 1:
             # nothing to scan over: the one period runs in line on the
-            # stacks' only slice (a view; a scan's slices are copies)
+            # stacks' only slice (a view, where a scan's slices of stacked
+            # expert banks are copies)
             (h,), ys = period((h,), jax.tree_util.tree_map(
                 lambda a: a[0], xs))
             k_all, v_all, moe_rows = jax.tree_util.tree_map(

@@ -10,12 +10,22 @@ place in a period): the engine scans over whole periods with the period's
 layers unrolled inside, and ``serving_params()["blocks"]`` is one dict of
 ``[periods, ...]`` stacks for each place.  A stack of identical layers (the
 Llama family) is the period of one.
+
+A place's expert banks (``EXPERT_BANKS``) may come unstacked instead: a tuple
+of ``periods`` arrays ``[E, ...]``, one a layer.  The grouped GEMMs that read
+them are custom calls, for which a layer sliced out of a stack is written
+out first (a copy of the bank a layer a step); a whole array is read where
+it lies, and the engine picks the layer's by the period's number.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
 from typing import Optional, Tuple
+
+# a MoE layer's expert banks in ``serving_params()``, [E, H, I] twice and
+# [E, I, H]: what the grouped GEMMs read whole
+EXPERT_BANKS = ("mlp.experts_gate", "mlp.experts_up", "mlp.experts_down")
 
 
 @dataclass(frozen=True)

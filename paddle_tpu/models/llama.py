@@ -889,9 +889,17 @@ class LlamaForCausalLM(Layer):
 
     def serving_params(self):
         """The engine's parameter tree: the layers stacked ``[L, ...]`` (a
-        copy beside the per-layer parameters this model trains with)."""
+        copy beside the per-layer parameters this model trains with), but
+        for a MoE layer's expert banks, which go unstacked: a tuple of the
+        layers' own arrays.  The grouped GEMMs are custom calls, and a layer
+        sliced out of a stack would be copied for them a layer a step."""
         from ..utils import extract_params, stack_params
-        blocks = stack_params([extract_params(l) for l in self.llama.layers])
+        from .decoder_spec import EXPERT_BANKS
+        layers = [extract_params(l) for l in self.llama.layers]
+        blocks = stack_params([{n: a for n, a in lp.items()
+                                if n not in EXPERT_BANKS} for lp in layers])
+        blocks.update({n: tuple(lp[n] for lp in layers)
+                       for n in EXPERT_BANKS if n in layers[0]})
         head = (self.lm_head.weight._data if self.lm_head is not None
                 else self.llama.embed_tokens.weight._data.T)
         return {"embed": self.llama.embed_tokens.weight._data, "head": head,

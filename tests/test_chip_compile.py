@@ -252,3 +252,70 @@ def test_gmm_with_live_tiles_compiles(one_chip):
                                           live_tiles=live),
              ((m, HIDDEN), BF16), ((e, HIDDEN, HIDDEN), BF16),
              ((m // bm,), jnp.int32), ((), jnp.int32))
+
+
+# ---- the engine's layer scan over a MoE stack (PR 30) ----
+# Mixtral 8x7B widths, the batch-docs cell's larger row count (2,048 tokens
+# x 2 choices + 8 x 512 = 8,192 rows): what the scan's body hands ``gmm``
+MX_L, MX_I, MX_TOKENS = 2, 14336, 2048
+MX_BANK = f"bf16[{E},{HIDDEN},{MX_I}]"
+
+
+def _scan_over_moe_layers(unstacked):
+    """Two layers of the engine's ``_moe_ffn`` under a ``lax.scan``: on a
+    place's banks unstacked (tuples the body closes over, the layer picked
+    by the period's number), or stacked and scanned (PR 29's layout)."""
+    from paddle_tpu.inference.generation import EXPERT_BANKS, _moe_ffn
+    from paddle_tpu.models.decoder_spec import MoeSpec
+    moe = MoeSpec(num_experts=E, top_k=2, dispatch="grouped", block_m=BM)
+
+    def loop(h, router, *banks):
+        def body(x, xs):
+            r, gw, sliced = xs
+            lp = {"mlp.gate.weight": gw,
+                  **dict(zip(EXPERT_BANKS, banks if unstacked else sliced))}
+            f, _ = _moe_ffn(x, lp, moe, layer=r if unstacked else None)
+            return x + f, None
+        if unstacked:
+            banks = [tuple(banks[i::3]) for i in range(3)]
+        layers = jnp.arange(MX_L, dtype=jnp.int32)
+        return jax.lax.scan(
+            body, h, (layers, router, None if unstacked else banks))[0]
+    return loop
+
+
+@pytest.mark.parametrize("unstacked", [True, False],
+                         ids=["unstacked_banks", "scanned_stack"])
+def test_layer_scan_reads_expert_banks_where_they_lie(one_chip, monkeypatch,
+                                                      unstacked):
+    """A scanned bank is sliced, and a slice that feeds a custom call is
+    written out first: one copy of a layer's 0.94 GB bank a layer a step
+    (27 % of the Mixtral cell's window, ledger PR 29).  On unstacked banks
+    each branch of the body's ``lax.switch`` calls ``gmm`` on whole arrays
+    and the compiled loop holds no operation that produces a bank."""
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    up, down = (E, HIDDEN, MX_I), (E, MX_I, HIDDEN)
+    lead = () if unstacked else (MX_L,)
+    banks = [(lead + s, BF16) for s in (up, up, down)] * \
+        (MX_L if unstacked else 1)
+    compiled = _compile(one_chip, _scan_over_moe_layers(unstacked),
+                        ((1, MX_TOKENS, HIDDEN), BF16),
+                        ((MX_L, HIDDEN, E), BF16), *banks)
+    text = compiled.as_text()
+    assert f"bf16[{M},{MX_I}]" in text          # the cell's row count
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        3 * (MX_L if unstacked else 1)
+    makes_a_bank = [
+        line.strip()[:120] for line in text.splitlines()
+        if re.search(r"= bf16\[%d,(%d,%d|%d,%d)\]\S* [a-z\-]+\("
+                     % (E, HIDDEN, MX_I, MX_I, HIDDEN), line)
+        and not re.search(r" (parameter|get-tuple-element)\(", line)]
+    bank_bytes = E * HIDDEN * MX_I * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if unstacked:
+        assert not makes_a_bank, makes_a_bank
+        assert temp < bank_bytes
+    else:       # what the case above guards against is there when asked for
+        assert any("dynamic-slice" in line for line in makes_a_bank), \
+            makes_a_bank
+        assert temp >= bank_bytes

@@ -260,3 +260,68 @@ def test_offline_generate_takes_the_same_rule(low_floor):
                            page_size=PAGE, prefill_bucket=T)
     dense.row_buckets = lambda t: [B * t]
     assert dense.generate(prompts, gc) == first
+
+
+# ---------------------------------------------------------------------------
+# the layer scan reads each layer's expert banks where they lie (PR 30)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def primed_two_periods():
+    """Mixtral-tiny at widths the kernel's tiles divide (128), two layers =
+    two periods of the scan, the pool holding 16 and 5 tokens of context."""
+    paddle.seed(11)
+    cfg = LlamaConfig.mixtral_tiny(hidden_size=128, intermediate_size=128,
+                                   moe_dispatch="grouped", moe_block_m=8)
+    model = LlamaForCausalLM(cfg)
+    g = LlamaGenerator(model, max_batch=B, max_seq_len=MAX_LEN,
+                       page_size=PAGE, prefill_bucket=T)
+    assert g.spec.periods == 2 and g.spec.moe.dispatch == "grouped"
+    # the banks come unstacked, and are the model's own arrays: no copy
+    place, = g.params["blocks"]
+    for name in generation.EXPERT_BANKS:
+        assert isinstance(place[name], tuple) and len(place[name]) == 2
+        assert place[name][1] is dict(
+            model.llama.layers[1].named_parameters())[name]._data
+    bt = jnp.asarray(np.arange(B * g.pages_per_seq, dtype=np.int32).reshape(
+        B, g.pages_per_seq))
+    rng = np.random.default_rng(3)
+    toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (B, T)).astype(
+        np.int32))
+    _, cache, _ = g._forward_tokens(
+        g.params, tuple(g.cache.arrays), toks,
+        jnp.asarray([16, 5, 0, 0], jnp.int32), jnp.zeros((B,), jnp.int32), bt)
+    return g, bt, cache
+
+
+@pytest.mark.parametrize("kernel", ["interpreted", "xla_reference"])
+@pytest.mark.parametrize("step", ["mixed", "decode"])
+def test_scanned_layers_read_their_own_banks_bit_for_bit(
+        primed_two_periods, monkeypatch, step, kernel):
+    """Two periods of a Mixtral-tiny stack: the logits, hidden states and
+    pool of a mixed and of a decode step on unstacked banks (the scan's body
+    picks the layer's by the period's number) are those of the same weights
+    stacked and sliced by the scan, as PR 29 ran them, to the bit."""
+    from paddle_tpu import flags
+    g, bt, cache = primed_two_periods
+    monkeypatch.setitem(flags._VALUES, "grouped_matmul_interpret",
+                        kernel == "interpreted")
+    t = T if step == "mixed" else 1
+    ql, pos = ([1, 11, 4, 16], [16, 5, MAX_LEN - 2, 0]) \
+        if step == "mixed" else ([1, 1, 0, 1], [16, 5, 0, 0])
+    toks = jnp.asarray(np.random.default_rng(5).integers(
+        1, g.config.vocab_size, (B, t)).astype(np.int32))
+    stacked = {**g.params, "blocks": tuple(
+        {n: jnp.stack(a) if isinstance(a, tuple) else a
+         for n, a in place.items()} for place in g.params["blocks"])}
+
+    def served(params):
+        h, pool, _ = jax.jit(g._forward_tokens)(
+            params, cache, toks, jnp.asarray(ql, jnp.int32),
+            jnp.asarray(pos, jnp.int32), bt)
+        return [np.asarray(g._head_logits(params, h[:, 0])),
+                np.asarray(h)] + [np.asarray(a) for a in pool]
+
+    got, want = served(g.params), served(stacked)
+    assert all(np.array_equal(a, b) for a, b in zip(got, want))
+    assert np.abs(got[0]).max() > 0
