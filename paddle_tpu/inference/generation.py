@@ -64,12 +64,13 @@ from ..kernels.grouped_matmul import take_sentinel_rows
 from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        paged_attention,
                                        ragged_paged_attention,
+                                       ragged_paged_attention_latent,
                                        write_kv_pages,
                                        write_kv_pages_all_layers,
-                                       write_kv_pages_all_layers_quantized)
+                                       write_kv_pages_all_layers_quantized,
+                                       write_latent_pages_all_layers)
 from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
 from ..models.decoder_spec import EXPERT_BANKS
-from ..models.llama import _rope_cos_sin
 from . import speculative as _sp
 from .kv_cache import PagedKVCache
 
@@ -120,7 +121,7 @@ def _pack_plan(ql, T, rows):
     return live, b * T + t, dst
 
 
-def _cow_copy_pages(cache, src, dst):
+def _cow_copy_pages(cache, src, dst, page_axis=2):
     """Whole-page KV copies src[i] -> dst[i] across every layer/head (the
     prefix cache's copy-on-write privatization).  Entries with src < 0
     are no-ops: their dst is routed out of bounds, which scatter drops.
@@ -129,14 +130,17 @@ def _cow_copy_pages(cache, src, dst):
 
     ``cache`` is the pool tuple — ``(k, v)`` float or ``(k, v, k_scale,
     v_scale)`` int8: every plane indexes pages on axis 2, so one loop
-    copies them all, and an int8 COW moves 4x fewer bytes."""
+    copies them all, and an int8 COW moves 4x fewer bytes.  A latent
+    pool's two planes have no head axis: ``page_axis`` 1
+    (``PagedKVCache.page_axis``)."""
     valid = src >= 0
     s = jnp.maximum(src, 0)
+    lead = (slice(None),) * page_axis
     out = []
     for arr in cache:
-        d = jnp.where(valid, dst, arr.shape[2])
-        out.append(arr.at[:, :, d].set(jnp.take(arr, s, axis=2),
-                                       mode="drop"))
+        d = jnp.where(valid, dst, arr.shape[page_axis])
+        out.append(arr.at[lead + (d,)].set(jnp.take(arr, s, axis=page_axis),
+                                           mode="drop"))
     return tuple(out)
 
 
@@ -229,7 +233,10 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
                                       for ws in zip(*banks)])
 
     with jax.named_scope("router"):
-        topv, topi, _, _ = _llama._route_topk(xf, gw, top_k, moe.score)
+        topv, topi, _, _ = _llama._route_topk(
+            xf, gw, top_k, moe.score,
+            bias=lp["mlp.gate.bias"] if moe.select_bias else None,
+            scale=moe.gate_scale)
     if moe.partial:
         local = topi - moe.offset
         own = jnp.logical_and(local >= 0, local < held)       # [N, k]
@@ -395,6 +402,11 @@ class LlamaGenerator:
         if tensor_parallel is None:
             tensor_parallel = int(flags.flag("serving_tensor_parallel") or 1)
         tp = max(int(tensor_parallel), 1)
+        la = c.latent
+        if tp > 1 and la is not None:
+            raise ValueError(
+                "tensor_parallel > 1 shards the pool by KV head; a latent "
+                "pool has none (inference/kv_cache.py)")
         if tp > 1:
             if len(jax.devices()) < tp:
                 raise ValueError(
@@ -444,6 +456,10 @@ class LlamaGenerator:
             raise ValueError(
                 f"serving_params() has {len(self.params['blocks'])} block "
                 f"stacks, the layer pattern {len(c.pattern)} places")
+        if len(self.params.get("leading", ())) != len(c.leading):
+            raise ValueError(
+                f"serving_params() has {len(self.params.get('leading', ()))}"
+                f" leading layers, the spec states {len(c.leading)}")
         if self.mesh is not None:
             # the step's shard_map takes the weights replicated: place them
             # on every device of the mesh ONCE, or each dispatch re-copies
@@ -465,32 +481,42 @@ class LlamaGenerator:
                 quantized=str(cache_dtype or dtype) == "int8",
                 kv_heads=c.num_kv_heads // tp,
                 num_pages=self.num_pages,
-                table_shape=(max_batch, self.pages_per_seq))
+                table_shape=(max_batch, self.pages_per_seq),
+                latent=None if la is None else (la.rank, la.rope))
             if why:
                 raise ValueError(
                     f"engine geometry is not served by the paged-attention "
                     f"kernel on TPU: {why}")
+        if c.leading and str(cache_dtype or dtype) == "int8" \
+                and la is None:
+            raise ValueError("an int8 pool's scale planes are scanned by "
+                             "whole periods: no leading layers")
         # a uniform pool: every layer keeps every page, whatever its window
-        # (pages behind a sliding layer's window are held and never read)
+        # (pages behind a sliding layer's window are held and never read);
+        # a latent stack's holds one row a token a layer, no head axis
+        latent = None if la is None else (la.rank, la.rope)
         self.cache = PagedKVCache(
             num_layers=c.num_layers,
             num_pages=self.num_pages,
             page_size=page_size, num_kv_heads=c.num_kv_heads,
             head_dim=c.head_dim, dtype=cache_dtype or dtype,
-            mesh=self.mesh, axis=MP_AXIS)
+            mesh=self.mesh, axis=MP_AXIS, latent=latent)
         # host-global pool bytes (all shards) — advertised via stats() /
         # /statusz so the router's capacity-weighted placement can rank
         # heterogeneous replicas
         self.pool_bytes = self.num_pages * PagedKVCache.bytes_per_page(
             c.num_layers, c.num_kv_heads, page_size,
-            c.head_dim, cache_dtype or dtype)
+            c.head_dim, cache_dtype or dtype, latent=latent)
         if _obs.metrics_enabled():
             from ..observability import metrics as _metrics
             _metrics.gauge("serving.tp.degree").set(tp)
             _metrics.gauge("serving.tp.shard_pool_bytes").set(
                 self.pool_bytes // tp)
-        cos, sin = _rope_cos_sin(self.max_seq_len, c.head_dim, c.rope_theta,
-                                 jnp.float32)
+            _metrics.gauge("serving.kv_bytes_per_token").set(
+                self.pool_bytes // (self.num_pages * page_size))
+        # over the part of a head that rotates, with the yarn blend where
+        # the spec states one (``DecoderSpec.rope_tables``)
+        cos, sin = map(jnp.asarray, c.rope_tables(self.max_seq_len))
         self._cos, self._sin = cos, sin
         self._jit_cache = {}
         self._metrics_on = _obs.metrics_enabled()
@@ -725,12 +751,69 @@ class LlamaGenerator:
         moe = c.moe
         norm_fn = rms_norm_fp32 if c.norm == "rms" else layer_norm_fp32
 
+        def latent_attention(x, lp, la, layer):
+            """A latent layer's attention in the absorbed form: ``W_uk``
+            carried into the query, ``heads`` query heads over ONE row
+            ``[c | k_r]`` of the pool, ``W_uv`` applied to the call's
+            result: (the block's output, this step's c rows, k_r rows).
+            Serves prefill chunks and decodes alike: expanding the context
+            for a chunk costs ``2 L rank heads (nope + value)`` whatever
+            the chunk's length, the absorbed scores ``2 T L heads (rank -
+            nope) x 2`` more than the expanded ones; at the published
+            sizes they cross at 171 tokens a slot, over the chunk."""
+            y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
+            q = (y @ lp["self_attn.q_proj.weight"]).reshape(
+                R0, R1, c.num_heads, la.nope + la.rope)
+            ckr = y @ lp["self_attn.kv_a_proj_with_mqa.weight"]
+            c_new = rms_norm_fp32(ckr[..., :la.rank],
+                                  lp["self_attn.kv_a_layernorm.weight"],
+                                  c.norm_eps)
+            r_new = _rope_bt(ckr[..., None, la.rank:], cos, sin)[..., 0, :]
+            q_r = _rope_bt(q[..., la.nope:], cos, sin)
+            w_uk = lp["self_attn.k_up_proj.weight"].reshape(
+                la.rank, c.num_heads, la.nope)
+            q_c = jnp.einsum("abhd,rhd->abhr", q[..., :la.nope], w_uk)
+            if packed:
+                q_c, q_r = unpack(q_c), unpack(q_r)
+                c_new, r_new = unpack(c_new), unpack(r_new)
+            u = ragged_paged_attention_latent(
+                q_c, q_r, kc, vc, block_tables, ctx_prev,
+                scale=c.softmax_scale, q_lens=ql, c_new=c_new, r_new=r_new,
+                layer=layer)
+            if packed:
+                u = pack(u)
+            w_uv = lp["self_attn.v_up_proj.weight"].reshape(
+                la.rank, c.num_heads, la.value)
+            attn = jnp.einsum("abhr,rhd->abhd", u, w_uv).reshape(R0, R1, -1)
+            return attn @ lp["self_attn.o_proj.weight"], c_new, r_new
+
+        def ffn(y, lp, kind, bank_layer):
+            """A place's FFN on its normed input: the spec's expert mixture,
+            or a dense gated MLP where the spec has none or the place says
+            so: (output, MoE rows or None)."""
+            if moe is not None and not kind.dense_ffn:
+                return _moe_ffn(y, lp, moe, mp_shards=self._moe_shards,
+                                live=live if packed else valid,
+                                layer=bank_layer)
+            act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
+                (y @ lp["mlp.up_proj.weight"])
+            return act @ lp["mlp.down_proj.weight"], None
+
         def one_layer(x, lp, kind, layer, ksl, vsl, bank_layer):
             """Decoder layer number ``layer``, of ``kind``, reading the pool
             (READ-ONLY; the kernel takes the whole pool and the layer, no
             layer is sliced out of it): (x, this step's k, v, MoE rows).
             ``bank_layer``: None, or ``lp``'s expert banks are its place's
             unstacked layers and this layer is that one of them."""
+            if kind.latent is not None:
+                with jax.named_scope("attention"):
+                    a, k, v = latent_attention(x, lp, kind.latent, layer)
+                    x = x + a
+                with jax.named_scope("mlp" if kind.dense_ffn else "moe"):
+                    y = norm_fn(x, lp["post_attention_layernorm.weight"],
+                                c.norm_eps)
+                    f, n_rows = ffn(y, lp, kind, bank_layer)
+                return x + f, k, v, n_rows
             with jax.named_scope("attention"):
                 y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
                 q = (y @ lp["self_attn.q_proj.weight"]).reshape(
@@ -780,15 +863,7 @@ class LlamaGenerator:
                 if not c.parallel_block:     # else the FFN reads the same y
                     y = norm_fn(x, lp["post_attention_layernorm.weight"],
                                 c.norm_eps)
-                n_rows = None
-                if moe is not None:
-                    f, n_rows = _moe_ffn(
-                        y, lp, moe, mp_shards=self._moe_shards,
-                        live=live if packed else valid, layer=bank_layer)
-                else:
-                    act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
-                        (y @ lp["mlp.up_proj.weight"])
-                    f = act @ lp["mlp.down_proj.weight"]
+                f, n_rows = ffn(y, lp, kind, bank_layer)
                 x = x + a + f if c.parallel_block else x + f
             return x, k, v, n_rows
 
@@ -816,8 +891,11 @@ class LlamaGenerator:
             r, blocks, ksp, vsp = xs
             ks_new, vs_new, n_rows = [], [], None
             for p, kind in enumerate(c.pattern):
+                layer = r * P + p
+                if c.leading:
+                    layer = layer + len(c.leading)
                 x, k, v, n = one_layer(
-                    x, {**blocks[p], **unstacked[p]}, kind, r * P + p,
+                    x, {**blocks[p], **unstacked[p]}, kind, layer,
                     None if ksp is None else ksp[p],
                     None if vsp is None else vsp[p],
                     r if unstacked[p] else None)
@@ -827,6 +905,13 @@ class LlamaGenerator:
                     n_rows = n if n_rows is None else n_rows + n
             return (x,), (jnp.stack(ks_new), jnp.stack(vs_new), n_rows)
 
+        # leading layers (a shape of their own) run once, unrolled, first
+        lead_k, lead_v = [], []
+        for i, kind in enumerate(c.leading):
+            h, k, v, _ = one_layer(h, params["leading"][i], kind,
+                                   jnp.int32(i), None, None, None)
+            lead_k.append(k)
+            lead_v.append(v)
         xs = (jnp.arange(c.periods, dtype=jnp.int32), scanned,
               by_period(ks), by_period(vs))
         if c.periods == 1:
@@ -842,6 +927,22 @@ class LlamaGenerator:
         if moe_rows is not None:
             moe_rows = moe_rows.sum(axis=0)        # over the periods: [2]
         L = c.num_layers
+        if c.latent is not None:
+            # [periods, places, B, T, width] -> [layers, B * T, width], the
+            # leading layers' rows first
+            def layers_first(lead, a):
+                a = a.reshape((-1,) + a.shape[2:])
+                if lead:
+                    a = jnp.concatenate([jnp.stack(lead), a])
+                return a.reshape(L, B * T, a.shape[-1])
+
+            k_all, v_all = layers_first(lead_k, k_all), \
+                layers_first(lead_v, v_all)
+            with jax.named_scope("attention"), jax.named_scope("kv_write"):
+                out_cache = write_latent_pages_all_layers(
+                    kc, vc, k_all, v_all, slots)
+            h = norm_fn(h, params["norm"], c.norm_eps)
+            return (unpack(h) if packed else h), out_cache, moe_rows
         kvh, dh = c.num_kv_heads, c.head_dim
         k_all = k_all.reshape(L, B * T, kvh, dh)
         v_all = v_all.reshape(L, B * T, kvh, dh)
@@ -1423,8 +1524,10 @@ class ContinuousBatchingEngine:
             self.prefix_cache = PrefixCache(
                 self.g.cache.allocator, self.g.page_size,
                 min_pages=flags.flag("prefix_cache_min_pages"))
-            self._cow_jit = self.g.pool_jit(_cow_copy_pages, "pool_cow_copy",
-                                            n_extra=2)
+            self._cow_jit = self.g.pool_jit(
+                functools.partial(_cow_copy_pages,
+                                  page_axis=self.g.cache.page_axis),
+                "pool_cow_copy", n_extra=2)
             # warm the copy program with an all-no-op call so the first
             # cache hit (and every later one) stays zero-recompile
             none = jnp.full((B,), -1, jnp.int32)

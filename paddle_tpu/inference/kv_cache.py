@@ -325,11 +325,23 @@ class PagedKVCache:
     kv-head axis, while page ids, block tables, the allocator, the
     prefix cache and the spill ring stay host-global.  ``np.asarray`` on
     a page slice gathers the full global plane, so migration snapshots
-    and spill bytes are identical at any shard count."""
+    and spill bytes are identical at any shard count.
+
+    ``latent=(rank, rope)`` is a LATENT pool (``models.decoder_spec.
+    LatentAttn``): one row ``[c | k_r]`` a token a layer and no head axis,
+    held as two arrays because ``rank + rope`` is not a multiple of the
+    128 lanes: ``k`` is the compressed part ``[layers, num_pages, page_size,
+    rank]`` and ``v`` the rotary keys TWO TOKENS A ROW, ``[layers,
+    num_pages, page_size / 2, 2 * rope]`` (token ``t`` of a page lies in
+    row ``t % (page_size / 2)``, lanes ``[(t // (page_size / 2)) * rope,
+    + rope)``): 64 numbers would be padded to 128 lanes in HBM, two tokens
+    fill them, and a page stays one whole tile of each array.  Pages are on
+    axis 1 (``page_axis``).  No int8 plane and no tensor-parallel layout:
+    both are refused here."""
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype="bfloat16",
-                 mesh=None, axis: str = "mp"):
+                 mesh=None, axis: str = "mp", latent=None):
         self.num_layers = num_layers
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
@@ -337,6 +349,31 @@ class PagedKVCache:
         self.quantized = str(dtype) == "int8"
         self.mesh = mesh
         self.axis = axis
+        self.latent = None if latent is None else tuple(latent)
+        # the axis of every plane that counts pages
+        self.page_axis = 2 if latent is None else 1
+        if latent is not None:
+            if self.quantized:
+                raise ValueError(
+                    "inference/kv_cache.py: a latent pool has no int8 "
+                    "plane (the per-(kv-head, page) scales assume per-head "
+                    "pages); use kv_cache_dtype auto, bf16 or fp32")
+            if mesh is not None:
+                raise ValueError(
+                    "inference/kv_cache.py: a latent pool has no head axis "
+                    "to shard; tensor_parallel must be 1")
+            if page_size % 2:
+                raise ValueError(f"a latent pool keeps two tokens' rotary "
+                                 f"keys a row: page_size ({page_size}) "
+                                 "must be even")
+            rank, rope = self.latent
+            dt = jnp.dtype(dtype)
+            self.k = jnp.zeros((num_layers, num_pages, page_size, rank), dt)
+            self.v = jnp.zeros((num_layers, num_pages, page_size // 2,
+                                2 * rope), dt)
+            self.k_scale = self.v_scale = None
+            self.allocator = PageAllocator(num_pages, page_size)
+            return
         if mesh is not None and num_kv_heads % mesh.shape[axis] != 0:
             raise ValueError(
                 f"num_kv_heads={num_kv_heads} not divisible by "
@@ -376,7 +413,8 @@ class PagedKVCache:
     @property
     def arrays(self):
         """The donated device state of one engine step: ``(k, v)`` for a
-        float pool, ``(k, v, k_scale, v_scale)`` when quantized."""
+        float pool (a latent pool's compressed rows and rotary keys),
+        ``(k, v, k_scale, v_scale)`` when quantized."""
         if self.quantized:
             return self.k, self.v, self.k_scale, self.v_scale
         return self.k, self.v
@@ -403,9 +441,14 @@ class PagedKVCache:
 
     @staticmethod
     def bytes_per_page(num_layers: int, num_kv_heads: int, page_size: int,
-                       head_dim: int, dtype="bfloat16") -> int:
+                       head_dim: int, dtype="bfloat16", latent=None) -> int:
         """HBM bytes one pool page costs (K + V + scales, all layers) —
-        the unit the kv_quant bench equalizes across dtype arms."""
+        the unit the kv_quant bench equalizes across dtype arms.  A latent
+        pool (``latent=(rank, rope)``): ``rank + rope`` numbers a token a
+        layer, whatever the heads."""
+        if latent is not None:
+            return num_layers * page_size * sum(latent) \
+                * jnp.dtype(dtype).itemsize
         per = num_layers * num_kv_heads
         if str(dtype) == "int8":
             return 2 * per * (page_size * head_dim + 4)

@@ -132,6 +132,16 @@ def _engine_counts(engine) -> Dict[str, int]:
 # geometry / codec
 # ---------------------------------------------------------------------------
 
+def _refuse_latent(engine) -> None:
+    """A snapshot's pages, geometry and digest are per-head planes; a
+    latent pool (one row a token, no head axis) is not exported, imported
+    or warmed."""
+    if getattr(engine.g.cache, "latent", None) is not None:
+        raise MigrationError(
+            "inference/migration.py: session snapshots move per-head page "
+            "planes; this engine serves a latent pool, which has none")
+
+
 def _geometry(engine) -> Dict[str, object]:
     g = engine.g
     cache = g.cache
@@ -250,6 +260,7 @@ def export_session(engine, req_id: Optional[int] = None,
     chain matching the token history; spilled chain nodes ship their
     host-ring bytes directly (no swap-in).
     """
+    _refuse_latent(engine)
     if (req_id is None) == (tokens is None):
         raise ValueError("export_session takes exactly one of "
                          "req_id= or tokens=")
@@ -369,6 +380,7 @@ def _uploader(engine):
 def warm(engine) -> None:
     """Compile the upload program with an out-of-range page id (every
     scatter write drops) so the first real import is dispatch-only."""
+    _refuse_latent(engine)
     cache = engine.g.cache
     zeros = tuple(jnp.zeros(arr.shape[:2] + arr.shape[3:], arr.dtype)
                   for arr in cache.arrays)
@@ -394,6 +406,7 @@ def import_session(engine, snap: dict, resume: bool = False) -> dict:
     re-prefilled.  Returns ``{"imported", "skipped", "pages",
     "resume_req_id"}``.
     """
+    _refuse_latent(engine)
     cache = engine.prefix_cache
     if cache is None:
         raise MigrationError("import needs the prefix cache "

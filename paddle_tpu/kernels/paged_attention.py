@@ -595,22 +595,51 @@ def _smem_need_bytes(kv_heads, num_pages, table_entries_padded):
 
 def kernel_geometry_error(page_size, head_dim, *, quantized=False,
                           kv_heads=0, num_pages=0, table_shape=(0, 0),
-                          smem_bytes=None):
-    """The rule a paged-KV geometry fails for the Pallas kernel, as a
-    sentence, or None when the kernel covers it.  On a TPU a failing
-    geometry raises (here at trace time, and in the engine when it is
-    built); off-TPU the XLA reference takes it.
+                          smem_bytes=None, interpret=False, latent=None):
+    """The rule a paged-KV geometry fails for the Pallas kernel it is asked
+    about, as a sentence, or None when the kernel covers it.  On a TPU a
+    failing geometry raises (here at trace time, and in the engine when it
+    is built); off-TPU the XLA reference takes it.
 
     ``smem_bytes`` is the scalar memory of the core the kernel is built
     for; None asks the attached TPU (``pltpu.get_tpu_info``) and, with no
-    TPU attached, leaves the SMEM rule to the compiler."""
+    TPU attached, leaves the SMEM rule to the compiler.
+
+    ``interpret``: the kernel as the interpreter runs it (CPU tests), which
+    has no lane tiling: a head half a tile wide (``head_dim % 128 == 64``)
+    passes there, while the TPU compiler refuses to slice it out of the
+    pool (``Slice shape along dimension 3 must be aligned to tiling
+    (128)``) and so does this rule.
+
+    ``latent=(rank, rope)`` asks about the latent call
+    (``ragged_paged_attention_latent``; ``head_dim`` is then ignored): its
+    pool is the compressed rows ``[.., page_size, rank]`` and the rotary
+    keys two tokens a row ``[.., page_size / 2, 2 * rope]``, each page a
+    whole number of ``(8, 128)`` tiles of both."""
+    if latent is not None:
+        rank, rope = latent
+        if quantized:
+            return "the latent call has no int8 plane"
+        if page_size % 2:
+            return (f"page_size ({page_size}) must be even: the rotary "
+                    "keys lie two tokens a row")
+        if interpret:
+            return None
+        if page_size % 16:
+            return (f"page_size ({page_size}) must be a multiple of 16: "
+                    "half a page of rotary keys is a whole sublane tile")
+        if rank % 128 or (2 * rope) % 128:
+            return (f"the compressed row ({rank}) and two rotary keys "
+                    f"(2 x {rope}) must each fill whole 128-lane tiles")
+        return None
     # f32 sublane is 8; bf16 packs 16 — page_size must tile the sublane
     # dim.  int8 packs 32 sublanes per tile, so a quantized pool needs
     # page_size % 32 == 0 to keep each page a whole-tile DMA.
     if page_size % 8:
         return f"page_size ({page_size}) must be a multiple of 8"
-    if head_dim % 128 not in (0, 64):
-        return f"head_dim % 128 must be 0 or 64, got head_dim {head_dim}"
+    if head_dim % 128 not in ((0, 64) if interpret else (0,)):
+        return (f"head_dim must be a multiple of 128 (the compiler slices "
+                f"whole lane tiles out of the pool), got {head_dim}")
     if quantized and page_size % 32:
         return (f"an int8 pool needs page_size % 32 == 0 (int8 packs 32 "
                 f"sublanes per tile), got {page_size}")
@@ -699,7 +728,8 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     on_tpu = jax.default_backend() == "tpu"
     why = kernel_geometry_error(
         page_size, d, quantized=k_scale is not None, kv_heads=kvh,
-        num_pages=n_pool_pages, table_shape=block_tables.shape)
+        num_pages=n_pool_pages, table_shape=block_tables.shape,
+        interpret=not on_tpu)
     if on_tpu and why:
         # the serving hot op has no business on the XLA reference on a chip
         raise ValueError(f"ragged_paged_attention on TPU: {why}")
@@ -716,6 +746,414 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
             q, k_cache, v_cache, block_tables, context_lens, q_lens,
             k_new, v_new, k_scale=k_scale, v_scale=v_scale, window=window)
     return (out, lse) if with_lse else out
+
+
+# ------------------------------------------------------ the latent call ---
+# Latent attention (MLA, ``models.decoder_spec.LatentAttn``) over a latent
+# page pool: a token's row is ``[c | k_r]`` (``rank`` + ``rope`` numbers),
+# every query head attends the SAME row, and the value is the first
+# ``rank`` numbers of the key.  The caller has absorbed ``W_uk`` into the
+# query (``q_c``, ``rank`` wide) and applies ``W_uv`` to the result.
+
+def unpack_rope_pages(r, rope):
+    """``[..., half, 2 * rope]`` (two tokens a row: token ``t`` of a page in
+    row ``t % half``, lanes ``[(t // half) * rope, + rope)``) ->
+    ``[..., 2 * half, rope]``, one token a row in order."""
+    lead, half = r.shape[:-2], r.shape[-2]
+    r = r.reshape(lead + (half, 2, rope))
+    return jnp.swapaxes(r, -3, -2).reshape(lead + (2 * half, rope))
+
+
+def _reference_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
+                                             block_tables, context_lens,
+                                             q_lens, c_new, r_new, scale):
+    """XLA oracle of the latent call (one layer's pool).  q_c ``[B, T, H,
+    rank]``, q_r ``[B, T, H, rope]``; c_cache ``[P, page, rank]``, r_cache
+    ``[P, page / 2, 2 * rope]``; c_new ``[B, T, rank]``, r_new ``[B, T,
+    rope]``.  Returns ``[B, T, H, rank]``: ``sum_j a_j c_j``."""
+    b, t, _, rank = q_c.shape
+    rope = q_r.shape[-1]
+    n_pages, page_size, _ = c_cache.shape
+    max_pages = block_tables.shape[1]
+    S = max_pages * page_size
+    f32 = jnp.float32
+    flat = block_tables.reshape(-1)
+    c = jnp.take(c_cache, flat, axis=0).reshape(b, S, rank).astype(f32)
+    r = unpack_rope_pages(jnp.take(r_cache, flat, axis=0), rope)
+    r = r.reshape(b, S, rope).astype(f32)
+    qc, qr = q_c.astype(f32), q_r.astype(f32)
+    s = (jnp.einsum("bthr,bsr->bths", qc, c)
+         + jnp.einsum("bthd,bsd->bths", qr, r)) * scale
+    mask = jnp.arange(S)[None, :] < context_lens[:, None]          # [B, S]
+    s = jnp.where(mask[:, None, None, :], s, NEG_INF)
+    parts_s, parts_v = [s], [c]
+    if c_new is not None:
+        cn, rn = c_new.astype(f32), r_new.astype(f32)
+        s2 = (jnp.einsum("bthr,bjr->bthj", qc, cn)
+              + jnp.einsum("bthd,bjd->bthj", qr, rn)) * scale
+        jq = jnp.arange(t)
+        ql = (q_lens if q_lens is not None
+              else jnp.full((b,), t)).astype(jnp.int32)
+        valid = jnp.logical_and(jq[None, :, None] >= jq[None, None, :],
+                                jq[None, None, :] < ql[:, None, None])
+        parts_s.append(jnp.where(valid[:, :, None, :], s2, NEG_INF))
+        parts_v.append(cn)
+    p = jax.nn.softmax(jnp.concatenate(parts_s, axis=-1), axis=-1)
+    out = jnp.einsum("bths,bsr->bthr", p, jnp.concatenate(parts_v, axis=1))
+    return out.astype(q_c.dtype)
+
+
+def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
+                        has_new, layered):
+    """One slot's program of the latent call, on the schedule of
+    ``_ragged_paged_attn_kernel``: the slot's live rows are the prefix
+    ``[0, q_len * heads)`` (row ``r`` = token ``r // heads``) in row tiles
+    of ``tile``; the KV is walked in blocks of ``ppb`` pages, two buffers.
+
+    A block's pages land in ONE ``[keys, rank]`` tile of compressed rows
+    and ONE ``[keys / 2, 2 * rope]`` tile of rotary keys (two tokens a row,
+    as the pool holds them).  So that the score columns of both parts line
+    up, a page's compressed rows are copied as its two halves: the block's
+    first ``keys / 2`` rows hold every page's tokens ``[0, half)``, the
+    rest their tokens ``[half, page)``, and the rotary scores are two
+    products, the query's rotary part against the lower and against the
+    upper lanes (``q_lo = [q_r | 0]``, ``q_hi = [0 | q_r]``), side by side.
+    The order of a block's keys is the kernel's own business: the mask
+    works from each column's position.  ``PV`` multiplies the SAME
+    compressed tile that made the scores: a page is read once for key and
+    value.  The probabilities enter ``PV`` in the pool's dtype where that
+    is bfloat16 (half of this call's operations are ``PV``; the float32 x
+    bf16 product costs several passes), float32 otherwise.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    it = iter(refs)
+    bt_ref, cl_ref, ql_ref = next(it), next(it), next(it)
+    ly_ref = next(it) if layered else None
+    qc_ref, qlo_ref, qhi_ref = next(it), next(it), next(it)
+    cnew_ref = next(it) if has_new else None
+    rnew_ref = next(it) if has_new else None
+    c_hbm, r_hbm = next(it), next(it)
+    o_ref = next(it)
+    cbuf, rbuf, sem = next(it), next(it), next(it)
+    m_ref, l_ref, acc_ref = next(it), next(it), next(it)
+
+    b = pl.program_id(0)
+    ctx = cl_ref[b]
+    ql = ql_ref[b]
+    rows, rank = qc_ref.shape
+    hk = rbuf.shape[1]              # half a block's keys: two tokens a row
+    keys = ppb * page_size
+    i32 = np.int32
+    ps_c, ppb_c, tile_c, one = i32(page_size), i32(ppb), i32(tile), i32(1)
+    half_c, hk_c = i32(half), i32(hk)
+    max_tiles = i32(pl.cdiv(rows, tile))
+    last_entry = i32(bt_ref.shape[1] - 1)
+    n_tiles = jnp.minimum(
+        jax.lax.div(ql * i32(heads) + tile_c - one, tile_c), max_tiles)
+    pages_total = jax.lax.div(ctx + ps_c - one, ps_c)
+    n_blocks = jax.lax.div(pages_total + ppb_c - one, ppb_c)
+    mxu = qc_ref.dtype if cbuf.dtype == qc_ref.dtype else jnp.float32
+    p_dtype = jnp.bfloat16 if cbuf.dtype == jnp.bfloat16 else jnp.float32
+
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(i * tile_c, tile), tile)
+
+    def half_rows(i, upper=False):
+        """Rows of page ``i``'s lower (or upper) half in a block's tiles."""
+        start = i * half_c + (hk_c if upper else _I0)
+        return pl.ds(pl.multiple_of(start, half), half)
+
+    def for_live_tiles(body):
+        if rows == tile:
+            body(_I0)
+            return
+
+        def step(i, carry):
+            body(i)
+            return carry
+
+        jax.lax.fori_loop(_I0, n_tiles, step, _I0)
+
+    def fetch(j, slot):
+        """Start the copies of block ``j``: a page's compressed rows as
+        its two halves, its rotary keys as the one tile they are."""
+        p0 = j * ppb_c
+
+        def page(i):
+            pid = bt_ref[b, jnp.minimum(p0 + i, last_entry)]
+            c_pg = c_hbm.at[ly_ref[0], pid] if layered else c_hbm.at[pid]
+            r_pg = r_hbm.at[ly_ref[0], pid] if layered else r_hbm.at[pid]
+            for upper in (False, True):
+                pltpu.make_async_copy(
+                    c_pg.at[pl.ds(half if upper else 0, half)],
+                    cbuf.at[slot, half_rows(i, upper)],
+                    sem.at[slot, _I0]).start()
+            pltpu.make_async_copy(r_pg, rbuf.at[slot, half_rows(i)],
+                                  sem.at[slot, one]).start()
+            return i + one
+
+        jax.lax.while_loop(lambda i: i < ppb_c, page, _I0)
+
+    def wait(slot):
+        for buf, col in ((cbuf, _I0), (rbuf, one)):
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sem.at[slot, col]).wait()
+
+    def accumulate(r, s, v):
+        m_prev, l_prev = m_ref[r, :], l_ref[r, :]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        m_ref[r, :] = m_new
+        l_ref[r, :] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[r, :] = alpha * acc_ref[r, :] + jax.lax.dot_general(
+            p.astype(p_dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def dot_t(a, k):
+        return jax.lax.dot_general(
+            a.astype(mxu), k.astype(mxu), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def tokens_of(i):
+        r = i * tile_c + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+        return jax.lax.div(r, jnp.full((tile, 1), heads, jnp.int32))
+
+    def block(j, carry):
+        slot = jax.lax.rem(j, i32(2))
+
+        @pl.when(j + one < n_blocks)
+        def _prefetch():
+            fetch(j + one, one - slot)
+
+        wait(slot)
+        p0 = j * ppb_c
+
+        # pages past the context are some other sequence's: their scores
+        # are masked, their values must not be kept (0 x non-finite)
+        def clear(i, c):
+            for upper in (False, True):
+                cbuf[slot, half_rows(i, upper), :] = jnp.zeros(
+                    (half, rank), cbuf.dtype)
+            return c
+
+        jax.lax.fori_loop(jnp.minimum(pages_total - p0, ppb_c), ppb_c,
+                          clear, _I0)
+
+        c = cbuf[slot]                                      # [keys, rank]
+        kr = rbuf[slot]                                     # [hk, 2 rope]
+        # the position of each key column: the first hk columns are the
+        # pages' lower halves, the rest their upper halves
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
+        upper = col >= hk_c
+        j2 = jnp.where(upper, col - hk_c, col)
+        half_v = jnp.full((1, keys), half, jnp.int32)
+        pos = (p0 + jax.lax.div(j2, half_v)) * ps_c \
+            + jax.lax.rem(j2, half_v) + jnp.where(upper, half_c, _I0)
+        seen = pos < ctx
+
+        def row_tile_of_block(i):
+            r = rows_of(i)
+            s = dot_t(qc_ref[r, :], c) + jnp.concatenate(
+                [dot_t(qlo_ref[r, :], kr), dot_t(qhi_ref[r, :], kr)], axis=1)
+            s = s * jnp.float32(scale)
+            accumulate(r, jnp.where(seen, s, jnp.float32(NEG_INF)), c)
+
+        for_live_tiles(row_tile_of_block)
+        return carry
+
+    def finish(i):
+        r = rows_of(i)
+        if has_new:
+            # the step's own rows, one token a row in order; their rotary
+            # keys lie in the lower lanes, so q_lo alone meets them
+            s = (dot_t(qc_ref[r, :], cnew_ref[...])
+                 + dot_t(qlo_ref[r, :], rnew_ref[...])) * jnp.float32(scale)
+            jq = tokens_of(i)
+            jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = jnp.logical_and(jk <= jq, jk < ql)
+            accumulate(r, jnp.where(valid, s, jnp.float32(NEG_INF)),
+                       cnew_ref[...])
+        l = jnp.maximum(l_ref[r, :], jnp.float32(1e-30))
+        o_ref[r, :] = (acc_ref[r, :] / l).astype(o_ref.dtype)
+
+    @pl.when(n_tiles < max_tiles)
+    def _blank():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(n_tiles > _I0)
+    def _work():
+        @pl.when(n_blocks > _I0)
+        def _warmup():
+            fetch(_I0, _I0)
+
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+        jax.lax.fori_loop(_I0, n_blocks, block, _I0)
+        for_live_tiles(finish)
+
+
+def _lane_padded_bytes(shape, dtype):
+    """VMEM bytes of a buffer whose last dim is padded to 128 lanes."""
+    *lead, last = shape
+    return int(np.prod(lead, dtype=np.int64)) * (-(-last // 128) * 128) \
+        * jnp.dtype(dtype).itemsize
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
+                                          block_tables, context_lens, q_lens,
+                                          c_new, r_new, interpret, scale,
+                                          layer=None):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, t, heads, rank = q_c.shape
+    rope = q_r.shape[-1]
+    layered = layer is not None
+    n_pages, page_size, _ = c_cache.shape[-3:]
+    rows = t * heads
+    R = _padded_rows(t, heads)
+    zeros = jnp.zeros_like(q_r)
+    qc = q_c.reshape(b, rows, rank)
+    q_lo = jnp.concatenate([q_r, zeros], axis=-1).reshape(b, rows, 2 * rope)
+    q_hi = jnp.concatenate([zeros, q_r], axis=-1).reshape(b, rows, 2 * rope)
+    if R != rows:
+        pad = ((0, 0), (0, R - rows), (0, 0))
+        qc, q_lo, q_hi = (jnp.pad(a, pad) for a in (qc, q_lo, q_hi))
+
+    ppb = _pages_per_block(page_size, block_tables.shape[1])
+    keys = ppb * page_size
+    bt = jnp.clip(block_tables, 0, n_pages - 1).astype(jnp.int32)
+    cl = context_lens.astype(jnp.int32)
+    ql = (q_lens if q_lens is not None
+          else jnp.full((b,), t)).astype(jnp.int32)
+
+    def block_of(block_rows, last):
+        return pl.BlockSpec((None, block_rows, last),
+                            lambda b_, *_: (b_, _I0, _I0))
+
+    has_new = c_new is not None
+    operands = [qc, q_lo, q_hi]
+    in_specs = [block_of(R, rank), block_of(R, 2 * rope),
+                block_of(R, 2 * rope)]
+    Tp = -(-t // _SUBLANE) * _SUBLANE
+    if has_new:
+        rn = jnp.concatenate([r_new, jnp.zeros_like(r_new)], axis=-1)
+        cn = c_new
+        if Tp != t:
+            pad = ((0, 0), (0, Tp - t), (0, 0))
+            cn, rn = jnp.pad(cn, pad), jnp.pad(rn, pad)
+        operands += [cn, rn]
+        in_specs += [block_of(Tp, rank), block_of(Tp, 2 * rope)]
+    scalars = [bt, cl, ql]
+    if layered:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+    operands += [c_cache, r_cache]
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
+                 pl.BlockSpec(memory_space=pl.ANY)]
+
+    tile = row_tile(t, heads)
+    kernel = functools.partial(
+        _latent_attn_kernel, page_size=page_size, half=page_size // 2, ppb=ppb,
+        tile=tile, scale=scale, heads=heads, has_new=has_new, layered=layered)
+    scratch = [
+        ((2, keys, rank), c_cache.dtype),
+        ((2, keys // 2, 2 * rope), r_cache.dtype),
+        ((R, 1), jnp.float32), ((R, 1), jnp.float32),
+        ((R, rank), jnp.float32),
+    ]
+    # a prefill slot's block is heads x T rows of the whole compressed
+    # width (4,096 x 512 at the published sizes): the query and output
+    # blocks (two buffers each, the pipeline's), the accumulator and the
+    # lane-padded m and l need more than the compiler's default scope
+    need = sum(_lane_padded_bytes(sh, dt) for sh, dt in scratch) \
+        + 2 * (2 * _lane_padded_bytes((R, rank), q_c.dtype)
+               + 2 * _lane_padded_bytes((R, 2 * rope), q_c.dtype)) \
+        + 4 * _lane_padded_bytes((Tp, rank), q_c.dtype) \
+        + 3 * _lane_padded_bytes((tile, keys), jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(scalars),
+        grid=(b,),
+        in_specs=in_specs,
+        out_specs=block_of(R, rank),
+        scratch_shapes=[
+            pltpu.VMEM(*scratch[0]), pltpu.VMEM(*scratch[1]),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM(*scratch[2]), pltpu.VMEM(*scratch[3]),
+            pltpu.VMEM(*scratch[4]),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="ragged_paged_attention_latent",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, R, rank), q_c.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=int(min(max(need * 5 // 4, 32 << 20),
+                                     110 << 20))),
+        interpret=interpret,
+    )(*scalars, *operands)
+    return out[:, :rows].reshape(b, t, heads, rank)
+
+
+def ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache, block_tables,
+                                  context_lens, *, scale, q_lens=None,
+                                  c_new=None, r_new=None, layer=None):
+    """Mixed-mode serving attention over a LATENT page pool (prefill chunks
+    and decode tokens in one call), in the absorbed form.
+
+    Args:
+      q_c:     [batch, T, heads, rank]: ``W_uk^T q_nope``, the query's
+               content part carried into the compressed space.
+      q_r:     [batch, T, heads, rope]: the query's rotary part, rotated.
+      c_cache: [num_pages, page_size, rank] compressed rows (normed), or
+               the whole pool [layers, ...] with ``layer``.
+      r_cache: [num_pages, page_size / 2, 2 * rope] rotary keys, two
+               tokens a row (``PagedKVCache``), or the whole pool.
+      block_tables, context_lens, q_lens: as ``ragged_paged_attention``.
+      scale:   static float: what the scores are multiplied by (the
+               model's ``(nope + rope)^-0.5`` times its yarn factor).
+      c_new/r_new: [batch, T, rank] / [batch, T, rope]: the step's own
+               rows, folded in causally; commit them after the step.
+      layer:   int32 scalar (may be traced), with the whole pool.
+
+    Returns ``u`` [batch, T, heads, rank]: ``sum_j a_j c_j`` for each head,
+    to which the caller applies ``W_uv``.  Rows past ``q_lens[b]`` are
+    don't-care (zeros past the live row tiles)."""
+    b, t, heads, rank = q_c.shape
+    rope = q_r.shape[-1]
+    if (c_cache.ndim == 4) != (layer is not None):
+        raise ValueError("a whole pool [layers, ...] is read at `layer`; "
+                         "one layer's cache takes none")
+    if (c_new is None) != (r_new is None):
+        raise ValueError("c_new and r_new must be given together")
+    page_size = c_cache.shape[-2]
+    if c_cache.shape[-1] != rank or r_cache.shape[-2:] != (page_size // 2,
+                                                          2 * rope):
+        raise ValueError(
+            f"latent pool {c_cache.shape} / {r_cache.shape} does not hold "
+            f"rows of {rank} + {rope} for pages of {page_size}")
+    on_tpu = jax.default_backend() == "tpu"
+    why = kernel_geometry_error(page_size, 0, latent=(rank, rope),
+                                interpret=not on_tpu)
+    if on_tpu and why:
+        raise ValueError(f"ragged_paged_attention_latent on TPU: {why}")
+    if (on_tpu or flags.flag("paged_attention_interpret")) and not why:
+        return _pallas_ragged_paged_attention_latent(
+            q_c, q_r, c_cache, r_cache, block_tables, context_lens, q_lens,
+            c_new, r_new, interpret=not on_tpu, scale=float(scale),
+            layer=layer)
+    if layer is not None:
+        c_cache, r_cache = (jax.lax.dynamic_index_in_dim(
+            a, layer, axis=0, keepdims=False) for a in (c_cache, r_cache))
+    return _reference_ragged_paged_attention_latent(
+        q_c, q_r, c_cache, r_cache, block_tables, context_lens, q_lens,
+        c_new, r_new, float(scale))
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
@@ -810,6 +1248,49 @@ def write_kv_pages_all_layers(k_cache, v_cache, k_all, v_all, slot_mapping):
     flat_k, flat_v = jax.lax.fori_loop(
         jnp.int32(0), valid.sum().astype(jnp.int32), commit, (flat_k, flat_v))
     return (flat_k.reshape(k_cache.shape), flat_v.reshape(v_cache.shape))
+
+
+def write_latent_pages_all_layers(c_cache, r_cache, c_all, r_all,
+                                  slot_mapping):
+    """Commit every layer's new latent rows, token by token, in place: the
+    latent pool's ``write_kv_pages_all_layers`` (a loop of
+    ``dynamic_update_slice`` over the step's valid tokens, for the reason
+    given there).
+
+    c_cache ``[layers, num_pages, page_size, rank]``; r_cache ``[layers,
+    num_pages, page_size / 2, 2 * rope]``; c_all ``[layers, n_tokens,
+    rank]``, r_all ``[layers, n_tokens, rope]``; slot_mapping ``[n_tokens]``
+    (``page * page_size + offset``; -1 = drop).  A token's rotary key goes
+    into its half of the row it shares (read, one half replaced, written
+    back whole: the update stays aligned to the lanes)."""
+    L, n_pages, page_size, rank = c_cache.shape
+    half, rope = page_size // 2, r_all.shape[-1]
+    flat_c = c_cache.reshape(L, n_pages * page_size, rank)
+    flat_r = r_cache.reshape(L, n_pages * half, 2 * rope)
+    slots = slot_mapping.astype(jnp.int32)
+    cn = c_all.astype(flat_c.dtype)
+    rn = jnp.concatenate([r_all, r_all], axis=-1).astype(flat_r.dtype)
+    valid = slots >= 0
+    order = jnp.argsort(jnp.logical_not(valid), stable=True).astype(jnp.int32)
+    lane_upper = jnp.arange(2 * rope) >= rope
+
+    def commit(i, cr):
+        fc, fr = cr
+        src = order[i]
+        dst = slots[src]
+        fc = jax.lax.dynamic_update_slice_in_dim(
+            fc, jax.lax.dynamic_slice_in_dim(cn, src, 1, axis=1), dst, axis=1)
+        offset = dst % page_size
+        row = (dst // page_size) * half + offset % half
+        old = jax.lax.dynamic_slice_in_dim(fr, row, 1, axis=1)
+        new = jnp.where(lane_upper == (offset >= half),
+                        jax.lax.dynamic_slice_in_dim(rn, src, 1, axis=1), old)
+        fr = jax.lax.dynamic_update_slice_in_dim(fr, new, row, axis=1)
+        return fc, fr
+
+    flat_c, flat_r = jax.lax.fori_loop(
+        jnp.int32(0), valid.sum().astype(jnp.int32), commit, (flat_c, flat_r))
+    return flat_c.reshape(c_cache.shape), flat_r.reshape(r_cache.shape)
 
 
 def _requantize_pages(flat, fresh, lslot, new_scale_shape):

@@ -6,10 +6,11 @@ built TPU-first — bf16 compute, flash-attention Pallas kernel, GSPMD
 sharding plan over the hybrid mesh (dp/mp/pp/sep axes).
 """
 
-from . import cohere2_moe, dit, gpt, llama  # noqa: F401
+from . import cohere2_moe, dit, gpt, llama, sarvam_mla  # noqa: F401
 from .cohere2_moe import Cohere2MoeConfig, CohereMoeForCausalLM  # noqa: F401
 from .dit import DiT, DiTConfig, DiTTrainStep, GaussianDiffusion  # noqa: F401
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_shard_plan,
 )
 from .gpt import GPTConfig, GPTForCausalLM  # noqa: F401
+from .sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM  # noqa: F401

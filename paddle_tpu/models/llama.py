@@ -419,18 +419,27 @@ def moe_mlp_forward_einsum(x, gate_w, w_gate, w_up, w_down, *, top_k,
     return y.reshape(B, S, H), aux, stats
 
 
-def _route_topk(xf, gate_w, k, score="softmax"):
+def _route_topk(xf, gate_w, k, score="softmax", bias=None, scale=1.0):
     """Shared top-k router: returns (gate weights [N, k], expert ids
     [N, k], GShard aux loss, first-choice load ce [E]).  Scores are the
     ``score`` function ("softmax" or "sigmoid") of the float32 logits; the
-    gates are the k largest, divided by their sum."""
+    gates are the k largest, divided by their sum.  With ``bias`` (float32
+    ``[E]``) the k chosen are those with the largest ``score + bias``: the
+    bias selects and is not in the gate.  ``scale`` multiplies the
+    normalised gates."""
     N = xf.shape[0]
     E = gate_w.shape[-1]
     logits = xf.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [N, E]
     probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
         else jax.nn.sigmoid(logits)
-    topv, topi = jax.lax.top_k(probs, k)
+    if bias is None:
+        topv, topi = jax.lax.top_k(probs, k)
+    else:
+        _, topi = jax.lax.top_k(probs + bias.astype(jnp.float32), k)
+        topv = jnp.take_along_axis(probs, topi, axis=-1)
     topv = topv / jnp.maximum(topv.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        topv = topv * jnp.float32(scale)
     me = probs.mean(axis=0)
     ce = jnp.zeros((E,), jnp.float32).at[topi[:, 0]].add(1.0) / N
     aux = E * jnp.sum(me * ce)

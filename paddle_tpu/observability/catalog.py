@@ -69,6 +69,14 @@ CATALOG: Dict[str, tuple] = {
         "rounded up to whole row tiles, at least one tile each; "
         "`moe_held_rows / moe_rows_laid_out` is the occupancy of the "
         "tiles that are multiplied"),
+    # ---- serving: what the pool holds of a token (PR 31) ----
+    "serving.kv_bytes_per_token": (
+        "gauge", "",
+        "pool bytes one cached token costs over all layers "
+        "(`pool_bytes / (num_pages x page_size)`): per-head K and V "
+        "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
+        "five latent layers of 512 + 64 in bf16; 16,384 for four layers "
+        "of 8 KV heads x 128)"),
     # ---- serving: per-phase step attribution (PR 10) ----
     "serving.step_ms": (
         "histogram", "phase=prefill|decode|spec_verify|fused_k|cow_copy"
@@ -512,9 +520,12 @@ SPANS: Dict[str, tuple] = {
         "length), `attn_rows` the query rows the paged kernel's row tiles "
         "cover for each KV head in one layer's call (every slot with work "
         "covers its `q_len x group` rows in whole tiles; "
-        "`kernels.paged_attention.attn_rows`), so `q_tokens x group / "
-        "attn_rows` is to attention what `q_tokens / gemm_rows` is to the "
-        "GEMMs, `slots` the batch B, `waiting` the queue behind it"),
+        "`kernels.paged_attention.attn_rows`; a latent stack's call has "
+        "one row of keys for all heads, so `group` is the number of query "
+        "heads and a slot covers `q_len x heads` rows), so `q_tokens x "
+        "group / attn_rows` is to attention what `q_tokens / gemm_rows` "
+        "is to the GEMMs, `slots` the batch B, `waiting` the queue behind "
+        "it"),
     "engine.admit": (
         "serving", "engine", "local", "admitted, waiting",
         "`_admit`: waiting requests into free slots, their pages and the "

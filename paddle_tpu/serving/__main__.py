@@ -28,7 +28,17 @@ _COHERE2_MOE_PRESETS = {
         num_hidden_layers=4, experts_held=16, expert_offset=0,
         vocab_size=262144 // 8),
 }
-_PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS)
+# sarvam_mla (models/sarvam_mla.py): the test size, and Sarvam-105B as one
+# chip of four that share each layer holds it (32 of the 128 experts, a
+# quarter of the vocabulary, the dense layer and four expert layers: 9.1 GB)
+_SARVAM_MLA_PRESETS = {
+    "sarvam_mla_tiny": lambda cfg: cfg.tiny(),
+    "sarvam_105b_ep4": lambda cfg: cfg.sarvam_105b(
+        num_hidden_layers=5, experts_held=32, expert_offset=0,
+        vocab_size=262144 // 4),
+}
+_PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS) \
+    + tuple(_SARVAM_MLA_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -137,6 +147,10 @@ def build_engine(args):
                                           CohereMoeForCausalLM)
         model = CohereMoeForCausalLM(
             _COHERE2_MOE_PRESETS[args.preset](Cohere2MoeConfig))
+    elif args.preset in _SARVAM_MLA_PRESETS:
+        from ..models.sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM
+        model = SarvamMlaForCausalLM(
+            _SARVAM_MLA_PRESETS[args.preset](SarvamMlaConfig))
     else:
         from ..models.llama import LlamaConfig, LlamaForCausalLM
         model = LlamaForCausalLM(getattr(LlamaConfig, args.preset)())

@@ -319,3 +319,150 @@ def test_layer_scan_reads_expert_banks_where_they_lie(one_chip, monkeypatch,
         assert any("dynamic-slice" in line for line in makes_a_bank), \
             makes_a_bank
         assert temp >= bank_bytes
+
+
+# ---- sarvam-105b as one chip of four holds it (PR 31) ----
+# latent attention: 64 query heads over ONE row [c (512) | k_r (64)] of the
+# pool, T = 64 a 4,096-row query block; the cell's engine: 32 slots, 16,640
+# positions in pages of 16 (a table 1,040 wide), five layers
+SV_HEADS, SV_RANK, SV_ROPE, SV_B, SV_TABLE, SV_L = 64, 512, 64, 32, 1040, 5
+
+_LATENT_CALL = re.compile(
+    r"%(ragged_paged_attention_latent)[.\d]* = (\w+\[[\d,]+\])\{[^}]*\} "
+    r"custom-call\(.*operand_layout_constraints=\{(\w+\[[\d,]+\])")
+
+
+def _latent_shapes(T, n_pages):
+    i32 = jnp.int32
+    return [((SV_B, T, SV_HEADS, SV_RANK), BF16),
+            ((SV_B, T, SV_HEADS, SV_ROPE), BF16),
+            ((SV_L, n_pages, PAGE, SV_RANK), BF16),
+            ((SV_L, n_pages, PAGE // 2, 2 * SV_ROPE), BF16),
+            ((SV_B, SV_TABLE), i32), ((SV_B,), i32), ((SV_B,), i32),
+            ((SV_B, T, SV_RANK), BF16), ((SV_B, T, SV_ROPE), BF16),
+            ((), i32)]
+
+
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_latent_attention_sarvam_shapes_compile(one_chip, T):
+    """The whole latent pool (33,280 pages: the compressed rows and the
+    rotary keys two tokens a row, each page whole tiles of both) read at a
+    traced layer; a 4,096-row block in 16 row tiles over KV blocks of 64
+    pages fits the chip's VMEM at the limit the wrapper asks for; and the
+    call is what the benchmark matches on: the name, the block table
+    first, ONE result ``[slots, T x heads, rank]``."""
+    assert pa.kernel_geometry_error(PAGE, 0, latent=(SV_RANK, SV_ROPE)) \
+        is None
+    compiled = _compile(
+        one_chip,
+        lambda qc, qr, c, r, bt, cl, ql, cn, rn, ly:
+        pa._pallas_ragged_paged_attention_latent(
+            qc, qr, c, r, bt, cl, ql, cn, rn, interpret=False, scale=0.1352,
+            layer=ly),
+        *_latent_shapes(T, 33280))
+    calls = _LATENT_CALL.findall(compiled.as_text())
+    rows = max(8, T * SV_HEADS)
+    assert calls == [("ragged_paged_attention_latent",
+                      f"bf16[32,{rows},512]", "s32[32,1040]")]
+    if T == 64:
+        assert rows == 4096 and pa.row_tile(T, SV_HEADS) == 256
+
+
+def test_a_64_wide_pool_is_refused_by_the_compiler_and_by_the_rule(one_chip):
+    """ROADMAP M12: rows half a lane tile wide cannot be sliced out of a
+    pool (per-head pages of ``head_dim`` 64, or the rotary keys one token a
+    row): the compiler's reason is the one ``kernel_geometry_error`` gives,
+    which is why the latent pool keeps two tokens' rotary keys a row."""
+    assert "multiple of 128" in pa.kernel_geometry_error(PAGE, 64)
+    i32, d = jnp.int32, 64
+    cache = ((KVH, N_PAGES, PAGE, d), BF16)
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\)"):
+        _compile(one_chip,
+                 lambda q, k, v, bt, cl, ql, kn, vn:
+                 pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn,
+                                                   vn, False),
+                 ((BATCH, 1, QH, d), BF16), cache, cache,
+                 ((BATCH, W), i32), ((BATCH,), i32), ((BATCH,), i32),
+                 ((BATCH, 1, KVH, d), BF16), ((BATCH, 1, KVH, d), BF16))
+
+
+def test_sarvam_step_program_compiles_at_published_widths(one_chip,
+                                                          monkeypatch):
+    """The packed T = 64 step program of the cell's engine (32 slots, 512
+    GEMM rows) at the published widths, on abstract parameters: the leading
+    dense layer unrolled before a scan over four expert layers, two latent
+    calls (one in the leading layer, one in the scan's body) and three
+    grouped GEMMs on each layer's own banks, no copy of a bank or of the
+    pool, and it fits the chip beside its 9.07 GB of weights (the pool here
+    is a sixteenth of the cell's: its size moves no operation but the
+    commit's bounds)."""
+    from paddle_tpu.inference import generation as gen
+    from paddle_tpu.models.sarvam_mla import (SarvamMlaConfig,
+                                              SarvamMlaForCausalLM,
+                                              layer_leaves)
+
+    class OnTpu:
+        """``jax`` as the kernel's entry point sees it on a chip."""
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def default_backend():
+            return "tpu"
+
+    monkeypatch.setattr(pa, "jax", OnTpu())
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    cfg = SarvamMlaConfig.sarvam_105b(
+        num_hidden_layers=SV_L, experts_held=32, vocab_size=65536,
+        max_position_embeddings=16640)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    class Abstract:
+        config = cfg
+        decoder_spec = SarvamMlaForCausalLM.decoder_spec
+
+        def serving_params(self):
+            H, V, n = cfg.hidden_size, cfg.vocab_size, SV_L - 1
+            experts = {
+                name: tuple(sds(shape, dt) for _ in range(n))
+                if name in gen.EXPERT_BANKS else sds((n,) + tuple(shape), dt)
+                for name, shape, _, dt in layer_leaves(cfg, False)}
+            return {"embed": sds((V, H), BF16), "norm": sds((H,), BF16),
+                    "head": sds((H, V), BF16),
+                    "leading": ({name: sds(shape, dt) for name, shape, _, dt
+                                 in layer_leaves(cfg, True)},),
+                    "blocks": (experts,)}
+
+    g = gen.LlamaGenerator(Abstract(), max_batch=SV_B, max_seq_len=16640,
+                           page_size=PAGE, prefill_bucket=64, num_pages=2080)
+    params = g.params
+    held = sum(int(jnp.prod(jnp.asarray(a.shape))) * a.dtype.itemsize
+               for a in jax.tree_util.tree_leaves(params))
+    assert abs(held / 2 - 4.535e9) < 1e6          # parameters, bf16
+    T, rows = 64, g.row_buckets(64)[0]
+    assert rows == 512
+    i32, key = jnp.int32, jax.random.key(0)
+    vec = lambda dt: sds((SV_B,), dt)             # noqa: E731
+    ops = (params, tuple(sds(a.shape, a.dtype) for a in g.cache.arrays),
+           sds((SV_B, T), i32), vec(i32), vec(i32), vec(jnp.bool_),
+           vec(jnp.bool_), vec(jnp.bool_), vec(i32), vec(i32),
+           sds((SV_B, g.pages_per_seq), i32),
+           jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    compiled = g._step_jit(gen.GenerationConfig(), T, False, rows) \
+        .lower(*ops).compile()
+    text = compiled.as_text()
+    assert len(_LATENT_CALL.findall(text)) == 2
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        2 + 3 * (SV_L - 1)
+    makes_a_bank = [
+        line.strip()[:120] for line in text.splitlines()
+        if re.search(r"= bf16\[32,(4096,2048|2048,4096)\]\S* [a-z\-]+\(", line)
+        and not re.search(r" (parameter|get-tuple-element)\(", line)]
+    assert not makes_a_bank, makes_a_bank
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30
+    assert mem.alias_size_in_bytes == sum(
+        a.size * a.dtype.itemsize for a in g.cache.arrays)   # pool in place
