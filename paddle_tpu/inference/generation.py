@@ -176,8 +176,10 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
     family: ``moe`` (``models.decoder_spec.MoeSpec``) states the router
     (softmax or sigmoid scores in float32, top-k, renormalised or not),
     the experts held here and the shared experts.  Returns ``(out, rows)``,
-    ``rows`` None or, where this chip holds a share of the experts,
-    int32 ``[entries that fell on held experts, rows laid out for them]``.
+    ``rows`` int32 ``[entries that fell on held experts, rows of the tiles
+    laid out for them]`` where this chip holds a share of the experts or
+    the grouped path runs on one device (every expert held: the entries of
+    the rows that hold a token), else None.
 
     - grouped (``moe.dispatch == "grouped"``): the expert-sorted ragged-GEMM
       path shared with training (``models.llama._grouped_ffn``) — each
@@ -195,8 +197,13 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
     experts' tiles, which alone are multiplied: ``gmm(live_tiles=)``) and
     a gate keeps the value it has over all ``top_k`` chosen.  Dropless:
     every entry of a held expert is computed, whatever their number.
-    ``live`` (bool, one a row of ``y``): rows that hold no token (the
-    padding of a step's row bucket) are routed nowhere and counted nowhere.
+
+    ``live`` (bool, one a row of ``y``; both grouped arms on one device,
+    not the tensor-parallel one): rows that hold no token (the padding of
+    a step's row bucket, the empty places of the dense grid) are routed
+    nowhere and counted nowhere.  Where every expert is held their entries
+    are dropped in the plan (``masked_dispatch_plan``) and the tile
+    table names the tiles left without rows, which ``gmm`` skips.
 
     ``layer`` (an int32 device scalar; the engine's layer scan): ``lp``'s
     three expert banks are then tuples, one ``[E, ...]`` bank for each
@@ -243,7 +250,8 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
         if live is not None:
             own = jnp.logical_and(own, live.reshape(N, 1))
     if moe.dispatch == "grouped":
-        from ..kernels.grouped_matmul import sorted_dispatch_plan
+        from ..kernels.grouped_matmul import (masked_dispatch_plan,
+                                              sorted_dispatch_plan)
 
         # decode batches carry a handful of rows: shrink the row tile to
         # the 8-row sublane multiple that covers them (same math, less pad)
@@ -304,10 +312,17 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
                                   live_rows])
         else:
             with jax.named_scope("experts"):
-                inv, pos, tg = sorted_dispatch_plan(
-                    topi.reshape(N * top_k), E, bm)
-                out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn(
-                    xf, wg, wu, wd, topv, inv, pos, tg, E, top_k, bm))
+                # every expert is held: only the entries of rows without
+                # a token are dropped, and the table names the dead tiles
+                real = jnp.ones((N, top_k), bool) if live is None else \
+                    jnp.broadcast_to(live.reshape(N, 1), (N, top_k))
+                inv, pos, tg, live_tiles = masked_dispatch_plan(
+                    topi.reshape(N * top_k), real.reshape(N * top_k), E, bm)
+                out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn_fwd(
+                    xf, wg, wu, wd, topv * real, inv, pos, tg, E, top_k, bm,
+                    dead_in_table=True)[0])
+                rows = jnp.stack([real.sum().astype(jnp.int32),
+                                  live_tiles * bm])
     else:
         with jax.named_scope("router"):
             comb = jnp.zeros((N, E), jnp.float32).at[
@@ -431,9 +446,11 @@ class LlamaGenerator:
             tp > 1 and moe is not None and moe.dispatch == "grouped"
             and moe.top_k <= 2 and not moe.partial
             and moe.num_experts % tp == 0) else None
-        # a share of the experts: the step also returns how many entries
-        # fell on held experts and the rows laid out for them
-        self.counts_moe_rows = moe is not None and moe.partial
+        # where ``_moe_ffn`` counts them the step also returns how many
+        # entries fell on held experts and the rows laid out for them
+        self.counts_moe_rows = moe is not None and (
+            moe.partial or (moe.dispatch == "grouped"
+                            and not self._moe_shards))
         self._layers_by_window = tuple(Counter(c.windows).items())
         dtype = str(model.config.dtype)
         if cache_dtype is None:
@@ -663,7 +680,7 @@ class LlamaGenerator:
         mixed-mode ``ragged_paged_attention`` kernel (the step's own K/V
         rows fold in causally), commit all layers' fresh KV in ONE pass,
         and return the final-norm hidden states for ALL T
-        positions, the updated pool and (a share of the experts only,
+        positions, the updated pool and (where ``_moe_ffn`` counts them,
         else None) the step's MoE row counts.  Callers own freeze semantics, sampling and
         bookkeeping — this core is shared verbatim by the plain step, the
         T=K speculative verify step and the fused K-step decode loop, so
@@ -713,8 +730,8 @@ class LlamaGenerator:
         pos = positions[:, None].astype(jnp.int32) + offs[None, :]   # [B, T]
         pos_c = jnp.minimum(pos, self.max_seq_len - 1)
         page_ids = jnp.take_along_axis(block_tables, pos_c // page, axis=1)
-        valid = jnp.logical_and(offs[None, :] < ql[:, None],
-                                pos < self.max_seq_len)
+        has_token = offs[None, :] < ql[:, None]
+        valid = jnp.logical_and(has_token, pos < self.max_seq_len)
         slots = jnp.where(valid, page_ids * page + pos_c % page,
                           -1).reshape(B * T)
 
@@ -792,8 +809,14 @@ class LlamaGenerator:
             or a dense gated MLP where the spec has none or the place says
             so: (output, MoE rows or None)."""
             if moe is not None and not kind.dense_ffn:
+                # the rows that hold a token, as a packed step's ``live``:
+                # a token past ``max_seq_len`` has no place in the pool and
+                # is routed all the same (its slot's logits are read).  A
+                # share's arm is handed ``valid``, as it always was: its
+                # step programs are the ones its cells were measured on
+                grid = valid if moe.partial else has_token
                 return _moe_ffn(y, lp, moe, mp_shards=self._moe_shards,
-                                live=live if packed else valid,
+                                live=live if packed else grid,
                                 layer=bank_layer)
             act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
                 (y @ lp["mlp.up_proj.weight"])
@@ -1333,10 +1356,10 @@ class _ServingMetrics:
         self.accept_len = m.histogram(
             "serving.spec.accept_len",
             bounds=[0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0])
-        # a share of the experts (held < the router's width): one
-        # observation a step, the sum over the layers, folded in at the
-        # drain — (token, choice) entries that fell on held experts and
-        # the rows the grouped GEMM laid out for them
+        # one observation a step, the sum over the layers, folded in at
+        # the drain — (token, choice) entries that fell on held experts
+        # (every expert is held unless the chip holds a share) and the
+        # rows of the tiles the grouped GEMM laid out for them
         rows_bounds = [float(2 ** i) for i in range(4, 21)]
         self.moe_held_rows = m.histogram("serving.moe_held_rows",
                                          bounds=rows_bounds)
@@ -2110,7 +2133,7 @@ class ContinuousBatchingEngine:
     def _drain_pending(self) -> tuple:
         """The pending window to the host and into its requests: (requests
         retired, tokens delivered, entries that fell on held experts: 0
-        unless this chip holds a share of them)."""
+        where the step does not count them)."""
         # per-array host transfers, NOT a device-side stack: the pending
         # window length varies (partial windows at tail/run end) and a
         # jnp.stack would compile one executable per distinct length —

@@ -168,12 +168,12 @@ def _tune(kind, key, M, K, N, E, bm, dtype):
 
 # ------------------------------------------------------------------ gmm ---
 
-def _gmm_kernel(*refs, nk, trans_rhs, ragged):
+def _gmm_kernel(*refs, nk, trans_rhs, skips):
     from jax.experimental import pallas as pl
 
-    # scalar prefetch first: tile_groups (the index maps' alone), then the
-    # live-tile count of a ragged call
-    live_ref = refs[1] if ragged else None
+    # scalar prefetch first: tile_groups, then the live-tile count of a
+    # call that is handed one
+    groups_ref, *live_ref = refs[:-4]
     lhs_ref, rhs_ref, out_ref, acc_ref = refs[-4:]
     # read outside every pl.when: interpret mode has no rule for it inside
     i, kk = pl.program_id(0), pl.program_id(2)
@@ -193,16 +193,17 @@ def _gmm_kernel(*refs, nk, trans_rhs, ragged):
         def _flush():
             out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
-    if ragged:
-        # a row tile past the live ones holds no expert's rows: nothing is
-        # multiplied and (the index maps park on one block) nothing moves
-        pl.when(i < live_ref[0])(tile)
-    else:
+    if not skips:
         tile()
+    else:
+        # a dead row tile holds no expert's rows: nothing is multiplied and
+        # (the index maps park on one block) nothing moves.  The count says
+        # which they are where there is one, else the table
+        pl.when(i < live_ref[0][0] if live_ref else groups_ref[i] >= 0)(tile)
 
 
 def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
-        interpret=None, live_tiles=None):
+        interpret=None, live_tiles=None, dead_in_table=False):
     """Grouped matmul: ``out[m, :] = lhs[m, :] @ rhs[tile_groups[m//bm]]``.
 
     lhs: [M, C] with rows grouped by expert, group spans bm-aligned.
@@ -211,13 +212,19 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
     bn/bk: explicit tiles win over the autotune cache and the sweep
     flags (see ``_resolve_tiles``).  Returns [M, O] in lhs.dtype.
 
+    The row tiles after the last expert's rows are dead: skipped, their
+    blocks neither fetched nor multiplied, their rows of the result left
+    unwritten (callers never read them: ``take_sentinel_rows`` on live
+    positions).  At least one tile must be live.  A call is told which
+    they are in one of two ways, and multiplies every tile if in neither:
+
     live_tiles: optional int32 scalar (a device value) — only the first
     ``live_tiles`` row tiles hold rows some expert owns (a layer that
     holds a share of the experts sorts the entries of the others last).
-    The tiles after them are skipped: their blocks are neither fetched
-    nor multiplied, and their rows of the result are left unwritten
-    (callers never read them: ``take_sentinel_rows`` on live positions).
-    At least one tile must be live.
+
+    dead_in_table: ``tile_groups`` itself says so (a plan made with
+    ``masked_dispatch_plan``): a dead tile's entry is
+    ``-(last live tile) - 1``.  The operands stay a call's without either.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -229,29 +236,47 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         raise ValueError(f"M ({M}) must be a multiple of bm ({bm})")
     mode = _mode(interpret)
     if mode is None:
-        return _gmm_reference(lhs, rhs, tile_groups, bm=bm,
+        # a dead tile's rows are never read: any expert serves
+        return _gmm_reference(lhs, rhs, jnp.maximum(tile_groups, 0)
+                              if dead_in_table else tile_groups, bm=bm,
                               trans_rhs=trans_rhs)
     bn, bk = _resolve_tiles("gmm_t" if trans_rhs else "gmm", M, C, O, E,
                             bm, lhs.dtype, bn, bk, mode)
     nk = C // bk
 
+    if live_tiles is not None and dead_in_table:
+        raise ValueError("gmm: live_tiles and dead_in_table both name the "
+                         "dead tiles; pass one")
     scalars = [tile_groups.astype(jnp.int32)]
-    ragged = live_tiles is not None
-    if ragged:
+    if live_tiles is not None:
         scalars.append(jnp.asarray(live_tiles, jnp.int32).reshape(1))
+    skips = live_tiles is not None or dead_in_table
+    if skips:
         nj = O // bn
 
         def park(fn):
-            """``fn``'s block for a live tile; for every tile after them
-            the last live tile's last block, so that consecutive dead
-            steps name one block and the pipeline moves nothing."""
-            def index_map(i, j, k, g, live):
+            """``fn``'s block for a live tile; for every dead tile the last
+            live tile's last block, so that consecutive dead steps name one
+            block and the pipeline moves nothing."""
+            def index_map(i, j, k, g, *live):
                 # np.int32 constants: a bare python int is an i64 under
                 # x64 mode, and the convert breaks Mosaic lowering
-                dead = i >= live[0]
-                i_ = jnp.minimum(i, live[0] - np.int32(1))
-                return fn(i_, jnp.where(dead, np.int32(nj - 1), j),
-                          jnp.where(dead, np.int32(nk - 1), k), g)
+                if live:
+                    is_dead = i >= live[0][0]
+                    i_ = jnp.minimum(i, live[0][0] - np.int32(1))
+                    pick = functools.partial(jnp.where, is_dead)
+                else:
+                    # bare primitives, not ``jnp.where`` (a traced jit of
+                    # its own): an index map is traced three times a call,
+                    # in every call of every layer of every step program.
+                    # The count's form keeps it: its programs are the ones
+                    # two cells were measured on (ROADMAP D15)
+                    gi = g[i]
+                    is_dead = jax.lax.lt(gi, np.int32(0))
+                    pick = functools.partial(jax.lax.select, is_dead)
+                    i_ = pick(jax.lax.sub(np.int32(-1), gi), i)
+                return fn(i_, pick(np.int32(nj - 1), j),
+                          pick(np.int32(nk - 1), k), g)
             return index_map
     else:
         def park(fn):
@@ -271,16 +296,16 @@ def gmm(lhs, rhs, tile_groups, *, bm=512, bn=None, bk=None, trans_rhs=False,
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     kernel = functools.partial(_gmm_kernel, nk=nk, trans_rhs=trans_rhs,
-                               ragged=ragged)
+                               skips=skips)
     return pl.pallas_call(
         kernel,
         name="gmm",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, O), lhs.dtype),
-        # the dead tiles of a ragged call all park on one result block,
-        # which only consecutive steps of one core may revisit
+        # the dead tiles all park on one result block, which only
+        # consecutive steps of one core may revisit
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary" if ragged else "parallel",
+            dimension_semantics=("arbitrary" if skips else "parallel",
                                  "parallel", "arbitrary")),
         interpret=(mode == "interpret"),
     )(*scalars, lhs, rhs)
@@ -431,6 +456,38 @@ def capacity_dispatch_plan(expert_ids, gate_vals, num_groups, capacity):
     return inv, slot, val_flat * keep.astype(jnp.float32), keep
 
 
+def _dispatch_plan(expert_ids, num_groups, bm, real=None):
+    """The two plans below: their three results and each expert's tiles."""
+    F = expert_ids.shape[0]
+    M = -(-F // bm) * bm + num_groups * bm
+    i32 = jnp.int32
+    expert_ids = expert_ids.astype(i32)
+    if real is not None:
+        # a dropped entry sorts behind every expert's and is counted nowhere
+        expert_ids = jnp.where(real, expert_ids, num_groups)
+    order = jnp.argsort(expert_ids, stable=True)
+    e_sorted = jnp.take(expert_ids, order)
+    counts = jnp.bincount(expert_ids, length=num_groups)
+    tiles = jnp.maximum(-(-counts // bm), 1)
+    padded = tiles * bm
+    starts = jnp.concatenate(
+        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
+    offsets = jnp.concatenate(
+        [jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)[:-1]])
+    r = jnp.arange(F, dtype=i32)
+    dest = (offsets[e_sorted] + (r - starts[e_sorted])).astype(i32)
+    if real is not None:
+        # row M is out of bounds: the scatter below drops it
+        dest = jnp.where(e_sorted < num_groups, dest, M)
+    inv_flat = jnp.full((M,), F, i32).at[dest].set(order.astype(i32))
+    pos = jnp.zeros((F,), i32).at[order].set(dest)
+    ends = jnp.cumsum(padded)
+    tile_groups = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(M // bm) * bm, side="right"),
+        num_groups - 1).astype(i32)
+    return inv_flat, pos, tile_groups, tiles
+
+
 def sorted_dispatch_plan(expert_ids, num_groups, bm):
     """Build the gather maps for a grouped-GEMM dispatch.
 
@@ -450,27 +507,30 @@ def sorted_dispatch_plan(expert_ids, num_groups, bm):
     scatter-adds appear anywhere in the MoE step (the scatters here are
     1 int32 word per row, vectorized).
     """
-    F = expert_ids.shape[0]
-    M = -(-F // bm) * bm + num_groups * bm
-    i32 = jnp.int32
-    expert_ids = expert_ids.astype(i32)
-    order = jnp.argsort(expert_ids, stable=True)
-    e_sorted = jnp.take(expert_ids, order)
-    counts = jnp.bincount(expert_ids, length=num_groups)
-    padded = jnp.maximum(-(-counts // bm), 1) * bm
-    starts = jnp.concatenate(
-        [jnp.zeros((1,), counts.dtype), jnp.cumsum(counts)[:-1]])
-    offsets = jnp.concatenate(
-        [jnp.zeros((1,), padded.dtype), jnp.cumsum(padded)[:-1]])
-    r = jnp.arange(F, dtype=i32)
-    dest = (offsets[e_sorted] + (r - starts[e_sorted])).astype(i32)
-    inv_flat = jnp.full((M,), F, i32).at[dest].set(order.astype(i32))
-    pos = jnp.zeros((F,), i32).at[order].set(dest)
-    ends = jnp.cumsum(padded)
-    tile_groups = jnp.minimum(
-        jnp.searchsorted(ends, jnp.arange(M // bm) * bm, side="right"),
-        num_groups - 1).astype(i32)
-    return inv_flat, pos, tile_groups
+    return _dispatch_plan(expert_ids, num_groups, bm)[:3]
+
+
+def masked_dispatch_plan(expert_ids, real, num_groups, bm):
+    """``sorted_dispatch_plan`` over the entries that belong to a token.
+
+    real: [F] bool (a serving step's row bucket holds rows without a
+    token).  The other entries are dropped before rows are laid out: such
+    an entry takes no row and its ``pos`` is the sentinel M
+    (``take_sentinel_rows`` reads zero).  M stays what it is without a
+    mask.  Returns (inv_flat, pos, tile_groups, live_tiles): the row tiles
+    after the last expert's are dead and the table says so itself,
+    ``tile_groups[t] = -(last live tile) - 1``, which is what
+    ``gmm(dead_in_table=True)`` skips them by; ``live_tiles`` counts the
+    tiles before them, an int32 device scalar.
+    """
+    inv_flat, pos, tile_groups, tiles = _dispatch_plan(
+        expert_ids, num_groups, bm, real)
+    # the sum of the experts' tiles, not ``ends[-1] // bm``: under x64 a
+    # division of a device int64 compiles for a second on the chip's host
+    live_tiles = tiles.sum().astype(jnp.int32)
+    tile_groups = jnp.where(jnp.arange(tile_groups.shape[0]) < live_tiles,
+                            tile_groups, -live_tiles)
+    return inv_flat, pos, tile_groups, live_tiles
 
 
 # ------------------------------------------------------ differentiable ---

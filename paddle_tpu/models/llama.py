@@ -480,11 +480,13 @@ def _padded_rows(buf, tok_of, scale=None):
 
 
 def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
-                     tile_groups, E, k, bm, live_tiles=None):
-    """The forward pass and its residuals.  ``live_tiles`` (serving a share
-    of the experts, forward only): the row tiles after the first
-    ``live_tiles`` hold no held expert's rows and are not multiplied
-    (``gmm``); their ``pos`` entries are the sentinel, so they read zero."""
+                     tile_groups, E, k, bm, live_tiles=None,
+                     dead_in_table=False):
+    """The forward pass and its residuals.  Serving, forward only: ``gmm``
+    is told which row tiles hold no expert's rows and are not multiplied,
+    by ``live_tiles`` (a share of the experts) or by the table itself
+    (``dead_in_table``: a plan that dropped the rows without a token);
+    their ``pos`` entries are the sentinel, so they read zero."""
     from ..kernels.grouped_matmul import (gmm, take_sentinel_rows,
                                           validate_tile_flags)
 
@@ -493,11 +495,16 @@ def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     validate_tile_flags(H, w_gate.shape[2])
     xz = jnp.concatenate([xf, jnp.zeros((1, H), xf.dtype)], axis=0)
     tok_of = jnp.where(inv_flat < N * k, inv_flat // k, N)
-    lt = {} if live_tiles is None else {"live_tiles": live_tiles}
-    h_g = gmm(_padded_rows(xz, tok_of), w_gate, tile_groups, bm=bm, **lt)
-    h_u = gmm(_padded_rows(xz, tok_of), w_up, tile_groups, bm=bm, **lt)
+    dead = dict(live_tiles=live_tiles, dead_in_table=dead_in_table)
+    h_g = gmm(_padded_rows(xz, tok_of), w_gate, tile_groups, bm=bm, **dead)
+    h_u = gmm(_padded_rows(xz, tok_of), w_up, tile_groups, bm=bm, **dead)
+    # over all M rows: a dead tile's rows of ``h_g`` / ``h_u`` were never
+    # written and may hold anything, NaN included.  Nothing reads what comes
+    # of them: the down projection skips the same tiles, and ``pos`` points
+    # at live rows or the sentinel alone (the plan's scatter; held by
+    # tests/test_moe_dispatch_parity.py::test_rows_without_a_token_...)
     a = jax.nn.silu(h_g) * h_u
-    o = gmm(a, w_down, tile_groups, bm=bm, **lt)          # [M, H]
+    o = gmm(a, w_down, tile_groups, bm=bm, **dead)        # [M, H]
     # combine gather: sentinel pos >= M (dropped entries) reads zero
     o_pos = take_sentinel_rows(o, pos).reshape(N, k, H)
     y = (o_pos * gates[..., None].astype(o.dtype)).sum(axis=1)

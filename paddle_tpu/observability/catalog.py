@@ -55,20 +55,24 @@ CATALOG: Dict[str, tuple] = {
         "gauge", "", "live waiting-queue depth"),
     "serving.batch_occupancy": (
         "histogram", "", "busy slots / max_batch per step"),
-    # ---- serving: a chip that holds a share of the experts (PR 27) ----
+    # ---- serving: the rows of the expert GEMMs (PR 27; PR 33) ----
     "serving.moe_held_rows": (
         "histogram", "",
         "(token, choice) entries of one plain step that fell on experts "
-        "this chip holds, summed over the layers (a model whose "
-        "`MoeSpec.held` is under the router's width; counted on the "
-        "device, read at the drain that exists)"),
+        "this chip holds, summed over the layers: held or, where every "
+        "expert is held (the grouped path on one device), live: the "
+        "entries of the rows that hold a token (a model whose "
+        "`MoeSpec.held` is under the router's width counts the entries "
+        "routed here; counted on the device, read at the drain that "
+        "exists)"),
     "serving.moe_rows_laid_out": (
         "histogram", "",
         "rows the grouped expert GEMM laid out for those entries in the "
         "same step, summed over the layers: every held expert's entries "
-        "rounded up to whole row tiles, at least one tile each; "
-        "`moe_held_rows / moe_rows_laid_out` is the occupancy of the "
-        "tiles that are multiplied"),
+        "rounded up to whole row tiles, at least one tile each (held or, "
+        "where every expert is held, live: the tiles behind them are "
+        "skipped); `moe_held_rows / moe_rows_laid_out` is the occupancy "
+        "of the tiles that are multiplied"),
     # ---- serving: what the pool holds of a token (PR 31) ----
     "serving.kv_bytes_per_token": (
         "gauge", "",
@@ -550,8 +554,9 @@ SPANS: Dict[str, tuple] = {
         "serving", "engine", "local", "steps, tokens, held_rows",
         "`_drain`: `steps` dispatches closed, `tokens` delivered to "
         "their requests, `held_rows` the (token, choice) entries of those "
-        "steps that fell on experts held here (0 unless the chip holds a "
-        "share of them)"),
+        "steps that fell on experts held here (0 where the step does not "
+        "count them: a dense model, the dense mixture with every expert "
+        "held, the tensor-parallel grouped arm)"),
     "engine.drain.wait": (
         "serving", "engine", "local", "",
         "the `np.asarray` block of the drain: the only place the host "

@@ -321,6 +321,70 @@ def test_layer_scan_reads_expert_banks_where_they_lie(one_chip, monkeypatch,
         assert temp >= bank_bytes
 
 
+# ---- both grouped calls as the benchmark knows them (PR 33) ----
+# ``gmm_roofline_pct.batch`` (the Mixtral cell) and
+# ``gmm_held_roofline_pct.batch`` (the two cells that hold a share) know
+# their calls by the first operands; a call that changes them reads ``null``
+# in a cell that lists the metric and the driver refuses that run
+def _gmm_calls_as_the_trace_names_them(compiled):
+    """The compiled program's ``gmm`` custom calls, each parsed as the
+    benchmark parses a device operation's name.  A trace's name has every
+    operand's shape where the compiled text has ``%name``: they are put
+    there from the call's ``operand_layout_constraints``."""
+    from chipbench.harness.trace_reduce import parse_op
+    call = re.compile(r"\s*(?:ROOT )?(%gmm[.\d]* = .* custom-call\()[^)]*(\),"
+                      r".* operand_layout_constraints=\{(.*?\})\}.*)")
+    ops = [parse_op(m.group(1) + m.group(3) + m.group(2), 0.0, 1.0)
+           for m in map(call.match, compiled.as_text().splitlines()) if m]
+    assert ops and all(op.is_kernel for op in ops)
+    return ops
+
+
+@pytest.mark.parametrize("arm", ["whole_bank", "held"])
+def test_grouped_calls_keep_the_operands_their_yardsticks_match(
+        one_chip, monkeypatch, arm):
+    """The whole-bank arm (every expert held; dead tiles named by the tile
+    table's negative entries, which Mosaic takes in an index map) matches
+    ``grouped_matmul`` alone, priced at the ``F`` entries laid out; the
+    held arm (``live_tiles`` second) matches ``grouped_matmul_held``
+    alone."""
+    from chipbench.kernels import grouped_matmul as whole
+    from chipbench.kernels import grouped_matmul_held as held
+    from paddle_tpu.inference.generation import EXPERT_BANKS, _moe_ffn
+    from paddle_tpu.models.decoder_spec import MoeSpec
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    if arm == "whole_bank":        # Mixtral 8x7B, a dense 2,048-row step
+        moe = MoeSpec(num_experts=E, top_k=2, dispatch="grouped", block_m=BM)
+        inter = MX_I
+    else:                          # Command A+, 16 of 128 experts here
+        moe = MoeSpec(num_experts=128, top_k=8, score="sigmoid", held=16,
+                      offset=32, dispatch="grouped", block_m=128)
+        inter = HIDDEN
+    up, down = (moe.held, HIDDEN, inter), (moe.held, inter, HIDDEN)
+
+    def ffn(h, live, router, *banks):
+        lp = {"mlp.gate.weight": router, **dict(zip(EXPERT_BANKS, banks))}
+        return _moe_ffn(h, lp, moe, live=live)
+
+    compiled = _compile(
+        one_chip, ffn, ((1, MX_TOKENS, HIDDEN), BF16),
+        ((MX_TOKENS,), jnp.bool_), ((HIDDEN, moe.num_experts), BF16),
+        (up, BF16), (up, BF16), (down, BF16))
+    calls = _gmm_calls_as_the_trace_names_them(compiled)
+    assert len(calls) == 3
+    F = MX_TOKENS * moe.top_k
+    for op in calls:
+        if arm == "whole_bank":
+            got = whole.match(op)
+            assert held.match(op) is None
+            assert (got["rows"], got["rows_laid_out"]) == (F, F + E * BM)
+        else:
+            got = held.match(op)
+            assert whole.match(op) is None
+            assert got["rows_laid_out"] == F + 17 * 128
+        assert (got["block_m"], got["experts"]) == (moe.block_m, moe.held)
+
+
 # ---- sarvam-105b as one chip of four holds it (PR 31) ----
 # latent attention: 64 query heads over ONE row [c (512) | k_r (64)] of the
 # pool, T = 64 a 4,096-row query block; the cell's engine: 32 slots, 16,640

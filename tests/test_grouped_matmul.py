@@ -182,6 +182,62 @@ class TestDispatchPlan:
         assert pos[0] < pos[2] < pos[4]  # expert-1 entries
 
 
+def _plan_by_hand(ids, E, bm, real):
+    """The plan row by row: the real entries of expert 0 in arrival order,
+    padded to whole tiles and to at least one, then expert 1's, ..."""
+    F = len(ids)
+    M = -(-F // bm) * bm + E * bm
+    inv, pos, tg = np.full(M, F), np.full(F, M), []
+    row = 0
+    for e in range(E):
+        mine = [f for f in range(F) if real[f] and ids[f] == e]
+        for i, f in enumerate(mine):
+            inv[row + i], pos[f] = f, row + i
+        tiles = max(-(-len(mine) // bm), 1)
+        tg += [e] * tiles
+        row += tiles * bm
+    return inv, pos, tg, M
+
+
+MASKS = {
+    "all_live": lambda F, k: np.ones(F, bool),
+    "one_token_live": lambda F, k: np.arange(F) // k == 3,
+    "a_third_live": lambda F, k: (np.arange(F) // k) % 3 == 0,
+    "none_live": lambda F, k: np.zeros(F, bool),
+}
+
+
+@pytest.mark.parametrize("ids_of", ["spread", "one_expert"])
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("bm", [8, 64, 512])
+def test_plan_with_a_mask_lays_out_the_real_entries_alone(bm, mask, ids_of):
+    """``masked_dispatch_plan``: a dropped entry takes no row and its
+    ``pos`` is the sentinel M; every expert still owns a tile; the tiles
+    after the last expert's rows name the last live tile, negated; the
+    fourth result counts the live ones.  ``sorted_dispatch_plan`` (no
+    mask): the three results it has always had."""
+    E, k, F = 8, 2, 2 * 296
+    rng = np.random.default_rng(bm)
+    ids = rng.integers(0, E, F) if ids_of == "spread" else np.full(F, 5)
+    real = MASKS[mask](F, k)
+    want_inv, want_pos, want_tg, M = _plan_by_hand(ids, E, bm, real)
+    inv, pos, tg, live = map(np.asarray, G.masked_dispatch_plan(
+        jnp.asarray(ids, jnp.int32), jnp.asarray(real), E, bm))
+    assert inv.shape == (M,) and M == -(-F // bm) * bm + E * bm
+    assert live == len(want_tg) and E <= live <= M // bm
+    assert (inv == want_inv).all() and (pos == want_pos).all()
+    assert (pos[~real] == M).all() and (pos[real] < live * bm).all()
+    assert tg[:live].tolist() == want_tg
+    assert (tg[live:] == -live).all()      # park on tile ``live - 1``
+    if mask == "all_live":
+        plain = G.sorted_dispatch_plan(jnp.asarray(ids, jnp.int32), E, bm)
+        assert len(plain) == 3
+        p_inv, p_pos, p_tg = map(np.asarray, plain)
+        assert (p_inv == want_inv).all() and (p_pos == want_pos).all()
+        assert p_tg[:live].tolist() == want_tg
+        assert (p_tg[live:] == E - 1).all()
+
+
 def _dense_oracle(x, gw, wg, wu, wd, k):
     """No-capacity routed mixture: what grouped must reproduce exactly."""
     B, S, H = x.shape
@@ -426,11 +482,15 @@ class TestMosaicLowering:
                           platforms=["tpu"])(x, wg, wu, wd, gw)
 
 
+@pytest.mark.parametrize("told", ["count", "table"])
 @pytest.mark.parametrize("trans_rhs", [False, True], ids=["plain", "trans"])
 @pytest.mark.parametrize("live", [1, 3, 6, 10])
-def test_gmm_live_tiles_multiplies_only_the_live_prefix(live, trans_rhs):
+def test_gmm_live_tiles_multiplies_only_the_live_prefix(live, trans_rhs,
+                                                        told):
     """``gmm(live_tiles=)`` (a chip that holds a share of the experts sorts
-    the other experts' entries last): the first ``live`` row tiles are the
+    the other experts' entries last) and ``gmm(dead_in_table=True)`` (the
+    plan dropped the rows without a token; the table's dead entries name
+    the last live tile, negated): the first ``live`` row tiles are the
     plain product; the rest are skipped, whatever their number, and only
     the live rows are ever read."""
     rng = np.random.default_rng(0)
@@ -442,9 +502,16 @@ def test_gmm_live_tiles_multiplies_only_the_live_prefix(live, trans_rhs):
     tg = jnp.asarray([0, 0, 1, 2, 2, 3, 3, 3, 3, 3], jnp.int32)
     want = np.asarray(G._gmm_reference(lhs, rhs, tg, bm=bm,
                                        trans_rhs=trans_rhs))
-    got = np.asarray(jax.jit(lambda n: G.gmm(
-        lhs, rhs, tg, bm=bm, bn=128, bk=128, trans_rhs=trans_rhs,
-        interpret=True, live_tiles=n))(jnp.int32(live)))
+
+    def call(n):
+        how = {"live_tiles": n} if told == "count" \
+            else {"dead_in_table": True}
+        table = tg if told == "count" else \
+            jnp.where(jnp.arange(10) < n, tg, -n)
+        return G.gmm(lhs, rhs, table, bm=bm, bn=128, bk=128,
+                     trans_rhs=trans_rhs, interpret=True, **how)
+
+    got = np.asarray(jax.jit(call)(jnp.int32(live)))
     np.testing.assert_allclose(got[:live * bm], want[:live * bm],
                                rtol=1e-5, atol=1e-5)
     if live == 10:          # all live: the call without the argument

@@ -129,6 +129,52 @@ class TestServingDispatch:
                                    rtol=1e-6)
 
 
+    @pytest.mark.parametrize("layered", [False, True],
+                             ids=["one_bank", "switch_over_layers"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bf16"])
+    def test_rows_without_a_token_are_routed_nowhere(self, dtype, layered):
+        """The whole-bank grouped arm with ``live`` (the kernel interpreted,
+        dead tiles named by the table): a live row's output has the bits of
+        the unmasked call's, a row without a token reads zero, and ``rows``
+        counts the real entries and the rows of the tiles they fill."""
+        from paddle_tpu import flags
+        from paddle_tpu.inference.generation import EXPERT_BANKS, _moe_ffn
+
+        H = I = 128
+        E, k, bm, N = self.E, self.k, 8, 48
+        lp = {"mlp.gate.weight": _rand((H, E), 0.1, 1, dtype)}
+        shapes = [(E, H, I), (E, H, I), (E, I, H)]
+        for i, (name, shape) in enumerate(zip(EXPERT_BANKS, shapes)):
+            bank = _rand(shape, 0.05, 2 + i, dtype)
+            # the layer's bank second of two: the first must not be read
+            lp[name] = (jnp.full(shape, jnp.nan, dtype), bank) if layered \
+                else bank
+        layer = jnp.int32(1) if layered else None
+        y = _rand((1, N, H), 0.5, 8, dtype)
+        live = np.zeros(N, bool)
+        live[[0, 1, 2, 3, 4, 17, 30, 31, 47]] = True
+        moe = self._moe("grouped", bm)
+        flags.set_flags({"FLAGS_grouped_matmul_interpret": True})
+        try:
+            masked, rows = _moe_ffn(y, lp, moe, live=jnp.asarray(live),
+                                    layer=layer)
+            plain, rows_plain = _moe_ffn(y, lp, moe, layer=layer)
+        finally:
+            flags.set_flags({"FLAGS_grouped_matmul_interpret": False})
+        masked, plain = (np.asarray(a[0].astype(jnp.float32))
+                         for a in (masked, plain))
+        assert np.isfinite(plain).all() and np.abs(plain).max() > 0
+        assert np.array_equal(masked[live], plain[live])
+        assert not masked[~live].any()
+        _, topi, _, _ = L._route_topk(y[0], lp["mlp.gate.weight"], k)
+        per = np.bincount(np.asarray(topi)[live].ravel(), minlength=E)
+        tiles = np.maximum(-(-per // bm), 1).sum()
+        assert rows.tolist() == [k * live.sum(), tiles * bm]
+        assert rows_plain[0] == k * N and rows[1] < rows_plain[1] <= \
+            N * k + E * bm
+
+
 def _keep_mask_global(x, gw, k, E, cf):
     """The (token, choice) keep mask of the global-capacity (gather /
     einsum G=1) formulations — the same k-major cumsum-slot computation
