@@ -35,6 +35,13 @@ Structure — ONE jitted step function serves every serving phase:
 - Prefill IS the step: prompts stream through T-sized chunks with
   per-sequence ``q_lens`` raggedness, so a prefill chunk and concurrent
   decode rows ride one ``pallas_call`` (the ragged-paged-attention shape).
+- A stack may state a state-space mixer beside its attention
+  (``LayerKind.ssm``): a slot then holds a fixed recurrent state besides
+  its pages (``kv_cache.RecurrentState``, riding with the pool, donated
+  with it).  The layer scan CARRIES that state and each layer's
+  ``ragged_ssd_update`` call updates its slots in place; a slot's state is
+  zeroed on the device by the step that runs its first chunk and is not
+  touched by a step in which the slot has no work.
 - EOS / budget / capacity tracking lives ON DEVICE (``finished``,
   ``gen_counts``, ``budgets``): the host loop is sync-free — one async jit
   dispatch per step — and drains results every ``sync_every`` steps.
@@ -70,9 +77,10 @@ from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        write_kv_pages_all_layers_quantized,
                                        write_latent_pages_all_layers)
 from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
+from ..kernels.ssd import ragged_ssd_update
 from ..models.decoder_spec import EXPERT_BANKS
 from . import speculative as _sp
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, RecurrentState
 
 # The serving tensor-parallel mesh axis (FLAGS_serving_tensor_parallel).
 # Every axis-name string reaching a shard_map-wrapped body must come
@@ -168,6 +176,14 @@ def _rope_bt(x, cos, sin):
     o1 = x1 * c - x2 * s
     o2 = x2 * c + x1 * s
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _scaled(a, scale: float):
+    """``a`` times a model's constant multiplier, the product taken in
+    float32 and rounded once (1.0: ``a`` itself, nothing traced)."""
+    if scale == 1.0:
+        return a
+    return (a.astype(jnp.float32) * scale).astype(a.dtype)
 
 
 def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
@@ -422,6 +438,11 @@ class LlamaGenerator:
             raise ValueError(
                 "tensor_parallel > 1 shards the pool by KV head; a latent "
                 "pool has none (inference/kv_cache.py)")
+        if tp > 1 and c.ssm is not None:
+            raise ValueError(
+                "tensor_parallel > 1 shards the pool by KV head; the "
+                "recurrent state beside it has no sharded layout "
+                "(inference/kv_cache.py)")
         if tp > 1:
             if len(jax.devices()) < tp:
                 raise ValueError(
@@ -508,16 +529,28 @@ class LlamaGenerator:
                 and la is None:
             raise ValueError("an int8 pool's scale planes are scanned by "
                              "whole periods: no leading layers")
+        if c.ssm is not None and str(cache_dtype or dtype) == "int8":
+            raise ValueError(
+                "inference/kv_cache.py: kv_cache_dtype int8 quantises "
+                "pages; a stack with a recurrent state is served with a "
+                "float pool (auto, bf16 or fp32)")
         # a uniform pool: every layer keeps every page, whatever its window
         # (pages behind a sliding layer's window are held and never read);
         # a latent stack's holds one row a token a layer, no head axis
         latent = None if la is None else (la.rank, la.rope)
+        # what a slot holds besides pages (a state-space mixer's state and
+        # convolution rows): fixed, by slot, riding with the pool
+        recurrent = None if c.ssm is None else RecurrentState(
+            c.ssm, c.num_layers, max_batch, dtype)
+        self.state_bytes_per_slot = 0 if recurrent is None else \
+            RecurrentState.bytes_per_slot(c.ssm, c.num_layers, dtype)
         self.cache = PagedKVCache(
             num_layers=c.num_layers,
             num_pages=self.num_pages,
             page_size=page_size, num_kv_heads=c.num_kv_heads,
             head_dim=c.head_dim, dtype=cache_dtype or dtype,
-            mesh=self.mesh, axis=MP_AXIS, latent=latent)
+            mesh=self.mesh, axis=MP_AXIS, latent=latent,
+            recurrent=recurrent)
         # host-global pool bytes (all shards) — advertised via stats() /
         # /statusz so the router's capacity-weighted placement can rank
         # heterogeneous replicas
@@ -531,6 +564,8 @@ class LlamaGenerator:
                 self.pool_bytes // tp)
             _metrics.gauge("serving.kv_bytes_per_token").set(
                 self.pool_bytes // (self.num_pages * page_size))
+            _metrics.gauge("serving.state_bytes_per_slot").set(
+                self.state_bytes_per_slot)
         # over the part of a head that rotates, with the yarn blend where
         # the spec states one (``DecoderSpec.rope_tables``)
         cos, sin = map(jnp.asarray, c.rope_tables(self.max_seq_len))
@@ -569,7 +604,7 @@ class LlamaGenerator:
             else:
                 logits = jnp.einsum("...h,vh->...v", h,
                                     params["embed"]).astype(jnp.float32)
-        return logits
+        return _scaled(logits, self.spec.logit_scale)
 
     def _tp_jit(self, fn, name, n_in, n_out, out_cache_idx):
         """jit one engine program under ``name`` (a profiler trace's ``XLA
@@ -708,6 +743,10 @@ class LlamaGenerator:
         c = self.spec
         B, T = tokens.shape
         page = self.page_size
+        ssm_state = conv_state = None
+        if c.ssm is not None:
+            # the slots' recurrent state rides last (``PagedKVCache.arrays``)
+            *cache, ssm_state, conv_state = cache
         quant = len(cache) == 4
         if quant:
             kc, vc, ks, vs = cache
@@ -760,7 +799,8 @@ class LlamaGenerator:
         cos = jnp.take(self._cos, pos_c, axis=0)          # [R0, R1, d/2]
         sin = jnp.take(self._sin, pos_c, axis=0)
         with jax.named_scope("embed"):
-            h = jnp.take(params["embed"], toks, axis=0)   # [R0, R1, H]
+            h = _scaled(jnp.take(params["embed"], toks, axis=0),
+                        c.embed_scale)                    # [R0, R1, H]
         if packed:
             h = jnp.where(live[None, :, None], h, jnp.zeros((), h.dtype))
         R0, R1 = h.shape[:2]              # B, T, or 1, rows when packed
@@ -804,6 +844,65 @@ class LlamaGenerator:
             attn = jnp.einsum("abhr,rhd->abhd", u, w_uv).reshape(R0, R1, -1)
             return attn @ lp["self_attn.o_proj.weight"], c_new, r_new
 
+        if c.ssm is not None:
+            # a slot whose first chunk this is: what the last request left
+            # in its recurrent state counts as zero
+            fresh = jnp.logical_and(positions == 0, ql > 0)
+
+        def ssm_mixer(y, lp, mx, layer, state, carried):
+            """A place's state-space mixer on the normed input ``y``, beside
+            its attention: (the branch's output, the whole state with this
+            layer's slots updated in place, this layer's new convolution
+            rows).  Everything per token (the projections, the gate, the
+            norm) runs over the packed rows; the convolution and the scan
+            see the slots' ``[B, T]`` places, as attention does.  A slot
+            without work (``ql == 0``) keeps its state and its rows."""
+            f32 = jnp.float32
+            d, cw, n = mx.inner, mx.conv_width, mx.groups * mx.state
+            zxd = _scaled(y, mx.in_scale) @ lp["mamba.in_proj.weight"]
+            if any(k != 1.0 for k in mx.zone_scales):
+                kz, kx, kb, kc, kd = mx.zone_scales
+                zones = np.repeat(np.asarray([kz, kx, kb, kc, kd], np.float32),
+                                  [d, d, n, n, mx.heads])
+                zxd = (zxd.astype(f32) * zones).astype(zxd.dtype)
+            z, xbc, dt = zxd[..., :d], zxd[..., d:d + cw], zxd[..., d + cw:]
+            if packed:
+                xbc, dt = unpack(xbc), unpack(dt)
+            with jax.named_scope("conv"):
+                # the slot's carried rows, then the step's: token t reads
+                # rows t .. t + conv - 1 of them
+                carried = jnp.where(fresh[:, None, None],
+                                    jnp.zeros((), carried.dtype), carried)
+                ext = jnp.concatenate([carried, xbc], axis=1)
+                w = lp["mamba.conv1d.weight"].astype(f32)     # [conv, cw]
+                acc = lp["mamba.conv1d.bias"].astype(f32)
+                for j in range(mx.conv):
+                    acc = acc + w[j] * ext[:, j:j + T].astype(f32)
+                xbc = jax.nn.silu(acc).astype(xbc.dtype)
+                # the last conv - 1 rows of what the slot has now seen
+                last = ql[:, None] + jnp.arange(mx.conv - 1, dtype=jnp.int32)
+                carried = jnp.take_along_axis(ext, last[:, :, None], axis=1)
+            x = xbc[..., :d].reshape(B, T, mx.heads, mx.head_dim)
+            b_in = xbc[..., d:d + n].reshape(B, T, mx.groups, mx.state)
+            c_in = xbc[..., d + n:].reshape(B, T, mx.groups, mx.state)
+            dt = jax.nn.softplus(dt.astype(f32)
+                                 + lp["mamba.dt_bias"].astype(f32))
+            yk, state = ragged_ssd_update(
+                state, x, b_in, c_in, dt,
+                -jnp.exp(lp["mamba.A_log"].astype(f32)),
+                lp["mamba.D"].astype(f32), ql, fresh, layer=layer)
+            yk = yk.reshape(B, T, d)
+            if packed:
+                yk = pack(yk)
+            # gated, then RMS-normed over each group's numbers
+            g = yk.astype(f32) * jax.nn.silu(z.astype(f32))
+            g = rms_norm_fp32(
+                g.reshape(R0, R1, mx.groups, d // mx.groups),
+                lp["mamba.norm.weight"].reshape(mx.groups, d // mx.groups),
+                c.norm_eps).reshape(R0, R1, d).astype(y.dtype)
+            return _scaled(g @ lp["mamba.out_proj.weight"], mx.out_scale), \
+                state, carried
+
         def ffn(y, lp, kind, bank_layer):
             """A place's FFN on its normed input: the spec's expert mixture,
             or a dense gated MLP where the spec has none or the place says
@@ -818,14 +917,20 @@ class LlamaGenerator:
                 return _moe_ffn(y, lp, moe, mp_shards=self._moe_shards,
                                 live=live if packed else grid,
                                 layer=bank_layer)
-            act = jax.nn.silu(y @ lp["mlp.gate_proj.weight"]) * \
+            act = jax.nn.silu(_scaled(y @ lp["mlp.gate_proj.weight"],
+                                      c.mlp_gate_scale)) * \
                 (y @ lp["mlp.up_proj.weight"])
-            return act @ lp["mlp.down_proj.weight"], None
+            return _scaled(act @ lp["mlp.down_proj.weight"],
+                           c.mlp_out_scale), None
 
-        def one_layer(x, lp, kind, layer, ksl, vsl, bank_layer):
+        def one_layer(x, lp, kind, layer, ksl, vsl, bank_layer,
+                      state=None, conv_rows=None):
             """Decoder layer number ``layer``, of ``kind``, reading the pool
             (READ-ONLY; the kernel takes the whole pool and the layer, no
-            layer is sliced out of it): (x, this step's k, v, MoE rows).
+            layer is sliced out of it): (x, this step's k, v, MoE rows, and
+            where the place has a state-space mixer the whole recurrent
+            state with this layer's updated in place and the layer's new
+            convolution rows, else None twice).
             ``bank_layer``: None, or ``lp``'s expert banks are its place's
             unstacked layers and this layer is that one of them."""
             if kind.latent is not None:
@@ -836,14 +941,16 @@ class LlamaGenerator:
                     y = norm_fn(x, lp["post_attention_layernorm.weight"],
                                 c.norm_eps)
                     f, n_rows = ffn(y, lp, kind, bank_layer)
-                return x + f, k, v, n_rows
+                return x + f, k, v, n_rows, None, None
             with jax.named_scope("attention"):
                 y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
-                q = (y @ lp["self_attn.q_proj.weight"]).reshape(
+                ya = _scaled(y, c.attn_in_scale)
+                q = (ya @ lp["self_attn.q_proj.weight"]).reshape(
                     R0, R1, c.num_heads, c.head_dim)
-                k = (y @ lp["self_attn.k_proj.weight"]).reshape(
+                k = _scaled(ya @ lp["self_attn.k_proj.weight"],
+                            c.key_scale).reshape(
                     R0, R1, c.num_kv_heads, c.head_dim)
-                v = (y @ lp["self_attn.v_proj.weight"]).reshape(
+                v = (ya @ lp["self_attn.v_proj.weight"]).reshape(
                     R0, R1, c.num_kv_heads, c.head_dim)
                 if kind.rope:
                     q = _rope_bt(q, cos, sin)
@@ -879,16 +986,22 @@ class LlamaGenerator:
                 attn = attn.reshape(B, T, -1)
                 if packed:
                     attn = pack(attn)
-                a = attn @ lp["self_attn.o_proj.weight"]
+                a = _scaled(attn @ lp["self_attn.o_proj.weight"],
+                            c.attn_out_scale)
                 if not c.parallel_block:
                     x = x + a
+            if kind.ssm is not None:
+                with jax.named_scope("ssm"):
+                    s, state, conv_rows = ssm_mixer(y, lp, kind.ssm, layer,
+                                                    state, conv_rows)
+                    x = x + s
             with jax.named_scope("moe" if moe is not None else "mlp"):
                 if not c.parallel_block:     # else the FFN reads the same y
                     y = norm_fn(x, lp["post_attention_layernorm.weight"],
                                 c.norm_eps)
                 f, n_rows = ffn(y, lp, kind, bank_layer)
                 x = x + a + f if c.parallel_block else x + f
-            return x, k, v, n_rows
+            return x, k, v, n_rows, state, conv_rows
 
         # the scan runs over whole periods of the layer pattern, a period's
         # layers unrolled inside; what is scanned is one [periods, ...]
@@ -910,43 +1023,54 @@ class LlamaGenerator:
                    for lp, own in zip(params["blocks"], unstacked)]
 
         def period(carry, xs):
-            x, = carry
-            r, blocks, ksp, vsp = xs
-            ks_new, vs_new, n_rows = [], [], None
+            # the recurrent state is CARRIED (the scan's call updates one
+            # layer of it in place); the convolution rows are small and
+            # go in and out a layer at a time
+            x, *state = carry
+            r, blocks, ksp, vsp, convp = xs
+            ks_new, vs_new, conv_new, n_rows = [], [], [], None
             for p, kind in enumerate(c.pattern):
                 layer = r * P + p
                 if c.leading:
                     layer = layer + len(c.leading)
-                x, k, v, n = one_layer(
+                x, k, v, n, *mixed = one_layer(
                     x, {**blocks[p], **unstacked[p]}, kind, layer,
                     None if ksp is None else ksp[p],
                     None if vsp is None else vsp[p],
-                    r if unstacked[p] else None)
+                    r if unstacked[p] else None,
+                    *((state[0], convp[p]) if state else ()))
                 ks_new.append(k)
                 vs_new.append(v)
+                if state:
+                    state = [mixed[0]]
+                    conv_new.append(mixed[1])
                 if n is not None:
                     n_rows = n if n_rows is None else n_rows + n
-            return (x,), (jnp.stack(ks_new), jnp.stack(vs_new), n_rows)
+            return (x, *state), (jnp.stack(ks_new), jnp.stack(vs_new), n_rows,
+                                 jnp.stack(conv_new) if state else None)
 
         # leading layers (a shape of their own) run once, unrolled, first
         lead_k, lead_v = [], []
         for i, kind in enumerate(c.leading):
-            h, k, v, _ = one_layer(h, params["leading"][i], kind,
-                                   jnp.int32(i), None, None, None)
+            h, k, v = one_layer(h, params["leading"][i], kind,
+                                jnp.int32(i), None, None, None)[:3]
             lead_k.append(k)
             lead_v.append(v)
         xs = (jnp.arange(c.periods, dtype=jnp.int32), scanned,
-              by_period(ks), by_period(vs))
+              by_period(ks), by_period(vs), by_period(conv_state))
+        carry = (h,) if ssm_state is None else (h, ssm_state)
         if c.periods == 1:
             # nothing to scan over: the one period runs in line on the
             # stacks' only slice (a view, where a scan's slices of stacked
             # expert banks are copies)
-            (h,), ys = period((h,), jax.tree_util.tree_map(
+            carry, ys = period(carry, jax.tree_util.tree_map(
                 lambda a: a[0], xs))
-            k_all, v_all, moe_rows = jax.tree_util.tree_map(
+            k_all, v_all, moe_rows, conv_all = jax.tree_util.tree_map(
                 lambda a: a[None], ys)
         else:
-            (h,), (k_all, v_all, moe_rows) = jax.lax.scan(period, (h,), xs)
+            carry, (k_all, v_all, moe_rows, conv_all) = jax.lax.scan(
+                period, carry, xs)
+        h = carry[0]
         if moe_rows is not None:
             moe_rows = moe_rows.sum(axis=0)        # over the periods: [2]
         L = c.num_layers
@@ -991,6 +1115,9 @@ class LlamaGenerator:
                 kc, vc = write_kv_pages_all_layers(kc, vc, k_all, v_all,
                                                    slots)
                 out_cache = (kc, vc)
+        if ssm_state is not None:
+            out_cache += (carry[1],
+                          conv_all.reshape(conv_state.shape))
 
         h = norm_fn(h, params["norm"], c.norm_eps)
         return (unpack(h) if packed else h), out_cache, moe_rows
@@ -1343,7 +1470,7 @@ class _ServingMetrics:
                  "peak_pages", "active_seqs", "cached_pages",
                  "evictable_pages", "spec_drafted", "spec_accepted",
                  "spec_rejected", "accept_len", "digest_epoch",
-                 "moe_held_rows", "moe_rows_laid_out")
+                 "moe_held_rows", "moe_rows_laid_out", "state_resets")
 
     def __init__(self):
         m = _obs.metrics
@@ -1365,6 +1492,9 @@ class _ServingMetrics:
                                          bounds=rows_bounds)
         self.moe_rows_laid_out = m.histogram("serving.moe_rows_laid_out",
                                              bounds=rows_bounds)
+        # slots whose recurrent state the device zeroes at their first
+        # chunk (a stack with a state-space mixer; else it stays 0)
+        self.state_resets = m.counter("serving.state_resets")
         self.requests = m.counter("serving.requests_total")
         self.completed = m.counter("serving.requests_completed")
         self.tokens = m.counter("serving.tokens_generated")
@@ -1516,6 +1646,20 @@ class ContinuousBatchingEngine:
         # through EVERY step (prefill commits update it too), so the
         # verify step's context is exact when the row reaches decode
         self._track_recent = self._recent is not None
+        if self.g.spec.ssm is not None:
+            # what cannot follow a recurrent state is refused now
+            if prefix_cache:
+                raise ValueError(
+                    "inference/prefix_cache.py: a prefix hit hands a "
+                    "request pages, and a stack with a recurrent state "
+                    "needs the STATE at the boundary too, which no page "
+                    "holds: build the engine with prefix_cache off")
+            if self.spec is not None:
+                raise ValueError(
+                    "inference/speculative.py: a rejected draft cannot be "
+                    "taken out of a recurrent state (the tail rollback "
+                    "moves a cursor over pages): build the engine with "
+                    "spec_decode off")
         self._families: dict = {}      # T -> {GEMM rows: compiled step}
         # the compiled step programs return carried state committed to
         # their device (mesh-replicated, out_specs P(), under tp).  Seed
@@ -1813,6 +1957,11 @@ class ContinuousBatchingEngine:
                           kv_read_tokens=g.kv_read_tokens(attends),
                           attn_rows=g.attn_rows(T, attends),
                           waiting=len(self.waiting))
+        if g.spec.ssm is not None:
+            # the slots whose recurrent state the step's scan calls read
+            # and write (those with work), and the tokens they scan
+            span.set_metadata(ssm_slots=int((ql > 0).sum()),
+                              ssm_tokens=q_tokens)
         with tracer.span("engine.dispatch", program=f"serve_step_T{T}"):
             out = step(g.params, g.cache.arrays, tokens_in, ql_dev,
                        self.positions, self.finished, dm, commit_dev,
@@ -2479,6 +2628,8 @@ class ContinuousBatchingEngine:
                     self._obs.queue_wait.observe(
                         (now - req.t_enqueue) * 1e3)
             self._obs.queue_now.set(len(self.waiting))
+            if g.spec.ssm is not None:
+                self._obs.state_resets.inc(len(admitted))
         for b, req in admitted:
             self.slot_req[b] = req
             self.prompt_pos[b] = int(starts[b])

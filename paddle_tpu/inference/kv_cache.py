@@ -308,6 +308,39 @@ class PageAllocator:
         return np.asarray([self._lens[s] for s in seq_ids], np.int32)
 
 
+class RecurrentState:
+    """What a slot holds besides pages where the stack has a state-space
+    mixer (``models.decoder_spec.SsmMixer``): fixed in size, indexed by
+    SLOT and never by page, so no allocator addresses it.  Two arrays: the
+    scan's state ``ssm [layers, slots, heads, head_dim, state]`` in float32
+    (in bf16 a term under 2^-8 of the state's size would be lost at every
+    token) and the convolution's carried rows ``conv [layers, slots,
+    conv - 1, conv_width]`` in the model's type.  A slot's state is zeroed
+    on the device by the step that runs its first chunk; nothing here is
+    copied, spilled or snapshotted (the prefix cache, the spill tier and
+    migration refuse a stack that has one)."""
+
+    def __init__(self, mixer, num_layers: int, slots: int, dtype):
+        self.mixer = mixer
+        self.ssm = jnp.zeros((num_layers, slots, mixer.heads,
+                              mixer.head_dim, mixer.state), jnp.float32)
+        self.conv = jnp.zeros((num_layers, slots, mixer.conv - 1,
+                               mixer.conv_width), jnp.dtype(dtype))
+
+    @property
+    def arrays(self):
+        return self.ssm, self.conv
+
+    def update(self, ssm, conv) -> None:
+        self.ssm, self.conv = ssm, conv
+
+    @staticmethod
+    def bytes_per_slot(mixer, num_layers: int, dtype) -> int:
+        """HBM bytes one slot's recurrent state costs over all layers (the
+        fixed counterpart of ``PagedKVCache.bytes_per_page``)."""
+        return num_layers * mixer.state_bytes(dtype)
+
+
 class PagedKVCache:
     """Device KV pool for all layers + the allocator that addresses it.
 
@@ -337,11 +370,15 @@ class PagedKVCache:
     + rope)``): 64 numbers would be padded to 128 lanes in HBM, two tokens
     fill them, and a page stays one whole tile of each array.  Pages are on
     axis 1 (``page_axis``).  No int8 plane and no tensor-parallel layout:
-    both are refused here."""
+    both are refused here.
+
+    ``recurrent`` (a ``RecurrentState``): the slots' fixed state rides
+    with the pool as the last two of ``.arrays``, donated and updated with
+    it; a float per-head pool on one device only."""
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype="bfloat16",
-                 mesh=None, axis: str = "mp", latent=None):
+                 mesh=None, axis: str = "mp", latent=None, recurrent=None):
         self.num_layers = num_layers
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
@@ -350,6 +387,13 @@ class PagedKVCache:
         self.mesh = mesh
         self.axis = axis
         self.latent = None if latent is None else tuple(latent)
+        self.recurrent = recurrent
+        if recurrent is not None and (self.quantized or mesh is not None
+                                      or latent is not None):
+            raise ValueError(
+                "inference/kv_cache.py: a recurrent state rides with a "
+                "float per-head pool on one device: no int8 plane, no "
+                "tensor-parallel layout, no latent pool")
         # the axis of every plane that counts pages
         self.page_axis = 2 if latent is None else 1
         if latent is not None:
@@ -414,9 +458,12 @@ class PagedKVCache:
     def arrays(self):
         """The donated device state of one engine step: ``(k, v)`` for a
         float pool (a latent pool's compressed rows and rotary keys),
-        ``(k, v, k_scale, v_scale)`` when quantized."""
+        ``(k, v, k_scale, v_scale)`` when quantized, ``(k, v, ssm, conv)``
+        with a recurrent state."""
         if self.quantized:
             return self.k, self.v, self.k_scale, self.v_scale
+        if self.recurrent is not None:
+            return (self.k, self.v) + self.recurrent.arrays
         return self.k, self.v
 
     @property
@@ -429,11 +476,14 @@ class PagedKVCache:
             return spec, spec, spec, spec
         return spec, spec
 
-    def update(self, k, v, k_scale=None, v_scale=None) -> None:
-        """Store the cache arrays returned by a jitted (donating) step."""
+    def update(self, k, v, *rest) -> None:
+        """Store the cache arrays returned by a jitted (donating) step, in
+        ``.arrays``' order."""
         self.k, self.v = k, v
         if self.quantized:
-            self.k_scale, self.v_scale = k_scale, v_scale
+            self.k_scale, self.v_scale = rest
+        elif self.recurrent is not None:
+            self.recurrent.update(*rest)
 
     @staticmethod
     def pages_for(max_batch: int, max_seq_len: int, page_size: int) -> int:

@@ -105,6 +105,12 @@ class HostSpillPool:
                 "inference/kv_spill.py: the host spill ring copies per-head "
                 "pages (axis 2 of every plane); a latent pool has no head "
                 "axis: build the engine with kv_spill_pages=0")
+        if getattr(cache, "recurrent", None) is not None:
+            raise ValueError(
+                "inference/kv_spill.py: the host spill ring holds pages; a "
+                "slot's recurrent state is no page and a spilled prefix "
+                "could not be resumed without it: build the engine with "
+                "kv_spill_pages=0")
         self.cache = cache               # PagedKVCache (live arrays)
         self.capacity = int(capacity)
         self._free: List[int] = list(range(self.capacity - 1, -1, -1))

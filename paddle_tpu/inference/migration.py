@@ -132,14 +132,19 @@ def _engine_counts(engine) -> Dict[str, int]:
 # geometry / codec
 # ---------------------------------------------------------------------------
 
-def _refuse_latent(engine) -> None:
+def _refuse_unsnapshotted(engine) -> None:
     """A snapshot's pages, geometry and digest are per-head planes; a
     latent pool (one row a token, no head axis) is not exported, imported
-    or warmed."""
+    or warmed, nor is a stack whose slots hold a recurrent state."""
     if getattr(engine.g.cache, "latent", None) is not None:
         raise MigrationError(
             "inference/migration.py: session snapshots move per-head page "
             "planes; this engine serves a latent pool, which has none")
+    if getattr(engine.g.cache, "recurrent", None) is not None:
+        raise MigrationError(
+            "inference/migration.py: a session snapshot is pages; this "
+            "engine's slots also hold a recurrent state, which no snapshot "
+            "carries")
 
 
 def _geometry(engine) -> Dict[str, object]:
@@ -260,7 +265,7 @@ def export_session(engine, req_id: Optional[int] = None,
     chain matching the token history; spilled chain nodes ship their
     host-ring bytes directly (no swap-in).
     """
-    _refuse_latent(engine)
+    _refuse_unsnapshotted(engine)
     if (req_id is None) == (tokens is None):
         raise ValueError("export_session takes exactly one of "
                          "req_id= or tokens=")
@@ -380,7 +385,7 @@ def _uploader(engine):
 def warm(engine) -> None:
     """Compile the upload program with an out-of-range page id (every
     scatter write drops) so the first real import is dispatch-only."""
-    _refuse_latent(engine)
+    _refuse_unsnapshotted(engine)
     cache = engine.g.cache
     zeros = tuple(jnp.zeros(arr.shape[:2] + arr.shape[3:], arr.dtype)
                   for arr in cache.arrays)
@@ -406,7 +411,7 @@ def import_session(engine, snap: dict, resume: bool = False) -> dict:
     re-prefilled.  Returns ``{"imported", "skipped", "pages",
     "resume_req_id"}``.
     """
-    _refuse_latent(engine)
+    _refuse_unsnapshotted(engine)
     cache = engine.prefix_cache
     if cache is None:
         raise MigrationError("import needs the prefix cache "

@@ -58,6 +58,57 @@ class LatentAttn:
 
 
 @dataclass(frozen=True)
+class SsmMixer:
+    """A state-space mixer (Mamba-2) that runs BESIDE a place's attention,
+    from the same norm ``u``: ``[z | xBC | dt] = W_in (u x in_scale)`` with
+    the five zones ``z, x, B, C, dt`` scaled by ``zone_scales``; a causal
+    depthwise convolution of ``conv`` taps with bias over ``xBC`` (so a
+    slot carries its last ``conv - 1`` rows), SiLU; per head the recurrence
+    ``S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T``, ``y_t = S_t C_t + D
+    x_t`` with ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``
+    (``kernels/ssd.py``; ``heads`` of ``head_dim`` over a state ``state``
+    wide, ``B`` and ``C`` shared by the heads of each of ``groups``);
+    ``y * silu(z)`` RMS-normed over each group's ``heads / groups x
+    head_dim`` numbers with a learned weight; ``out_scale x W_out``.
+
+    What a slot holds for it besides pages: the float32 state ``[heads,
+    head_dim, state]`` and the ``conv - 1`` rows, a layer."""
+    heads: int
+    head_dim: int
+    state: int
+    groups: int
+    conv: int
+    in_scale: float = 1.0
+    zone_scales: Tuple[float, float, float, float, float] = (1.0,) * 5
+    out_scale: float = 1.0
+
+    def __post_init__(self):
+        if self.heads % self.groups:
+            raise ValueError(f"mixer heads ({self.heads}) are not a whole "
+                             f"number a group ({self.groups})")
+
+    @property
+    def inner(self) -> int:
+        """Width of ``x`` (and of ``z``, and of the mixer's output)."""
+        return self.heads * self.head_dim
+
+    @property
+    def conv_width(self) -> int:
+        """Width of what is convolved: ``[x | B | C]``."""
+        return self.inner + 2 * self.groups * self.state
+
+    @property
+    def in_width(self) -> int:
+        return self.inner + self.conv_width + self.heads
+
+    def state_bytes(self, dtype) -> int:
+        """Bytes a slot holds for ONE layer: the float32 state and the
+        carried convolution rows in ``dtype``."""
+        return 4 * self.heads * self.head_dim * self.state \
+            + (self.conv - 1) * self.conv_width * np.dtype(dtype).itemsize
+
+
+@dataclass(frozen=True)
 class LayerKind:
     """One place in the layer pattern: what its attention sees, how its
     positions are embedded, and what its FFN is."""
@@ -70,6 +121,8 @@ class LayerKind:
     # a dense gated MLP (its width is its weights') even where the spec
     # states a ``moe``
     dense_ffn: bool = False
+    # a state-space mixer beside the attention: ``x + attn(u) + ssm(u)``
+    ssm: Optional[SsmMixer] = None
 
 
 @dataclass(frozen=True)
@@ -175,6 +228,17 @@ class DecoderSpec:
     # layers that run once, unrolled, before the scan of periods
     leading: Tuple[LayerKind, ...] = ()
     rope_yarn: Optional[RopeYarn] = None
+    # constant multipliers of a muP-parametrised model, each applied where
+    # its name says (1.0: nothing is traced): the embedding's rows; the
+    # attention's input, its keys and its output; the MLP's gate
+    # pre-activation and its output; the logits
+    embed_scale: float = 1.0
+    attn_in_scale: float = 1.0
+    key_scale: float = 1.0
+    attn_out_scale: float = 1.0
+    mlp_gate_scale: float = 1.0
+    mlp_out_scale: float = 1.0
+    logit_scale: float = 1.0
 
     def __post_init__(self):
         if self.norm not in ("rms", "layer"):
@@ -186,6 +250,16 @@ class DecoderSpec:
         if self.latent is not None and self.parallel_block:
             raise ValueError("latent attention is served with sequential "
                              "residuals only")
+        # what a slot holds besides pages is one shape for the whole stack
+        if len({k.ssm for k in self.leading + self.pattern}) != 1:
+            raise ValueError("one recurrent state serves every layer: "
+                             "layers with and without a state-space mixer "
+                             "(or two shapes of one) cannot share a stack")
+        if self.ssm is not None and (self.latent is not None
+                                     or self.parallel_block or self.leading):
+            raise ValueError("a state-space mixer is served beside per-head "
+                             "attention in a scanned stack with sequential "
+                             "residuals only")
 
     @property
     def num_layers(self) -> int:
@@ -195,6 +269,12 @@ class DecoderSpec:
     def latent(self) -> Optional[LatentAttn]:
         """The stack's latent attention (every layer's alike), or None."""
         return self.pattern[0].latent
+
+    @property
+    def ssm(self) -> Optional[SsmMixer]:
+        """The stack's state-space mixer (every layer's alike), or None:
+        whether a slot holds a recurrent state besides its pages."""
+        return self.pattern[0].ssm
 
     @property
     def softmax_scale(self) -> float:

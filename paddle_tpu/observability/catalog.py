@@ -81,6 +81,20 @@ CATALOG: Dict[str, tuple] = {
         "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
         "five latent layers of 512 + 64 in bf16; 16,384 for four layers "
         "of 8 KV heads x 128)"),
+    # ---- serving: what a slot holds besides pages (PR 34) ----
+    "serving.state_bytes_per_slot": (
+        "gauge", "",
+        "bytes of recurrent state one slot holds over all layers besides "
+        "its pages, fixed and by slot (`inference/kv_cache.py::"
+        "RecurrentState`): a state-space mixer's float32 state and its "
+        "convolution's carried rows (16,900,096 for four layers of 32 "
+        "heads x 128 x 256 and three rows of 5,120 in bf16); 0 for a "
+        "stack that keeps pages alone"),
+    "serving.state_resets": (
+        "counter", "",
+        "slots whose recurrent state is zeroed on the device by the step "
+        "that runs their first chunk: one an admission to a stack that "
+        "has such a state, none otherwise"),
     # ---- serving: per-phase step attribution (PR 10) ----
     "serving.step_ms": (
         "histogram", "phase=prefill|decode|spec_verify|fused_k|cow_copy"
@@ -508,7 +522,7 @@ SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
         "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
-        "kv_read_tokens, attn_rows, slots, waiting",
+        "kv_read_tokens, attn_rows, ssm_slots, ssm_tokens, slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
@@ -528,8 +542,11 @@ SPANS: Dict[str, tuple] = {
         "one row of keys for all heads, so `group` is the number of query "
         "heads and a slot covers `q_len x heads` rows), so `q_tokens x "
         "group / attn_rows` is to attention what `q_tokens / gemm_rows` "
-        "is to the GEMMs, `slots` the batch B, `waiting` the queue behind "
-        "it"),
+        "is to the GEMMs, `ssm_slots` and `ssm_tokens` (a stack with a "
+        "state-space mixer only) the slots whose recurrent state each "
+        "layer's scan call reads and writes in this step (those with "
+        "work) and the tokens they scan, `slots` the batch B, `waiting` "
+        "the queue behind it"),
     "engine.admit": (
         "serving", "engine", "local", "admitted, waiting",
         "`_admit`: waiting requests into free slots, their pages and the "

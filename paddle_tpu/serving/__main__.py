@@ -37,8 +37,15 @@ _SARVAM_MLA_PRESETS = {
         num_hidden_layers=5, experts_held=32, expert_offset=0,
         vocab_size=262144 // 4),
 }
+# falcon_h1 (models/falcon_h1.py): the test size, and Falcon-H1-34B as one
+# pipeline stage of four of its 72 layers holds it, with embedding and head
+# (8.8 GB; a slot keeps 16.9 MB of recurrent state besides its pages)
+_FALCON_H1_PRESETS = {
+    "falcon_h1_tiny": lambda cfg: cfg.tiny(),
+    "falcon_h1_34b_d4": lambda cfg: cfg.falcon_h1_34b(num_hidden_layers=4),
+}
 _PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS) \
-    + tuple(_SARVAM_MLA_PRESETS)
+    + tuple(_SARVAM_MLA_PRESETS) + tuple(_FALCON_H1_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -151,6 +158,10 @@ def build_engine(args):
         from ..models.sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM
         model = SarvamMlaForCausalLM(
             _SARVAM_MLA_PRESETS[args.preset](SarvamMlaConfig))
+    elif args.preset in _FALCON_H1_PRESETS:
+        from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
+        model = FalconH1ForCausalLM(
+            _FALCON_H1_PRESETS[args.preset](FalconH1Config))
     else:
         from ..models.llama import LlamaConfig, LlamaForCausalLM
         model = LlamaForCausalLM(getattr(LlamaConfig, args.preset)())

@@ -326,18 +326,23 @@ def test_layer_scan_reads_expert_banks_where_they_lie(one_chip, monkeypatch,
 # ``gmm_held_roofline_pct.batch`` (the two cells that hold a share) know
 # their calls by the first operands; a call that changes them reads ``null``
 # in a cell that lists the metric and the driver refuses that run
-def _gmm_calls_as_the_trace_names_them(compiled):
-    """The compiled program's ``gmm`` custom calls, each parsed as the
-    benchmark parses a device operation's name.  A trace's name has every
-    operand's shape where the compiled text has ``%name``: they are put
-    there from the call's ``operand_layout_constraints``."""
+def _calls_as_the_trace_names_them(compiled, name):
+    """The compiled program's custom calls named ``name``, each parsed as
+    the benchmark parses a device operation's name.  A trace's name has
+    every operand's shape where the compiled text has ``%name``: they are
+    put there from the call's ``operand_layout_constraints``."""
     from chipbench.harness.trace_reduce import parse_op
-    call = re.compile(r"\s*(?:ROOT )?(%gmm[.\d]* = .* custom-call\()[^)]*(\),"
+    call = re.compile(r"\s*(?:ROOT )?(%" + name + r"[.\d]* = .* "
+                      r"custom-call\()[^)]*(\),"
                       r".* operand_layout_constraints=\{(.*?\})\}.*)")
     ops = [parse_op(m.group(1) + m.group(3) + m.group(2), 0.0, 1.0)
            for m in map(call.match, compiled.as_text().splitlines()) if m]
     assert ops and all(op.is_kernel for op in ops)
     return ops
+
+
+def _gmm_calls_as_the_trace_names_them(compiled):
+    return _calls_as_the_trace_names_them(compiled, "gmm")
 
 
 @pytest.mark.parametrize("arm", ["whole_bank", "held"])
@@ -530,3 +535,124 @@ def test_sarvam_step_program_compiles_at_published_widths(one_chip,
     assert mem.temp_size_in_bytes < 1 << 30
     assert mem.alias_size_in_bytes == sum(
         a.size * a.dtype.itemsize for a in g.cache.arrays)   # pool in place
+
+
+# ---- falcon-h1-34b at depth 4 (PR 34) ----
+# a Mamba-2 mixer beside GQA 20 / 4 x 128: the scan call over a float32
+# state [layers, slots, 32, 128, 256] and the cell's engine: 128 slots,
+# 2,048 positions in pages of 16, chunks of 16, four layers
+FH_B, FH_L, FH_H, FH_P, FH_N, FH_G = 128, 4, 32, 128, 256, 2
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("T", [1, 16], ids=["decode", "mixed"])
+def test_ssd_update_falcon_shapes_compile(one_chip, T):
+    """The scan call at the published widths and 128 slots, on the whole
+    state read at a traced layer: one group's 16 heads a program (a 2 MiB
+    state block in and out), the state result aliased to its operand (the
+    2.15 GB are the call's only large buffer), and the call is what the
+    benchmark matches on: the name, ``y [slots, heads, rows, 128]`` first,
+    the float32 state second and among the operands."""
+    from chipbench.kernels import ssd_update
+    from paddle_tpu.kernels import ssd
+    assert ssd._heads_per_block(FH_H // FH_G, FH_P, FH_N) == 16
+    f32, i32 = jnp.float32, jnp.int32
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((FH_L, FH_B, FH_H, FH_P, FH_N), f32), ((FH_B, T, FH_H, FH_P), BF16),
+        ((FH_B, T, FH_G, FH_N), BF16), ((FH_B, T, FH_G, FH_N), BF16),
+        ((FH_B, T, FH_H), f32), ((FH_H,), f32), ((FH_H,), f32),
+        ((FH_B,), i32), ((FH_B,), jnp.bool_), ((), i32))]
+    compiled = jax.jit(
+        lambda *a: ssd._pallas_ragged_ssd_update(*a, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    state_bytes = FH_L * FH_B * FH_H * FH_P * FH_N * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes            # in place
+    assert mem.temp_size_in_bytes < 64 << 20
+    op, = _calls_as_the_trace_names_them(compiled, "ragged_ssd_update")
+    assert ssd_update.match(op) == {
+        "slots": FH_B, "heads": FH_H, "head_dim": FH_P, "state": FH_N,
+        "q_rows": max(8, T), "dtype": "bf16"}
+
+
+@pytest.mark.timeout(600)
+def test_falcon_h1_step_program_compiles_at_published_widths(one_chip,
+                                                             monkeypatch):
+    """The packed T = 16 step program of the cell's engine (128 slots, 512
+    GEMM rows) at the published widths, on abstract parameters: a scan over
+    four layers whose body holds one paged call (a group of 5: row tiles of
+    80) and one scan call on the carried state; pool AND state are updated
+    in place (no second ``[4, 128, 32, 128, 256]`` float32 buffer), and the
+    program fits the chip beside its 8.79 GB of weights, 2.16 GB of state
+    and the cell's 2.15 GB pool (the pool here is an eighth of the cell's:
+    its size moves no operation but the commit's bounds, and the peak below
+    counts the cell's)."""
+    from paddle_tpu.inference import generation as gen
+    from paddle_tpu.kernels import ssd
+    from paddle_tpu.models.falcon_h1 import (FalconH1Config,
+                                             FalconH1ForCausalLM,
+                                             layer_leaves)
+
+    class OnTpu:
+        """``jax`` as the kernels' entry points see it on a chip."""
+        def __getattr__(self, name):
+            return getattr(jax, name)
+
+        @staticmethod
+        def default_backend():
+            return "tpu"
+
+    monkeypatch.setattr(pa, "jax", OnTpu())
+    monkeypatch.setattr(ssd, "jax", OnTpu())
+    cfg = FalconH1Config.falcon_h1_34b(num_hidden_layers=FH_L,
+                                       max_position_embeddings=2048)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    class Abstract:
+        config = cfg
+        decoder_spec = FalconH1ForCausalLM.decoder_spec
+
+        def serving_params(self):
+            H, V = cfg.hidden_size, cfg.vocab_size
+            return {"embed": sds((V, H), BF16), "norm": sds((H,), BF16),
+                    "head": sds((H, V), BF16),
+                    "blocks": ({name: sds((FH_L,) + tuple(shape), dt)
+                                for name, shape, _, dt
+                                in layer_leaves(cfg)},)}
+
+    pages, cell_pages = 2048, 16384
+    g = gen.LlamaGenerator(Abstract(), max_batch=FH_B, max_seq_len=2048,
+                           page_size=PAGE, prefill_bucket=16,
+                           num_pages=pages)
+    leaves = jax.tree_util.tree_leaves(g.params)
+    assert sum(int(jnp.prod(jnp.asarray(a.shape))) for a in leaves) == \
+        4_394_354_048
+    assert g.state_bytes_per_slot == 16_900_096
+    T, rows = 16, g.row_buckets(16)[0]
+    assert g.row_buckets(16) == [512, 2048]
+    assert pa.row_tile(T, 5) == 80 and pa.row_tile(1, 5) == 8
+    i32, key = jnp.int32, jax.random.key(0)
+    vec = lambda dt: sds((FH_B,), dt)             # noqa: E731
+    ops = (g.params, tuple(sds(a.shape, a.dtype) for a in g.cache.arrays),
+           sds((FH_B, T), i32), vec(i32), vec(i32), vec(jnp.bool_),
+           vec(jnp.bool_), vec(jnp.bool_), vec(i32), vec(i32),
+           sds((FH_B, g.pages_per_seq), i32),
+           jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    compiled = g._step_jit(gen.GenerationConfig(), T, False, rows) \
+        .lower(*ops).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert len(_calls_as_the_trace_names_them(
+        compiled, "ragged_ssd_update")) == 1
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in g.cache.arrays)
+    assert mem.alias_size_in_bytes == held       # pool and state in place
+    assert mem.temp_size_in_bytes < 512 << 20
+    per_page = gen.PagedKVCache.bytes_per_page(FH_L, 4, PAGE, 128, "bfloat16")
+    peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes \
+        + (cell_pages - pages) * per_page
+    assert 13.0e9 < peak < 15.5e9, peak
