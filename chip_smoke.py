@@ -139,8 +139,9 @@ def phase_kernels(sz: Sizes, seed: int, on_tpu: bool) -> None:
         ql = jnp.asarray(rng.integers(1, T + 1, B), jnp.int32)
 
         def kern(q, kc, vc, bt, ctx, ql, kn, vn):
-            return pa.ragged_paged_attention(q, kc, vc, bt, ctx, q_lens=ql,
-                                             k_new=kn, v_new=vn)
+            return pa.ragged_paged_attention(q, pa.pool_of_heads(kc, vc), bt,
+                                             ctx, q_lens=ql, k_new=kn,
+                                             v_new=vn)
 
         def ref(q, kc, vc, bt, ctx, ql, kn, vn):
             with jax.default_matmul_precision("highest"):

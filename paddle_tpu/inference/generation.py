@@ -69,7 +69,7 @@ from .. import flags
 from .. import observability as _obs
 from ..kernels.grouped_matmul import take_sentinel_rows
 from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
-                                       paged_attention,
+                                       page_copies, paged_attention,
                                        ragged_paged_attention,
                                        ragged_paged_attention_latent,
                                        write_kv_pages,
@@ -129,23 +129,22 @@ def _pack_plan(ql, T, rows):
     return live, b * T + t, dst
 
 
-def _cow_copy_pages(cache, src, dst, page_axis=2):
+def _cow_copy_pages(cache, src, dst, page_axes=(1,)):
     """Whole-page KV copies src[i] -> dst[i] across every layer/head (the
     prefix cache's copy-on-write privatization).  Entries with src < 0
     are no-ops: their dst is routed out of bounds, which scatter drops.
     Jitted once per engine over the fixed [max_batch] pair bucket and
     donated like the step, so warm hit admissions never recompile.
 
-    ``cache`` is the pool tuple — ``(k, v)`` float or ``(k, v, k_scale,
-    v_scale)`` int8: every plane indexes pages on axis 2, so one loop
-    copies them all, and an int8 COW moves 4x fewer bytes.  A latent
-    pool's two planes have no head axis: ``page_axis`` 1
-    (``PagedKVCache.page_axis``)."""
+    ``cache`` is the pool tuple — ``(kv,)`` float, ``(kv, k_scale,
+    v_scale)`` int8 (an int8 COW moves 4x fewer bytes), a latent pool's
+    ``(k, v)`` — and ``page_axes`` the axis of each array that counts
+    pages (``PagedKVCache.page_axes``), so one loop copies them all."""
     valid = src >= 0
     s = jnp.maximum(src, 0)
-    lead = (slice(None),) * page_axis
     out = []
-    for arr in cache:
+    for arr, page_axis in zip(cache, page_axes):
+        lead = (slice(None),) * page_axis
         d = jnp.where(valid, dst, arr.shape[page_axis])
         out.append(arr.at[lead + (d,)].set(jnp.take(arr, s, axis=page_axis),
                                            mode="drop"))
@@ -514,9 +513,10 @@ class LlamaGenerator:
         if jax.default_backend() == "tpu":
             # the step's attention is the Pallas kernel or nothing on a
             # chip: refuse a geometry it does not cover now, not mid-trace
+            pool_dtype = str(cache_dtype or dtype)
             why = kernel_geometry_error(
-                page_size, c.head_dim,
-                quantized=str(cache_dtype or dtype) == "int8",
+                page_size, c.head_dim, quantized=pool_dtype == "int8",
+                dtype=pool_dtype,
                 kv_heads=c.num_kv_heads // tp,
                 num_pages=self.num_pages,
                 table_shape=(max_batch, self.pages_per_seq),
@@ -557,8 +557,18 @@ class LlamaGenerator:
         self.pool_bytes = self.num_pages * PagedKVCache.bytes_per_page(
             c.num_layers, c.num_kv_heads, page_size,
             c.head_dim, cache_dtype or dtype, latent=latent)
+        # what ONE descriptor of the paged call moves: a page's K and V of
+        # every KV head this shard holds, one layer (a latent call copies a
+        # page's compressed rows as two halves and its rotary keys apart:
+        # the largest of the three)
+        itemsize = jnp.dtype(self.cache.dtype).itemsize
+        self.kv_copy_bytes = (
+            page_size // 2 * la.rank if la is not None
+            else 2 * (c.num_kv_heads // tp) * page_size * c.head_dim) \
+            * itemsize
         if _obs.metrics_enabled():
             from ..observability import metrics as _metrics
+            _metrics.gauge("serving.kv_copy_bytes").set(self.kv_copy_bytes)
             _metrics.gauge("serving.tp.degree").set(tp)
             _metrics.gauge("serving.tp.shard_pool_bytes").set(
                 self.pool_bytes // tp)
@@ -594,6 +604,22 @@ class LlamaGenerator:
         c = self.spec
         return attn_rows([q for q, _ in rows], t,
                          c.num_heads // c.num_kv_heads)
+
+    def page_copies(self, rows) -> int:
+        """DMA descriptors one layer's paged call starts for ``rows`` =
+        [(query tokens, context before them)]: the kernel's own host
+        arithmetic (working slots x the blocks their walk reaches x the
+        pages of a block, whole blocks whatever the context holds of them),
+        for a layer that sees the whole context where the stack has one,
+        else the layer with the widest window.  A latent call starts three
+        copies a page (its compressed rows as two halves, its rotary
+        keys)."""
+        windows = [w for w, _ in self._layers_by_window]
+        n = page_copies(rows, self.page_size, self.pages_per_seq,
+                        0 if self.spec.latent is not None
+                        else self.kv_copy_bytes,
+                        None if None in windows else max(windows))
+        return 3 * n if self.spec.latent is not None else n
 
     def _head_logits(self, params, h):
         """float32 logits of hidden states ``h [..., H]``: the head, or the
@@ -726,8 +752,9 @@ class LlamaGenerator:
         — embedding lookups clip, and their slots are routed to -1 / not
         attended).  ql: [B] valid tokens per row (0 = inert row).
         positions: [B] cache tokens BEFORE this step (the write cursor).
-        cache: the pool tuple — (kc, vc) float, or (kc, vc, ks, vs) for
-        the int8 plane (per-(layer, kv-head, page) fp32 scales): pages
+        cache: the pool tuple — (kv,) float, or (kv, ks, vs) for
+        the int8 plane (per-(layer, kv-head, page) fp32 scales), a latent
+        pool's (c, r): pages
         dequantize inside the kernel and the commit requantizes per
         page, so the two modes share this whole function.
 
@@ -747,12 +774,14 @@ class LlamaGenerator:
         if c.ssm is not None:
             # the slots' recurrent state rides last (``PagedKVCache.arrays``)
             *cache, ssm_state, conv_state = cache
-        quant = len(cache) == 4
-        if quant:
-            kc, vc, ks, vs = cache
+        quant = len(cache) == 3
+        ks = vs = None
+        if c.latent is not None:
+            kc, vc = cache          # the compressed rows, the rotary keys
+        elif quant:
+            kc, ks, vs = cache      # the one page-major pool, its scales
         else:
-            kc, vc = cache
-            ks = vs = None
+            kc, = cache
         tp = self.tp
         if tp > 1:
             # inside the shard_map body: this shard's contiguous head
@@ -960,7 +989,7 @@ class LlamaGenerator:
                 # prior context from the paged cache + this step's own rows
                 # (causal), one mixed-mode kernel call; the fresh rows are
                 # committed to the cache only at the end of the step.  Under
-                # tp the pool kc/vc is already this shard's head planes
+                # tp the pool kc is already this shard's heads of every page
                 # (per-shard storage), q/k/v slice to
                 # the matching head block, and each shard's kernel DMAs only
                 # its own heads' pages; the head-axis all_gather restores the
@@ -974,7 +1003,7 @@ class LlamaGenerator:
                         v, shard * kvh_l, kvh_l, axis=2)
                 else:
                     q_a, k_a, v_a = q, k, v
-                attn = ragged_paged_attention(q_a, kc, vc, block_tables,
+                attn = ragged_paged_attention(q_a, kc, block_tables,
                                               ctx_prev, q_lens=ql,
                                               k_new=k_a, v_new=v_a,
                                               k_scale=ksl, v_scale=vsl,
@@ -1107,14 +1136,12 @@ class LlamaGenerator:
             if quant:
                 # quantize fresh K/V per page on the way in (page-level
                 # RMW: the absmax scale covers every row of the page)
-                kc, vc, ks, vs = write_kv_pages_all_layers_quantized(
-                    kc, vc, ks, vs, k_all, v_all, positions, ql,
+                out_cache = write_kv_pages_all_layers_quantized(
+                    kc, ks, vs, k_all, v_all, positions, ql,
                     block_tables, self.max_seq_len)
-                out_cache = (kc, vc, ks, vs)
             else:
-                kc, vc = write_kv_pages_all_layers(kc, vc, k_all, v_all,
-                                                   slots)
-                out_cache = (kc, vc)
+                out_cache = (write_kv_pages_all_layers(kc, k_all, v_all,
+                                                       slots),)
         if ssm_state is not None:
             out_cache += (carry[1],
                           conv_all.reshape(conv_state.shape))
@@ -1693,7 +1720,7 @@ class ContinuousBatchingEngine:
                 min_pages=flags.flag("prefix_cache_min_pages"))
             self._cow_jit = self.g.pool_jit(
                 functools.partial(_cow_copy_pages,
-                                  page_axis=self.g.cache.page_axis),
+                                  page_axes=self.g.cache.page_axes),
                 "pool_cow_copy", n_extra=2)
             # warm the copy program with an all-no-op call so the first
             # cache hit (and every later one) stays zero-recompile
@@ -1832,7 +1859,7 @@ class ContinuousBatchingEngine:
         if all(r is None for r in self.slot_req):
             span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
                               gemm_rows=0, kv_read_tokens=0, attn_rows=0,
-                              waiting=len(self.waiting))
+                              page_copies=0, waiting=len(self.waiting))
             return self._drain() if self._pending else []
         g = self.g
         B = self.B
@@ -1878,7 +1905,8 @@ class ContinuousBatchingEngine:
                 kind="spec", T=k, rows=rows, q_tokens=rows * k,
                 gemm_rows=B * k, waiting=len(self.waiting),
                 kv_read_tokens=g.kv_read_tokens(attends),
-                attn_rows=g.attn_rows(k, attends))
+                attn_rows=g.attn_rows(k, attends),
+                page_copies=g.page_copies(attends))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
             self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
@@ -1956,6 +1984,7 @@ class ContinuousBatchingEngine:
                           rows=rows, q_tokens=q_tokens, gemm_rows=gemm_rows,
                           kv_read_tokens=g.kv_read_tokens(attends),
                           attn_rows=g.attn_rows(T, attends),
+                          page_copies=g.page_copies(attends),
                           waiting=len(self.waiting))
         if g.spec.ssm is not None:
             # the slots whose recurrent state the step's scan calls read
@@ -2155,7 +2184,7 @@ class ContinuousBatchingEngine:
         ``last_stats``).  With the cache off, every prefix counter is 0."""
         s = self.g.cache.allocator.stats()
         s["kv_cache_dtype"] = ("int8" if self.g.cache.quantized
-                               else str(self.g.cache.k.dtype))
+                               else str(self.g.cache.dtype))
         # capacity advertisement (tensor-parallel serving): /statusz
         # carries these so the router's capacity-weighted placement can
         # rank heterogeneous fleets (a tp=4 replica outranks tp=1)

@@ -7,12 +7,14 @@ lists) and AnalysisPredictor's buffer management
 (paddle/fluid/inference/api/analysis_predictor.h:105).
 
 TPU-first split of responsibilities:
-- **Device**: one K and one V pool, laid out head-major
-  ``[layers, kv_heads, num_pages, page_size, head_dim]`` — static shapes,
-  donated through the jitted decode step so XLA updates pages in place,
-  and each (head, page) tile is a native ``[page_size, head_dim]`` VMEM
-  block for the Pallas kernel.  The decode step must treat the pool as
-  read-only until one batched end-of-step commit (see generation.py) —
+- **Device**: ONE pool array, laid out page-major ``[layers, num_pages, 2,
+  kv_heads, page_size, head_dim]``: every head's K, then every head's V, of
+  one page of one layer is one contiguous run in HBM (32 KB at 4 KV heads
+  of 128 in bf16, 64 KB at 8), which the Pallas kernel fetches with ONE
+  copy, and each head's ``[page_size, head_dim]`` of it is whole tiles of
+  the block it lands in.  Static shapes, donated through the jitted decode
+  step so XLA updates pages in place.  The decode step must treat the pool
+  as read-only until one batched end-of-step commit (see generation.py) —
   a scan that carries the cache copies all of it every step.
 - **Host**: a free-list page allocator (pure Python — page bookkeeping is
   control flow, not math) producing the int32 block tables / context-lens /
@@ -344,17 +346,26 @@ class RecurrentState:
 class PagedKVCache:
     """Device KV pool for all layers + the allocator that addresses it.
 
+    The pool is ``kv [layers, num_pages, 2, kv_heads, page_size,
+    head_dim]`` (a page's K and V of every head together: the unit the
+    kernel copies); ``.arrays`` is ``(kv,)``, and ``page_axes`` /
+    ``head_axes`` say, array by array, where pages and KV heads are
+    counted, so that whoever copies, spills or snapshots a page
+    (``page_planes``) never restates the layout.
+
     ``dtype="int8"`` stores the pool quantized (the ISSUE 13 memory
     plane): int8 pages with one fp32 absmax scale per (layer, kv-head,
-    page) riding in ``k_scale``/``v_scale``.  The ragged paged-attention
+    page) riding in ``k_scale``/``v_scale`` (``[layers, kv_heads,
+    num_pages]``: one layer's plane is the kernel's scalar-prefetched
+    ``[kv_heads, num_pages]``).  The ragged paged-attention
     kernel dequantizes on its VMEM slot right after the DMA wait and the
     engine's batched commit requantizes per page on the way in, so
     nothing above the cache changes shape — the pool just holds ~4x more
     tokens per HBM byte.
 
     Under tensor-parallel serving (``mesh=`` + ``axis=``) page *storage*
-    is shard-local: the pools (and int8 scale rows) are laid out
-    ``[num_kv_heads/mp, ...]`` per device via a NamedSharding on the
+    is shard-local: the pool (and int8 scale rows) holds
+    ``num_kv_heads/mp`` heads per device via a NamedSharding on the
     kv-head axis, while page ids, block tables, the allocator, the
     prefix cache and the spill ring stay host-global.  ``np.asarray`` on
     a page slice gathers the full global plane, so migration snapshots
@@ -369,12 +380,14 @@ class PagedKVCache:
     row ``t % (page_size / 2)``, lanes ``[(t // (page_size / 2)) * rope,
     + rope)``): 64 numbers would be padded to 128 lanes in HBM, two tokens
     fill them, and a page stays one whole tile of each array.  Pages are on
-    axis 1 (``page_axis``).  No int8 plane and no tensor-parallel layout:
-    both are refused here.
+    axis 1 (``page_axis``), as the per-head pool's are; ``.arrays`` is
+    ``(k, v)``.  No int8 plane and no tensor-parallel layout: both are
+    refused here.
 
     ``recurrent`` (a ``RecurrentState``): the slots' fixed state rides
-    with the pool as the last two of ``.arrays``, donated and updated with
-    it; a float per-head pool on one device only."""
+    with the pool as the last two of ``.arrays`` (``(kv, ssm, conv)``),
+    donated and updated with it; a float per-head pool on one device
+    only."""
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype="bfloat16",
@@ -394,8 +407,10 @@ class PagedKVCache:
                 "inference/kv_cache.py: a recurrent state rides with a "
                 "float per-head pool on one device: no int8 plane, no "
                 "tensor-parallel layout, no latent pool")
-        # the axis of every plane that counts pages
-        self.page_axis = 2 if latent is None else 1
+        # the axis of the pool that counts pages (``page_axes``: of every
+        # array of ``.arrays``)
+        self.page_axis = 1
+        self.kv = self.k = self.v = None    # the per-head pool | a latent's
         if latent is not None:
             if self.quantized:
                 raise ValueError(
@@ -422,14 +437,14 @@ class PagedKVCache:
             raise ValueError(
                 f"num_kv_heads={num_kv_heads} not divisible by "
                 f"tensor-parallel degree {mesh.shape[axis]}")
-        shape = (num_layers, num_kv_heads, num_pages, page_size, head_dim)
+        shape = (num_layers, num_pages, 2, num_kv_heads, page_size, head_dim)
         if self.quantized:
-            self.k = self._pool(shape, jnp.int8, jnp.zeros)
-            self.v = self._pool(shape, jnp.int8, jnp.zeros)
+            self.kv = self._pool(shape, jnp.int8, jnp.zeros, head_axis=3)
             # all-zero pages dequantize to exactly 0 under any scale;
             # 1.0 keeps untouched pages' dequant well-defined
-            self.k_scale = self._pool(shape[:3], jnp.float32, jnp.ones)
-            self.v_scale = self._pool(shape[:3], jnp.float32, jnp.ones)
+            planes = (num_layers, num_kv_heads, num_pages)
+            self.k_scale = self._pool(planes, jnp.float32, jnp.ones, 1)
+            self.v_scale = self._pool(planes, jnp.float32, jnp.ones, 1)
             # pool bytes saved vs an equal-page fp32 pool (K and V, minus
             # the scale planes) — the capacity headroom the quantized
             # plane buys at fixed HBM budget
@@ -437,49 +452,95 @@ class PagedKVCache:
             saved = 2 * (per * page_size * head_dim * 3 - per * 4)
             _serving_bump("kv.quant_bytes_saved", max(saved, 0))
         else:
-            dt = jnp.dtype(dtype)
-            self.k = self._pool(shape, dt, jnp.zeros)
-            self.v = self._pool(shape, dt, jnp.zeros)
+            self.kv = self._pool(shape, jnp.dtype(dtype), jnp.zeros, 3)
             self.k_scale = None
             self.v_scale = None
         self.allocator = PageAllocator(num_pages, page_size)
 
-    def _pool(self, shape, dt, fill):
-        """One pool plane: host-global shape, shard-local storage on the
-        kv-head axis (axis 1) when a mesh is configured."""
+    def _head_spec(self, head_axis):
+        from jax.sharding import PartitionSpec
+        return PartitionSpec(*(None,) * head_axis, self.axis)
+
+    def _pool(self, shape, dt, fill, head_axis):
+        """One array of the pool: host-global shape, shard-local storage
+        on its kv-head axis when a mesh is configured."""
         arr = fill(shape, dt)
         if self.mesh is None:
             return arr
-        from jax.sharding import NamedSharding, PartitionSpec
-        spec = PartitionSpec(None, self.axis)
-        return jax.device_put(arr, NamedSharding(self.mesh, spec))
+        from jax.sharding import NamedSharding
+        return jax.device_put(
+            arr, NamedSharding(self.mesh, self._head_spec(head_axis)))
 
     @property
     def arrays(self):
-        """The donated device state of one engine step: ``(k, v)`` for a
-        float pool (a latent pool's compressed rows and rotary keys),
-        ``(k, v, k_scale, v_scale)`` when quantized, ``(k, v, ssm, conv)``
-        with a recurrent state."""
+        """The donated device state of one engine step: ``(kv,)`` for a
+        float per-head pool, ``(kv, k_scale, v_scale)`` when quantized,
+        ``(kv, ssm, conv)`` with a recurrent state; ``(k, v)`` for a latent
+        pool (its compressed rows and rotary keys)."""
+        if self.latent is not None:
+            return self.k, self.v
         if self.quantized:
-            return self.k, self.v, self.k_scale, self.v_scale
+            return self.kv, self.k_scale, self.v_scale
         if self.recurrent is not None:
-            return (self.k, self.v) + self.recurrent.arrays
-        return self.k, self.v
+            return (self.kv,) + self.recurrent.arrays
+        return (self.kv,)
+
+    @property
+    def dtype(self):
+        """The type the pool's pages are stored in."""
+        return (self.k if self.latent is not None else self.kv).dtype
+
+    @property
+    def page_axes(self):
+        """The axis that counts pages, for each array of ``.arrays`` that
+        holds pages (a recurrent state's two hold none and get none): 1 for
+        the pool (and a latent pool's two), 2 for an int8 pool's scale
+        planes."""
+        if self.latent is not None:
+            return 1, 1
+        return (1, 2, 2) if self.quantized else (1,)
+
+    @property
+    def head_axes(self):
+        """The axis that counts KV heads in ONE PAGE's plane of each array
+        (``page_planes``: the page axis taken out), which is where a
+        tensor-parallel shard finds its heads in a host-global plane."""
+        return (2, 1, 1) if self.quantized else (2,)
 
     @property
     def pspecs(self):
         """shard_map partition specs matching ``.arrays`` order: every
-        plane (pools AND scale rows) is sharded on the kv-head axis."""
-        from jax.sharding import PartitionSpec
-        spec = PartitionSpec(None, self.axis)
+        array (the pool AND the scale rows) is sharded on its kv-head
+        axis."""
         if self.quantized:
-            return spec, spec, spec, spec
-        return spec, spec
+            return self._head_spec(3), self._head_spec(1), self._head_spec(1)
+        return (self._head_spec(3),)
 
-    def update(self, k, v, *rest) -> None:
+    def page_planes(self, page_id: int):
+        """One page as the host sees it: for each array of ``.arrays`` its
+        slice at ``page_id`` (all layers; the pool's is one contiguous
+        ``[2, kv_heads, page_size, head_dim]`` a layer), host-global under
+        a mesh.  What the spill ring keeps and a snapshot is made from:
+        a marked, intentional host<->device sync (it blocks until every
+        already-dispatched write to the page has executed)."""
+        from .. import observability as _obs
+        _obs.count_sync()
+        return tuple(np.asarray(arr[(slice(None),) * ax + (page_id,)])
+                     for arr, ax in zip(self.arrays, self.page_axes))
+
+    def page_plane_zeros(self):
+        """Device zeros shaped like ``page_planes``' (to warm an upload
+        program with)."""
+        return tuple(jnp.zeros(arr.shape[:ax] + arr.shape[ax + 1:], arr.dtype)
+                     for arr, ax in zip(self.arrays, self.page_axes))
+
+    def update(self, *arrays) -> None:
         """Store the cache arrays returned by a jitted (donating) step, in
         ``.arrays``' order."""
-        self.k, self.v = k, v
+        if self.latent is not None:
+            self.k, self.v = arrays
+            return
+        self.kv, *rest = arrays
         if self.quantized:
             self.k_scale, self.v_scale = rest
         elif self.recurrent is not None:

@@ -33,6 +33,7 @@ runtime uses for any host buffer.
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -45,15 +46,16 @@ from .. import observability as _obs
 _SWAPIN_BOUNDS = [0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0]
 
 
-def _upload_page(cache, page, host):
-    """Scatter one spilled page's host bytes back into the pool tuple.
+def _upload_page(cache, page, host, page_axes):
+    """Scatter one spilled page's host bytes back into the pool tuple
+    (``page_axes``: where each array counts pages,
+    ``PagedKVCache.page_axes``).
 
     ``page`` is traced, so one compile serves every swap-in; a page id
     of ``num_pages`` (the warmup call) is dropped by the scatter."""
-    out = list(cache)
-    for i, h in enumerate(host):
-        out[i] = cache[i].at[:, :, page].set(h, mode="drop")
-    return tuple(out)
+    return tuple(
+        arr.at[(slice(None),) * ax + (page,)].set(h, mode="drop")
+        for arr, h, ax in zip(cache, host, page_axes))
 
 
 def make_upload_program(cache):
@@ -61,25 +63,27 @@ def make_upload_program(cache):
 
     Single-device pools jit ``_upload_page`` directly.  Tensor-parallel
     pools (``cache.mesh`` set) keep the HOST side of the wire format
-    global — a spilled/migrated page plane is always the full
-    ``[layers, num_kv_heads, ...]`` array — and re-shard on install: the
-    shard_map body slices each host plane to its shard's kv-head block
-    (every plane, pools and int8 scale rows alike, carries kv heads on
-    axis 1) before the scatter into shard-local storage.  Spill ring,
+    global — a spilled page plane always holds every KV head — and
+    re-shard on install: the shard_map body slices each host plane to its
+    shard's kv-head block (``cache.head_axes``: where a page's plane of
+    the pool, and of an int8 scale row, counts heads) before the scatter
+    into shard-local storage.  Spill ring,
     migration import and warmup all share this one program, so swap-in
     bytes and compile counts are identical at any shard count."""
+    upload = functools.partial(_upload_page, page_axes=cache.page_axes)
     if getattr(cache, "mesh", None) is None:
-        return jax.jit(_obs.tracing.named(_upload_page, "pool_swap_in"),
+        return jax.jit(_obs.tracing.named(upload, "pool_swap_in"),
                        donate_argnums=(0,))
     axis = cache.axis
+    head_axes = cache.head_axes
+    heads = cache.num_kv_heads // cache.mesh.shape[axis]
 
     def _sharded(pool, page, host):
         i = jax.lax.axis_index(axis)
         local = tuple(
-            jax.lax.dynamic_slice_in_dim(h, i * p.shape[1], p.shape[1],
-                                         axis=1)
-            for p, h in zip(pool, host))
-        return _upload_page(pool, page, local)
+            jax.lax.dynamic_slice_in_dim(h, i * heads, heads, axis=ax)
+            for h, ax in zip(host, head_axes))
+        return upload(pool, page, local)
 
     from jax.sharding import PartitionSpec
     rep = PartitionSpec()
@@ -103,8 +107,8 @@ class HostSpillPool:
         if getattr(cache, "latent", None) is not None:
             raise ValueError(
                 "inference/kv_spill.py: the host spill ring copies per-head "
-                "pages (axis 2 of every plane); a latent pool has no head "
-                "axis: build the engine with kv_spill_pages=0")
+                "pages; a latent pool has no head axis: build the engine "
+                "with kv_spill_pages=0")
         if getattr(cache, "recurrent", None) is not None:
             raise ValueError(
                 "inference/kv_spill.py: the host spill ring holds pages; a "
@@ -152,9 +156,7 @@ class HostSpillPool:
         executed, so the host copy is exactly the bytes the pool held."""
         if not self._free:
             return None
-        _obs.count_sync()                # eviction-path page readback
-        host = tuple(np.asarray(arr[:, :, page_id])
-                     for arr in self.cache.arrays)
+        host = self.cache.page_planes(page_id)   # counts its sync
         slot = self._free.pop()
         self._slots[slot] = host
         self.spilled_pages += 1
@@ -195,7 +197,6 @@ class HostSpillPool:
         """Compile the upload program with an out-of-range page id (the
         scatter drops every write) so the first real swap-in — and every
         later one — is dispatch-only."""
-        zeros = tuple(jnp.zeros(arr.shape[:2] + arr.shape[3:], arr.dtype)
-                      for arr in self.cache.arrays)
         self.cache.update(*self._upload(
-            self.cache.arrays, jnp.int32(self.cache.k.shape[2]), zeros))
+            self.cache.arrays, jnp.int32(self.cache.allocator.num_pages),
+            self.cache.page_plane_zeros()))

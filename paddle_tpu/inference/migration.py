@@ -154,7 +154,7 @@ def _geometry(engine) -> Dict[str, object]:
             "kv_heads": cache.num_kv_heads,
             "page_size": cache.page_size,
             "head_dim": cache.head_dim,
-            "dtype": "int8" if cache.quantized else str(cache.k.dtype)}
+            "dtype": "int8" if cache.quantized else str(cache.dtype)}
 
 
 def _check_geometry(engine, snap: dict) -> None:
@@ -166,14 +166,30 @@ def _check_geometry(engine, snap: dict) -> None:
             f"{mine}; migration moves raw pool bytes and cannot convert")
 
 
+def _wire_planes(planes) -> Tuple[np.ndarray, ...]:
+    """A page as the pool (and the spill ring) holds it, ``(kv [layers, 2,
+    kv_heads, page_size, head_dim][, k_scale, v_scale])``, in the
+    snapshot's form: ``(k, v[, k_scale, v_scale])``, each ``[layers,
+    kv_heads, ...]``.  The wire stays head-major, as it was before the pool
+    became page-major, so a stored snapshot and its digest keep their
+    meaning and ``SNAP_VERSION`` stands."""
+    kv, *scales = planes
+    return (kv[:, 0], kv[:, 1], *scales)
+
+
+def _pool_planes(planes) -> Tuple[np.ndarray, ...]:
+    """A snapshot's page back in the pool's form (``_wire_planes``
+    undone), for the upload program."""
+    k, v, *scales = planes
+    return (np.stack([k, v], axis=1), *scales)
+
+
 def _page_planes(engine, page_id: int) -> Tuple[np.ndarray, ...]:
-    """One device page's raw planes, in ``cache.arrays`` order — int8
-    pools ship ``(k int8, v int8, k_scale, v_scale)`` untouched.  The
-    readback is a marked intentional sync on the migration control
-    path."""
-    _obs.count_sync()
-    return tuple(np.asarray(arr[:, :, page_id])
-                 for arr in engine.g.cache.arrays)
+    """One device page's raw planes in the snapshot's form — int8 pools
+    ship ``(k int8, v int8, k_scale, v_scale)`` untouched.  The readback
+    is a marked intentional sync on the migration control path
+    (``page_planes`` counts it)."""
+    return _wire_planes(engine.g.cache.page_planes(page_id))
 
 
 def _encode_planes(planes) -> List[dict]:
@@ -306,7 +322,7 @@ def export_session(engine, req_id: Optional[int] = None,
             if node.spill is not None:
                 # spilled page: the bytes already live in host RAM —
                 # ship the ring slot's planes directly, no swap-in
-                planes = engine.spill.peek(node.spill)
+                planes = _wire_planes(engine.spill.peek(node.spill))
                 snap["pages"].append({"index": i, "source": "spill",
                                       "planes": planes})
             elif node.ready:
@@ -387,10 +403,9 @@ def warm(engine) -> None:
     scatter write drops) so the first real import is dispatch-only."""
     _refuse_unsnapshotted(engine)
     cache = engine.g.cache
-    zeros = tuple(jnp.zeros(arr.shape[:2] + arr.shape[3:], arr.dtype)
-                  for arr in cache.arrays)
     cache.update(*_uploader(engine)(
-        cache.arrays, jnp.int32(cache.k.shape[2]), zeros))
+        cache.arrays, jnp.int32(cache.allocator.num_pages),
+        cache.page_plane_zeros()))
 
 
 def import_session(engine, snap: dict, resume: bool = False) -> dict:
@@ -461,7 +476,7 @@ def import_session(engine, snap: dict, resume: bool = False) -> dict:
                 continue
             pid = alloc.acquire_page()
             try:
-                planes = pg["planes"]    # decoded up front
+                planes = _pool_planes(pg["planes"])    # decoded up front
                 engine.g.cache.update(*up(
                     engine.g.cache.arrays, jnp.int32(pid),
                     tuple(jnp.asarray(p) for p in planes)))

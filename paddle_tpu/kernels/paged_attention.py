@@ -16,25 +16,41 @@ TPU-first design (the "Ragged Paged Attention" schedule of arxiv 2604.15464):
   in-kernel with a causal mask, so a serving step never needs a separate
   flash-attention call or an analytic current-token merge — chunked
   prefill rides the decode schedule in one ``pallas_call``.
-- The grid is **(sequence, kv_head)**: one program walks a slot's whole
-  KV in a loop whose trip count comes from ``context_lens`` (and, with a
-  window, from the first page the earliest query sees), so no grid step
-  exists for pages a sequence does not have.  A slot with ``q_lens == 0``
-  does nothing: no DMA, no fold, no normalization (its rows are written
-  as zeros).
-- The KV cache is laid out **head-major**, ``[kv_heads, num_pages,
-  page_size, head_dim]``, and stays in **HBM** (``pl.ANY``).  The walk
-  goes in **blocks of many pages** (``_BLOCK_KEYS`` keys in whole pages,
-  never more than the table's width): the kernel DMAs the pages of the
-  blocks a sequence's context reaches, one copy a page, into ONE
-  contiguous ``[keys, head_dim]`` VMEM tile per K and V, **double-buffered** at block granularity — block
-  ``j+1``'s copies are in flight while block ``j`` is computed.  A block
-  gets one ``QK^T``, one mask, one online-softmax update and one ``PV``,
-  so the score tile is lane-dense and the (m, l, acc) carry is touched
-  once per block, not once per page.
-- GQA is native: a program holds the ``group = q_heads // kv_heads``
-  query rows of all T tokens for one KV head, row ``r`` = token
-  ``r // group``, so K/V pages are fetched ONCE per group and a slot's
+- The grid is **(sequence,)**: one program walks a slot's whole KV once
+  for ALL its KV heads, in a loop whose trip count comes from
+  ``context_lens`` (and, with a window, from the first page the earliest
+  query sees), so no grid step exists for pages a sequence does not have.
+  A slot with ``q_lens == 0`` does nothing: no DMA, no fold, no
+  normalization (its rows are written as zeros); a call has ``slots``
+  such programs at most, not ``slots x kv_heads``.
+- The KV cache is laid out **page-major**, ``[num_pages, 2, kv_heads,
+  page_size, head_dim]`` a layer (``inference/kv_cache.py``), and stays in
+  **HBM** (``pl.ANY``): every head's K, then every head's V, of one page
+  is ONE contiguous run (32 KB at 4 bf16 KV heads of 128, 64 KB at 8) and
+  **the unit of copy**.  A decoding slot's program is bound by ISSUING
+  its copies, not by their bytes (PERF.md section 6, PRs 28 and 35), so
+  a page is one descriptor, not two a KV head.  The walk goes in **blocks
+  of many pages** (``_BLOCK_KEYS`` keys in whole pages, never more than
+  the table's width, nor than two buffers may take of VMEM): the kernel
+  DMAs the pages of the blocks a sequence's context reaches, one copy a
+  page, into a ``[pages, 2, kv_heads, page_size, head_dim]`` VMEM buffer,
+  **double-buffered** at block granularity — block ``j+1``'s copies are
+  in flight while block ``j`` is computed.  Inside a block the KV heads
+  are a loop: head ``h``'s K (and V) over the block's keys is the buffer
+  read at ``[:, 0, h]`` (``[:, 1, h]``), whole ``(page_size, head_dim)``
+  tiles stacked into the ``[keys, head_dim]`` operand without a relayout
+  (``kernel_geometry_error``: a page is a whole number of the pool type's
+  sublane tiles).  A (head, block) gets one ``QK^T``, one mask, one
+  online-softmax update and one ``PV``, so the score tile is lane-dense
+  and the (m, l, acc) carry is touched once per block, not once per page.
+- **VMEM** follows from the static shapes (``vmem_limit_bytes``): two KV
+  buffers (4 MiB at 4 bf16 KV heads and 1,024 keys, 8 MiB at 8), the
+  (m, l, acc) carry and the pipeline's q, fresh-row, output and
+  log-sum-exp blocks, which now span the slot's KV heads: 49 MiB for 8
+  heads of 1,024 query rows, of the chip's 128.
+- GQA is native: for each KV head a program holds the ``group = q_heads
+  // kv_heads`` query rows of all T tokens, row ``r`` = token
+  ``r // group``, so K/V pages are fetched ONCE per slot and a head's
   live rows are the prefix ``[0, q_len * group)``.  The program loops
   over **row tiles** of that prefix only (``row_tile``): a decoding slot
   inside a T = 64 step computes one tile, not ``64 * group`` rows.  The
@@ -86,6 +102,10 @@ _SUBLANE = 8      # f32 sublane count — a query-row block pads to a multiple
 # hardly fill one block, 7 % longer; 512 rows or 2,048 keys gain no more.
 _ROW_TILE = 256
 _BLOCK_KEYS = 1024
+# VMEM the two KV buffers of a block may take: a page holds every KV head's
+# K and V, so where a block of ``_BLOCK_KEYS`` keys would pass it (32 bf16
+# KV heads: 32 MiB) the block holds fewer pages
+_KV_BUFFER_BYTES = 16 << 20
 
 
 # --------------------------------------------------------------- oracles ---
@@ -192,6 +212,24 @@ def _reference_ragged_paged_attention(q, k_cache, v_cache, block_tables,
     return out, lse
 
 
+def pool_of_heads(k, v):
+    """Head-major K and V planes ``[..., kv_heads, num_pages, page_size,
+    head_dim]`` packed into the page-major form the kernel reads, ``[...,
+    num_pages, 2, kv_heads, page_size, head_dim]`` (the Paddle-facing
+    entry points and the tests build pools with it)."""
+    return jnp.moveaxis(jnp.stack([k, v], axis=-5), -3, -5)
+
+
+def heads_of_pool(kv):
+    """The head-major view of a page-major pool (one layer's, or all
+    layers'): ``(k, v)``, each ``[..., kv_heads, num_pages, page_size,
+    head_dim]``.  The ONE place that restates the layout for the XLA
+    oracles, which take head-major planes and so stay an independent check
+    of it."""
+    kv = jnp.moveaxis(kv, -5, -3)
+    return kv[..., 0, :, :, :, :], kv[..., 1, :, :, :, :]
+
+
 # ---------------------------------------------------------------- kernel ---
 
 def _padded_rows(t, group):
@@ -221,30 +259,54 @@ def attn_rows(q_lens, t, group):
     return sum(-(-int(q) * group // tile) * tile for q in q_lens if q > 0)
 
 
-def _pages_per_block(page_size, max_pages):
+def _pages_per_block(page_size, max_pages, page_bytes=0):
     """Pages of one KV block: ``_BLOCK_KEYS`` keys, never more than the
-    block table is wide."""
-    return max(1, min(_BLOCK_KEYS // page_size, max_pages))
+    block table is wide, nor than two buffers of ``page_bytes`` a page
+    (every KV head's K and V) may take of VMEM."""
+    ppb = min(_BLOCK_KEYS // page_size, max_pages)
+    if page_bytes:
+        ppb = min(ppb, _KV_BUFFER_BYTES // (2 * page_bytes))
+    return max(1, ppb)
+
+
+def page_copies(rows, page_size, max_pages, page_bytes=0, window=None):
+    """DMA descriptors one layer's call starts for ``rows`` = [(query
+    tokens, context before them)]: a working slot fetches every page of
+    every block its walk reaches (whole blocks, one copy a page).  Host
+    arithmetic beside ``attn_rows`` (the engine's ``page_copies``)."""
+    ppb = _pages_per_block(page_size, max_pages, page_bytes)
+    n = 0
+    for q, ctx in rows:
+        if q <= 0:
+            continue
+        first = 0 if window is None else max(ctx + 1 - window, 0) // page_size
+        n += -(-max(-(-ctx // page_size) - first, 0) // ppb) * ppb
+    return n
 
 
 def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
                               has_new, quantized=False, window=None,
                               layered=False):
-    """One (slot, kv_head) program: the whole walk over the slot's KV.
+    """One slot's program: the whole walk over the slot's KV, once for ALL
+    its KV heads.
 
-    The slot's live query rows are the prefix ``[0, q_len * group)`` of
-    its block (row ``r`` = token ``r // group``), covered by ``n_tiles``
-    row tiles of ``tile`` rows; a slot with ``q_len == 0`` covers none and
-    fetches nothing.  The KV is walked in blocks of ``ppb`` pages: a
-    block's pages are copied (one DMA a page) into ONE contiguous
-    ``[ppb * page_size, d]`` tile per K and V, block ``j + 1``'s copies in
-    flight while block ``j`` is computed (two buffers), and every row tile
-    takes one ``QK^T``, one mask, one online-softmax update and one ``PV``
-    per block.  ``q`` and ``k`` enter the MXU as stored (a bf16 x bf16
+    The slot's live query rows are, for every KV head, the prefix ``[0,
+    q_len * group)`` of that head's block (row ``r`` = token ``r //
+    group``), covered by ``n_tiles`` row tiles of ``tile`` rows; a slot
+    with ``q_len == 0`` covers none and fetches nothing.  The KV is walked
+    in blocks of ``ppb`` pages.  A page of the pool is ONE contiguous run
+    ``[2, kv_heads, page_size, d]`` (every head's K, then every head's V)
+    and ONE copy, block ``j + 1``'s copies in flight while block ``j`` is
+    computed (two buffers).  Inside a block the KV heads are a loop; head
+    ``h``'s K (and V) over the block's keys is read out of the buffer as
+    the ``[ppb * page_size, d]`` operand it is, and every row tile takes
+    one ``QK^T``, one mask, one online-softmax update and one ``PV`` per
+    (head, block).  ``q`` and ``k`` enter the MXU as stored (a bf16 x bf16
     product is exact in the float32 accumulator; ``1/sqrt(d)`` is applied
     to the float32 scores); the probabilities stay float32 into ``PV``.
-    After the walk each tile folds the step's own K/V rows with a causal mask and
-    normalizes.  Rows of the tiles past ``n_tiles`` are written as zeros.
+    After the walk each (head, tile) folds the step's own K/V rows with a
+    causal mask and normalizes.  Rows of the tiles past ``n_tiles`` are
+    written as zeros.
 
     ``quantized`` (int8 pool): the DMA moves the pages' int8 bytes and the
     per-(kv-head, page) fp32 scales ride the scalar-prefetch channel
@@ -257,8 +319,8 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
     The walk starts at the page that holds the EARLIEST query's first
     visible key (``first``): pages wholly behind it are never fetched.
 
-    ``layered`` (static): the caches are the WHOLE pool ``[layers,
-    kv_heads, num_pages, page_size, head_dim]`` in HBM and the layer to
+    ``layered`` (static): the cache is the WHOLE pool ``[layers,
+    num_pages, 2, kv_heads, page_size, head_dim]`` in HBM and the layer to
     read rides the scalar-prefetch channel, so the caller never slices a
     layer out of the pool (a slice handed to a kernel is a copy).
     """
@@ -273,16 +335,15 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
     q_ref = next(it)
     knew_ref = next(it) if has_new else None
     vnew_ref = next(it) if has_new else None
-    k_hbm, v_hbm = next(it), next(it)
+    kv_hbm = next(it)
     o_ref, lse_ref = next(it), next(it)
-    kbuf, vbuf, sem = next(it), next(it), next(it)
+    kvbuf, sem = next(it), next(it)
     m_ref, l_ref, acc_ref = next(it), next(it), next(it)
 
     b = pl.program_id(0)
-    h = pl.program_id(1)
     ctx = cl_ref[b]
     ql = ql_ref[b]
-    rows, d = q_ref.shape
+    kvh, rows, d = q_ref.shape
     keys = ppb * page_size
     # all int scalars must stay strongly-typed int32: python-int divisors /
     # clip bounds embed i64 literals under x64 mode, and the i64->i32
@@ -303,15 +364,11 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
         jnp.maximum(pages_total - first, _I0) + ppb_c - one, ppb_c)
     # q and k meet in q's dtype where the cache holds it or int8 (exact
     # there), else (a float32 cache under bf16 queries) in float32
-    mxu = q_ref.dtype if kbuf.dtype in (q_ref.dtype, jnp.int8) \
+    mxu = q_ref.dtype if kvbuf.dtype in (q_ref.dtype, jnp.int8) \
         else jnp.float32
 
     def rows_of(i):
         return pl.ds(pl.multiple_of(i * tile_c, tile), tile)
-
-    def page_rows(i):
-        """Rows of page ``i`` of a block in the K and V buffers."""
-        return pl.ds(pl.multiple_of(i * ps_c, page_size), page_size)
 
     def for_live_tiles(body):
         """``body(i)`` for every live row tile (a block of one tile has
@@ -326,55 +383,73 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
 
         jax.lax.fori_loop(_I0, n_tiles, step, _I0)
 
+    def for_heads(body):
+        """``body(h)`` for every KV head: a loop with a dynamic index, not
+        ``kv_heads`` copies of the body (what is written out is lowered at
+        every set-up).  Static bounds: a while_loop keeps the counter
+        int32 under x64."""
+        if kvh == 1:
+            body(_I0)
+            return
+
+        def step(h):
+            body(h)
+            return h + one
+
+        jax.lax.while_loop(lambda h: h < i32(kvh), step, _I0)
+
     def fetch(j, slot):
         """Start the copies of block ``j`` into buffer ``slot``: one DMA a
-        page, K's on one semaphore and V's on another.  The whole block
-        is fetched whatever the context holds of it (an entry past the
-        context, or past the table, names a valid page whose keys are
-        masked), so one wait takes all copies.  A loop, not ``ppb``
-        copies written out: unrolled, the kernel takes ten times as long
-        to lower, which every run pays for every step program."""
+        page, every head's K and V at once.  The whole block is fetched
+        whatever the context holds of it (an entry past the context, or
+        past the table, names a valid page whose keys are masked), so one
+        wait takes all copies.  A loop, not ``ppb`` copies written out:
+        unrolled, the kernel takes ten times as long to lower, which every
+        run pays for every step program."""
         p0 = first + j * ppb_c
 
         def page(i):
             pid = bt_ref[b, jnp.minimum(p0 + i, last_entry)]
-            for hbm, buf, col in ((k_hbm, kbuf, _I0), (v_hbm, vbuf, one)):
-                src = hbm.at[ly_ref[0], h, pid] if layered \
-                    else hbm.at[h, pid]
-                pltpu.make_async_copy(src, buf.at[slot, page_rows(i)],
-                                      sem.at[slot, col]).start()
+            src = kv_hbm.at[ly_ref[0], pid] if layered else kv_hbm.at[pid]
+            pltpu.make_async_copy(src, kvbuf.at[slot, i],
+                                  sem.at[slot]).start()
             return i + one
 
-        # static bounds: a while_loop keeps the counter int32 under x64
         jax.lax.while_loop(lambda i: i < ppb_c, page, _I0)
 
     def wait(slot):
         """Wait for the ``ppb`` page copies into buffer ``slot``: a DMA
         semaphore counts bytes, and this descriptor is a block's."""
-        for buf, col in ((kbuf, _I0), (vbuf, one)):
-            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
-                                  sem.at[slot, col]).wait()
+        pltpu.make_async_copy(kvbuf.at[slot], kvbuf.at[slot],
+                              sem.at[slot]).wait()
 
-    def accumulate(r, s, v, v_scale=None):
-        """Online-softmax update of row tile ``r`` of the (m, l, acc)
-        scratch with scores ``s`` over the keys whose values are ``v``."""
-        m_prev, l_prev = m_ref[r, :], l_ref[r, :]
+    def keys_of(slot, which, h):
+        """Head ``h``'s K (``which`` 0) or V (1) over the block in buffer
+        ``slot``, ``[keys, d]``: a page's ``[page_size, d]`` of one head is
+        whole tiles, so the pages stack without a relayout."""
+        return kvbuf[slot, :, which, h].reshape(keys, d)
+
+    def accumulate(h, r, s, v, v_scale=None):
+        """Online-softmax update of row tile ``r`` of head ``h``'s (m, l,
+        acc) scratch with scores ``s`` over the keys whose values are
+        ``v``."""
+        m_prev, l_prev = m_ref[h, r, :], l_ref[h, r, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        m_ref[r, :] = m_new
-        l_ref[r, :] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[h, r, :] = m_new
+        l_ref[h, r, :] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         if v_scale is not None:
             p = p * v_scale
         # float32 p x v as stored: the compiler's mixed product (a split
         # of p into three bf16 terms by hand measured 15-35 % slower)
-        acc_ref[r, :] = alpha * acc_ref[r, :] + jax.lax.dot_general(
+        acc_ref[h, r, :] = alpha * acc_ref[h, r, :] + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    def scores(r, k):
+    def scores(h, r, k):
         return jax.lax.dot_general(
-            q_ref[r, :].astype(mxu), k, (((1,), (1,)), ((), ())),
+            q_ref[h, r, :].astype(mxu), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
 
     def tokens_of(i):
@@ -382,9 +457,9 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
         r = i * tile_c + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
         return jax.lax.div(r, jnp.full((tile, 1), group, jnp.int32))
 
-    def column_scales(sc_ref, p0):
-        """[1, keys]: the dequant scale of each key column of the block
-        that starts at page ``p0``."""
+    def column_scales(sc_ref, h, p0):
+        """[1, keys]: head ``h``'s dequant scale of each key column of the
+        block that starts at page ``p0``."""
         col = jax.lax.broadcasted_iota(jnp.int32, (1, keys), 1)
         out = jnp.zeros((1, keys), jnp.float32)
         for i in range(ppb):
@@ -406,51 +481,56 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
         # sequence's: their scores are masked, but p = 0 times a V that is
         # not finite would still be NaN, so no value of theirs is kept
         def clear(i, c):
-            vbuf[slot, page_rows(i), :] = jnp.zeros((page_size, d),
-                                                    vbuf.dtype)
+            kvbuf[slot, i, 1] = jnp.zeros((kvh, page_size, d), kvbuf.dtype)
             return c
 
         jax.lax.fori_loop(jnp.minimum(pages_total - p0, ppb_c), ppb_c,
                           clear, _I0)
-
-        k = kbuf[slot].astype(mxu)                          # [keys, d]
-        v = vbuf[slot]
-        if quantized:        # exact, and the product bf16 pools take
-            v = v.astype(jnp.bfloat16)
-        k_scale = column_scales(ksc_ref, p0) if quantized else None
-        v_scale = column_scales(vsc_ref, p0) if quantized else None
         base = p0 * ps_c
 
-        def row_tile_of_block(i):
-            r = rows_of(i)
-            s = scores(r, k)
-            if quantized:
-                s = s * k_scale
-            pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            seen = pos < ctx
-            if window is not None:
-                seen = jnp.logical_and(
-                    seen, pos > ctx + tokens_of(i) - i32(window))
-            accumulate(r, jnp.where(seen, s, jnp.float32(NEG_INF)), v,
-                       v_scale)
+        def head(h):
+            k = keys_of(slot, 0, h).astype(mxu)             # [keys, d]
+            v = keys_of(slot, 1, h)
+            if quantized:        # exact, and the product bf16 pools take
+                v = v.astype(jnp.bfloat16)
+            k_scale = column_scales(ksc_ref, h, p0) if quantized else None
+            v_scale = column_scales(vsc_ref, h, p0) if quantized else None
 
-        for_live_tiles(row_tile_of_block)
+            def row_tile_of_block(i):
+                r = rows_of(i)
+                s = scores(h, r, k)
+                if quantized:
+                    s = s * k_scale
+                pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                seen = pos < ctx
+                if window is not None:
+                    seen = jnp.logical_and(
+                        seen, pos > ctx + tokens_of(i) - i32(window))
+                accumulate(h, r, jnp.where(seen, s, jnp.float32(NEG_INF)),
+                           v, v_scale)
+
+            for_live_tiles(row_tile_of_block)
+
+        for_heads(head)
         return carry
 
-    def finish(i):
-        r = rows_of(i)
-        if has_new:   # static: compiled in only for the mixed-mode form
-            s = scores(r, knew_ref[...].astype(mxu))         # [tile, Tp]
-            jq = tokens_of(i)
-            jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            valid = jnp.logical_and(jk <= jq, jk < ql)
-            if window is not None:
-                valid = jnp.logical_and(valid, jq - jk < i32(window))
-            s = jnp.where(valid, s, jnp.float32(NEG_INF))
-            accumulate(r, s, vnew_ref[...])
-        l = jnp.maximum(l_ref[r, :], jnp.float32(1e-30))
-        o_ref[r, :] = (acc_ref[r, :] / l).astype(o_ref.dtype)
-        lse_ref[r, :] = m_ref[r, :] + jnp.log(l)
+    def finish(h):
+        def tile_of_head(i):
+            r = rows_of(i)
+            if has_new:   # static: compiled in only for the mixed-mode form
+                s = scores(h, r, knew_ref[h].astype(mxu))    # [tile, Tp]
+                jq = tokens_of(i)
+                jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                valid = jnp.logical_and(jk <= jq, jk < ql)
+                if window is not None:
+                    valid = jnp.logical_and(valid, jq - jk < i32(window))
+                s = jnp.where(valid, s, jnp.float32(NEG_INF))
+                accumulate(h, r, s, vnew_ref[h])
+            l = jnp.maximum(l_ref[h, r, :], jnp.float32(1e-30))
+            o_ref[h, r, :] = (acc_ref[h, r, :] / l).astype(o_ref.dtype)
+            lse_ref[h, r, :] = m_ref[h, r, :] + jnp.log(l)
+
+        for_live_tiles(tile_of_head)
 
     # rows past the live tiles (all of them where the slot holds nothing)
     # are don't-care by contract: they read zeros, never what VMEM held
@@ -469,7 +549,21 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
         jax.lax.fori_loop(_I0, n_blocks, block, _I0)
-        for_live_tiles(finish)
+        for_heads(finish)
+
+
+def _lane_padded_bytes(shape, dtype):
+    """VMEM bytes of a buffer whose last dim is padded to 128 lanes."""
+    *lead, last = shape
+    return int(np.prod(lead, dtype=np.int64)) * (-(-last // 128) * 128) \
+        * jnp.dtype(dtype).itemsize
+
+
+def _vmem_limit(need):
+    """What a call asks of VMEM for ``need`` bytes of buffers it can count
+    from its static shapes: a quarter more (the compiler's own temporaries),
+    at least 32 MiB, and under the chip's 128."""
+    return int(min(max(need * 5 // 4, 32 << 20), 110 << 20))
 
 
 # jitted so that the kernel is traced once for all the call sites of one
@@ -477,29 +571,31 @@ def _ragged_paged_attn_kernel(*refs, page_size, ppb, tile, scale, group,
 # member of a step family again) and lowered once a program: lowering it
 # is paid at every set-up, cache hit or not (PERF.md section 6, PR 28)
 @functools.partial(jax.jit, static_argnames=("interpret", "window"))
-def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
-                                   context_lens, q_lens, k_new, v_new,
-                                   interpret, k_scale=None, v_scale=None,
-                                   window=None, layer=None):
+def _pallas_ragged_paged_attention(q, kv_cache, block_tables, context_lens,
+                                   q_lens, k_new, v_new, interpret,
+                                   k_scale=None, v_scale=None, window=None,
+                                   layer=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, qh, d = q.shape
     layered = layer is not None
-    kvh, n_pages, page_size, _ = k_cache.shape[-4:]
+    n_pages, _, kvh, page_size, _ = kv_cache.shape[-5:]
     group = qh // kvh
     rows = t * group
     R = _padded_rows(t, group)
 
     # [B, T, qh, d] -> [B, kvh, T*group, d]: row r = token*(group) + g, so
-    # one block holds every query row sharing this program's KV head and a
-    # slot's live rows are its prefix [0, q_len * group)
+    # one block holds every query row of the slot, a KV head's together,
+    # and a head's live rows are its prefix [0, q_len * group)
     qg = q.reshape(b, t, kvh, group, d).transpose(0, 2, 1, 3, 4)
     qg = qg.reshape(b, kvh, rows, d)
     if R != rows:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, R - rows), (0, 0)))
 
-    ppb = _pages_per_block(page_size, block_tables.shape[1])
+    page_shape = (2, kvh, page_size, d)
+    ppb = _pages_per_block(page_size, block_tables.shape[1],
+                           math.prod(page_shape) * kv_cache.dtype.itemsize)
     keys = ppb * page_size
 
     # unused table entries must still be valid page ids for the DMA
@@ -509,14 +605,14 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
           else jnp.full((b,), t)).astype(jnp.int32)
 
     def block_of(block_rows, last):
-        return pl.BlockSpec((None, None, block_rows, last),
-                            lambda b_, h, *_: (b_, h, _I0, _I0))
+        return pl.BlockSpec((None, kvh, block_rows, last),
+                            lambda b_, *_: (b_, _I0, _I0, _I0))
 
     has_new = k_new is not None
     operands = [qg]
     in_specs = [block_of(R, d)]
+    Tp = -(-t // _SUBLANE) * _SUBLANE
     if has_new:
-        Tp = -(-t // _SUBLANE) * _SUBLANE
         kn = k_new.transpose(0, 2, 1, 3)        # [B, kvh, T, d]
         vn = v_new.transpose(0, 2, 1, 3)
         if Tp != t:
@@ -535,27 +631,36 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         # SMEM *operand* of a scalar-prefetch grid Mosaic refuses it.)
         scalars += [k_scale.astype(jnp.float32),
                     v_scale.astype(jnp.float32)]
-    operands += [k_cache, v_cache]
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                 pl.BlockSpec(memory_space=pl.ANY)]
+    operands.append(kv_cache)
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
+    tile = row_tile(t, group)
     kernel = functools.partial(
         _ragged_paged_attn_kernel, page_size=page_size, ppb=ppb,
-        tile=row_tile(t, group), scale=1.0 / math.sqrt(d), group=group,
+        tile=tile, scale=1.0 / math.sqrt(d), group=group,
         has_new=has_new, quantized=quantized, window=window, layered=layered)
+    scratch = [((2, ppb) + page_shape, kv_cache.dtype),
+               ((kvh, R, 1), jnp.float32), ((kvh, R, 1), jnp.float32),
+               ((kvh, R, d), jnp.float32)]
+    # a slot's program holds every KV head's rows: the two KV buffers, the
+    # (m, l, acc) carry, the pipeline's two buffers of each q, fresh-row,
+    # output and log-sum-exp block (a [.., 1] column pads to 128 lanes)
+    # and a tile's float32 scores, probabilities and mask.  At 8 KV heads
+    # of 1,024 rows that is past the 16 MiB a call is given by default
+    need = sum(_lane_padded_bytes(sh, dt) for sh, dt in scratch) \
+        + 2 * (2 * _lane_padded_bytes((kvh, R, d), q.dtype)
+               + _lane_padded_bytes((kvh, R, 1), jnp.float32)) \
+        + (4 * _lane_padded_bytes((kvh, Tp, d), q.dtype) if has_new else 0) \
+        + 3 * _lane_padded_bytes((tile, keys), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, kvh),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=[block_of(R, d), block_of(R, 1)],
-        scratch_shapes=[
-            pltpu.VMEM((2, keys, d), k_cache.dtype),
-            pltpu.VMEM((2, keys, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, d), jnp.float32),
-        ],
+        scratch_shapes=[pltpu.VMEM(*scratch[0]),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM(*scratch[1]), pltpu.VMEM(*scratch[2]),
+                        pltpu.VMEM(*scratch[3])],
     )
     out, lse = pl.pallas_call(
         kernel,
@@ -566,7 +671,8 @@ def _pallas_ragged_paged_attention(q, k_cache, v_cache, block_tables,
         out_shape=[jax.ShapeDtypeStruct((b, kvh, R, d), q.dtype),
                    jax.ShapeDtypeStruct((b, kvh, R, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_vmem_limit(need)),
         interpret=interpret,
     )(*scalars, *operands)
     out = out[:, :, :rows].reshape(b, kvh, t, group, d)
@@ -595,7 +701,8 @@ def _smem_need_bytes(kv_heads, num_pages, table_entries_padded):
 
 def kernel_geometry_error(page_size, head_dim, *, quantized=False,
                           kv_heads=0, num_pages=0, table_shape=(0, 0),
-                          smem_bytes=None, interpret=False, latent=None):
+                          smem_bytes=None, interpret=False, latent=None,
+                          dtype="bfloat16"):
     """The rule a paged-KV geometry fails for the Pallas kernel it is asked
     about, as a sentence, or None when the kernel covers it.  On a TPU a
     failing geometry raises (here at trace time, and in the engine when it
@@ -605,11 +712,18 @@ def kernel_geometry_error(page_size, head_dim, *, quantized=False,
     for; None asks the attached TPU (``pltpu.get_tpu_info``) and, with no
     TPU attached, leaves the SMEM rule to the compiler.
 
+    ``dtype`` is the pool's (an int8 pool says ``quantized`` too and is
+    held to its own rule of 32): a page
+    is one copy and one head's ``[page_size, head_dim]`` of it is read out
+    of the block buffer as whole tiles, so ``page_size`` must be a multiple
+    of the sublanes a tile of that type packs (float32 8, bfloat16 16,
+    int8 32).
+
     ``interpret``: the kernel as the interpreter runs it (CPU tests), which
-    has no lane tiling: a head half a tile wide (``head_dim % 128 == 64``)
-    passes there, while the TPU compiler refuses to slice it out of the
-    pool (``Slice shape along dimension 3 must be aligned to tiling
-    (128)``) and so does this rule.
+    has no tiling: a page of 8 bfloat16 rows and a head half a tile wide
+    (``head_dim % 128 == 64``) pass there, while the TPU compiler refuses
+    to slice the latter out of the pool (``Slice shape along dimension 3
+    must be aligned to tiling (128)``) and so does this rule.
 
     ``latent=(rank, rope)`` asks about the latent call
     (``ragged_paged_attention_latent``; ``head_dim`` is then ignored): its
@@ -632,11 +746,15 @@ def kernel_geometry_error(page_size, head_dim, *, quantized=False,
             return (f"the compressed row ({rank}) and two rotary keys "
                     f"(2 x {rope}) must each fill whole 128-lane tiles")
         return None
-    # f32 sublane is 8; bf16 packs 16 — page_size must tile the sublane
-    # dim.  int8 packs 32 sublanes per tile, so a quantized pool needs
-    # page_size % 32 == 0 to keep each page a whole-tile DMA.
+    # f32 sublane is 8; bf16 packs 16 and int8 32 sublanes per tile: one
+    # head's rows of a page must be whole tiles of the block buffer
     if page_size % 8:
         return f"page_size ({page_size}) must be a multiple of 8"
+    packs = 32 // jnp.dtype(dtype).itemsize
+    if not interpret and not quantized and page_size % packs:
+        return (f"page_size ({page_size}) must be a multiple of {packs}: a "
+                f"head's rows of a {jnp.dtype(dtype).name} page are whole "
+                f"({packs}, 128) tiles of the block the kernel copies it to")
     if head_dim % 128 not in ((0, 64) if interpret else (0,)):
         return (f"head_dim must be a multiple of 128 (the compiler slices "
                 f"whole lane tiles out of the pool), got {head_dim}")
@@ -664,8 +782,8 @@ def kernel_geometry_error(page_size, head_dim, *, quantized=False,
     return None
 
 
-def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
-                           *, q_lens=None, k_new=None, v_new=None,
+def ragged_paged_attention(q, kv_cache, block_tables, context_lens, *,
+                           q_lens=None, k_new=None, v_new=None,
                            k_scale=None, v_scale=None, with_lse=False,
                            window=None, layer=None):
     """Mixed-mode serving attention: prefill chunks and decode tokens in one
@@ -675,9 +793,10 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
       q:            [batch, T, num_q_heads, head_dim] — this step's query
                     tokens (T = 1 for pure decode, the chunk length for
                     chunked prefill; sequences ragged via ``q_lens``).
-      k_cache:      [num_kv_heads, num_pages, page_size, head_dim], or the
-                    whole pool [layers, num_kv_heads, ...] with ``layer``.
-      v_cache:      same shape as k_cache.
+      kv_cache:     [num_pages, 2, num_kv_heads, page_size, head_dim] (a
+                    page holds every head's K, then every head's V:
+                    ``pool_of_heads`` packs head-major planes so), or the
+                    whole pool [layers, num_pages, ...] with ``layer``.
       block_tables: [batch, max_pages_per_seq] int32 page ids (pad with 0).
       context_lens: [batch] int32 — tokens ALREADY in the cache (the prior
                     context; this step's own tokens are NOT included).
@@ -704,10 +823,10 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                     skipped in the walk, not masked after the fetch, and
                     the kernel is named ``ragged_paged_attention_w<window>``.
       layer:        int32 scalar (may be traced) — with the whole pool as
-                    ``k_cache``/``v_cache``, the layer whose pages are
-                    read: the kernel indexes the pool in HBM, no layer is
-                    sliced out of it (``k_scale``/``v_scale`` stay one
-                    layer's [num_kv_heads, num_pages] planes).
+                    ``kv_cache``, the layer whose pages are read: the
+                    kernel indexes the pool in HBM, no layer is sliced out
+                    of it (``k_scale``/``v_scale`` stay one layer's
+                    [num_kv_heads, num_pages] planes).
 
     Returns [batch, T, num_q_heads, head_dim] (and lse when requested).
     """
@@ -715,10 +834,13 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     if window is not None and (int(window) != window or window < 1):
         raise ValueError(f"window must be a whole number >= 1, got {window!r}")
     window = None if window is None else int(window)
-    if (k_cache.ndim == 5) != (layer is not None):
+    if (kv_cache.ndim == 6) != (layer is not None):
         raise ValueError("a whole pool [layers, ...] is read at `layer`; "
                          "one layer's cache takes none")
-    kvh, n_pool_pages, page_size, _ = k_cache.shape[-4:]
+    n_pool_pages, two, kvh, page_size, _ = kv_cache.shape[-5:]
+    if two != 2:
+        raise ValueError(f"a page holds K and V: axis -4 of the pool must "
+                         f"be 2, got {kv_cache.shape}")
     if qh % kvh:
         raise ValueError(f"q heads ({qh}) must be a multiple of kv heads ({kvh})")
     if (k_new is None) != (v_new is None):
@@ -729,21 +851,21 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     why = kernel_geometry_error(
         page_size, d, quantized=k_scale is not None, kv_heads=kvh,
         num_pages=n_pool_pages, table_shape=block_tables.shape,
-        interpret=not on_tpu)
+        interpret=not on_tpu, dtype=kv_cache.dtype)
     if on_tpu and why:
         # the serving hot op has no business on the XLA reference on a chip
         raise ValueError(f"ragged_paged_attention on TPU: {why}")
     if (on_tpu or flags.flag("paged_attention_interpret")) and not why:
         out, lse = _pallas_ragged_paged_attention(
-            q, k_cache, v_cache, block_tables, context_lens, q_lens,
+            q, kv_cache, block_tables, context_lens, q_lens,
             k_new, v_new, interpret=not on_tpu, k_scale=k_scale,
             v_scale=v_scale, window=window, layer=layer)
     else:
         if layer is not None:       # the oracle takes one layer's cache
-            k_cache, v_cache = (jax.lax.dynamic_index_in_dim(
-                c, layer, axis=0, keepdims=False) for c in (k_cache, v_cache))
+            kv_cache = jax.lax.dynamic_index_in_dim(
+                kv_cache, layer, axis=0, keepdims=False)
         out, lse = _reference_ragged_paged_attention(
-            q, k_cache, v_cache, block_tables, context_lens, q_lens,
+            q, *heads_of_pool(kv_cache), block_tables, context_lens, q_lens,
             k_new, v_new, k_scale=k_scale, v_scale=v_scale, window=window)
     return (out, lse) if with_lse else out
 
@@ -996,13 +1118,6 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
         for_live_tiles(finish)
 
 
-def _lane_padded_bytes(shape, dtype):
-    """VMEM bytes of a buffer whose last dim is padded to 128 lanes."""
-    *lead, last = shape
-    return int(np.prod(lead, dtype=np.int64)) * (-(-last // 128) * 128) \
-        * jnp.dtype(dtype).itemsize
-
-
 @functools.partial(jax.jit, static_argnames=("interpret", "scale"))
 def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
                                           block_tables, context_lens, q_lens,
@@ -1094,8 +1209,7 @@ def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
         out_shape=jax.ShapeDtypeStruct((b, R, rank), q_c.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
-            vmem_limit_bytes=int(min(max(need * 5 // 4, 32 << 20),
-                                     110 << 20))),
+            vmem_limit_bytes=_vmem_limit(need)),
         interpret=interpret,
     )(*scalars, *operands)
     return out[:, :rows].reshape(b, t, heads, rank)
@@ -1161,7 +1275,9 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
     """Single-token decode attention over a paged KV cache.
 
     The T=1, no-fresh-rows form of :func:`ragged_paged_attention` (kept as
-    the stable decode API; the reference oracle for it is
+    the stable decode API, head-major K and V planes as the reference's
+    kernel takes them, packed into a page-major pool here at the boundary:
+    a copy, and no serving step calls it; the reference oracle for it is
     ``_reference_paged_attention``).
 
     Args:
@@ -1177,8 +1293,9 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
 
     Returns [batch, num_q_heads, head_dim] (and lse when requested).
     """
-    res = ragged_paged_attention(q[:, None], k_cache, v_cache, block_tables,
-                                 context_lens, with_lse=with_lse)
+    res = ragged_paged_attention(q[:, None], pool_of_heads(k_cache, v_cache),
+                                 block_tables, context_lens,
+                                 with_lse=with_lse)
     if with_lse:
         out, lse = res
         return out[:, 0], lse[:, 0]
@@ -1187,67 +1304,69 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
 
 # ----------------------------------------------------------- cache writes ---
 
-def write_kv_pages(k_cache, v_cache, k_new, v_new, slot_mapping):
-    """Scatter new KV rows into the paged cache.
+def write_kv_pages(kv_cache, k_new, v_new, slot_mapping):
+    """Scatter new KV rows into one layer's paged cache.
 
-    k_new/v_new: [n_tokens, kv_heads, head_dim]; slot_mapping: [n_tokens]
-    int32 flat slots (page_id * page_size + offset; -1 = drop the token).
-    Returns updated (k_cache, v_cache).  Donate the caches under jit and
-    XLA performs the scatter in place.
+    kv_cache: [num_pages, 2, kv_heads, page_size, head_dim]; k_new/v_new:
+    [n_tokens, kv_heads, head_dim]; slot_mapping: [n_tokens] int32 flat
+    slots (page_id * page_size + offset; -1 = drop the token).  Returns
+    the updated cache.  Donate it under jit and XLA performs the scatter
+    in place.
     """
-    kvh, n_pages, page_size, d = k_cache.shape
-    flat_k = k_cache.reshape(kvh, n_pages * page_size, d)
-    flat_v = v_cache.reshape(kvh, n_pages * page_size, d)
+    n_pages, _, _, page_size, _ = kv_cache.shape
     slots = slot_mapping.astype(jnp.int32)
     # dropped tokens (-1) are redirected out of range; mode="drop" elides them
-    safe = jnp.where(slots >= 0, slots, n_pages * page_size)
-    kn = jnp.swapaxes(k_new, 0, 1).astype(flat_k.dtype)   # [kvh, n, d]
-    vn = jnp.swapaxes(v_new, 0, 1).astype(flat_v.dtype)
-    flat_k = flat_k.at[:, safe].set(kn, mode="drop")
-    flat_v = flat_v.at[:, safe].set(vn, mode="drop")
-    return (flat_k.reshape(k_cache.shape), flat_v.reshape(v_cache.shape))
+    page = jnp.where(slots >= 0, slots // page_size, n_pages)
+    rows = jnp.stack([k_new, v_new], axis=1).astype(kv_cache.dtype)
+    return kv_cache.at[page, :, :, slots % page_size].set(rows, mode="drop")
 
 
-def write_kv_pages_all_layers(k_cache, v_cache, k_all, v_all, slot_mapping):
+def write_kv_pages_all_layers(kv_cache, k_all, v_all, slot_mapping):
     """Commit every layer's new KV rows, token by token, in place.
 
-    k_cache/v_cache: [layers, kv_heads, num_pages, page_size, head_dim];
+    kv_cache: [layers, num_pages, 2, kv_heads, page_size, head_dim];
     k_all/v_all: [layers, n_tokens, kv_heads, head_dim]; slot_mapping:
     [n_tokens] (-1 = drop).  All layers share the slot vector and the
     commit happens once at the end of the step, so the cache stays strictly
     read-before-write: attention reads the pre-step cache and XLA aliases
-    the donated buffers in place.
+    the donated buffer in place.
 
     A loop of ``dynamic_update_slice`` over the step's VALID tokens (those
     with a slot, taken first), not one scatter: for a scatter along the
     token axis XLA's TPU layout assignment moves the whole pool into a
     token-major layout and back (four copies of the pool a step: 3.25 GB of
     transients beside a 3.25 GB pool, and a third of the dense chat step,
-    v5e, PR 27), where an update of one ``[layers, kv_heads, 1, head_dim]``
-    window leaves the pool where it lies.
+    v5e, PR 27).  The window is the token's WHOLE page, ``[layers, 1, 2,
+    kv_heads, page_size, head_dim]``, read, one row of every head's K and V
+    replaced, and written back: ONE dynamic index, whole tiles, and XLA
+    leaves the pool where it lies.  A window of the one row alone (the page
+    AND the offset dynamic) made it move the pool into a layout with the
+    heads beside ``head_dim`` and back, two pool-shaped copies a step
+    (compiled for a described v5e, PR 35: ``tests/test_chip_compile.py``);
+    a row is a part of a ``(16, 128)`` tile, so the hardware rewrites the
+    tile either way.
     """
-    L, kvh, n_pages, page_size, d = k_cache.shape
-    flat_k = k_cache.reshape(L, kvh, n_pages * page_size, d)
-    flat_v = v_cache.reshape(L, kvh, n_pages * page_size, d)
+    L, n_pages, _, kvh, page_size, d = kv_cache.shape
     slots = slot_mapping.astype(jnp.int32)
-    kn = jnp.swapaxes(k_all, 1, 2).astype(flat_k.dtype)   # [L, kvh, n, d]
-    vn = jnp.swapaxes(v_all, 1, 2).astype(flat_v.dtype)
+    rows = jnp.stack([k_all, v_all], axis=2).astype(kv_cache.dtype)
     valid = slots >= 0
     order = jnp.argsort(jnp.logical_not(valid), stable=True).astype(jnp.int32)
+    ps_c = jnp.int32(page_size)
+
+    at = jnp.arange(page_size, dtype=jnp.int32)[:, None]     # [page, 1]
 
     def commit(i, kv):
-        fk, fv = kv
         src = order[i]
         dst = slots[src]
-        fk = jax.lax.dynamic_update_slice_in_dim(
-            fk, jax.lax.dynamic_slice_in_dim(kn, src, 1, axis=2), dst, axis=2)
-        fv = jax.lax.dynamic_update_slice_in_dim(
-            fv, jax.lax.dynamic_slice_in_dim(vn, src, 1, axis=2), dst, axis=2)
-        return fk, fv
+        page = jax.lax.div(dst, ps_c)
+        row = jax.lax.dynamic_slice_in_dim(rows, src, 1, axis=1)
+        old = jax.lax.dynamic_slice_in_dim(kv, page, 1, axis=1)
+        new = jnp.where(at == jax.lax.rem(dst, ps_c),
+                        row.reshape(L, 1, 2, kvh, 1, d), old)
+        return jax.lax.dynamic_update_slice_in_dim(kv, new, page, axis=1)
 
-    flat_k, flat_v = jax.lax.fori_loop(
-        jnp.int32(0), valid.sum().astype(jnp.int32), commit, (flat_k, flat_v))
-    return (flat_k.reshape(k_cache.shape), flat_v.reshape(v_cache.shape))
+    return jax.lax.fori_loop(
+        jnp.int32(0), valid.sum().astype(jnp.int32), commit, kv_cache)
 
 
 def write_latent_pages_all_layers(c_cache, r_cache, c_all, r_all,
@@ -1310,7 +1429,7 @@ def _requantize_pages(flat, fresh, lslot, new_scale_shape):
     return q, scales
 
 
-def write_kv_pages_all_layers_quantized(k_cache, v_cache, k_scale, v_scale,
+def write_kv_pages_all_layers_quantized(kv_cache, k_scale, v_scale,
                                         k_all, v_all, positions, q_lens,
                                         block_tables, max_len):
     """The int8 pool's batched all-layer commit: quantize fresh K/V per
@@ -1335,12 +1454,14 @@ def write_kv_pages_all_layers_quantized(k_cache, v_cache, k_scale, v_scale,
     ``context_lens`` anyway, so zeroing it is free and keeps the error
     bound relative to the page's OWN live content.
 
-    k_cache/v_cache: [L, kvh, n_pages, page, d] int8; k_scale/v_scale:
+    kv_cache: [L, n_pages, 2, kvh, page, d] int8; k_scale/v_scale:
     [L, kvh, n_pages] fp32; k_all/v_all: [L, B*T, kvh, d] fresh rows;
     positions/q_lens: [B] (write cursor / valid tokens per row);
-    block_tables: [B, W].  Returns the four updated arrays.
+    block_tables: [B, W].  Returns the three updated arrays.  The window's
+    pages are worked on head-major (``heads_of_pool`` of the gathered
+    pages, a few pages a slot), beside the scale planes' layout.
     """
-    L, kvh, n_pages, page, d = k_cache.shape
+    L, n_pages, _, kvh, page, d = kv_cache.shape
     B, W = block_tables.shape
     T = k_all.shape[1] // B
     # a T-token run starting anywhere in a page straddles at most Pmax
@@ -1368,8 +1489,8 @@ def write_kv_pages_all_layers_quantized(k_cache, v_cache, k_scale, v_scale,
     safe_pid = jnp.minimum(flat_pid, n_pages - 1)
 
     # gather + dequant the write window
-    kg = jnp.take(k_cache, safe_pid, axis=2)   # [L, kvh, B*Pmax, page, d]
-    vg = jnp.take(v_cache, safe_pid, axis=2)
+    # [L, kvh, B*Pmax, page, d] each
+    kg, vg = heads_of_pool(jnp.take(kv_cache, safe_pid, axis=1))
     ksg = jnp.take(k_scale, safe_pid, axis=2)  # [L, kvh, B*Pmax]
     vsg = jnp.take(v_scale, safe_pid, axis=2)
     # live-extent mask: row r of window page j holds a valid token iff
@@ -1399,8 +1520,8 @@ def write_kv_pages_all_layers_quantized(k_cache, v_cache, k_scale, v_scale,
     vq, vs_new = _requantize_pages(vf, vn, lslot, (B * Pmax, page))
 
     # untouched window entries were routed to n_pages: scatter drops them
-    k_cache = k_cache.at[:, :, flat_pid].set(kq, mode="drop")
-    v_cache = v_cache.at[:, :, flat_pid].set(vq, mode="drop")
+    kv_cache = kv_cache.at[:, flat_pid].set(pool_of_heads(kq, vq),
+                                            mode="drop")
     k_scale = k_scale.at[:, :, flat_pid].set(ks_new, mode="drop")
     v_scale = v_scale.at[:, :, flat_pid].set(vs_new, mode="drop")
-    return k_cache, v_cache, k_scale, v_scale
+    return kv_cache, k_scale, v_scale

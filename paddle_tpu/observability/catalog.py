@@ -81,6 +81,17 @@ CATALOG: Dict[str, tuple] = {
         "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
         "five latent layers of 512 + 64 in bf16; 16,384 for four layers "
         "of 8 KV heads x 128)"),
+    # ---- serving: what one copy of the paged call moves (PR 35) ----
+    "serving.kv_copy_bytes": (
+        "gauge", "",
+        "bytes ONE DMA descriptor of the paged-attention call moves: a "
+        "page's K and V of every KV head the shard holds, one layer, "
+        "which the page-major pool keeps as one contiguous run (32,768 at "
+        "4 KV heads x 16 tokens x 128 in bf16, 65,536 at 8; the head-major "
+        "pool before PR 35 moved 4,096, one head's K or V); a latent "
+        "pool's largest copy, half a page's compressed rows.  "
+        "`engine.step`'s `page_copies` x this = the bytes one layer's "
+        "call fetches"),
     # ---- serving: what a slot holds besides pages (PR 34) ----
     "serving.state_bytes_per_slot": (
         "gauge", "",
@@ -522,7 +533,8 @@ SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
         "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
-        "kv_read_tokens, attn_rows, ssm_slots, ssm_tokens, slots, waiting",
+        "kv_read_tokens, attn_rows, page_copies, ssm_slots, ssm_tokens, "
+        "slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
@@ -542,7 +554,13 @@ SPANS: Dict[str, tuple] = {
         "one row of keys for all heads, so `group` is the number of query "
         "heads and a slot covers `q_len x heads` rows), so `q_tokens x "
         "group / attn_rows` is to attention what `q_tokens / gemm_rows` "
-        "is to the GEMMs, `ssm_slots` and `ssm_tokens` (a stack with a "
+        "is to the GEMMs, `page_copies` the DMA descriptors one layer's "
+        "paged call starts (every slot with work fetches whole blocks of "
+        "pages, one copy a page of `serving.kv_copy_bytes`: working slots "
+        "x the blocks their walk reaches x the pages of a block; "
+        "`kernels.paged_attention.page_copies`; for a layer that sees the "
+        "whole context where the stack has one; three copies a page in a "
+        "latent stack's call), `ssm_slots` and `ssm_tokens` (a stack with a "
         "state-space mixer only) the slots whose recurrent state each "
         "layer's scan call reads and writes in this step (those with "
         "work) and the tokens they scan, `slots` the batch B, `waiting` "

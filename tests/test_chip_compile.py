@@ -69,8 +69,8 @@ def _compile(one_chip, fn, *shapes):
 
 def _paged_shapes(T, cache_dtype=BF16, page=PAGE, n_pages=N_PAGES):
     i32 = jnp.int32
-    cache = ((KVH, n_pages, page, D), cache_dtype)
-    return [((BATCH, T, QH, D), BF16), cache, cache,
+    cache = ((n_pages, 2, KVH, page, D), cache_dtype)
+    return [((BATCH, T, QH, D), BF16), cache,
             ((BATCH, MAX_LEN // page), i32), ((BATCH,), i32),
             ((BATCH,), i32), ((BATCH, T, KVH, D), BF16),
             ((BATCH, T, KVH, D), BF16)]
@@ -80,8 +80,8 @@ def _paged_shapes(T, cache_dtype=BF16, page=PAGE, n_pages=N_PAGES):
                          ids=["decode", "prefill_chunk", "spec_verify"])
 def test_paged_attention_compiles(one_chip, T):
     _compile(one_chip,
-             lambda q, k, v, bt, cl, ql, kn, vn:
-             pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn, vn,
+             lambda q, kv, bt, cl, ql, kn, vn:
+             pa._pallas_ragged_paged_attention(q, kv, bt, cl, ql, kn, vn,
                                                False),
              *_paged_shapes(T))
 
@@ -94,8 +94,8 @@ def test_paged_attention_int8_compiles(one_chip, T):
     page, n_pages = 32, BATCH * (MAX_LEN // 32)
     scale = ((KVH, n_pages), jnp.float32)
     _compile(one_chip,
-             lambda q, k, v, bt, cl, ql, kn, vn, ks, vs:
-             pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn, vn,
+             lambda q, kv, bt, cl, ql, kn, vn, ks, vs:
+             pa._pallas_ragged_paged_attention(q, kv, bt, cl, ql, kn, vn,
                                                False, k_scale=ks, v_scale=vs),
              *_paged_shapes(T, jnp.int8, page, n_pages), scale, scale)
 
@@ -118,14 +118,14 @@ def test_paged_int8_scale_planes_bounded_by_smem(one_chip, kvh, fits,
             table_shape=(BATCH, MAX_LEN // 32), smem_bytes=V5E_SMEM)
 
     def compile_pool(n):
-        i32, cache = jnp.int32, ((kvh, n, 32, D), jnp.int8)
+        i32, cache = jnp.int32, ((n, 2, kvh, 32, D), jnp.int8)
         new, scale = ((BATCH, 1, kvh, D), BF16), ((kvh, n), jnp.float32)
         _compile(one_chip,
-                 lambda q, k, v, bt, cl, ql, kn, vn, ks, vs:
+                 lambda q, kv, bt, cl, ql, kn, vn, ks, vs:
                  pa._pallas_ragged_paged_attention(
-                     q, k, v, bt, cl, ql, kn, vn, False, k_scale=ks,
+                     q, kv, bt, cl, ql, kn, vn, False, k_scale=ks,
                      v_scale=vs),
-                 ((BATCH, 1, kvh, D), BF16), cache, cache,
+                 ((BATCH, 1, kvh, D), BF16), cache,
                  ((BATCH, MAX_LEN // 32), i32), ((BATCH,), i32),
                  ((BATCH,), i32), new, new, scale, scale)
 
@@ -194,21 +194,60 @@ def _paged_call(compiled):
     return calls[0]
 
 
-def _compile_engine_call(one_chip, slots, qh, kvh, T, table, n_pages, window):
+def _compile_engine_call(one_chip, slots, qh, kvh, T, table, n_pages, window,
+                         layers=4):
     """The kernel as an engine's step calls it: the step's own K/V rows,
-    one layer's bf16 cache, a table ``[slots, table]``."""
+    the WHOLE bf16 pool ``[layers, pages, 2, kv_heads, page, d]`` read at a
+    traced layer, a table ``[slots, table]``, with the VMEM limit the code
+    sets from these shapes."""
     assert pa.kernel_geometry_error(PAGE, D, kv_heads=kvh,
                                     num_pages=n_pages,
                                     table_shape=(slots, table)) is None
     i32 = jnp.int32
-    cache = ((kvh, n_pages, PAGE, D), BF16)
     return _compile(
         one_chip,
-        lambda q, k, v, bt, cl, ql, kn, vn: pa._pallas_ragged_paged_attention(
-            q, k, v, bt, cl, ql, kn, vn, interpret=False, window=window),
-        ((slots, T, qh, D), BF16), cache, cache,
+        lambda q, kv, bt, cl, ql, kn, vn, ly: pa._pallas_ragged_paged_attention(
+            q, kv, bt, cl, ql, kn, vn, interpret=False, window=window,
+            layer=ly),
+        ((slots, T, qh, D), BF16), ((layers, n_pages, 2, kvh, PAGE, D), BF16),
         ((slots, table), i32), ((slots,), i32), ((slots,), i32),
-        ((slots, T, kvh, D), BF16), ((slots, T, kvh, D), BF16))
+        ((slots, T, kvh, D), BF16), ((slots, T, kvh, D), BF16), ((), i32))
+
+
+def _pool_shaped_copies(text, pool):
+    """The ``copy`` operations of a compiled program whose result has the
+    pool's shape: what a traced window would show as a pool-shaped copy."""
+    shape = f"bf16[{','.join(map(str, pool.shape))}]"
+    return re.findall(r"= " + re.escape(shape) + r"\{[^}]*\} copy\(", text)
+
+
+@pytest.mark.parametrize("kvh", [4, 8])
+def test_the_commit_updates_the_pool_in_place(one_chip, kvh):
+    """``write_kv_pages_all_layers`` alone, the pool donated: the loop's
+    window is a whole page with ONE dynamic index, which XLA's layout
+    assignment leaves in the pool's own layout."""
+    i32 = jnp.int32
+    pool = jax.ShapeDtypeStruct((4, 2048, 2, kvh, PAGE, D), BF16,
+                                sharding=one_chip)
+    new = jax.ShapeDtypeStruct((4, 512, kvh, D), BF16, sharding=one_chip)
+    slots = jax.ShapeDtypeStruct((512,), i32, sharding=one_chip)
+    compiled = jax.jit(pa.write_kv_pages_all_layers, donate_argnums=(0,)) \
+        .lower(pool, new, new, slots).compile()
+    assert not _pool_shaped_copies(compiled.as_text(), pool)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * 2048 * 2 * kvh * PAGE * D * 2
+    assert mem.temp_size_in_bytes < 16 << 20
+
+
+def _vmem_limit_of(compiled):
+    """The VMEM a compiled program's one paged call is given, bytes: the
+    size of the scoped memory in the custom call's ``backend_config``
+    (``vmem_limit_bytes`` as the compiler took it)."""
+    lines = [ln for ln in compiled.as_text().splitlines()
+             if re.search(r"%ragged_paged_attention\w*[.\d]* = ", ln)]
+    assert len(lines) == 1, lines
+    return int(re.search(r'"scoped_memory_configs":\[\{[^}]*"size":"(\d+)"',
+                         lines[0]).group(1))
 
 
 @pytest.mark.parametrize("window", [4096, None], ids=["sliding", "full"])
@@ -227,6 +266,34 @@ def test_paged_attention_command_a_plus_shapes_compile(one_chip, T, window):
         f"bf16[16,8,{rows},128]", f"f32[16,8,{rows},1]", "s32[16,776]")
     if T == 64:
         assert rows == 1024 and pa.row_tile(T, CA_QH // CA_KVH) == 256
+        # 8 MiB of KV buffers, 4 MiB of accumulator, 8 MiB of lane-padded
+        # m and l, the pipeline's q, output and log-sum-exp blocks: past
+        # the 16 MiB a call is given by default, far under the chip's 128
+        assert 32 << 20 < _vmem_limit_of(compiled) < 64 << 20
+
+
+@pytest.mark.parametrize("T", [1, 16], ids=["decode", "mixed"])
+def test_paged_attention_falcon_h1_engine_shapes_compile(one_chip, T):
+    """The generation cell's engine: 128 slots, 20 query heads over 4 KV
+    heads (a group of 5: 80 rows at T = 16, padded to 8 at T = 1), a table
+    of 128 pages, a pool of 16,384 pages of 32 KB a layer; one program a
+    slot walks all four heads."""
+    compiled = _compile_engine_call(one_chip, 128, 20, 4, T, 128, 16384,
+                                    None)
+    rows = max(8, T * 5)
+    assert _paged_call(compiled) == (
+        "ragged_paged_attention", f"bf16[128,4,{rows},128]",
+        f"f32[128,4,{rows},1]", "s32[128,128]")
+    assert _vmem_limit_of(compiled) == 32 << 20
+
+
+def test_a_bf16_page_of_8_rows_is_refused_by_the_rule():
+    """A page is one copy and a head's rows of it are whole tiles of the
+    block buffer: 16 rows of bfloat16, 8 of float32, 32 of int8."""
+    assert "multiple of 16" in pa.kernel_geometry_error(8, D)
+    assert pa.kernel_geometry_error(8, D, dtype="float32") is None
+    assert pa.kernel_geometry_error(8, D, interpret=True) is None
+    assert "% 32" in pa.kernel_geometry_error(16, D, quantized=True)
 
 
 @pytest.mark.parametrize("table", [160, 264], ids=["chat", "mixtral_batch"])
@@ -444,13 +511,13 @@ def test_a_64_wide_pool_is_refused_by_the_compiler_and_by_the_rule(one_chip):
     which is why the latent pool keeps two tokens' rotary keys a row."""
     assert "multiple of 128" in pa.kernel_geometry_error(PAGE, 64)
     i32, d = jnp.int32, 64
-    cache = ((KVH, N_PAGES, PAGE, d), BF16)
+    cache = ((N_PAGES, 2, KVH, PAGE, d), BF16)
     with pytest.raises(Exception, match=r"aligned to tiling \(128\)"):
         _compile(one_chip,
-                 lambda q, k, v, bt, cl, ql, kn, vn:
-                 pa._pallas_ragged_paged_attention(q, k, v, bt, cl, ql, kn,
+                 lambda q, kv, bt, cl, ql, kn, vn:
+                 pa._pallas_ragged_paged_attention(q, kv, bt, cl, ql, kn,
                                                    vn, False),
-                 ((BATCH, 1, QH, d), BF16), cache, cache,
+                 ((BATCH, 1, QH, d), BF16), cache,
                  ((BATCH, W), i32), ((BATCH,), i32), ((BATCH,), i32),
                  ((BATCH, 1, KVH, d), BF16), ((BATCH, 1, KVH, d), BF16))
 
@@ -651,6 +718,10 @@ def test_falcon_h1_step_program_compiles_at_published_widths(one_chip,
     held = sum(a.size * a.dtype.itemsize for a in g.cache.arrays)
     assert mem.alias_size_in_bytes == held       # pool and state in place
     assert mem.temp_size_in_bytes < 512 << 20
+    # the commit leaves the pool where it lies: no copy of it into another
+    # layout and back (a window of one row a token with the page AND the
+    # offset dynamic made XLA move the whole pool twice a step, PR 35)
+    assert not _pool_shaped_copies(text, g.cache.kv)
     per_page = gen.PagedKVCache.bytes_per_page(FH_L, 4, PAGE, 128, "bfloat16")
     peak = mem.argument_size_in_bytes + mem.output_size_in_bytes \
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes \

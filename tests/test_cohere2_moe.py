@@ -216,7 +216,7 @@ def test_the_period_of_one_is_its_layers_written_out_by_hand(experts):
     got, _, _ = g._forward_tokens(g.params, cache, toks2, ql, ql, table)
 
     blocks, = g.params["blocks"]
-    kc, vc = cache
+    kv, = cache
     offs = jnp.arange(T, dtype=jnp.int32)
     at = jnp.minimum(ql[:, None] + offs[None, :], g.max_seq_len - 1)
     cos, sin = jnp.take(g._cos, at, axis=0), jnp.take(g._sin, at, axis=0)
@@ -231,7 +231,7 @@ def test_the_period_of_one_is_its_layers_written_out_by_hand(experts):
         v = (y @ lp["self_attn.v_proj.weight"]).reshape(
             B, T, c.num_key_value_heads, c.head_dim)
         q, k = _rope(q, cos, sin), _rope(k, cos, sin)
-        attn = ragged_paged_attention(q, kc[l], vc[l], table, ql, q_lens=ql,
+        attn = ragged_paged_attention(q, kv[l], table, ql, q_lens=ql,
                                       k_new=k, v_new=v)
         x = x + attn.reshape(B, T, -1) @ lp["self_attn.o_proj.weight"]
         y = rms_norm_fp32(x, lp["post_attention_layernorm.weight"],

@@ -92,7 +92,9 @@ def test_write_kv_pages_scatter(rng):
     k_new = jnp.asarray(rng.standard_normal((3, kvh, d)), jnp.float32)
     v_new = jnp.asarray(rng.standard_normal((3, kvh, d)), jnp.float32)
     slots = jnp.asarray([0, 9, -1], jnp.int32)   # last token dropped
-    k2, v2 = pa.write_kv_pages(kc, vc, k_new, v_new, slots)
+    # the pool is page-major; the assertions read it back head-major
+    k2, v2 = pa.heads_of_pool(pa.write_kv_pages(
+        pa.pool_of_heads(kc, vc), k_new, v_new, slots))
     # slot 0 = page 0 offset 0; slot 9 = page 1 offset 1
     np.testing.assert_allclose(np.asarray(k2[:, 0, 0]), np.asarray(k_new[0]))
     np.testing.assert_allclose(np.asarray(k2[:, 1, 1]), np.asarray(k_new[1]))
@@ -465,8 +467,8 @@ def _schedule_case(rng, cache, *, kvh, group, T, ctx, ql, window=None):
     flags.set_flags({"paged_attention_interpret": True})
     try:
         got = pa.ragged_paged_attention(
-            q, kc, vc, bt, cl, q_lens=qlens, k_new=kn, v_new=vn, k_scale=ks,
-            v_scale=vs, window=window, with_lse=True)
+            q, pa.pool_of_heads(kc, vc), bt, cl, q_lens=qlens, k_new=kn,
+            v_new=vn, k_scale=ks, v_scale=vs, window=window, with_lse=True)
     finally:
         flags.set_flags(old)
     return got, ref
@@ -552,8 +554,8 @@ def test_ragged_paged_attention_mixed_mode_parity(rng):
     flags.set_flags({"paged_attention_interpret": True})
     try:
         out, lse = pa.ragged_paged_attention(
-            q, kc, vc, bt, ctx, q_lens=qlens, k_new=kn, v_new=vn,
-            with_lse=True)
+            q, pa.pool_of_heads(kc, vc), bt, ctx, q_lens=qlens, k_new=kn,
+            v_new=vn, with_lse=True)
     finally:
         flags.set_flags(old)
     group = qh // kvh

@@ -112,8 +112,8 @@ def test_int8_kernel_parity_vs_reference(rng, t, qls):
     flags.set_flags({"paged_attention_interpret": True})
     try:
         got = pa.ragged_paged_attention(
-            q, kc, vc, bt, cl, q_lens=ql, k_new=kn, v_new=vn,
-            k_scale=ks, v_scale=vs)
+            q, pa.pool_of_heads(kc, vc), bt, cl, q_lens=ql, k_new=kn,
+            v_new=vn, k_scale=ks, v_scale=vs)
     finally:
         flags.set_flags(old)
     for i in range(b):
@@ -165,8 +165,11 @@ def test_quantized_commit_matches_float_oracle(rng):
     ql = jnp.asarray([T, 2], jnp.int32)
     bt = jnp.asarray([[0, 1, 0, 0], [4, 5, 6, 0]], jnp.int32)
 
-    kq, vq, ks2, vs2 = pa.write_kv_pages_all_layers_quantized(
-        kc, vc, ks, vs, k_all, v_all, positions, ql, bt, max_len)
+    # the pool is page-major; the assertions read it back head-major
+    kvq, ks2, vs2 = pa.write_kv_pages_all_layers_quantized(
+        pa.pool_of_heads(kc, vc), ks, vs, k_all, v_all, positions, ql, bt,
+        max_len)
+    kq, vq = pa.heads_of_pool(kvq)
     deq = np.asarray(kq, np.float32) * np.asarray(ks2)[..., None, None]
 
     kn = np.asarray(k_all)
@@ -201,10 +204,12 @@ def test_quantized_commit_masks_recycled_page_garbage(rng):
     ks = jnp.full((L, kvh, n_pages), 0.5, jnp.float32)   # absmax ~63.5
     fresh = jnp.asarray(rng.uniform(-0.01, 0.01, (L, 1, kvh, d)),
                         jnp.float32)                      # tiny new row
-    kq, _, ks2, _ = pa.write_kv_pages_all_layers_quantized(
-        kc, kc, ks, ks, fresh, fresh,
+    kvq, ks2, _ = pa.write_kv_pages_all_layers_quantized(
+        pa.pool_of_heads(kc, kc), ks, ks, fresh, fresh,
         jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32),
         jnp.zeros((1, 1), jnp.int32), 16)
+    kq, vq = pa.heads_of_pool(kvq)
+    assert (np.asarray(kq) == np.asarray(vq)).all()   # V took the same rows
     got = np.asarray(kq, np.float32)[0, 0, 0, 0] \
         * float(np.asarray(ks2)[0, 0, 0])
     want = np.asarray(fresh)[0, 0, 0]
@@ -221,7 +226,7 @@ def test_quantized_commit_is_deterministic(rng):
                      jnp.int8)
     ks = jnp.asarray(rng.uniform(0.01, 0.02, (L, kvh, n_pages)), jnp.float32)
     k_all = jnp.asarray(rng.standard_normal((L, 2, kvh, d)), jnp.float32)
-    args = (kc, kc, ks, ks, k_all, k_all,
+    args = (pa.pool_of_heads(kc, kc), ks, ks, k_all, k_all,
             jnp.asarray([3, 9], jnp.int32), jnp.asarray([1, 1], jnp.int32),
             jnp.asarray([[0, 1], [1, 2]], jnp.int32), 16)
     a = pa.write_kv_pages_all_layers_quantized(*args)
@@ -495,16 +500,20 @@ def test_spill_pool_unit_roundtrip(rng):
     a full ring returns None."""
     cache = PagedKVCache(num_layers=2, num_pages=4, page_size=8,
                          num_kv_heads=2, head_dim=16, dtype="int8")
-    kq = jnp.asarray(rng.integers(-127, 128, cache.k.shape), jnp.int8)
-    vq = jnp.asarray(rng.integers(-127, 128, cache.v.shape), jnp.int8)
+    assert cache.kv.shape == (2, 4, 2, 2, 8, 16)   # [L, pages, K|V, kvh, ..]
+    kvq = jnp.asarray(rng.integers(-127, 128, cache.kv.shape), jnp.int8)
     ks = jnp.asarray(rng.uniform(0.01, 0.02, cache.k_scale.shape),
                      jnp.float32)
     vs = jnp.asarray(rng.uniform(0.02, 0.03, cache.v_scale.shape),
                      jnp.float32)
-    cache.update(kq, vq, ks, vs)
+    cache.update(kvq, ks, vs)
+    kvq, ks = np.asarray(kvq), np.asarray(ks)   # the pool is donated below
     pool = HostSpillPool(cache, capacity=2)
     pool.warm()
-    before = tuple(np.asarray(a[:, :, 1]) for a in cache.arrays)
+    before = cache.page_planes(1)
+    # every head's K and V of the page, all layers, and its scale rows
+    assert np.array_equal(before[0], kvq[:, 1])
+    assert np.array_equal(before[1], ks[:, :, 1])
     s0 = pool.spill(1)
     s1 = pool.spill(2)
     assert s0 is not None and s1 is not None
@@ -512,7 +521,7 @@ def test_spill_pool_unit_roundtrip(rng):
     # clobber page 1 on device, then swap the spilled copy into page 3
     cache.update(*(jnp.zeros_like(a) for a in cache.arrays))
     pool.swap_in(s0, 3)
-    after = tuple(np.asarray(a[:, :, 3]) for a in cache.arrays)
+    after = cache.page_planes(3)
     for b, a in zip(before, after):
         assert (b == a).all()
     assert pool.free_slots == 1 and pool.resident == 1
@@ -579,7 +588,11 @@ def test_migration_of_spilled_prefix_ships_ring_bytes(cache_dtype):
         nodes = dst.prefix_cache.chain(S)
         assert len(nodes) == 2
         for node, pg in zip(nodes, snap["pages"]):
-            for plane, arr in zip(pg["planes"], dst.g.cache.arrays):
+            # the wire stays head-major (k, v, k_scale, v_scale)
+            k, v = pa.heads_of_pool(dst.g.cache.kv)
+            for plane, arr in zip(pg["planes"],
+                                  (k, v, dst.g.cache.k_scale,
+                                   dst.g.cache.v_scale)):
                 assert np.array_equal(plane,
                                       np.asarray(arr[:, :, node.page]))
     r1 = dst.add_request(S + [30])

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.kernels.paged_attention import pool_of_heads
+
 
 def _export_tpu(fn, *args):
     """Lower ``fn`` for the TPU platform (no hardware needed)."""
@@ -123,18 +125,18 @@ class TestPagedAttentionMosaic:
                         seed=2)
         bt = jnp.zeros((self.b, self.max_pages), jnp.int32)
         cl = jnp.full((self.b,), 40, jnp.int32)
-        return k_cache, v_cache, bt, cl
+        return pool_of_heads(k_cache, v_cache), bt, cl
 
     def test_decode_kernel(self):
         from paddle_tpu.kernels.paged_attention import \
             _pallas_ragged_paged_attention
 
         q = _rand((self.b, 1, self.qh, self.d))
-        k_cache, v_cache, bt, cl = self._cache()
+        kv, bt, cl = self._cache()
         _export_tpu(
             lambda *a: _pallas_ragged_paged_attention(
                 *a, None, None, None, False)[0],
-            q, k_cache, v_cache, bt, cl)
+            q, kv, bt, cl)
 
     def test_mixed_mode_kernel(self):
         """Prefill chunk + fresh-KV causal fold, the ragged mixed form."""
@@ -143,15 +145,15 @@ class TestPagedAttentionMosaic:
 
         T = 16
         q = _rand((self.b, T, self.qh, self.d))
-        k_cache, v_cache, bt, cl = self._cache()
+        kv, bt, cl = self._cache()
         ql = jnp.asarray([T, 3], jnp.int32)
         kn = _rand((self.b, T, self.kvh, self.d), seed=3)
         vn = _rand((self.b, T, self.kvh, self.d), seed=4)
         _export_tpu(
-            lambda q_, kc, vc, bt_, cl_, ql_, kn_, vn_:
+            lambda q_, kv_, bt_, cl_, ql_, kn_, vn_:
                 _pallas_ragged_paged_attention(
-                    q_, kc, vc, bt_, cl_, ql_, kn_, vn_, False)[0],
-            q, k_cache, v_cache, bt, cl, ql, kn, vn)
+                    q_, kv_, bt_, cl_, ql_, kn_, vn_, False)[0],
+            q, kv, bt, cl, ql, kn, vn)
 
     def _int8_cache(self):
         """int8 KV pool + per-(kv-head, page) fp32 scales (ISSUE 13)."""
@@ -168,7 +170,7 @@ class TestPagedAttentionMosaic:
                                      (self.kvh, self.n_pages)), jnp.float32)
         bt = jnp.zeros((self.b, self.max_pages), jnp.int32)
         cl = jnp.full((self.b,), 40, jnp.int32)
-        return kc, vc, ks, vs, bt, cl
+        return pool_of_heads(kc, vc), ks, vs, bt, cl
 
     @pytest.mark.parametrize("T,ql", [(1, (1, 1)),     # pure decode
                                       (4, (4, 1)),     # T=K spec verify
@@ -182,17 +184,17 @@ class TestPagedAttentionMosaic:
         from paddle_tpu.kernels.paged_attention import \
             _pallas_ragged_paged_attention
 
-        kc, vc, ks, vs, bt, cl = self._int8_cache()
+        kv, ks, vs, bt, cl = self._int8_cache()
         q = _rand((self.b, T, self.qh, self.d), jnp.float32)
         qlv = jnp.asarray(ql, jnp.int32)
         kn = _rand((self.b, T, self.kvh, self.d), jnp.float32, seed=3)
         vn = _rand((self.b, T, self.kvh, self.d), jnp.float32, seed=4)
         _export_tpu(
-            lambda q_, kc_, vc_, bt_, cl_, ql_, kn_, vn_, ks_, vs_:
+            lambda q_, kv_, bt_, cl_, ql_, kn_, vn_, ks_, vs_:
                 _pallas_ragged_paged_attention(
-                    q_, kc_, vc_, bt_, cl_, ql_, kn_, vn_, False,
+                    q_, kv_, bt_, cl_, ql_, kn_, vn_, False,
                     ks_, vs_)[0],
-            q, kc, vc, bt, cl, qlv, kn, vn, ks, vs)
+            q, kv, bt, cl, qlv, kn, vn, ks, vs)
 
     def test_int8_quantized_commit_lowering(self):
         """The page-RMW quantized commit must also reach the chip: lower
@@ -203,10 +205,10 @@ class TestPagedAttentionMosaic:
 
         L, B, T = 2, self.b, 1
         rng = np.random.default_rng(9)
-        kc = jnp.asarray(rng.integers(
+        kv = jnp.asarray(rng.integers(
             -127, 128,
-            (L, self.kvh, self.n_pages, self.page_size, self.d)), jnp.int8)
-        vc = jnp.asarray(kc)
+            (L, self.n_pages, 2, self.kvh, self.page_size, self.d)),
+            jnp.int8)
         ks = jnp.ones((L, self.kvh, self.n_pages), jnp.float32)
         vs = jnp.ones((L, self.kvh, self.n_pages), jnp.float32)
         k_all = _rand((L, B * T, self.kvh, self.d), jnp.float32)
@@ -217,7 +219,7 @@ class TestPagedAttentionMosaic:
         _export_tpu(
             lambda *a: write_kv_pages_all_layers_quantized(
                 *a, self.max_pages * self.page_size),
-            kc, vc, ks, vs, k_all, v_all, pos, qlv, bt)
+            kv, ks, vs, k_all, v_all, pos, qlv, bt)
 
     @pytest.mark.parametrize("K", [4, 8])
     def test_spec_verify_bucket_kernel(self, K):
@@ -230,15 +232,15 @@ class TestPagedAttentionMosaic:
             _pallas_ragged_paged_attention
 
         q = _rand((self.b, K, self.qh, self.d))
-        k_cache, v_cache, bt, cl = self._cache()
+        kv, bt, cl = self._cache()
         ql = jnp.asarray([K, 1], jnp.int32)   # full draft vs no-draft row
         kn = _rand((self.b, K, self.kvh, self.d), seed=3)
         vn = _rand((self.b, K, self.kvh, self.d), seed=4)
         _export_tpu(
-            lambda q_, kc, vc, bt_, cl_, ql_, kn_, vn_:
+            lambda q_, kv_, bt_, cl_, ql_, kn_, vn_:
                 _pallas_ragged_paged_attention(
-                    q_, kc, vc, bt_, cl_, ql_, kn_, vn_, False)[0],
-            q, k_cache, v_cache, bt, cl, ql, kn, vn)
+                    q_, kv_, bt_, cl_, ql_, kn_, vn_, False)[0],
+            q, kv, bt, cl, ql, kn, vn)
 
 
 class TestTensorParallelMosaic:
@@ -265,7 +267,7 @@ class TestTensorParallelMosaic:
                    seed=2)
         bt = jnp.zeros((self.b, self.max_pages), jnp.int32)
         cl = jnp.full((self.b,), 40, jnp.int32)
-        return kc, vc, bt, cl
+        return pool_of_heads(kc, vc), bt, cl
 
     def _shard_export(self, T, ql, int8=False):
         from jax.sharding import PartitionSpec as P
@@ -281,8 +283,9 @@ class TestTensorParallelMosaic:
         if int8:
             rng = np.random.default_rng(7)
             shape = (self.kvh, self.n_pages, self.page_size, self.d)
-            kc = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
-            vc = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
+            kv = pool_of_heads(
+                jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                jnp.asarray(rng.integers(-127, 128, shape), jnp.int8))
             ks = jnp.asarray(rng.uniform(0.005, 0.02,
                                          (self.kvh, self.n_pages)),
                              jnp.float32)
@@ -290,7 +293,7 @@ class TestTensorParallelMosaic:
             bt = jnp.zeros((self.b, self.max_pages), jnp.int32)
             cl = jnp.full((self.b,), 40, jnp.int32)
         else:
-            kc, vc, bt, cl = self._cache()
+            kv, bt, cl = self._cache()
             ks = vs = None
         decode = T == 1 and ql is None
         qlv = None if decode else jnp.asarray(ql, jnp.int32)
@@ -299,7 +302,7 @@ class TestTensorParallelMosaic:
         vn = None if decode else _rand((self.b, T, self.kvh, self.d),
                                        dt, seed=4)
 
-        def body(q_, kc_, vc_, bt_, cl_, ql_=None, kn_=None, vn_=None,
+        def body(q_, kv_, bt_, cl_, ql_=None, kn_=None, vn_=None,
                  ks_=None, vs_=None):
             # mirror of generation._forward_tokens' tp layer body: slice
             # q (and fresh KV) to this shard's heads, run the kernel on
@@ -313,13 +316,15 @@ class TestTensorParallelMosaic:
                 vn_ = jax.lax.dynamic_slice_in_dim(
                     vn_, shard * kvh_l, kvh_l, axis=2)
             attn = _pallas_ragged_paged_attention(
-                q_s, kc_, vc_, bt_, cl_, ql_, kn_, vn_, False,
+                q_s, kv_, bt_, cl_, ql_, kn_, vn_, False,
                 ks_, vs_)[0]
             return jax.lax.all_gather(attn, "mp", axis=2, tiled=True)
 
-        rep, sh = P(), P("mp")
-        args = [q, kc, vc, bt, cl]
-        specs = [rep, sh, sh, rep, rep]
+        # the pool [pages, K|V, kv_heads, ...] and its scale rows
+        # [kv_heads, pages] are sharded where each counts heads
+        rep, sh, pool = P(), P("mp"), P(None, None, "mp")
+        args = [q, kv, bt, cl]
+        specs = [rep, pool, rep, rep]
         if not decode:
             args += [qlv, kn, vn]
             specs += [rep, rep, rep]

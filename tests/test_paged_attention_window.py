@@ -57,16 +57,17 @@ def _kernel_and_dense(window, T, contexts, max_pages):
     ctx = np.asarray(contexts, np.int32)
     table = rng.permutation(n_pages)[:B * max_pages].reshape(
         B, max_pages).astype(np.int32)          # pages scattered in the pool
-    kc = np.zeros((KVH, n_pages, PAGE, D), np.float32)
-    vc = np.zeros_like(kc)
+    # the pool as PagedKVCache lays it out, written here index by index:
+    # a page holds every head's K, then every head's V
+    kv = np.zeros((n_pages, 2, KVH, PAGE, D), np.float32)
     for b in range(B):
         for pos in range(int(ctx[b])):
-            kc[:, table[b, pos // PAGE], pos % PAGE] = k_full[b, pos]
-            vc[:, table[b, pos // PAGE], pos % PAGE] = v_full[b, pos]
+            kv[table[b, pos // PAGE], 0, :, pos % PAGE] = k_full[b, pos]
+            kv[table[b, pos // PAGE], 1, :, pos % PAGE] = v_full[b, pos]
     k_new = np.stack([k_full[b, ctx[b]:ctx[b] + T] for b in range(B)])
     v_new = np.stack([v_full[b, ctx[b]:ctx[b] + T] for b in range(B)])
     got = np.asarray(ragged_paged_attention(
-        jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(table),
+        jnp.asarray(q), jnp.asarray(kv), jnp.asarray(table),
         jnp.asarray(ctx), q_lens=jnp.asarray(ql), k_new=jnp.asarray(k_new),
         v_new=jnp.asarray(v_new), window=window))
     return got, _dense(q, k_full, v_full, ctx, ql, window), ql
@@ -102,9 +103,9 @@ def test_a_windows_first_page_falls_inside_a_block(window, interpret):
 
 def test_a_window_is_a_whole_number_of_at_least_one():
     z = jnp.zeros((1, 1, QH, D))
-    cache = jnp.zeros((KVH, 4, PAGE, D))
+    cache = jnp.zeros((4, 2, KVH, PAGE, D))
     for bad in (0, -3, 2.5):
         with pytest.raises(ValueError, match="window"):
-            ragged_paged_attention(z, cache, cache,
+            ragged_paged_attention(z, cache,
                                    jnp.zeros((1, 2), jnp.int32),
                                    jnp.zeros((1,), jnp.int32), window=bad)

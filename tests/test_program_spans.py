@@ -342,6 +342,12 @@ def test_a_steps_span_carries_the_rows_of_its_attention_tiles(sinks,
             a["T"] * 2, 8)
     assert all(a["attn_rows"] == 8 * a["rows"] for a in steps
                if a["kind"] == "decode")
+    # ``page_copies``: the descriptors one layer's call starts.  A table
+    # row is 8 pages (64 positions in pages of 8), so a block is the whole
+    # row: 8 copies a slot with work and a context, none for a first chunk
+    assert [a["page_copies"] for a in mixed] == [0, 8 + 8, 8 + 8]
+    assert all(a["page_copies"] == 8 * a["rows"] for a in steps
+               if a["kind"] == "decode")
     # the same sums from the kernel's module, whatever the tile
     monkeypatch.undo()
     assert pa.attn_rows([64, 1, 0, 3], 64, 4) == 3 * 256

@@ -43,7 +43,10 @@ def test_bytes_a_slot_at_the_published_sizes():
 def test_the_state_rides_with_the_pool_by_slot(model):
     eng = ContinuousBatchingEngine(model, **GEOMETRY)
     cache = eng.g.cache
-    k, v, ssm, conv = cache.arrays
+    kv, ssm, conv = cache.arrays
+    assert kv.shape == (model.config.num_hidden_layers,
+                        cache.allocator.num_pages, 2, cache.num_kv_heads,
+                        cache.page_size, cache.head_dim)
     c = model.config
     assert ssm.shape == (c.num_hidden_layers, 4, c.mamba_n_heads,
                          c.mamba_d_head, c.mamba_d_state)
@@ -55,8 +58,8 @@ def test_the_state_rides_with_the_pool_by_slot(model):
         == RecurrentState.bytes_per_slot(cache.recurrent.mixer,
                                          c.num_hidden_layers, "float32")
     # the pool's own bytes do not count it: it is no page
-    assert k.nbytes + v.nbytes == eng.g.pool_bytes
-    assert eng.step_operands(16)[1][2].shape == ssm.shape
+    assert kv.nbytes == eng.g.pool_bytes
+    assert eng.step_operands(16)[1][1].shape == ssm.shape
 
 
 def test_a_stack_states_one_kind_of_state():

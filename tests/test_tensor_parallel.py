@@ -143,8 +143,9 @@ def test_tp_int8_pages_bit_match(model):
     base, _ = _run(model, tp=1, cache_dtype="int8")
     got, eng = _run(model, tp=2, cache_dtype="int8")
     assert got == base
-    # per-(kv-head, page) scales shard with their heads: 4 planes
-    assert len(eng.g.cache.arrays) == 4 and len(eng.g.cache.pspecs) == 4
+    # per-(kv-head, page) scales shard with their heads: the pool and
+    # its two scale planes
+    assert len(eng.g.cache.arrays) == 3 and len(eng.g.cache.pspecs) == 3
 
 
 def test_tp_moe_grouped_expert_sharding_bit_match():
