@@ -6,8 +6,13 @@ docstring cites the reference component (file:line) it re-implements.
 """
 
 import os as _os
+import time as _time
 
-import jax as _jax
+# the first of the two clock readings `startup.import` is recorded from
+# (observability/startup.py, after the fact: no tracer exists yet)
+_IMPORT_BEGAN = _time.perf_counter()
+
+import jax as _jax  # noqa: E402
 
 # Paddle's dtype surface includes real int64/float64 tensors
 # (phi DataType::INT64/FLOAT64); without x64 JAX silently narrows to 32-bit.
@@ -159,3 +164,6 @@ def __getattr__(name):
         # shadowed inside this module (annotations, future bool() calls)
         return dtypes.bool_
     raise AttributeError(f"module 'paddle_tpu' has no attribute {name!r}")
+
+
+_IMPORT_ENDED = _time.perf_counter()

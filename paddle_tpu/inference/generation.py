@@ -67,6 +67,7 @@ import numpy as np
 
 from .. import flags
 from .. import observability as _obs
+from ..observability import startup as _startup
 from ..kernels.grouped_matmul import take_sentinel_rows
 from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        page_copies, paged_attention,
@@ -488,7 +489,8 @@ class LlamaGenerator:
 
         # the model's own layout for the engine's scan: a tuple with one
         # dict of [periods, ...] stacks for each place in the layer pattern
-        self.params = model.serving_params()
+        with _startup.phase("startup.stack_params"):
+            self.params = model.serving_params()
         if len(self.params["blocks"]) != len(c.pattern):
             raise ValueError(
                 f"serving_params() has {len(self.params['blocks'])} block "
@@ -540,17 +542,18 @@ class LlamaGenerator:
         latent = None if la is None else (la.rank, la.rope)
         # what a slot holds besides pages (a state-space mixer's state and
         # convolution rows): fixed, by slot, riding with the pool
-        recurrent = None if c.ssm is None else RecurrentState(
-            c.ssm, c.num_layers, max_batch, dtype)
+        with _startup.phase("startup.pool_alloc", pages=self.num_pages):
+            recurrent = None if c.ssm is None else RecurrentState(
+                c.ssm, c.num_layers, max_batch, dtype)
+            self.cache = PagedKVCache(
+                num_layers=c.num_layers,
+                num_pages=self.num_pages,
+                page_size=page_size, num_kv_heads=c.num_kv_heads,
+                head_dim=c.head_dim, dtype=cache_dtype or dtype,
+                mesh=self.mesh, axis=MP_AXIS, latent=latent,
+                recurrent=recurrent)
         self.state_bytes_per_slot = 0 if recurrent is None else \
             RecurrentState.bytes_per_slot(c.ssm, c.num_layers, dtype)
-        self.cache = PagedKVCache(
-            num_layers=c.num_layers,
-            num_pages=self.num_pages,
-            page_size=page_size, num_kv_heads=c.num_kv_heads,
-            head_dim=c.head_dim, dtype=cache_dtype or dtype,
-            mesh=self.mesh, axis=MP_AXIS, latent=latent,
-            recurrent=recurrent)
         # host-global pool bytes (all shards) — advertised via stats() /
         # /statusz so the router's capacity-weighted placement can rank
         # heterogeneous replicas
@@ -620,6 +623,14 @@ class LlamaGenerator:
                         else self.kv_copy_bytes,
                         None if None in windows else max(windows))
         return 3 * n if self.spec.latent is not None else n
+
+    def attention_counts(self, t, rows) -> dict:
+        """The three above as ``engine.step``'s arguments.  Each is a loop
+        over the working slots: the step computes them only while somebody
+        listens (``Tracer.listening``)."""
+        return {"kv_read_tokens": self.kv_read_tokens(rows),
+                "attn_rows": self.attn_rows(t, rows),
+                "page_copies": self.page_copies(rows)}
 
     def _head_logits(self, params, h):
         """float32 logits of hidden states ``h [..., H]``: the head, or the
@@ -715,10 +726,10 @@ class LlamaGenerator:
         key = ("spec", gc._key(), k, nmax)
         if key not in self._jit_cache:
             import functools
-            self._jit_cache[key] = self._tp_jit(
+            self._jit_cache[key] = self._first_call_logged(key, self._tp_jit(
                 functools.partial(self._spec_verify_fn, gc, k, nmax),
                 f"serve_spec_verify_K{k}",
-                n_in=13, n_out=11, out_cache_idx=9)
+                n_in=13, n_out=11, out_cache_idx=9), k)
         return self._jit_cache[key]
 
     def _fused_jit(self, gc: GenerationConfig, k: int):
@@ -727,11 +738,25 @@ class LlamaGenerator:
         key = ("fused", gc._key(), k)
         if key not in self._jit_cache:
             import functools
-            self._jit_cache[key] = self._tp_jit(
+            self._jit_cache[key] = self._first_call_logged(key, self._tp_jit(
                 functools.partial(self._fused_decode_fn, gc, k),
                 f"serve_fused_K{k}",
-                n_in=10, n_out=9, out_cache_idx=7)
+                n_in=10, n_out=9, out_cache_idx=7), k)
         return self._jit_cache[key]
+
+    def _first_call_logged(self, key, jitted, T: int):
+        """``jitted`` for ``_jit_cache[key]``: its first call (tracing,
+        lowering, the compile or the cache's read) runs as a
+        ``startup.program`` phase and leaves the bare program in the cache,
+        so no later dispatch passes through here."""
+        def first(*operands):
+            with _startup.program("jit_" + jitted.__name__, T=T,
+                                  rows=self.max_batch * T):
+                out = jitted(*operands)
+            self._jit_cache[key] = jitted
+            return out
+        first.__name__, first.lower = jitted.__name__, jitted.lower
+        return first
 
     # ---- the shared transformer core of every serving step ----
     def _forward_tokens(self, params, cache, tokens, ql, positions,
@@ -1585,6 +1610,9 @@ class ContinuousBatchingEngine:
     cache on bit-match the cache-off oracle.
     """
 
+    @_startup.around("startup.engine_build", lambda self: {
+        "slots": self.B, "pages": self.g.num_pages,
+        "pool_bytes": self.g.pool_bytes})
     def __init__(self, model, *, max_batch: int = 8,
                  gen: Optional[GenerationConfig] = None,
                  prefix_cache: Optional[bool] = None,
@@ -1725,8 +1753,9 @@ class ContinuousBatchingEngine:
             # warm the copy program with an all-no-op call so the first
             # cache hit (and every later one) stays zero-recompile
             none = jnp.full((B,), -1, jnp.int32)
-            self.g.cache.update(*self._cow_jit(self.g.cache.arrays,
-                                               none, none))
+            with _startup.program("jit_pool_cow_copy"):
+                self.g.cache.update(*self._cow_jit(self.g.cache.arrays,
+                                                   none, none))
             # ---- host-RAM spill tier (ISSUE 13): LRU-evicted prefix
             # pages spill to a pinned-host ring instead of dropping, and
             # admission swaps them back asynchronously — eviction becomes
@@ -1823,9 +1852,18 @@ class ContinuousBatchingEngine:
         fam = self._families.get(T)
         if fam is None:
             fam = self._families[T] = {
-                n: self.lowered_step(T, n).compile()
-                for n in self.g.row_buckets(T)}
+                n: self._compiled_step(T, n) for n in self.g.row_buckets(T)}
         return fam
+
+    def _compiled_step(self, T: int, rows: int):
+        """One member of a family, built under the start-up log's phases:
+        tracing and lowering (Python, paid on every start), then the
+        backend's compile or the persistent cache's read, and the load."""
+        with _startup.program(f"jit_serve_step_T{T}", T=T, rows=rows):
+            with _startup.phase("startup.lower"):
+                lowered = self.lowered_step(T, rows)
+            with _startup.compiling():
+                return lowered.compile()
 
     def run(self) -> dict:
         """Drive to completion; returns {req_id: generated tokens} for every
@@ -1899,14 +1937,13 @@ class ContinuousBatchingEngine:
                                 + self._upload_caps())
             rows = sum(r is not None for r in self.slot_req)
             k = int(self.spec.k)
-            attends = [(k, max(int(self.host_lens[b]) - k, 0))
-                       for b in range(B) if self.slot_req[b] is not None]
             span.set_metadata(
                 kind="spec", T=k, rows=rows, q_tokens=rows * k,
-                gemm_rows=B * k, waiting=len(self.waiting),
-                kv_read_tokens=g.kv_read_tokens(attends),
-                attn_rows=g.attn_rows(k, attends),
-                page_copies=g.page_copies(attends))
+                gemm_rows=B * k, waiting=len(self.waiting))
+            if tracer.listening():
+                span.set_metadata(**g.attention_counts(k, [
+                    (k, max(int(self.host_lens[b]) - k, 0))
+                    for b in range(B) if self.slot_req[b] is not None]))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
             self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
@@ -1982,15 +2019,15 @@ class ContinuousBatchingEngine:
         step = self._step_family(T)[gemm_rows]
         span.set_metadata(kind="mixed" if T > 1 else "decode", T=int(T),
                           rows=rows, q_tokens=q_tokens, gemm_rows=gemm_rows,
-                          kv_read_tokens=g.kv_read_tokens(attends),
-                          attn_rows=g.attn_rows(T, attends),
-                          page_copies=g.page_copies(attends),
                           waiting=len(self.waiting))
-        if g.spec.ssm is not None:
-            # the slots whose recurrent state the step's scan calls read
-            # and write (those with work), and the tokens they scan
-            span.set_metadata(ssm_slots=int((ql > 0).sum()),
-                              ssm_tokens=q_tokens)
+        if tracer.listening():
+            # three loops over the working slots, for a reader's sake alone
+            span.set_metadata(**g.attention_counts(T, attends))
+            if g.spec.ssm is not None:
+                # the slots whose recurrent state the step's scan calls
+                # read and write (those with work), and the tokens they scan
+                span.set_metadata(ssm_slots=int((ql > 0).sum()),
+                                  ssm_tokens=q_tokens)
         with tracer.span("engine.dispatch", program=f"serve_step_T{T}"):
             out = step(g.params, g.cache.arrays, tokens_in, ql_dev,
                        self.positions, self.finished, dm, commit_dev,

@@ -197,6 +197,8 @@ class HostSpillPool:
         """Compile the upload program with an out-of-range page id (the
         scatter drops every write) so the first real swap-in — and every
         later one — is dispatch-only."""
-        self.cache.update(*self._upload(
-            self.cache.arrays, jnp.int32(self.cache.allocator.num_pages),
-            self.cache.page_plane_zeros()))
+        with _obs.startup.program("jit_pool_swap_in"):
+            self.cache.update(*self._upload(
+                self.cache.arrays,
+                jnp.int32(self.cache.allocator.num_pages),
+                self.cache.page_plane_zeros()))

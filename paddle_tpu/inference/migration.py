@@ -403,9 +403,10 @@ def warm(engine) -> None:
     scatter write drops) so the first real import is dispatch-only."""
     _refuse_unsnapshotted(engine)
     cache = engine.g.cache
-    cache.update(*_uploader(engine)(
-        cache.arrays, jnp.int32(cache.allocator.num_pages),
-        cache.page_plane_zeros()))
+    with _obs.startup.program("jit_pool_swap_in"):
+        cache.update(*_uploader(engine)(
+            cache.arrays, jnp.int32(cache.allocator.num_pages),
+            cache.page_plane_zeros()))
 
 
 def import_session(engine, snap: dict, resume: bool = False) -> dict:

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from ..nn.layer import Layer, LayerList
 from ..ops._prim import apply_op
 from .decoder_spec import DecoderSpec, LayerKind, MoeSpec
-from .llama import _rope_cos_sin, _scaled_init
+from .llama import _model_init, _rope_cos_sin, _scaled_init
 
 
 @dataclass
@@ -231,6 +231,7 @@ class CohereMoeForCausalLM(Layer):
     parameters instead of drawing random ones, so that a build holds the
     weights once and never a second, discarded set."""
 
+    @_model_init("cohere2_moe")
     def __init__(self, config: Cohere2MoeConfig,
                  params: Optional[dict] = None):
         super().__init__(dtype=config.dtype)

@@ -43,7 +43,7 @@ from ..nn.layer import Layer
 from ..ops._prim import apply_op
 from .cohere2_moe import _adopt, _ones
 from .decoder_spec import DecoderSpec, LayerKind, SsmMixer
-from .llama import _rope_cos_sin, _scaled_init
+from .llama import _model_init, _rope_cos_sin, _scaled_init
 
 # what the scan reads in float32 whatever the model's type
 FLOAT32_LEAVES = ("mamba.dt_bias", "mamba.A_log", "mamba.D")
@@ -216,6 +216,7 @@ class FalconH1ForCausalLM(Layer):
     ``serving_params()``): a caller's own arrays, adopted as the model's
     parameters instead of drawing random ones."""
 
+    @_model_init("falcon_h1")
     def __init__(self, config: FalconH1Config, params: Optional[dict] = None):
         super().__init__(dtype=config.dtype)
         c = self.config = config

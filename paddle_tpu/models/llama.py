@@ -33,6 +33,7 @@ from ..core.tensor import Tensor
 from ..kernels.flash_attention import flash_attention
 from ..nn import functional as F
 from ..nn.layer import Layer, LayerList
+from ..observability import startup as _startup
 from ..ops._prim import apply_op
 
 
@@ -862,7 +863,17 @@ def _seq_constrain(h, config: LlamaConfig):
                     lambda v: jax.lax.with_sharding_constraint(v, sh), (h,))
 
 
+def _model_init(family: str):
+    """A served model's constructor as the start-up log's
+    ``startup.model_init`` phase: the random initialisation that a
+    launcher's checkpoint or a caller's own weights then replace."""
+    return _startup.around("startup.model_init", lambda self: {
+        "family": family, "layers": self.config.num_hidden_layers,
+        "params": sum(math.prod(p.shape) for p in self.parameters())})
+
+
 class LlamaForCausalLM(Layer):
+    @_model_init("llama")
     def __init__(self, config: LlamaConfig):
         super().__init__(dtype=config.dtype)
         self.config = config

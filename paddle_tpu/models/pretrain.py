@@ -21,6 +21,7 @@ The model *math* comes from models.llama's layers via the functional bridge
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 import warnings
@@ -46,6 +47,9 @@ from .llama import LlamaConfig, LlamaDecoderLayer, _rope_cos_sin, _scaled_init
 # data-parallel mesh axis: collectives inside shard_map bodies must
 # reference this constant, not the literal (jaxlint JL008)
 DP_AXIS = "dp"
+
+
+_WARM = contextlib.nullcontext()    # around every call of the step but the first
 
 
 def _remat(f, policy: str):
@@ -165,6 +169,9 @@ def _block_spec(name: str) -> Tuple[Optional[str], ...]:
 class PretrainStep:
     """Builds init_state() and a jitted train_step(state, ids, labels)."""
 
+    @_obs.startup.around("startup.train_build", lambda self: {
+        "dp": self.pc.dp, "mp": self.pc.mp,
+        "layers": self.config.num_hidden_layers})
     def __init__(self, config: LlamaConfig, parallel: Optional[ParallelConfig] = None,
                  learning_rate: float = 3e-4, weight_decay: float = 0.1,
                  beta1: float = 0.9, beta2: float = 0.95, eps: float = 1e-8,
@@ -271,6 +278,7 @@ class PretrainStep:
                 "kernel is not split over the mesh inside pipeline "
                 "stages yet — use pp=1, or pp alone")
         self._jit_step = None
+        self._step_called = False      # its first call is a start-up phase
         self._zero1_warned: set = set()
         # per-step train telemetry (ISSUE 5): host-timestamp StepTimer —
         # step wall time, tokens/s, per-step recompiles and the analytic
@@ -866,8 +874,13 @@ class PretrainStep:
                     ids, labels = self.shard_batch(np.asarray(ids),
                                                    np.asarray(labels))
             step = self._jitted_step(state, ids, labels)
-            with tracer.span("train.dispatch"):
+            # the first call traces, lowers and compiles (or reads the
+            # cache): a phase of the start-up log; no later one is
+            first = _WARM if self._step_called else _obs.startup.program(
+                "jit_pretrain_step", T=int(t), rows=tokens)
+            with tracer.span("train.dispatch"), first:
                 out = step(state, ids, labels)
+            self._step_called = True
             if self._telemetry is not None:
                 if self._grad_sync_bytes is None:
                     try:    # analytic per-step dp gradient-sync traffic

@@ -53,7 +53,7 @@ from ..ops._prim import apply_op
 from .cohere2_moe import _adopt, _ones
 from .decoder_spec import (EXPERT_BANKS, DecoderSpec, LatentAttn, LayerKind,
                            MoeSpec, RopeYarn)
-from .llama import _scaled_init
+from .llama import _model_init, _scaled_init
 
 
 @dataclass
@@ -260,6 +260,7 @@ class SarvamMlaForCausalLM(Layer):
     ``serving_params()``): a caller's own arrays, adopted as the model's
     parameters instead of drawing random ones."""
 
+    @_model_init("sarvam_mla")
     def __init__(self, config: SarvamMlaConfig,
                  params: Optional[dict] = None):
         super().__init__(dtype=config.dtype)

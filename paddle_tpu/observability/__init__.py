@@ -15,7 +15,10 @@ behind every subsystem's telemetry:
 - **compile** — the jax.monitoring backend-compile listener lives HERE and
   feeds ``jit.backend_compiles`` / ``jit.backend_compile_ms``;
   ``paddle_tpu.jit.cache_stats()`` and ``assert_no_recompiles`` read the
-  same series, so compile telemetry is one system.
+  same series, so compile telemetry is one system.  The same listener
+  hands every trace, lowering, compile and cache-read event to the
+  start-up log (``startup.py``), which keeps it for the set-up phase open
+  on the event's thread.
 - **profiler** — ``paddle_tpu.profiler.RecordEvent`` is a thin frontend
   over this tracer + registry (same public API; ``summary()`` reads the
   registry).
@@ -32,7 +35,7 @@ import time
 from typing import Optional
 
 from .. import flags
-from . import catalog, metrics, tracing
+from . import catalog, metrics, startup, tracing
 from .attribution import StepAttribution
 from .collector import (ClockSync, HttpTransport, InprocTransport,
                         SpanExporter, StoreTransport, TraceCollector)
@@ -44,8 +47,9 @@ from .tracing import TRACER, Tracer
 
 tracer = TRACER
 
-__all__ = ["metrics", "tracing", "catalog", "REGISTRY", "counter", "gauge",
-           "histogram", "snapshot", "prometheus_text", "reset", "find",
+__all__ = ["metrics", "tracing", "catalog", "startup", "REGISTRY",
+           "counter", "gauge", "histogram", "snapshot", "prometheus_text",
+           "reset", "find",
            "set_help", "tracer", "Tracer", "TRACER", "FlightRecorder",
            "StepAttribution", "Sentinel",
            "ClockSync", "SpanExporter", "TraceCollector",
@@ -75,11 +79,17 @@ _COMPILE_MS = metrics.histogram("jit.backend_compile_ms")
 
 
 def _on_event_duration(name, *args, **kw):
+    dur = args[0] if args else kw.get("duration_secs")
+    if not isinstance(dur, (int, float)):
+        dur = None
     if name == "/jax/core/compile/backend_compile_duration":
         _BACKEND_COMPILES.inc()
-        dur = args[0] if args else kw.get("duration_secs")
-        if isinstance(dur, (int, float)):
+        if dur is not None:
             _COMPILE_MS.observe(dur * 1e3)
+    if dur is not None:
+        # the start-up log keeps what the phase open on this thread traced,
+        # lowered, compiled or read from the persistent cache
+        startup.LOG.on_jit_event(name, dur)
 
 
 import jax as _jax  # noqa: E402  (after the registry exists)

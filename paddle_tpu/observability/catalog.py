@@ -623,6 +623,65 @@ SPANS: Dict[str, tuple] = {
     "train.dispatch": (
         "train", "train", "local", "",
         "the call of the jitted `pretrain_step` program"),
+    # ---- set-up by phase (PR 36): `observability/startup.py` opens each
+    # as a span AND, until the log is sealed at ready, keeps it as a record
+    # on the process's own age (`/statusz`'s `startup` block) ----
+    "startup.import": (
+        "startup", "startup", "local", "began_age_s",
+        "`paddle_tpu/__init__.py`, top to bottom (jax's own import when "
+        "nothing imported it before).  In the log only, recorded after the "
+        "fact from two clock readings: no tracer exists at the top.  "
+        "`began_age_s` the process's age when the import began: the "
+        "interpreter's start and whatever the caller imported first"),
+    "startup.model_init": (
+        "startup", "startup", "local", "family, layers, params",
+        "the constructor of `LlamaForCausalLM`, `CohereMoeForCausalLM`, "
+        "`SarvamMlaForCausalLM` or `FalconH1ForCausalLM`: the random "
+        "initialisation, leaf by leaf, that a launcher's checkpoint or a "
+        "caller's own weights then replace (`params` the parameters it "
+        "made)"),
+    "startup.engine_build": (
+        "startup", "startup", "local", "slots, pages, pool_bytes",
+        "`ContinuousBatchingEngine.__init__`, whole; holds "
+        "`startup.stack_params`, `startup.pool_alloc` and the pool "
+        "programs' first calls"),
+    "startup.stack_params": (
+        "startup", "startup", "local", "",
+        "`model.serving_params()` in `LlamaGenerator.__init__`: the "
+        "engine's stacked copy of the weights (dispatch: the device's "
+        "part ends in whichever phase first waits for it)"),
+    "startup.pool_alloc": (
+        "startup", "startup", "local", "pages",
+        "the paged pool, the latent pool or the recurrent state beside it "
+        "(`PagedKVCache`, `RecurrentState`)"),
+    "startup.train_build": (
+        "startup", "startup", "local", "dp, mp, layers",
+        "`PretrainStep.__init__`: the mesh and the template layer"),
+    "startup.program": (
+        "startup", "startup", "local",
+        "program, T, rows, cache_hit, compile_s|cache_read_s",
+        "one jitted program made ready: a member of a step family in "
+        "`_step_family` (`startup.lower` and `startup.compile` inside), or "
+        "the FIRST call of a program compiled by its call (`PretrainStep`'s "
+        "step, the speculative lanes' programs, `pool_cow_copy`, "
+        "`pool_swap_in`).  `program` the `jit_<name>` a trace shows, `T` "
+        "and `rows` its query bucket and GEMM rows (a train step: sequence "
+        "length and tokens), `cache_hit` whether the persistent cache held "
+        "every module it compiled, with the cache's `cache_read_s` or the "
+        "backend's `compile_s`"),
+    "startup.lower": (
+        "startup", "startup", "local", "",
+        "`lowered_step(T, rows)`: tracing and lowering, Python, paid on "
+        "every start whatever the cache holds"),
+    "startup.compile": (
+        "startup", "startup", "local",
+        "cache_hit, compile_s|cache_read_s",
+        "`Lowered.compile()`: the backend's compile or the persistent "
+        "cache's read, and the load"),
+    "startup.warm": (
+        "startup", "startup", "local", "",
+        "`ServingServer._warm`: one junk request through both step "
+        "families on the engine thread, before `/readyz` flips"),
 }
 
 
@@ -685,6 +744,30 @@ def generate_markdown() -> str:
     for name, (_cat, lane, sinks, args, where) in SPANS.items():
         lines.append(f"| `{name}` | {lane} | {sinks} "
                      f"| {'`' + args + '`' if args else '—'} | {where} |")
+    lines += [
+        "", "## Time to ready by phase", "",
+        "`GET /statusz` of a replica carries a `startup` block: `records`,",
+        "one for each `startup.*` span that opened before the replica was",
+        "ready (`name`, `args`, `start_age_s`, `dur_s`, `thread`, `depth`,",
+        "`jit`), `sealed` (true once `/readyz` has flipped: nothing is",
+        "appended afterwards), `ready_age_s` and `overflow` (records beyond",
+        "the list's 256, counted and not kept).  `start_age_s` and",
+        "`ready_age_s` are seconds since the PROCESS started, so the gap",
+        "before the first record is the interpreter, jax and the launcher's",
+        "own work.  Read a slow start from the top: `startup.import`, then",
+        "`startup.model_init` (a random initialisation a checkpoint",
+        "replaces), `startup.engine_build` with the stacked weights and",
+        "the pool inside it, then under `startup.warm` one `startup.program`",
+        "a step program, each split into `startup.lower` (Python, paid on",
+        "every start) and `startup.compile`, whose `cache_hit` says whether",
+        "the persistent cache held the program (`cache_read_s`) or the",
+        "backend compiled it (`compile_s`); a record whose `dur_s` is null",
+        "is still open, which is where a start that hangs is.  `jit` counts",
+        "what jax reported while that phase was the innermost open one on",
+        "its thread: `trace_n/_s`, `lower_n/_s`, `compile_n/_s` (every",
+        "backend compile, the cache's read inside it) and `cache_read_n/_s`;",
+        "a trace inside a trace is counted twice, so the seconds are a",
+        "guide and the phases' own `dur_s` the measure."]
     return "\n".join(lines) + "\n"
 
 

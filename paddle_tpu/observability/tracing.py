@@ -127,6 +127,12 @@ class Tracer:
         instrumentation site gates on this one attribute."""
         return self._active
 
+    def listening(self) -> bool:
+        """True when a span's arguments reach anybody: the sinks are on, or
+        a profiler session runs.  A site whose arguments cost a loop to
+        compute asks first."""
+        return self._active or TraceAnnotation.is_enabled()
+
     # -------------------------------------------------------- lifecycle --
     def start(self, clear: bool = True) -> "Tracer":
         if clear:

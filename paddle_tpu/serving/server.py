@@ -412,6 +412,8 @@ class ServingServer:
                 self._warm()
                 if self.slo is not None:
                     self.slo.forget()     # warmup latency is compile time
+            # ready: the start-up log closes (`/statusz`'s `startup` block)
+            _obs.startup.seal()
             self._ready.set()
             tracer = _obs.TRACER
             while not self._stop.is_set():
@@ -514,6 +516,7 @@ class ServingServer:
                             {"finish_reason": "queue_expired",
                              "n": 0}))
 
+    @_obs.startup.around("startup.warm")
     def _warm(self) -> None:
         """Compile the engine's step-program pair (T=prefill_bucket mixed
         + T=1 decode) by driving one junk request to completion on the
@@ -1144,6 +1147,9 @@ class ServingServer:
             if self.sentinel is not None else None,
             "flight_recorder": None,
             "jit_cache": _jit.cache_stats(),
+            # time to ready by phase (observability/startup.py): the
+            # records on the process's own age, sealed when /readyz flipped
+            "startup": _obs.startup.status(),
             "build": {
                 "jax": jax.__version__,
                 "backend": jax.default_backend(),
