@@ -43,9 +43,15 @@ Structure — ONE jitted step function serves every serving phase:
   zeroed on the device by the step that runs its first chunk and is not
   touched by a step in which the slot has no work.
 - EOS / budget / capacity tracking lives ON DEVICE (``finished``,
-  ``gen_counts``, ``budgets``): the host loop is sync-free — one async jit
-  dispatch per step — and drains results every ``sync_every`` steps.
-  Essential when the device sits behind a high-latency link.
+  ``gen_counts``, ``budgets``): the host loop is one async jit dispatch
+  per step.  Each dispatch starts the copy of ITS OWN results to the
+  host, and every ``step()`` gathers the steps that have landed, oldest
+  first, without waiting; the host waits for the device only when
+  ``MAX_STEPS_IN_FLIGHT`` steps are out, when it has nothing to dispatch,
+  or when a caller needs a settled engine (``_gather``).  There is no
+  cadence: a token reaches its request one step after it was sampled.
+  (``LlamaGenerator.generate``'s own loop probes every ``sync_every``
+  steps; no engine reads that number.)
 
 Static shapes throughout: fixed [max_batch] rows, fixed chunk buckets, a
 fixed block-table width and two row buckets keep the compile count at
@@ -102,6 +108,17 @@ MIN_GEMM_ROWS = 256
 # holds a few prefilling slots' chunks and one token of each other slot,
 # so steady traffic stays under it; a burst takes the dense member.
 PACKED_GRID_SHARE = 4
+# The most engine steps in flight: dispatched, their results not yet
+# gathered by the host.  Not a setting: one step has to be queued behind
+# the one that runs for the device never to wait for the host (the host's
+# work a step is a fifth of a device step or less in every cell), and a
+# second queued step bought nothing on the chip (PERF.md section 6, PR
+# 37), while every step in flight is one more a finished slot waits to be
+# refilled and one more whose tokens the host has not counted.
+MAX_STEPS_IN_FLIGHT = 2
+# Why a gather waits for the device: the queue is at its bound, there is
+# nothing to dispatch, or a caller needs a settled engine.
+GATHER_BLOCKS = ("bound", "idle", "settle")
 
 
 def _pack_plan(ql, T, rows):
@@ -484,6 +501,7 @@ class LlamaGenerator:
         page_size = int(page_size)
         self.page_size = page_size
         self.prefill_bucket = min(prefill_bucket, self.max_seq_len)
+        # generate()'s all-done probe alone reads it: the engine has no cadence
         self.sync_every = sync_every
         self.pages_per_seq = -(-self.max_seq_len // page_size)
 
@@ -1231,7 +1249,7 @@ class LlamaGenerator:
                                       committed.astype(jnp.int32))
             out = out + (recent,)
         # last, where this chip holds a share of the experts: [entries on
-        # held experts, rows laid out], read at the drain that exists
+        # held experts, rows laid out], read when the step is gathered
         return out + (moe_rows,) if self.counts_moe_rows else out
 
     # ---- ISSUE 9: the T=K speculative verify step (ngram mode) ----
@@ -1486,7 +1504,7 @@ class Request:
 
     The ``t_*`` fields are host ``perf_counter`` stamps of the request's
     lifecycle (enqueue → admission → first token → last token), recorded
-    by the engine's observability instrumentation at dispatch/drain time —
+    by the engine's observability instrumentation at dispatch/gather time —
     never via a device sync.
 
     ``trace_id`` is the caller's trace-context id (the HTTP front door's
@@ -1512,13 +1530,67 @@ class Request:
         self.trace_id = trace_id
 
 
+class _InFlight:
+    """One dispatched step's OWN results on their way to the host: the
+    arrays that step returned (never a chained "latest" value, which a
+    later step in flight would make the gather wait for) and the request
+    every row held when it was dispatched, so a token gathered after its
+    slot was handed on is credited to the request that made it or to none.
+
+    ``kind`` "step": ``out`` [B] sampled tokens, ``commit`` the host's
+    [B] commit marks; "spec": ``out`` [B, K], ``commit`` the device's [B]
+    commit counts, ``drafted`` [B] or None.  ``finished`` [B] as that step
+    left it; ``moe_rows`` [entries on held experts, rows laid out] where
+    the step counts them; ``t`` the host's dispatch stamp."""
+
+    __slots__ = ("kind", "out", "commit", "drafted", "finished",
+                 "moe_rows", "reqs", "t")
+
+    def __init__(self, kind, out, commit, drafted, finished, moe_rows,
+                 reqs, t):
+        self.kind = kind
+        self.out = out
+        self.commit = commit
+        self.drafted = drafted
+        self.finished = finished
+        self.moe_rows = moe_rows
+        self.reqs = reqs
+        self.t = t
+        # the copies start now, behind the step: by the time the host
+        # asks, the bytes are there or on their way
+        for a in (out, commit, drafted, finished, moe_rows):
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+
+    def landed(self) -> bool:
+        """Whether the step has run: a gather of it would not wait for
+        the device (one program made all its arrays, so one is asked)."""
+        return self.out.is_ready()
+
+    def to_host(self) -> None:
+        """The arrays as numpy values; waits for the step if it has not
+        landed."""
+        self.out = np.asarray(self.out)
+        self.commit = np.asarray(self.commit)
+        self.finished = np.asarray(self.finished)
+        if self.drafted is not None:
+            self.drafted = np.asarray(self.drafted)
+        if self.moe_rows is not None:
+            self.moe_rows = np.asarray(self.moe_rows)
+
+    def commits_ahead(self, b: int, k: int) -> int:
+        """The most tokens this step may yet commit for row ``b``."""
+        return k if self.kind == "spec" else int(self.commit[b])
+
+
 class _ServingMetrics:
     """Resolved registry handles for the serving hot path (one dict lookup
     per series at engine construction, plain attribute access per step)."""
 
     __slots__ = ("requests", "completed", "tokens", "prefill_tokens",
                  "queue_wait", "ttft", "itl", "queue_depth", "queue_now",
-                 "occupancy", "steps", "drains", "pages_in_use",
+                 "occupancy", "steps", "drains", "gather_blocked",
+                 "steps_in_flight", "pages_in_use",
                  "peak_pages", "active_seqs", "cached_pages",
                  "evictable_pages", "spec_drafted", "spec_accepted",
                  "spec_rejected", "accept_len", "digest_epoch",
@@ -1528,15 +1600,16 @@ class _ServingMetrics:
         m = _obs.metrics
         # speculative decoding (ISSUE 9): drafted/accepted/rejected token
         # counters + per-dispatch accepted-prefix-length histogram, all
-        # folded in at the existing drain (never per step)
+        # folded in when the step is gathered
         self.spec_drafted = m.counter("serving.spec.drafted_tokens")
         self.spec_accepted = m.counter("serving.spec.accepted_tokens")
         self.spec_rejected = m.counter("serving.spec.rejected_tokens")
         self.accept_len = m.histogram(
             "serving.spec.accept_len",
             bounds=[0.0, 1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0])
-        # one observation a step, the sum over the layers, folded in at
-        # the drain — (token, choice) entries that fell on held experts
+        # one observation a step, the sum over the layers, folded in when
+        # the step is gathered — (token, choice) entries that fell on held
+        # experts
         # (every expert is held unless the chip holds a share) and the
         # rows of the tiles the grouped GEMM laid out for them
         rows_bounds = [float(2 ** i) for i in range(4, 21)]
@@ -1559,6 +1632,13 @@ class _ServingMetrics:
         self.occupancy = m.histogram("serving.batch_occupancy")
         self.steps = m.counter("serving.steps")
         self.drains = m.counter("serving.drains")
+        # gathers that had to wait for the device, by what made them
+        self.gather_blocked = {
+            why: m.counter("serving.gather_blocked", reason=why)
+            for why in GATHER_BLOCKS}
+        self.steps_in_flight = m.histogram(
+            "serving.steps_in_flight",
+            bounds=[float(i) for i in range(1, 9)])
         self.pages_in_use = m.gauge("serving.pages_in_use")
         self.peak_pages = m.gauge("serving.peak_pages_in_use")
         self.active_seqs = m.gauge("serving.active_seqs")
@@ -1568,7 +1648,7 @@ class _ServingMetrics:
 
     def update_pool(self, stats: dict) -> None:
         """Fold the allocator/prefix-cache gauges in from engine.stats()
-        (called at every drain — the existing host touch point)."""
+        (called at every gather — the existing host touch point)."""
         self.pages_in_use.set(stats["pages_in_use"])
         self.peak_pages.set(stats["peak_in_use"])
         self.active_seqs.set(stats["active_seqs"])
@@ -1592,10 +1672,15 @@ class ContinuousBatchingEngine:
     dense member), each compiled whole at its first dispatch; every warm
     step reuses them — telemetry-asserted zero recompiles.
 
-    EOS / budget / capacity freezing happens on device; the host drains
-    sampled tokens, retires finished requests (freeing their pages back to
-    the pool) and admits waiting ones every ``sync_every`` steps, so steady
-    state runs one async dispatch per step with no per-step host sync.
+    EOS / budget / capacity freezing happens on device.  Every dispatch
+    sends its own results towards the host and every ``step()`` gathers the
+    steps that have landed (``_gather``): their tokens go to their requests,
+    finished requests are retired (their pages back to the pool) and waiting
+    ones admitted, one step after the device made the token.  The host
+    waits for the device only with ``MAX_STEPS_IN_FLIGHT`` steps out, with
+    nothing to dispatch, or when asked for a settled engine (``_drain``), so
+    steady state runs one async dispatch per step and the device always has
+    the next step queued.
 
     With ``prefix_cache=True`` (or ``FLAGS_prefix_cache``) admission
     consults the radix prefix cache (``inference/prefix_cache.py``): a
@@ -1641,24 +1726,19 @@ class ContinuousBatchingEngine:
         self._bt = np.zeros((B, self.g.pages_per_seq), np.int32)
         self._bt_dev = jnp.asarray(self._bt)
         self._ql1 = jnp.ones((B,), i32)
-        # pending window entries are ("step", out_dev [B], commit np [B],
-        # None, t_disp) for plain steps and ("spec", out_dev [B, K],
-        # n_commit_dev [B], drafted_dev [B] | None, t_disp) for
-        # speculative dispatches — drained together
-        self._pending: List[tuple] = []
-        # a share of the experts: each plain step's [entries on held
-        # experts, rows laid out], device values drained with the window
-        self._pending_moe_rows: List = []
-        self._steps_since_drain = 0
+        # the steps in flight, oldest first: dispatched, their results
+        # on the way to the host, not yet gathered (plain and speculative
+        # dispatches alike; at most MAX_STEPS_IN_FLIGHT after a dispatch)
+        self._pending: List[_InFlight] = []
         self._step_no = 0               # running number of step() calls
         # per-slot hard cap on VALID generated tokens, set when a sequence
-        # freezes early (KV pool ran dry mid-decode): the device keeps
-        # emitting frozen repeats until the next drain, which trims here
+        # freezes early (KV pool ran dry mid-decode): the steps in flight
+        # keep emitting frozen repeats, which the gather trims here
         self._gen_cap: List[Optional[int]] = [None] * B
         # ---- observability (ISSUE 5): per-request lifecycle telemetry —
         # TTFT/ITL/queue/occupancy histograms + pool gauges, all host-
-        # timestamped at dispatch and folded in at the existing drain (no
-        # added device syncs; warm steps tested compile/sync-free)
+        # timestamped at dispatch and folded in when a step is gathered
+        # (no added device syncs; warm steps tested compile/sync-free)
         if metrics is None:
             metrics = _obs.metrics_enabled()
         self._obs: Optional[_ServingMetrics] = \
@@ -1667,7 +1747,7 @@ class ContinuousBatchingEngine:
         # classified by program shape (prefill chunk / decode / spec
         # verify / fused-K / COW copy / drain) — stamp() on the hot path
         # is one list append; durations, histograms and EWMA baselines
-        # all fold at the existing drain
+        # all fold at the gather
         self.attribution: Optional[_obs.StepAttribution] = \
             _obs.StepAttribution() if metrics else None
         # ---- prefix cache (ISSUE 4): radix-shared KV pages ----
@@ -1689,7 +1769,7 @@ class ContinuousBatchingEngine:
                              "spec_accepted_tokens": 0,
                              "spec_rejected_tokens": 0}
         if self.spec is not None and self.spec.mode == "ngram":
-            # host-owned history table (rebuilt at admission/drain only)
+            # host-owned history table (rebuilt at admission/gather only)
             # + the device-resident recent-token ring the steps maintain
             self._hist = _sp.SpecHistory(B, self.g.max_seq_len)
             self._recent = jnp.full((B, self.spec.ngram_max),
@@ -1718,7 +1798,7 @@ class ContinuousBatchingEngine:
         self._families: dict = {}      # T -> {GEMM rows: compiled step}
         # the compiled step programs return carried state committed to
         # their device (mesh-replicated, out_specs P(), under tp).  Seed
-        # the carried arrays with the SAME sharding, or the first drain
+        # the carried arrays with the SAME sharding, or the first step
         # flips their layout and the second admission wave re-specializes
         # every eager op AND the step program (warm contract: 0 compiles)
         if self.g.tp > 1:
@@ -1778,8 +1858,8 @@ class ContinuousBatchingEngine:
                trace_id: Optional[str] = None) -> Request:
         """Enqueue a request and return its live ``Request`` object (the
         HTTP front door streams tokens by watching ``req.output`` grow at
-        drains).  ``trace_id`` threads the caller's trace context through
-        the request's lifecycle spans."""
+        every step's gather).  ``trace_id`` threads the caller's trace
+        context through the request's lifecycle spans."""
         rid = self._next_id
         self._next_id += 1
         req = Request(rid, prompt,
@@ -1870,13 +1950,13 @@ class ContinuousBatchingEngine:
         request completed so far (incl. during earlier manual step() calls)."""
         while self.has_work():
             self.step()
-        self._drain()
+        self._drain("idle")
         return dict(self.completed)
 
     # ---- engine step ----
     def step(self) -> List[Request]:
-        """Admit what fits, run ONE fused device step, drain every
-        ``sync_every`` steps.  Returns requests retired by this call."""
+        """Gather the steps that have landed, admit what fits, run ONE
+        fused device step.  Returns requests retired by this call."""
         self._step_no += 1
         with _obs.TRACER.span("engine.step", step=self._step_no,
                               slots=self.B) as span:
@@ -1887,18 +1967,20 @@ class ContinuousBatchingEngine:
         host's work runs under a span of its own (``catalog.SPANS``), so
         nothing between two device launches lies outside a named one."""
         tracer = _obs.TRACER
+        # first what has landed (and, at the bound, the oldest step): the
+        # slots it frees are admitted into below.  Requests retired here
+        # or by a mid-step emergency drain (pool pressure under
+        # speculative overestimate) ride this call's return — callers
+        # stream completions off it
+        early_done: List[Request] = self._gather()
         with tracer.span("engine.admit") as sp:
             admitted = self._admit()
             sp.set_metadata(admitted=admitted, waiting=len(self.waiting))
-        # requests retired by a mid-step emergency drain (pool pressure
-        # under speculative overestimate) must still ride this call's
-        # return — callers stream completions off it
-        early_done: List[Request] = []
         if all(r is None for r in self.slot_req):
             span.set_metadata(kind="idle", T=0, rows=0, q_tokens=0,
                               gemm_rows=0, kv_read_tokens=0, attn_rows=0,
                               page_copies=0, waiting=len(self.waiting))
-            return self._drain() if self._pending else []
+            return early_done + self._drain("idle")
         g = self.g
         B = self.B
         if self.prefix_cache is not None:
@@ -1918,8 +2000,9 @@ class ContinuousBatchingEngine:
             # the device may commit up to K tokens per row this dispatch:
             # bump the host-side length bound FIRST so the shared growth
             # loop below covers every position the step can write
-            # (safe-by-overestimate; the drain resyncs the bound to the
-            # device's true commit count and rolls surplus pages back)
+            # (safe-by-overestimate; the gather resyncs the bound to the
+            # device's true commit count, plus what the steps still in
+            # flight may commit, and rolls surplus pages back)
             for b in range(B):
                 req = self.slot_req[b]
                 if req is not None and self.prompt_pos[b] >= len(req.prompt):
@@ -1946,10 +2029,12 @@ class ContinuousBatchingEngine:
                     for b in range(B) if self.slot_req[b] is not None]))
             out_mat, ncommit, dlen = self._dispatch_spec()
             t_step = time.perf_counter()
-            self._pending.append(("spec", out_mat, ncommit, dlen, t_step))
+            self._in_flight(_InFlight("spec", out_mat, ncommit, dlen,
+                                      self.finished, None,
+                                      list(self.slot_req), t_step))
             if self.attribution is not None:
                 # committed-token counts are device-resident until the
-                # drain; credit_tokens() supplies them there
+                # gather; credit_tokens() supplies them there
                 self.attribution.stamp(
                     "spec_verify" if self.spec.mode == "ngram"
                     else "fused_k", k, t_step)
@@ -1959,9 +2044,6 @@ class ContinuousBatchingEngine:
                 o.occupancy.observe(rows / B)
                 o.queue_depth.observe(len(self.waiting))
                 o.queue_now.set(len(self.waiting))
-            self._steps_since_drain += 1
-            if self._steps_since_drain >= self.g.sync_every:
-                return early_done + self._drain()
             return early_done
 
         with tracer.span("engine.build"):
@@ -2037,15 +2119,14 @@ class ContinuousBatchingEngine:
              self.counts, cache, self.key) = out[:7]
             if track:
                 self._recent = out[7]
-            if g.counts_moe_rows:
-                # device values until the drain that exists reads them
-                self._pending_moe_rows.append(out[-1])
             g.cache.update(*cache)
-        # host dispatch timestamp rides the pending window: the drain
-        # stamps TTFT/ITL per committed token from it — dispatch-side
-        # wall clock, no device sync
+        # the step's own arrays start for the host now; the dispatch
+        # stamp rides with them (a request's first gap opens there)
         t_step = time.perf_counter()
-        self._pending.append(("step", self.tokens, commit, None, t_step))
+        self._in_flight(_InFlight(
+            "step", self.tokens, commit, None, self.finished,
+            out[-1] if g.counts_moe_rows else None, list(self.slot_req),
+            t_step))
         if self.attribution is not None:
             # a mixed step (prefill chunks in flight) is the prefill-
             # chunk program shape; T=1 is pure decode.  Tokens = query
@@ -2070,10 +2151,13 @@ class ContinuousBatchingEngine:
                 if req is not None and ql[b] > 0 and not decode[b]:
                     self.prefix_cache.note_progress(
                         req.req_id, int(self.prompt_pos[b]))
-        self._steps_since_drain += 1
-        if self._steps_since_drain >= self.g.sync_every:
-            return early_done + self._drain()
         return early_done
+
+    def _in_flight(self, entry: _InFlight) -> None:
+        """One more step dispatched: its results wait to be gathered."""
+        self._pending.append(entry)
+        if self._obs is not None:
+            self._obs.steps_in_flight.observe(len(self._pending))
 
     def _grow_pages(self, early_done: List[Request]) -> int:
         """Grow pages BEFORE the step: every position this step writes
@@ -2093,10 +2177,11 @@ class ContinuousBatchingEngine:
                 if alloc.available_pages == 0:
                     if self.spec is not None and self._pending:
                         # the speculative overestimate may be what holds
-                        # the pool: drain now — the drain resyncs host
-                        # lengths and rolls surplus tail pages back —
-                        # then retry this row's growth (at most once:
-                        # the pending window is empty afterwards)
+                        # the pool: settle now — with nothing in flight
+                        # the gather resyncs host lengths to the device's
+                        # own and rolls surplus tail pages back — then
+                        # retry this row's growth (at most once: nothing
+                        # is in flight afterwards)
                         early_done.extend(self._drain())
                         if self.slot_req[b] is None:
                             break
@@ -2107,13 +2192,15 @@ class ContinuousBatchingEngine:
                     # valid output at what was generated before this step
                     if self._gen_cap[b] is None:
                         n = len(req.output)
-                        for kind, _o, cm, _dl, _t in self._pending:
-                            if kind != "step":
+                        for e in self._pending:
+                            if e.reqs[b] is not req:
+                                continue     # a predecessor's repeats
+                            if e.kind != "step":
                                 # degraded path (pool exhausted): the
                                 # exact cap needs the in-flight spec
                                 # commit counts — one marked sync
                                 _obs.count_sync()
-                            n += int(cm[b])
+                            n += int(e.commit[b])
                         self._gen_cap[b] = n
                         self.finished = self.finished.at[b].set(True)
                     break
@@ -2131,9 +2218,16 @@ class ContinuousBatchingEngine:
         speculative lane's write caps stale; the arrays uploaded."""
         if not grown:
             return 0
-        self._bt_dev = jnp.asarray(self._bt)
-        self._caps_dirty = True
+        self._upload_bt()
         return 1
+
+    def _upload_bt(self) -> None:
+        """The block table to the device, as a COPY: steps in flight keep
+        the table they were handed, and ``jnp.asarray`` of a numpy array
+        may share its memory, so the host's own must stay free to change
+        (a slot handed on, a tail rolled back) under them."""
+        self._bt_dev = jnp.asarray(self._bt.copy())
+        self._caps_dirty = True
 
     # ---- prefix-cache gates: rows waiting on producer prefill ----
     def _open_gates(self):
@@ -2165,11 +2259,12 @@ class ContinuousBatchingEngine:
         """Dispatch ONE speculative step: the T=K ngram verify program or
         the fused K-step decode program.  Everything the step consumes
         beyond the chained engine state is either static (K, sampling
-        config) or drain-refreshed (the history table), so the warm spec
-        loop is dispatch-only — zero per-step host reads or uploads.
+        config) or refreshed by the gather (the history table, uploaded
+        again when a gathered step extended it), so the warm spec loop
+        reads nothing back from the device.
 
-        Returns the pending-window payload ``(out [B, K], n_commit [B],
-        drafted [B] | None)`` — device arrays, materialized at the drain.
+        Returns the in-flight entry's payload ``(out [B, K], n_commit [B],
+        drafted [B] | None)`` — device arrays, materialized at the gather.
         """
         g = self.g
         spec = self.spec
@@ -2217,7 +2312,7 @@ class ContinuousBatchingEngine:
 
     # ---- serving telemetry ----
     def stats(self) -> dict:
-        """Pool + prefix-cache telemetry (refreshed at every drain into
+        """Pool + prefix-cache telemetry (refreshed at every gather into
         ``last_stats``).  With the cache off, every prefix counter is 0."""
         s = self.g.cache.allocator.stats()
         s["kv_cache_dtype"] = ("int8" if self.g.cache.quantized
@@ -2334,224 +2429,258 @@ class ContinuousBatchingEngine:
         out.update(mode="full", hashes=cache.digest(max_entries))
         return out
 
-    # ---- drain: the ONLY host<->device sync of the steady state ----
-    def _drain(self) -> List[Request]:
-        if not self._pending:
-            self._steps_since_drain = 0
+    # ---- gather: the steps in flight, to the host as they land ----
+    def _drain(self, reason: str = "settle") -> List[Request]:
+        """The blocking form of ``_gather``: wait for every step in
+        flight, so that the host's books are the device's (``reason``:
+        "settle" for a caller that needs that, "idle" when there is
+        nothing to dispatch)."""
+        return self._gather(block=reason)
+
+    def _gather(self, block: Optional[str] = None) -> List[Request]:
+        """Deliver the steps in flight that have landed, oldest first, and
+        retire what they finished.  Waits for the device only as far as
+        it must: for the oldest step when ``MAX_STEPS_IN_FLIGHT`` are out
+        (the next dispatch needs room), for all of them when ``block``
+        names why (``GATHER_BLOCKS``).  Never for a newer step than it
+        delivers: an entry holds its own step's arrays."""
+        pending = self._pending
+        n = 0
+        while n < len(pending) and pending[n].landed():
+            n += 1
+        need = len(pending) if block is not None \
+            else len(pending) - MAX_STEPS_IN_FLIGHT + 1
+        blocked = ""
+        if n < need:
+            blocked, n = block or "bound", need
+        if not n:
             return []
-        with _obs.TRACER.span("engine.drain",
-                              steps=len(self._pending)) as span:
-            done, n_tokens, held_rows = self._drain_pending()
+        window = pending[:n]
+        del pending[:n]
+        with _obs.TRACER.span("engine.drain", steps=n,
+                              in_flight=len(pending),
+                              blocked=blocked) as span:
+            done, n_tokens, held_rows = self._deliver(window, blocked)
             span.set_metadata(tokens=n_tokens, held_rows=held_rows)
         return done
 
-    def _drain_pending(self) -> tuple:
-        """The pending window to the host and into its requests: (requests
+    def _deliver(self, window: List[_InFlight], blocked: str) -> tuple:
+        """Gathered steps to the host and into their requests: (requests
         retired, tokens delivered, entries that fell on held experts: 0
         where the step does not count them)."""
-        # per-array host transfers, NOT a device-side stack: the pending
-        # window length varies (partial windows at tail/run end) and a
-        # jnp.stack would compile one executable per distinct length —
-        # breaking the warm loop's zero-recompile contract
         obs = self._obs
         attr = self.attribution
-        t_drain0 = time.perf_counter() if attr is not None else None
-        if obs is not None:
-            obs.drains.inc()
-            _obs.count_sync()        # the window's host<->device transfer
-        with _obs.TRACER.span("engine.drain.wait"):
-            window = [(kind, np.asarray(out), np.asarray(cm),
-                       None if dl is None else np.asarray(dl), t)
-                      for kind, out, cm, dl, t in self._pending]
-            fin = np.asarray(self.finished)
-            moe_rows = [np.asarray(r) for r in self._pending_moe_rows]
-        self._pending_moe_rows.clear()
-        held_rows = 0
-        for held, laid_out in moe_rows:
-            held_rows += int(held)
+        t_gather0 = time.perf_counter() if attr is not None else None
+        if blocked:
+            _obs.count_sync()        # the host waits for the device here
             if obs is not None:
-                obs.moe_held_rows.observe(float(held))
-                obs.moe_rows_laid_out.observe(float(laid_out))
-        # the moment this window's tokens became visible to the host —
+                obs.gather_blocked[blocked].inc()
+        # per-array host transfers, started at dispatch; NOT a device-side
+        # stack: the window's length varies and a jnp.stack would compile
+        # one executable per distinct length — breaking the warm loop's
+        # zero-recompile contract
+        with _obs.TRACER.span("engine.drain.wait"):
+            for e in window:
+                e.to_host()
+        # the moment these steps' tokens became visible to the host —
         # the only progress of the device the host can observe
         t_ready = time.perf_counter()
-        self._pending.clear()
-        self._steps_since_drain = 0
+        held_rows = 0
+        for e in window:
+            if e.moe_rows is not None:
+                held, laid_out = e.moe_rows
+                held_rows += int(held)
+                if obs is not None:
+                    obs.moe_held_rows.observe(float(held))
+                    obs.moe_rows_laid_out.observe(float(laid_out))
+        if obs is not None:
+            obs.drains.inc()
         self._fold_spec_metrics(window)
         if attr is not None:
-            # fold the window's dispatch stamps (the final one closes
-            # against the drain's entry time) AFTER the spec token
-            # credits landed in _fold_spec_metrics
-            attr.fold(t_drain0)
+            # fold the dispatch stamps since the last gather (the final
+            # one closes against this gather's entry time) AFTER the spec
+            # token credits landed in _fold_spec_metrics
+            attr.fold(t_gather0)
         with _obs.TRACER.span("engine.drain.retire") as span:
-            done, n_tokens, bt_dirty = self._retire_rows(window, fin,
-                                                         t_ready)
+            done, n_tokens, bt_dirty = self._retire_rows(window, t_ready)
             span.set_metadata(retired=len(done))
         if bt_dirty:
-            self._bt_dev = jnp.asarray(self._bt)
-            self._caps_dirty = True
+            self._upload_bt()
         self.last_stats = self.stats()
         if obs is not None:
             obs.update_pool(self.last_stats)
         if attr is not None:
-            # the drain IS a phase: the steady state's one blocking
-            # host<->device transfer plus retire bookkeeping
-            attr.observe_host("drain", time.perf_counter() - t_drain0)
+            # the gather IS a phase: the host's wait for the device, if
+            # any, plus retire bookkeeping
+            attr.observe_host("drain", time.perf_counter() - t_gather0)
         return done, n_tokens, held_rows
 
-    def _retire_rows(self, window, fin, t_ready: float) -> tuple:
-        """Per-row bookkeeping of a drained window: tokens into their
-        requests, latency stamps, trims, retirement.  Returns (requests
-        retired, tokens delivered, whether a block-table row changed)."""
+    def _retire_rows(self, window: List[_InFlight],
+                     t_ready: float) -> tuple:
+        """Per-row bookkeeping of the gathered steps, one step after the
+        other: tokens into the request that made them, latency stamps,
+        trims, retirement.  Returns (requests retired, tokens delivered,
+        whether a block-table row changed)."""
         obs = self._obs
         done: List[Request] = []
         n_tokens = 0
-        alloc = self.g.cache.allocator
         eos = self.gen_cfg.eos_token_id
-        bt_dirty = False
-        for b in range(self.B):
-            req = self.slot_req[b]
-            if req is None:
-                continue
-            prev_len = len(req.output)
-            # committed tokens this window + their dispatch stamps: a
-            # plain step contributes its column-0 sample where the host
-            # marked the row committing; a spec step contributes its
-            # device-computed accepted prefix (frozen rows: 0 tokens)
-            new_tok: List[int] = []
-            tok_ts: List[float] = []
-            for kind, out, cm, _dl, t in window:
-                if kind == "step":
-                    if cm[b]:
-                        new_tok.append(int(out[b]))
-                        tok_ts.append(t)
+        resync = {}          # speculative rows still running: slot -> req
+        for e in window:
+            spec = e.kind == "spec"
+            fin = e.finished
+            for b, req in enumerate(e.reqs):
+                if req is None or req.done:
+                    # an empty slot, or a step dispatched past its
+                    # request's retirement: what the row holds is a
+                    # frozen repeat, and belongs to no request (least of
+                    # all to the one the slot was handed to since)
+                    continue
+                prev_len = len(req.output)
+                # committed tokens of this step: a plain step contributes
+                # its column-0 sample where the host marked the row
+                # committing; a spec step its device-computed accepted
+                # prefix (frozen rows: 0 tokens)
+                if spec:
+                    new_tok = [int(v) for v in e.out[b, :int(e.commit[b])]]
                 else:
-                    for v in out[b, :int(cm[b])]:
-                        new_tok.append(int(v))
-                        tok_ts.append(t)
-            req.output.extend(new_tok)
-            n_tokens += len(new_tok)
-            if obs is not None:
-                # TTFT/ITL are stamped HERE, when the tokens reach the
-                # host: the host runs sync_every dispatches ahead and
-                # then blocks, so a dispatch stamp is when a step was
-                # queued, not when its token existed.  A drain that
-                # hands a request n tokens observes n gaps of (span
-                # since its previous delivery) / n.  Commits the trims
-                # below drop — past the budget, past cache capacity, or
-                # frozen repeats after a device-side EOS — are not real
-                # tokens and must not be timed
-                room = max(0, req.max_new_tokens - prev_len)
-                cap_v = max(1, self.g.max_seq_len - len(req.prompt))
+                    new_tok = [int(e.out[b])] if e.commit[b] else []
+                req.output.extend(new_tok)
+                n_tokens += len(new_tok)
+                # cap = what physically fits in the cache (max_seq minus
+                # the prompt), further lowered if the KV pool ran dry
+                # mid-decode
+                cap = max(1, self.g.max_seq_len - len(req.prompt))
                 if self._gen_cap[b] is not None:
-                    cap_v = min(cap_v, max(1, self._gen_cap[b]))
-                room = min(room, max(0, cap_v - prev_len))
-                if eos is not None and eos in new_tok:
-                    room = min(room, new_tok.index(eos) + 1)
-                n_timed = len(tok_ts[:room])
-                if n_timed:
-                    # a request's first burst has no previous delivery:
-                    # its span opens where its first token's step was
-                    # dispatched
-                    since = req.t_last if req.t_last is not None \
-                        else tok_ts[0]
-                    gap_ms = (t_ready - since) / n_timed * 1e3
-                    if req.t_first is None:
-                        req.t_first = t_ready
-                        base = req.t_enqueue if req.t_enqueue is not None \
-                            else tok_ts[0]
-                        obs.ttft.observe((t_ready - base) * 1e3)
-                        n_timed -= 1
-                    for _ in range(n_timed):
-                        obs.itl.observe(gap_ms)
-                    req.t_last = t_ready
-            # device freeze repeats the last token once finished — trim to
-            # the true capacity/EOS/budget boundary host-side.  cap =
-            # what physically fits in the cache (max_seq minus the
-            # prompt), further lowered if the KV pool ran dry mid-decode
-            cap = max(1, self.g.max_seq_len - len(req.prompt))
-            if self._gen_cap[b] is not None:
-                cap = min(cap, max(1, self._gen_cap[b]))
-            if len(req.output) > cap:
-                req.output = req.output[:cap]
-            if eos is not None and eos in req.output:
-                req.output = req.output[:req.output.index(eos) + 1]
-            elif len(req.output) >= req.max_new_tokens:
-                req.output = req.output[:req.max_new_tokens]
-            elif len(req.output) < cap and not fin[b]:
-                if obs is not None and len(req.output) > req.n_emitted:
-                    obs.tokens.inc(len(req.output) - req.n_emitted)
-                    req.n_emitted = len(req.output)
-                if self.spec is not None and \
-                        self.prompt_pos[b] >= len(req.prompt):
-                    bt_dirty |= self._rollback_tail(b, req)
-                if self._hist is not None and new_tok:
-                    # the drafter's n-gram table grows ONLY here: retired
-                    # (drained) tokens, at the existing sync point —
-                    # never a per-step host read
-                    self._hist.extend_row(b, new_tok)
-                continue                     # still running
-            req.done = True
-            if obs is not None:
-                if len(req.output) > req.n_emitted:
-                    obs.tokens.inc(len(req.output) - req.n_emitted)
-                    req.n_emitted = len(req.output)
-                obs.completed.inc()
-                if _obs.TRACER.enabled and req.t_enqueue is not None:
-                    # retroactive lifecycle spans: queued -> prefill ->
-                    # decode.  With a trace context (HTTP front door) the
-                    # lane IS the request id — one correlated track from
-                    # accept to retire; otherwise the slot's lane.
-                    tr = _obs.TRACER
-                    t_adm = req.t_admit or req.t_enqueue
-                    t_f = req.t_first if req.t_first is not None else t_adm
-                    t_l = req.t_last if req.t_last is not None else t_f
-                    lane = req.trace_id or f"slot{b}"
-                    rid = req.req_id
-                    ctx = {"trace_id": req.trace_id, "slot": b} \
-                        if req.trace_id else {"slot": b}
-                    # component tag for the fleet collector (ISSUE 20):
-                    # the serving server stamps its identity on the
-                    # engine so multi-engine processes (the in-proc
-                    # disagg bench, tests) still assemble one track per
-                    # logical replica
-                    proc = getattr(self, "trace_proc", None)
-                    if proc:
-                        ctx["proc"] = proc
-                    tr.event(f"req{rid}.queued", req.t_enqueue,
-                             t_adm - req.t_enqueue, cat="serving",
-                             tid=lane, args=ctx)
-                    tr.event(f"req{rid}.prefill", t_adm, t_f - t_adm,
-                             cat="serving", tid=lane,
-                             args={**ctx, "prompt_tokens": len(req.prompt)})
-                    tr.event(f"req{rid}.decode", t_f, t_l - t_f,
-                             cat="serving", tid=lane,
-                             args={**ctx, "generated": len(req.output)})
-            if self.prefix_cache is not None:
-                # retiring drops the sequence's node refs: its cached
-                # prefix pages fall to the LRU free-pool (evicted only
-                # when admission actually needs the memory)
-                self.prefix_cache.release(req.req_id)
-            alloc.free(req.req_id)
-            self.slot_req[b] = None
-            self._gen_cap[b] = None
-            self.finished = self.finished.at[b].set(True)
-            self.completed[req.req_id] = req.output
-            done.append(req)
+                    cap = min(cap, max(1, self._gen_cap[b]))
+                if obs is not None and new_tok:
+                    # TTFT/ITL are stamped HERE, when the token reaches
+                    # the host: the only moment of the device's progress
+                    # the host can see.  A plain step brings one token, so
+                    # an observation is the true gap since the request's
+                    # previous token; a speculative step's n tokens share
+                    # the span.  Commits the trims below drop — past the
+                    # budget, past cache capacity, or frozen repeats after
+                    # a device-side EOS — are not real tokens and must
+                    # not be timed
+                    room = min(max(0, req.max_new_tokens - prev_len),
+                               max(0, cap - prev_len))
+                    if eos is not None and eos in new_tok:
+                        room = min(room, new_tok.index(eos) + 1)
+                    n_timed = min(len(new_tok), room)
+                    if n_timed:
+                        # a request's first token has no previous one: its
+                        # span opens where its step was dispatched
+                        since = req.t_last if req.t_last is not None \
+                            else e.t
+                        gap_ms = (t_ready - since) / n_timed * 1e3
+                        if req.t_first is None:
+                            req.t_first = t_ready
+                            base = req.t_enqueue \
+                                if req.t_enqueue is not None else e.t
+                            obs.ttft.observe((t_ready - base) * 1e3)
+                            n_timed -= 1
+                        for _ in range(n_timed):
+                            obs.itl.observe(gap_ms)
+                        req.t_last = t_ready
+                # device freeze repeats the last token once finished —
+                # trim to the true capacity/EOS/budget boundary host-side
+                if len(req.output) > cap:
+                    req.output = req.output[:cap]
+                if eos is not None and eos in req.output:
+                    req.output = req.output[:req.output.index(eos) + 1]
+                elif len(req.output) >= req.max_new_tokens:
+                    req.output = req.output[:req.max_new_tokens]
+                elif len(req.output) < cap and not fin[b]:
+                    if obs is not None and len(req.output) > req.n_emitted:
+                        obs.tokens.inc(len(req.output) - req.n_emitted)
+                        req.n_emitted = len(req.output)
+                    if self.spec is not None and \
+                            self.prompt_pos[b] >= len(req.prompt):
+                        resync[b] = req
+                    if self._hist is not None and new_tok:
+                        # the drafter's n-gram table grows ONLY here, from
+                        # tokens the host has gathered — never a read of
+                        # the device for its sake
+                        self._hist.extend_row(b, new_tok)
+                    continue                     # still running
+                self._retire(b, req, done)
+        # after the whole window: a row's bound must count every gathered
+        # commit before a page is given back
+        bt_dirty = False
+        for b, req in resync.items():
+            if not req.done:
+                bt_dirty |= self._rollback_tail(b, req)
         return done, n_tokens, bt_dirty
 
+    def _retire(self, b: int, req: Request, done: List[Request]) -> None:
+        """Slot ``b``'s request is finished: its books closed, its pages
+        back to the pool, the slot free for the next admission."""
+        obs = self._obs
+        req.done = True
+        if obs is not None:
+            if len(req.output) > req.n_emitted:
+                obs.tokens.inc(len(req.output) - req.n_emitted)
+                req.n_emitted = len(req.output)
+            obs.completed.inc()
+            if _obs.TRACER.enabled and req.t_enqueue is not None:
+                # retroactive lifecycle spans: queued -> prefill ->
+                # decode.  With a trace context (HTTP front door) the
+                # lane IS the request id — one correlated track from
+                # accept to retire; otherwise the slot's lane.
+                tr = _obs.TRACER
+                t_adm = req.t_admit or req.t_enqueue
+                t_f = req.t_first if req.t_first is not None else t_adm
+                t_l = req.t_last if req.t_last is not None else t_f
+                lane = req.trace_id or f"slot{b}"
+                rid = req.req_id
+                ctx = {"trace_id": req.trace_id, "slot": b} \
+                    if req.trace_id else {"slot": b}
+                # component tag for the fleet collector (ISSUE 20):
+                # the serving server stamps its identity on the
+                # engine so multi-engine processes (the in-proc
+                # disagg bench, tests) still assemble one track per
+                # logical replica
+                proc = getattr(self, "trace_proc", None)
+                if proc:
+                    ctx["proc"] = proc
+                tr.event(f"req{rid}.queued", req.t_enqueue,
+                         t_adm - req.t_enqueue, cat="serving",
+                         tid=lane, args=ctx)
+                tr.event(f"req{rid}.prefill", t_adm, t_f - t_adm,
+                         cat="serving", tid=lane,
+                         args={**ctx, "prompt_tokens": len(req.prompt)})
+                tr.event(f"req{rid}.decode", t_f, t_l - t_f,
+                         cat="serving", tid=lane,
+                         args={**ctx, "generated": len(req.output)})
+        if self.prefix_cache is not None:
+            # retiring drops the sequence's node refs: its cached
+            # prefix pages fall to the LRU free-pool (evicted only
+            # when admission actually needs the memory)
+            self.prefix_cache.release(req.req_id)
+        self.g.cache.allocator.free(req.req_id)
+        self.slot_req[b] = None
+        self._gen_cap[b] = None
+        # the device froze the row with the step that made its last
+        # token, so no step in flight writes its pages; said again for a
+        # slot whose step was the newest dispatched
+        self.finished = self.finished.at[b].set(True)
+        self.completed[req.req_id] = req.output
+        done.append(req)
+
     def _fold_spec_metrics(self, window) -> None:
-        """Fold the window's speculative telemetry into the engine books
-        and the registry (drafted/accepted/rejected token counters + the
-        accept_len histogram) — at the drain, never per step."""
+        """Fold the gathered steps' speculative telemetry into the engine
+        books and the registry (drafted/accepted/rejected token counters +
+        the accept_len histogram) — from host values, at the gather."""
         if self.spec is None:
             return
         obs = self._obs
         n_spec = c_tot = d_tot = a_tot = r_tot = 0
-        for kind, _out, cm, dl, _t in window:
-            if kind != "spec":
+        for e in window:
+            if e.kind != "spec":
                 continue
             n_spec += 1
+            cm, dl = e.commit, e.drafted
             for b in range(self.B):
                 n = int(cm[b])
                 d = int(dl[b]) if dl is not None else 0
@@ -2589,18 +2718,23 @@ class ContinuousBatchingEngine:
 
     def _rollback_tail(self, b: int, req: Request) -> bool:
         """Block-table tail rollback (ISSUE 9): resync the host length
-        bound to the device's true commit count and release surplus tail
-        pages the speculative overestimate grew for tokens that were then
-        rejected.  ``PageAllocator.truncate`` is refcount-aware, so only
-        THIS sequence's references drop — prefix-shared and COW pages can
-        never be yanked from a sibling.  K tokens of headroom stay
+        bound to the device's true commit count, plus the most the steps
+        still in flight may commit for this request (up to ``k`` a
+        speculative one), and release surplus tail pages the speculative
+        overestimate grew for tokens that were then rejected: no page a
+        step in flight may write is given back.  ``PageAllocator.truncate``
+        is refcount-aware, so only THIS sequence's references drop —
+        prefix-shared and COW pages can never be yanked from a sibling.  K tokens of headroom stay
         allocated so the steady state doesn't thrash truncate/extend.
         Returns True when the row's block table changed."""
         g = self.g
-        true_len = len(req.prompt) + len(req.output)
-        self.host_lens[b] = true_len
+        k = self.spec.k
+        bound = min(len(req.prompt) + len(req.output) + sum(
+            e.commits_ahead(b, k) for e in self._pending
+            if e.reqs[b] is req), g.max_seq_len)
+        self.host_lens[b] = bound
         alloc = g.cache.allocator
-        keep = min(true_len + self.spec.k, g.max_seq_len)
+        keep = min(bound + k, g.max_seq_len)
         if alloc.context_len(req.req_id) > keep + g.page_size:
             alloc.truncate(req.req_id, keep)
             self._bt[b] = alloc.block_table(
@@ -2608,7 +2742,7 @@ class ContinuousBatchingEngine:
             return True
         return False
 
-    # ---- admission (host-known free slots only; frees appear at drains) ----
+    # ---- admission (host-known free slots only; frees appear at gathers) ----
     def _admit(self) -> int:
         """Waiting requests into free slots; how many were admitted."""
         free = [b for b in range(self.B) if self.slot_req[b] is None]
@@ -2712,8 +2846,7 @@ class ContinuousBatchingEngine:
         self.counts = jnp.where(m, zero, self.counts)
         self.budgets = jnp.asarray(budgets.astype(np.int32))
         self.finished = jnp.where(m, jnp.zeros((), bool), self.finished)
-        self._bt_dev = jnp.asarray(self._bt)
-        self._caps_dirty = True
+        self._upload_bt()
         if self._hist is not None:
             # seed the drafter (ISSUE 9): the full prompt into the
             # history table, the prompt tail into the device recent ring

@@ -237,12 +237,13 @@ def recent_window(tokens: Sequence[int], nmax: int) -> np.ndarray:
 class SpecHistory:
     """The drafter's n-gram table: per-slot prompt+output token history.
 
-    Host-owned numpy mirror + lazily refreshed device copy.  The update
-    path is drain-aligned by construction: ``reset_row`` runs at
-    admission, ``extend_row`` runs at the engine drain with the tokens
-    that just retired from the pending window, and ``device_arrays``
-    re-uploads ONLY when a row changed (an async host->device transfer,
-    not a sync) — so warm spec steps between drains touch nothing here.
+    Host-owned numpy mirror + lazily refreshed device copy: ``reset_row``
+    runs at admission, ``extend_row`` when the engine gathers a step, with
+    the tokens that step committed, and ``device_arrays`` re-uploads ONLY
+    when a row changed (an async host->device transfer, not a sync).  The
+    upload is of a COPY: steps in flight still read the array they were
+    handed, and ``jnp.asarray`` of a numpy array may share its memory (on
+    the CPU it often does), so the mirror must not change under them.
     """
 
     def __init__(self, max_batch: int, max_seq_len: int):
@@ -262,7 +263,7 @@ class SpecHistory:
         self._dirty = True
 
     def extend_row(self, b: int, tokens: Sequence[int]) -> None:
-        """Append drained output tokens to slot ``b``'s history."""
+        """Append gathered output tokens to slot ``b``'s history."""
         if not len(tokens):
             return
         row = self._np[b]
@@ -279,6 +280,7 @@ class SpecHistory:
     def device_arrays(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """(hist [B, S], hist_len [B]) on device, refreshed iff dirty."""
         if self._dirty or self._dev is None:
-            self._dev = (jnp.asarray(self._np), jnp.asarray(self._len))
+            self._dev = (jnp.asarray(self._np.copy()),
+                         jnp.asarray(self._len.copy()))
             self._dirty = False
         return self._dev

@@ -3,7 +3,7 @@
 The serving engine's whole cost model is bucket-shaped — every dispatch is
 one of a handful of program shapes (a prefill chunk at T=prefill_bucket, a
 decode step at T=1, a speculative verify at T=K, a fused K-step decode, a
-COW page copy, the drain's host<->device transfer) — but until now the
+COW page copy, the gather's wait for the device) — but until now the
 telemetry only answered "how fast is the engine" in aggregate.  This module
 answers "which PHASE paid the latency": every dispatch is classified by its
 program shape and its host-stamped wall time and token count fold into
@@ -20,9 +20,10 @@ per-dispatch-shape cost table.
 
 Overhead contract (the PR 5 pattern, exactly): ``stamp()`` is one list
 append on the hot step path; ALL arithmetic — durations, histogram
-observes, EWMA folds — happens in ``fold()`` at the engine's EXISTING
-``sync_every`` drain.  Nothing here touches a device array, so warm steps
-with attribution enabled stay telemetry-asserted at 0 compiles / 0 syncs.
+observes, EWMA folds — happens in ``fold()`` at the engine's gather (every
+step: one stamp's worth).  Nothing here touches a device array, so warm
+steps with attribution enabled stay telemetry-asserted at 0 compiles / 0
+syncs.
 """
 
 from __future__ import annotations

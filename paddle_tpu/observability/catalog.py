@@ -38,17 +38,28 @@ CATALOG: Dict[str, tuple] = {
         "counter", "", "prompt tokens prefilled (post prefix-cache trim)"),
     "serving.steps": ("counter", "", "engine dispatches"),
     "serving.drains": (
-        "counter", "", "host<->device drains (the steady state's only "
-        "sync; one per sync_every steps)"),
+        "counter", "", "gathers that delivered at least one step's "
+        "results to the host (every step in steady state: there is no "
+        "drain cadence)"),
+    "serving.gather_blocked": (
+        "counter", "reason", "gathers that had to wait for the device: "
+        "bound (`MAX_STEPS_IN_FLIGHT` steps were out and the next "
+        "dispatch needs room), idle (nothing to dispatch), settle (a "
+        "caller needs the host's books to be the device's: session "
+        "export, the emergency drain under pool pressure, `_drain()`)"),
+    "serving.steps_in_flight": (
+        "histogram", "", "steps dispatched and not yet gathered, "
+        "observed after each dispatch (never over `MAX_STEPS_IN_FLIGHT`)"),
     "serving.queue_wait_ms": (
         "histogram", "", "enqueue -> admission wait per request"),
     "serving.ttft_ms": (
         "histogram", "", "enqueue -> first token on the host, per "
-        "request (stamped when the drain that carries it returns)"),
+        "request (stamped when the gather that carries it returns)"),
     "serving.itl_ms": (
         "histogram", "", "inter-token latency per generated token after "
-        "the first: the span between the drains that delivered a "
-        "request's tokens, shared evenly by the tokens of one drain"),
+        "the first, stamped when the token reaches the host: a plain "
+        "step brings a request one token, so an observation is a true "
+        "gap; the tokens of one speculative step share their span"),
     "serving.queue_depth": (
         "histogram", "", "waiting-queue depth observed at each step"),
     "serving.queue_depth_now": (
@@ -586,16 +597,21 @@ SPANS: Dict[str, tuple] = {
         "the call of a jitted program: `serve_step_T<bucket>`, "
         "`serve_spec_verify_K<k>`, `serve_fused_K<k>`, `pool_cow_copy`"),
     "engine.drain": (
-        "serving", "engine", "local", "steps, tokens, held_rows",
-        "`_drain`: `steps` dispatches closed, `tokens` delivered to "
-        "their requests, `held_rows` the (token, choice) entries of those "
+        "serving", "engine", "local",
+        "steps, in_flight, blocked, tokens, held_rows",
+        "`_gather`: `steps` dispatches gathered (those that had landed; "
+        "more only where it had to wait), `in_flight` left out, "
+        "`blocked` why it waited for the device (`bound`, `idle`, "
+        "`settle`; empty: it did not), `tokens` delivered to their "
+        "requests, `held_rows` the (token, choice) entries of those "
         "steps that fell on experts held here (0 where the step does not "
         "count them: a dense model, the dense mixture with every expert "
         "held, the tensor-parallel grouped arm)"),
     "engine.drain.wait": (
         "serving", "engine", "local", "",
-        "the `np.asarray` block of the drain: the only place the host "
-        "waits for the device"),
+        "the gathered steps' arrays to numpy: the only place the host "
+        "waits for the device, and only in a gather that is `blocked` "
+        "(the copies started at dispatch)"),
     "engine.drain.retire": (
         "serving", "engine", "local", "retired",
         "the per-row bookkeeping after the wait (`retired` requests "

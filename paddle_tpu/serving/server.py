@@ -9,10 +9,12 @@ Architecture — one engine thread, one event loop, a thread-safe seam:
   arrays chained between dispatches).  It pulls submissions from a
   thread-safe inbox, admits them through the engine's existing admission
   path, runs the fused engine step in a loop, and after each step diffs
-  every live request's ``output`` (which grows at the engine's existing
-  ``sync_every`` drains — streaming granularity IS the drain cadence, no
-  new host<->device syncs) and posts fresh tokens into the owning HTTP
-  connection's asyncio queue via ``loop.call_soon_threadsafe``.
+  every live request's ``output`` and posts fresh tokens into the owning
+  HTTP connection's asyncio queue via ``loop.call_soon_threadsafe``.
+  Every ``engine.step()`` gathers the steps that have landed, so a
+  stream gets a chunk a step, one step after the device sampled the
+  token: streaming granularity is the device step, and the engine has no
+  drain cadence (``generation.MAX_STEPS_IN_FLIGHT``).
 - The **event loop** parses HTTP, makes the SLO admission decision
   (``slo.SLOController`` — histogram burn, not queue length), enqueues,
   and streams Server-Sent Events as token batches arrive.
@@ -22,7 +24,7 @@ Endpoints:
 - ``POST /v1/completions`` — OpenAI-compatible completion over token ids
   (``prompt``: list of ints; no tokenizer in-tree, so ``text`` fields
   carry space-joined ids and ``token_ids`` the raw list).  ``stream``
-  true sends SSE chunks per drain; the response/chunk ``id`` is the
+  true sends an SSE chunk a step; the response/chunk ``id`` is the
   request's trace-context id, the SAME id on its engine lifecycle spans.
 - ``GET /metrics`` — live Prometheus exposition of the whole registry.
 - ``GET /healthz`` — liveness (engine thread up; the pre-ISSUE-7 shape).
@@ -433,8 +435,8 @@ class ServingServer:
                 else:
                     if flush:
                         # one idle step() after the last active one is the
-                        # public tail-drain flush: with no active slots it
-                        # drains any pending window and returns
+                        # public tail flush: with no active slots it waits
+                        # for whatever is still in flight and returns
                         eng.step()
                         self._publish()
                         flush = False
@@ -540,7 +542,7 @@ class ServingServer:
         req = eng.submit(prompt, max_new_tokens=2, trace_id="warmup")
         while not req.done and not self._stop.is_set():
             eng.step()
-        eng.step()                        # idle tail-flush drain
+        eng.step()                        # idle tail flush (blocking)
         if eng.prefix_cache is not None:
             # compile the session-migration upload program too (ISSUE
             # 14) so a live import/migration never compiles under
@@ -550,7 +552,8 @@ class ServingServer:
             _mig.warm(eng)
 
     def _publish(self) -> None:
-        """Diff every live request's drained output; push fresh tokens."""
+        """Diff every live request's gathered output; push fresh tokens
+        (after every step: a chunk a step for every stream that moved)."""
         eos = self.engine.gen_cfg.eos_token_id
         with _obs.TRACER.span("serve.publish") as span:
             tokens = streams = 0
@@ -1110,7 +1113,7 @@ class ServingServer:
                 # degree + host-global KV pool bytes, the inputs of the
                 # router's capacity-weighted heterogeneous placement
                 # (explicit here so the advertisement never depends on
-                # drain cadence refreshing last_stats)
+                # a gather having refreshed last_stats)
                 "tp": getattr(eng.g, "tp", 1),
                 "pool_bytes": getattr(eng.g, "pool_bytes", 0),
                 # the router's failover-resume eligibility check (ISSUE

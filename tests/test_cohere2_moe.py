@@ -79,7 +79,8 @@ def test_engine_serves_the_mixed_stack_as_the_whole_sequence_forward(
 
 def test_a_share_counts_its_rows_on_the_drain_that_exists():
     """Each plain step observes [entries on held experts, rows laid out],
-    the sums over the layers; nothing but the drain reads them."""
+    the sums over the layers; they ride to the host with the step's own
+    tokens and nothing but the gather reads them."""
     model = _model("grouped", 4, 0)
     eng = ContinuousBatchingEngine(model, **GEOMETRY)
     held_h = metrics.histogram("serving.moe_held_rows")
@@ -96,7 +97,7 @@ def test_a_share_counts_its_rows_on_the_drain_that_exists():
     # at most every choice falls here, and every held expert owns a tile
     assert 0 < held <= 73 * 4 * 4
     assert laid >= held and laid >= steps * 4 * 4 * 8
-    assert eng._pending_moe_rows == []
+    assert eng._pending == []
 
 
 @pytest.mark.parametrize("mode", ["ngram", "fused"])

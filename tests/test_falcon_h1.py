@@ -208,13 +208,19 @@ def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
         assert jnp.array_equal(a, b)
 
 
-def test_a_finished_slots_frozen_steps_leave_its_state_bit_equal(model):
-    """Between drains the host keeps dispatching a slot the device has
-    frozen (its budget is spent): ``ql`` is 0 there, the scan call names a
-    neighbour's block and the convolution keeps the carried rows, so the
-    slot's state does not move while the other slot's does."""
+def test_a_finished_slots_frozen_steps_leave_its_state_bit_equal(
+        model, monkeypatch):
+    """Until it has gathered a slot's last token the host keeps dispatching
+    a slot the device has frozen (its budget is spent); here no step looks
+    landed and the bound is far, so it does for nine steps: ``ql`` is 0
+    there, the scan call names a neighbour's block and the convolution
+    keeps the carried rows, so the slot's state does not move while the
+    other slot's does."""
+    from paddle_tpu.inference import generation
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
+    monkeypatch.setattr(generation._InFlight, "landed", lambda self: False)
     short, long_ = _prompts(model.config.vocab_size, (9, 12))
-    eng = ContinuousBatchingEngine(model, **dict(GEOMETRY, sync_every=64))
+    eng = ContinuousBatchingEngine(model, **GEOMETRY)
     eng.submit(short, max_new_tokens=2)
     eng.submit(long_, max_new_tokens=12)
     for _ in range(4):             # the prompts' chunk, then slot 0 is done
@@ -226,6 +232,7 @@ def test_a_finished_slots_frozen_steps_leave_its_state_bit_equal(model):
     for a, b in zip(before, after):
         assert np.array_equal(a[:, 0], b[:, 0])              # frozen
         assert not np.array_equal(a[:, 1], b[:, 1])          # still running
+    assert len(eng._pending) == 9 and eng.slot_req[0] is not None
     done = eng.run()
     assert [len(done[i]) for i in (0, 1)] == [2, 12]
 

@@ -307,14 +307,17 @@ def test_engine_int8_prefix_cache_cow_moves_scales():
     assert eng.stats()["prefix_hits"] >= 2
 
 
-def test_engine_int8_warm_steps_zero_compiles_zero_syncs():
+def test_engine_int8_warm_steps_zero_compiles_zero_syncs(monkeypatch):
     """Acceptance: the int8 arm's warm engine steps, attribution on,
-    compile nothing and sync nothing between drains."""
+    compile nothing and sync nothing while the steps in flight stay under
+    their bound (the steps that land are gathered on the way)."""
+    from paddle_tpu.inference import generation
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
     model = _tiny_model()
     gc = GenerationConfig(max_new_tokens=12, do_sample=False)
     eng = ContinuousBatchingEngine(
         model, max_batch=2, gen=gc, max_seq_len=64, page_size=8,
-        prefill_bucket=8, cache_dtype="int8", metrics=True, sync_every=64)
+        prefill_bucket=8, cache_dtype="int8", metrics=True)
     assert eng.attribution is not None
     for p in ([1, 2, 3], [4, 5]):
         eng.add_request(p)

@@ -396,10 +396,14 @@ def test_engine_request_lifecycle_histograms():
     assert obs.metrics.gauge("serving.peak_pages_in_use").value > 0
 
 
-def test_engine_eos_does_not_inflate_itl():
+def test_engine_eos_does_not_inflate_itl(monkeypatch):
     """Frozen-repeat commits after a device-side EOS are trimmed from the
     output — they must not be timed either: the per-token invariant
-    itl.count == tokens - requests holds on EOS-terminating traffic."""
+    itl.count == tokens - requests holds on EOS-terminating traffic, with
+    three steps in flight behind the one that sampled the EOS."""
+    from paddle_tpu.inference import generation
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 4)
+    monkeypatch.setattr(generation._InFlight, "landed", lambda self: False)
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny())
     prompt = [1, 2, 3, 4, 5]
@@ -414,9 +418,8 @@ def test_engine_eos_does_not_inflate_itl():
     eng = ContinuousBatchingEngine(
         model, max_batch=2,
         gen=GenerationConfig(max_new_tokens=8, eos_token_id=int(eos)),
-        max_seq_len=64, page_size=8, prefill_bucket=8, metrics=True,
-        sync_every=8)                        # EOS lands mid drain-window
-    rid = eng.add_request(prompt)
+        max_seq_len=64, page_size=8, prefill_bucket=8, metrics=True)
+    rid = eng.add_request(prompt)            # EOS lands mid flight
     out = eng.run()
     assert out[rid][-1] == eos and len(out[rid]) < 8   # terminated early
     assert obs.metrics.counter(
@@ -427,39 +430,43 @@ def test_engine_eos_does_not_inflate_itl():
 
 
 def test_engine_latency_is_stamped_when_tokens_reach_the_host(monkeypatch):
-    """The host dispatches sync_every steps ahead and then blocks in the
-    drain.  A token exists for the client when that drain returns: TTFT
-    includes the block, and the tokens a drain delivers share its span
-    evenly — no observation is the whole catch-up (which is what sheds a
-    healthy replica through the ITL SLO) and none is the ~0 between two
-    dispatches."""
+    """A token exists for the client when the gather that carries it
+    returns, one step after its own.  Here every step takes the device
+    ``block_s`` to hand over (the wait sits where the host's is: in the
+    gather's transfer): TTFT includes it, and every gap is a TRUE gap of
+    one step — none is a share of a burst, none the whole catch-up of
+    several steps (which is what sheds a healthy replica through the ITL
+    SLO) and none the ~0 between two dispatches."""
     import time
-    block_s = 0.3
-    count_sync = obs.count_sync
+    from paddle_tpu.inference import generation
+    block_s = 0.05
+    to_host = generation._InFlight.to_host
 
-    def blocking_sync(n=1):          # called once per drain, ahead of the
-        time.sleep(block_s)          # window's device->host transfer
-        count_sync(n)
+    def slow_to_host(self):
+        time.sleep(block_s)
+        to_host(self)
 
+    # one step a gather: the oldest, when the bound asks for it
+    monkeypatch.setattr(generation._InFlight, "landed", lambda self: False)
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny())
     eng = ContinuousBatchingEngine(
         model, max_batch=2, gen=GenerationConfig(max_new_tokens=20),
-        max_seq_len=64, page_size=8, prefill_bucket=8, metrics=True,
-        sync_every=8)
+        max_seq_len=64, page_size=8, prefill_bucket=8, metrics=True)
     eng.add_request([1, 2, 3])
     eng.run()                                # compiles: not timed below
     obs.reset("serving.")
-    monkeypatch.setattr(obs, "count_sync", blocking_sync)
+    monkeypatch.setattr(generation._InFlight, "to_host", slow_to_host)
     rid = eng.add_request([1, 2, 3])
     out = eng.run()
     ttft = obs.metrics.histogram("serving.ttft_ms")
     itl = obs.metrics.histogram("serving.itl_ms")
     assert ttft.count == 1 and itl.count == len(out[rid]) - 1 == 19
-    assert ttft.min >= block_s * 1e3         # visible only after the drain
-    # 8 tokens per drain share (block + 8 dispatches): ~40 ms each
-    assert itl.min >= block_s * 1e3 / 8 / 2
-    assert itl.max < block_s * 1e3
+    assert ttft.min >= block_s * 1e3         # visible only after its gather
+    # a token a gather: each gap is one hand-over and the host's work
+    # (a loaded machine may stretch one, never to the catch-up of many)
+    assert itl.min >= block_s * 1e3
+    assert itl.max < itl.sum / 4
 
 
 @pytest.mark.parametrize("engine_kw", [{}, {"prefix_cache": True}],
@@ -489,11 +496,15 @@ def test_engine_metrics_off_records_nothing():
     assert obs.metrics.counter("serving.requests_total").value == 0
 
 
-def test_engine_warm_steps_zero_compiles_zero_syncs():
+def test_engine_warm_steps_zero_compiles_zero_syncs(monkeypatch):
     """The ISSUE 5 overhead contract, telemetry-asserted: warm engine
     steps with metrics ON perform ZERO XLA compiles and ZERO marked
-    host<->device syncs between drains."""
-    eng = _tiny_engine(metrics=True, sync_every=64)
+    host<->device syncs while the steps in flight stay under their bound
+    (a marked sync is a gather that had to WAIT: for the bound, for want
+    of anything to dispatch, or for a caller who needs it settled)."""
+    from paddle_tpu.inference import generation
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
+    eng = _tiny_engine(metrics=True)
     for p in ([1, 2, 3], [4, 5]):
         eng.add_request(p)
     eng.run()                                 # warm the T-pair programs

@@ -25,14 +25,12 @@ from paddle_tpu.observability.collector import (_KEEP_MARKERS,
                                                 TraceCollector)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SYNC_EVERY = 4
 BUCKET = 8
 
 
 def _tiny_engine(**kw):
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny())
-    kw.setdefault("sync_every", SYNC_EVERY)
     return ContinuousBatchingEngine(
         model, max_batch=2, gen=GenerationConfig(max_new_tokens=6),
         max_seq_len=64, page_size=8, prefill_bucket=BUCKET, **kw)
@@ -76,13 +74,19 @@ def _inside(spans, outer):
 
 @pytest.fixture(scope="module")
 def engine_trace(tmp_path_factory):
-    """Two requests through a warm engine under the profiler.  By hand:
-    A's 19 prompt tokens go in chunks of 8, 8, 3 and B's 2 in one, so the
-    first three steps are mixed (T=8) with 8+2, 8+1 and 3+1 query tokens
-    (B decodes from step 2 on); the third commits A's first token, and
-    five decode steps (T=1, one token a row) bring both to their six.  B
-    has its six after step 6, but the host learns that at the drain that
-    closes step 8, so it rides steps 7 and 8 as a row too."""
+    """Two requests through a warm engine under the profiler, each step
+    gathered as late as the bound on steps in flight (2) allows, so that
+    the schedule is the same on every machine: step n's gather, at its
+    start, waits for step n - 2 and delivers it alone.  By hand: A's 19
+    prompt tokens go in chunks of 8, 8, 3 and B's 2 in one, so the first
+    three steps are mixed (T=8) with 8+2, 8+1 and 3+1 query tokens (B
+    decodes from step 2 on); the third commits A's first token, and decode
+    steps (T=1, one token a row) follow.  B has its six after step 6 and
+    the host learns that at the start of step 8, so B rides step 7 as a
+    row too and steps 8 and 9 hold A alone; A has its six after step 8,
+    which step 10 gathers: it has nothing to dispatch, waits for step 9
+    (a frozen repeat) and is idle."""
+    from paddle_tpu.inference import generation
     eng = _tiny_engine(metrics=True)
     eng.add_request([1, 2, 3])
     eng.run()                                   # both programs compiled
@@ -93,12 +97,16 @@ def engine_trace(tmp_path_factory):
         eng.add_request([4, 5])
         eng.run()
 
-    spans, names = _profiled(tmp_path_factory.mktemp("engine"), work)
+    with pytest.MonkeyPatch.context() as mp:
+        assert generation.MAX_STEPS_IN_FLIGHT == 2
+        mp.setattr(generation._InFlight, "landed", lambda self: False)
+        spans, names = _profiled(tmp_path_factory.mktemp("engine"), work)
     return spans, names, first
 
 
 WANT_STEPS = [("mixed", BUCKET, 2, 10), ("mixed", BUCKET, 2, 9),
-              ("mixed", BUCKET, 2, 4)] + [("decode", 1, 2, 2)] * 5
+              ("mixed", BUCKET, 2, 4)] + [("decode", 1, 2, 2)] * 4 \
+    + [("decode", 1, 1, 1)] * 2 + [("idle", 0, 0, 0)]
 
 
 def test_engine_steps_carry_the_counts_of_their_own_prompts(engine_trace):
@@ -119,30 +127,44 @@ def test_a_steps_span_carries_the_rows_of_its_gemms(engine_trace):
     steps = [s[3] for s in spans if s[0] == "engine.step"]
     for a in steps:
         assert a["q_tokens"] <= a["gemm_rows"] <= a["slots"] * a["T"]
-    assert [a["gemm_rows"] for a in steps] == [2 * BUCKET] * 3 + [2] * 5
+    assert [a["gemm_rows"] for a in steps] == [2 * BUCKET] * 3 + [2] * 6 + [0]
 
 
-def test_every_step_holds_its_phases_and_every_fourth_a_drain(engine_trace):
+def test_every_step_holds_its_phases_and_gathers_the_step_two_before(
+        engine_trace):
+    """No cadence: every step that has two steps before it gathers the
+    older of them at its start (here by waiting: ``blocked`` says why),
+    and leaves the newer in flight."""
     spans, _, _ = engine_trace
     steps = [s for s in spans if s[0] == "engine.step"]
+    assert len(steps) == 10
     for i, step in enumerate(steps, 1):
         inner = [s[0] for s in _inside(spans, step)]
-        for phase in ("engine.admit", "engine.grow", "engine.build",
-                      "engine.h2d", "engine.dispatch"):
-            assert inner.count(phase) == 1, (i, phase, inner)
-        drains = i % SYNC_EVERY == 0
+        assert inner.count("engine.admit") == 1
+        for phase in ("engine.grow", "engine.build", "engine.h2d",
+                      "engine.dispatch"):
+            assert inner.count(phase) == (i < 10), (i, phase, inner)
+        gathers = 1 if 3 <= i < 10 else 2 if i == 10 else 0
         for phase in ("engine.drain", "engine.drain.wait",
                       "engine.drain.retire"):
-            assert (phase in inner) == drains, (i, phase, inner)
+            assert inner.count(phase) == gathers, (i, phase, inner)
+        if gathers:                 # the gather comes first: it frees slots
+            assert inner[0] == "engine.drain"
     admits = [s for s in spans if s[0] == "engine.admit"]
     assert [a[3]["admitted"] for a in admits[:2]] == [2, 0]
-    drains = [s for s in spans if s[0] == "engine.drain"]
-    assert [d[3]["steps"] for d in drains] == [4, 4]
-    # both requests' six tokens each, and B's two frozen repeats
-    assert sum(d[3]["tokens"] for d in drains) == 6 + 6 + 2
+    drains = [s[3] for s in spans if s[0] == "engine.drain"]
+    # steps 3-10 each wait for the oldest step, and the tenth, with
+    # nothing to dispatch, then for the one still out
+    assert [d["steps"] for d in drains] == [1] * 9
+    assert [d["in_flight"] for d in drains] == [1] * 8 + [0]
+    assert [d["blocked"] for d in drains] == ["bound"] * 8 + ["idle"]
+    # both requests' six tokens each; B's frozen repeat of step 7 and A's
+    # of step 9 are gathered after their requests' retirement and dropped
+    assert [d["tokens"] for d in drains] == [1, 1, 2, 2, 2, 2, 1, 1, 0]
     retire = [s for s in spans if s[0] == "engine.drain.retire"]
-    assert [r[3]["retired"] for r in retire] == [0, 2]
-    for d in drains:                # wait and retire lie inside the drain
+    assert [r[3]["retired"] for r in retire] == [0] * 5 + [1, 0, 1, 0]
+    for d in (s for s in spans if s[0] == "engine.drain"):
+        # wait and retire lie inside the gather
         assert {"engine.drain.wait", "engine.drain.retire"} <= {
             s[0] for s in _inside(spans, d)}
 
@@ -150,7 +172,7 @@ def test_every_step_holds_its_phases_and_every_fourth_a_drain(engine_trace):
 def test_step_programs_have_stable_names(engine_trace):
     spans, names, _ = engine_trace
     programs = [s[3]["program"] for s in spans if s[0] == "engine.dispatch"]
-    assert programs == [f"serve_step_T{BUCKET}"] * 3 + ["serve_step_T1"] * 5
+    assert programs == [f"serve_step_T{BUCKET}"] * 3 + ["serve_step_T1"] * 6
     # the engine calls its step programs as compiled ahead of time
     assert f"PjitFunction(jit(serve_step_T{BUCKET}))" in names
     assert "PjitFunction(jit(serve_step_T1))" in names
@@ -407,7 +429,7 @@ def test_a_span_costs_under_two_microseconds_when_nobody_listens():
 # what is handed in before each step, by prompt length.  The second plan
 # (8 slots of 8 places, the floor of the row buckets lowered to 8: a packed
 # member of 16 rows and the dense 64) walks through both members of the T=8
-# family with no drain in between: 5 tokens -> 16 rows; 8 + 1 decoding ->
+# family: 5 tokens -> 16 rows; 8 + 1 decoding ->
 # 16; three first chunks + 2 -> 64; three new prompts + three second chunks
 # + 2 -> 64; then decode-only steps (the T=1 program, 8 rows).
 PLANS = {
@@ -423,6 +445,9 @@ PLANS = {
 def test_warm_steps_compile_nothing_and_sync_nothing(monkeypatch, listening,
                                                      plan):
     from paddle_tpu.inference import generation
+    # under the bound on steps in flight nothing waits for the device: a
+    # step that has landed is gathered on the way, unmarked
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
     max_batch, floor, arrivals, want_rows = PLANS[plan]
     if floor:
         monkeypatch.setattr(generation, "MIN_GEMM_ROWS", floor)
@@ -430,7 +455,7 @@ def test_warm_steps_compile_nothing_and_sync_nothing(monkeypatch, listening,
     eng = ContinuousBatchingEngine(
         LlamaForCausalLM(LlamaConfig.tiny()), max_batch=max_batch,
         gen=GenerationConfig(max_new_tokens=6), max_seq_len=64, page_size=8,
-        prefill_bucket=BUCKET, metrics=True, sync_every=64)
+        prefill_bucket=BUCKET, metrics=True)
     # the first dispatch of a T compiles its whole family
     for p in ([1, 2, 3], [4, 5]):
         eng.add_request(p)

@@ -312,10 +312,13 @@ def test_the_step_counts_rows_and_reads_for_a_latent_place(tmp_path):
         obs.tracer.stop()
     doc = json.load(open(obs.export_chrome_trace(str(tmp_path / "t.json"))))
     reads = [e["args"]["kv_read_tokens"] for e in doc["traceEvents"]
-             if e["name"] == "engine.step"]
-    # a prompt of 33 in one chunk, then a decode row a step up to the drain
-    # (the host counts what it dispatched: a row frozen on the device too)
-    assert reads and reads == [layers * (33 + i) for i in range(len(reads))]
+             if e["name"] == "engine.step" and e["args"]["T"]]
+    # a prompt of 33 in one chunk, then a decode row a step until the host
+    # has gathered the last token, a step or two behind the device (the
+    # host counts what it dispatched: a row frozen on the device too); the
+    # idle step that flushes what is still in flight reads nothing
+    assert len(reads) >= 3
+    assert reads == [layers * (33 + i) for i in range(len(reads))]
 
 
 @pytest.mark.parametrize("preset", ["sarvam_mla_tiny"])

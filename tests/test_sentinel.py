@@ -238,9 +238,10 @@ def model():
 
 def test_engine_attributes_prefill_and_decode_phases(model):
     obs.reset("serving.")
-    # sync_every=4: the run spans multiple drain windows, the last of
-    # which is decode-only (prefill finished in window 1)
-    eng = _tiny_engine(model, metrics=True, sync_every=4)
+    # every step's gather folds the stamps since the last one: the run
+    # spans a fold a step, the last of which are decode-only (the prompts
+    # are through after two steps)
+    eng = _tiny_engine(model, metrics=True)
     for p in ([1, 2, 3, 4, 5, 6, 7, 8, 9], [4, 5, 6]):
         eng.add_request(p)
     out = eng.run()
@@ -255,8 +256,8 @@ def test_engine_attributes_prefill_and_decode_phases(model):
     assert drn.count == obs.metrics.counter("serving.drains").value
     assert obs.metrics.gauge("serving.tokens_per_sec",
                              phase="decode").value > 0
-    # the gauge is per-WINDOW: prefill went idle before the final drain,
-    # so its rate reads 0 rather than the last active window's forever
+    # the gauge is of the LAST fold: prefill went idle before the final
+    # gather, so its rate reads 0 rather than its last active step's forever
     assert obs.metrics.gauge("serving.tokens_per_sec",
                              phase="prefill").value == 0.0
     # EWMA cost table keyed by (phase, bucket)
@@ -290,10 +291,14 @@ def test_spec_engine_attributes_fused_phase(model):
                              phase="fused_k").value > 0
 
 
-def test_warm_steps_with_attribution_zero_compiles_zero_syncs(model):
-    """The acceptance criterion: attribution enabled, warm engine steps
+def test_warm_steps_with_attribution_zero_compiles_zero_syncs(
+        model, monkeypatch):
+    """The acceptance criterion: attribution enabled (its stamps folded at
+    every gather), warm engine steps under the bound on steps in flight
     still perform ZERO XLA compiles and ZERO marked device syncs."""
-    eng = _tiny_engine(model, metrics=True, sync_every=64)
+    from paddle_tpu.inference import generation
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
+    eng = _tiny_engine(model, metrics=True)
     eng.add_request([1, 2, 3])
     eng.run()                                 # warm the T pair
     eng.add_request([7, 8, 9])

@@ -142,13 +142,13 @@ def _tiny_model(layers=2, maxpos=256):
 
 
 def _run(model, prompts, *, spec, k=4, max_new=16, eos=None, max_batch=3,
-         num_pages=None, sync_every=8, do_sample=False, seed=0,
+         num_pages=None, do_sample=False, seed=0,
          prefix_cache=False, staggered=0):
     gc = GenerationConfig(max_new_tokens=max_new, do_sample=do_sample,
                           eos_token_id=eos, seed=seed)
     eng = ContinuousBatchingEngine(
         model, max_batch=max_batch, gen=gc, max_seq_len=128, page_size=8,
-        prefill_bucket=8, sync_every=sync_every, num_pages=num_pages,
+        prefill_bucket=8, num_pages=num_pages,
         prefix_cache=prefix_cache, spec_decode=spec, spec_k=k)
     rids = [eng.add_request(p) for p in prompts[:len(prompts) - staggered]]
     if staggered:
@@ -259,49 +259,63 @@ def test_engine_spec_undersized_pool_never_crashes():
 
 
 def test_engine_spec_rollback_bounds_page_overshoot():
-    """The drain resyncs host lengths and truncates surplus tail pages:
-    a low-acceptance workload at K=8 must not let the host's
-    safe-by-overestimate growth run away past true_len + K + one page."""
+    """Every gather resyncs the host's length bound to the tokens it has
+    gathered plus the most the steps still in flight may commit (K each),
+    and truncates surplus tail pages: a low-acceptance workload at K=8
+    must not let the host's safe-by-overestimate growth run away past
+    that bound + one page, and no page a step in flight may write is
+    given back (the tokens are the spec-off engine's)."""
     model = _tiny_model()
     gc = GenerationConfig(max_new_tokens=48, do_sample=False)
     eng = ContinuousBatchingEngine(
         model, max_batch=1, gen=gc, max_seq_len=128, page_size=8,
-        prefill_bucket=8, sync_every=4, spec_decode="ngram", spec_k=8)
+        prefill_bucket=8, spec_decode="ngram", spec_k=8)
     rid = eng.add_request([3, 14, 15, 9, 2, 6])
     eng.step()                            # prefill
     alloc = eng.g.cache.allocator
     checked = 0
     while eng.has_work():
-        done = eng.step()
+        eng.step()
         req = eng.slot_req[0]
-        if req is not None and not eng._pending:   # just drained, live
+        if req is not None:
             ctx = alloc.context_len(req.req_id)
             true_len = len(req.prompt) + len(req.output)
-            assert ctx <= true_len + 8 + 8, \
+            assert int(eng.host_lens[0]) <= min(
+                true_len + 8 * len(eng._pending), 128)
+            assert ctx <= true_len + 8 * len(eng._pending) + 8, \
                 f"tail rollback failed: ctx {ctx} vs true {true_len}"
             checked += 1
     eng._drain()
     assert checked > 0
     assert len(eng.completed[rid]) == 48
     assert alloc.free_pages == alloc.num_pages
+    plain = ContinuousBatchingEngine(
+        model, max_batch=1, gen=gc, max_seq_len=128, page_size=8,
+        prefill_bucket=8)
+    want = plain.add_request([3, 14, 15, 9, 2, 6])
+    assert plain.run()[want] == eng.completed[rid]
 
 
 # ---------------------------------------------------------------------------
 # overhead contract: warm spec steps compile nothing, sync nothing
 # ---------------------------------------------------------------------------
 
-def test_warm_spec_steps_zero_compiles_zero_syncs():
+def test_warm_spec_steps_zero_compiles_zero_syncs(monkeypatch):
     """ISSUE 9 satellite: telemetry-asserted via assert_overhead — warm
     speculative steps (both modes) trigger ZERO XLA compiles and ZERO
-    marked host<->device syncs between drains."""
+    marked host<->device syncs while the steps in flight stay under their
+    bound (landed steps are gathered on the way, which waits for nothing:
+    the drafter's table grows and is uploaded again between them)."""
     from paddle_tpu import observability as obs
+    from paddle_tpu.inference import generation
 
+    monkeypatch.setattr(generation, "MAX_STEPS_IN_FLIGHT", 64)
     model = _tiny_model()
     for mode in ("ngram", "fused"):
         gc = GenerationConfig(max_new_tokens=32, do_sample=False)
         eng = ContinuousBatchingEngine(
             model, max_batch=2, gen=gc, max_seq_len=128, page_size=8,
-            prefill_bucket=8, sync_every=64, spec_decode=mode, spec_k=4)
+            prefill_bucket=8, spec_decode=mode, spec_k=4)
         # warmup: one full lifecycle compiles the bucket step + the spec
         # program (+ drafter upload paths)
         eng.add_request([1, 2, 3])
@@ -310,7 +324,11 @@ def test_warm_spec_steps_zero_compiles_zero_syncs():
         with obs.assert_overhead(max_compiles=0, max_syncs=0):
             eng.add_request([5, 6, 7])
             eng.add_request([1, 4, 1, 4, 1, 4, 1, 4, 1])
-            for _ in range(20):           # < sync_every: no drain inside
+            # under the bound nothing waits; eight steps, so that neither
+            # request can have its 32 tokens (two steps of prefill, then at
+            # most 4 a step) and no step finds nothing to dispatch, which
+            # is a reason to wait
+            for _ in range(8):
                 eng.step()
         out = eng.run()
         assert all(len(v) == 32 for v in out.values()), mode
