@@ -25,7 +25,8 @@ from chipbench.harness.checks import Checks
 READING_OF = {"served_logit_gap_max": "gap_max",
               "served_logit_gap_p99": "gap_p99",
               "served_logit_gap_mean": "gap_mean",
-              "served_disagree_share": "disagree_share"}
+              "served_disagree_share": "disagree_share",
+              "served_flip_share": "flip_share"}
 
 
 def lines_of(path: str) -> tuple:
@@ -47,7 +48,8 @@ def lines_of(path: str) -> tuple:
 
 
 def verdict(cell, readings: dict, tokens: int) -> dict:
-    """``readings`` (``gap_*``, ``greedy_agree_share``) against the cell's
+    """``readings`` (``gap_*``, ``greedy_agree_share``, ``flip_share`` where
+    the cell limits it) against the cell's
     limits, through ``Checks``: {"correct", "not_ok": [names]}."""
     got = dict(readings,
                disagree_share=1.0 - readings["greedy_agree_share"])

@@ -163,8 +163,9 @@ class Run:
         configuration cut to one chip's share (``spec.py``'s docstring) the
         counts held here lie over the published ones, which stand beside
         them under ``published`` (the router's width, the whole vocabulary)
-        with the place among the chips that share a layer under ``share``:
-        the program and the reference are handed the same share."""
+        with the place among the chips that share a layer under ``share``
+        (and, where the file divides a key over fewer parts than chips, its
+        ``over``): the program and the reference are handed the same share."""
         config = self.cell.config
         m = dict(config["model"])
         role = "train" if self.cell.kind == "train" else "serve"
@@ -172,9 +173,14 @@ class Run:
         share = config.get("share")
         if share is not None:
             m.update(share.get(role, {}))
+            pattern = config.get("layer_pattern", {})
+            if "leading_key" in pattern:        # leading dense layers, as run
+                m[pattern["leading_key"]] = int(pattern["leading_dense"])
             m["published"] = {k: config["model"][k]
                               for k in config["reduced"]}
             m["share"] = {"chips": share["chips"], "index": share["index"]}
+            if "over" in share:
+                m["share"]["over"] = dict(share["over"])
         if self.rehearse:
             m.update(config.get("rehearsal_model", {}))
         return m

@@ -1,9 +1,9 @@
 """Operations a token needs in training: 6 N + attention.
 
-Copied arithmetic (``bench.py``'s 6 * N per token, N the parameters a token
-touches; ``PretrainStep.flops_per_token``), with the attention term that
-``bench.py`` leaves out.  Recomputation (``remat``) is NOT counted: model
-FLOP/s utilization counts the operations forward and backward require."""
+Copied arithmetic (6 * N per token, N the parameters a token touches:
+``PretrainStep.flops_per_token``), with the attention term that count leaves
+out.  Recomputation (``remat``) is NOT counted: model FLOP/s utilization
+counts the operations forward and backward require."""
 
 from __future__ import annotations
 

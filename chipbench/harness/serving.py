@@ -66,12 +66,45 @@ def _gaps(logits: list, chosen: list) -> np.ndarray:
         for lg, c in zip(logits, chosen)])
 
 
+def _margins(lg: np.ndarray) -> np.ndarray:
+    """How far the reference's best logit lies above its second, a position:
+    a served token can differ from the best only where this is small."""
+    top = np.partition(lg, -2, axis=-1)[:, -2:]
+    return top[:, 1] - top[:, 0]
+
+
+def readings(gaps: np.ndarray, margins: np.ndarray, flip: dict | None) -> dict:
+    """What one set of chosen tokens reads against the reference: the gaps'
+    widest, mean and 99th percentile and the share that are the reference's
+    own first choice.  With ``flip`` (a cell whose file limits
+    ``served_flip_share`` states ``gap_over``, ``margin_under``, ``plus``
+    beside the limit) also ``flips``, the tokens that lie more than
+    ``gap_over`` below the reference's best, ``near``, the positions where
+    the reference's own best leads its second by under ``margin_under``,
+    and ``flip_share`` = flips / (near + plus): a token can only differ
+    where the reference's choice is close, and how many such positions a
+    run's text has swings from seed to seed (seeded weights often repeat one
+    token at wide margins), so the flips are held against them and not
+    against all tokens; ``plus`` keeps a text with few close calls from
+    reading a ratio of two small counts."""
+    out = {"gap_max": float(gaps.max()), "gap_mean": float(gaps.mean()),
+           "gap_p99": float(np.quantile(gaps, 0.99)),
+           "greedy_agree_share": float((gaps == 0).mean())}
+    if flip:
+        out["flips"] = int((gaps > flip["gap_over"]).sum())
+        out["near"] = int((margins < flip["margin_under"]).sum())
+        out["flip_share"] = out["flips"] / (out["near"] + flip["plus"])
+    return out
+
+
 def check_served(run, finished: list, vocab: int) -> None:
     """Once the window has closed and the engine is freed: a sample of the
     finished requests, drawn from ``--seed`` with the longest in it; the
     reference runs once over each prompt with its served tokens; the number
     compared is how far a served token's logit lies below the reference's
-    best, at every served position.  ``finished``: [(item, served ids)].
+    best, at every served position (``readings``; every position's numbers
+    go out on the ``reference_detail`` line, so that a refused seed can be
+    read again token by token).  ``finished``: [(item, served ids)].
     With ``--control 1`` the int8 reference's own first choices are read
     at the same positions (the comparison that must fail)."""
     cell, m = run.cell, run.model
@@ -100,21 +133,28 @@ def check_served(run, finished: list, vocab: int) -> None:
         seqs, positions)
     gaps = _gaps(logits, served)
     took = time.perf_counter() - t0
+    margins = np.concatenate([_margins(lg) for lg in logits])
+    flip = cell.extras["limits"].get("served_flip_share")
+    detail = [{"index": i, "prompt_len": by_index[i][0].prompt_len,
+               "served": [int(t) for t in c],
+               "best": [int(t) for t in lg.argmax(-1)],
+               "gaps": [float(g) for g in _gaps([lg], [c])],
+               "margins": [float(g) for g in _margins(lg)]}
+              for i, lg, c in zip(picked, logits, served)]
     out = {"reference_seconds": took, "requests_compared": len(picked),
            "sample": picked, "tokens": int(gaps.size),
-           "gap_max": float(gaps.max()), "gap_mean": float(gaps.mean()),
-           "gap_p99": float(np.quantile(gaps, 0.99)),
-           "greedy_agree_share": float((gaps == 0).mean())}
+           **readings(gaps, margins, flip)}
     if run.control:
         low = ref.sequence_logits(
             lambda l: weights.make_layer(run.seed, leaves, l, dt), flat, L,
             m, seqs, positions, precision="int8")
         cg = _gaps(logits, [lo.argmax(-1) for lo in low])
-        out["control_int8"] = {
-            "gap_max": float(cg.max()), "gap_mean": float(cg.mean()),
-            "gap_p99": float(np.quantile(cg, 0.99)),
-            "greedy_agree_share": float((cg == 0).mean())}
+        for d, lg, lo in zip(detail, logits, low):
+            d["control_gaps"] = [float(g)
+                                 for g in _gaps([lg], [lo.argmax(-1)])]
+        out["control_int8"] = readings(cg, margins, flip)
     emit(phase="reference", **out)
+    emit(phase="reference_detail", requests=detail)
     run.results["reference"] = out
     ck = run.checks
     ck.add("served_tokens_compared", gaps.size,
@@ -123,6 +163,7 @@ def check_served(run, finished: list, vocab: int) -> None:
     for name, key in (("served_logit_gap_max", "gap_max"),
                       ("served_logit_gap_p99", "gap_p99"),
                       ("served_logit_gap_mean", "gap_mean"),
-                      ("served_disagree_share", "disagree_share")):
+                      ("served_disagree_share", "disagree_share"),
+                      ("served_flip_share", "flip_share")):
         if name in cell.extras["limits"]:        # those the cell's file limits
             ck.add(name, out[key], cell.limit(name))

@@ -13,18 +13,53 @@ A configuration cut to ONE CHIP'S SHARE of a stated deployment adds
 
     "share": {"chips": <n that share each layer>, "index": <which, 0-based>,
               "how": "<expert parallel / vocabulary parallel ..., in words>",
+              "over": {<key>: <d>, ...},                (absent: every key
+                                                         over ``chips``)
               "serve": {<key of model>: <held here>, ...}, "train": {...}}
     "layer_pattern": {"period": <layers in one period>,
-                      "leading_dense": <count>}        (absent: 1 and 0)
+                      "leading_dense": <count as run>,  (absent: 1 and 0)
+                      "leading_key": <key of model>}    (absent: none)
 
-with a role only where ``depth`` has it.  ``reduced`` is then exactly
-``num_hidden_layers`` (where depth is cut) plus the keys of ``share[role]``.
-``chips`` is the number that share a layer, not the cell's ``chips``.  What
-is held is one of ``chips`` equal parts (held x chips = published), never a
-width, never fewer than 8 experts, never under an eighth (``chips`` <= 8);
-the depth keeps the leading dense layers and whole periods, at least four
-layers.  ``Run.model`` (``core.py``) lays ``share[role]`` over the published
-sizes and puts the source's values beside them under ``published``.
+with a role only where ``depth`` has it.  ``chips`` is the number that share
+a layer, not the cell's ``chips``: a whole number of at least 2, bounded by
+what is HELD and by nothing else (the ``model-configs`` guide's floors):
+
+1. A held key is one of ``d`` equal parts of the published count (held x d
+   = published), never a width.  ``d`` is ``chips``, or ``over[key]`` where
+   the file states it: a divisor of ``chips`` (1 <= d <= chips), so each
+   part is held by ``chips / d`` chips alike.  ``index`` is the place among
+   the ``chips``; of a key divided over ``d`` the part held is number
+   ``index % d``.  The harness computes no offset: the family's program and
+   reference do, from ``share`` as ``Run.model`` hands it to them.
+2. A key with ``expert`` in its name holds at least 8 (``EXPERTS_HELD_FLOOR``);
+   every other held key holds at least an eighth of the published count.
+3. ``leading_dense`` layers open the stack; the depth keeps them and whole
+   periods, at least four layers after them.  With ``leading_key`` the source
+   publishes their number under that key of ``model`` and ``leading_dense``
+   is the number AS RUN (at least 1, at most the published one: leading dense
+   layers count once); ``Run.model`` lays it over the key.
+4. ``reduced`` is then exactly ``num_hidden_layers`` (where depth is cut),
+   the keys of ``share[role]``, and ``leading_key`` where 3 cut it.
+
+``Run.model`` (``core.py``) lays ``share[role]`` over the published sizes
+and puts the source's values of every reduced key beside them under
+``published``, and ``chips``, ``index`` and (where stated) ``over`` under
+``share``.  The guide's example: 256 experts over 32 chips, 8 held (``chips``
+32, the vocabulary ``over`` 8).  A share of 16 that no chip fits as one of 8
+(256 experts of a width that puts a whole expert layer at 23 GB):
+
+    "model": {..., "n_routed_experts": 256, "vocab_size": 4096,
+              "first_k_dense_replace": 3, "num_hidden_layers": 61},
+    "depth": {"published": 61, "serve": 5},
+    "layer_pattern": {"period": 1, "leading_dense": 1,
+                      "leading_key": "first_k_dense_replace"},
+    "share": {"chips": 16, "index": 5, "how": "...", "over": {"vocab_size": 8},
+              "serve": {"n_routed_experts": 16, "vocab_size": 512}},
+    "reduced": {"num_hidden_layers": ..., "n_routed_experts": ...,
+                "vocab_size": ..., "first_k_dense_replace": ...}
+
+holds experts [16 x 5, 16 x 5 + 16) and part 5 % 8 of the vocabulary, and
+runs 1 + 4 layers (``tests/chipbench/test_chipbench_spec.py``'s third toy).
 """
 
 from __future__ import annotations
@@ -45,8 +80,9 @@ SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 WIDTH_RE = re.compile(r"(_dim|_rank|_size|_per_tok|_width)$|window|top_?k"
                       r"|expand")
 ROLES = ("serve", "train")
-SHARE_CHIPS = (2, 8)           # an eighth is the least a chip may hold
-EXPERTS_HELD_FLOOR = 8
+SHARE_CHIPS_LEAST = 2
+EXPERTS_HELD_FLOOR = 8         # the guide's floors are on what is HELD:
+HELD_PARTS_MOST = 8            # experts by count, any other key by its eighth
 DEPTH_FLOOR = 4                # layers after the leading dense ones
 
 
@@ -165,19 +201,27 @@ def validate_config(entry: dict, config: dict) -> list:
         bad.append(f"{who}: the file has no deployment text")
     share = config.get("share")
     if share is None:
+        if "leading_key" in config.get("layer_pattern", {}):
+            bad.append(f"{who}: layer_pattern.leading_key, but the file has "
+                       "no share to publish the source's count beside")
         return bad
     model, depth = config.get("model", {}), config.get("depth", {})
     chips = share.get("chips")
-    if not _whole(chips, 1) or not SHARE_CHIPS[0] <= chips <= SHARE_CHIPS[1]:
-        bad.append(f"{who}: share.chips {chips!r} is not a whole number "
-                   f"from {SHARE_CHIPS[0]} to {SHARE_CHIPS[1]}")
+    if not _whole(chips, SHARE_CHIPS_LEAST):
+        bad.append(f"{who}: share.chips {chips!r} is not a whole number of "
+                   f"at least {SHARE_CHIPS_LEAST}")
         return bad
     if not _whole(share.get("index"), 0) or share["index"] >= chips:
         bad.append(f"{who}: share.index {share.get('index')!r} is not one "
                    f"of the {chips} chips")
     if not str(share.get("how", "")).strip():
         bad.append(f"{who}: share.how does not say how a layer is divided")
-    roles = [k for k in share if k not in ("chips", "index", "how")]
+    over = share.get("over", {})
+    for key, d in over.items():
+        if not _whole(d, 1) or d > chips or chips % d:
+            bad.append(f"{who}: share.over.{key} {d!r} does not divide the "
+                       f"{chips} chips")
+    roles = [k for k in share if k not in ("chips", "index", "how", "over")]
     held_keys = set()
     for role in roles:
         if role not in ROLES or role not in depth:
@@ -185,26 +229,49 @@ def validate_config(entry: dict, config: dict) -> list:
             continue
         for key, held in share[role].items():
             held_keys.add(key)
+            parts = over.get(key, chips)
             if key not in model:
                 bad.append(f"{who}: share.{role}.{key} is no key of model")
-            elif not _whole(held, 1) or held * chips != model[key]:
-                bad.append(f"{who}: share.{role}.{key} {held!r} x {chips} "
-                           f"chips != the published {model[key]!r}")
+            elif not _whole(parts, 1):
+                continue                    # said above, of share.over
+            elif not _whole(held, 1) or held * parts != model[key]:
+                bad.append(f"{who}: share.{role}.{key} {held!r} x {parts} "
+                           f"{'parts' if key in over else 'chips'} != the "
+                           f"published {model[key]!r}")
             elif "expert" in key and held < EXPERTS_HELD_FLOOR:
                 bad.append(f"{who}: share.{role}.{key} holds {held} "
                            f"experts, under {EXPERTS_HELD_FLOOR}")
-    cut = any(depth.get(r) != depth.get("published")
-              for r in ROLES if r in depth)
-    want = held_keys | ({"num_hidden_layers"} if cut else set())
-    if reduced != want:
-        bad.append(f"{who}: reduced {sorted(reduced)} is not depth plus "
-                   f"the keys of share: {sorted(want)}")
+            elif "expert" not in key and held * HELD_PARTS_MOST < model[key]:
+                bad.append(f"{who}: share.{role}.{key} holds {held} of "
+                           f"{model[key]}, under an eighth "
+                           f"({model[key] / HELD_PARTS_MOST:g})")
+    bad += [f"{who}: share.over.{key}, but no role of share holds {key}"
+            for key in sorted(set(over) - held_keys)]
     pattern = config.get("layer_pattern", {})
     period = pattern.get("period", 1)
     dense = pattern.get("leading_dense", 0)
+    leading_key = pattern.get("leading_key")
     if not _whole(period, 1) or not _whole(dense, 0):
         bad.append(f"{who}: layer_pattern {pattern!r}")
         return bad
+    cut_keys = set()
+    if leading_key is not None:
+        if not _whole(model.get(leading_key), 1):
+            bad.append(f"{who}: layer_pattern.leading_key {leading_key!r} "
+                       "is no key of model that counts layers")
+        elif not 1 <= dense <= model[leading_key]:
+            bad.append(f"{who}: layer_pattern.leading_dense {dense} is not "
+                       f"from 1 to the published {leading_key} "
+                       f"{model[leading_key]}")
+        elif dense != model[leading_key]:
+            cut_keys.add(leading_key)
+    cut = any(depth.get(r) != depth.get("published")
+              for r in ROLES if r in depth)
+    want = held_keys | cut_keys | ({"num_hidden_layers"} if cut else set())
+    if reduced != want:
+        bad.append(f"{who}: reduced {sorted(reduced)} is not depth plus "
+                   f"the keys of share plus a leading_key that is cut: "
+                   f"{sorted(want)}")
     floor = dense + max(DEPTH_FLOOR, period)
     for role in (r for r in ROLES if r in depth):
         d = depth[role]

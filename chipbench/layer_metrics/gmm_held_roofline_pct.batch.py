@@ -9,10 +9,12 @@ SOURCE = "device_trace"
 
 def read(run):
     """One observation of the series is one step's entries summed over the
-    layers; a layer makes three calls, each over that layer's entries."""
+    layers that have experts (all but the configuration's leading dense
+    ones, as run); a layer makes three calls, each over its own entries."""
     d = run.results.get("registry", {}).get("serving.moe_held_rows")
     if not d or not d["count"]:
         return None
-    rows = d["sum"] / d["count"] / run.model["num_hidden_layers"]
+    dense = run.cell.config.get("layer_pattern", {}).get("leading_dense", 0)
+    rows = d["sum"] / d["count"] / (run.model["num_hidden_layers"] - dense)
     return readers.kernel_roofline_pct(
         run, "grouped_matmul_held", lambda mod, shapes: mod.cost(shapes, rows))

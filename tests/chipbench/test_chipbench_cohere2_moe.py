@@ -197,9 +197,9 @@ def _config():
         ROOT, "chipbench", "configs", "command-a-plus-05-2026-ep8.json"))
 
 
-def test_spec_validate_is_empty_with_the_new_files():
-    assert spec.validate(spec.benchmark(ROOT), ROOT) == []
-    cell = spec.load_cell("commandaplus-batch-longdocs", ROOT)
+def test_spec_validate_is_empty_with_the_new_files(root=ROOT):
+    assert spec.validate(spec.benchmark(root), root) == []
+    cell = spec.load_cell("commandaplus-batch-longdocs", root)
     assert cell.kind == "closed_loop_serve" and cell.chips == 1
     names = {m["name"] for m in cell.per_layer}
     assert {"gmm_held_roofline_pct.batch", "expert_rows_occupancy_pct.batch",
@@ -288,29 +288,33 @@ CELLS = [w["name"] for w in spec.benchmark(ROOT)["workloads"]]
 
 @pytest.mark.parametrize("rehearse", [0, 1])
 @pytest.mark.parametrize("name", CELLS)
-def test_run_model_with_and_without_a_share(name, rehearse):
-    """What ``test_chipbench_spec.py`` states of every cell, restated now
-    that one has a share (its own cases fail on that cell: PERF.md section
-    7).  Without a share: the published sizes, the role's depth, the
-    rehearsal's sizes on top, and nothing else.  With one: the held sizes
-    over them, and ``published`` / ``share`` beside them."""
-    c = spec.load_cell(name, ROOT)
+def test_run_model_with_and_without_a_share(name, rehearse, root=ROOT):
+    """What ``test_chipbench_spec.py`` states of every cell, from each
+    cell's own files.  Without a share: the published sizes, the role's
+    depth, the rehearsal's sizes on top, and nothing else.  With one: the
+    held sizes over them, and ``published`` / ``share`` beside them."""
+    c = spec.load_cell(name, root)
     role = "train" if c.kind == "train" else "serve"
     want = dict(c.config["model"])
     want["num_hidden_layers"] = int(c.config["depth"][role])
     cut = c.config.get("share")
     if cut:
         want.update(cut[role])
+        pattern = c.config.get("layer_pattern", {})
+        if "leading_key" in pattern:
+            want[pattern["leading_key"]] = pattern["leading_dense"]
     if rehearse:
         want.update(c.config.get("rehearsal_model", {}))
     got = _a_run(c, rehearse).model
     if cut and not rehearse:
         assert got.pop("published") == {
             k: c.config["model"][k] for k in c.config["reduced"]}
-        assert got.pop("share") == {"chips": cut["chips"],
-                                    "index": cut["index"]}
-        assert got["num_experts"] * cut["chips"] \
-            == c.config["model"]["num_experts"]
+        place = got.pop("share")
+        assert (place["chips"], place["index"]) == (cut["chips"],
+                                                    cut["index"])
+        for key, held in cut[role].items():
+            parts = place.get("over", {}).get(key, cut["chips"])
+            assert got[key] * parts == c.config["model"][key], key
     elif not cut:
         assert "published" not in got and "share" not in got
         assert json.dumps(got) == json.dumps(want)
@@ -318,17 +322,20 @@ def test_run_model_with_and_without_a_share(name, rehearse):
         assert got[key] == value, key
 
 
-def test_the_cells_own_registry_series_are_read_over_the_window():
+def test_the_cells_own_registry_series_are_read_over_the_window(root=ROOT):
     """``reports.registry_series`` of the cell's file reaches the driver's
     snapshot: the window's difference holds what was observed inside it
-    and nothing from before, and a cell that names none gets none more."""
+    and nothing from before; every cell gets what its OWN file names and
+    none more."""
     from chipbench.harness import registry
     always = ("serving.queue_wait_ms", "serving.batch_occupancy")
     mine = ("serving.moe_held_rows", "serving.moe_rows_laid_out")
-    for name in CELLS:
-        series = registry.series_of(spec.load_cell(name, ROOT), always)
-        assert series == always + (
-            mine if name == "commandaplus-batch-longdocs" else ())
+    for w in spec.benchmark(root)["workloads"]:
+        cell = spec.load_cell(w["name"], root)
+        own = tuple(cell.extras["reports"].get("registry_series", ()))
+        assert registry.series_of(cell, always) == always + own, w["name"]
+    assert registry.series_of(spec.load_cell(
+        "commandaplus-batch-longdocs", root), always) == always + mine
     series = always + mine
     held = registry.histogram("serving.moe_held_rows")
     laid = registry.histogram("serving.moe_rows_laid_out")
@@ -347,56 +354,130 @@ def test_the_cells_own_registry_series_are_read_over_the_window():
     assert window["serving.queue_wait_ms"]["count"] == 0
 
 
-def _recorded_readings():
-    path = os.path.join(ROOT, "tests", "chipbench", "data",
-                        "recorded_longdocs_readings.jsonl")
+def _recorded(name):
+    path = os.path.join(ROOT, "tests", "chipbench", "data", name)
     with open(path) as f:
         return [json.loads(line) for line in f]
 
 
+def _recorded_readings():
+    """PR 27's runs, 4 documents compared a run (the 99th percentile's)."""
+    return _recorded("recorded_longdocs_readings.jsonl")
+
+
+def _flip_readings():
+    """PR 38's runs, 8 documents compared a run, each with ``--control 1``."""
+    return _recorded("recorded_longdocs_flip_readings.jsonl")
+
+
 def test_the_limits_stand_between_the_recorded_readings():
-    """Every run of the cell on the committed step programs (chip calls 4,
-    7 and 8 of PR 27: 30 runs, 14 of them with ``--control 1``), through
+    """Every run of the cell that its limits were set from or held against
+    (PR 38, chip calls 7 and 8: the committed step programs, 8 documents a
+    run, 16 seeds, 12 of them with ``--control 1``), through
     the harness's ``Checks`` and the limits of the cell's file as it
-    stands: every program reading passes, and the int8 control is refused
-    in every run but the one whose text gives int8 nothing to disagree
-    with (PERF.md section 7).  A limit moved past a reading fails here."""
+    stands: every program reading passes, the int8 control is refused in
+    every run, by ``served_flip_share``.  A limit moved past a reading
+    fails here."""
     from chipbench import control_verdict
     cell = spec.load_cell("commandaplus-batch-longdocs", ROOT)
     assert set(cell.extras["limits"]) == {
-        "served_tokens_compared", "served_logit_gap_p99",
+        "served_tokens_compared", "served_flip_share",
         "served_disagree_share"}
-    runs = _recorded_readings()
-    assert len(runs) == 30 and len({r["seed"] for r in runs}) == 29
-    passed = []
+    assert cell.traffic["reference_sample"] == 8
+    runs = _flip_readings()
+    assert len(runs) >= 10 and len({r["seed"] for r in runs}) == len(runs)
+    assert 780148689 in {r["seed"] for r in runs}    # the driver's refused
     for r in runs:
+        assert r["requests_compared"] == 8
         assert control_verdict.verdict(cell, r, r["tokens"])["correct"], r
         if "control_int8" in r:
             v = control_verdict.verdict(cell, r["control_int8"], r["tokens"])
-            if v["correct"]:
-                passed.append(r["seed"])
-            else:
-                assert "served_logit_gap_p99" in v["not_ok"]
-    assert passed == [2600000099]
-    # each limit lies where its ``from`` says
-    sound_p99 = max(r["gap_p99"] for r in runs)
-    control_p99 = sorted(r["control_int8"]["gap_p99"] for r in runs
-                         if "control_int8" in r)
-    p99 = cell.limit("served_logit_gap_p99")
-    assert sound_p99 < 0.0441 and control_p99[1] > 0.0624
-    assert p99 - sound_p99 == pytest.approx(control_p99[1] - p99, abs=1e-3)
-    sound_dis = max(1 - r["greedy_agree_share"] for r in runs)
-    assert sound_dis < 0.0281 < cell.limit("served_disagree_share") / 1.7
+            assert not v["correct"]
+            assert "served_flip_share" in v["not_ok"]
+    # the limit lies where its ``from`` says: between the two readings, the
+    # upper three times the lower or more, the more room above the lower
+    sound = max(r["flip_share"] for r in runs)
+    control = min(r["control_int8"]["flip_share"] for r in runs
+                  if "control_int8" in r)
+    limit = cell.limit("served_flip_share")
+    assert sum("control_int8" in r for r in runs) >= 10
+    assert control >= 3 * sound
+    assert limit / sound > control / limit > 1.25
+    assert max(1 - r["greedy_agree_share"] for r in runs) \
+        < cell.limit("served_disagree_share") / 2
+
+
+def test_the_flip_share_is_what_the_cells_file_says():
+    """``readings``: flips over ``gap_over``, near positions under
+    ``margin_under``, ``plus`` in the denominator; a recorded line holds
+    the same three numbers; a cell that does not limit it reads none."""
+    from chipbench.harness.serving import readings
+    cell = spec.load_cell("commandaplus-batch-longdocs", ROOT)
+    flip = cell.extras["limits"]["served_flip_share"]
+    assert (flip["gap_over"], flip["margin_under"], flip["plus"]) \
+        == (0.02, 0.1, 40)
+    gaps = np.array([0.0, 0.0, 0.5, 0.02, 0.021, 0.0])
+    margins = np.array([0.3, 0.05, 0.5, 0.02, 0.0999, 0.1])
+    got = readings(gaps, margins, flip)
+    assert (got["flips"], got["near"]) == (2, 3)
+    assert got["flip_share"] == 2 / 43
+    assert got["gap_max"] == 0.5 and got["greedy_agree_share"] == 0.5
+    assert "flip_share" not in readings(gaps, margins, None)
+    for r in _flip_readings():
+        assert r["flip_share"] == r["flips"] / (r["near"] + flip["plus"])
+
+
+@pytest.mark.parametrize("documents, refused", [(4, 3), (8, 0)])
+def test_the_gaps_99th_percentile_was_no_number_with_two_readings(
+        documents, refused):
+    """Why ``served_logit_gap_p99`` went (PR 38): over 4 documents sound
+    seeds read over PR 27's limit 0.053 and over the control's smallest
+    (three known: PR 29's, PR 38's, the driver's), which no limit fits;
+    over 8 none of PR 38's ten does, and the cell's file still does not
+    compare it, since 6 of the same 8 documents read 0.103."""
+    known = {2900000007: 0.0733, 3800000802: 0.0842, 780148689: 0.0760}
+    control = sorted(r["control_int8"]["gap_p99"]
+                     for r in _recorded_readings() if "control_int8" in r)
+    if documents == 4:
+        over = [s for s, v in known.items() if v > 0.053 and v > control[1]]
+    else:
+        over = [r["seed"] for r in _flip_readings() if r["gap_p99"] > 0.053]
+    assert len(over) == refused
+    cell = spec.load_cell("commandaplus-batch-longdocs", ROOT)
+    assert "served_logit_gap_p99" not in cell.extras["limits"]
+    assert "0.0760" in cell.extras["not_compared"]
+
+
+def test_an_altered_token_is_refused_by_the_flip_share():
+    """A whole rehearsal run of the cell with every served token altered
+    where it is produced (``broken_run.py token``): ``correct`` is false,
+    and ``served_flip_share`` is among the checks that say so."""
+    import subprocess
+    here = os.path.dirname(os.path.abspath(__file__))
+    p = subprocess.run(
+        [sys.executable, os.path.join(here, "broken_run.py"), "token",
+         "--workload", "commandaplus-batch-longdocs", "--seed",
+         str(2**31 + 38), "--seconds", "4", "--trace", "0", "--rehearse",
+         "1"], cwd=ROOT, capture_output=True, text=True, timeout=600,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT))
+    assert p.returncode == 0, p.stderr[-2000:]
+    last = json.loads(p.stdout.splitlines()[-1])
+    assert last["correct"] is False
+    bad = [n for n, c in last["checks"].items() if not c["ok"]]
+    assert "served_flip_share" in bad
+    assert any(ln.startswith("check served_flip_share:")
+               and ln.endswith("NOT OK") for ln in p.stderr.splitlines())
 
 
 def test_control_verdict_reads_a_runs_output(tmp_path, capsys):
     """``python -m chipbench.control_verdict``: the lines of a run with
-    ``--control 1`` as the chip printed them (seed 2500000004, chip call
-    4), and a run without the control, which is skipped."""
+    ``--control 1`` as the chip printed them (seed 3800001002, PR 38's
+    chip call 7: the control's smallest reading), and a run without the
+    control, which is skipped."""
     from chipbench import control_verdict
-    r = next(x for x in _recorded_readings() if x["seed"] == 2500000004)
-    ref = {k: r[k] for k in ("tokens", "gap_max", "gap_p99", "gap_mean",
-                             "greedy_agree_share", "control_int8")}
+    r = next(x for x in _flip_readings() if x["seed"] == 3800001002)
+    ref = {k: v for k, v in r.items()
+           if k not in ("chip_call", "seed", "trace")}
     out = tmp_path / "run.out"
     out.write_text("\n".join([
         "a line that is no JSON",
@@ -412,10 +493,10 @@ def test_control_verdict_reads_a_runs_output(tmp_path, capsys):
     lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
     assert lines[0]["program"]["correct"] is True
     assert lines[0]["control_int8"] == {
-        "correct": False, "not_ok": ["served_logit_gap_p99"],
-        "checks": {"served_tokens_compared": 351.0,
-                   "served_logit_gap_p99": r["control_int8"]["gap_p99"],
-                   "served_disagree_share": pytest.approx(9 / 351)}}
+        "correct": False, "not_ok": ["served_flip_share"],
+        "checks": {"served_tokens_compared": 730.0,
+                   "served_disagree_share": pytest.approx(28 / 730),
+                   "served_flip_share": pytest.approx(20 / 154)}}
     assert "skipped" in lines[1]
     assert lines[2] == {"runs": 1, "program_correct_and_control_refused": 1}
     assert control_verdict.main([str(plain)]) == 1         # nothing judged

@@ -175,12 +175,12 @@ def _a_run(cell, rehearse=0):
         {"kind": "none"})
 
 
-def test_spec_validate_is_empty_with_the_new_files():
-    bench = spec.benchmark(ROOT)
-    assert spec.validate(bench, ROOT) == []
+def test_spec_validate_is_empty_with_the_new_files(root=ROOT):
+    bench = spec.benchmark(root)
+    assert spec.validate(bench, root) == []
     assert len(bench["configs"]) >= 6 and len(bench["workloads"]) >= 7
     assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
-    cell = spec.load_cell(CELL, ROOT)
+    cell = spec.load_cell(CELL, root)
     assert cell.kind == "closed_loop_serve" and cell.chips == 1
     names = {m["name"] for m in cell.per_layer}
     assert names == set(cell.extras["reports"]["per_layer"]) == {
@@ -192,15 +192,19 @@ def test_spec_validate_is_empty_with_the_new_files():
                                                     "setup_s"}
     new = {m["name"]: m for m in bench["per_layer"]
            if m["name"].startswith("ssd_")}
-    assert {(m["layer"], m["moves"], m["source"], m["unit"],
-             tuple(m["workloads"])) for m in new.values()} == {
-        ("kernels", "serve_total_tok_s", "device_trace", "%", (CELL,))}
+    assert {(m["layer"], m["moves"], m["source"], m["unit"])
+            for m in new.values()} == {
+        ("kernels", "serve_total_tok_s", "device_trace", "%")}
+    assert all(CELL in m["workloads"] for m in new.values())
     assert new["ssd_roofline_pct.batch"]["better"] == "higher"
     assert new["ssd_share_pct.batch"]["better"] == "lower"
-    # new entries stand at the end of their lists
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert bench["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in bench["per_layer"][-2:]] == sorted(new)
+    # its entries are there, wherever later ones stand, and its two
+    # metrics follow each other
+    assert CONFIG in [c["name"] for c in bench["configs"]]
+    assert CELL in [w["name"] for w in bench["workloads"]]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("ssd_roofline_pct.batch")
+    assert names[at:at + 2] == sorted(new)
 
 
 def test_the_new_entries_keep_the_forms_validate_does_not_hold():

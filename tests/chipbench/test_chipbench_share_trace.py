@@ -30,7 +30,14 @@ def _reader(name):
     return spec.load_module(ROOT, "layer_metrics", name)
 
 
-def _run(xplane, want, registry, model):
+def _config():
+    """The configuration's file of the cell the trace was recorded from:
+    a share without a leading dense layer."""
+    return spec.load_json(os.path.join(
+        ROOT, "chipbench", "configs", "command-a-plus-05-2026-ep8.json"))
+
+
+def _run(xplane, want, registry, model, config=None):
     run = SimpleNamespace()
     run.program_spans, run.launch_waits = ps.read_host(xplane)
     run.trace = tr.load(xplane)
@@ -40,7 +47,7 @@ def _run(xplane, want, registry, model):
                                  seconds=want["trace_seconds"])
     run.model = model
     run.traffic = {"engine": {"page_size": want["page_size"]}}
-    run.cell = SimpleNamespace(root=ROOT)
+    run.cell = SimpleNamespace(root=ROOT, config=config or {})
     run.peaks = lambda: PEAKS
     return run
 
@@ -53,7 +60,8 @@ def share(tmp_path_factory):
     with gzip.open(os.path.join(DATA, "recorded_share_trace.xplane.pb.gz"),
                    "rb") as src, open(xplane, "wb") as dst:
         dst.write(src.read())
-    return _run(xplane, want, want["registry"], want["model"]), want
+    return _run(xplane, want, want["registry"], want["model"],
+                _config()), want
 
 
 def test_the_window_and_the_calls_by_their_names(share):
@@ -93,6 +101,27 @@ def test_each_new_reader_gives_the_number_worked_out_apart(share, name, key):
     assert 0 < got < 105.0
     assert got == pytest.approx(want["readers_said_on_the_chip"][name],
                                 rel=1e-9)
+
+
+def test_the_held_gemms_rows_are_divided_over_the_layers_that_have_experts(
+        share):
+    """PR 38: the reader divides a step's held rows by the layers that HAVE
+    experts, ``num_hidden_layers`` less ``layer_pattern.leading_dense`` of
+    the configuration's file.  This cell has no leading layer (stated as 0,
+    or not stated: the same), so it reads what the chip said under PR 27's
+    reader to the last digit; were one of its four layers a leading dense
+    one, the same rows would be three layers' and the share higher."""
+    run, want = share
+    reader = _reader(NEW[1])
+    said = want["readers_said_on_the_chip"][NEW[1]]
+    assert run.cell.config["layer_pattern"]["leading_dense"] == 0
+    assert reader.read(run) == said
+    run.cell = SimpleNamespace(root=ROOT, config={})
+    assert reader.read(run) == said
+    run.cell = SimpleNamespace(root=ROOT, config={
+        "layer_pattern": {"period": 1, "leading_dense": 1}})
+    assert said < reader.read(run) < 1.02 * said
+    run.cell = SimpleNamespace(root=ROOT, config=_config())
 
 
 def test_held_rows_are_never_priced_from_the_operands_rows(share):
