@@ -79,10 +79,12 @@ from ..kernels.paged_attention import (attn_rows, kernel_geometry_error,
                                        page_copies, paged_attention,
                                        ragged_paged_attention,
                                        ragged_paged_attention_latent,
+                                       ragged_paged_attention_latent_sparse,
                                        write_kv_pages,
                                        write_kv_pages_all_layers,
                                        write_kv_pages_all_layers_quantized,
                                        write_latent_pages_all_layers)
+from ..kernels.latent_index import latent_index_scores, latent_index_select
 from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
 from ..kernels.ssd import ragged_ssd_update
 from ..models.decoder_spec import EXPERT_BANKS
@@ -195,6 +197,26 @@ def _rope_bt(x, cos, sin):
     return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _index_inputs(y, c_q, lp, ix, eps, cos, sin):
+    """What a learned index (``models.decoder_spec.LatentIndex``) scores
+    with, from a layer's normed input ``y`` and query latent ``c_q`` (both
+    ``[B, T, width]``): the index queries ``[B, T, heads, dim]``, the ONE
+    index key a token ``[B, T, dim]`` (LayerNorm with weight and bias), both
+    rotated on their first ``ix.rope`` numbers, and the heads' weights ``[B,
+    T, heads]``."""
+    def rotated(a):
+        return jnp.concatenate([_rope_bt(a[..., :ix.rope], cos, sin),
+                                a[..., ix.rope:]], axis=-1)
+
+    q_i = rotated((c_q @ lp["self_attn.indexer.wq_b.weight"]).reshape(
+        *c_q.shape[:-1], ix.heads, ix.dim))
+    k_i = rotated(layer_norm_fp32(
+        y @ lp["self_attn.indexer.wk.weight"],
+        lp["self_attn.indexer.k_norm.weight"], eps,
+        bias=lp["self_attn.indexer.k_norm.bias"])[..., None, :])[..., 0, :]
+    return q_i, k_i, y @ lp["self_attn.indexer.weights_proj.weight"]
+
+
 def _scaled(a, scale: float):
     """``a`` times a model's constant multiplier, the product taken in
     float32 and rounded once (1.0: ``a`` itself, nothing traced)."""
@@ -276,7 +298,8 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
         topv, topi, _, _ = _llama._route_topk(
             xf, gw, top_k, moe.score,
             bias=lp["mlp.gate.bias"] if moe.select_bias else None,
-            scale=moe.gate_scale)
+            scale=moe.gate_scale, groups=moe.groups,
+            groups_kept=moe.groups_kept)
     if moe.partial:
         local = topi - moe.offset
         own = jnp.logical_and(local >= 0, local < held)       # [N, k]
@@ -558,6 +581,8 @@ class LlamaGenerator:
         # (pages behind a sliding layer's window are held and never read);
         # a latent stack's holds one row a token a layer, no head axis
         latent = None if la is None else (la.rank, la.rope)
+        # a learned index keeps one key a token a layer beside that row
+        index = None if c.index is None else c.index.dim
         # what a slot holds besides pages (a state-space mixer's state and
         # convolution rows): fixed, by slot, riding with the pool
         with _startup.phase("startup.pool_alloc", pages=self.num_pages):
@@ -569,7 +594,7 @@ class LlamaGenerator:
                 page_size=page_size, num_kv_heads=c.num_kv_heads,
                 head_dim=c.head_dim, dtype=cache_dtype or dtype,
                 mesh=self.mesh, axis=MP_AXIS, latent=latent,
-                recurrent=recurrent)
+                recurrent=recurrent, index=index)
         self.state_bytes_per_slot = 0 if recurrent is None else \
             RecurrentState.bytes_per_slot(c.ssm, c.num_layers, dtype)
         # host-global pool bytes (all shards) — advertised via stats() /
@@ -577,7 +602,7 @@ class LlamaGenerator:
         # heterogeneous replicas
         self.pool_bytes = self.num_pages * PagedKVCache.bytes_per_page(
             c.num_layers, c.num_kv_heads, page_size,
-            c.head_dim, cache_dtype or dtype, latent=latent)
+            c.head_dim, cache_dtype or dtype, latent=latent, index=index)
         # what ONE descriptor of the paged call moves: a page's K and V of
         # every KV head this shard holds, one layer (a latent call copies a
         # page's compressed rows as two halves and its rotary keys apart:
@@ -597,6 +622,8 @@ class LlamaGenerator:
                 self.pool_bytes // (self.num_pages * page_size))
             _metrics.gauge("serving.state_bytes_per_slot").set(
                 self.state_bytes_per_slot)
+            _metrics.gauge("serving.index_bytes_per_token").set(
+                c.num_layers * (index or 0) * itemsize)
         # over the part of a head that rotates, with the yarn blend where
         # the spec states one (``DecoderSpec.rope_tables``)
         cos, sin = map(jnp.asarray, c.rope_tables(self.max_seq_len))
@@ -642,13 +669,32 @@ class LlamaGenerator:
                         None if None in windows else max(windows))
         return 3 * n if self.spec.latent is not None else n
 
+    def index_counts(self, rows) -> tuple:
+        """What a learned index does in ONE layer of a step over ``rows`` =
+        [(query tokens, context before them)]: the pairs ``(t, s)`` scored
+        (``q x ctx + q (q + 1) / 2`` a working slot) and the keys chosen
+        (``min(position + 1, top_k)`` a query token)."""
+        top_k = self.spec.index.top_k
+        pairs = chosen = 0
+        for q, ctx in rows:
+            pairs += q * ctx + q * (q + 1) // 2
+            # positions ctx .. ctx + q - 1: those under top_k choose all
+            low = min(max(top_k - ctx, 0), q)
+            chosen += low * ctx + low * (low + 1) // 2 + (q - low) * top_k
+        return pairs, chosen
+
     def attention_counts(self, t, rows) -> dict:
-        """The three above as ``engine.step``'s arguments.  Each is a loop
-        over the working slots: the step computes them only while somebody
-        listens (``Tracer.listening``)."""
-        return {"kv_read_tokens": self.kv_read_tokens(rows),
-                "attn_rows": self.attn_rows(t, rows),
-                "page_copies": self.page_copies(rows)}
+        """The three above as ``engine.step``'s arguments (and a learned
+        index's two).  Each is a loop over the working slots: the step
+        computes them only while somebody listens
+        (``Tracer.listening``)."""
+        out = {"kv_read_tokens": self.kv_read_tokens(rows),
+               "attn_rows": self.attn_rows(t, rows),
+               "page_copies": self.page_copies(rows)}
+        if self.spec.index is not None:
+            out["index_pairs"], out["selected_keys"] = \
+                self.index_counts(rows)
+        return out
 
     def _head_logits(self, params, h):
         """float32 logits of hidden states ``h [..., H]``: the head, or the
@@ -820,7 +866,8 @@ class LlamaGenerator:
         quant = len(cache) == 3
         ks = vs = None
         if c.latent is not None:
-            kc, vc = cache          # the compressed rows, the rotary keys
+            # the compressed rows, the rotary keys, (the index keys)
+            kc, vc, *ic = cache
         elif quant:
             kc, ks, vs = cache      # the one page-major pool, its scales
         else:
@@ -880,7 +927,7 @@ class LlamaGenerator:
         moe = c.moe
         norm_fn = rms_norm_fp32 if c.norm == "rms" else layer_norm_fp32
 
-        def latent_attention(x, lp, la, layer):
+        def latent_attention(x, lp, la, layer, ix=None):
             """A latent layer's attention in the absorbed form: ``W_uk``
             carried into the query, ``heads`` query heads over ONE row
             ``[c | k_r]`` of the pool, ``W_uv`` applied to the call's
@@ -888,11 +935,27 @@ class LlamaGenerator:
             Serves prefill chunks and decodes alike: expanding the context
             for a chunk costs ``2 L rank heads (nope + value)`` whatever
             the chunk's length, the absorbed scores ``2 T L heads (rank -
-            nope) x 2`` more than the expanded ones; at the published
-            sizes they cross at 171 tokens a slot, over the chunk."""
+            nope) x 2`` more than the expanded ones; they cross at ``T =
+            rank (nope + value) / (2 (rank - nope))`` tokens a slot,
+            whatever the number of heads: 171 at rank 512 and nope = value
+            = 128 (both latent configurations served), over the chunk.
+
+            ``la.q_rank``: the heads are made from the query latent ``c_q
+            = RMSNorm(W_dq y)``.  ``ix`` (a learned index): the step's
+            index keys ride with the rotary keys (``[k_r | k_i]`` comes
+            back as the second of the step's rows), every cached token is
+            scored for every query token, each query token's best
+            ``ix.top_k`` are chosen exactly and the call's softmax runs
+            over those alone."""
             y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
-            q = (y @ lp["self_attn.q_proj.weight"]).reshape(
-                R0, R1, c.num_heads, la.nope + la.rope)
+            if la.q_rank is None:
+                q = y @ lp["self_attn.q_proj.weight"]
+            else:
+                c_q = rms_norm_fp32(y @ lp["self_attn.q_a_proj.weight"],
+                                    lp["self_attn.q_a_layernorm.weight"],
+                                    c.norm_eps)
+                q = c_q @ lp["self_attn.q_b_proj.weight"]
+            q = q.reshape(R0, R1, c.num_heads, la.nope + la.rope)
             ckr = y @ lp["self_attn.kv_a_proj_with_mqa.weight"]
             c_new = rms_norm_fp32(ckr[..., :la.rank],
                                   lp["self_attn.kv_a_layernorm.weight"],
@@ -905,10 +968,27 @@ class LlamaGenerator:
             if packed:
                 q_c, q_r = unpack(q_c), unpack(q_r)
                 c_new, r_new = unpack(c_new), unpack(r_new)
-            u = ragged_paged_attention_latent(
-                q_c, q_r, kc, vc, block_tables, ctx_prev,
-                scale=c.softmax_scale, q_lens=ql, c_new=c_new, r_new=r_new,
-                layer=layer)
+            if ix is None:
+                u = ragged_paged_attention_latent(
+                    q_c, q_r, kc, vc, block_tables, ctx_prev,
+                    scale=c.softmax_scale, q_lens=ql, c_new=c_new,
+                    r_new=r_new, layer=layer)
+            else:
+                q_i, k_i, w_i = _index_inputs(y, c_q, lp, ix, c.norm_eps,
+                                              cos, sin)
+                if packed:
+                    q_i, k_i, w_i = unpack(q_i), unpack(k_i), unpack(w_i)
+                scores = latent_index_scores(
+                    q_i, w_i, ic[0], block_tables, ctx_prev, q_lens=ql,
+                    k_new=k_i, layer=layer)
+                chosen = latent_index_select(
+                    scores, jnp.minimum(pos + 1, ix.top_k), q_lens=ql,
+                    context_lens=ctx_prev, n_new=T)
+                u = ragged_paged_attention_latent_sparse(
+                    q_c, q_r, kc, vc, block_tables, ctx_prev, chosen,
+                    scale=c.softmax_scale, q_lens=ql, c_new=c_new,
+                    r_new=r_new, layer=layer)
+                r_new = jnp.concatenate([r_new, k_i], axis=-1)
             if packed:
                 u = pack(u)
             w_uv = lp["self_attn.v_up_proj.weight"].reshape(
@@ -1007,7 +1087,8 @@ class LlamaGenerator:
             unstacked layers and this layer is that one of them."""
             if kind.latent is not None:
                 with jax.named_scope("attention"):
-                    a, k, v = latent_attention(x, lp, kind.latent, layer)
+                    a, k, v = latent_attention(x, lp, kind.latent, layer,
+                                               kind.index)
                     x = x + a
                 with jax.named_scope("mlp" if kind.dense_ffn else "moe"):
                     y = norm_fn(x, lp["post_attention_layernorm.weight"],
@@ -1157,9 +1238,14 @@ class LlamaGenerator:
 
             k_all, v_all = layers_first(lead_k, k_all), \
                 layers_first(lead_v, v_all)
+            third = ()
+            if ic:      # the index keys rode with the rotary keys
+                rope = c.latent.rope
+                third = (ic[0], v_all[..., rope:])
+                v_all = v_all[..., :rope]
             with jax.named_scope("attention"), jax.named_scope("kv_write"):
                 out_cache = write_latent_pages_all_layers(
-                    kc, vc, k_all, v_all, slots)
+                    kc, vc, k_all, v_all, slots, *third)
             h = norm_fn(h, params["norm"], c.norm_eps)
             return (unpack(h) if packed else h), out_cache, moe_rows
         kvh, dh = c.num_kv_heads, c.head_dim
@@ -1594,7 +1680,8 @@ class _ServingMetrics:
                  "peak_pages", "active_seqs", "cached_pages",
                  "evictable_pages", "spec_drafted", "spec_accepted",
                  "spec_rejected", "accept_len", "digest_epoch",
-                 "moe_held_rows", "moe_rows_laid_out", "state_resets")
+                 "moe_held_rows", "moe_rows_laid_out", "state_resets",
+                 "index_pairs", "selected_keys")
 
     def __init__(self):
         m = _obs.metrics
@@ -1620,6 +1707,14 @@ class _ServingMetrics:
         # slots whose recurrent state the device zeroes at their first
         # chunk (a stack with a state-space mixer; else it stays 0)
         self.state_resets = m.counter("serving.state_resets")
+        # a learned index's work in one layer of a step (one observation a
+        # plain step of a stack that has one): the pairs scored, the keys
+        # chosen
+        pair_bounds = [float(4 ** i) for i in range(2, 14)]
+        self.index_pairs = m.histogram("serving.index_pairs",
+                                       bounds=pair_bounds)
+        self.selected_keys = m.histogram("serving.selected_keys",
+                                         bounds=pair_bounds)
         self.requests = m.counter("serving.requests_total")
         self.completed = m.counter("serving.requests_completed")
         self.tokens = m.counter("serving.tokens_generated")
@@ -2104,7 +2199,12 @@ class ContinuousBatchingEngine:
                           waiting=len(self.waiting))
         if tracer.listening():
             # three loops over the working slots, for a reader's sake alone
-            span.set_metadata(**g.attention_counts(T, attends))
+            counts = g.attention_counts(T, attends)
+            span.set_metadata(**counts)
+            if g.spec.index is not None and self._obs is not None:
+                self._obs.index_pairs.observe(float(counts["index_pairs"]))
+                self._obs.selected_keys.observe(
+                    float(counts["selected_keys"]))
             if g.spec.ssm is not None:
                 # the slots whose recurrent state the step's scan calls
                 # read and write (those with work), and the tokens they scan

@@ -382,7 +382,12 @@ class PagedKVCache:
     fill them, and a page stays one whole tile of each array.  Pages are on
     axis 1 (``page_axis``), as the per-head pool's are; ``.arrays`` is
     ``(k, v)``.  No int8 plane and no tensor-parallel layout: both are
-    refused here.
+    refused here.  ``index=dim`` (a stack with a learned index,
+    ``LatentIndex``): a third array ``index [layers, num_pages, page_size,
+    dim]``, one index key a token a layer, in the pool's dtype and with
+    pages on axis 1 like the other two; ``.arrays`` is ``(k, v, index)``,
+    so whoever moves a page (COW, the prefix cache, a speculative lane's
+    rollback) moves its index keys with it.
 
     ``recurrent`` (a ``RecurrentState``): the slots' fixed state rides
     with the pool as the last two of ``.arrays`` (``(kv, ssm, conv)``),
@@ -391,7 +396,8 @@ class PagedKVCache:
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
                  num_kv_heads: int, head_dim: int, dtype="bfloat16",
-                 mesh=None, axis: str = "mp", latent=None, recurrent=None):
+                 mesh=None, axis: str = "mp", latent=None, recurrent=None,
+                 index=None):
         self.num_layers = num_layers
         self.page_size = page_size
         self.num_kv_heads = num_kv_heads
@@ -401,6 +407,10 @@ class PagedKVCache:
         self.axis = axis
         self.latent = None if latent is None else tuple(latent)
         self.recurrent = recurrent
+        self.index = None
+        if index is not None and latent is None:
+            raise ValueError("inference/kv_cache.py: index keys are a plane "
+                             "of a latent pool")
         if recurrent is not None and (self.quantized or mesh is not None
                                       or latent is not None):
             raise ValueError(
@@ -430,6 +440,9 @@ class PagedKVCache:
             self.k = jnp.zeros((num_layers, num_pages, page_size, rank), dt)
             self.v = jnp.zeros((num_layers, num_pages, page_size // 2,
                                 2 * rope), dt)
+            if index is not None:
+                self.index = jnp.zeros((num_layers, num_pages, page_size,
+                                        int(index)), dt)
             self.k_scale = self.v_scale = None
             self.allocator = PageAllocator(num_pages, page_size)
             return
@@ -476,9 +489,11 @@ class PagedKVCache:
         """The donated device state of one engine step: ``(kv,)`` for a
         float per-head pool, ``(kv, k_scale, v_scale)`` when quantized,
         ``(kv, ssm, conv)`` with a recurrent state; ``(k, v)`` for a latent
-        pool (its compressed rows and rotary keys)."""
+        pool (its compressed rows and rotary keys), ``(k, v, index)`` where
+        it keeps index keys."""
         if self.latent is not None:
-            return self.k, self.v
+            return (self.k, self.v) if self.index is None \
+                else (self.k, self.v, self.index)
         if self.quantized:
             return self.kv, self.k_scale, self.v_scale
         if self.recurrent is not None:
@@ -494,10 +509,10 @@ class PagedKVCache:
     def page_axes(self):
         """The axis that counts pages, for each array of ``.arrays`` that
         holds pages (a recurrent state's two hold none and get none): 1 for
-        the pool (and a latent pool's two), 2 for an int8 pool's scale
-        planes."""
+        the pool (and a latent pool's two or three), 2 for an int8 pool's
+        scale planes."""
         if self.latent is not None:
-            return 1, 1
+            return (1,) * len(self.arrays)
         return (1, 2, 2) if self.quantized else (1,)
 
     @property
@@ -538,7 +553,9 @@ class PagedKVCache:
         """Store the cache arrays returned by a jitted (donating) step, in
         ``.arrays``' order."""
         if self.latent is not None:
-            self.k, self.v = arrays
+            self.k, self.v, *rest = arrays
+            if rest:
+                self.index, = rest
             return
         self.kv, *rest = arrays
         if self.quantized:
@@ -552,13 +569,15 @@ class PagedKVCache:
 
     @staticmethod
     def bytes_per_page(num_layers: int, num_kv_heads: int, page_size: int,
-                       head_dim: int, dtype="bfloat16", latent=None) -> int:
+                       head_dim: int, dtype="bfloat16", latent=None,
+                       index=None) -> int:
         """HBM bytes one pool page costs (K + V + scales, all layers) —
         the unit the kv_quant bench equalizes across dtype arms.  A latent
         pool (``latent=(rank, rope)``): ``rank + rope`` numbers a token a
-        layer, whatever the heads."""
+        layer, whatever the heads, and ``index`` more where it keeps index
+        keys."""
         if latent is not None:
-            return num_layers * page_size * sum(latent) \
+            return num_layers * page_size * (sum(latent) + (index or 0)) \
                 * jnp.dtype(dtype).itemsize
         per = num_layers * num_kv_heads
         if str(dtype) == "int8":

@@ -888,11 +888,14 @@ def unpack_rope_pages(r, rope):
 
 def _reference_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
                                              block_tables, context_lens,
-                                             q_lens, c_new, r_new, scale):
+                                             q_lens, c_new, r_new, scale,
+                                             selected=None):
     """XLA oracle of the latent call (one layer's pool).  q_c ``[B, T, H,
     rank]``, q_r ``[B, T, H, rope]``; c_cache ``[P, page, rank]``, r_cache
     ``[P, page / 2, 2 * rope]``; c_new ``[B, T, rank]``, r_new ``[B, T,
-    rope]``.  Returns ``[B, T, H, rank]``: ``sum_j a_j c_j``."""
+    rope]``.  Returns ``[B, T, H, rank]``: ``sum_j a_j c_j``.  ``selected``
+    (bool ``[B, T, S (+ T)]``, the sparse call's): a query token's softmax
+    runs over the columns it names alone."""
     b, t, _, rank = q_c.shape
     rope = q_r.shape[-1]
     n_pages, page_size, _ = c_cache.shape
@@ -920,13 +923,16 @@ def _reference_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
                                 jq[None, None, :] < ql[:, None, None])
         parts_s.append(jnp.where(valid[:, :, None, :], s2, NEG_INF))
         parts_v.append(cn)
-    p = jax.nn.softmax(jnp.concatenate(parts_s, axis=-1), axis=-1)
+    s = jnp.concatenate(parts_s, axis=-1)
+    if selected is not None:
+        s = jnp.where(selected[:, :, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bths,bsr->bthr", p, jnp.concatenate(parts_v, axis=1))
     return out.astype(q_c.dtype)
 
 
 def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
-                        has_new, layered):
+                        has_new, layered, sparse=False):
     """One slot's program of the latent call, on the schedule of
     ``_ragged_paged_attn_kernel``: the slot's live rows are the prefix
     ``[0, q_len * heads)`` (row ``r`` = token ``r // heads``) in row tiles
@@ -946,6 +952,13 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
     value.  The probabilities enter ``PV`` in the pool's dtype where that
     is bfloat16 (half of this call's operations are ``PV``; the float32 x
     bf16 product costs several passes), float32 otherwise.
+
+    ``sparse`` (``ragged_paged_attention_latent_sparse``): the walk is the
+    same and every score is MASKED to the query token's chosen set.  The
+    set comes as 0/1 rows a query token (``[tokens, keys]`` a block, in
+    the block's own key order, copied beside the block's pages; ``[tokens,
+    own rows]`` for the step's rows); a row tile's mask is the product of
+    its rows' one-hot token numbers with them (exact: one term a sum).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -956,9 +969,12 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
     qc_ref, qlo_ref, qhi_ref = next(it), next(it), next(it)
     cnew_ref = next(it) if has_new else None
     rnew_ref = next(it) if has_new else None
+    snew_ref = next(it) if sparse and has_new else None
     c_hbm, r_hbm = next(it), next(it)
+    sel_hbm = next(it) if sparse else None
     o_ref = next(it)
     cbuf, rbuf, sem = next(it), next(it), next(it)
+    sbuf = next(it) if sparse else None
     m_ref, l_ref, acc_ref = next(it), next(it), next(it)
 
     b = pl.program_id(0)
@@ -1017,16 +1033,34 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
             return i + one
 
         jax.lax.while_loop(lambda i: i < ppb_c, page, _I0)
+        if sparse:
+            pltpu.make_async_copy(sel_hbm.at[b, j], sbuf.at[slot],
+                                  sem.at[slot, i32(2)]).start()
 
     def wait(slot):
-        for buf, col in ((cbuf, _I0), (rbuf, one)):
+        bufs = ((cbuf, _I0), (rbuf, one)) + (
+            ((sbuf, i32(2)),) if sparse else ())
+        for buf, col in bufs:
             pltpu.make_async_copy(buf.at[slot], buf.at[slot],
                                   sem.at[slot, col]).wait()
+
+    def chosen(i, rows01):
+        """[tile, columns] bool: row tile ``i``'s rows against the 0/1
+        rows a query token (``rows01 [tokens, columns]``)."""
+        tok = jax.lax.broadcasted_iota(jnp.int32,
+                                       (tile, rows01.shape[0]), 1)
+        onehot = (tok == tokens_of(i)).astype(rows01.dtype)
+        return jax.lax.dot_general(
+            onehot, rows01, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) > jnp.float32(0.5)
 
     def accumulate(r, s, v):
         m_prev, l_prev = m_ref[r, :], l_ref[r, :]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
+        if sparse:
+            # a tile may find none of a row's chosen keys in a block
+            p = jnp.where(s > jnp.float32(NEG_INF / 2), p, jnp.float32(0.0))
         alpha = jnp.exp(m_prev - m_new)
         m_ref[r, :] = m_new
         l_ref[r, :] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
@@ -1081,7 +1115,9 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
             s = dot_t(qc_ref[r, :], c) + jnp.concatenate(
                 [dot_t(qlo_ref[r, :], kr), dot_t(qhi_ref[r, :], kr)], axis=1)
             s = s * jnp.float32(scale)
-            accumulate(r, jnp.where(seen, s, jnp.float32(NEG_INF)), c)
+            keep = seen if not sparse else jnp.logical_and(
+                seen, chosen(i, sbuf[slot]))
+            accumulate(r, jnp.where(keep, s, jnp.float32(NEG_INF)), c)
 
         for_live_tiles(row_tile_of_block)
         return carry
@@ -1096,6 +1132,8 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
             jq = tokens_of(i)
             jk = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             valid = jnp.logical_and(jk <= jq, jk < ql)
+            if sparse:
+                valid = jnp.logical_and(valid, chosen(i, snew_ref[...]))
             accumulate(r, jnp.where(valid, s, jnp.float32(NEG_INF)),
                        cnew_ref[...])
         l = jnp.maximum(l_ref[r, :], jnp.float32(1e-30))
@@ -1122,13 +1160,14 @@ def _latent_attn_kernel(*refs, page_size, half, ppb, tile, scale, heads,
 def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
                                           block_tables, context_lens, q_lens,
                                           c_new, r_new, interpret, scale,
-                                          layer=None):
+                                          layer=None, selected=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, t, heads, rank = q_c.shape
     rope = q_r.shape[-1]
     layered = layer is not None
+    sparse = selected is not None
     n_pages, page_size, _ = c_cache.shape[-3:]
     rows = t * heads
     R = _padded_rows(t, heads)
@@ -1167,14 +1206,35 @@ def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
     scalars = [bt, cl, ql]
     if layered:
         scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+    if sparse:
+        # the chosen set as 0/1 rows a query token (16 rows at least: a
+        # bfloat16 tile), a block's columns in the kernel's own key order
+        # (every page's lower half, then every page's upper half)
+        S = block_tables.shape[1] * page_size
+        n_blocks = -(-block_tables.shape[1] // ppb)
+        Tb = -(-t // 16) * 16
+        half = page_size // 2
+        sel = jnp.pad(selected[..., :S].astype(jnp.bfloat16),
+                      ((0, 0), (0, Tb - t), (0, n_blocks * keys - S)))
+        sel = sel.reshape(b, Tb, n_blocks, ppb, 2, half)
+        sel = sel.transpose(0, 2, 1, 4, 3, 5).reshape(b, n_blocks, Tb, keys)
+        if has_new:
+            operands.append(jnp.pad(
+                selected[..., S:].astype(jnp.bfloat16),
+                ((0, 0), (0, Tb - t), (0, Tp - t))))
+            in_specs.append(block_of(Tb, Tp))
     operands += [c_cache, r_cache]
     in_specs += [pl.BlockSpec(memory_space=pl.ANY),
                  pl.BlockSpec(memory_space=pl.ANY)]
+    if sparse:
+        operands.append(sel)
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
 
     tile = row_tile(t, heads)
     kernel = functools.partial(
         _latent_attn_kernel, page_size=page_size, half=page_size // 2, ppb=ppb,
-        tile=tile, scale=scale, heads=heads, has_new=has_new, layered=layered)
+        tile=tile, scale=scale, heads=heads, has_new=has_new, layered=layered,
+        sparse=sparse)
     scratch = [
         ((2, keys, rank), c_cache.dtype),
         ((2, keys // 2, 2 * rope), r_cache.dtype),
@@ -1190,6 +1250,14 @@ def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
                + 2 * _lane_padded_bytes((R, 2 * rope), q_c.dtype)) \
         + 4 * _lane_padded_bytes((Tp, rank), q_c.dtype) \
         + 3 * _lane_padded_bytes((tile, keys), jnp.float32)
+    sel_scratch = []
+    if sparse:
+        # the block's 0/1 rows (two buffers), the own rows' (the pipeline's
+        # two) and a tile's mask beside its scores
+        sel_scratch = [pltpu.VMEM((2, Tb, keys), jnp.bfloat16)]
+        need += 2 * _lane_padded_bytes((Tb, keys), jnp.bfloat16) \
+            + 2 * _lane_padded_bytes((Tb, Tp), jnp.bfloat16) \
+            + 2 * _lane_padded_bytes((tile, keys), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),
@@ -1197,14 +1265,16 @@ def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
         out_specs=block_of(R, rank),
         scratch_shapes=[
             pltpu.VMEM(*scratch[0]), pltpu.VMEM(*scratch[1]),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SemaphoreType.DMA((2, 3 if sparse else 2)),
+            *sel_scratch,
             pltpu.VMEM(*scratch[2]), pltpu.VMEM(*scratch[3]),
             pltpu.VMEM(*scratch[4]),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        name="ragged_paged_attention_latent",
+        name="ragged_paged_attention_latent_sparse" if sparse
+        else "ragged_paged_attention_latent",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, R, rank), q_c.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -1217,7 +1287,8 @@ def _pallas_ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache,
 
 def ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache, block_tables,
                                   context_lens, *, scale, q_lens=None,
-                                  c_new=None, r_new=None, layer=None):
+                                  c_new=None, r_new=None, layer=None,
+                                  selected=None):
     """Mixed-mode serving attention over a LATENT page pool (prefill chunks
     and decode tokens in one call), in the absorbed form.
 
@@ -1235,6 +1306,9 @@ def ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache, block_tables,
       c_new/r_new: [batch, T, rank] / [batch, T, rope]: the step's own
                rows, folded in causally; commit them after the step.
       layer:   int32 scalar (may be traced), with the whole pool.
+      selected: None, or bool [batch, T, S (+ T)]: the sparse call
+               (``ragged_paged_attention_latent_sparse``, which says what
+               it holds).
 
     Returns ``u`` [batch, T, heads, rank]: ``sum_j a_j c_j`` for each head,
     to which the caller applies ``W_uv``.  Rows past ``q_lens[b]`` are
@@ -1252,6 +1326,10 @@ def ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache, block_tables,
         raise ValueError(
             f"latent pool {c_cache.shape} / {r_cache.shape} does not hold "
             f"rows of {rank} + {rope} for pages of {page_size}")
+    n = block_tables.shape[1] * page_size + (0 if c_new is None else t)
+    if selected is not None and selected.shape != (b, t, n):
+        raise ValueError(f"selected {selected.shape} is not [batch, T, "
+                         f"positions (+ own rows)] = {(b, t, n)}")
     on_tpu = jax.default_backend() == "tpu"
     why = kernel_geometry_error(page_size, 0, latent=(rank, rope),
                                 interpret=not on_tpu)
@@ -1261,13 +1339,38 @@ def ragged_paged_attention_latent(q_c, q_r, c_cache, r_cache, block_tables,
         return _pallas_ragged_paged_attention_latent(
             q_c, q_r, c_cache, r_cache, block_tables, context_lens, q_lens,
             c_new, r_new, interpret=not on_tpu, scale=float(scale),
-            layer=layer)
+            layer=layer, selected=selected)
     if layer is not None:
         c_cache, r_cache = (jax.lax.dynamic_index_in_dim(
             a, layer, axis=0, keepdims=False) for a in (c_cache, r_cache))
     return _reference_ragged_paged_attention_latent(
         q_c, q_r, c_cache, r_cache, block_tables, context_lens, q_lens,
-        c_new, r_new, float(scale))
+        c_new, r_new, float(scale), selected=selected)
+
+
+def ragged_paged_attention_latent_sparse(q_c, q_r, c_cache, r_cache,
+                                         block_tables, context_lens,
+                                         selected, *, scale, q_lens=None,
+                                         c_new=None, r_new=None, layer=None):
+    """``ragged_paged_attention_latent`` in which query token ``t``'s softmax
+    runs over its CHOSEN positions alone (a learned index's set,
+    ``kernels/latent_index.py``).
+
+    ``selected``: bool ``[batch, T, S (+ T)]``, ``S = max_pages x
+    page_size``: the slot's positions in order, then (with ``c_new``) the
+    step's own rows; a position outside the causal set counts as not
+    chosen whatever it says.  Everything else as the dense call's.
+
+    The form built is the MASKED WALK: the slot's pages are walked as the
+    dense call walks them (whole pages, one copy each: a gathered row
+    would be a descriptor of 1 KB, and the walk is bound by issuing
+    copies, PERF.md section 6) and each score is masked to the set, so
+    the products are those of the dense call while the softmax and the
+    result are the sparse one's."""
+    return ragged_paged_attention_latent(
+        q_c, q_r, c_cache, r_cache, block_tables, context_lens, scale=scale,
+        q_lens=q_lens, c_new=c_new, r_new=r_new, layer=layer,
+        selected=selected)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
@@ -1370,7 +1473,7 @@ def write_kv_pages_all_layers(kv_cache, k_all, v_all, slot_mapping):
 
 
 def write_latent_pages_all_layers(c_cache, r_cache, c_all, r_all,
-                                  slot_mapping):
+                                  slot_mapping, i_cache=None, i_all=None):
     """Commit every layer's new latent rows, token by token, in place: the
     latent pool's ``write_kv_pages_all_layers`` (a loop of
     ``dynamic_update_slice`` over the step's valid tokens, for the reason
@@ -1381,7 +1484,11 @@ def write_latent_pages_all_layers(c_cache, r_cache, c_all, r_all,
     rank]``, r_all ``[layers, n_tokens, rope]``; slot_mapping ``[n_tokens]``
     (``page * page_size + offset``; -1 = drop).  A token's rotary key goes
     into its half of the row it shares (read, one half replaced, written
-    back whole: the update stays aligned to the lanes)."""
+    back whole: the update stays aligned to the lanes).
+
+    ``i_cache`` ``[layers, num_pages, page_size, dim]`` with ``i_all``
+    ``[layers, n_tokens, dim]``: the pool's third plane (a learned index's
+    keys), committed by the same loop; three arrays come back."""
     L, n_pages, page_size, rank = c_cache.shape
     half, rope = page_size // 2, r_all.shape[-1]
     flat_c = c_cache.reshape(L, n_pages * page_size, rank)
@@ -1393,23 +1500,32 @@ def write_latent_pages_all_layers(c_cache, r_cache, c_all, r_all,
     order = jnp.argsort(jnp.logical_not(valid), stable=True).astype(jnp.int32)
     lane_upper = jnp.arange(2 * rope) >= rope
 
+    planes = (flat_c, flat_r)
+    if i_cache is not None:
+        planes += (i_cache.reshape(L, n_pages * page_size, -1),)
+        i_new = i_all.astype(i_cache.dtype)
+
     def commit(i, cr):
-        fc, fr = cr
+        fc, fr, *fi = cr
         src = order[i]
         dst = slots[src]
         fc = jax.lax.dynamic_update_slice_in_dim(
             fc, jax.lax.dynamic_slice_in_dim(cn, src, 1, axis=1), dst, axis=1)
+        fi = [jax.lax.dynamic_update_slice_in_dim(
+            f, jax.lax.dynamic_slice_in_dim(i_new, src, 1, axis=1), dst,
+            axis=1) for f in fi]
         offset = dst % page_size
         row = (dst // page_size) * half + offset % half
         old = jax.lax.dynamic_slice_in_dim(fr, row, 1, axis=1)
         new = jnp.where(lane_upper == (offset >= half),
                         jax.lax.dynamic_slice_in_dim(rn, src, 1, axis=1), old)
         fr = jax.lax.dynamic_update_slice_in_dim(fr, new, row, axis=1)
-        return fc, fr
+        return (fc, fr, *fi)
 
-    flat_c, flat_r = jax.lax.fori_loop(
-        jnp.int32(0), valid.sum().astype(jnp.int32), commit, (flat_c, flat_r))
-    return flat_c.reshape(c_cache.shape), flat_r.reshape(r_cache.shape)
+    flat_c, flat_r, *flat_i = jax.lax.fori_loop(
+        jnp.int32(0), valid.sum().astype(jnp.int32), commit, planes)
+    return (flat_c.reshape(c_cache.shape), flat_r.reshape(r_cache.shape),
+            *(f.reshape(i_cache.shape) for f in flat_i))
 
 
 def _requantize_pages(flat, fresh, lslot, new_scale_shape):

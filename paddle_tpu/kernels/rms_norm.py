@@ -23,10 +23,14 @@ def rms_norm_fp32(x, weight, eps: float, bias=None, axes=(-1,)):
     return out.astype(x.dtype)
 
 
-def layer_norm_fp32(x, weight, eps: float, axes=(-1,)):
-    """LayerNorm without a bias (mean subtracted, variance normalised, a
-    weight) with fp32 accumulation over ``axes``, returning x.dtype."""
+def layer_norm_fp32(x, weight, eps: float, axes=(-1,), bias=None):
+    """LayerNorm (mean subtracted, variance normalised, a weight and, where
+    given, a bias) with fp32 accumulation over ``axes``, returning
+    x.dtype."""
     h = x.astype(jnp.float32)
     h = h - jnp.mean(h, axis=axes, keepdims=True)
     h = h * jax.lax.rsqrt(jnp.mean(h * h, axis=axes, keepdims=True) + eps)
-    return (h * weight.astype(jnp.float32)).astype(x.dtype)
+    out = h * weight.astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return out.astype(x.dtype)

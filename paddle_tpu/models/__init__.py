@@ -6,8 +6,10 @@ built TPU-first — bf16 compute, flash-attention Pallas kernel, GSPMD
 sharding plan over the hybrid mesh (dp/mp/pp/sep axes).
 """
 
-from . import (cohere2_moe, dit, falcon_h1, gpt, llama,  # noqa: F401
-               sarvam_mla)
+from . import (cohere2_moe, deepseek_v32, dit, falcon_h1, gpt,  # noqa: F401
+               llama, sarvam_mla)
+from .deepseek_v32 import (DeepseekV32Config,  # noqa: F401
+                           DeepseekV32ForCausalLM)
 from .cohere2_moe import Cohere2MoeConfig, CohereMoeForCausalLM  # noqa: F401
 from .falcon_h1 import FalconH1Config, FalconH1ForCausalLM  # noqa: F401
 from .dit import DiT, DiTConfig, DiTTrainStep, GaussianDiffusion  # noqa: F401

@@ -45,16 +45,39 @@ class LatentAttn:
     ``[W_uk c | k_r]``, its value ``W_uv c`` (``value`` wide).  The engine
     absorbs ``W_uk`` into the query and applies ``W_uv`` after the call, so
     the kernel sees ``num_heads`` query heads over one row of the pool, the
-    value being the first ``rank`` of the key."""
+    value being the first ``rank`` of the key.
+
+    ``q_rank``: the query goes through a latent of its own, ``c_q =
+    RMSNorm(W_dq y)`` (``q_rank`` wide), and the heads are made from it
+    (``W_uq c_q``); None: ``W_q y`` directly."""
     rank: int
     nope: int
     rope: int
     value: int
+    q_rank: Optional[int] = None
 
     @property
     def row(self) -> int:
         """Numbers a cached token holds in one layer."""
         return self.rank + self.rope
+
+
+@dataclass(frozen=True)
+class LatentIndex:
+    """A learned index over a latent pool (sparse attention): a place keeps
+    ONE index key a token a layer, ``k_i = LayerNorm(W_ik y)`` (``dim``
+    wide, weight and bias, rotary on its first ``rope`` numbers), cached
+    beside ``[c | k_r]``; a query token has ``heads`` index queries ``q_i,j
+    = W_iq,j c_q`` (from the query latent, rotary on the first ``rope``)
+    and ``heads`` weights ``w = W_iw y``.  ``I(t, s) = sum_j w_t,j
+    ReLU(q_i,t,j . k_i,s)`` in float32; the attention's softmax of query
+    token ``t`` runs over the ``min(t + 1, top_k)`` positions ``s <= t``
+    with the largest ``I(t, s)`` alone (a tie at the edge goes to the
+    lower position)."""
+    heads: int
+    dim: int
+    rope: int
+    top_k: int
 
 
 @dataclass(frozen=True)
@@ -123,6 +146,9 @@ class LayerKind:
     dense_ffn: bool = False
     # a state-space mixer beside the attention: ``x + attn(u) + ssm(u)``
     ssm: Optional[SsmMixer] = None
+    # a learned index over the latent pool: the attention reads the keys it
+    # chooses alone
+    index: Optional[LatentIndex] = None
 
 
 @dataclass(frozen=True)
@@ -193,10 +219,26 @@ class MoeSpec:
     # selects and is not in the gate
     select_bias: bool = False
     gate_scale: float = 1.0             # the normalised gates times this
+    # group-limited choice: the experts lie in ``groups`` equal groups, a
+    # group is ranked by the sum of its two largest ``score + bias``, and
+    # the ``top_k`` are chosen inside the best ``groups_kept`` groups
+    groups: Optional[int] = None
+    groups_kept: Optional[int] = None
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"router score {self.score!r}")
+        if (self.groups is None) != (self.groups_kept is None):
+            raise ValueError("groups and groups_kept are stated together")
+        if self.groups is not None and (
+                self.num_experts % self.groups
+                or not 0 < self.groups_kept <= self.groups
+                or self.groups_kept * (self.num_experts // self.groups)
+                < self.top_k):
+            raise ValueError(
+                f"{self.num_experts} experts in {self.groups} groups of "
+                f"which {self.groups_kept} are kept do not hold "
+                f"{self.top_k} choices a token")
         held = self.num_experts if self.held is None else self.held
         object.__setattr__(self, "held", held)
         if not 0 < held <= self.num_experts or self.offset < 0 \
@@ -250,6 +292,17 @@ class DecoderSpec:
         if self.latent is not None and self.parallel_block:
             raise ValueError("latent attention is served with sequential "
                              "residuals only")
+        # the index keys are a plane of the pool: one shape for the stack
+        if len({k.index for k in self.leading + self.pattern}) != 1:
+            raise ValueError("one pool serves every layer: every place of "
+                             "a stack has the same index or none")
+        if self.index is not None and (self.latent is None
+                                       or self.latent.q_rank is None):
+            raise ValueError("a learned index reads a latent pool and the "
+                             "query latent (LatentAttn.q_rank)")
+        if self.index is not None and self.index.rope != self.latent.rope:
+            raise ValueError("the index rotates as the attention's rotary "
+                             "part does: one table serves both")
         # what a slot holds besides pages is one shape for the whole stack
         if len({k.ssm for k in self.leading + self.pattern}) != 1:
             raise ValueError("one recurrent state serves every layer: "
@@ -269,6 +322,11 @@ class DecoderSpec:
     def latent(self) -> Optional[LatentAttn]:
         """The stack's latent attention (every layer's alike), or None."""
         return self.pattern[0].latent
+
+    @property
+    def index(self) -> Optional[LatentIndex]:
+        """The stack's learned index (every layer's alike), or None."""
+        return self.pattern[0].index
 
     @property
     def ssm(self) -> Optional[SsmMixer]:

@@ -420,20 +420,33 @@ def moe_mlp_forward_einsum(x, gate_w, w_gate, w_up, w_down, *, top_k,
     return y.reshape(B, S, H), aux, stats
 
 
-def _route_topk(xf, gate_w, k, score="softmax", bias=None, scale=1.0):
+def _route_topk(xf, gate_w, k, score="softmax", bias=None, scale=1.0,
+                groups=None, groups_kept=None):
     """Shared top-k router: returns (gate weights [N, k], expert ids
     [N, k], GShard aux loss, first-choice load ce [E]).  Scores are the
     ``score`` function ("softmax" or "sigmoid") of the float32 logits; the
     gates are the k largest, divided by their sum.  With ``bias`` (float32
     ``[E]``) the k chosen are those with the largest ``score + bias``: the
     bias selects and is not in the gate.  ``scale`` multiplies the
-    normalised gates."""
+    normalised gates.  ``groups`` (with ``groups_kept``): the choice is
+    group-limited: the experts lie in ``groups`` equal runs, a group is
+    ranked by the sum of its two largest ``score + bias``, and the k are
+    chosen inside the best ``groups_kept`` groups alone."""
     N = xf.shape[0]
     E = gate_w.shape[-1]
     logits = xf.astype(jnp.float32) @ gate_w.astype(jnp.float32)  # [N, E]
     probs = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
         else jax.nn.sigmoid(logits)
-    if bias is None:
+    if groups is not None:
+        by = probs if bias is None else probs + bias.astype(jnp.float32)
+        rank = jax.lax.top_k(by.reshape(N, groups, E // groups),
+                             min(2, E // groups))[0].sum(-1)   # [N, groups]
+        _, kept = jax.lax.top_k(rank, groups_kept)
+        keep = (kept[:, :, None] == jnp.arange(groups)).any(axis=1)
+        by = jnp.where(jnp.repeat(keep, E // groups, axis=1), by, -jnp.inf)
+        _, topi = jax.lax.top_k(by, k)
+        topv = jnp.take_along_axis(probs, topi, axis=-1)
+    elif bias is None:
         topv, topi = jax.lax.top_k(probs, k)
     else:
         _, topi = jax.lax.top_k(probs + bias.astype(jnp.float32), k)

@@ -227,12 +227,13 @@ class _Layers(Layer):
     arrays a layer, no layer axis) or of all the expert layers (one
     ``[layers, ...]`` stack a leaf, the expert banks one array a layer)."""
 
-    def __init__(self, c: SarvamMlaConfig, dense: bool, n: int,
-                 given: Optional[dict]):
+    def __init__(self, c, dense: bool, n: int, given: Optional[dict],
+                 leaves=None):
         super().__init__(dtype=c.dtype)
         self.banks = () if dense else EXPERT_BANKS
         self.n = n
-        for name, shape, make, dt in layer_leaves(c, dense):
+        # ``leaves``: another family's, in ``layer_leaves``' form
+        for name, shape, make, dt in leaves or layer_leaves(c, dense):
             if name in self.banks:
                 for l in range(n):
                     init = make if given is None else _adopt(
@@ -317,39 +318,39 @@ class SarvamMlaForCausalLM(Layer):
                         lambda ids: _forward(spec, params, ids), (input_ids,))
 
 
-def _forward(spec: DecoderSpec, params: dict, ids):
-    """ids [b, s] -> float32 logits [b, s, V]: the EXPANDED attention (every
-    head's own key and value made from ``c``), dense masked softmax, the
-    serving path's own expert mixture (``generation._moe_ffn``)."""
-    from ..inference.generation import _moe_ffn, _rope_bt
-    from ..kernels.rms_norm import rms_norm_fp32 as norm
+def _expanded_attention(spec: DecoderSpec, lp, q, c, k_r, cos, sin, keep):
+    """The EXPANDED latent attention of every head (its own key ``[W_uk c |
+    k_r]`` and value ``W_uv c`` made from ``c``): q ``[b, s, H, nope +
+    rope]`` (its rotary part not yet rotated), c ``[b, s, rank]``, k_r ``[b,
+    s, rope]`` (rotated); a dense softmax over the keys ``keep`` names
+    (bool, broadcast against ``[b, H, s, s]``) -> ``[b, s, H x value]``."""
+    from ..inference.generation import _rope_bt
 
     la, H = spec.latent, spec.num_heads
-    b, s = ids.shape
-    cos, sin = (jnp.broadcast_to(jnp.asarray(t)[None], (b, s, la.rope // 2))
-                for t in spec.rope_tables(s))
-    seen = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+    b, s = c.shape[:2]
     f32 = jnp.float32
+    q_r = _rope_bt(q[..., la.nope:], cos, sin)
+    k_n = (c @ lp["self_attn.k_up_proj.weight"]).reshape(b, s, H, la.nope)
+    v = (c @ lp["self_attn.v_up_proj.weight"]).reshape(b, s, H, la.value)
+    sc = (jnp.einsum("bihd,bjhd->bhij", q[..., :la.nope].astype(f32),
+                     k_n.astype(f32))
+          + jnp.einsum("bihd,bjd->bhij", q_r.astype(f32),
+                       k_r.astype(f32))) * spec.softmax_scale
+    p = jax.nn.softmax(jnp.where(keep, sc, -jnp.inf), axis=-1)
+    return jnp.einsum("bhij,bjhd->bihd", p, v.astype(f32)).reshape(b, s, -1)
+
+
+def _run_stack(spec: DecoderSpec, params: dict, ids, attend):
+    """ids [b, s] -> float32 logits [b, s, V] of a latent stack laid out as
+    this file's (leading dense layers, then the expert layers' stacks):
+    ``attend(y, lp)`` is a layer's attention on its normed input, before
+    ``W_o``; the FFN is the serving path's own (``generation._moe_ffn``)."""
+    from ..inference.generation import _moe_ffn
+    from ..kernels.rms_norm import rms_norm_fp32 as norm
 
     def layer(x, lp, kind, bank_layer):
         y = norm(x, lp["input_layernorm.weight"], spec.norm_eps)
-        q = (y @ lp["self_attn.q_proj.weight"]).reshape(
-            b, s, H, la.nope + la.rope)
-        ckr = y @ lp["self_attn.kv_a_proj_with_mqa.weight"]
-        c = norm(ckr[..., :la.rank], lp["self_attn.kv_a_layernorm.weight"],
-                 spec.norm_eps)
-        k_r = _rope_bt(ckr[..., None, la.rank:], cos, sin)[..., 0, :]
-        q_r = _rope_bt(q[..., la.nope:], cos, sin)
-        k_n = (c @ lp["self_attn.k_up_proj.weight"]).reshape(b, s, H, la.nope)
-        v = (c @ lp["self_attn.v_up_proj.weight"]).reshape(b, s, H, la.value)
-        sc = (jnp.einsum("bihd,bjhd->bhij", q[..., :la.nope].astype(f32),
-                         k_n.astype(f32))
-              + jnp.einsum("bihd,bjd->bhij", q_r.astype(f32),
-                           k_r.astype(f32))) * spec.softmax_scale
-        p = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), axis=-1)
-        a = jnp.einsum("bhij,bjhd->bihd", p, v.astype(f32))
-        x = x + a.reshape(b, s, -1).astype(x.dtype) \
-            @ lp["self_attn.o_proj.weight"]
+        x = x + attend(y, lp).astype(x.dtype) @ lp["self_attn.o_proj.weight"]
         y = norm(x, lp["post_attention_layernorm.weight"], spec.norm_eps)
         if kind.dense_ffn:
             f = (jax.nn.silu(y @ lp["mlp.gate_proj.weight"])
@@ -367,4 +368,29 @@ def _forward(spec: DecoderSpec, params: dict, ids):
               for n, a in stack.items()}
         x = layer(x, lp, kind, jnp.int32(r))
     h = norm(x, params["norm"], spec.norm_eps)
-    return (h @ params["head"]).astype(f32)
+    return (h @ params["head"]).astype(jnp.float32)
+
+
+def _forward(spec: DecoderSpec, params: dict, ids):
+    """ids [b, s] -> float32 logits [b, s, V]: the EXPANDED attention (every
+    head's own key and value made from ``c``), dense masked softmax, the
+    serving path's own expert mixture (``generation._moe_ffn``)."""
+    from ..inference.generation import _rope_bt
+    from ..kernels.rms_norm import rms_norm_fp32 as norm
+
+    la, H = spec.latent, spec.num_heads
+    b, s = ids.shape
+    cos, sin = (jnp.broadcast_to(jnp.asarray(t)[None], (b, s, la.rope // 2))
+                for t in spec.rope_tables(s))
+    seen = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
+
+    def attend(y, lp):
+        q = (y @ lp["self_attn.q_proj.weight"]).reshape(
+            b, s, H, la.nope + la.rope)
+        ckr = y @ lp["self_attn.kv_a_proj_with_mqa.weight"]
+        c = norm(ckr[..., :la.rank], lp["self_attn.kv_a_layernorm.weight"],
+                 spec.norm_eps)
+        k_r = _rope_bt(ckr[..., None, la.rank:], cos, sin)[..., 0, :]
+        return _expanded_attention(spec, lp, q, c, k_r, cos, sin, seen)
+
+    return _run_stack(spec, params, ids, attend)

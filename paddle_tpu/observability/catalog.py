@@ -90,8 +90,30 @@ CATALOG: Dict[str, tuple] = {
         "pool bytes one cached token costs over all layers "
         "(`pool_bytes / (num_pages x page_size)`): per-head K and V "
         "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
-        "five latent layers of 512 + 64 in bf16; 16,384 for four layers "
-        "of 8 KV heads x 128)"),
+        "five latent layers of 512 + 64 in bf16; 7,040 where each also "
+        "keeps an index key of 128; 16,384 for four layers of 8 KV heads "
+        "x 128)"),
+    # ---- serving: a learned index over the latent pool (PR 39) ----
+    "serving.index_bytes_per_token": (
+        "gauge", "",
+        "the part of `serving.kv_bytes_per_token` that is a learned "
+        "index's keys (`models.decoder_spec.LatentIndex`: one key of "
+        "`dim` a token a layer, the pool's third plane): 1,280 for five "
+        "layers of 128 in bf16; 0 for a stack without an index"),
+    "serving.index_pairs": (
+        "histogram", "",
+        "pairs (query token, cached or own token) a learned index scores "
+        "in ONE layer of a plain step, the sum over the working slots of "
+        "`q x ctx + q (q + 1) / 2` (`engine.step`'s `index_pairs`; one "
+        "observation a step while a tracer or a profiler listens, none "
+        "for a stack without an index)"),
+    "serving.selected_keys": (
+        "histogram", "",
+        "keys the learned index chooses in ONE layer of a plain step: "
+        "`min(position + 1, top_k)` summed over the step's query tokens "
+        "(`engine.step`'s `selected_keys`; observed as "
+        "`serving.index_pairs` is): what the sparse latent call's softmax "
+        "runs over, whatever the walk multiplies"),
     # ---- serving: what one copy of the paged call moves (PR 35) ----
     "serving.kv_copy_bytes": (
         "gauge", "",
@@ -544,8 +566,8 @@ SPANS: Dict[str, tuple] = {
     "engine.step": (
         "serving", "engine", "fleet",
         "step, kind=decode|mixed|spec|idle, T, rows, q_tokens, gemm_rows, "
-        "kv_read_tokens, attn_rows, page_copies, ssm_slots, ssm_tokens, "
-        "slots, waiting",
+        "kv_read_tokens, attn_rows, page_copies, index_pairs, "
+        "selected_keys, ssm_slots, ssm_tokens, slots, waiting",
         "one `ContinuousBatchingEngine.step` call, whole: `step` its "
         "running number, `T` the program's query bucket (K in the "
         "speculative lane, 0 when nothing was dispatched), `rows` the "
@@ -571,7 +593,11 @@ SPANS: Dict[str, tuple] = {
         "x the blocks their walk reaches x the pages of a block; "
         "`kernels.paged_attention.page_copies`; for a layer that sees the "
         "whole context where the stack has one; three copies a page in a "
-        "latent stack's call), `ssm_slots` and `ssm_tokens` (a stack with a "
+        "latent stack's call), `index_pairs` and `selected_keys` (a stack "
+        "with a learned index only) the pairs (query token, key) the index "
+        "scores and the keys it chooses in ONE layer of this step (`q x "
+        "ctx + q (q + 1) / 2` a working slot; `min(position + 1, top_k)` "
+        "a query token), `ssm_slots` and `ssm_tokens` (a stack with a "
         "state-space mixer only) the slots whose recurrent state each "
         "layer's scan call reads and writes in this step (those with "
         "work) and the tokens they scan, `slots` the batch B, `waiting` "

@@ -44,8 +44,16 @@ _FALCON_H1_PRESETS = {
     "falcon_h1_tiny": lambda cfg: cfg.tiny(),
     "falcon_h1_34b_d4": lambda cfg: cfg.falcon_h1_34b(num_hidden_layers=4),
 }
+# deepseek_v32 (models/deepseek_v32.py): the test size, and DeepSeek-V3.2 as
+# chip 0 of the 16 that share each layer holds it (16 of the 256 experts, an
+# eighth of the vocabulary, one dense layer and four expert layers: 9.3 GB)
+_DEEPSEEK_V32_PRESETS = {
+    "deepseek_v32_tiny": lambda cfg: cfg.tiny(),
+    "deepseek_v32_ep16": lambda cfg: cfg.deepseek_v32_ep16(index=0),
+}
 _PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS) \
-    + tuple(_SARVAM_MLA_PRESETS) + tuple(_FALCON_H1_PRESETS)
+    + tuple(_SARVAM_MLA_PRESETS) + tuple(_FALCON_H1_PRESETS) \
+    + tuple(_DEEPSEEK_V32_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -158,6 +166,11 @@ def build_engine(args):
         from ..models.sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM
         model = SarvamMlaForCausalLM(
             _SARVAM_MLA_PRESETS[args.preset](SarvamMlaConfig))
+    elif args.preset in _DEEPSEEK_V32_PRESETS:
+        from ..models.deepseek_v32 import (DeepseekV32Config,
+                                           DeepseekV32ForCausalLM)
+        model = DeepseekV32ForCausalLM(
+            _DEEPSEEK_V32_PRESETS[args.preset](DeepseekV32Config))
     elif args.preset in _FALCON_H1_PRESETS:
         from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
         model = FalconH1ForCausalLM(

@@ -59,6 +59,16 @@ def one_chip(topo):
     cc.reset_cache()
 
 
+class _OnTpu:
+    """``jax`` as the kernels' entry points see it on a chip."""
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+    @staticmethod
+    def default_backend():
+        return "tpu"
+
+
 def _compile(one_chip, fn, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
@@ -537,16 +547,7 @@ def test_sarvam_step_program_compiles_at_published_widths(one_chip,
                                               SarvamMlaForCausalLM,
                                               layer_leaves)
 
-    class OnTpu:
-        """``jax`` as the kernel's entry point sees it on a chip."""
-        def __getattr__(self, name):
-            return getattr(jax, name)
-
-        @staticmethod
-        def default_backend():
-            return "tpu"
-
-    monkeypatch.setattr(pa, "jax", OnTpu())
+    monkeypatch.setattr(pa, "jax", _OnTpu())
     monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
     cfg = SarvamMlaConfig.sarvam_105b(
         num_hidden_layers=SV_L, experts_held=32, vocab_size=65536,
@@ -660,17 +661,8 @@ def test_falcon_h1_step_program_compiles_at_published_widths(one_chip,
                                              FalconH1ForCausalLM,
                                              layer_leaves)
 
-    class OnTpu:
-        """``jax`` as the kernels' entry points see it on a chip."""
-        def __getattr__(self, name):
-            return getattr(jax, name)
-
-        @staticmethod
-        def default_backend():
-            return "tpu"
-
-    monkeypatch.setattr(pa, "jax", OnTpu())
-    monkeypatch.setattr(ssd, "jax", OnTpu())
+    monkeypatch.setattr(pa, "jax", _OnTpu())
+    monkeypatch.setattr(ssd, "jax", _OnTpu())
     cfg = FalconH1Config.falcon_h1_34b(num_hidden_layers=FH_L,
                                        max_position_embeddings=2048)
 
@@ -727,3 +719,151 @@ def test_falcon_h1_step_program_compiles_at_published_widths(one_chip,
         - mem.alias_size_in_bytes + mem.temp_size_in_bytes \
         + (cell_pages - pages) * per_page
     assert 13.0e9 < peak < 15.5e9, peak
+
+
+# ---- deepseek-v3.2 as one chip of sixteen holds it (PR 39) ----
+# a learned index over the latent pool: 64 index heads of 128 score every
+# cached token (one index key a token a layer, the pool's third plane),
+# each query token's best 2,048 are chosen exactly, and 128 query heads
+# read ONE row [c (512) | k_r (64)] masked to the set; the cell's engine:
+# 8 slots, 33,024 positions in pages of 16 (a table 2,064 wide), five layers
+DS_HEADS, DS_IH, DS_DIM, DS_B, DS_TABLE, DS_L = 128, 64, 128, 8, 2064, 5
+DS_PAGES = DS_B * DS_TABLE
+
+
+def _ds(one_chip):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+    return sds
+
+
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_latent_index_calls_deepseek_shapes_compile(one_chip, T):
+    """The three calls of the learned sparse attention at the cell's sizes,
+    the whole three-plane pool read at a traced layer, each named as the
+    benchmark's matchers want it: the scores over paged index keys (one
+    float32 result ``[slots, tokens, keys]``), the exact selection (the
+    rows of 16 query tokens in VMEM, a bfloat16 0/1 result) and the masked
+    walk (``[slots, T x heads, rank]``, the block's 0/1 rows copied beside
+    its pages)."""
+    from paddle_tpu.kernels import latent_index as li
+    from chipbench.kernels import (latent_index as k_index,
+                                   paged_attention_latent,
+                                   paged_attention_latent_sparse as k_sparse)
+    sds, i32, f32 = _ds(one_chip), jnp.int32, jnp.float32
+    assert li.index_geometry_error(PAGE, DS_DIM) is None
+    assert "multiple of 16" in li.index_geometry_error(8, DS_DIM)
+    S = DS_TABLE * PAGE
+    table, vec = sds((DS_B, DS_TABLE), i32), sds((DS_B,), i32)
+
+    def named(compiled, name):
+        (op,) = _calls_as_the_trace_names_them(compiled, name)
+        return op
+
+    scores = jax.jit(
+        lambda q, w, k, bt, cl, ql, ly: li._pallas_latent_index_scores(
+            q, w, k, bt, cl, ql, interpret=False, layer=ly)).lower(
+        sds((DS_B, T, DS_IH, DS_DIM), BF16), sds((DS_B, T, DS_IH), f32),
+        sds((DS_L, DS_PAGES, PAGE, DS_DIM), BF16), table, vec, vec,
+        sds((), i32)).compile()
+    Tp = -(-T // 8) * 8
+    assert k_index.match(named(scores, "latent_index_scores")) == {
+        "kind": "scores", "slots": DS_B, "tokens": Tp, "heads": DS_IH,
+        "dim": DS_DIM}
+    select = jax.jit(
+        lambda s, k, ql, cl: li._pallas_latent_index_select(
+            s, k, ql, cl, interpret=False, n_new=T)).lower(
+        sds((DS_B, T, S + T), f32), sds((DS_B, T), i32), vec, vec).compile()
+    got = k_index.match(named(select, "latent_index_select"))
+    assert got["kind"] == "select" and got["tokens"] == -(-T // 16) * 16
+    sparse = jax.jit(
+        lambda qc, qr, c, r, bt, cl, ql, cn, rn, sel, ly:
+        pa._pallas_ragged_paged_attention_latent(
+            qc, qr, c, r, bt, cl, ql, cn, rn, interpret=False, scale=0.1352,
+            layer=ly, selected=sel)).lower(
+        sds((DS_B, T, DS_HEADS, SV_RANK), BF16),
+        sds((DS_B, T, DS_HEADS, SV_ROPE), BF16),
+        sds((DS_L, DS_PAGES, PAGE, SV_RANK), BF16),
+        sds((DS_L, DS_PAGES, PAGE // 2, 2 * SV_ROPE), BF16), table, vec, vec,
+        sds((DS_B, T, SV_RANK), BF16), sds((DS_B, T, SV_ROPE), BF16),
+        sds((DS_B, T, S + T), jnp.bool_), sds((), i32)).compile()
+    op = named(sparse, "ragged_paged_attention_latent_sparse")
+    assert k_sparse.match(op) == {
+        "slots": DS_B, "q_rows": max(8, T * DS_HEADS), "rank": SV_RANK,
+        "rope": SV_ROPE, "dtype": "bf16"}
+    # neither yardstick takes the other's call
+    assert paged_attention_latent.match(op) is None
+    assert k_index.match(op) is None
+    assert k_sparse.match(named(scores, "latent_index_scores")) is None
+
+
+@pytest.mark.timeout(900)
+def test_deepseek_v32_step_program_compiles_at_published_widths(one_chip,
+                                                                monkeypatch):
+    """The packed T = 64 step program of the cell's engine (8 slots, 256
+    GEMM rows) at the published widths, on abstract parameters: the leading
+    dense layer unrolled before a scan over four expert layers; each of the
+    two holds the three calls of the learned sparse attention, the expert
+    layers three grouped GEMMs on their own banks; the three-plane pool is
+    committed in place, and the program fits the chip beside its 9.27 GB of
+    weights and the cell's 1.86 GB pool (the pool here is an eighth of the
+    cell's: its size moves no operation but the commit's bounds)."""
+    from paddle_tpu.inference import generation as gen
+    from paddle_tpu.kernels import latent_index as li
+    from paddle_tpu.models.deepseek_v32 import (DeepseekV32Config,
+                                                DeepseekV32ForCausalLM,
+                                                layer_leaves)
+
+    monkeypatch.setattr(pa, "jax", _OnTpu())
+    monkeypatch.setattr(li, "jax", _OnTpu())
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    cfg = DeepseekV32Config.deepseek_v32_ep16(max_position_embeddings=33024)
+    sds = _ds(one_chip)
+
+    class Abstract:
+        config = cfg
+        decoder_spec = DeepseekV32ForCausalLM.decoder_spec
+
+        def serving_params(self):
+            H, V, n = cfg.hidden_size, cfg.vocab_size, DS_L - 1
+            experts = {
+                name: tuple(sds(shape, dt) for _ in range(n))
+                if name in gen.EXPERT_BANKS else sds((n,) + tuple(shape), dt)
+                for name, shape, _, dt in layer_leaves(cfg, False)}
+            return {"embed": sds((V, H), BF16), "norm": sds((H,), BF16),
+                    "head": sds((H, V), BF16),
+                    "leading": ({name: sds(shape, dt) for name, shape, _, dt
+                                 in layer_leaves(cfg, True)},),
+                    "blocks": (experts,)}
+
+    pages = DS_PAGES // 8
+    g = gen.LlamaGenerator(Abstract(), max_batch=DS_B, max_seq_len=33024,
+                           page_size=PAGE, prefill_bucket=64, num_pages=pages)
+    assert g.pool_bytes // (pages * PAGE) == 7040
+    n_params = sum(int(jnp.prod(jnp.asarray(a.shape)))
+                   for a in jax.tree_util.tree_leaves(g.params))
+    assert n_params == 4_635_518_208
+    T, rows = 64, g.row_buckets(64)[0]
+    assert g.row_buckets(64) == [256, 512]
+    i32, key = jnp.int32, jax.random.key(0)
+    vec = lambda dt: sds((DS_B,), dt)             # noqa: E731
+    ops = (g.params, tuple(sds(a.shape, a.dtype) for a in g.cache.arrays),
+           sds((DS_B, T), i32), vec(i32), vec(i32), vec(jnp.bool_),
+           vec(jnp.bool_), vec(jnp.bool_), vec(i32), vec(i32),
+           sds((DS_B, g.pages_per_seq), i32),
+           jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    compiled = g._step_jit(gen.GenerationConfig(), T, False, rows) \
+        .lower(*ops).compile()
+    text = compiled.as_text()
+    for name in ("latent_index_scores", "latent_index_select",
+                 "ragged_paged_attention_latent_sparse"):
+        assert len(_calls_as_the_trace_names_them(compiled, name)) == 2, name
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        3 * 2 + 3 * (DS_L - 1)
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in g.cache.arrays)
+    assert mem.alias_size_in_bytes == held        # three planes in place
+    cell_pool = DS_PAGES * PAGE * 7040
+    peak = 2 * n_params + cell_pool + mem.temp_size_in_bytes
+    assert peak < 15.5e9, (peak, mem.temp_size_in_bytes)

@@ -352,6 +352,62 @@ class TestTensorParallelMosaic:
         self._shard_export(T=4, ql=(4, 1), int8=True)
 
 
+
+class TestLatentIndexMosaic:
+    """The learned sparse attention's three kernels (PR 39) through the
+    Mosaic pipeline at small shapes of the published tiling (pages of 16,
+    index keys and the rope pair 128 wide); ``tests/test_chip_compile.py``
+    compiles them at the cell's sizes."""
+    B, T, IH, D, H, RANK, ROPE, PAGE, TABLE, P = 2, 16, 8, 128, 8, 128, 64, \
+        16, 6, 16
+
+    def _common(self):
+        i32 = jnp.int32
+        bt = jnp.arange(self.B * self.TABLE, dtype=i32).reshape(
+            self.B, self.TABLE) % self.P
+        return bt, jnp.asarray([40, 7], i32), jnp.asarray([16, 1], i32)
+
+    def test_scores_kernel(self):
+        from paddle_tpu.kernels.latent_index import \
+            _pallas_latent_index_scores
+        bt, ctx, ql = self._common()
+        _export_tpu(
+            lambda q, w, k: _pallas_latent_index_scores(
+                q, w, k, bt, ctx, ql, interpret=False, layer=jnp.int32(1)),
+            _rand((self.B, self.T, self.IH, self.D)),
+            _rand((self.B, self.T, self.IH), jnp.float32, 1),
+            _rand((2, self.P, self.PAGE, self.D), seed=2))
+
+    def test_select_kernel(self):
+        from paddle_tpu.kernels.latent_index import \
+            _pallas_latent_index_select
+        _, ctx, ql = self._common()
+        n = self.TABLE * self.PAGE + self.T
+        k = jnp.minimum(ctx[:, None] + jnp.arange(self.T)[None] + 1, 8)
+        _export_tpu(
+            lambda s: _pallas_latent_index_select(
+                s, k, ql, ctx, interpret=False, n_new=self.T),
+            _rand((self.B, self.T, n), jnp.float32))
+
+    def test_sparse_latent_kernel(self):
+        from paddle_tpu.kernels.paged_attention import \
+            _pallas_ragged_paged_attention_latent
+        bt, ctx, ql = self._common()
+        n = self.TABLE * self.PAGE + self.T
+        sel = jnp.ones((self.B, self.T, n), bool)
+        _export_tpu(
+            lambda qc, qr, c, r, cn, rn:
+            _pallas_ragged_paged_attention_latent(
+                qc, qr, c, r, bt, ctx, ql, cn, rn, interpret=False,
+                scale=0.1352, layer=jnp.int32(0), selected=sel),
+            _rand((self.B, self.T, self.H, self.RANK)),
+            _rand((self.B, self.T, self.H, self.ROPE), seed=1),
+            _rand((2, self.P, self.PAGE, self.RANK), seed=2),
+            _rand((2, self.P, self.PAGE // 2, 2 * self.ROPE), seed=3),
+            _rand((self.B, self.T, self.RANK), seed=4),
+            _rand((self.B, self.T, self.ROPE), seed=5))
+
+
 class TestWeightOnlyMosaic:
     def test_w8a16(self):
         from paddle_tpu.kernels.weight_only import _wo_core
