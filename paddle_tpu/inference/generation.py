@@ -226,16 +226,49 @@ def _scaled(a, scale: float):
     return (a.astype(jnp.float32) * scale).astype(a.dtype)
 
 
+def _moe_choice(y, lp, moe):
+    """The router of an expert mixture on the tensor it reads, ``y [..., H]``
+    (``MoeSpec.router_input``: the FFN's normed input or the attention's):
+    ``(topv, topi)``, each ``[N, top_k]`` over the flattened rows: the
+    gates in float32 (softmax or sigmoid scores, the ``top_k`` largest,
+    renormalised; a selecting bias, a gate scale and a group-limited choice
+    where ``moe`` states them) and the experts chosen.  It depends on ``y``
+    and the router's weights alone, so a model whose router reads the
+    attention's input makes it before the attention call."""
+    from ..models import llama as _llama
+
+    with jax.named_scope("router"):
+        topv, topi, _, _ = _llama._route_topk(
+            y.reshape(-1, y.shape[-1]), lp["mlp.gate.weight"], moe.top_k,
+            moe.score, bias=lp["mlp.gate.bias"] if moe.select_bias else None,
+            scale=moe.gate_scale, groups=moe.groups,
+            groups_kept=moe.groups_kept)
+    return topv, topi
+
+
 def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
-    """Routed SwiGLU expert mixture for the serving path (reference:
-    incubate fused_moe inference semantics), one function for every
-    family: ``moe`` (``models.decoder_spec.MoeSpec``) states the router
-    (softmax or sigmoid scores in float32, top-k, renormalised or not),
-    the experts held here and the shared experts.  Returns ``(out, rows)``,
+    """Routed expert mixture for the serving path where router and experts
+    read ONE tensor ``y``: ``_moe_experts`` on ``_moe_choice``'s choice
+    (the rows are flattened once, for both)."""
+    xf = y.reshape(-1, y.shape[-1])
+    out, rows = _moe_experts(xf, lp, moe, _moe_choice(xf, lp, moe),
+                             mp_shards=mp_shards, live=live, layer=layer)
+    return out.reshape(y.shape), rows
+
+
+def _moe_experts(y, lp, moe, choice, mp_shards=None, live=None, layer=None):
+    """The experts of a routed mixture on THEIR input ``y`` for a choice
+    already made (``choice = (topv, topi)`` of ``_moe_choice``, over the
+    same rows) (reference: incubate fused_moe inference semantics), one
+    function for every family: ``moe`` (``models.decoder_spec.MoeSpec``)
+    states the experts held here, their gated activation (``W_down
+    (act(W_gate y) * (W_up y))``, SiLU or ReLU) and the shared experts
+    (SwiGLU).  Returns ``(out, rows)``,
     ``rows`` int32 ``[entries that fell on held experts, rows of the tiles
     laid out for them]`` where this chip holds a share of the experts or
     the grouped path runs on one device (every expert held: the entries of
-    the rows that hold a token), else None.
+    the rows that hold a token, and a third number, the entries of the
+    fullest expert), else None.
 
     - grouped (``moe.dispatch == "grouped"``): the expert-sorted ragged-GEMM
       path shared with training (``models.llama._grouped_ffn``) — each
@@ -277,10 +310,13 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
     ``top_k`` nonzero expert terms, every other shard contributes an
     exact +0.0, and IEEE addition of two values is order-insensitive
     bitwise for top_k <= 2 (the caller only enables sharding then).
+    SiLU experts only: this arm runs training's differentiable
+    ``_grouped_ffn``.
     """
     from ..models import llama as _llama
 
-    gw = lp["mlp.gate.weight"]              # [H, router width]
+    topv, topi = choice
+    act = _llama._GATE_ACTIVATIONS[moe.activation]
     shape = y.shape
     xf = y.reshape(-1, shape[-1])
     N = xf.shape[0]
@@ -295,12 +331,6 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
         return jax.lax.switch(layer, [functools.partial(fn, *ws)
                                       for ws in zip(*banks)])
 
-    with jax.named_scope("router"):
-        topv, topi, _, _ = _llama._route_topk(
-            xf, gw, top_k, moe.score,
-            bias=lp["mlp.gate.bias"] if moe.select_bias else None,
-            scale=moe.gate_scale, groups=moe.groups,
-            groups_kept=moe.groups_kept)
     if moe.partial:
         local = topi - moe.offset
         own = jnp.logical_and(local >= 0, local < held)       # [N, k]
@@ -314,6 +344,11 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
         # the 8-row sublane multiple that covers them (same math, less pad)
         bm = max(8, min(moe.block_m, -(-N * top_k // 8) * 8))
         if mp_shards and mp_shards > 1:
+            if moe.activation != "silu":
+                raise ValueError(
+                    f"{moe.activation!r} experts under tensor parallelism: "
+                    "the sharded arm runs training's _grouped_ffn, whose "
+                    "backward is written for \"silu\" alone")
             E_loc = E // mp_shards
             my = jax.lax.axis_index(MP_AXIS)
             own = (topi // E_loc) == my
@@ -364,7 +399,8 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
                 out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn_fwd(
                     xf, wg, wu, wd, topv * own, inv, pos,
                     jnp.minimum(tg, held - 1), held, top_k, bm,
-                    live_tiles=live_rows // bm)[0])
+                    live_tiles=live_rows // bm,
+                    activation=moe.activation)[0])
                 rows = jnp.stack([own_flat.sum().astype(jnp.int32),
                                   live_rows])
         else:
@@ -373,13 +409,14 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
                 # a token are dropped, and the table names the dead tiles
                 real = jnp.ones((N, top_k), bool) if live is None else \
                     jnp.broadcast_to(live.reshape(N, 1), (N, top_k))
-                inv, pos, tg, live_tiles = masked_dispatch_plan(
+                inv, pos, tg, live_tiles, per_expert = masked_dispatch_plan(
                     topi.reshape(N * top_k), real.reshape(N * top_k), E, bm)
                 out = on_banks(lambda wg, wu, wd: _llama._grouped_ffn_fwd(
                     xf, wg, wu, wd, topv * real, inv, pos, tg, E, top_k, bm,
-                    dead_in_table=True)[0])
+                    dead_in_table=True, activation=moe.activation)[0])
                 rows = jnp.stack([real.sum().astype(jnp.int32),
-                                  live_tiles * bm])
+                                  live_tiles * bm,
+                                  per_expert.max().astype(jnp.int32)])
     else:
         with jax.named_scope("router"):
             comb = jnp.zeros((N, E), jnp.float32).at[
@@ -390,7 +427,7 @@ def _moe_ffn(y, lp, moe, mp_shards=None, live=None, layer=None):
                                   jnp.int32(N * held)])
 
         def step(acc, ex):
-            h = jax.nn.silu(xf @ ex["wg"]) * (xf @ ex["wu"])
+            h = act(xf @ ex["wg"]) * (xf @ ex["wu"])
             return acc + ex["c"][:, None].astype(acc.dtype) \
                 * (h @ ex["wd"]), None
 
@@ -507,6 +544,7 @@ class LlamaGenerator:
         self._moe_shards = tp if (
             tp > 1 and moe is not None and moe.dispatch == "grouped"
             and moe.top_k <= 2 and not moe.partial
+            and moe.activation == "silu"
             and moe.num_experts % tp == 0) else None
         # where ``_moe_ffn`` counts them the step also returns how many
         # entries fell on held experts and the rows laid out for them
@@ -1058,8 +1096,14 @@ class LlamaGenerator:
             return _scaled(g @ lp["mamba.out_proj.weight"], mx.out_scale), \
                 state, carried
 
-        def ffn(y, lp, kind, bank_layer):
-            """A place's FFN on its normed input: the spec's expert mixture,
+        # the router reads the attention's normed input: a layer makes its
+        # choice before the attention call and hands it past it
+        routes_early = moe is not None and moe.router_input == "attention" \
+            and not c.parallel_block        # there the two are one tensor
+
+        def ffn(y, lp, kind, bank_layer, choice=None):
+            """A place's FFN on its normed input: the spec's expert mixture
+            (on ``choice`` where the layer's router has made it already),
             or a dense gated MLP where the spec has none or the place says
             so: (output, MoE rows or None)."""
             if moe is not None and not kind.dense_ffn:
@@ -1069,9 +1113,11 @@ class LlamaGenerator:
                 # share's arm is handed ``valid``, as it always was: its
                 # step programs are the ones its cells were measured on
                 grid = valid if moe.partial else has_token
-                return _moe_ffn(y, lp, moe, mp_shards=self._moe_shards,
-                                live=live if packed else grid,
-                                layer=bank_layer)
+                kw = dict(mp_shards=self._moe_shards,
+                          live=live if packed else grid, layer=bank_layer)
+                if choice is None:
+                    return _moe_ffn(y, lp, moe, **kw)
+                return _moe_experts(y, lp, moe, choice, **kw)
             act = jax.nn.silu(_scaled(y @ lp["mlp.gate_proj.weight"],
                                       c.mlp_gate_scale)) * \
                 (y @ lp["mlp.up_proj.weight"])
@@ -1100,6 +1146,13 @@ class LlamaGenerator:
                 return x + f, k, v, n_rows, None, None
             with jax.named_scope("attention"):
                 y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
+            choice = None
+            if routes_early and not kind.dense_ffn:
+                # a scope of its own: a device trace tells this router from
+                # the experts under "moe" and from the attention around it
+                with jax.named_scope("moe_router"):
+                    choice = _moe_choice(y, lp, moe)
+            with jax.named_scope("attention"):
                 ya = _scaled(y, c.attn_in_scale)
                 q = (ya @ lp["self_attn.q_proj.weight"]).reshape(
                     R0, R1, c.num_heads, c.head_dim)
@@ -1155,7 +1208,7 @@ class LlamaGenerator:
                 if not c.parallel_block:     # else the FFN reads the same y
                     y = norm_fn(x, lp["post_attention_layernorm.weight"],
                                 c.norm_eps)
-                f, n_rows = ffn(y, lp, kind, bank_layer)
+                f, n_rows = ffn(y, lp, kind, bank_layer, choice)
                 x = x + a + f if c.parallel_block else x + f
             return x, k, v, n_rows, state, conv_rows
 
@@ -1228,7 +1281,7 @@ class LlamaGenerator:
                 period, carry, xs)
         h = carry[0]
         if moe_rows is not None:
-            moe_rows = moe_rows.sum(axis=0)        # over the periods: [2]
+            moe_rows = moe_rows.sum(axis=0)        # over the periods
         L = c.num_layers
         if c.latent is not None:
             # [periods, places, B, T, width] -> [layers, B * T, width], the
@@ -1629,8 +1682,8 @@ class _InFlight:
     ``kind`` "step": ``out`` [B] sampled tokens, ``commit`` the host's
     [B] commit marks; "spec": ``out`` [B, K], ``commit`` the device's [B]
     commit counts, ``drafted`` [B] or None.  ``finished`` [B] as that step
-    left it; ``moe_rows`` [entries on held experts, rows laid out] where
-    the step counts them; ``t`` the host's dispatch stamp."""
+    left it; ``moe_rows`` [entries on held experts, rows laid out(, the
+    fullest expert's entries)] where the step counts them; ``t`` the host's dispatch stamp."""
 
     __slots__ = ("kind", "out", "commit", "drafted", "finished",
                  "moe_rows", "reqs", "t")
@@ -1683,8 +1736,8 @@ class _ServingMetrics:
                  "peak_pages", "active_seqs", "cached_pages",
                  "evictable_pages", "spec_drafted", "spec_accepted",
                  "spec_rejected", "accept_len", "digest_epoch",
-                 "moe_held_rows", "moe_rows_laid_out", "state_resets",
-                 "index_pairs", "selected_keys")
+                 "moe_held_rows", "moe_rows_laid_out", "moe_expert_rows_max",
+                 "state_resets", "index_pairs", "selected_keys")
 
     def __init__(self):
         m = _obs.metrics
@@ -1707,6 +1760,10 @@ class _ServingMetrics:
                                          bounds=rows_bounds)
         self.moe_rows_laid_out = m.histogram("serving.moe_rows_laid_out",
                                              bounds=rows_bounds)
+        # the fullest expert's entries, summed over the layers as the two
+        # above (every expert held, grouped dispatch on one device)
+        self.moe_expert_rows_max = m.histogram(
+            "serving.moe_expert_rows_max", bounds=rows_bounds)
         # slots whose recurrent state the device zeroes at their first
         # chunk (a stack with a state-space mixer; else it stays 0)
         self.state_resets = m.counter("serving.state_resets")
@@ -2591,11 +2648,13 @@ class ContinuousBatchingEngine:
         held_rows = 0
         for e in window:
             if e.moe_rows is not None:
-                held, laid_out = e.moe_rows
+                held, laid_out, *fullest = e.moe_rows
                 held_rows += int(held)
                 if obs is not None:
                     obs.moe_held_rows.observe(float(held))
                     obs.moe_rows_laid_out.observe(float(laid_out))
+                    if fullest and _obs.TRACER.listening():
+                        obs.moe_expert_rows_max.observe(float(fullest[0]))
         if obs is not None:
             obs.drains.inc()
         self._fold_spec_metrics(window)

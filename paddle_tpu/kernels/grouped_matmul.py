@@ -457,7 +457,8 @@ def capacity_dispatch_plan(expert_ids, gate_vals, num_groups, capacity):
 
 
 def _dispatch_plan(expert_ids, num_groups, bm, real=None):
-    """The two plans below: their three results and each expert's tiles."""
+    """The two plans below: their three results, each expert's tiles and
+    each expert's entries."""
     F = expert_ids.shape[0]
     M = -(-F // bm) * bm + num_groups * bm
     i32 = jnp.int32
@@ -485,7 +486,7 @@ def _dispatch_plan(expert_ids, num_groups, bm, real=None):
     tile_groups = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(M // bm) * bm, side="right"),
         num_groups - 1).astype(i32)
-    return inv_flat, pos, tile_groups, tiles
+    return inv_flat, pos, tile_groups, tiles, counts
 
 
 def sorted_dispatch_plan(expert_ids, num_groups, bm):
@@ -517,20 +518,21 @@ def masked_dispatch_plan(expert_ids, real, num_groups, bm):
     token).  The other entries are dropped before rows are laid out: such
     an entry takes no row and its ``pos`` is the sentinel M
     (``take_sentinel_rows`` reads zero).  M stays what it is without a
-    mask.  Returns (inv_flat, pos, tile_groups, live_tiles): the row tiles
-    after the last expert's are dead and the table says so itself,
+    mask.  Returns (inv_flat, pos, tile_groups, live_tiles, counts): the
+    row tiles after the last expert's are dead and the table says so itself,
     ``tile_groups[t] = -(last live tile) - 1``, which is what
     ``gmm(dead_in_table=True)`` skips them by; ``live_tiles`` counts the
-    tiles before them, an int32 device scalar.
+    tiles before them, an int32 device scalar; ``counts [num_groups]`` are
+    each expert's real entries.
     """
-    inv_flat, pos, tile_groups, tiles = _dispatch_plan(
+    inv_flat, pos, tile_groups, tiles, counts = _dispatch_plan(
         expert_ids, num_groups, bm, real)
     # the sum of the experts' tiles, not ``ends[-1] // bm``: under x64 a
     # division of a device int64 compiles for a second on the chip's host
     live_tiles = tiles.sum().astype(jnp.int32)
     tile_groups = jnp.where(jnp.arange(tile_groups.shape[0]) < live_tiles,
                             tile_groups, -live_tiles)
-    return inv_flat, pos, tile_groups, live_tiles
+    return inv_flat, pos, tile_groups, live_tiles, counts
 
 
 # ------------------------------------------------------ differentiable ---

@@ -7,7 +7,7 @@ sharding plan over the hybrid mesh (dp/mp/pp/sep axes).
 """
 
 from . import (cohere2_moe, deepseek_v32, dit, falcon_h1, gpt,  # noqa: F401
-               llama, sarvam_mla)
+               llama, sarvam_mla, smallthinker)
 from .deepseek_v32 import (DeepseekV32Config,  # noqa: F401
                            DeepseekV32ForCausalLM)
 from .cohere2_moe import Cohere2MoeConfig, CohereMoeForCausalLM  # noqa: F401
@@ -18,3 +18,5 @@ from .llama import (  # noqa: F401
 )
 from .gpt import GPTConfig, GPTForCausalLM  # noqa: F401
 from .sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM  # noqa: F401
+from .smallthinker import (SmallThinkerConfig,  # noqa: F401
+                           SmallThinkerForCausalLM)

@@ -16,6 +16,13 @@ A stack may open with layers of another shape than the period's
 before the scan, and ``serving_params()["leading"]`` is one dict a layer,
 its arrays without a layer axis.
 
+A ``MoeSpec`` states the router (its width, ``top_k``, softmax or sigmoid
+scores, a selecting bias, a gate scale, a group-limited choice), the experts
+held here (``held``, ``offset``) and the shared ones, how entries reach the
+experts (``dispatch``, ``block_m``), which tensor the router reads
+(``router_input``: the FFN's normed input, or the attention's) and the
+experts' gated activation (``activation``: SiLU or ReLU).
+
 A place's expert banks (``EXPERT_BANKS``) may come unstacked instead: a tuple
 of ``periods`` arrays ``[E, ...]``, one a layer.  The grouped GEMMs that read
 them are custom calls, for which a layer sliced out of a stack is written
@@ -224,10 +231,25 @@ class MoeSpec:
     # the ``top_k`` are chosen inside the best ``groups_kept`` groups
     groups: Optional[int] = None
     groups_kept: Optional[int] = None
+    # the tensor the router's logits are made from: "ffn", the normed input
+    # of the FFN (what the experts read), or "attention", the normed input
+    # of the layer's attention (the choice is made before the attention
+    # call and handed past it; in a parallel block the two are one tensor)
+    router_input: str = "ffn"
+    # an expert is ``W_down (act(W_gate z) * (W_up z))``: "silu" or "relu"
+    activation: str = "silu"
 
     def __post_init__(self):
         if self.score not in ("softmax", "sigmoid"):
             raise ValueError(f"router score {self.score!r}")
+        if self.router_input not in ("ffn", "attention"):
+            raise ValueError(
+                f"router input {self.router_input!r}: the router reads the "
+                "normed input of the \"ffn\" or of the \"attention\"")
+        if self.activation not in ("silu", "relu"):
+            raise ValueError(
+                f"expert activation {self.activation!r}: \"silu\" or "
+                "\"relu\"")
         if (self.groups is None) != (self.groups_kept is None):
             raise ValueError("groups and groups_kept are stated together")
         if self.groups is not None and (
@@ -292,6 +314,10 @@ class DecoderSpec:
         if self.latent is not None and self.parallel_block:
             raise ValueError("latent attention is served with sequential "
                              "residuals only")
+        if self.latent is not None and self.moe is not None \
+                and self.moe.router_input == "attention":
+            raise ValueError("a router that reads the attention's input is "
+                             "served with per-head attention only")
         # the index keys are a plane of the pool: one shape for the stack
         if len({k.index for k in self.leading + self.pattern}) != 1:
             raise ValueError("one pool serves every layer: every place of "

@@ -493,14 +493,22 @@ def _padded_rows(buf, tok_of, scale=None):
     return out
 
 
+# a gated expert's activation by its name in ``MoeSpec.activation``
+_GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
                      tile_groups, E, k, bm, live_tiles=None,
-                     dead_in_table=False):
+                     dead_in_table=False, activation="silu"):
     """The forward pass and its residuals.  Serving, forward only: ``gmm``
     is told which row tiles hold no expert's rows and are not multiplied,
     by ``live_tiles`` (a share of the experts) or by the table itself
     (``dead_in_table``: a plan that dropped the rows without a token);
-    their ``pos`` entries are the sentinel, so they read zero."""
+    their ``pos`` entries are the sentinel, so they read zero; and the
+    gate's ``activation`` is what the model states (``MoeSpec.activation``:
+    "relu" for ReGLU experts).  The VJP below differentiates "silu" alone:
+    training reaches this function through ``_grouped_ffn``, which hands it
+    no other."""
     from ..kernels.grouped_matmul import (gmm, take_sentinel_rows,
                                           validate_tile_flags)
 
@@ -517,7 +525,7 @@ def _grouped_ffn_fwd(xf, w_gate, w_up, w_down, gates, inv_flat, pos,
     # of them: the down projection skips the same tiles, and ``pos`` points
     # at live rows or the sentinel alone (the plan's scatter; held by
     # tests/test_moe_dispatch_parity.py::test_rows_without_a_token_...)
-    a = jax.nn.silu(h_g) * h_u
+    a = _GATE_ACTIVATIONS[activation](h_g) * h_u
     o = gmm(a, w_down, tile_groups, bm=bm, **dead)        # [M, H]
     # combine gather: sentinel pos >= M (dropped entries) reads zero
     o_pos = take_sentinel_rows(o, pos).reshape(N, k, H)

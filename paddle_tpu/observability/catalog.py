@@ -84,6 +84,22 @@ CATALOG: Dict[str, tuple] = {
         "where every expert is held, live: the tiles behind them are "
         "skipped); `moe_held_rows / moe_rows_laid_out` is the occupancy "
         "of the tiles that are multiplied"),
+    "serving.moe_expert_rows_max": (
+        "histogram", "",
+        "the (token, choice) entries of the FULLEST expert in one plain "
+        "step, summed over the layers as the two above (divide by the "
+        "layers that have experts for a layer's): what a straggler among "
+        "many small experts shows beside `moe_held_rows / experts`.  "
+        "Counted on the device where every expert is held and the grouped "
+        "path runs on one device (a third number beside the two above, a "
+        "max over the plan's per-expert counts) and read where the step's "
+        "counts land (the gather); observed while a tracer or a profiler "
+        "listens, one observation a step, none otherwise and none where a "
+        "chip holds a share of the experts. "
+        "Where the router reads the attention's input "
+        "(`MoeSpec.router_input`) its operations run before the attention "
+        "call under the device scope `moe_router` (`moe_router/router/...`), "
+        "apart from the experts' `moe/experts/...`"),
     # ---- serving: what the pool holds of a token (PR 31) ----
     "serving.kv_bytes_per_token": (
         "gauge", "",
@@ -92,7 +108,7 @@ CATALOG: Dict[str, tuple] = {
         "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
         "five latent layers of 512 + 64 in bf16; 7,040 where each also "
         "keeps an index key of 128; 16,384 for four layers of 8 KV heads "
-        "x 128)"),
+        "x 128, and for eight layers of 4)"),
     # ---- serving: a learned index over the latent pool (PR 39) ----
     "serving.index_bytes_per_token": (
         "gauge", "",

@@ -51,9 +51,16 @@ _DEEPSEEK_V32_PRESETS = {
     "deepseek_v32_tiny": lambda cfg: cfg.tiny(),
     "deepseek_v32_ep16": lambda cfg: cfg.deepseek_v32_ep16(index=0),
 }
+# smallthinker (models/smallthinker.py): the test size, and
+# SmallThinker-21BA3B-Instruct as the first of seven pipeline stages holds
+# it: 8 of its 52 layers with all 64 experts each, embedding and head (7.9 GB)
+_SMALLTHINKER_PRESETS = {
+    "smallthinker_tiny": lambda cfg: cfg.tiny(),
+    "smallthinker_21b": lambda cfg: cfg.smallthinker_21b(depth=8),
+}
 _PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS) \
     + tuple(_SARVAM_MLA_PRESETS) + tuple(_FALCON_H1_PRESETS) \
-    + tuple(_DEEPSEEK_V32_PRESETS)
+    + tuple(_DEEPSEEK_V32_PRESETS) + tuple(_SMALLTHINKER_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -175,6 +182,11 @@ def build_engine(args):
         from ..models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
         model = FalconH1ForCausalLM(
             _FALCON_H1_PRESETS[args.preset](FalconH1Config))
+    elif args.preset in _SMALLTHINKER_PRESETS:
+        from ..models.smallthinker import (SmallThinkerConfig,
+                                           SmallThinkerForCausalLM)
+        model = SmallThinkerForCausalLM(
+            _SMALLTHINKER_PRESETS[args.preset](SmallThinkerConfig))
     else:
         from ..models.llama import LlamaConfig, LlamaForCausalLM
         model = LlamaForCausalLM(getattr(LlamaConfig, args.preset)())
@@ -186,6 +198,11 @@ def build_engine(args):
 
 def main(argv: Optional[List[str]] = None) -> int:
     args = build_parser().parse_args(argv)
+    # a replica tunes no kernel while it warms up: the grouped GEMM's probe
+    # runs inside the step's trace and fails there (ROADMAP D12), the
+    # engine thread dies and /readyz never comes.  Off, as in every
+    # benchmark cell; ``--set autotune_enable=true`` asks for it all the same
+    flags.set_flags({"autotune_enable": False})
     apply_flag_sets(args.flag_sets)
     if args.prefix_cache:
         # single source of truth: the engine's prefix_cache=None default

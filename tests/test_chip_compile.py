@@ -870,3 +870,83 @@ def test_deepseek_v32_step_program_compiles_at_published_widths(one_chip,
     cell_pool = DS_PAGES * PAGE * 7040
     peak = 2 * n_params + cell_pool + mem.temp_size_in_bytes
     assert peak < 15.5e9, (peak, mem.temp_size_in_bytes)
+
+
+# ---- smallthinker-21ba3b-instruct, depth 8 with every expert (PR 41) ----
+# 28 query heads over 4 KV heads (a group of 7: 448 rows at T = 64, padded
+# to 8 at T = 1), with and without a window of 4,096 in one stack; 64 ReGLU
+# experts of 768 over a hidden of 2,560, all held; the cell's engine: 48
+# slots, 5,120 positions in pages of 16 (a table 320 wide), a pool of 15,360
+ST_QH, ST_KVH, ST_B, ST_TABLE, ST_PAGES = 28, 4, 48, 320, 15360
+ST_H, ST_I, ST_E, ST_BM, ST_ROWS = 2560, 768, 64, 128, 768
+
+
+@pytest.mark.parametrize("window", [4096, None], ids=["windowed", "full"])
+@pytest.mark.parametrize("T", [1, 64], ids=["decode", "mixed"])
+def test_paged_attention_smallthinker_engine_shapes_compile(one_chip, T,
+                                                            window):
+    """A group of seven (no power of two) over pages of 32 KB a layer, the
+    windowed and the full call of one stack: each is what the benchmark
+    matches on, the window in the name."""
+    compiled = _compile_engine_call(one_chip, ST_B, ST_QH, ST_KVH, T,
+                                    ST_TABLE, ST_PAGES, window, layers=8)
+    rows = max(8, T * ST_QH // ST_KVH)
+    assert _paged_call(compiled) == (
+        "ragged_paged_attention" + ("_w4096" if window else ""),
+        f"bf16[48,4,{rows},128]", f"f32[48,4,{rows},1]", "s32[48,320]")
+    if T == 64:
+        assert rows == 448 and pa.row_tile(T, 7) == 224
+
+
+@pytest.mark.parametrize("k,n", [(ST_H, ST_I), (ST_I, ST_H)],
+                         ids=["gate_and_up", "down"])
+def test_gmm_smallthinker_shapes_compile(one_chip, k, n):
+    """``[M, 2560] x [64, 2560, 768]`` and ``[M, 768] x [64, 768, 2560]``
+    with the dead tiles named in the table (the whole-bank arm), at the
+    768-row packed member: M = 768 x 6 entries + 64 tiles of 128."""
+    m = ST_ROWS * 6 + ST_E * ST_BM
+    assert gm._pick_block(ST_I, 512) == 256 and gm._pick_block(ST_H, 512) == 512
+    _compile(one_chip,
+             lambda l, r, t: gm.gmm(l, r, t, bm=ST_BM, interpret=False,
+                                    dead_in_table=True),
+             ((m, k), BF16), ((ST_E, k, n), BF16), ((m // ST_BM,), jnp.int32))
+
+
+def test_smallthinker_expert_layer_compiles_and_its_yardstick_matches(
+        one_chip, monkeypatch):
+    """The expert layer as the step calls it at the published widths: the
+    choice made apart (on another tensor than the experts read), ReLU
+    between the grouped calls, the fullest expert counted; its three calls
+    match ``grouped_matmul`` (and not the held arm's matcher), with the row
+    bucket's ``768 x 6`` entries as ``rows``: what ``gmm_roofline_pct.batch``
+    would price, and why the cell reports ``gmm_counted_roofline_pct.batch``
+    (the program's own count) instead."""
+    from chipbench.kernels import grouped_matmul as whole
+    from chipbench.kernels import grouped_matmul_held as held
+    from paddle_tpu.inference.generation import (EXPERT_BANKS, _moe_choice,
+                                                 _moe_experts)
+    from paddle_tpu.models.smallthinker import SmallThinkerConfig
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    moe = SmallThinkerConfig.smallthinker_21b().moe_spec()
+    up, down = (ST_E, ST_H, ST_I), (ST_E, ST_I, ST_H)
+
+    def ffn(u, z, live, router, *banks):
+        lp = {"mlp.gate.weight": router, **dict(zip(EXPERT_BANKS, banks))}
+        return _moe_experts(z, lp, moe, _moe_choice(u, lp, moe), live=live)
+
+    compiled = _compile(
+        one_chip, ffn, ((1, ST_ROWS, ST_H), BF16), ((1, ST_ROWS, ST_H), BF16),
+        ((ST_ROWS,), jnp.bool_), ((ST_H, ST_E), BF16), (up, BF16),
+        (up, BF16), (down, BF16))
+    calls = _gmm_calls_as_the_trace_names_them(compiled)
+    assert len(calls) == 3
+    for op in calls:
+        got = whole.match(op)
+        assert held.match(op) is None
+        assert (got["rows"], got["rows_laid_out"], got["block_m"],
+                got["experts"]) == (ST_ROWS * 6, ST_ROWS * 6 + ST_E * ST_BM,
+                                    ST_BM, ST_E)
+        # priced with what a steady step holds (about 1,200 entries) the
+        # call is bound by its bank's bytes, not by its products
+        flops, nbytes = held.cost(got, 1200.0)
+        assert flops / 197e12 < nbytes / 819e9

@@ -214,14 +214,15 @@ def test_plan_with_a_mask_lays_out_the_real_entries_alone(bm, mask, ids_of):
     """``masked_dispatch_plan``: a dropped entry takes no row and its
     ``pos`` is the sentinel M; every expert still owns a tile; the tiles
     after the last expert's rows name the last live tile, negated; the
-    fourth result counts the live ones.  ``sorted_dispatch_plan`` (no
+    fourth result counts the live ones, the fifth each expert's real
+    entries.  ``sorted_dispatch_plan`` (no
     mask): the three results it has always had."""
     E, k, F = 8, 2, 2 * 296
     rng = np.random.default_rng(bm)
     ids = rng.integers(0, E, F) if ids_of == "spread" else np.full(F, 5)
     real = MASKS[mask](F, k)
     want_inv, want_pos, want_tg, M = _plan_by_hand(ids, E, bm, real)
-    inv, pos, tg, live = map(np.asarray, G.masked_dispatch_plan(
+    inv, pos, tg, live, counts = map(np.asarray, G.masked_dispatch_plan(
         jnp.asarray(ids, jnp.int32), jnp.asarray(real), E, bm))
     assert inv.shape == (M,) and M == -(-F // bm) * bm + E * bm
     assert live == len(want_tg) and E <= live <= M // bm
@@ -229,6 +230,7 @@ def test_plan_with_a_mask_lays_out_the_real_entries_alone(bm, mask, ids_of):
     assert (pos[~real] == M).all() and (pos[real] < live * bm).all()
     assert tg[:live].tolist() == want_tg
     assert (tg[live:] == -live).all()      # park on tile ``live - 1``
+    assert counts.tolist() == np.bincount(ids[real], minlength=E).tolist()
     if mask == "all_live":
         plain = G.sorted_dispatch_plan(jnp.asarray(ids, jnp.int32), E, bm)
         assert len(plain) == 3

@@ -170,7 +170,7 @@ class TestServingDispatch:
         _, topi, _, _ = L._route_topk(y[0], lp["mlp.gate.weight"], k)
         per = np.bincount(np.asarray(topi)[live].ravel(), minlength=E)
         tiles = np.maximum(-(-per // bm), 1).sum()
-        assert rows.tolist() == [k * live.sum(), tiles * bm]
+        assert rows.tolist() == [k * live.sum(), tiles * bm, per.max()]
         assert rows_plain[0] == k * N and rows[1] < rows_plain[1] <= \
             N * k + E * bm
 
