@@ -35,7 +35,7 @@ import time
 from typing import Optional
 
 from .. import flags
-from . import catalog, metrics, startup, tracing
+from . import catalog, collectives, metrics, startup, tracing
 from .attribution import StepAttribution
 from .collector import (ClockSync, HttpTransport, InprocTransport,
                         SpanExporter, StoreTransport, TraceCollector)
@@ -47,7 +47,8 @@ from .tracing import TRACER, Tracer
 
 tracer = TRACER
 
-__all__ = ["metrics", "tracing", "catalog", "startup", "REGISTRY",
+__all__ = ["metrics", "tracing", "catalog", "collectives", "startup",
+           "REGISTRY",
            "counter", "gauge", "histogram", "snapshot", "prometheus_text",
            "reset", "find",
            "set_help", "tracer", "Tracer", "TRACER", "FlightRecorder",

@@ -524,6 +524,14 @@ CATALOG: Dict[str, tuple] = {
         "counter", "", "XLA backend compiles attributed to train steps"),
     "train.grad_comm_bytes": (
         "counter", "", "analytic gradient-sync traffic"),
+    "train.collectives": (
+        "gauge", "", "all-reduces in the compiled train step (scheduled "
+        "HLO, read once the step program is built: "
+        "`PretrainStep.count_collectives`)"),
+    "train.collectives_async": (
+        "gauge", "", "of those, the asynchronous ones: start/done pairs "
+        "that run under other work (several TPU chips: "
+        "`models/pretrain.py::_ASYNC_SUMS`); the rest stop the core"),
     # ---- kernels (PR 21) ----
     "kernels.reference_fallbacks": (
         "counter", "kernel=flash_attention",

@@ -54,18 +54,25 @@ def test_missing_and_shape_mismatch_are_divergent():
     assert len(diffs) == 2
 
 
-def test_pretrain_serial_vs_hybrid_aligns():
+@pytest.mark.parametrize("hybrid", [dict(dp=2, mp=2), dict(dp=2),
+                                    dict(mp=2), dict(dp=2, mp=2, remat=True)],
+                         ids=["dp2mp2", "dp2", "mp2", "dp2mp2_remat"])
+def test_pretrain_serial_vs_hybrid_aligns(hybrid):
     """The headline workflow: the SAME model under serial and dp x mp
-    topologies must align step-for-step (canonical param layout)."""
+    topologies must align step-for-step (canonical param layout).  The
+    step's asynchronous sums are asked of the TPU's compiler alone: on
+    these host devices every mesh compiles with no option and the sums stay
+    what they were."""
     from paddle_tpu.models.llama import LlamaConfig
-    from paddle_tpu.models.pretrain import ParallelConfig
+    from paddle_tpu.models.pretrain import ParallelConfig, PretrainStep
 
     cfg = LlamaConfig.tiny(num_hidden_layers=2)
+    assert PretrainStep(cfg, ParallelConfig(**hybrid))._compile_kwargs() == {}
     rng = np.random.default_rng(0)
     ids = rng.integers(0, 256, (8, 16)).astype("int32")
     labels = rng.integers(0, 256, (8, 16)).astype("int32")
     diffs, report = align_pretrain_configs(
-        cfg, ParallelConfig(), ParallelConfig(dp=2, mp=2),
+        cfg, ParallelConfig(), ParallelConfig(**hybrid),
         ids, labels, steps=2, rtol=2e-3, atol=2e-4)
     assert diffs == [], report
 

@@ -950,3 +950,166 @@ def test_smallthinker_expert_layer_compiles_and_its_yardstick_matches(
         # call is bound by its bank's bytes, not by its products
         flops, nbytes = held.cost(got, 1200.0)
         assert flops / 197e12 < nbytes / 819e9
+
+
+# ---- the dp 2 x mp 2 train step of mistral-7b-v0.3 (PR 44) ----
+# `PretrainStep` on a mesh of the DESCRIBED chips, the state and the batch
+# as shapes that carry its own shardings: the program of the cell
+# `mistral7b-train-dp2mp2`, two layers deep (the layers are a loop)
+
+def _abstract_train_step(topo, monkeypatch, layout, layers, batch,
+                         seq=4096, **widths):
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu import flags
+    from paddle_tpu.models.llama import LlamaConfig
+    from paddle_tpu.models.pretrain import (ParallelConfig, PretrainStep,
+                                            build_mesh)
+
+    monkeypatch.setattr(fa, "jax", _OnTpu())
+    # the tile probe would run the kernel: the cells turn it off too
+    monkeypatch.setitem(flags._VALUES, "autotune_enable", False)
+    cfg = LlamaConfig(**dict(dict(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=layers, num_attention_heads=32,
+        num_key_value_heads=8, max_position_embeddings=4096,
+        rms_norm_eps=1e-5, rope_theta=1e6, dtype="bfloat16"), **widths))
+    pc = ParallelConfig(remat=True, **layout)
+    mesh = build_mesh(pc, np.asarray(topo.devices))
+    ps = PretrainStep(cfg, pc, mesh=mesh)
+    shapes = {"embed": (cfg.vocab_size, cfg.hidden_size),
+              "head": (cfg.hidden_size, cfg.vocab_size),
+              "norm": (cfg.hidden_size,),
+              "blocks": ps._block_shapes()}
+    is_shape = lambda x: isinstance(x, tuple)             # noqa: E731
+    sh = ps._shardings(jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s, BF16), shapes, is_leaf=is_shape))
+    like = lambda dt: jax.tree_util.tree_map(             # noqa: E731
+        lambda s, at: jax.ShapeDtypeStruct(s, dt, sharding=at), shapes, sh,
+        is_leaf=is_shape)
+    state = {"params": like(BF16), "m": like(jnp.float32),
+             "v": like(jnp.float32),
+             "step": jax.ShapeDtypeStruct((), jnp.int32,
+                                          sharding=NamedSharding(mesh, P()))}
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp", None)))
+    return ps, state, ids
+
+
+@pytest.mark.timeout(600)
+def test_the_four_chip_train_step_starts_its_sums_and_runs_on(topo, one_chip,
+                                                              monkeypatch):
+    """Compiled as ``PretrainStep`` itself builds it for the 2 x 2 mesh, the
+    step holds asynchronous sums (start, the product it runs under, done):
+    of the activation sums (mp) two a backward-loop body and the head's;
+    of the dp gradient sums every weight matrix's in the loop, each its own
+    instruction.  What is left to stop the core is what has no independent
+    work inside one layer over one batch (the activation sums on the
+    critical path), vectors, and the head's and embedding's gradients
+    after the loop."""
+    from paddle_tpu.models.pretrain import _ASYNC_SUMS
+    from paddle_tpu.observability.collectives import find_all_reduces
+    ps, state, ids = _abstract_train_step(topo, monkeypatch, dict(dp=2, mp=2), 2, 4)
+    assert ps._compile_kwargs() == {"compiler_options": _ASYNC_SUMS}
+    compiled = ps.lowered_step(state, ids, ids).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4  # flash
+    found = find_all_reduces(text)
+    act = "bf16[2,4096,4096]"
+    started = [(entry, typ) for entry, is_async, typ in found if is_async]
+    assert started.count((False, act)) >= 2, found
+    assert (True, act) in started, found
+    # each leaf of a layer's weights: q, k, v, o, gate, up, down
+    weights = sorted(typ for entry, typ in started
+                     if not entry and typ.startswith("bf16[1,"))
+    assert weights == sorted(
+        ["bf16[1,4096,2048]", "bf16[1,4096,512]", "bf16[1,4096,512]",
+         "bf16[1,2048,4096]", "bf16[1,4096,7168]", "bf16[1,4096,7168]",
+         "bf16[1,7168,4096]"]), found
+    standing = [(entry, typ) for entry, is_async, typ in found
+                if not is_async]
+    assert not [typ for _, typ in standing if typ.startswith("(bf16[1,")], \
+        "a combined gradient sum is back in the loop: " + repr(standing)
+    in_loops = sorted(typ for entry, typ in standing if not entry)
+    assert in_loops == sorted([act] * 3 + ["bf16[4096]"]), found
+    assert ps.count_collectives(state, ids, ids) == \
+        (len(found), len(started))
+    # the pairs hold their operands a little longer: 13.8 GiB at depth 4
+    # was the parent's reckoning, a chip has 16
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12 << 30
+
+
+@pytest.mark.timeout(600)
+def test_the_one_chip_train_step_is_compiled_with_no_option(topo, one_chip,
+                                                            monkeypatch):
+    """One device has no collective: ``jax.jit`` is handed nothing, so the
+    program and its cache key stay what they were."""
+    from paddle_tpu.observability.collectives import find_all_reduces
+    ps, state, ids = _abstract_train_step(topo, monkeypatch, {}, 1, 1)
+    assert ps._compile_kwargs() == {}
+    compiled = ps.lowered_step(state, ids, ids).compile()
+    assert find_all_reduces(compiled.as_text()) == []
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 4
+
+
+def _sum_bytes(typ):
+    """Bytes of an all-reduce's result, a combined (tuple) one's together."""
+    import math
+    return sum(math.prod(int(d) for d in dims.split(",") if d)
+               * (2 if dt == "bf16" else 4)
+               for dt, dims in re.findall(r"(bf16|f32)\[([\d,]*)\]", typ))
+
+
+@pytest.mark.timeout(900)
+@pytest.mark.parametrize("layout,layers,batch,seq,widths", [
+    (dict(dp=2, ep=2), 2, 4, 4096,
+     dict(moe_num_experts=8, intermediate_size=3584)),
+    (dict(pp=2, micro_batches=2), 2, 2, 1000, {})],
+    ids=["moe_dp2_ep2", "pp2"])
+def test_other_layouts_sums_do_not_blow_up_under_the_options(
+        topo, one_chip, monkeypatch, layout, layers, batch, seq, widths):
+    """``_ASYNC_SUMS`` reaches EVERY step program on several TPU chips, and
+    was measured on the dense dp 2 x mp 2 one alone.  What a compile for the
+    described chips can say of two others (an expert-parallel MoE step: 8
+    experts of 3,584 a layer, router and vectors beside the banks; a
+    two-stage pipeline, whose stages pass activations by permutes), each
+    compiled without and with the options: the options ADD no sum (every
+    all-reduce with them is one member of a sum the plain compile holds,
+    alone or combined), the core stops in a loop for no more sums and no
+    more bytes than before, and the temporaries grow by less than a sixth.
+    No time is read here: a training cell of such a layout has to measure
+    the options again (ROADMAP S5)."""
+    from paddle_tpu.models.pretrain import _ASYNC_SUMS
+    from paddle_tpu.observability.collectives import find_all_reduces
+
+    def all_reduces_and_temporaries(options):
+        ps, state, ids = _abstract_train_step(
+            topo, monkeypatch, layout, layers, batch, seq, **widths)
+        assert ps._compile_kwargs() == {"compiler_options": _ASYNC_SUMS}
+        if not options:
+            monkeypatch.setattr(ps, "_compile_kwargs", lambda: {})
+        compiled = ps.lowered_step(state, ids, ids).compile()
+        return (find_all_reduces(compiled.as_text()),
+                compiled.memory_analysis().temp_size_in_bytes)
+
+    def standing(sums, in_loops):
+        """The synchronous ones' types: those in loop bodies, or all."""
+        return [typ for entry, is_async, typ in sums
+                if not is_async and not (in_loops and entry)]
+
+    plain, plain_temp = all_reduces_and_temporaries(options=False)
+    found, temp = all_reduces_and_temporaries(options=True)
+    assert plain and len(standing(plain, False)) == len(plain)
+    assert len(found) == sum(typ.count("[") for _, _, typ in plain), \
+        (plain, found)
+    for in_loops in (True, False):
+        assert sum(map(_sum_bytes, standing(found, in_loops))) <= \
+            sum(map(_sum_bytes, standing(plain, in_loops))), (plain, found)
+    assert len(standing(found, True)) <= len(standing(plain, True)), \
+        (plain, found)
+    assert temp < plain_temp * 7 / 6, (plain_temp, temp)
+    if "moe_num_experts" in widths:
+        # the banks' gradient sums are the bytes: they run under products
+        assert sum(is_async for _, is_async, _ in found) >= 8, found
