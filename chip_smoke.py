@@ -163,6 +163,46 @@ def phase_kernels(sz: Sizes, seed: int, on_tpu: bool) -> None:
         paged[f"T={T}"] = {"max_abs_diff": diff, "max_abs_ref": mag}
     t_paged = time.perf_counter() - t0
 
+    # the delta-rule call of a linear-attention place (kernels/kda.py) at
+    # Solar-Open2's mixer widths: decode slots, a chunk, an idle slot and a
+    # fresh one, packed; against its float32 oracle
+    from paddle_tpu.kernels import kda
+    kh, kd, kchunk = (2, 16, 8) if sz.rehearse else (64, 128, 64)
+    kql = np.asarray([1, 0, kchunk, 1, 3, 1, 1, 1], np.int32)
+    krows = 64 if sz.rehearse else 256
+    f32 = jnp.float32
+
+    def unit(*shape):
+        x = jnp.asarray(rng.standard_normal(shape), f32)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    kargs = (jnp.asarray(rng.standard_normal((len(kql), kh, kd, kd)), f32),
+             (unit(krows, kh, kd) * kd ** -0.5).astype(dt),
+             unit(krows, kh, kd), jnp.asarray(
+                 rng.standard_normal((krows, kh, kd)), f32),
+             -jnp.exp(jnp.asarray(
+                 rng.standard_normal((krows, kh, kd)) * 2 - 2, f32)),
+             2 * jax.nn.sigmoid(jnp.asarray(
+                 rng.standard_normal((krows, kh)), f32)),
+             jnp.asarray(np.cumsum(kql) - kql, jnp.int32), jnp.asarray(kql),
+             jnp.asarray(np.arange(len(kql)) == 4))
+    low = jax.jit(lambda *a: kda.ragged_kda_update(*a, chunk=kchunk)) \
+        .lower(*kargs)
+    if on_tpu:
+        check("tpu_custom_call" in low.as_text(),
+              "kda: no Pallas kernel in the lowered program")
+    got_o, got_s = low.compile()(*kargs)
+    want_o, want_s = jax.jit(lambda *a: kda._reference_ragged_kda_update(
+        *a, kchunk))(*kargs)
+    kda_out = {}
+    for name, a, b in (("o", got_o, want_o), ("state", got_s, want_s)):
+        diff, mag = rel_err(a, b)
+        check(diff <= PAGED_TOL * max(1.0, mag),
+              f"kda {name}: max|diff| {diff} vs max|ref| {mag}")
+        kda_out[name] = {"max_abs_diff": diff, "max_abs_ref": mag}
+    check(bool(jnp.array_equal(got_s[1], kargs[0][1])),
+          "kda: an idle slot's state moved")
+
     # flash fwd + bwd at the train step's shapes
     S, Bt = sz.seq, sz.train_batch
     q, k, v = rnd(Bt, S, qh, d), rnd(Bt, S, kvh, d), rnd(Bt, S, kvh, d)
@@ -199,6 +239,8 @@ def phase_kernels(sz: Sizes, seed: int, on_tpu: bool) -> None:
                        "cache": [kvh, n_pages, page, d], "block_table": [B, W]},
          paged=paged, paged_tol=PAGED_TOL, paged_seconds=round(t_paged, 2),
          paged_tiles={"page_size": page},
+         kda_shapes={"tokens": [krows, kh, kd], "slots": len(kql),
+                     "chunk": kchunk}, kda=kda_out,
          flash_shapes={"q": [Bt, S, qh, d], "kv": [Bt, S, kvh, d],
                        "causal": True},
          flash=flash, flash_tol=FLASH_TOL,

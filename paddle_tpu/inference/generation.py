@@ -41,7 +41,11 @@ Structure — ONE jitted step function serves every serving phase:
   with it).  The layer scan CARRIES that state and each layer's
   ``ragged_ssd_update`` call updates its slots in place; a slot's state is
   zeroed on the device by the step that runs its first chunk and is not
-  touched by a step in which the slot has no work.
+  touched by a step in which the slot has no work.  A place whose token
+  mixer is a recurrence INSTEAD of attention (``LayerKind.linear``, gated
+  delta-rule linear attention: ``kernels/kda.py``) keeps such a state and
+  NO pages: the pool and the commit count the layers that have pages, the
+  recurrent state those that have a state (``kv_cache.LayerPlanes``).
 - EOS / budget / capacity tracking lives ON DEVICE (``finished``,
   ``gen_counts``, ``budgets``): the host loop is one async jit dispatch
   per step.  Each dispatch starts the copy of ITS OWN results to the
@@ -86,10 +90,12 @@ from ..kernels.paged_pool_writes import (write_kv_pages_all_layers,
                                          write_latent_pages_all_layers)
 from ..kernels.latent_index import latent_index_scores, latent_index_select
 from ..kernels.rms_norm import layer_norm_fp32, rms_norm_fp32
+from ..kernels.kda import (kda_geometry_error, l2_normalised,
+                           ragged_kda_update, rows_of_slots)
 from ..kernels.ssd import ragged_ssd_update
 from ..models.decoder_spec import EXPERT_BANKS
 from . import speculative as _sp
-from .kv_cache import PagedKVCache, RecurrentState
+from .kv_cache import LayerPlanes, PagedKVCache, RecurrentState
 
 # The serving tensor-parallel mesh axis (FLAGS_serving_tensor_parallel).
 # Every axis-name string reaching a shard_map-wrapped body must come
@@ -515,7 +521,7 @@ class LlamaGenerator:
             raise ValueError(
                 "tensor_parallel > 1 shards the pool by KV head; a latent "
                 "pool has none (inference/kv_cache.py)")
-        if tp > 1 and c.ssm is not None:
+        if tp > 1 and c.state_mixer is not None:
             raise ValueError(
                 "tensor_parallel > 1 shards the pool by KV head; the "
                 "recurrent state beside it has no sharded layout "
@@ -606,40 +612,52 @@ class LlamaGenerator:
                 raise ValueError(
                     f"engine geometry is not served by the paged-attention "
                     f"kernel on TPU: {why}")
+        if jax.default_backend() == "tpu" and c.linear is not None:
+            why = kda_geometry_error(c.linear.heads, c.linear.key_dim,
+                                     c.linear.value_dim)
+            if why:
+                raise ValueError(
+                    f"the linear-attention places are not served by the "
+                    f"delta-rule kernel on TPU: {why}")
         if c.leading and str(cache_dtype or dtype) == "int8" \
                 and la is None:
             raise ValueError("an int8 pool's scale planes are scanned by "
                              "whole periods: no leading layers")
-        if c.ssm is not None and str(cache_dtype or dtype) == "int8":
+        if c.state_mixer is not None and str(cache_dtype or dtype) == "int8":
             raise ValueError(
                 "inference/kv_cache.py: kv_cache_dtype int8 quantises "
                 "pages; a stack with a recurrent state is served with a "
                 "float pool (auto, bf16 or fp32)")
-        # a uniform pool: every layer keeps every page, whatever its window
-        # (pages behind a sliding layer's window are held and never read);
-        # a latent stack's holds one row a token a layer, no head axis
+        # a uniform pool over the layers that keep pages: each keeps every
+        # page, whatever its window (pages behind a sliding layer's window
+        # are held and never read); a latent stack's holds one row a token
+        # a layer, no head axis.  A linear-attention place keeps none: its
+        # layers own no plane of the pool (``LayerPlanes``)
+        self.planes = LayerPlanes.of(c)
         latent = None if la is None else (la.rank, la.rope)
         # a learned index keeps one key a token a layer beside that row
         index = None if c.index is None else c.index.dim
-        # what a slot holds besides pages (a state-space mixer's state and
-        # convolution rows): fixed, by slot, riding with the pool
+        # what a slot holds besides pages (a mixer's state and convolution
+        # rows, over the layers that have one): fixed, by slot, riding with
+        # the pool
         with _startup.phase("startup.pool_alloc", pages=self.num_pages):
-            recurrent = None if c.ssm is None else RecurrentState(
-                c.ssm, c.num_layers, max_batch, dtype)
+            recurrent = None if c.state_mixer is None else RecurrentState(
+                c.state_mixer, c.state_layers, max_batch, dtype)
             self.cache = PagedKVCache(
-                num_layers=c.num_layers,
+                num_layers=c.page_layers,
                 num_pages=self.num_pages,
                 page_size=page_size, num_kv_heads=c.num_kv_heads,
                 head_dim=c.head_dim, dtype=cache_dtype or dtype,
                 mesh=self.mesh, axis=MP_AXIS, latent=latent,
                 recurrent=recurrent, index=index)
         self.state_bytes_per_slot = 0 if recurrent is None else \
-            RecurrentState.bytes_per_slot(c.ssm, c.num_layers, dtype)
+            RecurrentState.bytes_per_slot(c.state_mixer, c.state_layers,
+                                          dtype)
         # host-global pool bytes (all shards) — advertised via stats() /
         # /statusz so the router's capacity-weighted placement can rank
         # heterogeneous replicas
         self.pool_bytes = self.num_pages * PagedKVCache.bytes_per_page(
-            c.num_layers, c.num_kv_heads, page_size,
+            c.page_layers, c.num_kv_heads, page_size,
             c.head_dim, cache_dtype or dtype, latent=latent, index=index)
         # what ONE descriptor of the paged call moves: a page's K and V of
         # every KV head this shard holds, one layer (a latent call copies a
@@ -887,7 +905,7 @@ class LlamaGenerator:
         B, T = tokens.shape
         page = self.page_size
         ssm_state = conv_state = None
-        if c.ssm is not None:
+        if c.state_mixer is not None:
             # the slots' recurrent state rides last (``PagedKVCache.arrays``)
             *cache, ssm_state, conv_state = cache
         quant = len(cache) == 3
@@ -1023,10 +1041,16 @@ class LlamaGenerator:
             attn = jnp.einsum("abhr,rhd->abhd", u, w_uv).reshape(R0, R1, -1)
             return attn @ lp["self_attn.o_proj.weight"], c_new, r_new
 
-        if c.ssm is not None:
+        if c.state_mixer is not None:
             # a slot whose first chunk this is: what the last request left
             # in its recurrent state counts as zero
             fresh = jnp.logical_and(positions == 0, ql > 0)
+        if c.linear is not None:
+            # where each slot's tokens lie among the rows the per-token work
+            # runs over: the packed rows, or the [B, T] grid row by row
+            row_start = (jnp.cumsum(ql) - ql) if packed \
+                else jnp.arange(B, dtype=jnp.int32) * T
+            row_slot, row_t, _ = rows_of_slots(row_start, ql, R0 * R1)
 
         def ssm_mixer(y, lp, mx, layer, state, carried):
             """A place's state-space mixer on the normed input ``y``, beside
@@ -1082,6 +1106,96 @@ class LlamaGenerator:
             return _scaled(g @ lp["mamba.out_proj.weight"], mx.out_scale), \
                 state, carried
 
+        def delta_mixer(y, lp, mx, layer, state, carried):
+            """A linear place's token mixer (``DeltaMixer``) on the normed
+            input ``y``, in attention's stead: (the branch's output, the
+            whole state with this layer's slots updated in place, this
+            layer's new convolution rows).  EVERYTHING runs over the rows
+            the per-token work runs over, the convolution and the
+            recurrence too: the kernel takes the step's tokens packed and
+            finds each slot's by ``row_start`` and ``ql``, so no ``[B, T]``
+            grid of its operands is written out.  A slot without work
+            keeps its state and its rows."""
+            f32 = jnp.float32
+            R, n = R0 * R1, mx.conv - 1
+            hk, hv = mx.heads * mx.key_dim, mx.heads * mx.value_dim
+            y = y.reshape(R, -1)
+            qkv = y @ lp["linear_attn.qkv_proj.weight"]    # [R, q | k | v]
+            with jax.named_scope("conv"):
+                # token t of a slot reads its own row and the conv - 1
+                # before it: rows of this step where the slot has them,
+                # else the slot's carried ones
+                carried = jnp.where(fresh[:, None, None],
+                                    jnp.zeros((), carried.dtype), carried)
+                w = lp["linear_attn.conv1d.weight"].astype(f32)  # [conv, C]
+                old = carried.astype(f32)
+                acc = w[n] * qkv.astype(f32)
+                if T == 1:
+                    # a decode step: row b is slot b's one token, which
+                    # reads its own row and the slot's carried ones (the
+                    # gathers and the scatter below were 18 % of a decode
+                    # step of the long-generation cell: PERF.md section 6)
+                    acc = acc + sum(w[j] * old[:, j] for j in range(n))
+                    carried = jnp.where(
+                        (ql > 0)[:, None, None], jnp.concatenate(
+                            [carried[:, 1:], qkv[:, None]], axis=1), carried)
+                else:
+                    for back in range(1, n + 1):
+                        acc = acc + w[n - back] * jnp.where(
+                            (row_t >= back)[:, None],
+                            jnp.roll(qkv, back, axis=0),
+                            jnp.zeros((), qkv.dtype)).astype(f32)
+                    # a slot's first conv - 1 tokens read carried rows too:
+                    # token t the rows t .. conv - 2, under the first taps
+                    heads_of = jnp.stack([sum(
+                        w[j - t] * old[:, j] for j in range(t, n))
+                        for t in range(n)], axis=1)            # [B, n, C]
+                    first = jnp.arange(n, dtype=jnp.int32)[None, :]
+                    acc = acc.at[jnp.where(first < ql[:, None],
+                                           row_start[:, None] + first,
+                                           R).reshape(-1)].add(
+                        heads_of.reshape(B * n, -1), mode="drop")
+                    # the last conv - 1 rows of what the slot has now seen
+                    at = ql[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+                    carried = jnp.where(
+                        (at >= n)[:, :, None],
+                        jnp.take(qkv, jnp.clip(row_start[:, None] + at - n,
+                                               0, R - 1), axis=0),
+                        jnp.take_along_axis(carried, jnp.clip(
+                            at, 0, n - 1)[:, :, None], axis=1))
+                mixed = jax.nn.silu(acc).astype(qkv.dtype)
+
+            q = l2_normalised(mixed[:, :hk].reshape(
+                R, mx.heads, mx.key_dim)) * mx.key_dim ** -0.5
+            k = l2_normalised(mixed[:, hk:2 * hk].reshape(
+                R, mx.heads, mx.key_dim))
+            v = mixed[:, 2 * hk:].reshape(R, mx.heads, mx.value_dim)
+            with jax.named_scope("gates"):
+                fa = (y @ lp["linear_attn.f_a_proj.weight"]) \
+                    @ lp["linear_attn.f_b_proj.weight"]
+                g = -jnp.exp(lp["linear_attn.A_log"].astype(f32))[
+                    None, :, None] * jax.nn.softplus(
+                    fa.astype(f32).reshape(R, mx.heads, mx.key_dim)
+                    + lp["linear_attn.dt_bias"].astype(f32).reshape(
+                        mx.heads, mx.key_dim))
+                beta = jax.nn.sigmoid(
+                    (y @ lp["linear_attn.b_proj.weight"]).astype(f32)) \
+                    * (2.0 if mx.neg_eigval else 1.0)
+                og = (y @ lp["linear_attn.g_a_proj.weight"]) \
+                    @ lp["linear_attn.g_b_proj.weight"]
+            with jax.named_scope("kda"):
+                o, state = ragged_kda_update(
+                    state, q.astype(y.dtype), k, v, g, beta, row_start, ql,
+                    fresh, chunk=T, layer=layer)
+            # RMS-normed a head with a learned weight, then the gate
+            o = o.astype(f32)
+            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
+                                  + c.norm_eps) \
+                * lp["linear_attn.o_norm.weight"].astype(f32)
+            o = (o.reshape(R, hv) * jax.nn.sigmoid(og.astype(f32))) \
+                .astype(y.dtype).reshape(R0, R1, hv)
+            return o @ lp["linear_attn.o_proj.weight"], state, carried
+
         # the router reads the attention's normed input: a layer makes its
         # choice before the attention call and hands it past it
         routes_early = moe is not None and moe.router_input == "attention" \
@@ -1111,15 +1225,28 @@ class LlamaGenerator:
                            c.mlp_out_scale), None
 
         def one_layer(x, lp, kind, layer, ksl, vsl, bank_layer,
-                      state=None, conv_rows=None):
-            """Decoder layer number ``layer``, of ``kind``, reading the pool
-            (READ-ONLY; the kernel takes the whole pool and the layer, no
-            layer is sliced out of it): (x, this step's k, v, MoE rows, and
-            where the place has a state-space mixer the whole recurrent
-            state with this layer's updated in place and the layer's new
-            convolution rows, else None twice).
+                      state=None, conv_rows=None, state_layer=None):
+            """A decoder layer of ``kind`` whose plane of the pool is
+            ``layer`` (READ-ONLY; the kernel takes the whole pool and the
+            layer, no layer is sliced out of it) and whose plane of the
+            recurrent state is ``state_layer``: (x, this step's k, v (None
+            twice for a linear place, which keeps no pages), MoE rows, and
+            where the place has a mixer the whole recurrent state with this
+            layer's updated in place and the layer's new convolution rows,
+            else None twice).
             ``bank_layer``: None, or ``lp``'s expert banks are its place's
             unstacked layers and this layer is that one of them."""
+            if kind.linear is not None:
+                with jax.named_scope("linear_attn"):
+                    y = norm_fn(x, lp["input_layernorm.weight"], c.norm_eps)
+                    a, state, conv_rows = delta_mixer(
+                        y, lp, kind.linear, state_layer, state, conv_rows)
+                    x = x + a
+                with jax.named_scope("moe" if moe is not None else "mlp"):
+                    y = norm_fn(x, lp["post_attention_layernorm.weight"],
+                                c.norm_eps)
+                    f, n_rows = ffn(y, lp, kind, bank_layer)
+                return x + f, None, None, n_rows, state, conv_rows
             if kind.latent is not None:
                 with jax.named_scope("attention"):
                     a, k, v = latent_attention(x, lp, kind.latent, layer,
@@ -1181,14 +1308,19 @@ class LlamaGenerator:
                 attn = attn.reshape(B, T, -1)
                 if packed:
                     attn = pack(attn)
+                if kind.out_gate:
+                    with jax.named_scope("out_gate"):
+                        attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(
+                            (ya @ lp["self_attn.g_proj.weight"]).astype(
+                                jnp.float32))).astype(attn.dtype)
                 a = _scaled(attn @ lp["self_attn.o_proj.weight"],
                             c.attn_out_scale)
                 if not c.parallel_block:
                     x = x + a
             if kind.ssm is not None:
                 with jax.named_scope("ssm"):
-                    s, state, conv_rows = ssm_mixer(y, lp, kind.ssm, layer,
-                                                    state, conv_rows)
+                    s, state, conv_rows = ssm_mixer(
+                        y, lp, kind.ssm, state_layer, state, conv_rows)
                     x = x + s
             with jax.named_scope("moe" if moe is not None else "mlp"):
                 if not c.parallel_block:     # else the FFN reads the same y
@@ -1202,10 +1334,15 @@ class LlamaGenerator:
         # layers unrolled inside; what is scanned is one [periods, ...]
         # stack a place (and the int8 scale planes, split [periods, places])
         P = len(c.pattern)
+        # a place's plane among its period's planes of the pool, and of the
+        # recurrent state (a linear place has none of the first, a place
+        # without a mixer none of the second)
+        page_of = {p: i for i, p in enumerate(c.page_places)}
+        state_of = {p: i for i, p in enumerate(c.state_places)}
 
-        def by_period(a):
+        def by_period(a, places=P):
             return None if a is None else \
-                a.reshape((c.periods, P) + a.shape[1:])
+                a.reshape((c.periods, places) + a.shape[1:])
 
         # a scanned stack is sliced, and a slice that feeds a custom call
         # (``gmm``) is written out first: a copy of the layer's expert banks
@@ -1228,15 +1365,24 @@ class LlamaGenerator:
                 layer = r * P + p
                 if c.leading:
                     layer = layer + len(c.leading)
+                # where every place keeps pages (a state) the layer's
+                # number is its plane of the pool (of the state)
+                state_layer = layer if len(state_of) == P else \
+                    r * len(state_of) + state_of.get(p, 0)
+                if len(page_of) != P:
+                    layer = r * len(page_of) + page_of.get(p, 0)
+                mixes = bool(state) and p in state_of
                 x, k, v, n, *mixed = one_layer(
                     x, {**blocks[p], **unstacked[p]}, kind, layer,
                     None if ksp is None else ksp[p],
                     None if vsp is None else vsp[p],
                     r if unstacked[p] else None,
-                    *((state[0], convp[p]) if state else ()))
-                ks_new.append(k)
-                vs_new.append(v)
-                if state:
+                    *((state[0], convp[state_of[p]], state_layer)
+                      if mixes else ()))
+                if p in page_of:
+                    ks_new.append(k)
+                    vs_new.append(v)
+                if mixes:
                     state = [mixed[0]]
                     conv_new.append(mixed[1])
                 if n is not None:
@@ -1252,7 +1398,8 @@ class LlamaGenerator:
             lead_k.append(k)
             lead_v.append(v)
         xs = (jnp.arange(c.periods, dtype=jnp.int32), scanned,
-              by_period(ks), by_period(vs), by_period(conv_state))
+              by_period(ks), by_period(vs),
+              by_period(conv_state, len(state_of)))
         carry = (h,) if ssm_state is None else (h, ssm_state)
         if c.periods == 1:
             # nothing to scan over: the one period runs in line on the
@@ -1268,7 +1415,7 @@ class LlamaGenerator:
         h = carry[0]
         if moe_rows is not None:
             moe_rows = moe_rows.sum(axis=0)        # over the periods
-        L = c.num_layers
+        L = c.page_layers
         if c.latent is not None:
             # [periods, places, B, T, width] -> [layers, B * T, width], the
             # leading layers' rows first
@@ -1303,6 +1450,13 @@ class LlamaGenerator:
                 k_all, shard * kvh_l, kvh_l, axis=2)
             v_all = jax.lax.dynamic_slice_in_dim(
                 v_all, shard * kvh_l, kvh_l, axis=2)
+        if len(page_of) != P:
+            # the commit waits for the last layer: where the LAST layers
+            # keep no pages, the page layers' fresh rows depend on no call
+            # that reads the pool, and without an order between that read
+            # and this write XLA copies the whole pool in and out of the
+            # commit's loop (compiled for a described v5e, PR 46)
+            h, kc = jax.lax.optimization_barrier((h, kc))
         with jax.named_scope("attention"), jax.named_scope("kv_write"):
             if quant:
                 # quantize fresh K/V per page on the way in (page-level
@@ -1877,7 +2031,7 @@ class ContinuousBatchingEngine:
         # through EVERY step (prefill commits update it too), so the
         # verify step's context is exact when the row reaches decode
         self._track_recent = self._recent is not None
-        if self.g.spec.ssm is not None:
+        if self.g.spec.state_mixer is not None:
             # what cannot follow a recurrent state is refused now
             if prefix_cache:
                 raise ValueError(
@@ -2204,7 +2358,7 @@ class ContinuousBatchingEngine:
                 self._obs.index_pairs.observe(float(counts["index_pairs"]))
                 self._obs.selected_keys.observe(
                     float(counts["selected_keys"]))
-            if g.spec.ssm is not None:
+            if g.spec.state_mixer is not None:
                 # the slots whose recurrent state the step's scan calls
                 # read and write (those with work), and the tokens they scan
                 span.set_metadata(ssm_slots=int((ql > 0).sum()),
@@ -2913,7 +3067,7 @@ class ContinuousBatchingEngine:
                     self._obs.queue_wait.observe(
                         (now - req.t_enqueue) * 1e3)
             self._obs.queue_now.set(len(self.waiting))
-            if g.spec.ssm is not None:
+            if g.spec.state_mixer is not None:
                 self._obs.state_resets.inc(len(admitted))
         for b, req in admitted:
             self.slot_req[b] = req

@@ -31,6 +31,7 @@ free-list append, and releasing a page that is already free raises.
 
 from __future__ import annotations
 
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -310,22 +311,51 @@ class PageAllocator:
         return np.asarray([self._lens[s] for s in seq_ids], np.int32)
 
 
+@dataclass(frozen=True)
+class LayerPlanes:
+    """Which plane of the pool, and of the recurrent state, each layer of a
+    stack owns: two kinds of cache, each over its own layers.  ``pages[l]``
+    is layer ``l``'s index on the pool's first axis, None where the layer
+    keeps no pages (a linear-attention place); ``state[l]`` its index on the
+    recurrent state's first axis, None where it keeps none.  Made once from
+    the spec; where every layer keeps pages ``pages[l] == l``."""
+    pages: Tuple[Optional[int], ...]
+    state: Tuple[Optional[int], ...]
+
+    @classmethod
+    def of(cls, spec) -> "LayerPlanes":
+        lead = len(spec.leading)
+        page_of = {p: i for i, p in enumerate(spec.page_places)}
+        state_of = {p: i for i, p in enumerate(spec.state_places)}
+        pages, state = list(range(lead)), [None] * lead
+        for r in range(spec.periods):
+            for p in range(len(spec.pattern)):
+                pages.append(lead + r * len(page_of) + page_of[p]
+                             if p in page_of else None)
+                state.append(r * len(state_of) + state_of[p]
+                             if p in state_of else None)
+        return cls(tuple(pages), tuple(state))
+
+
 class RecurrentState:
-    """What a slot holds besides pages where the stack has a state-space
-    mixer (``models.decoder_spec.SsmMixer``): fixed in size, indexed by
-    SLOT and never by page, so no allocator addresses it.  Two arrays: the
-    scan's state ``ssm [layers, slots, heads, head_dim, state]`` in float32
-    (in bf16 a term under 2^-8 of the state's size would be lost at every
-    token) and the convolution's carried rows ``conv [layers, slots,
-    conv - 1, conv_width]`` in the model's type.  A slot's state is zeroed
+    """What a slot holds besides pages where layers of the stack keep a
+    recurrent state (``models.decoder_spec.SsmMixer`` beside attention, or a
+    ``DeltaMixer`` in its stead): fixed in size, indexed by SLOT and never
+    by page, so no allocator addresses it.  Two arrays over the
+    ``num_layers`` layers that HAVE a state (``LayerPlanes.state``), shaped
+    by the mixer: the recurrence's state ``ssm [layers, slots,
+    *mixer.state_shape]`` in float32 (in bf16 a term under 2^-8 of the
+    state's size would be lost at every token) and the convolution's carried
+    rows ``conv [layers, slots, conv - 1, conv_width]`` in the model's type.
+    A slot's state is zeroed
     on the device by the step that runs its first chunk; nothing here is
     copied, spilled or snapshotted (the prefix cache, the spill tier and
     migration refuse a stack that has one)."""
 
     def __init__(self, mixer, num_layers: int, slots: int, dtype):
         self.mixer = mixer
-        self.ssm = jnp.zeros((num_layers, slots, mixer.heads,
-                              mixer.head_dim, mixer.state), jnp.float32)
+        self.ssm = jnp.zeros((num_layers, slots) + tuple(mixer.state_shape),
+                             jnp.float32)
         self.conv = jnp.zeros((num_layers, slots, mixer.conv - 1,
                                mixer.conv_width), jnp.dtype(dtype))
 
@@ -344,11 +374,13 @@ class RecurrentState:
 
 
 class PagedKVCache:
-    """Device KV pool for all layers + the allocator that addresses it.
+    """Device KV pool for the layers that keep pages + the allocator that
+    addresses it.
 
     The pool is ``kv [layers, num_pages, 2, kv_heads, page_size,
     head_dim]`` (a page's K and V of every head together: the unit the
-    kernel copies); ``.arrays`` is ``(kv,)``, and ``page_axes`` /
+    kernel copies; ``layers`` counts the layers that HAVE pages, every layer
+    but a linear-attention place's: ``LayerPlanes.pages``); ``.arrays`` is ``(kv,)``, and ``page_axes`` /
     ``head_axes`` say, array by array, where pages and KV heads are
     counted, so that whoever copies, spills or snapshots a page
     (``page_planes``) never restates the layout.

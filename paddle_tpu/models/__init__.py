@@ -7,7 +7,7 @@ sharding plan over the hybrid mesh (dp/mp/pp/sep axes).
 """
 
 from . import (cohere2_moe, deepseek_v32, dit, falcon_h1, gpt,  # noqa: F401
-               llama, sarvam_mla, smallthinker)
+               llama, sarvam_mla, smallthinker, solar_open2)
 from .deepseek_v32 import (DeepseekV32Config,  # noqa: F401
                            DeepseekV32ForCausalLM)
 from .cohere2_moe import Cohere2MoeConfig, CohereMoeForCausalLM  # noqa: F401
@@ -20,3 +20,5 @@ from .gpt import GPTConfig, GPTForCausalLM  # noqa: F401
 from .sarvam_mla import SarvamMlaConfig, SarvamMlaForCausalLM  # noqa: F401
 from .smallthinker import (SmallThinkerConfig,  # noqa: F401
                            SmallThinkerForCausalLM)
+from .solar_open2 import (SolarOpen2Config,  # noqa: F401
+                          SolarOpen2ForCausalLM)

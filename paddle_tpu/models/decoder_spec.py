@@ -23,6 +23,11 @@ experts (``dispatch``, ``block_m``), which tensor the router reads
 (``router_input``: the FFN's normed input, or the attention's) and the
 experts' gated activation (``activation``: SiLU or ReLU).
 
+A place's token mixer may be a recurrence INSTEAD of attention
+(``LayerKind.linear``, a ``DeltaMixer``): such a place keeps no pages, only a
+fixed state a slot, and ``DecoderSpec.page_places`` / ``state_places`` say
+which places of a period hold which.
+
 A place's expert banks (``EXPERT_BANKS``) may come unstacked instead: a tuple
 of ``periods`` arrays ``[E, ...]``, one a layer.  The grouped GEMMs that read
 them are custom calls, for which a layer sliced out of a stack is written
@@ -131,10 +136,62 @@ class SsmMixer:
     def in_width(self) -> int:
         return self.inner + self.conv_width + self.heads
 
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        """One slot's float32 state in one layer."""
+        return self.heads, self.head_dim, self.state
+
     def state_bytes(self, dtype) -> int:
         """Bytes a slot holds for ONE layer: the float32 state and the
         carried convolution rows in ``dtype``."""
         return 4 * self.heads * self.head_dim * self.state \
+            + (self.conv - 1) * self.conv_width * np.dtype(dtype).itemsize
+
+
+@dataclass(frozen=True)
+class DeltaMixer:
+    """Gated delta-rule linear attention with a decay a CHANNEL (Kimi Delta
+    Attention) that is a place's token mixer IN ATTENTION'S STEAD, on the
+    normed input ``u``: ``[q | k | v] = W_qkv u`` (``heads`` of ``key_dim``
+    twice, of ``value_dim`` once), a causal depthwise convolution of
+    ``conv`` taps without bias over all of it (so a slot carries its last
+    ``conv - 1`` rows), SiLU; ``q`` and ``k`` L2-normalised a head, ``q``
+    times ``key_dim^-0.5``; the log decay ``a_t = -exp(A_log[h]) x
+    softplus(W_fb (W_fa u) + dt_bias)`` a channel of the key (through a
+    rank of ``gate_rank``), ``beta_t = sigmoid(W_b u)`` a head, doubled
+    with ``neg_eigval`` (the transition's eigenvalues then reach (-1, 1));
+    per head the float32 state ``S`` (``key_dim x value_dim``), zero at
+    position 0 (``kernels/kda.py``):
+
+        S' = diag(exp(a_t)) S_{t-1}
+        S_t = S' + beta_t k_t (v_t - S'^T k_t)^T        o_t = S_t^T q_t
+
+    ``RMSNorm_head(o_t)`` (a learned weight of ``value_dim``) times
+    ``sigmoid(W_gb (W_ga u))`` (through ``gate_rank`` too), then ``W_o``.
+
+    What a slot holds for it, and it holds NO pages: the float32 state
+    ``[heads, key_dim, value_dim]`` and the ``conv - 1`` rows, a layer."""
+    heads: int
+    key_dim: int
+    value_dim: int
+    conv: int
+    gate_rank: int
+    neg_eigval: bool = False
+
+    @property
+    def conv_width(self) -> int:
+        """Width of what is convolved: ``[q | k | v]``."""
+        return self.heads * (2 * self.key_dim + self.value_dim)
+
+    @property
+    def state_shape(self) -> Tuple[int, int, int]:
+        """One slot's float32 state in one layer."""
+        return self.heads, self.key_dim, self.value_dim
+
+    def state_bytes(self, dtype) -> int:
+        """Bytes a slot holds for ONE layer: the float32 state and the
+        carried convolution rows in ``dtype``."""
+        return 4 * self.heads * self.key_dim * self.value_dim \
             + (self.conv - 1) * self.conv_width * np.dtype(dtype).itemsize
 
 
@@ -156,6 +213,12 @@ class LayerKind:
     # a learned index over the latent pool: the attention reads the keys it
     # chooses alone
     index: Optional[LatentIndex] = None
+    # the token mixer is this recurrence and NOT attention: the place has
+    # no q/k/v pages (``window``, ``rope``, ``latent`` say nothing of it)
+    linear: Optional[DeltaMixer] = None
+    # per-head attention's result times ``sigmoid(W_g u)``, elementwise,
+    # before ``W_o``
+    out_gate: bool = False
 
 
 @dataclass(frozen=True)
@@ -182,6 +245,42 @@ class RopeYarn:
         """What cos and sin are multiplied by."""
         return self._mscale(self.factor, self.mscale) \
             / self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def linear(self) -> Optional[DeltaMixer]:
+        """The stack's linear-attention mixer (every linear place's alike),
+        or None."""
+        return next((k.linear for k in self.pattern
+                     if k.linear is not None), None)
+
+    @property
+    def state_mixer(self):
+        """The mixer whose state a slot holds besides its pages (a
+        ``SsmMixer`` or a ``DeltaMixer``), or None: the stack has none."""
+        return self.ssm if self.ssm is not None else self.linear
+
+    @property
+    def page_places(self) -> Tuple[int, ...]:
+        """The places of a period that keep pages (all but the linear
+        ones), in order."""
+        return tuple(p for p, k in enumerate(self.pattern)
+                     if k.linear is None)
+
+    @property
+    def state_places(self) -> Tuple[int, ...]:
+        """The places of a period that keep a recurrent state, in order."""
+        return tuple(p for p, k in enumerate(self.pattern)
+                     if k.ssm is not None or k.linear is not None)
+
+    @property
+    def page_layers(self) -> int:
+        """Layers that keep pages: what the pool's first axis counts."""
+        return len(self.leading) + self.periods * len(self.page_places)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state."""
+        return self.periods * len(self.state_places)
 
     @property
     def softmax_scale(self) -> float:
@@ -339,6 +438,31 @@ class DecoderSpec:
             raise ValueError("a state-space mixer is served beside per-head "
                              "attention in a scanned stack with sequential "
                              "residuals only")
+        linear = {k.linear for k in self.leading + self.pattern} - {None}
+        if len(linear) > 1 or (linear and self.ssm is not None):
+            raise ValueError("one recurrent state serves every layer that "
+                             "has one: two state shapes cannot share a "
+                             "stack")
+        if linear and (self.latent is not None or self.parallel_block
+                       or any(k.linear is not None for k in self.leading)):
+            raise ValueError("a linear-attention place is served among "
+                             "per-head attention places in a scanned stack "
+                             "with sequential residuals only: no latent "
+                             "place beside it, no parallel block, not among "
+                             "the leading layers")
+        if linear and self.moe is not None \
+                and self.moe.router_input == "attention":
+            raise ValueError("a router that reads the attention's input is "
+                             "served where every place attends: not beside "
+                             "a linear-attention place")
+        if linear and not self.page_places:
+            raise ValueError("a stack of linear-attention places alone has "
+                             "no pool to page: at least one place of the "
+                             "period keeps pages")
+        if any(k.out_gate and (k.latent is not None or k.linear is not None)
+               for k in self.leading + self.pattern):
+            raise ValueError("an output gate is served on per-head "
+                             "attention only")
 
     @property
     def num_layers(self) -> int:
@@ -359,6 +483,42 @@ class DecoderSpec:
         """The stack's state-space mixer (every layer's alike), or None:
         whether a slot holds a recurrent state besides its pages."""
         return self.pattern[0].ssm
+
+    @property
+    def linear(self) -> Optional[DeltaMixer]:
+        """The stack's linear-attention mixer (every linear place's alike),
+        or None."""
+        return next((k.linear for k in self.pattern
+                     if k.linear is not None), None)
+
+    @property
+    def state_mixer(self):
+        """The mixer whose state a slot holds besides its pages (a
+        ``SsmMixer`` or a ``DeltaMixer``), or None: the stack has none."""
+        return self.ssm if self.ssm is not None else self.linear
+
+    @property
+    def page_places(self) -> Tuple[int, ...]:
+        """The places of a period that keep pages (all but the linear
+        ones), in order."""
+        return tuple(p for p, k in enumerate(self.pattern)
+                     if k.linear is None)
+
+    @property
+    def state_places(self) -> Tuple[int, ...]:
+        """The places of a period that keep a recurrent state, in order."""
+        return tuple(p for p, k in enumerate(self.pattern)
+                     if k.ssm is not None or k.linear is not None)
+
+    @property
+    def page_layers(self) -> int:
+        """Layers that keep pages: what the pool's first axis counts."""
+        return len(self.leading) + self.periods * len(self.page_places)
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state."""
+        return self.periods * len(self.state_places)
 
     @property
     def softmax_scale(self) -> float:
@@ -385,6 +545,8 @@ class DecoderSpec:
 
     @property
     def windows(self) -> Tuple[Optional[int], ...]:
-        """The window of every layer of the stack, in order."""
+        """The window of every layer that attends (a linear place reads no
+        key), in order."""
         return tuple(k.window for k in self.leading) \
-            + tuple(k.window for k in self.pattern) * self.periods
+            + tuple(k.window for k in self.pattern
+                    if k.linear is None) * self.periods

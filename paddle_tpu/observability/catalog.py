@@ -103,12 +103,15 @@ CATALOG: Dict[str, tuple] = {
     # ---- serving: what the pool holds of a token (PR 31) ----
     "serving.kv_bytes_per_token": (
         "gauge", "",
-        "pool bytes one cached token costs over all layers "
-        "(`pool_bytes / (num_pages x page_size)`): per-head K and V "
+        "pool bytes one cached token costs over the layers that KEEP "
+        "PAGES (`pool_bytes / (num_pages x page_size)`; "
+        "`inference/kv_cache.py::LayerPlanes`: a linear-attention place "
+        "keeps none): per-head K and V "
         "pages, or a latent pool's one row `[c | k_r]` a layer (5,760 for "
         "five latent layers of 512 + 64 in bf16; 7,040 where each also "
         "keeps an index key of 128; 16,384 for four layers of 8 KV heads "
-        "x 128, and for eight layers of 4)"),
+        "x 128, and for eight layers of 4; 4,096 for the ONE softmax layer "
+        "in four of 8 KV heads x 128)"),
     # ---- serving: a learned index over the latent pool (PR 39) ----
     "serving.index_bytes_per_token": (
         "gauge", "",
@@ -144,12 +147,22 @@ CATALOG: Dict[str, tuple] = {
     # ---- serving: what a slot holds besides pages (PR 34) ----
     "serving.state_bytes_per_slot": (
         "gauge", "",
-        "bytes of recurrent state one slot holds over all layers besides "
-        "its pages, fixed and by slot (`inference/kv_cache.py::"
-        "RecurrentState`): a state-space mixer's float32 state and its "
-        "convolution's carried rows (16,900,096 for four layers of 32 "
-        "heads x 128 x 256 and three rows of 5,120 in bf16); 0 for a "
-        "stack that keeps pages alone"),
+        "bytes of recurrent state one slot holds over the layers that "
+        "have one, besides its pages, fixed and by slot "
+        "(`inference/kv_cache.py::RecurrentState`, `LayerPlanes`): a "
+        "mixer's float32 state and its "
+        "convolution's carried rows (16,900,096 for four layers of a "
+        "state-space mixer of 32 "
+        "heads x 128 x 256 and three rows of 5,120 in bf16; 13,025,280 "
+        "for three linear-attention layers of 64 heads x 128 x 128 and "
+        "three rows of 24,576); 0 for a "
+        "stack that keeps pages alone.  A linear-attention place's "
+        "operations run under the device scope `linear_attn` (its "
+        "convolution `linear_attn/conv`, the decay, beta and the output "
+        "gate's projections `linear_attn/gates`, the delta-rule call "
+        "`linear_attn/kda`: `kernels/kda.py`, the custom call "
+        "`ragged_kda_update`), a state-space mixer's under `ssm`; a gated "
+        "softmax place's gate under `attention/out_gate`"),
     "serving.state_resets": (
         "counter", "",
         "slots whose recurrent state is zeroed on the device by the step "
@@ -624,9 +637,10 @@ SPANS: Dict[str, tuple] = {
         "with a learned index only) the pairs (query token, key) the index "
         "scores and the keys it chooses in ONE layer of this step (`q x "
         "ctx + q (q + 1) / 2` a working slot; `min(position + 1, top_k)` "
-        "a query token), `ssm_slots` and `ssm_tokens` (a stack with a "
-        "state-space mixer only) the slots whose recurrent state each "
-        "layer's scan call reads and writes in this step (those with "
+        "a query token), `ssm_slots` and `ssm_tokens` (a stack whose "
+        "slots hold a recurrent state only: a state-space mixer's or a "
+        "linear-attention place's) the slots whose recurrent state each "
+        "such layer's call reads and writes in this step (those with "
         "work) and the tokens they scan, `slots` the batch B, `waiting` "
         "the queue behind it"),
     "engine.admit": (

@@ -58,9 +58,19 @@ _SMALLTHINKER_PRESETS = {
     "smallthinker_tiny": lambda cfg: cfg.tiny(),
     "smallthinker_21b": lambda cfg: cfg.smallthinker_21b(depth=8),
 }
+# solar_open2 (models/solar_open2.py): the test size, and Solar-Open2-250B as
+# chip 0 of the eight that share each layer of its first pipeline stage holds
+# it: one period of four layers (a gated softmax layer, three linear-attention
+# ones), 40 of the 320 experts, an eighth of the vocabulary (6.6 GB; a slot
+# keeps 13.0 MB of recurrent state, and pages on one layer in four)
+_SOLAR_OPEN2_PRESETS = {
+    "solar_open2_tiny": lambda cfg: cfg.tiny(),
+    "solar_open2_250b": lambda cfg: cfg.solar_open2_250b(depth=4, share=8),
+}
 _PRESETS = _LLAMA_PRESETS + tuple(_COHERE2_MOE_PRESETS) \
     + tuple(_SARVAM_MLA_PRESETS) + tuple(_FALCON_H1_PRESETS) \
-    + tuple(_DEEPSEEK_V32_PRESETS) + tuple(_SMALLTHINKER_PRESETS)
+    + tuple(_DEEPSEEK_V32_PRESETS) + tuple(_SMALLTHINKER_PRESETS) \
+    + tuple(_SOLAR_OPEN2_PRESETS)
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -187,6 +197,11 @@ def build_engine(args):
                                            SmallThinkerForCausalLM)
         model = SmallThinkerForCausalLM(
             _SMALLTHINKER_PRESETS[args.preset](SmallThinkerConfig))
+    elif args.preset in _SOLAR_OPEN2_PRESETS:
+        from ..models.solar_open2 import (SolarOpen2Config,
+                                          SolarOpen2ForCausalLM)
+        model = SolarOpen2ForCausalLM(
+            _SOLAR_OPEN2_PRESETS[args.preset](SolarOpen2Config))
     else:
         from ..models.llama import LlamaConfig, LlamaForCausalLM
         model = LlamaForCausalLM(getattr(LlamaConfig, args.preset)())

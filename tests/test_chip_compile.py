@@ -960,6 +960,131 @@ def test_smallthinker_expert_layer_compiles_and_its_yardstick_matches(
 # as shapes that carry its own shardings: the program of the cell
 # `mistral7b-train-dp2mp2`, two layers deep (the layers are a loop)
 
+# ---- solar-open2-250b as one chip of eight holds it (PR 46) ----
+# gated delta-rule linear attention on three layers in four: 64 heads over a
+# float32 state [3, slots, 64, 128, 128], the step's tokens PACKED (a block
+# may begin at any row); the cell's engine: 192 slots, 2,560 positions in
+# pages of 16, chunks of 64, one period of four layers, 40 of 320 experts
+SO_B, SO_L, SO_H, SO_D = 192, 3, 64, 128
+
+
+@pytest.mark.timeout(300)
+@pytest.mark.parametrize("rows,chunk", [(SO_B, 1), (3072, 64), (12288, 64)],
+                         ids=["decode", "packed", "dense_grid"])
+def test_kda_update_solar_open2_shapes_compile(one_chip, rows, chunk):
+    """The delta-rule call at the published widths and 192 slots, on the
+    whole state read at a traced layer: 16 heads a program (a 1 MiB state
+    block in and out), a decode slot's tokens a block of one row and a
+    chunk's a block of 64 rows at an element offset, the state result
+    aliased to its operand (2.4 GB: the call's only large buffer), and the
+    call is what the benchmark matches on: the name, the decode slots' rows
+    first, the float32 state last and among the operands."""
+    from chipbench.kernels import kda_update
+    from paddle_tpu.kernels import kda
+    assert kda._heads_per_block(SO_H, SO_D, SO_D) == 16
+    assert kda.kda_geometry_error(SO_H, SO_D, SO_D) is None
+    f32, i32 = jnp.float32, jnp.int32
+    token = (rows, SO_H, SO_D)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in (
+        ((SO_L, SO_B, SO_H, SO_D, SO_D), f32), (token, BF16), (token, f32),
+        (token, f32), (token, f32), ((rows, SO_H), f32), ((SO_B,), i32),
+        ((SO_B,), i32), ((SO_B,), jnp.bool_), ((), i32))]
+    compiled = jax.jit(
+        lambda s, *a: kda._pallas_ragged_kda_update(
+            s, *a[:-1], chunk, a[-1], False),
+        donate_argnums=(0,)).lower(*args).compile()
+    state_bytes = SO_L * SO_B * SO_H * SO_D * SO_D * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == state_bytes            # in place
+    # the chunks' rows come back in [slots, chunk, heads, 128]: 201 MB that
+    # only chunk slots' blocks are written to; beside it the folded k, v
+    assert mem.temp_size_in_bytes < (64 << 20) + (
+        SO_B * chunk * SO_H * SO_D * 2 + 3 * rows * SO_H * SO_D * 2
+        if chunk > 1 else 0)
+    op, = _calls_as_the_trace_names_them(compiled, "ragged_kda_update")
+    assert kda_update.match(op) == {
+        "slots": SO_B, "heads": SO_H, "key_dim": SO_D, "value_dim": SO_D,
+        "chunk": chunk, "dtype": "bf16"}
+
+
+@pytest.mark.timeout(600)
+def test_solar_open2_step_program_compiles_at_published_widths(one_chip,
+                                                               monkeypatch):
+    """The packed T = 64 step program of the cell's engine (192 slots,
+    3,072 GEMM rows) at the published widths, on abstract parameters: one
+    period in line, its softmax place one paged call (a group of 8) and
+    its three linear places one delta-rule call each on the carried state,
+    every place three grouped GEMMs over 40 held experts; the pool (ONE
+    page layer) and the state (three) are updated in place, and the
+    program fits the chip beside its 6.62 GB of weights, 2.50 GB of state
+    and the cell's 2.01 GB pool (the pool here is a fifteenth of the
+    cell's: its size moves no operation but the commit's bounds)."""
+    from paddle_tpu.inference import generation as gen
+    from paddle_tpu.kernels import kda
+    from paddle_tpu.models.solar_open2 import (SolarOpen2Config,
+                                               SolarOpen2ForCausalLM,
+                                               layer_leaves)
+
+    monkeypatch.setattr(pa, "jax", _OnTpu())
+    monkeypatch.setattr(kda, "jax", _OnTpu())
+    monkeypatch.setattr(gm, "_mode", lambda interpret=None: "tpu")
+    cfg = SolarOpen2Config.solar_open2_250b(max_position_embeddings=2560)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt),
+                                    sharding=one_chip)
+
+    class Abstract:
+        config = cfg
+        decoder_spec = SolarOpen2ForCausalLM.decoder_spec
+
+        def serving_params(self):
+            H, V = cfg.hidden_size, cfg.vocab_size
+            return {"embed": sds((V, H), BF16), "norm": sds((H,), BF16),
+                    "head": sds((H, V), BF16),
+                    "blocks": tuple({
+                        name: (sds(shape, dt),) if name in gen.EXPERT_BANKS
+                        else sds((1,) + tuple(shape), dt)
+                        for name, shape, _, dt in layer_leaves(cfg, p > 0)}
+                        for p in range(4))}
+
+    pages, cell_pages = 2048, 30720
+    g = gen.LlamaGenerator(Abstract(), max_batch=SO_B, max_seq_len=2560,
+                           page_size=PAGE, prefill_bucket=64,
+                           num_pages=pages)
+    n_params = sum(int(jnp.prod(jnp.asarray(a.shape)))
+                   for a in jax.tree_util.tree_leaves(g.params))
+    assert n_params == 3_308_353_344
+    assert g.state_bytes_per_slot == 13_025_280
+    assert g.pool_bytes // (pages * PAGE) == 4096
+    assert [a.shape for a in g.cache.arrays] == [
+        (1, pages, 2, 8, PAGE, 128), (3, SO_B, 64, 128, 128),
+        (3, SO_B, 3, 24576)]
+    T, rows = 64, g.row_buckets(64)[0]
+    assert g.row_buckets(64) == [3072, 12288]
+    i32, key = jnp.int32, jax.random.key(0)
+    vec = lambda dt: sds((SO_B,), dt)             # noqa: E731
+    ops = (g.params, tuple(sds(a.shape, a.dtype) for a in g.cache.arrays),
+           sds((SO_B, T), i32), vec(i32), vec(i32), vec(jnp.bool_),
+           vec(jnp.bool_), vec(jnp.bool_), vec(i32), vec(i32),
+           sds((SO_B, g.pages_per_seq), i32),
+           jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one_chip))
+    compiled = g._step_jit(gen.GenerationConfig(), T, False, rows) \
+        .lower(*ops).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 + 3 + 4 * 3
+    assert len(_calls_as_the_trace_names_them(
+        compiled, "ragged_kda_update")) == 3
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in g.cache.arrays)
+    assert mem.alias_size_in_bytes == held       # pool and state in place
+    assert mem.temp_size_in_bytes < 1536 << 20
+    assert not _pool_shaped_copies(text, g.cache.kv)
+    peak = 2 * n_params + SO_B * 13_025_280 + cell_pages * PAGE * 4096 \
+        + mem.temp_size_in_bytes
+    assert 11.5e9 < peak < 13.5e9, peak
+
+
 def _abstract_train_step(topo, monkeypatch, layout, layers, batch,
                          seq=4096, **widths):
     import numpy as np
